@@ -14,14 +14,13 @@ levers instead of arguing:
 - **two_pass_var** — ``use_fast_variance=False``: textbook two-pass
   variance, expected slower (one more full activation read) — measured
   to bound how much the one-pass trick is already buying;
-- **XLA flag experiments** (run via subprocess so the flag reaches
-  backend init): ``--xla_tpu_scoped_vmem_limit_kib=65536`` (deeper
-  fusion headroom).
+- **XLA flag experiments** (child processes, so the flag reaches backend
+  init; they run BEFORE this process first touches JAX, because a parent
+  that holds the chip leaves none for a child):
+  ``--xla_tpu_scoped_vmem_limit_kib=65536`` (deeper fusion headroom).
 
 Each config: compile, warmup, timed steps on the attached chip →
-images/sec + MFU.  Output: one JSON object; commit to
-``benchmarks/results/resnet_levers_v5e.json`` and transcribe the table
-into ``docs/perf_r4.md``.
+images/sec + MFU.  Needs a TPU.  Output: one JSON object.
 
 Run: ``python benchmarks/resnet_levers.py [--iters 20]``
 Single-config child mode (used for flag experiments):
@@ -37,9 +36,6 @@ import subprocess
 import sys
 import time
 
-PEAK_V5E = 197e12
-FLOPS_FALLBACK = 3 * 2 * 4.09e9  # per image; bench.py convention
-
 
 def run_config(name: str, iters: int, warmup: int, batch_size: int,
                check_numerics: bool) -> dict:
@@ -48,6 +44,7 @@ def run_config(name: str, iters: int, warmup: int, batch_size: int,
     import numpy as np
     import optax
 
+    import bench
     from horovod_tpu.models import ResNet50
     from horovod_tpu.models.training import (
         create_train_state,
@@ -55,10 +52,8 @@ def run_config(name: str, iters: int, warmup: int, batch_size: int,
     )
     from horovod_tpu.parallel import MeshSpec, build_mesh, shard_batch
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    bs = batch_size if on_tpu else 8
-    img = 224 if on_tpu else 64
-    iters = iters if on_tpu else 3
+    device, peak = bench.require_tpu()
+    bs, img = batch_size, 224
 
     overrides = {
         "baseline": {},
@@ -75,9 +70,7 @@ def run_config(name: str, iters: int, warmup: int, batch_size: int,
     n_dev = len(jax.devices())
     if overrides.get("fuse_conv1x1_bn") and n_dev > 1:
         overrides["fused_bn_mesh"] = mesh  # shard_map flavor
-    model = ResNet50(num_classes=1000,
-                     dtype=jnp.bfloat16 if on_tpu else jnp.float32,
-                     **overrides)
+    model = ResNet50(num_classes=1000, dtype=jnp.bfloat16, **overrides)
     tx = optax.sgd(0.01, momentum=0.9)
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.rand(bs, img, img, 3), jnp.float32)
@@ -89,10 +82,7 @@ def run_config(name: str, iters: int, warmup: int, batch_size: int,
                                    donate=True)
     batch = shard_batch(mesh, {"x": x, "y": y})
     compiled = step.lower(state, batch).compile()
-    try:
-        flops = compiled.cost_analysis()["flops"]
-    except Exception:  # noqa: BLE001
-        flops = FLOPS_FALLBACK * bs
+    flops = compiled.cost_analysis()["flops"]
 
     losses = []
     for _ in range(max(1, warmup)):  # >=1: compile outside the timed loop
@@ -109,7 +99,8 @@ def run_config(name: str, iters: int, warmup: int, batch_size: int,
         "batch_size": bs,
         "step_ms": round(dt * 1e3, 3),
         "images_per_sec": round(bs / dt, 2),
-        "mfu": round(flops / dt / PEAK_V5E, 4) if on_tpu else None,
+        "mfu": round(flops / dt / peak, 4),
+        "device": device.device_kind,
         "final_loss": losses[-1],
         "finite": bool(np.isfinite(losses[-1])),
     }
@@ -133,71 +124,45 @@ def main() -> int:
                              "mode for flag experiments)")
     parser.add_argument("--out", default=None)
     args = parser.parse_args()
-
-    # Bounded backend probe BEFORE this process touches jax: a wedged
-    # chip must yield a structured record, not an infinite hang (the
-    # exact defense bench.py grew after round 4 — reuse it).
+    # bench.py (the peaks table) lives at the repo root
     sys.path.insert(0, os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
-    import bench as _bench
-
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
-        probe = _bench._probe_accelerator(
-            timeout_s=float(os.environ.get("HVD_BENCH_PROBE_TIMEOUT_S",
-                                           "120")),
-            retries=int(os.environ.get("HVD_BENCH_PROBE_RETRIES", "3")))
-        if not probe["ok"]:
-            line = json.dumps({"metric": "resnet50_bn_levers",
-                               "error": "tpu_unavailable", "probe": probe})
-            print(line)
-            if args.out:
-                with open(args.out, "w") as f:
-                    f.write(line + "\n")
-            return 0
 
     if args.single:
         print(json.dumps(run_config(args.single, args.iters, args.warmup,
                                     args.batch_size, True)))
         return 0
 
-    import jax
-
-    on_tpu = jax.devices()[0].platform == "tpu"
     results = {}
-    configs = ["baseline", "bf16_stats", "two_pass_var"]
-    if on_tpu:
-        # fused lever: TPU-only — interpret mode on CPU would run dozens
-        # of interpreted pallas grids per grad step.  Multi-device runs
-        # use the shard_map flavor (psum'd statistics).
-        configs.append("fused_conv1x1_bn")
-    else:
-        results["fused_conv1x1_bn"] = {
-            "skipped": "TPU-only (pallas kernel; no CPU interpret timing)"}
-    for name in configs:
-        results[name] = run_config(name, args.iters, args.warmup,
-                                   args.batch_size, True)
-        print(name, "->", results[name], file=sys.stderr)
-
-    # Flag experiments in child processes (XLA_FLAGS latch at backend init)
+    # Flag experiments first, each in a child process: XLA_FLAGS latch at
+    # backend init, and a chip belongs to one process at a time, so the
+    # children must be done before this process makes its first JAX call.
     here = os.path.abspath(__file__)
     for flag_name, flags in (
             ("vmem64m", "--xla_tpu_scoped_vmem_limit_kib=65536"),):
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flags).strip()
-        try:
-            proc = subprocess.run(
-                [sys.executable, here, "--single", "baseline",
-                 "--iters", str(args.iters), "--warmup", str(args.warmup),
-                 "--batch-size", str(args.batch_size)],
-                env=env, capture_output=True, text=True, timeout=560)
-            line = proc.stdout.strip().splitlines()[-1] if \
-                proc.stdout.strip() else ""
-            results[flag_name] = json.loads(line) if line.startswith("{") \
-                else {"error": proc.stderr[-500:]}
-        except Exception as e:  # noqa: BLE001
-            results[flag_name] = {"error": str(e)}
+        proc = subprocess.run(
+            [sys.executable, here, "--single", "baseline",
+             "--iters", str(args.iters), "--warmup", str(args.warmup),
+             "--batch-size", str(args.batch_size)],
+            env=env, capture_output=True, text=True, timeout=560)
+        lines = proc.stdout.strip().splitlines()
+        if proc.returncode == 0 and lines and lines[-1].startswith("{"):
+            results[flag_name] = json.loads(lines[-1])
+        else:
+            results[flag_name] = {"error": proc.stderr[-500:],
+                                  "returncode": proc.returncode}
         results[flag_name]["xla_flags"] = flags
         print(flag_name, "->", results[flag_name], file=sys.stderr)
+
+    # fused lever: multi-device runs use the shard_map flavor (psum'd
+    # statistics).
+    for name in ("baseline", "bf16_stats", "two_pass_var",
+                 "fused_conv1x1_bn"):
+        results[name] = run_config(name, args.iters, args.warmup,
+                                   args.batch_size, True)
+        print(name, "->", results[name], file=sys.stderr)
 
     payload = {"metric": "resnet50_bn_levers", "results": results}
     line = json.dumps(payload)

@@ -126,12 +126,9 @@ class XlaContext:
             if not jax_distributed_initialized():
                 _fail("jax.distributed is not initialized")
                 return
-            if jax.process_count() != topo.size or \
-                    jax.process_index() != topo.rank:
-                _fail("XLA data plane topology mismatch (jax procs=%d/%d "
-                      "vs horovod %d/%d)",
-                      jax.process_index(), jax.process_count(),
-                      topo.rank, topo.size)
+            if jax.process_count() != topo.size:
+                _fail("%d jax processes != world %d",
+                      jax.process_count(), topo.size)
                 return
             # One device per process: the eager plane stages each rank's
             # contribution on its first local device (process-per-chip
@@ -140,12 +137,28 @@ class XlaContext:
             per_proc = {}
             for d in jax.devices():
                 per_proc.setdefault(d.process_index, d)
-            devs = [per_proc[p] for p in sorted(per_proc)]
-            if len(devs) != topo.size:
-                _fail("%d jax processes != world %d", len(devs), topo.size)
+            # Mesh position r must be Horovod rank r (broadcast roots,
+            # allgather order and alltoall blocks index it), and the jax
+            # process index is not the rank: libtpu numbers single-chip
+            # processes by where their chip sits in the torus, whatever
+            # task id the launcher gave them (a v5e 2x2 host made ranks
+            # 0,1,2,3 processes 0,2,3,1).  So the order is read, not
+            # assumed; the gather is also the first cross-process
+            # collective, and fails here rather than in the first step.
+            from jax.experimental import multihost_utils
+
+            pairs = np.asarray(multihost_utils.process_allgather(
+                np.array([jax.process_index(), topo.rank], np.int32)))
+            proc_of_rank = {int(r): int(p) for p, r in pairs}
+            if sorted(proc_of_rank) != list(range(topo.size)) or \
+                    set(proc_of_rank.values()) != set(per_proc):
+                _fail("jax processes %s do not cover Horovod ranks 0..%d",
+                      pairs.tolist(), topo.size - 1)
                 return
-            self.device = per_proc[topo.rank]
-            self.mesh = Mesh(np.array(devs), ("proc",))
+            self.device = per_proc[jax.process_index()]
+            self.mesh = Mesh(
+                np.array([per_proc[proc_of_rank[r]]
+                          for r in range(topo.size)]), ("proc",))
             self.ready = True
             log.info("XLA eager data plane up: %d-process mesh on %s",
                      topo.size, self.device.platform)
@@ -194,7 +207,7 @@ class XlaContext:
         fused = self._get(key, build)(*[e.tensor for e in entries])
         # jit outputs land on the default device; only re-place when that
         # is not this rank's mesh device (device_put on an in-flight array
-        # is a dependent dispatch — a full round trip on remote backends).
+        # is one more dependent dispatch).
         if fused.devices() != {self.device}:
             fused = jax.device_put(fused, self.device)
         return fused
@@ -277,9 +290,7 @@ class XlaContext:
                         prescale: float, postscale: float) -> tuple:
         """size==1 allreduce: one jit, straight from entry tensors to
         per-entry outputs (sum over one rank is identity × scales).  No
-        fuse buffer, no mesh resharding — a single dispatch keeps the
-        host→device chain one hop deep, which matters on remote backends
-        where every dependent dispatch costs a round trip."""
+        fuse buffer, no mesh resharding — a single dispatch."""
         import jax
         import jax.numpy as jnp
 
@@ -513,21 +524,9 @@ def is_jax_array(t: Any) -> bool:
 
 
 def jax_distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` across jax versions: the
-    public predicate only exists in newer jax; older releases (e.g.
-    0.4.37) expose the same fact as the distributed global state's live
-    client.  Without this shim the whole np>1 XLA data plane is
-    unavailable on those versions (the AttributeError aborts init)."""
     import jax
 
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    try:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:  # noqa: BLE001 — unknown layout: assume not up
-        return False
+    return jax.distributed.is_initialized()
 
 
 def data_plane_requested() -> str:
@@ -756,13 +755,6 @@ class XlaAlltoall(XlaOp):
         inner = tuple(entry.tensor.shape[1:])
         inner_n = int(np.prod(inner)) if inner else 1
 
-        # Deterministic capability pre-check (same jax build on every
-        # rank): a missing lax.ragged_all_to_all must not be discovered
-        # via a rank-local AttributeError mid-dispatch, where it would be
-        # indistinguishable from a transient fault.
-        if not XlaAlltoall._ragged_broken and \
-                not hasattr(jax.lax, "ragged_all_to_all"):
-            XlaAlltoall._ragged_broken = True
         if (not XlaAlltoall._ragged_broken
                 and _device_platform(ctx) == "tpu"):
             try:
@@ -853,7 +845,7 @@ class XlaAlltoall(XlaOp):
         key = ("a2a.ragged", tuple(matrix), inner, str(np_dtype))
 
         def build():
-            from ..parallel.sharding import _shard_map as shard_map
+            from jax import shard_map
 
             elems = m * inner_n
             in_offs = np.zeros((size, size), np.int32)

@@ -226,19 +226,11 @@ def _reinit_xla_plane(topo) -> None:
     # shrink to size 1, where a leftover distributed client would keep
     # heartbeating a coordinator that may live on the dead host.
     if xla_backend.jax_distributed_initialized():
-        from jax._src import xla_bridge
+        import jax.extend.backend
 
         jax.distributed.shutdown()
         jax.clear_caches()
-        try:
-            # Supported path first (also invalidates pjit/device caches);
-            # fall back to the private bridge hook on jax versions where
-            # jax.extend lacks it.
-            import jax.extend.backend
-
-            jax.extend.backend.clear_backends()
-        except (ImportError, AttributeError):
-            xla_bridge._clear_backends()
+        jax.extend.backend.clear_backends()
     elif plane != "xla":
         return  # auto mode never had a device plane; keep TCP
 

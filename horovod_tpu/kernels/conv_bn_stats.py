@@ -26,8 +26,9 @@ its vendor library, we get it from pallas.
 
 Numerics: accumulation and statistics in fp32 (like the shipped
 ``force_float32_reductions`` BN config); output cast to the model dtype
-(bf16).  Verified against the unfused composition in interpret mode
-(``tests/test_conv_bn_kernel.py``) for values and gradients.
+(bf16).  Verified against the unfused composition for values and
+gradients: interpreted on CPU (``tests/test_conv_bn_kernel.py``) and
+compiled on the chip at ResNet-50's 1x1 shapes (``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -43,11 +44,10 @@ _DEF_BN = 256
 _DEF_BK = 256
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover — backend init failure
-        return False
+# Height of an fp32 VMEM tile: the statistics partials leave the kernel as
+# whole (8, bn) tiles, because Mosaic rejects an output block whose
+# second-to-last dimension is neither a multiple of 8 nor the full array.
+_SUBLANES = 8
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -82,13 +82,17 @@ def _matmul_stats_kernel(x_ref, w_ref, y_ref, s1_ref, s2_ref, acc_ref):
     def _epilogue():
         acc = acc_ref[:]
         y_ref[:] = acc.astype(y_ref.dtype)
-        # Per-channel partials for THIS i block; reduced outside.
-        s1_ref[:] = jnp.sum(acc, axis=0, keepdims=True)
-        s2_ref[:] = jnp.sum(acc * acc, axis=0, keepdims=True)
+        # Per-channel partials for THIS i block, one row per sublane (row
+        # r sums tile rows r, r+8, ...: elementwise adds of whole vregs,
+        # no cross-sublane reduce); collapsed outside.
+        bm, bn = acc.shape
+        part = acc.reshape(bm // _SUBLANES, _SUBLANES, bn)
+        s1_ref[:] = jnp.sum(part, axis=0)
+        s2_ref[:] = jnp.sum(part * part, axis=0)
 
 
 def _matmul_stats_fwd_pallas(x: jnp.ndarray, w: jnp.ndarray,
-                             bm: int, bn: int, bk: int, interpret: bool
+                             bm: int, bn: int, bk: int
                              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -96,6 +100,11 @@ def _matmul_stats_fwd_pallas(x: jnp.ndarray, w: jnp.ndarray,
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
+    if bm % _SUBLANES:
+        raise ValueError(f"bm={bm} must be a multiple of {_SUBLANES}")
+    # A K that fits one block is taken whole (a block may span a full
+    # array dimension of any size), so K=64 is not padded to 256.
+    bk = min(bk, k)
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
     wp = _pad_to(_pad_to(w, 0, bk), 1, bn)
     mp, kp = xp.shape
@@ -111,16 +120,19 @@ def _matmul_stats_fwd_pallas(x: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((_SUBLANES, bn), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((_SUBLANES, bn), lambda i, j, kk: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, np_), x.dtype),
-            jax.ShapeDtypeStruct((gi, np_), jnp.float32),
-            jax.ShapeDtypeStruct((gi, np_), jnp.float32),
+            jax.ShapeDtypeStruct((gi * _SUBLANES, np_), jnp.float32),
+            jax.ShapeDtypeStruct((gi * _SUBLANES, np_), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        # Compiled on a TPU, interpreted everywhere else; never a choice.
+        interpret=jax.default_backend() != "tpu",
         cost_estimate=pl.CostEstimate(
             flops=2 * mp * np_ * kp,
             bytes_accessed=(mp * kp + kp * np_) * x.dtype.itemsize
@@ -131,31 +143,25 @@ def _matmul_stats_fwd_pallas(x: jnp.ndarray, w: jnp.ndarray,
     return (y[:m, :n], jnp.sum(s1p, axis=0)[:n], jnp.sum(s2p, axis=0)[:n])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def matmul_bn_stats(x: jnp.ndarray, w: jnp.ndarray,
-                    bm: int = _DEF_BM, bn: int = _DEF_BN, bk: int = _DEF_BK,
-                    interpret: bool | None = None
+                    bm: int = _DEF_BM, bn: int = _DEF_BN, bk: int = _DEF_BK
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``y = x @ w`` plus per-channel ``(sum(y), sum(y*y))`` in one pass.
 
     ``x``: ``[M, K]`` (model dtype, e.g. bf16), ``w``: ``[K, N]``.
     Returns ``(y [M,N] in x.dtype, s1 [N] f32, s2 [N] f32)``.
-    ``interpret=None`` auto-selects the pallas interpreter off-TPU (CPU
-    tests / virtual meshes)."""
-    return _fwd_impl(x, w, bm, bn, bk, interpret)
+    Off-TPU (CPU tests / virtual meshes) the pallas interpreter runs the
+    same kernel body."""
+    return _matmul_stats_fwd_pallas(x, w, bm, bn, bk)
 
 
-def _fwd_impl(x, w, bm, bn, bk, interpret):
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return _matmul_stats_fwd_pallas(x, w, bm, bn, bk, interp)
-
-
-def _fwd_rule(x, w, bm, bn, bk, interpret):
-    y, s1, s2 = _fwd_impl(x, w, bm, bn, bk, interpret)
+def _fwd_rule(x, w, bm, bn, bk):
+    y, s1, s2 = _matmul_stats_fwd_pallas(x, w, bm, bn, bk)
     return (y, s1, s2), (x, w, y)
 
 
-def _bwd_rule(bm, bn, bk, interpret, residuals, cotangents):
+def _bwd_rule(bm, bn, bk, residuals, cotangents):
     """VJP: with ``r = dy + ds1·1ᵀ + 2·y∘ds2·1ᵀ`` (the stats cotangents
     broadcast over rows), ``dx = r @ wᵀ`` and ``dw = xᵀ @ r`` — plain XLA
     matmuls; the fusion win targeted the forward stats read.

@@ -13,11 +13,6 @@ from typing import Any, Optional, Sequence, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
@@ -51,10 +46,6 @@ def shard_batch(mesh: Mesh, batch: Any,
 
 
 def shard_map_fn(fn, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Uniform wrapper over jax's shard_map (API moved across jax versions)."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    except TypeError:  # older kwarg name
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with this repo's default of ``check_vma=False``."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

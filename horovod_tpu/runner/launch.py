@@ -73,7 +73,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="don't export per-slot TPU_VISIBLE_CHIPS/"
                         "TPU_PROCESS_* (default: exported on TPU VMs when "
                         "a host runs more than one slot)")
-    p.add_argument("--data-plane", default=None, choices=["xla", "tcp", "auto"])
+    p.add_argument("--data-plane", default=None, choices=["xla", "tcp", "auto"],
+                   help="eager collectives on the device mesh (xla, strict), "
+                        "the host ring (tcp), or xla when it comes up "
+                        "(auto); default: xla when the launcher binds one "
+                        "chip per process, else tcp")
     # elastic (wired by horovod_tpu.elastic)
     p.add_argument("--min-np", type=int, default=None)
     p.add_argument("--max-np", type=int, default=None)
@@ -106,22 +110,15 @@ def _slot_env(slot: SlotInfo, rdv_addr: str, rdv_port: int,
         env_mod.HOROVOD_RENDEZVOUS_PORT: str(rdv_port),
         env_mod.HOROVOD_CONTROLLER: "tcp",
     })
+    job_host_slots = job_host_slots or [("localhost", slot.local_size)]
     if tpu_chip_binding is None:
-        # Auto-decide so every launch path (static, elastic, programmatic
-        # run()) binds consistently; only the static CLI exposes an opt-out.
-        # The decision is job-global (ANY host multi-slot → every slot
-        # binds): a single-slot host must still join the slice-wide
-        # process tiling the other ranks' TPU_PROCESS_ADDRESSES count.
-        multi = (any(n > 1 for _, n in job_host_slots)
-                 if job_host_slots else slot.local_size > 1)
-        tpu_chip_binding = tpu_topology.running_on_tpu_vm() and multi
+        tpu_chip_binding = binds_chips(job_host_slots)
     if tpu_chip_binding:
         # One process per chip (reference role: per-slot CUDA_VISIBLE_DEVICES
         # construction in gloo_run.py:65-76; here libtpu needs the full
         # TPU_PROCESS_* tiling, see tpu_topology.slot_tpu_env).
         env.update(tpu_topology.slot_tpu_env(
-            slot.rank, slot.local_rank,
-            job_host_slots or [("localhost", slot.local_size)]))
+            slot.rank, slot.local_rank, job_host_slots))
     env.update(extra)
     # Make horovod_tpu importable in workers regardless of their cwd /
     # script location (the reference relies on pip-installation instead).
@@ -131,6 +128,17 @@ def _slot_env(slot: SlotInfo, rdv_addr: str, rdv_port: int,
     if pkg_parent not in parts:
         env["PYTHONPATH"] = os.pathsep.join([pkg_parent] + [p for p in parts if p])
     return env
+
+
+def binds_chips(job_host_slots: List) -> bool:
+    """Whether the launcher gives each slot one chip, decided so every
+    launch path (static, programmatic run()) binds consistently; only the
+    static CLI exposes an opt-out.  The decision is job-global (ANY host
+    multi-slot → every slot binds): a single-slot host must still join the
+    slice-wide process tiling the other ranks' TPU_PROCESS_ADDRESSES count.
+    """
+    return tpu_topology.running_on_tpu_vm() and \
+        any(n > 1 for _, n in job_host_slots)
 
 
 def spawn_worker(slot: SlotInfo, command: List[str],
@@ -188,7 +196,8 @@ def _ssh_command(slot: SlotInfo, command: List[str],
     """Remote slot: carry HOROVOD_*/PYTHON* env through ssh explicitly
     (reference ``gloo_run.py:133-183`` builds the same kind of line)."""
     # Forward only keys WE set for this slot: HOROVOD_* plus the per-slot
-    # chip-binding keys from slot_tpu_env.  Never blanket-forward ambient
+    # chip-binding keys from slot_tpu_env, and where the operator placed
+    # the compile cache.  Never blanket-forward ambient
     # TPU_*/JAX_* from the launcher VM — e.g. its own TPU_WORKER_ID=0
     # would clobber every remote host's identity and break slice init.
     # The job's HMAC key travels over ssh STDIN, not the command line —
@@ -196,7 +205,7 @@ def _ssh_command(slot: SlotInfo, command: List[str],
     exports = " ".join(
         f"{k}={shlex.quote(v)}" for k, v in env.items()
         if (k.startswith("HOROVOD_") and k != env_mod.HOROVOD_SECRET_KEY)
-        or k in ("PYTHONPATH", "PATH")
+        or k in ("PYTHONPATH", "PATH", "JAX_COMPILATION_CACHE_DIR")
         or k in tpu_topology.SLOT_ENV_KEYS)
     remote = "IFS= read -r HOROVOD_SECRET_KEY && export HOROVOD_SECRET_KEY" \
         f" && cd {shlex.quote(os.getcwd())} && env {exports} " + \
@@ -266,8 +275,9 @@ def launch_job(args, command: List[str]) -> int:
             print(f"hvdrun: discovered TPU slice hosts: {hosts_str}",
                   file=sys.stderr)
     slots = get_host_assignments(parse_hosts(hosts_str), args.num_proc)
-    tpu_chip_binding = False if args.no_tpu_chip_binding else None
     job_host_slots = host_slots_of(slots)
+    tpu_chip_binding = (not args.no_tpu_chip_binding
+                        and binds_chips(job_host_slots))
 
     # Per-job HMAC key for every service-plane RPC (reference secret.py:36).
     from ..common import secret as secret_mod
@@ -310,7 +320,13 @@ def launch_job(args, command: List[str]) -> int:
     # in rank 0's process regardless of where the KV store lives).
     rdv_host = ext_host if external else rdv_addr
     extra = config_parser.env_from_args(args)
-    if (args.data_plane or "").lower() in ("xla", "auto"):
+    data_plane = (args.data_plane or "").lower()
+    if not data_plane and tpu_chip_binding:
+        # One process per chip means device gradients: reduce them on the
+        # chips (strict — a plane that cannot come up is an error), not by
+        # copying each one to the host and round the TCP ring.
+        data_plane = extra[env_mod.HOROVOD_DATA_PLANE] = "xla"
+    if data_plane in ("xla", "auto"):
         # The jax.distributed coordination service runs inside rank 0's
         # process; every worker needs its address before first device use.
         coord_host = slots[0].hostname

@@ -133,12 +133,15 @@ def slot_tpu_env(rank: int, local_rank: int,
 
 
 def running_on_tpu_vm() -> bool:
-    """True when this machine exposes TPU devices (accel device nodes or
-    the Cloud TPU runtime env)."""
+    """True when this machine exposes TPU chips: the Cloud TPU runtime env,
+    or chip device nodes — ``/dev/accel<N>`` on v2–v4 hosts,
+    ``/dev/vfio/<N>`` on v5e and later."""
     if os.environ.get("TPU_ACCELERATOR_TYPE") or \
             os.environ.get("TPU_WORKER_HOSTNAMES"):
         return True
     try:
-        return any(name.startswith("accel") for name in os.listdir("/dev"))
+        if any(name.startswith("accel") for name in os.listdir("/dev")):
+            return True
+        return any(name.isdigit() for name in os.listdir("/dev/vfio"))
     except OSError:
         return False

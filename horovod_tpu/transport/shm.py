@@ -58,7 +58,6 @@ import errno
 import glob
 import os
 import queue
-import struct
 import threading
 import time
 import uuid
@@ -112,7 +111,8 @@ _SHM_MAGIC = 0x48565348  # "HVSH"
 # fails loudly at attach, like every other layout change.
 _SHM_VERSION = 2
 
-# Segment header layout (little-endian).  Direction counters sit 64 bytes
+# Segment header layout (native byte order: both ends are on this host).
+# Direction counters sit 64 bytes
 # apart so the two writers never share a cache line.
 _OFF_MAGIC = 0          # u32
 _OFF_VERSION = 4        # u32
@@ -132,9 +132,6 @@ _OFF_L2H_SPACE_BELL = 296  # u32: bumped by higher (L2H receiver) only
 _OFF_H2L_DATA_BELL = 304   # u32: bumped by higher (H2L sender) only
 _OFF_H2L_SPACE_BELL = 312  # u32: bumped by lower (H2L receiver) only
 _RINGS_OFF = 320        # L2H ring, then H2L ring at +capacity
-
-_U32 = struct.Struct("<I")
-_U64 = struct.Struct("<Q")
 
 # Blocked ring waits sleep on a FUTEX DOORBELL: each direction carries
 # two u32 bells, each with exactly ONE writer — the sender bumps the
@@ -225,20 +222,30 @@ _MIN_RING_BYTES = 4096
 # store, every bell read and write, and the magic/version words go
 # through these four functions, so the set of shared-memory accesses the
 # model checker must consider is closed by construction.
+#
+# Each access must be ONE aligned machine-word move: the ring protocol and
+# its proof take a load or store of head, tail or a bell to be atomic.
+# ``struct.pack_into`` is not — it zero-fills its target and then, for an
+# explicit byte order, writes a byte at a time, so a concurrent reader in
+# the peer process sees 0 or a half-written counter (observed: a torn
+# head gave a negative run and killed the background loop of an np=2
+# ResNet-50 job).  Item access on a memoryview cast to a native word is a
+# single word-sized memcpy; the peer is on this host, so native byte
+# order is shared.  All header offsets are word-aligned.
 def _load_u64(buf, off: int) -> int:
-    return _U64.unpack_from(buf, off)[0]
+    return buf[off:off + 8].cast("Q")[0]
 
 
 def _store_u64(buf, off: int, value: int) -> None:
-    _U64.pack_into(buf, off, value)
+    buf[off:off + 8].cast("Q")[0] = value
 
 
 def _load_u32(buf, off: int) -> int:
-    return _U32.unpack_from(buf, off)[0]
+    return buf[off:off + 4].cast("I")[0]
 
 
 def _store_u32(buf, off: int, value: int) -> None:
-    _U32.pack_into(buf, off, value)
+    buf[off:off + 4].cast("I")[0] = value
 
 
 # -- ring protocol kernel (model-checked; see tools/mck) ----------------------

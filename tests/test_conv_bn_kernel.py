@@ -34,7 +34,7 @@ def test_matmul_stats_matches_reference(m, k, n):
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(m, k), jnp.float32)
     w = jnp.asarray(rng.randn(k, n), jnp.float32)
-    y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128, True)
+    y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128)
     yr, s1r, s2r = _ref(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=1e-5, atol=1e-4)
@@ -49,7 +49,7 @@ def test_matmul_stats_bf16_inputs():
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(128, 64), jnp.bfloat16)
     w = jnp.asarray(rng.randn(64, 96), jnp.bfloat16)
-    y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128, True)
+    y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128)
     assert y.dtype == jnp.bfloat16
     assert s1.dtype == s2.dtype == jnp.float32
     yr = jnp.dot(x, w, preferred_element_type=jnp.float32)
@@ -69,7 +69,7 @@ def test_matmul_stats_gradients_match():
     w = jnp.asarray(rng.randn(40, 24), jnp.float32)
 
     def loss_fused(x, w):
-        y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128, True)
+        y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128)
         mean = s1 / y.shape[0]
         var = s2 / y.shape[0] - mean * mean
         return jnp.sum((y - mean) * jax.lax.rsqrt(var + 1e-5)) \
@@ -238,13 +238,13 @@ def test_sharded_kernel_matches_single_device():
         return jnp.sum((y - mean) * jax.lax.rsqrt(var + 1e-5))
 
     def loss_single(x, w):
-        y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128, True)
+        y, s1, s2 = matmul_bn_stats(x, w, 128, 128, 128)
         mean = s1 / y.shape[0]
         var = s2 / y.shape[0] - mean * mean
         return jnp.sum((y - mean) * jax.lax.rsqrt(var + 1e-5))
 
     ys, s1s, s2s = sharded_matmul_bn_stats(x, w, mesh)
-    yr, s1r, s2r = matmul_bn_stats(x, w, 128, 128, 128, True)
+    yr, s1r, s2r = matmul_bn_stats(x, w, 128, 128, 128)
     np.testing.assert_allclose(np.asarray(ys), np.asarray(yr),
                                rtol=1e-5, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s1s), np.asarray(s1r),

@@ -478,3 +478,55 @@ def test_hierarchical_np4_shm_intra_tcp_cross_bit_identical():
     for r, out in enumerate(tcp):
         # forced tcp takes the pre-seam TcpMesh path: no links classified
         assert f"LINKS {r} 0 0" in out, (r, out)
+
+
+# ---------------------------------------------------------------------------
+# control words move as single machine words
+# ---------------------------------------------------------------------------
+
+def _counter_writer(name, n):
+    from multiprocessing import shared_memory
+
+    from horovod_tpu.transport.shm import _store_u64
+
+    seg = shared_memory.SharedMemory(name=name)
+    try:
+        value = 0
+        for _ in range(n):
+            value += 251  # every store changes several bytes
+            _store_u64(seg.buf, 64, value)
+        _store_u64(seg.buf, 128, 1)
+    finally:
+        seg.close()
+
+
+def test_control_word_access_is_never_torn():
+    """The ring protocol (and hvd-mck's proof of it) takes a load or store
+    of head/tail to be atomic.  A reader in another process must only ever
+    see values the writer stored: ``struct.pack_into`` zero-fills and then
+    writes a byte at a time, and a torn head once gave a negative run that
+    killed the background loop of a two-process ResNet-50 job."""
+    import multiprocessing as mp
+    from multiprocessing import shared_memory
+
+    from horovod_tpu.transport.shm import _load_u64, _store_u64
+
+    seg = shared_memory.SharedMemory(create=True, size=256)
+    try:
+        _store_u64(seg.buf, 64, 0)
+        _store_u64(seg.buf, 128, 0)
+        proc = mp.get_context("spawn").Process(
+            target=_counter_writer, args=(seg.name, 400_000))
+        proc.start()
+        last = reads = 0
+        while _load_u64(seg.buf, 128) == 0 and proc.is_alive():
+            value = _load_u64(seg.buf, 64)
+            reads += 1
+            assert value >= last and value % 251 == 0, (last, value)
+            last = value
+        proc.join(timeout=60)
+        assert not proc.is_alive() and proc.exitcode == 0
+        assert reads > 1000 and _load_u64(seg.buf, 64) == 251 * 400_000
+    finally:
+        seg.close()
+        seg.unlink()

@@ -101,12 +101,16 @@ def test_process_bounds_shapes():
 
 
 def test_hvdrun_exports_chip_binding(tmp_path):
-    """hvdrun on a (simulated) TPU VM gives each slot its own chip."""
+    """hvdrun on a (simulated) TPU VM gives each slot its own chip and,
+    with no --data-plane, the strict XLA plane with its coordinator:
+    gradients that live on a chip are not reduced over the host ring."""
     script = tmp_path / "show.py"
     script.write_text(textwrap.dedent("""
         import os
         print("CHIP", os.environ["HOROVOD_RANK"],
               os.environ.get("TPU_VISIBLE_CHIPS"), flush=True)
+        print("PLANE", os.environ.get("HOROVOD_DATA_PLANE"),
+              bool(os.environ.get("HOROVOD_JAX_COORDINATOR")), flush=True)
     """))
     env = dict(os.environ, TPU_ACCELERATOR_TYPE="v5litepod-4")
     env.pop("TPU_WORKER_HOSTNAMES", None)
@@ -116,12 +120,21 @@ def test_hvdrun_exports_chip_binding(tmp_path):
         cwd=REPO_ROOT, text=True, capture_output=True, timeout=60, env=env)
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "CHIP 0 0" in proc.stdout and "CHIP 1 1" in proc.stdout
+    assert proc.stdout.count("PLANE xla True") == 2
+    # an explicit plane wins over the default
+    proc = subprocess.run(
+        [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "2",
+         "-H", "localhost:2", "--data-plane", "tcp",
+         sys.executable, str(script)],
+        cwd=REPO_ROOT, text=True, capture_output=True, timeout=60, env=env)
+    assert proc.stdout.count("PLANE tcp False") == 2, proc.stdout
 
 
 def test_hvdrun_no_chip_binding_off_tpu(tmp_path):
     script = tmp_path / "show.py"
     script.write_text(
-        "import os; print('CHIP', repr(os.environ.get('TPU_VISIBLE_CHIPS')))")
+        "import os; print('CHIP', repr(os.environ.get('TPU_VISIBLE_CHIPS')),"
+        " repr(os.environ.get('HOROVOD_DATA_PLANE')))")
     env = dict(os.environ)
     env.pop("TPU_ACCELERATOR_TYPE", None)
     env.pop("TPU_WORKER_HOSTNAMES", None)
@@ -130,7 +143,7 @@ def test_hvdrun_no_chip_binding_off_tpu(tmp_path):
          sys.executable, str(script)],
         cwd=REPO_ROOT, text=True, capture_output=True, timeout=60, env=env)
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
-    assert "CHIP None" in proc.stdout
+    assert "CHIP None None" in proc.stdout
 
 
 def test_start_timeout_aborts_unstarted_job(tmp_path):

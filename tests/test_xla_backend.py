@@ -132,6 +132,45 @@ print("XLA_4P_OK", rank, flush=True)
         assert f"XLA_4P_OK {r}" in o
 
 
+def test_xla_mesh_follows_horovod_ranks_not_jax_process_ids():
+    """libtpu numbers single-chip processes by where their chips sit, not
+    by the launcher's task id (a v5e 2x2 host made ranks 0,1,2,3 jax
+    processes 0,2,3,1).  Everything rank-indexed on the device plane —
+    broadcast roots, allgather order, alltoall blocks — must follow the
+    Horovod rank regardless."""
+    from .helpers import PREAMBLE
+
+    shifted = """
+import os, jax
+_r, _n = int(os.environ["HOROVOD_RANK"]), int(os.environ["HOROVOD_SIZE"])
+jax.distributed.initialize(os.environ["HOROVOD_JAX_COORDINATOR"], _n,
+                           (_r + 1) % _n)
+""" + PREAMBLE
+    out = run_distributed(3, _ASSERT_XLA + """
+import jax, jax.numpy as jnp
+assert jax.process_index() == (rank + 1) % size != rank
+b = hvd.broadcast(jnp.full((4,), float(rank)), root_rank=1, name="b")
+assert np.allclose(np.asarray(b), 1.0), b
+g = hvd.allgather(jnp.full((rank + 1, 2), float(rank)), name="g")
+exp = np.concatenate([np.full((r + 1, 2), float(r)) for r in range(size)])
+assert np.array_equal(np.asarray(g), exp), g
+splits = [(rank + j) % 2 + 1 for j in range(size)]
+x = jnp.concatenate([jnp.full((n, 2), 10.0 * rank + j)
+                     for j, n in enumerate(splits)])
+o = hvd.alltoall(x, splits=splits, name="a")
+exp = np.concatenate([np.full(((r + rank) % 2 + 1, 2), 10.0 * r + rank)
+                      for r in range(size)])
+assert np.array_equal(np.asarray(o), exp), (o, exp)
+s = hvd.allreduce(jnp.full((4,), float(rank + 1)), op=hvd.Sum, name="s")
+assert np.allclose(np.asarray(s), 6.0), s
+assert all(stats.get(k, 0) >= 1 for k in
+           ("broadcast", "allgather", "alltoall", "allreduce")), stats
+print("XLA_ORDER_OK", rank, flush=True)
+""", extra_env=_xla_env(), preamble=shifted)
+    for r, o in enumerate(out):
+        assert f"XLA_ORDER_OK {r}" in o
+
+
 def test_xla_single_process_lazy_context():
     """Without HOROVOD_DATA_PLANE, a single-process world still uses the
     device plane lazily the first time a jax array is enqueued."""
