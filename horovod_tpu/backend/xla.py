@@ -46,6 +46,7 @@ from ..common.logging_util import get_logger
 from ..common.topology import ProcessTopology
 from ..core.messages import Response, ResponseType
 from ..core.tensor_queue import Status, TensorTableEntry
+from ..core.timeline import phase, program_call
 
 log = get_logger("horovod_tpu.backend.xla")
 
@@ -196,15 +197,16 @@ class XlaContext:
         key = ("fuse", shapes, str(np_dtype), bucket)
 
         def build():
-            def f(*tensors):
+            def hvd_fuse(*tensors):
                 flat = [t.ravel() for t in tensors]
                 total = sum(int(np.prod(s)) if s else 1 for s in shapes)
                 if bucket > total:
                     flat.append(jnp.zeros((bucket - total,), np_dtype))
                 return jnp.concatenate(flat) if len(flat) > 1 else flat[0]
-            return jax.jit(f)
+            return jax.jit(hvd_fuse)
 
-        fused = self._get(key, build)(*[e.tensor for e in entries])
+        fused = program_call(self._get(key, build),
+                             *[e.tensor for e in entries])
         # jit outputs land on the default device; only re-place when that
         # is not this rank's mesh device (device_put on an in-flight array
         # is one more dependent dispatch).
@@ -221,7 +223,7 @@ class XlaContext:
         key = ("unfuse", shapes, str(buf.dtype), buf.shape)
 
         def build():
-            def f(x):
+            def hvd_unfuse(x):
                 outs = []
                 off = 0
                 for s in shapes:
@@ -229,9 +231,9 @@ class XlaContext:
                     outs.append(x[off:off + n].reshape(s))
                     off += n
                 return tuple(outs)
-            return jax.jit(f)
+            return jax.jit(hvd_unfuse)
 
-        outs = self._get(key, build)(buf)
+        outs = program_call(self._get(key, build), buf)
         for e, o in zip(entries, outs):
             e.output = _localize(o)
 
@@ -273,7 +275,7 @@ class XlaContext:
             dt = np.dtype(np_dtype)
             widen = dt.itemsize <= 2 and jnp.issubdtype(dt, jnp.floating)
 
-            def f(x):
+            def hvd_allreduce(x):
                 acc = x.astype(jnp.float32) if widen else x
                 if prescale != 1.0:
                     acc = acc * prescale
@@ -282,7 +284,8 @@ class XlaContext:
                     s = s * postscale
                 return s.astype(dt)
 
-            return jax.jit(f, in_shardings=(in_sh,), out_shardings=rep)
+            return jax.jit(hvd_allreduce, in_shardings=(in_sh,),
+                           out_shardings=rep)
 
         return self._get(key, build)
 
@@ -302,7 +305,7 @@ class XlaContext:
             widen = dt.itemsize <= 2 and jnp.issubdtype(dt, jnp.floating)
             scale = prescale * postscale
 
-            def f(*ts):
+            def hvd_local_allreduce(*ts):
                 outs = []
                 for t in ts:
                     acc = t.astype(jnp.float32) if widen else t
@@ -311,9 +314,10 @@ class XlaContext:
                     outs.append(acc.astype(dt))
                 return tuple(outs)
 
-            return jax.jit(f)
+            return jax.jit(hvd_local_allreduce)
 
-        return self._get(key, build)(*[e.tensor for e in entries])
+        return program_call(self._get(key, build),
+                            *[e.tensor for e in entries])
 
     def allreduce_unfuse_fn(self, shapes: Tuple, bucket: int, np_dtype,
                             prescale: float, postscale: float) -> Callable:
@@ -334,7 +338,7 @@ class XlaContext:
             dt = np.dtype(np_dtype)
             widen = dt.itemsize <= 2 and jnp.issubdtype(dt, jnp.floating)
 
-            def f(x):
+            def hvd_allreduce_unfuse(x):
                 acc = x.astype(jnp.float32) if widen else x
                 if prescale != 1.0:
                     acc = acc * prescale
@@ -350,7 +354,8 @@ class XlaContext:
                     off += n
                 return tuple(outs)
 
-            return jax.jit(f, in_shardings=(in_sh,), out_shardings=rep)
+            return jax.jit(hvd_allreduce_unfuse, in_shardings=(in_sh,),
+                           out_shardings=rep)
 
         return self._get(key, build)
 
@@ -392,7 +397,7 @@ class XlaContext:
                                           jnp.float32))
                 return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
-            def f(x):  # [1, bucket] local block
+            def hvd_adasum(x):  # [1, bucket] local block
                 v = x.reshape(-1).astype(jnp.float32)
                 if prescale != 1.0:
                     v = v * prescale
@@ -410,7 +415,7 @@ class XlaContext:
                     for i in range(len(shapes)))
 
             if size == 1:
-                def f1(x):
+                def hvd_adasum_local(x):
                     v = x.reshape(-1).astype(jnp.float32)
                     scale = prescale * postscale
                     if scale != 1.0:
@@ -420,7 +425,7 @@ class XlaContext:
                         out[bounds[i]:bounds[i + 1]].reshape(shapes[i])
                         for i in range(len(shapes)))
 
-                return jax.jit(f1)
+                return jax.jit(hvd_adasum_local)
 
             in_sh = NamedSharding(self.mesh, P("proc"))
             rep = NamedSharding(self.mesh, P())
@@ -428,7 +433,7 @@ class XlaContext:
             # same value, but the tracer cannot prove ppermute outputs
             # replicated.
             return jax.jit(
-                shard_map_fn(f, self.mesh, in_specs=P("proc"),
+                shard_map_fn(hvd_adasum, self.mesh, in_specs=P("proc"),
                              out_specs=P(), check_vma=False),
                 in_shardings=(in_sh,), out_shardings=rep)
 
@@ -444,7 +449,10 @@ class XlaContext:
         def build():
             in_sh = NamedSharding(self.mesh, P("proc"))
             rep = NamedSharding(self.mesh, P())
-            return jax.jit(lambda x: x, in_shardings=(in_sh,),
+            def hvd_allgather(x):
+                return x
+
+            return jax.jit(hvd_allgather, in_shardings=(in_sh,),
                            out_shardings=rep)
 
         return self._get(key, build)
@@ -460,7 +468,10 @@ class XlaContext:
         def build():
             in_sh = NamedSharding(self.mesh, P("proc"))
             rep = NamedSharding(self.mesh, P())
-            return jax.jit(lambda x: x[root], in_shardings=(in_sh,),
+            def hvd_broadcast(x):
+                return x[root]
+
+            return jax.jit(hvd_broadcast, in_shardings=(in_sh,),
                            out_shardings=rep)
 
         return self._get(key, build)
@@ -492,8 +503,11 @@ class XlaContext:
 
         def build():
             sh = NamedSharding(self.mesh, P("proc"))
-            return jax.jit(lambda x: jnp.swapaxes(x, 0, 1),
-                           in_shardings=(sh,), out_shardings=sh)
+            def hvd_alltoall(x):
+                return jnp.swapaxes(x, 0, 1)
+
+            return jax.jit(hvd_alltoall, in_shardings=(sh,),
+                           out_shardings=sh)
 
         return self._get(key, build)
 
@@ -576,37 +590,29 @@ class XlaAllreduce(XlaOp):
 
     def execute(self, response: Response,
                 entries: List[TensorTableEntry]) -> Status:
-        import time
-
-        from ..core.timeline import phase_stats
-
         ctx = self.ctx
         np_dtype = response.tensor_type.to_numpy()
         if self.topo.size == 1:
-            t0 = time.monotonic()
-            outs = ctx.local_allreduce(entries, np_dtype,
-                                       response.prescale_factor,
-                                       response.postscale_factor)
-            phase_stats.add("collective", time.monotonic() - t0)
+            with phase("collective"):
+                outs = ctx.local_allreduce(entries, np_dtype,
+                                           response.prescale_factor,
+                                           response.postscale_factor)
         else:
             total = sum(int(np.prod(e.tensor.shape)) if e.tensor.shape else 1
                         for e in entries)
             bucket = bucket_elems(total)
             shapes = tuple(tuple(e.tensor.shape) for e in entries)
-            t0 = time.monotonic()
-            fused = ctx.fuse(entries, bucket, np_dtype)
-            gin = ctx.global_input(fused)
-            t1 = time.monotonic()
-            phase_stats.add("fuse", t1 - t0)
-            fn = ctx.allreduce_unfuse_fn(shapes, bucket, np_dtype,
-                                         response.prescale_factor,
-                                         response.postscale_factor)
-            outs = fn(gin)
-            phase_stats.add("collective", time.monotonic() - t1)
-        t2 = time.monotonic()
-        for e, o in zip(entries, outs):
-            e.output = _localize(o)
-        phase_stats.add("unfuse", time.monotonic() - t2)
+            with phase("fuse"):
+                fused = ctx.fuse(entries, bucket, np_dtype)
+                gin = ctx.global_input(fused)
+            with phase("collective"):
+                fn = ctx.allreduce_unfuse_fn(shapes, bucket, np_dtype,
+                                             response.prescale_factor,
+                                             response.postscale_factor)
+                outs = program_call(fn, gin)
+        with phase("unfuse"):
+            for e, o in zip(entries, outs):
+                e.output = _localize(o)
         _count("allreduce")
         return Status.dispatched()
 
@@ -651,16 +657,17 @@ class XlaAllgather(XlaOp):
         def build_pack():
             import jax.numpy as jnp
 
-            def f(*ts):
+            def hvd_allgather_pack(*ts):
                 buf = []
                 for t, s in zip(ts, seg):
                     flat = t.ravel()
                     buf.append(jnp.pad(flat, (0, s - flat.shape[0])))
                 return jnp.concatenate(buf) if len(buf) > 1 else buf[0]
 
-            return jax.jit(f)
+            return jax.jit(hvd_allgather_pack)
 
-        local = ctx._get(pack_key, build_pack)(*[e.tensor for e in entries])
+        local = program_call(ctx._get(pack_key, build_pack),
+                             *[e.tensor for e in entries])
         if local.devices() != {ctx.device}:
             local = jax.device_put(local, ctx.device)
 
@@ -673,7 +680,8 @@ class XlaAllgather(XlaOp):
             in_sh = NamedSharding(ctx.mesh, P("proc"))
             rep = NamedSharding(ctx.mesh, P())
 
-            def f(x):  # [P, row] sharded → per-entry concatenated outputs
+            def hvd_allgather_unpack(x):  # [P, row] sharded → per-entry
+                # concatenated outputs
                 outs = []
                 for i, inner in enumerate(inners):
                     parts = [
@@ -685,9 +693,12 @@ class XlaAllgather(XlaOp):
                                 if size > 1 else parts[0])
                 return tuple(outs)
 
-            return jax.jit(f, in_shardings=(in_sh,), out_shardings=rep)
+            return jax.jit(hvd_allgather_unpack, in_shardings=(in_sh,),
+                           out_shardings=rep)
 
-        outs = ctx._get(unpack_key, build_unpack)(ctx.global_input(local))
+        gin = ctx.global_input(local)
+        with phase("collective"):
+            outs = program_call(ctx._get(unpack_key, build_unpack), gin)
         for e, o in zip(entries, outs):
             e.output = _localize(o)
         _count("allgather")
@@ -790,18 +801,21 @@ class XlaAlltoall(XlaOp):
 
             bounds = np.cumsum([0] + list(send_splits))
 
-            def f(x):
+            def hvd_alltoall_pack(x):
                 rows = []
                 for j in range(size):
                     blk = x[bounds[j]:bounds[j + 1]].reshape(-1)
                     rows.append(jnp.pad(blk, (0, bucket - blk.shape[0])))
                 return jnp.stack(rows)
 
-            return jax.jit(f)
+            return jax.jit(hvd_alltoall_pack)
 
         local = jax.device_put(
-            ctx._get(pack_key, build_pack)(entry.tensor), ctx.device)
-        out = ctx.alltoall_fn(bucket, np_dtype)(ctx.rows_input(local))
+            program_call(ctx._get(pack_key, build_pack), entry.tensor),
+            ctx.device)
+        rows = ctx.rows_input(local)
+        with phase("collective"):
+            out = program_call(ctx.alltoall_fn(bucket, np_dtype), rows)
         mine = ctx.local_view(out).reshape(size, bucket)
 
         unpack_key = ("a2a.unpack", tuple(recv_splits), inner,
@@ -810,15 +824,15 @@ class XlaAlltoall(XlaOp):
         def build_unpack():
             import jax.numpy as jnp
 
-            def f(x):
+            def hvd_alltoall_unpack(x):
                 parts = [x[i, :recv_splits[i] * inner_n].reshape(
                     (recv_splits[i],) + inner) for i in range(size)]
                 return jnp.concatenate(parts, axis=0)
 
-            return jax.jit(f)
+            return jax.jit(hvd_alltoall_unpack)
 
         entry.output = _localize(
-            ctx._get(unpack_key, build_unpack)(mine))
+            program_call(ctx._get(unpack_key, build_unpack), mine))
         _count("alltoall")
         return Status.dispatched()
 
@@ -855,7 +869,7 @@ class XlaAlltoall(XlaOp):
             out_offs[1:, :] = np.cumsum(elems[:-1, :], axis=0)
             recv_sz = elems.T.astype(np.int32)
 
-            def f(x):  # [1, in_cap] local block
+            def hvd_alltoall_ragged(x):  # [1, in_cap] local block
                 i = jax.lax.axis_index("proc")
                 out = jnp.zeros((out_cap,), x.dtype)
                 res = jax.lax.ragged_all_to_all(
@@ -866,23 +880,26 @@ class XlaAlltoall(XlaOp):
                 return res.reshape(1, out_cap)
 
             return jax.jit(shard_map(
-                f, mesh=ctx.mesh, in_specs=P("proc"), out_specs=P("proc")))
+                hvd_alltoall_ragged, mesh=ctx.mesh, in_specs=P("proc"),
+                out_specs=P("proc")))
 
         send_splits = [int(v) for v in m[rank]]
         pack_key = ("a2a.ragged.pack", tuple(send_splits), inner,
                     str(np_dtype), in_cap)
 
         def build_pack():
-            def f(x):
+            def hvd_alltoall_ragged_pack(x):
                 flat = x.reshape(-1)
                 return jnp.pad(flat, (0, in_cap - flat.shape[0]))
 
-            return jax.jit(f)
+            return jax.jit(hvd_alltoall_ragged_pack)
 
-        local = ctx._get(pack_key, build_pack)(entry.tensor)
+        local = program_call(ctx._get(pack_key, build_pack), entry.tensor)
         if local.devices() != {ctx.device}:
             local = jax.device_put(local, ctx.device)
-        out = ctx._get(key, build)(ctx.rows_input(local))
+        rows = ctx.rows_input(local)
+        with phase("collective"):
+            out = program_call(ctx._get(key, build), rows)
         mine = ctx.local_view(out).reshape(-1)
 
         total_recv = int(m[:, rank].sum())
@@ -890,12 +907,12 @@ class XlaAlltoall(XlaOp):
                       str(np_dtype), out_cap)
 
         def build_unpack():
-            def f(x):
+            def hvd_alltoall_ragged_unpack(x):
                 return x[:total_recv * inner_n].reshape((total_recv,) + inner)
 
-            return jax.jit(f)
+            return jax.jit(hvd_alltoall_ragged_unpack)
 
-        return ctx._get(unpack_key, build_unpack)(mine)
+        return program_call(ctx._get(unpack_key, build_unpack), mine)
 
 
 class XlaAdasum(XlaOp):
@@ -932,7 +949,9 @@ class XlaAdasum(XlaOp):
         fn = ctx.adasum_fn(shapes, bucket, np_dtype,
                            response.prescale_factor,
                            response.postscale_factor)
-        outs = fn(ctx.global_input(fused))
+        gin = ctx.global_input(fused)
+        with phase("collective"):
+            outs = program_call(fn, gin)
         for e, o in zip(entries, outs):
             e.output = _localize(o)
         _count("adasum")
@@ -957,7 +976,9 @@ class XlaBroadcast(XlaOp):
         bucket = bucket_elems(total)
         fused = ctx.fuse([entry], bucket, np_dtype)
         fn = ctx.broadcast_fn(bucket, np_dtype, entry.root_rank)
-        out = fn(ctx.global_input(fused))
+        gin = ctx.global_input(fused)
+        with phase("collective"):
+            out = program_call(fn, gin)
         ctx.unfuse(ctx.local_view(out), [entry])
         _count("broadcast")
         return Status.dispatched()

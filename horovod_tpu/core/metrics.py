@@ -286,13 +286,23 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "fusion_reorders_total": (
         "counter", "negotiation cycles where readiness ordering changed "
                    "the fusion packing order (coordinator only)"),
-    # -- raw stat names (the literals fed to phase_stats/wire_stats.add;
-    #    HVD007 checks those call sites against this catalog too) --
+    # -- raw stat names (the literals fed to phase_stats/wire_stats.add
+    #    and to timeline.phase(); HVD007 checks those call sites against
+    #    this catalog too, and the phases are timeline.PHASES) --
     "negotiate": ("stat", "phase_stats: controller round, busy cycles"),
     "fuse": ("stat", "phase_stats: staging the fused buffer"),
     "collective": ("stat", "phase_stats: host cost of the collective"),
     "unfuse": ("stat", "phase_stats: slicing results to outputs"),
     "wait": ("stat", "phase_stats: framework-thread handle waits"),
+    "update": ("stat", "phase_stats: a whole DistributedOptimizer.update"),
+    "enqueue": ("stat", "phase_stats: submitting a tree's tensors"),
+    "tree_unflatten": ("stat", "phase_stats: fused buffers to leaves"),
+    "optimizer_update": ("stat", "phase_stats: the inner optax programs"),
+    "queue_wait": ("stat", "phase_stats: tensor queue to background loop"),
+    "dispatch_wait": ("stat", "phase_stats: loop to dispatcher thread"),
+    "program_call": ("stat", "phase_stats: calls of the framework's "
+                             "jitted programs; count = output arrays"),
+    "wfbp_dispatch": ("stat", "phase_stats: one OverlappedTrainStep call"),
     "bytes_on_wire": ("stat", "wire_stats: per-frame payload bytes"),
     "heap_copies": ("stat", "wire_stats: data-plane materializations"),
     "compressed_bytes": ("stat", "wire_stats: narrow wire-dtype bytes"),

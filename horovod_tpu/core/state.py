@@ -562,6 +562,7 @@ class HorovodGlobalState:
     # ------------------------------------------------------------------
 
     def _background_loop(self) -> None:
+        timeline_mod.name_os_thread()
         try:
             self._build_transport()
         except BaseException as e:  # noqa: BLE001
@@ -667,27 +668,30 @@ class HorovodGlobalState:
         else (host-TCP collectives, which share the mesh with negotiation;
         JOIN/ERROR/BARRIER bookkeeping) executes inline behind a drain
         barrier so the cross-rank execution order stays identical."""
-        from .timeline import phase_stats
-
         requests = self.tensor_queue.pop_messages()
-        t0 = time.monotonic()
-        if self.timeline is not None:
-            # Tag this round's spans with the lockstep cycle id BEFORE
-            # negotiating — the same id names the same global round on
-            # every rank (trace_merge matches lanes on it).
-            self.timeline.set_cycle(self.cycle_count + 1)
-        response_list = self.controller.compute_response_list(
-            requests, self.shutdown_requested.is_set())
-        self.cycle_count += 1
-        self._last_cycle_had_work = bool(requests) \
-            or bool(response_list.responses)
-        metrics.set_gauge("tensor_queue_depth", self.tensor_queue.size())
-        if self._last_cycle_had_work:
+        # The profiler's span shows every round, with the number of
+        # requests it took, and the step of the first of them.
+        with timeline_mod.phase(
+                "negotiate", cycle=self.cycle_count + 1,
+                requests=len(requests),
+                step=getattr(requests[0], "_step", None)
+                if requests else None) as span:
+            if self.timeline is not None:
+                # Tag this round's spans with the lockstep cycle id BEFORE
+                # negotiating — the same id names the same global round on
+                # every rank (trace_merge matches lanes on it).
+                self.timeline.set_cycle(self.cycle_count + 1)
+            response_list = self.controller.compute_response_list(
+                requests, self.shutdown_requested.is_set())
+            self.cycle_count += 1
+            self._last_cycle_had_work = bool(requests) \
+                or bool(response_list.responses)
+            metrics.set_gauge("tensor_queue_depth", self.tensor_queue.size())
             # Busy cycles only: timing idle lockstep parks would swamp the
-            # negotiate lane with waiting, not negotiating.
-            dt = time.monotonic() - t0
-            phase_stats.add("negotiate", dt)
-            metrics.observe("controller_cycle_seconds", dt)
+            # negotiate total with waiting, not negotiating.
+            span.record = self._last_cycle_had_work
+        if self._last_cycle_had_work:
+            metrics.observe("controller_cycle_seconds", span.seconds)
             flight_recorder.record("cycle", n=self.cycle_count,
                                    requests=len(requests),
                                    responses=len(response_list.responses))
@@ -750,13 +754,17 @@ class HorovodGlobalState:
             self._dispatch_thread.start()
         with self._dispatch_cv:
             self._dispatch_inflight += 1
+        response._dispatched_at = time.monotonic()
         self._dispatch_queue.put(response)
 
     def _dispatch_loop(self) -> None:
+        timeline_mod.name_os_thread()
         while True:
             response = self._dispatch_queue.get()
             if response is None:
                 return
+            timeline_mod.phase_stats.add(
+                "dispatch_wait", time.monotonic() - response._dispatched_at)
             try:
                 self._perform_operation(response, require_device=True)
             except BaseException as e:  # noqa: BLE001 — the negotiation
@@ -876,7 +884,12 @@ class HorovodGlobalState:
             self.timeline.op_start(response, entries)
         t_op = time.monotonic()
         try:
-            status = self.op_manager.execute(response, entries)
+            # Every phase of this dispatch carries the step of the
+            # optimizer update that submitted the tensors, and the cycle.
+            with timeline_mod.span_ids(
+                    step=entries[0].step if entries else None,
+                    cycle=getattr(response, "_cycle", None)):
+                status = self.op_manager.execute(response, entries)
         except (PeerGoneError, CoordinatedAbortError) as e:
             # A dead mesh is FATAL, not an entry-level error: if this rank
             # kept cycling, its next negotiation frames would be consumed

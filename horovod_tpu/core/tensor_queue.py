@@ -17,6 +17,7 @@ shape/dtype metadata, staying framework-agnostic.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -80,6 +81,9 @@ class TensorTableEntry:
     callback: Callable = field(default=lambda status, entry: None)
     # context fields used by the data plane to hand results back
     context: dict = field(default_factory=dict)
+    # ordinal of the optimizer update that submitted this tensor, if one
+    # did: the ``step`` of its spans on the runtime's threads
+    step: Optional[int] = None
 
 
 class TensorQueue:
@@ -109,6 +113,8 @@ class TensorQueue:
         if faults.ACTIVE:
             faults.inject("enqueue.collective")
         timeline_mod.lifecycle_begin(entry.tensor_name, "LC_SUBMITTED")
+        entry.step = request._step = timeline_mod.current_ids().get("step")
+        request._enqueued_at = time.monotonic()
         with self._lock:
             if self._closed:
                 # The background loop has exited and drained the table; an
@@ -135,7 +141,15 @@ class TensorQueue:
         ``PopMessagesFromQueue`` (``tensor_queue.h:44``)."""
         with self._lock:
             out, self._pending = self._pending, []
-            return out
+        # queue_wait: from ``add`` to this hand-over, per tensor (a JOIN
+        # request or a re-queued one carries no stamp).
+        now = time.monotonic()
+        waits = [now - r.__dict__.pop("_enqueued_at") for r in out
+                 if "_enqueued_at" in r.__dict__]
+        if waits:
+            timeline_mod.phase_stats.add("queue_wait", sum(waits),
+                                         n=len(waits))
+        return out
 
     def push_messages(self, requests: List[Request]) -> None:
         """Re-queue requests (cache-invalidation / retry path)."""

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import json
 import queue
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -344,33 +345,55 @@ class Timeline:
 # ---------------------------------------------------------------------------
 
 
-class PhaseStats:
-    """Always-on wall-time accumulator over the eager dispatch chain's
-    phases: ``negotiate`` (controller round, busy cycles only), ``fuse``
-    (staging the fused buffer onto the mesh), ``collective`` (host cost of
-    dispatching the device collective), ``unfuse`` (slicing results back to
-    per-entry outputs), ``wait`` (framework-thread handle synchronization).
+#: Every phase of the eager runtime, old names first.  ``core/metrics.py``'s
+#: ``CATALOG``, ``docs/observability.md`` and lint rule HVD007 are held to it.
+PHASES = (
+    "negotiate", "fuse", "collective", "unfuse", "wait",
+    "update", "enqueue", "tree_unflatten", "optimizer_update",
+    "queue_wait", "dispatch_wait", "program_call", "wfbp_dispatch",
+)
 
-    This is the aggregate companion to the Chrome-trace timeline: the trace
-    answers "what happened when", this answers "where does a dispatch's
-    millisecond budget go" cheaply enough to leave enabled (a few monotonic
-    reads + one dict update per phase per response).  Surfaced by
-    ``benchmarks/eager_bench.py --profile`` / ``eager_np_bench.py
-    --profile``, snapshot-able from tests, and registered as a view in the
-    metrics registry (``phase_seconds_total``/``phase_ops_total``)."""
+
+class PhaseStats:
+    """Always-on wall-time accumulator over the eager runtime's phases
+    (``PHASES``).  On the dispatch chain: ``negotiate`` (controller round,
+    busy cycles only), ``fuse`` (staging the fused buffer), ``collective``
+    (host cost of dispatching the device collective), ``unfuse`` (results
+    back to per-entry outputs), ``wait`` (framework-thread handle
+    synchronization), and the two hand-offs between threads,
+    ``queue_wait`` (tensor queue → background loop) and ``dispatch_wait``
+    (background loop → dispatcher thread).  On the calling thread:
+    ``update`` (the whole of ``DistributedOptimizer.update``) with its
+    parts ``enqueue``, ``tree_unflatten`` and ``optimizer_update``;
+    ``wfbp_dispatch`` (one call of an ``OverlappedTrainStep``); and
+    ``program_call``, nested in the others, around every call of a jitted
+    program the framework owns.
+
+    This is the aggregate companion to the traces: a trace answers "what
+    happened when", this answers "where does a step's millisecond budget
+    go" cheaply enough to leave enabled (two monotonic reads + one dict
+    update per phase).  :func:`phase` feeds it and puts the same extent
+    into the jax profiler's trace.  Surfaced by the chip benchmark's
+    per-layer metrics, ``benchmarks/eager_bench.py --profile``,
+    snapshot-able from tests, and registered as a view in the metrics
+    registry (``phase_seconds_total``/``phase_ops_total``)."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._acc: Dict[str, List[float]] = {}
 
-    def add(self, phase: str, seconds: float) -> None:
+    def add(self, phase: str, seconds: float, n: int = 1) -> None:
+        """``n`` is what ``count`` grows by: 1 event for most phases, the
+        number of tensors that waited for ``queue_wait``, and for
+        ``program_call`` the number of output arrays the program returned
+        (so ``mean_ms`` there is host ms per output buffer)."""
         with self._lock:
             slot = self._acc.get(phase)
             if slot is None:
-                self._acc[phase] = [seconds, 1]
+                self._acc[phase] = [seconds, n]
             else:
                 slot[0] += seconds
-                slot[1] += 1
+                slot[1] += n
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
@@ -378,7 +401,7 @@ class PhaseStats:
                 phase: {
                     "total_ms": round(total * 1e3, 3),
                     "count": int(count),
-                    "mean_ms": round(total / count * 1e3, 4),
+                    "mean_ms": round(total / max(count, 1) * 1e3, 4),
                 }
                 for phase, (total, count) in self._acc.items()
             }
@@ -391,6 +414,120 @@ class PhaseStats:
 #: Process-global instance — the background loop, the XLA backend, and the
 #: framework-side handle waits all record into this.
 phase_stats = PhaseStats()
+
+# ``jax.profiler.TraceAnnotation``, looked up once the process has imported
+# jax (before that nobody can have started its profiler, and ``core/`` also
+# serves bindings that never do): until then, and where jax cannot be
+# imported (False), a phase is its accumulator alone.
+_annotation = None
+_local = threading.local()
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = False
+    return _annotation
+
+
+def current_ids() -> dict:
+    """The identifiers of the span this thread is inside (``step``, and
+    ``cycle`` on the runtime's threads), for what carries them to another
+    thread."""
+    return getattr(_local, "ids", None) or {}
+
+
+class span_ids:
+    """``with span_ids(step=..., cycle=...):`` — every :func:`phase` opened
+    inside, on this thread, carries these identifiers besides those of the
+    scope around it.  None values are dropped."""
+
+    __slots__ = ("ids", "_outer")
+
+    def __init__(self, **ids):
+        self.ids = ids
+
+    def __enter__(self):
+        outer = self._outer = current_ids()
+        _local.ids = {**outer, **{k: v for k, v in self.ids.items()
+                                  if v is not None}} if self.ids else outer
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _local.ids = self._outer
+
+
+class phase(span_ids):
+    """``with phase(name, **ids):`` — one extent, two records: a
+    ``hvd.<name>`` span in the jax profiler's trace, on the clock of the
+    device's op line, and the elapsed ``time.monotonic()`` added to
+    :data:`phase_stats` under ``name``, also when the body raises.  With
+    no profiler running the span is a no-op of under a microsecond, so
+    nothing switches this off.
+
+    ``ids`` become the span's arguments and, as in :class:`span_ids`, those
+    of every span inside it on its thread: one ``step=`` at the top names
+    every span of that step.  Inside the block, ``record = False`` keeps
+    the extent out of the accumulator (the span stays) and ``n`` sets what
+    ``count`` grows by; ``seconds`` holds the elapsed time afterwards."""
+
+    __slots__ = ("name", "record", "n", "seconds", "_span", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.ids = ids
+        self.record = True
+        self.n = 1
+        self.seconds = 0.0
+
+    def __enter__(self) -> "phase":
+        super().__enter__()
+        annotation = _trace_annotation()
+        self._span = annotation("hvd." + self.name, **_local.ids) \
+            if annotation else None
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.monotonic() - self._t0
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        super().__exit__()
+        if self.record:
+            phase_stats.add(self.name, self.seconds, self.n)  # hvdlint: disable=HVD007 -- the one forwarding site: HVD007 checks the literal at every phase(...) call
+
+
+def program_call(fn, *args):
+    """Call a jitted program the framework owns inside a ``program_call``
+    phase named for it.  ``count`` grows by the number of arrays the
+    program returned, counted outside the timed call: the host's cost of a
+    program is mostly its output buffers (``PERF.md`` section 5)."""
+    from jax.tree_util import tree_leaves
+
+    with phase("program_call", program=getattr(fn, "__name__", None)) as span:
+        span.record = False
+        out = fn(*args)
+    phase_stats.add("program_call", span.seconds, n=len(tree_leaves(out)))
+    return out
+
+
+def name_os_thread() -> None:
+    """Give the calling thread's OS thread its Python name (cut to the
+    kernel's 15 characters): the jax profiler labels a host line with the
+    OS name, which for a ``threading.Thread`` is the interpreter's."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(
+            15, threading.current_thread().name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass  # not Linux: the line keeps the interpreter's name
 
 
 class CounterStats:

@@ -24,6 +24,7 @@ from ...core.handle_manager import HandleManager
 from ...core.messages import RequestType
 from ...core.state import global_state
 from ...core.tensor_queue import Status
+from ...core.timeline import phase
 
 # Reduce-op constants (reference ``horovod/torch/mpi_ops.py`` Sum/Average/Adasum)
 Sum = "sum"
@@ -226,15 +227,8 @@ def poll(handle: int) -> bool:
 
 def synchronize(handle: int, timeout: Optional[float] = None):
     """Wait for an async op and return its result."""
-    import time
-
-    from ...core.timeline import phase_stats
-
-    t0 = time.monotonic()
-    try:
+    with phase("wait"):
         return _handles.wait(handle, timeout=timeout)
-    finally:
-        phase_stats.add("wait", time.monotonic() - t0)
 
 
 def synchronize_many(handles, timeout: Optional[float] = None) -> list:
@@ -242,12 +236,5 @@ def synchronize_many(handles, timeout: Optional[float] = None) -> list:
 
     One wait per fused bucket instead of one per tensor — the batch flavor
     the DistributedOptimizer/WFBP step paths use."""
-    import time
-
-    from ...core.timeline import phase_stats
-
-    t0 = time.monotonic()
-    try:
+    with phase("wait"):
         return _handles.wait_many(handles, timeout=timeout)
-    finally:
-        phase_stats.add("wait", time.monotonic() - t0)

@@ -28,6 +28,7 @@ import numpy as np
 from . import ops, wfbp
 from ...common.exceptions import HorovodInternalError
 from ...common.logging_util import get_logger
+from ...core.timeline import phase, program_call
 from .compression import Compression
 
 log = get_logger(__name__)
@@ -40,6 +41,9 @@ log = get_logger(__name__)
 # which polls non-blockingly, releases completed ones, and force-discards
 # the rest after a deadline.
 _instance_ids = itertools.count()
+# Ordinal of each ``update()`` call in this process: the ``step`` that every
+# span of that update carries, on the runtime's threads too.
+_update_ordinals = itertools.count()
 
 _DRAIN_TIMEOUT_S = 120.0
 _drain_lock = threading.Lock()
@@ -115,6 +119,19 @@ class DistributedState(NamedTuple):
     window: int = -1
 
 
+def _named_jit(name: str, fn):
+    """``jax.jit(fn)`` as a program called ``name``: a trace's ``XLA
+    Modules`` line then shows ``jit_<name>`` for it and not the
+    ``jit__lambda_`` or ``jit_update_fn`` of whatever was passed in."""
+    import jax
+
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
+
+
 def _leaf_names(tree) -> list:
     """Stable names from tree paths — all ranks traverse identically, the
     same contract the reference uses for unnamed tensors."""
@@ -138,13 +155,14 @@ def _allreduce_tree_per_leaf(grads, op, compression, prescale_factor,
     # Enqueue everything first (async) so the runtime can fuse; then one
     # batched wait over the lot — the WFBP analog: comm of leaf i overlaps
     # enqueue/compress of i+1, and the step blocks once, not per tensor.
-    for leaf, name in zip(leaves, names):
-        comp, ctx = compression.compress(leaf)
-        ctxs.append(ctx)
-        handles.append(ops.allreduce_async(
-            comp, name=f"{name_prefix}.{name}", op=op,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor))
+    with phase("enqueue"):
+        for leaf, name in zip(leaves, names):
+            comp, ctx = compression.compress(leaf)
+            ctxs.append(ctx)
+            handles.append(ops.allreduce_async(
+                comp, name=f"{name_prefix}.{name}", op=op,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor))
     out = [compression.decompress(r, ctx)
            for r, ctx in zip(ops.synchronize_many(handles), ctxs)]
     return jax.tree_util.tree_unflatten(treedef, out)
@@ -255,14 +273,12 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
     # allreduce in the middle is host-driven.
     _jits: dict = {}
 
-    def _jitted(key: str, fn):
-        import jax
-
+    def _run(key: str, fn, *args):
         cached = _jits.get(key)
         if cached is None:
-            cached = jax.jit(fn)
-            _jits[key] = cached
-        return cached
+            cached = _jits[key] = _named_jit(f"hvd_optimizer_{key}", fn)
+        with phase("optimizer_update"):
+            return program_call(cached, *args)
 
     def init(params):
         import jax
@@ -290,6 +306,10 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
     _window_seq = [0]
 
     def update(grads, state: DistributedState, params=None):
+        with phase("update", step=next(_update_ordinals)):
+            return _update(grads, state, params)
+
+    def _update(grads, state: DistributedState, params):
         import jax
         import jax.numpy as jnp
 
@@ -338,22 +358,22 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
                     postscale_factor,
                     name_prefix=f"{_name_root()}.mb{count - 1}"))
                 if count < n_accum:
-                    zeros = _jitted(
+                    zeros = _run(
                         "zeros",
-                        lambda g: jax.tree_util.tree_map(jnp.zeros_like, g)
-                    )(grads)
+                        lambda g: jax.tree_util.tree_map(jnp.zeros_like, g),
+                        grads)
                     return zeros, DistributedState(
                         state.inner_state, state.accumulated, count, window)
                 trees = [wfbp.wait_tree(p) for p in pending]
                 del _windows[window]
                 scale = 1.0 / n_accum if average_aggregated_gradients \
                     else 1.0
-                grads = _jitted(
+                grads = _run(
                     "combine",
                     lambda *ts: jax.tree_util.tree_map(
-                        lambda *xs: sum(xs) * scale, *ts))(*trees)
-                updates, inner = _jitted("update", tx.update)(
-                    grads, state.inner_state, params)
+                        lambda *xs: sum(xs) * scale, *ts), *trees)
+                updates, inner = _run("update", tx.update, grads,
+                                      state.inner_state, params)
                 return updates, DistributedState(inner, state.accumulated,
                                                  0, -1)
             if count > 1 and state.window != -1:
@@ -367,19 +387,19 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
         if n_accum > 1:
             count = state.counter + 1
             if count < n_accum:
-                acc, zeros = _jitted(
+                acc, zeros = _run(
                     "accum",
                     lambda a, g: (jax.tree_util.tree_map(jnp.add, a, g),
-                                  jax.tree_util.tree_map(jnp.zeros_like, g))
-                )(state.accumulated, grads)
+                                  jax.tree_util.tree_map(jnp.zeros_like, g)),
+                    state.accumulated, grads)
                 return zeros, DistributedState(state.inner_state, acc, count)
             scale = 1.0 / n_accum if average_aggregated_gradients else 1.0
-            grads, new_acc = _jitted(
+            grads, new_acc = _run(
                 "flush",
                 lambda a, g: (
                     jax.tree_util.tree_map(lambda x, y: (x + y) * scale, a, g),
-                    jax.tree_util.tree_map(jnp.zeros_like, a))
-            )(state.accumulated, grads)
+                    jax.tree_util.tree_map(jnp.zeros_like, a)),
+                state.accumulated, grads)
             count = 0
         else:
             new_acc, count = None, 0
@@ -391,8 +411,8 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
             grads = _allreduce_tree(grads, op_name, compression,
                                     prescale_factor, postscale_factor,
                                     name_prefix=_name_root())
-        updates, inner = _jitted("update", tx.update)(
-            grads, state.inner_state, params)
+        updates, inner = _run("update", tx.update, grads, state.inner_state,
+                              params)
         return updates, DistributedState(inner, new_acc, count)
 
     return optax.GradientTransformation(init, update)
@@ -428,22 +448,20 @@ def DistributedAdasumOptimizer(tx, compression=Compression.none,
 
     _jits: dict = {}
 
-    def _jitted(fn):
-        import jax
-
-        if "u" not in _jits:
-            _jits["u"] = jax.jit(fn)
-        return _jits["u"]
-
     def init(params):
         return tx.init(params)
 
     def update(grads, state, params=None):
-        updates, inner = _jitted(tx.update)(grads, state, params)
-        if ops.initialized():
-            updates = _allreduce_tree_per_leaf(
-                updates, ops.Adasum, compression, 1.0, 1.0,
-                name_prefix=f"{_name_root()}.delta")
+        if "u" not in _jits:
+            _jits["u"] = _named_jit("hvd_optimizer_update", tx.update)
+        with phase("update", step=next(_update_ordinals)):
+            with phase("optimizer_update"):
+                updates, inner = program_call(_jits["u"], grads, state,
+                                              params)
+            if ops.initialized():
+                updates = _allreduce_tree_per_leaf(
+                    updates, ops.Adasum, compression, 1.0, 1.0,
+                    name_prefix=f"{_name_root()}.delta")
         return updates, inner
 
     return optax.GradientTransformation(init, update)

@@ -42,6 +42,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import numpy as np
 
 from . import ops
+from ...core.timeline import phase, program_call
 from .compression import Compression
 
 # ---------------------------------------------------------------------------
@@ -71,13 +72,13 @@ def _fuse_plan(sig):
         groups.setdefault(dt, []).append(i)
     groups = list(groups.items())
 
-    def flatten(leaves_in):
+    def hvd_tree_flatten(leaves_in):
         return tuple(
             jnp.concatenate([leaves_in[i].ravel() for i in idxs])
             if len(idxs) > 1 else leaves_in[idxs[0]].ravel()
             for _, idxs in groups)
 
-    def unflatten(bufs, leaves_in):
+    def hvd_tree_unflatten(bufs, leaves_in):
         outs = list(leaves_in)  # placeholders, right treedef slots
         for buf, (_, idxs) in zip(bufs, groups):
             off = 0
@@ -88,7 +89,8 @@ def _fuse_plan(sig):
                 off += n
         return tuple(outs)
 
-    cached = (groups, jax.jit(flatten), jax.jit(unflatten))
+    cached = (groups, jax.jit(hvd_tree_flatten),
+              jax.jit(hvd_tree_unflatten))
     with _cache_lock:
         _tree_fuse_cache[sig] = cached
     return cached
@@ -112,28 +114,24 @@ def enqueue_tree_fused(grads, op, compression, prescale_factor,
     ``optimizer._allreduce_tree``).  Returns immediately; the background
     runtime negotiates/dispatches while the caller computes the next
     microbatch's backward.  Finish with :func:`wait_tree`."""
-    import time
-
     import jax
     import jax.numpy as jnp
-
-    from ...core.timeline import phase_stats
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     sig = tuple((tuple(l.shape), jnp.asarray(l).dtype.name) for l in leaves)
     groups, flatten, unflatten = _fuse_plan(sig)
 
-    t0 = time.monotonic()
-    bufs = flatten(leaves)
-    phase_stats.add("fuse", time.monotonic() - t0)
+    with phase("fuse"):
+        bufs = program_call(flatten, leaves)
     handles, ctxs = [], []
-    for buf, (dt, idxs) in zip(bufs, groups):
-        comp, cctx = compression.compress(buf)
-        ctxs.append(cctx)
-        handles.append(ops.allreduce_async(
-            comp, name=f"{name_prefix}.fused.{dt}.{buf.size}", op=op,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor))
+    with phase("enqueue"):
+        for buf, (dt, idxs) in zip(bufs, groups):
+            comp, cctx = compression.compress(buf)
+            ctxs.append(cctx)
+            handles.append(ops.allreduce_async(
+                comp, name=f"{name_prefix}.fused.{dt}.{buf.size}", op=op,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor))
     return PendingTree(tuple(handles), tuple(ctxs), groups, unflatten,
                        leaves, treedef, compression)
 
@@ -147,9 +145,10 @@ def wait_tree(pending: PendingTree):
     import jax
 
     results = ops.synchronize_many(pending.handles)
-    reduced = tuple(pending.compression.decompress(r, c)
-                    for r, c in zip(results, pending.ctxs))
-    out = pending.unflatten(reduced, pending.leaves)
+    with phase("tree_unflatten"):
+        reduced = tuple(pending.compression.decompress(r, c)
+                        for r, c in zip(results, pending.ctxs))
+        out = program_call(pending.unflatten, reduced, pending.leaves)
     return jax.tree_util.tree_unflatten(pending.treedef, out)
 
 
@@ -344,6 +343,10 @@ class OverlappedTrainStep:
     def __call__(self, params, opt_state, batch, aux=None):
         """Returns ``(params, opt_state, loss)``, or
         ``(params, opt_state, aux, loss)`` with ``has_aux``."""
+        with phase("wfbp_dispatch"):
+            return self._dispatch(params, opt_state, batch, aux)
+
+    def _dispatch(self, params, opt_state, batch, aux):
         ctx = self._context()
         gbatch = self._lift_batch(ctx, batch)
         if self._step is None:

@@ -715,7 +715,8 @@ class MetricCatalogRule(Rule):
     vacuously, the exact silent failure HVD003 closes for fault sites.
     Every name fed to ``metrics.inc``/``set_gauge``/``observe`` (and to
     the ``phase_stats``/``wire_stats`` ``add`` accumulators the registry
-    absorbs as views) must be a literal found in ``core/metrics.py``'s
+    absorbs as views, or to ``timeline.phase(...)``, which feeds
+    ``phase_stats``) must be a literal found in ``core/metrics.py``'s
     ``CATALOG``, and every catalog entry must appear in
     ``docs/observability.md`` so operators can discover it."""
 
@@ -737,13 +738,15 @@ class MetricCatalogRule(Rule):
                 continue
             func = node.func
             fname = _terminal_name(func)
-            if not isinstance(func, ast.Attribute):
-                continue
-            recv = _terminal_name(func.value)
+            recv = _terminal_name(func.value) \
+                if isinstance(func, ast.Attribute) else None
             if fname in self._REG_FUNCS and recv in self._REG_RECEIVERS:
                 pass
             elif fname == "add" and recv in self._STATS_RECEIVERS:
                 pass
+            elif fname == "phase" and recv in (None, "timeline",
+                                               "timeline_mod"):
+                pass  # ``with phase("..."):`` feeds phase_stats
             else:
                 continue
             arg = node.args[0]

@@ -649,6 +649,22 @@ def test_hvd007_stats_add_checked_too():
     assert codes(vs) == ["HVD007"]
 
 
+@pytest.mark.parametrize("call", [
+    'phase("udpate")', 'timeline_mod.phase("udpate", step=3)', 'phase(name)'])
+def test_hvd007_phase_literal_checked_too(call):
+    vs = run(f"""
+        from horovod_tpu.core import timeline as timeline_mod
+        from horovod_tpu.core.timeline import phase
+        def f(name):
+            with {call}:
+                pass
+            with phase("update", step=1), timeline_mod.phase("wait"):
+                pass
+            unrelated.phase("whatever")  # not the timeline's
+    """)
+    assert codes(vs) == ["HVD007"]
+
+
 def test_hvd007_computed_name_rejected():
     vs = run("""
         from horovod_tpu.core import metrics

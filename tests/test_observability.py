@@ -133,8 +133,11 @@ class TestRegistry:
     def test_catalog_covers_every_stat_literal(self):
         # The names the codebase feeds to phase_stats/wire_stats.add —
         # HVD007's contract, restated where a registry edit breaks it.
-        for name in ("negotiate", "fuse", "collective", "unfuse", "wait",
-                     "bytes_on_wire", "heap_copies"):
+        from horovod_tpu.core.timeline import PHASES
+
+        assert PHASES[:5] == ("negotiate", "fuse", "collective", "unfuse",
+                              "wait")
+        for name in PHASES + ("bytes_on_wire", "heap_copies"):
             assert name in metrics.CATALOG
 
 

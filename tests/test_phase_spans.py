@@ -1,0 +1,390 @@
+"""The eager runtime's spans on the profiler's clock: ``timeline.phase`` and
+the sites it instruments (ISSUE 24).
+
+One np=1 worker drives ``DistributedOptimizer`` on a small MLP and reports
+what one step added to ``phase_stats``, what a ``jax.profiler`` trace of
+three steps holds, and ``backend.xla.stats``; the cases below each assert
+one fact of that report.  Counts only: nothing here is a timing claim.
+"""
+
+import json
+import sys
+import threading
+
+import pytest
+
+from .helpers import reserve_port, run_distributed
+
+# The tree: four float32 leaves, so one fused buffer, one request, one
+# response a step.  SGD with momentum: ``tx.update`` returns a delta and a
+# momentum per leaf.
+LEAVES = 4
+
+WORKER = """
+import glob, json, tempfile
+import jax, jax.numpy as jnp, optax
+from horovod_tpu.backend import xla
+from horovod_tpu.core.state import global_state
+from horovod_tpu.core.timeline import phase_stats
+
+def loss_fn(p, b):
+    h = jnp.tanh(b["x"] @ p["w1"] + p["b1"])
+    return jnp.mean((h @ p["w2"] + p["b2"] - b["y"]) ** 2)
+
+params = {"w1": jnp.ones((4, 8)) * 0.1, "b1": jnp.zeros((8,)),
+          "w2": jnp.ones((8, 2)) * 0.1, "b2": jnp.zeros((2,))}
+batch = {"x": jnp.ones((8, 4)), "y": jnp.ones((8, 2))}
+grads = jax.jit(jax.grad(loss_fn))(params, batch)
+inner = optax.sgd(0.1, momentum=0.9)
+
+def delta(fn):
+    before = phase_stats.snapshot()
+    out = fn()
+    jax.block_until_ready(out)
+    after = phase_stats.snapshot()
+    zero = {"count": 0, "total_ms": 0.0}
+    return out, {k: {"count": v["count"] - before.get(k, zero)["count"],
+                     "ms": v["total_ms"] - before.get(k, zero)["total_ms"]}
+                 for k, v in after.items()}
+
+report = {}
+dopt = hvd.DistributedOptimizer(inner)
+state = dopt.init(params)
+for _ in range(2):                          # compile everything
+    updates, state = dopt.update(grads, state, params)
+(updates, state), report["step"] = delta(
+    lambda: dopt.update(grads, state, params))
+report["update_outputs"] = len(jax.tree_util.tree_leaves(
+    (updates, state.inner_state)))
+report["xla_stats"] = dict(xla.stats)
+
+# Local aggregation: the off step accumulates and sends nothing.
+acc = hvd.DistributedOptimizer(inner, backward_passes_per_step=2)
+acc_state = acc.init(params)
+for _ in range(2):
+    _, acc_state = acc.update(grads, acc_state, params)
+(_, acc_state), report["off_step"] = delta(
+    lambda: acc.update(grads, acc_state, params))
+
+# The one-program path.
+wstep = hvd.make_overlapped_train_step(loss_fn, inner)
+wp, ws = wstep.init(params, inner.init(params))
+wp, ws, _ = wstep(wp, ws, batch)
+(wp, ws, _), report["wfbp"] = delta(lambda: wstep(wp, ws, batch))
+
+# Three steps under the profiler, the collective dispatched inline (as at
+# one rank) and then from the dispatcher thread (as at several).
+def traced(pipelined):
+    global state
+    global_state().pipeline_dispatch = pipelined
+    updates, state = dopt.update(grads, state, params)   # thread comes up
+    jax.block_until_ready(updates)
+    d = tempfile.mkdtemp()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=options)
+    for _ in range(3):
+        updates, state = dopt.update(grads, state, params)
+        jax.block_until_ready(updates)
+    jax.profiler.stop_trace()
+    path = glob.glob(d + "/plugins/profile/*/*.xplane.pb")[0]
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("hvd."):
+                    events.append([line.name, e.name, dict(e.stats)])
+    return events
+
+report["trace_inline"] = traced(False)
+_, report["pipelined"] = delta(lambda: report.__setitem__(
+    "trace_pipelined", traced(True)))
+global_state().pipeline_dispatch = False
+print("REPORT " + json.dumps(report), flush=True)
+"""
+
+
+@pytest.fixture(scope="module")
+def report():
+    out = run_distributed(1, WORKER, timeout=240)[0]
+    line = [x for x in out.splitlines() if x.startswith("REPORT ")][-1]
+    return json.loads(line[len("REPORT "):])
+
+
+# What one plain step at np=1 adds to each phase's count.  program_call
+# counts output arrays: flatten 1 + allreduce 1 + unflatten LEAVES +
+# tx.update 2 * LEAVES.
+STEP_COUNTS = {
+    "update": 1, "fuse": 1, "enqueue": 1, "queue_wait": 1, "negotiate": 1,
+    "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 1,
+    "optimizer_update": 1, "program_call": 2 + LEAVES + 2 * LEAVES,
+}
+
+
+@pytest.mark.parametrize("name", sorted(STEP_COUNTS))
+def test_one_step_adds_exactly(report, name):
+    assert report["step"][name]["count"] == STEP_COUNTS[name]
+
+
+def test_one_step_enters_no_other_phase(report):
+    from horovod_tpu.core.timeline import PHASES
+
+    entered = {k for k, v in report["step"].items() if v["count"]}
+    assert entered == set(STEP_COUNTS)
+    assert entered < set(PHASES)
+    # The rest belong to the dispatcher thread and the one-program path.
+    assert set(PHASES) - entered == {"dispatch_wait", "wfbp_dispatch"}
+
+
+def test_program_call_counts_outputs_worked_out_from_the_tree(report):
+    groups = 1                                  # one dtype
+    expect = groups + groups + LEAVES + report["update_outputs"]
+    assert report["update_outputs"] == 2 * LEAVES
+    assert report["step"]["program_call"]["count"] == expect
+
+
+def test_callers_parts_sum_to_no_more_than_update(report):
+    step = report["step"]
+    parts = sum(step[k]["ms"] for k in (
+        "fuse", "enqueue", "wait", "tree_unflatten", "optimizer_update"))
+    # total_ms is rounded to a microsecond per phase.
+    assert 0 < parts <= step["update"]["ms"] + 0.005
+
+
+def test_old_phases_record_where_the_parent_did(report):
+    # The parent commit (PR 23), same tree, same step: one each.
+    parent = {"fuse": 1, "unfuse": 1, "collective": 1, "wait": 1,
+              "negotiate": 1}
+    assert {k: report["step"][k]["count"] for k in parent} == parent
+
+
+def test_xla_stats_gains_no_key(report):
+    assert sorted(report["xla_stats"]) == ["allreduce"]
+
+
+def test_off_step_of_local_aggregation_is_one_optimizer_program(report):
+    entered = {k: v["count"] for k, v in report["off_step"].items()
+               if v["count"]}
+    # accumulate returns the accumulator and the zero updates.
+    assert entered == {"update": 1, "optimizer_update": 1,
+                       "program_call": 2 * LEAVES}
+
+
+def test_overlapped_step_is_one_wfbp_dispatch(report):
+    entered = {k: v["count"] for k, v in report["wfbp"].items()
+               if v["count"]}
+    assert entered == {"wfbp_dispatch": 1}
+
+
+def _by_name(events, name):
+    return [(line, ids) for line, n, ids in events if n == name]
+
+
+@pytest.mark.parametrize("mode,thread", [
+    ("trace_inline", "horovod-background"),
+    ("trace_pipelined", "horovod-dispatch")])
+def test_trace_holds_update_and_collective_with_one_step_id(report, mode,
+                                                            thread):
+    events = report[mode]
+    updates = _by_name(events, "hvd.update")
+    collectives = _by_name(events, "hvd.collective")
+    assert len(updates) == len(collectives) == 3
+    caller = {line for line, _ in updates}
+    assert len(caller) == 1
+    # The profiler labels a line with the OS thread's name, 15 characters.
+    assert {line for line, _ in collectives} == {thread[:15]}
+    assert caller != {thread[:15]}
+    steps = [ids["step"] for _, ids in updates]
+    assert steps == list(range(steps[0], steps[0] + 3))
+    assert [ids["step"] for _, ids in collectives] == steps
+    cycles = [ids["cycle"] for _, ids in collectives]
+    assert cycles == sorted(set(cycles))
+    # Every span of the step carries its id, on both threads.
+    for name in ("hvd.fuse", "hvd.enqueue", "hvd.wait", "hvd.unfuse",
+                 "hvd.tree_unflatten", "hvd.optimizer_update"):
+        assert [ids["step"] for _, ids in _by_name(events, name)] == steps
+    busy = [ids for _, ids in _by_name(events, "hvd.negotiate")
+            if ids["requests"]]
+    assert [ids["step"] for ids in busy] == steps
+    assert [ids["cycle"] for ids in busy] == cycles
+    programs = {ids["program"] for _, ids in
+                _by_name(events, "hvd.program_call")}
+    assert programs == {"hvd_tree_flatten", "hvd_local_allreduce",
+                        "hvd_tree_unflatten", "hvd_optimizer_update"}
+
+
+def test_dispatcher_thread_records_dispatch_wait(report):
+    # Four pipelined steps: one before the trace and three in it.
+    assert report["pipelined"]["dispatch_wait"]["count"] == 4
+    assert report["pipelined"]["dispatch_wait"]["ms"] >= 0
+    assert "dispatch_wait" not in report["step"]
+
+
+@pytest.mark.timeout(300)
+def test_two_ranks_stamp_queue_wait_and_dispatch_wait():
+    out = run_distributed(2, """
+import jax, jax.numpy as jnp, optax
+from horovod_tpu.core.timeline import phase_stats
+
+params = {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}
+grads = {"w": jnp.full((4, 8), float(rank + 1)), "b": jnp.ones((8,))}
+dopt = hvd.DistributedOptimizer(optax.sgd(0.1))
+state = dopt.init(params)
+for _ in range(2):
+    updates, state = dopt.update(grads, state, params)
+before = phase_stats.snapshot()
+for _ in range(3):
+    updates, state = dopt.update(grads, state, params)
+assert abs(float(updates["w"][0, 0]) + 0.1 * 1.5) < 1e-6, updates["w"]
+after = phase_stats.snapshot()
+d = {k: after[k]["count"] - before[k]["count"] for k in after}
+# A step's one tensor waits once in each queue; fuse runs on the caller
+# (the tree's flatten) and on the dispatcher (the bucket's staging).
+assert d["queue_wait"] == d["dispatch_wait"] == 3, d
+assert d["fuse"] == 6 and d["collective"] == d["unfuse"] == 3, d
+assert d["update"] == d["wait"] == d["tree_unflatten"] == 3, d
+assert d["negotiate"] >= 3, d
+for k in ("queue_wait", "dispatch_wait"):
+    assert after[k]["total_ms"] >= before[k]["total_ms"]
+print("SPANS_NP2_OK", rank, flush=True)
+""", timeout=240, extra_env={
+        "HOROVOD_DATA_PLANE": "xla",
+        "HOROVOD_JAX_COORDINATOR": f"127.0.0.1:{reserve_port()}"})
+    for r, o in enumerate(out):
+        assert f"SPANS_NP2_OK {r}" in o
+
+
+# ---------------------------------------------------------------------------
+# the primitive alone, in this process
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def stats(monkeypatch):
+    from horovod_tpu.core import timeline
+
+    fresh = timeline.PhaseStats()
+    monkeypatch.setattr(timeline, "phase_stats", fresh)
+    return fresh
+
+
+def test_phase_records_when_its_body_raises(stats):
+    from horovod_tpu.core.timeline import current_ids, phase
+
+    with pytest.raises(ValueError):
+        with phase("wait", step=3):
+            raise ValueError("boom")
+    assert stats.snapshot()["wait"]["count"] == 1
+    assert current_ids() == {}
+
+
+def test_phase_without_jax_is_the_accumulator_alone(stats, monkeypatch):
+    from horovod_tpu.core import timeline
+
+    monkeypatch.setattr(timeline, "_annotation", None)
+    monkeypatch.setitem(sys.modules, "jax", None)       # import jax raises
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    with timeline.phase("fuse", step=1) as span:
+        assert span._span is None
+    assert timeline._annotation is False
+    assert stats.snapshot()["fuse"]["count"] == 1
+
+
+def test_phase_never_imports_jax_itself(stats, monkeypatch):
+    from horovod_tpu.core import timeline
+
+    monkeypatch.setattr(timeline, "_annotation", None)
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    with timeline.phase("fuse"):
+        pass
+    assert "jax" not in sys.modules and timeline._annotation is None
+    assert stats.snapshot()["fuse"]["count"] == 1
+
+
+def test_spans_inherit_ids_per_thread_and_drop_none(stats):
+    from horovod_tpu.core.timeline import current_ids, phase, span_ids
+
+    seen = {}
+
+    def other():
+        seen["thread"] = current_ids()
+
+    with phase("update", step=7):
+        with phase("fuse", cycle=None):
+            seen["nested"] = current_ids()
+        with span_ids(cycle=2), phase("collective"):
+            seen["scoped"] = current_ids()
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        seen["after"] = current_ids()
+    assert seen == {"nested": {"step": 7}, "scoped": {"step": 7, "cycle": 2},
+                    "thread": {}, "after": {"step": 7}}
+    assert current_ids() == {}
+
+
+def test_record_false_and_n_steer_the_accumulator(stats):
+    from horovod_tpu.core.timeline import phase
+
+    with phase("negotiate") as idle:
+        idle.record = False
+    assert "negotiate" not in stats.snapshot()
+    with phase("negotiate") as busy:
+        busy.n = 5
+    assert stats.snapshot()["negotiate"]["count"] == 5
+    assert busy.seconds >= 0 and idle.seconds >= 0
+
+
+def test_program_call_counts_output_arrays(stats):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.core.timeline import program_call
+
+    @jax.jit
+    def three(x):
+        return x, {"a": x + 1, "b": (x * 2,)}
+
+    out = program_call(three, jnp.ones(2))
+    assert float(out[1]["b"][0][0]) == 2.0
+    snap = stats.snapshot()["program_call"]
+    assert snap["count"] == 3
+    # mean_ms is per output buffer.
+    assert snap["mean_ms"] == pytest.approx(snap["total_ms"] / 3, abs=1e-3)
+
+
+def test_tensor_queue_stamps_step_and_queue_wait(stats):
+    from horovod_tpu.core.messages import Request
+    from horovod_tpu.core.tensor_queue import TensorQueue, TensorTableEntry
+    from horovod_tpu.core.timeline import phase
+
+    q = TensorQueue()
+    entry, bare = TensorTableEntry("t0"), TensorTableEntry("t1")
+    with phase("update", step=11):
+        q.add(entry, Request(tensor_name="t0"))
+    q.add(bare, Request(tensor_name="t1"))
+    q.push_messages([Request(tensor_name="join")])          # no stamp
+    popped = q.pop_messages()
+    assert [r.tensor_name for r in popped] == ["join", "t0", "t1"]
+    assert (entry.step, bare.step) == (11, None)
+    assert stats.snapshot()["queue_wait"]["count"] == 2
+    # Re-queued requests have waited already.
+    q.push_messages(popped)
+    q.pop_messages()
+    assert stats.snapshot()["queue_wait"]["count"] == 2
+
+
+def test_phases_are_the_catalogued_and_documented_names():
+    import os
+
+    from horovod_tpu.core import metrics
+    from horovod_tpu.core.timeline import PHASES
+
+    assert len(set(PHASES)) == len(PHASES) == 13
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")).read()
+    section = doc.split("## Reading a step on the profiler's clock")[1] \
+        .split("\n## ")[0]
+    for name in PHASES:
+        assert metrics.CATALOG[name][0] == "stat"
+        assert f"`{name}`" in section, name
