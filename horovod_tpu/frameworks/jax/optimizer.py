@@ -185,6 +185,12 @@ def _allreduce_tree(grads, op, compression, prescale_factor,
     O(leaves).  Enqueue/wait mechanics live in :mod:`.wfbp` so the
     overlapped (microbatch-pipelined) mode shares them.
 
+    Returns the reduced tree as one array per leaf, so this is the path of
+    entry points that hand the gradient back to the user
+    (``distributed_value_and_grad``).  ``DistributedOptimizer.update``
+    consumes the gradient itself and takes ``wfbp.wait_buffers`` instead:
+    the reduced buffers go into the optimizer's program uncut.
+
     Adasum falls back to per-leaf enqueue: its operator is per-tensor.
     """
     if op == ops.Adasum:
@@ -273,12 +279,39 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
     # allreduce in the middle is host-driven.
     _jits: dict = {}
 
-    def _run(key: str, fn, *args):
+    def _run(name: str, fn, *args, key=None):
+        """``fn`` as the program ``hvd_optimizer_<name>``, compiled once per
+        ``key`` (``name`` unless ``fn`` closes over more than the
+        instance)."""
+        key = name if key is None else key
         cached = _jits.get(key)
         if cached is None:
-            cached = _jits[key] = _named_jit(f"hvd_optimizer_{key}", fn)
+            cached = _jits[key] = _named_jit(f"hvd_optimizer_{name}", fn)
         with phase("optimizer_update"):
             return program_call(cached, *args)
+
+    def _update_from_buffers(bufs, pending, inner_state, params):
+        """``tx.update`` on a reduced gradient that is still its fused
+        per-dtype buffers (``wfbp.wait_buffers``): the cut back into leaves
+        happens inside the optimizer's program, where a leaf is a
+        temporary of the device and no output buffer, so the gradient
+        never becomes one array per leaf on the host.  The program closes
+        over the plan's offsets and the treedef, hence the key: a second
+        gradient tree through this instance compiles its own."""
+        import jax
+
+        unflatten, treedef = pending.plan.unflatten, pending.treedef
+
+        def update(b, s, p):
+            # The barrier keeps ``tx.update``'s arithmetic compiled as it
+            # is for per-leaf arguments: with the slices fused into it,
+            # XLA's CPU backend contracts AdamW's multiply-adds otherwise
+            # and the last bit differs from the tree path's.
+            leaves = jax.lax.optimization_barrier(unflatten(b))
+            return tx.update(treedef.unflatten(leaves), s, p)
+
+        return _run("update", update, bufs, inner_state, params,
+                    key=(pending.plan.sig, treedef))
 
     def init(params):
         import jax
@@ -364,16 +397,26 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
                         grads)
                     return zeros, DistributedState(
                         state.inner_state, state.accumulated, count, window)
-                trees = [wfbp.wait_tree(p) for p in pending]
+                reduced = [wfbp.wait_buffers(p) for p in pending]
                 del _windows[window]
+                last = pending[-1]
+                if any(p.plan.sig != last.plan.sig
+                       or p.treedef != last.treedef for p in pending):
+                    raise ValueError(
+                        "overlap window: the microbatches' gradient trees "
+                        "differ in structure, shapes or dtypes")
                 scale = 1.0 / n_accum if average_aggregated_gradients \
                     else 1.0
-                grads = _run(
+                # The same elementwise sum as over the trees, on the K
+                # microbatches' buffers; a program of its own, as the
+                # per-leaf combine was, so the optimizer's arithmetic
+                # compiles as it did.
+                bufs = _run(
                     "combine",
-                    lambda *ts: jax.tree_util.tree_map(
-                        lambda *xs: sum(xs) * scale, *ts), *trees)
-                updates, inner = _run("update", tx.update, grads,
-                                      state.inner_state, params)
+                    lambda *bs: tuple(sum(xs) * scale for xs in zip(*bs)),
+                    *reduced)
+                updates, inner = _update_from_buffers(
+                    bufs, last, state.inner_state, params)
                 return updates, DistributedState(inner, state.accumulated,
                                                  0, -1)
             if count > 1 and state.window != -1:
@@ -408,11 +451,15 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
             # The reference runs the full enqueue/negotiate path even at
             # np=1 (allreduce is never skipped on size); matching that
             # keeps single-process behavior — and overhead — honest.
-            grads = _allreduce_tree(grads, op_name, compression,
-                                    prescale_factor, postscale_factor,
-                                    name_prefix=_name_root())
-        updates, inner = _run("update", tx.update, grads, state.inner_state,
-                              params)
+            pending = wfbp.enqueue_tree_fused(
+                grads, op_name, compression, prescale_factor,
+                postscale_factor, name_prefix=_name_root())
+            updates, inner = _update_from_buffers(
+                wfbp.wait_buffers(pending), pending, state.inner_state,
+                params)
+        else:
+            updates, inner = _run("update", tx.update, grads,
+                                  state.inner_state, params)
         return updates, DistributedState(inner, new_acc, count)
 
     return optax.GradientTransformation(init, update)

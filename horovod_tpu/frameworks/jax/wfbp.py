@@ -18,7 +18,7 @@ This module provides the two schedules that DO overlap on this hardware:
    many-chip regime (VERDICT r3 missing #1).
 
 2. **Microbatch-pipelined enqueue** (:func:`enqueue_tree_fused` /
-   :func:`wait_tree`, used by ``DistributedOptimizer(overlap=True)``) —
+   :func:`wait_buffers`, used by ``DistributedOptimizer(overlap=True)``) —
    with ``backward_passes_per_step=K``, each microbatch's fused gradients
    are enqueued asynchronously the moment its backward returns; the
    background runtime negotiates and dispatches them while the host
@@ -55,9 +55,32 @@ _tree_fuse_cache: dict = {}
 _cache_lock = threading.Lock()
 
 
-def _fuse_plan(sig):
-    """(groups, jit flatten, jit unflatten) for a leaf signature; one
-    compile per signature for the life of the process."""
+class FusePlan(NamedTuple):
+    """How one leaf signature is fused: built once per signature for the
+    life of the process, from the signature alone."""
+    sig: tuple              # ((shape, dtype name), ...) per leaf
+    groups: list            # [(dtype name, [leaf index, ...]), ...]
+    flatten: Callable       # jitted: leaves -> one flat buffer per group
+    unflatten: Callable     # pure and traceable: buffers -> tuple of leaves
+    unflatten_jit: Callable  # the same as a program of its own
+
+
+def _leaf_signature(leaves) -> tuple:
+    """((shape, dtype name), ...): what a :class:`FusePlan` is built from.
+    A ``jax.Array`` says its dtype itself; anything else (Python scalars,
+    NumPy arrays) gets the dtype JAX would canonicalise it to."""
+    import jax
+    import jax.numpy as jnp
+
+    return tuple(
+        (tuple(l.shape), l.dtype.name) if isinstance(l, jax.Array)
+        else (tuple(np.shape(l)), jnp.asarray(l).dtype.name)
+        for l in leaves)
+
+
+def _fuse_plan(sig) -> FusePlan:
+    """The :class:`FusePlan` of a leaf signature; one compile per
+    signature for the life of the process."""
     import jax
     import jax.numpy as jnp
 
@@ -78,8 +101,10 @@ def _fuse_plan(sig):
             if len(idxs) > 1 else leaves_in[idxs[0]].ravel()
             for _, idxs in groups)
 
-    def hvd_tree_unflatten(bufs, leaves_in):
-        outs = list(leaves_in)  # placeholders, right treedef slots
+    def hvd_tree_unflatten(bufs):
+        # Shapes and offsets are static, so this also traces inside a
+        # consumer's program, where a leaf is no output buffer.
+        outs = [None] * len(sig)
         for buf, (_, idxs) in zip(bufs, groups):
             off = 0
             for i in idxs:
@@ -89,20 +114,20 @@ def _fuse_plan(sig):
                 off += n
         return tuple(outs)
 
-    cached = (groups, jax.jit(hvd_tree_flatten),
-              jax.jit(hvd_tree_unflatten))
+    cached = FusePlan(sig, groups, jax.jit(hvd_tree_flatten),
+                      hvd_tree_unflatten, jax.jit(hvd_tree_unflatten))
     with _cache_lock:
         _tree_fuse_cache[sig] = cached
     return cached
 
 
 class PendingTree(NamedTuple):
-    """In-flight fused-tree allreduce: everything needed to finish it."""
+    """In-flight fused-tree allreduce: everything needed to finish it,
+    as buffers (:func:`wait_buffers`) or as the tree (:func:`wait_tree`).
+    It holds no gradient array: the cut back into leaves needs none."""
     handles: tuple
     ctxs: tuple
-    groups: Any
-    unflatten: Callable
-    leaves: Any
+    plan: FusePlan
     treedef: Any
     compression: Any
 
@@ -113,42 +138,57 @@ def enqueue_tree_fused(grads, op, compression, prescale_factor,
     dtype (static fusion at the source — see
     ``optimizer._allreduce_tree``).  Returns immediately; the background
     runtime negotiates/dispatches while the caller computes the next
-    microbatch's backward.  Finish with :func:`wait_tree`."""
+    microbatch's backward.  Finish with :func:`wait_buffers` (the
+    framework consumes the gradient) or :func:`wait_tree` (the caller
+    gets the tree back)."""
     import jax
-    import jax.numpy as jnp
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
-    sig = tuple((tuple(l.shape), jnp.asarray(l).dtype.name) for l in leaves)
-    groups, flatten, unflatten = _fuse_plan(sig)
+    plan = _fuse_plan(_leaf_signature(leaves))
 
     with phase("fuse"):
-        bufs = program_call(flatten, leaves)
+        bufs = program_call(plan.flatten, leaves)
     handles, ctxs = [], []
     with phase("enqueue"):
-        for buf, (dt, idxs) in zip(bufs, groups):
+        for buf, (dt, idxs) in zip(bufs, plan.groups):
             comp, cctx = compression.compress(buf)
             ctxs.append(cctx)
             handles.append(ops.allreduce_async(
                 comp, name=f"{name_prefix}.fused.{dt}.{buf.size}", op=op,
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor))
-    return PendingTree(tuple(handles), tuple(ctxs), groups, unflatten,
-                       leaves, treedef, compression)
+    return PendingTree(tuple(handles), tuple(ctxs), plan, treedef,
+                       compression)
+
+
+def wait_buffers(pending: PendingTree) -> tuple:
+    """Synchronize a :class:`PendingTree`; returns the reduced,
+    decompressed buffers, one per dtype group, still fused.
+
+    One batched wait over the fused buckets (``ops.synchronize_many``)
+    instead of a per-handle loop — a step blocks once per fused bucket,
+    never once per tensor.  For a consumer inside the framework: it passes
+    the buffers to its own program and applies ``pending.plan.unflatten``
+    there, so the reduced gradient never becomes one array per leaf
+    (``DistributedOptimizer.update``)."""
+    results = ops.synchronize_many(pending.handles)
+    return tuple(pending.compression.decompress(r, c)
+                 for r, c in zip(results, pending.ctxs))
 
 
 def wait_tree(pending: PendingTree):
     """Synchronize a :class:`PendingTree`; returns the reduced pytree.
 
-    One batched wait over the fused buckets (``ops.synchronize_many``)
-    instead of a per-handle loop — a step blocks once per fused bucket,
-    never once per tensor."""
+    :func:`wait_buffers`, then the cut back into per-leaf arrays as a
+    program of its own (``hvd_tree_unflatten``, one output buffer per
+    leaf).  For entry points that hand the gradient tree back to the user
+    (``distributed_value_and_grad``); the reference the buffer path is
+    tested against."""
     import jax
 
-    results = ops.synchronize_many(pending.handles)
+    bufs = wait_buffers(pending)
     with phase("tree_unflatten"):
-        reduced = tuple(pending.compression.decompress(r, c)
-                        for r, c in zip(results, pending.ctxs))
-        out = program_call(pending.unflatten, reduced, pending.leaves)
+        out = program_call(pending.plan.unflatten_jit, bufs)
     return jax.tree_util.tree_unflatten(pending.treedef, out)
 
 

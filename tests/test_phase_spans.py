@@ -58,6 +58,14 @@ report["update_outputs"] = len(jax.tree_util.tree_leaves(
     (updates, state.inner_state)))
 report["xla_stats"] = dict(xla.stats)
 
+# An entry point that hands the gradient tree back to the caller.
+dvg = hvd.distributed_value_and_grad(loss_fn)
+for _ in range(2):
+    dvg(params, batch)
+(_, returned), report["value_and_grad"] = delta(lambda: dvg(params, batch))
+report["returned_arrays"] = sum(
+    isinstance(l, jax.Array) for l in jax.tree_util.tree_leaves(returned))
+
 # Local aggregation: the off step accumulates and sends nothing.
 acc = hvd.DistributedOptimizer(inner, backward_passes_per_step=2)
 acc_state = acc.init(params)
@@ -112,41 +120,63 @@ def report():
 
 
 # What one plain step at np=1 adds to each phase's count.  program_call
-# counts output arrays: flatten 1 + allreduce 1 + unflatten LEAVES +
-# tx.update 2 * LEAVES.
+# counts output arrays: flatten 1 + allreduce 1 + tx.update 2 * LEAVES; the
+# reduced buffer goes into tx.update's program uncut (ISSUE 26), so no
+# step enters tree_unflatten.
 STEP_COUNTS = {
     "update": 1, "fuse": 1, "enqueue": 1, "queue_wait": 1, "negotiate": 1,
-    "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 1,
-    "optimizer_update": 1, "program_call": 2 + LEAVES + 2 * LEAVES,
+    "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 0,
+    "optimizer_update": 1, "program_call": 2 + 2 * LEAVES,
 }
 
 
 @pytest.mark.parametrize("name", sorted(STEP_COUNTS))
 def test_one_step_adds_exactly(report, name):
-    assert report["step"][name]["count"] == STEP_COUNTS[name]
+    zero = {"count": 0}
+    assert report["step"].get(name, zero)["count"] == STEP_COUNTS[name]
 
 
 def test_one_step_enters_no_other_phase(report):
     from horovod_tpu.core.timeline import PHASES
 
     entered = {k for k, v in report["step"].items() if v["count"]}
-    assert entered == set(STEP_COUNTS)
+    assert entered == {k for k, n in STEP_COUNTS.items() if n}
     assert entered < set(PHASES)
-    # The rest belong to the dispatcher thread and the one-program path.
-    assert set(PHASES) - entered == {"dispatch_wait", "wfbp_dispatch"}
+    # The rest belong to the dispatcher thread, the one-program path and
+    # the entry points that return a gradient tree.
+    assert set(PHASES) - entered == {"dispatch_wait", "wfbp_dispatch",
+                                     "tree_unflatten"}
 
 
 def test_program_call_counts_outputs_worked_out_from_the_tree(report):
     groups = 1                                  # one dtype
-    expect = groups + groups + LEAVES + report["update_outputs"]
+    expect = groups + groups + report["update_outputs"]
     assert report["update_outputs"] == 2 * LEAVES
-    assert report["step"]["program_call"]["count"] == expect
+    assert report["step"]["program_call"]["count"] == expect == 10
+
+
+# distributed_value_and_grad hands the tree to the user, so it alone still
+# cuts the reduced buffer into one array per leaf: flatten 1 + allreduce 1
+# + unflatten LEAVES.
+VALUE_AND_GRAD_COUNTS = {
+    "fuse": 1, "enqueue": 1, "queue_wait": 1, "negotiate": 1,
+    "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 1,
+    "program_call": 2 + LEAVES, "update": 0, "optimizer_update": 0,
+}
+
+
+@pytest.mark.parametrize("name", sorted(VALUE_AND_GRAD_COUNTS))
+def test_value_and_grad_still_returns_the_tree(report, name):
+    zero = {"count": 0}
+    got = report["value_and_grad"].get(name, zero)["count"]
+    assert got == VALUE_AND_GRAD_COUNTS[name]
+    assert report["returned_arrays"] == LEAVES
 
 
 def test_callers_parts_sum_to_no_more_than_update(report):
     step = report["step"]
     parts = sum(step[k]["ms"] for k in (
-        "fuse", "enqueue", "wait", "tree_unflatten", "optimizer_update"))
+        "fuse", "enqueue", "wait", "optimizer_update"))
     # total_ms is rounded to a microsecond per phase.
     assert 0 < parts <= step["update"]["ms"] + 0.005
 
@@ -201,8 +231,9 @@ def test_trace_holds_update_and_collective_with_one_step_id(report, mode,
     assert cycles == sorted(set(cycles))
     # Every span of the step carries its id, on both threads.
     for name in ("hvd.fuse", "hvd.enqueue", "hvd.wait", "hvd.unfuse",
-                 "hvd.tree_unflatten", "hvd.optimizer_update"):
+                 "hvd.optimizer_update"):
         assert [ids["step"] for _, ids in _by_name(events, name)] == steps
+    assert _by_name(events, "hvd.tree_unflatten") == []
     busy = [ids for _, ids in _by_name(events, "hvd.negotiate")
             if ids["requests"]]
     assert [ids["step"] for ids in busy] == steps
@@ -210,7 +241,7 @@ def test_trace_holds_update_and_collective_with_one_step_id(report, mode,
     programs = {ids["program"] for _, ids in
                 _by_name(events, "hvd.program_call")}
     assert programs == {"hvd_tree_flatten", "hvd_local_allreduce",
-                        "hvd_tree_unflatten", "hvd_optimizer_update"}
+                        "hvd_optimizer_update"}
 
 
 def test_dispatcher_thread_records_dispatch_wait(report):
@@ -242,7 +273,8 @@ d = {k: after[k]["count"] - before[k]["count"] for k in after}
 # (the tree's flatten) and on the dispatcher (the bucket's staging).
 assert d["queue_wait"] == d["dispatch_wait"] == 3, d
 assert d["fuse"] == 6 and d["collective"] == d["unfuse"] == 3, d
-assert d["update"] == d["wait"] == d["tree_unflatten"] == 3, d
+assert d["update"] == d["wait"] == d["optimizer_update"] == 3, d
+assert d.get("tree_unflatten", 0) == 0, d
 assert d["negotiate"] >= 3, d
 for k in ("queue_wait", "dispatch_wait"):
     assert after[k]["total_ms"] >= before[k]["total_ms"]
