@@ -335,9 +335,6 @@ HOROVOD_NUM_FINALIZER_THREADS = "HOROVOD_NUM_FINALIZER_THREADS"
 # Truthy: never build/load the optional native kernel library
 # (_native/__init__.py).
 HOROVOD_DISABLE_NATIVE = "HOROVOD_DISABLE_NATIVE"
-# "1": use the pallas flash-attention kernel in models/transformer.py
-# (opt-in; measured slower than the XLA-fused einsum at moderate s).
-HOROVOD_FLASH_ATTENTION = "HOROVOD_FLASH_ATTENTION"
 # Row cap for the store-less (driver-collect) Spark fit path; 0 disables.
 HOROVOD_SPARK_INLINE_MAX_ROWS = "HOROVOD_SPARK_INLINE_MAX_ROWS"
 

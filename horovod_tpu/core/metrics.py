@@ -207,6 +207,15 @@ CATALOG: Dict[str, Tuple[str, str]] = {
         "gauge", "smallest time-to-expiry across live leases (driver "
                  "only; negative means a lease is inside its grace "
                  "window and about to be declared expired)"),
+    # -- sparse experts (parallel/moe.py::publish_routing) --
+    "moe_max_load_ratio": (
+        "gauge", "busiest expert's routed rows over the mean over experts, "
+                 "per layer= , summed over the steps the router's counters "
+                 "saw"),
+    "moe_routed_tokens_per_step": (
+        "gauge", "routed rows a step (k * tokens, summed over layers)"),
+    "moe_steps": (
+        "gauge", "steps the router's counters have counted"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

@@ -62,6 +62,7 @@ from .optimizer import (  # noqa: F401
     distributed_value_and_grad,
 )
 from .wfbp import (  # noqa: F401
+    PROCESS_AXIS,
     OverlappedTrainStep,
     make_overlapped_train_step,
 )

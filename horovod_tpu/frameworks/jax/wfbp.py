@@ -197,6 +197,10 @@ def wait_tree(pending: PendingTree):
 # ---------------------------------------------------------------------------
 
 
+# The one axis of the eager runtime's process mesh (``backend/xla.py``).
+PROCESS_AXIS = "proc"
+
+
 class OverlappedTrainStep:
     """Forward + backward + gradient allreduce + optimizer update as ONE
     XLA program over the eager runtime's process mesh.
@@ -216,6 +220,13 @@ class OverlappedTrainStep:
     gradient collective computes exactly the cross-rank average gradient —
     and XLA's latency-hiding scheduler overlaps it with the remaining
     backward (the WFBP schedule, compiler-made).
+
+    The step is traced and run with the process mesh in context
+    (``jax.set_mesh``), its one axis :data:`PROCESS_AXIS`: a layer that must
+    not mix the rows of different ranks (a sort over "all tokens" would be a
+    sort across ranks, which GSPMD serves by gathering activations) can see
+    the axis and keep to a rank's rows in a ``shard_map``, as
+    ``parallel.moe.moe_ffn`` does.
 
     Cross-rank program agreement is checked once through the negotiation
     plane (allgather of the program signature) — a rank tracing a different
@@ -268,7 +279,7 @@ class OverlappedTrainStep:
     def _batch_sharding(self, ctx):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        return NamedSharding(ctx.mesh, P("proc"))
+        return NamedSharding(ctx.mesh, P(PROCESS_AXIS))
 
     def _lift_replicated(self, ctx, tree):
         """Local pytree → replicated global arrays on the process mesh
@@ -403,9 +414,12 @@ class OverlappedTrainStep:
                 self._sig_checked = True
             self._step = self._compile(ctx, params, opt_state, gbatch,
                                        aux=aux)
-        if self._has_aux:
-            return self._step(params, opt_state, aux, gbatch)
-        return self._step(params, opt_state, gbatch)
+        import jax
+
+        with jax.set_mesh(ctx.mesh):
+            if self._has_aux:
+                return self._step(params, opt_state, aux, gbatch)
+            return self._step(params, opt_state, gbatch)
 
 
 def make_overlapped_train_step(loss_fn: Callable, tx, *, donate: bool = True,

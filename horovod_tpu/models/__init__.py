@@ -12,8 +12,9 @@ MXU-sized matmuls):
   (BASELINE.md: ResNet-50 images/sec/chip);
 - :mod:`.transformer` — encoder (BERT-large preset for the Adasum
   BERT-pretraining config) and decoder (GPT preset) with pluggable
-  attention: full, ring (sequence-parallel long context), Ulysses;
-  optional MoE FFN;
+  attention: full, ring (sequence-parallel long context), Ulysses; the
+  OLMoE preset (RMSNorm, RoPE, QK-norm, untied head, dropless top-k
+  expert FFN) through options of the same config;
 - :mod:`.training` — sharded train-step builders wiring models to the
   ``parallel`` layer and optax.
 """
@@ -25,6 +26,8 @@ from .transformer import (  # noqa: F401
     TransformerConfig,
     bert_large_config,
     gpt_small_config,
+    moe_stats,
+    olmoe_1b_7b_config,
     tiny_config,
 )
 from .training import TrainState, make_sharded_train_step  # noqa: F401

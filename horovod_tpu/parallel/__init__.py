@@ -19,7 +19,8 @@ and ride ICI within a slice / DCN across slices.  This package is therefore
   parallelism built on the alltoall primitive the reference exposes raw
   (`operations.cc:1081-1142`);
 - :mod:`.pipeline` — pipeline parallelism over a ``pipe`` mesh axis;
-- :mod:`.moe` — expert parallelism (gating + all_to_all dispatch/combine).
+- :mod:`.moe` — expert parallelism (gating + all_to_all dispatch/combine)
+  and the dropless top-k expert layer (sort + grouped matmul).
 
 Beyond-parity scope (TP/PP/SP/EP) is deliberate: on TPU these fall out of
 the same mesh machinery that gives data parallelism, and the build target
@@ -58,4 +59,4 @@ from .sharding import (  # noqa: F401
 from .ring_attention import ring_attention  # noqa: F401
 from .ulysses import ulysses_attention  # noqa: F401
 from .pipeline import pipeline_apply  # noqa: F401
-from .moe import moe_dispatch_combine  # noqa: F401
+from .moe import moe_dispatch_combine, moe_ffn  # noqa: F401

@@ -1,0 +1,107 @@
+"""Compile the main path's kernels at their real widths for a TPU v5e that
+is described, not attached: what the chip's compiler refuses (a block that
+does not fit the fast memory, a slice off the tiling) fails here, at no chip
+time.  Nothing runs, so nothing here is a result or a time.
+
+The topology is described inside a fixture, never while a module is imported:
+only one process may hold the TPU's library, and every pytest worker imports
+every test file.  All such tests live in this one file.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache and
+    cannot be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_attention_compiles_at_olmoes_shape(one_chip, no_compile_cache):
+    """2 sequences of 4096, 16 heads of 128, causal, forward and backward,
+    with the blocks ``models/transformer.py`` gives the kernel."""
+    from horovod_tpu.models.transformer import (
+        _FLASH_BLOCK,
+        _FLASH_MIN_SEQ,
+        _flash_attention,
+    )
+
+    assert _FLASH_MIN_SEQ % _FLASH_BLOCK == 0
+    qkv = [_shape((2, 4096, 16, 128), jnp.bfloat16, one_chip)] * 3
+
+    def loss(q, k, v):
+        return jnp.sum(_flash_attention(q, k, v, True, 128)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *qkv).compile().as_text()
+    kernels = set(re.findall(r"%(flash\w*?)[.\d]* =", text))
+    assert any("bwd_dkv" in k for k in kernels), kernels
+    assert any("bwd_dq" in k for k in kernels), kernels
+    assert f"block_q_{_FLASH_BLOCK}" in text
+
+
+def test_expert_layer_compiles_at_published_widths(one_chip,
+                                                   no_compile_cache):
+    """8192 tokens through 64 experts of 2048 x 1024, 8 a token: the grouped
+    products are XLA's grouped-matmul kernels and their work is the routed
+    rows, not 64 experts a token."""
+    from horovod_tpu.parallel.moe import moe_ffn
+
+    d, f, e, k = 2048, 1024, 64, 8
+    args = [_shape((2, 4096, d), jnp.bfloat16, one_chip),
+            _shape((d, e), jnp.float32, one_chip),
+            _shape((e, d, f), jnp.float32, one_chip),
+            _shape((e, d, f), jnp.float32, one_chip),
+            _shape((e, f, d), jnp.float32, one_chip)]
+
+    def loss(*a):
+        y, stats = moe_ffn(*a, k=k)
+        return jnp.sum(y.astype(jnp.float32)) \
+            + jnp.sum(stats.load_balancing_loss)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* =", text)) == 9
+    # Dispatch and combine are gathers in both directions: no scatter of
+    # 4 KB rows (top-k's own cotangent is a scatter of 65536 scalars).
+    assert not re.findall(r"= \w+\[\d+,2048\]\S* scatter\(", text)
+    routed = 9 * 2 * (8192 * k) * d * f
+    flops = compiled.cost_analysis()["flops"]
+    assert routed < flops < 1.15 * routed, (flops, routed)
