@@ -130,6 +130,12 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                      "applied, wall-clock across processes (driver only; "
                      "the sim lane measures the full flag->first-step "
                      "curve on one clock)"),
+    # -- runtime init (common/compile_cache.py) --
+    "compile_cache_entries_written": (
+        "counter", "persistent compile-cache entries this process wrote "
+                   "through common/compile_cache.py's hook: programs on its "
+                   "own devices, in a jax.distributed process other than 0 "
+                   "(process 0 writes through JAX and counts none)"),
     # -- rendezvous / elastic --
     "rendezvous_store_ops_total": (
         "counter", "HTTP KV store requests, labeled op=get|set|delete|keys"),
