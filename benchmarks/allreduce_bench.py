@@ -139,15 +139,11 @@ def main() -> int:
     p.add_argument("--compression-sweep", action="store_true",
                    help="sweep HOROVOD_WIRE_COMPRESSION none/fp16/bf16 × "
                         "HOROVOD_WIRE_CRC on/off (interleaved) and report "
-                        "per-variant step time + speedup vs uncompressed "
-                        "(canonical artifact: "
-                        "benchmarks/results/ring_compression_r9.json)")
+                        "per-variant step time + speedup vs uncompressed")
     p.add_argument("--transport-sweep", action="store_true",
                    help="sweep HOROVOD_TRANSPORT shm/tcp/auto "
                         "(interleaved) per config and report per-variant "
-                        "step time + shm speedup over loopback TCP "
-                        "(canonical artifact: "
-                        "benchmarks/results/ring_transport_sweep_r11.json)")
+                        "step time + shm speedup over loopback TCP")
     p.add_argument("--out", type=str, default=None,
                    help="write result records to this JSON file")
     args = p.parse_args()

@@ -36,7 +36,7 @@ rm -rf hvd_flight_recorder/ hvd_flight_recorder.rank*.json
 # np=8 reshard proofs are excluded here and run in their own lane below.
 rc=0
 JAX_PLATFORMS=cpu python -m pytest tests/test_fault_injection.py \
-    -m "chaos and not slow" \
+    tests/test_fault_injection_elastic.py -m "chaos and not slow" \
     -v -p no:cacheprovider "$@" > ci/chaos.last.log 2>&1 || rc=$?
 cat ci/chaos.last.log
 [ "$rc" -eq 0 ] || { echo "chaos lane FAILED (rc=$rc)"; exit "$rc"; }
@@ -136,7 +136,7 @@ cat ci/chaos.demotion.log
 echo "reshard lane: np=8 live churn under HOROVOD_LOCK_DEBUG=1"
 rc=0
 JAX_PLATFORMS=cpu HOROVOD_LOCK_DEBUG=1 \
-python -m pytest tests/test_fault_injection.py -m "chaos and slow" \
+python -m pytest tests/test_fault_injection_elastic.py -m "chaos and slow" \
     -k "live_reshard" -v -p no:cacheprovider \
     > ci/chaos.reshard.log 2>&1 || rc=$?
 cat ci/chaos.reshard.log

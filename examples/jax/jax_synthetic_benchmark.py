@@ -3,15 +3,18 @@
 Two modes:
 - ``--mode eager``: the Horovod-style eager path (``hvd.allreduce`` of
   grads via ``DistributedOptimizer``) — any-tensor-any-time semantics, XLA
-  data plane when launched with ``hvdrun --data-plane xla``.
+  data plane when launched with ``hvdrun --data-plane xla``.  The updates
+  are applied through ``jax.jit(optax.apply_updates, donate_argnums=(0,))``:
+  un-jitted, ``optax.apply_updates`` dispatches one add per parameter leaf,
+  and those dispatches held 64-72% of the device's idle time in the eager
+  cells of the benchmark that ran this script (ledger, PR 22).
 - ``--mode spmd`` (default): the TPU-first path — one jit'd train step over
   the device mesh, gradient sync folded into the step as a psum (XLA fuses
   it with backprop; this is the configuration ``bench.py`` measures).
 - ``--mode wfbp``: the overlapped eager path —
   ``hvd.make_overlapped_train_step`` compiles forward+backward+allreduce+
   update into one program over the runtime's process mesh; XLA overlaps
-  the gradient collectives with backward (in-program WFBP,
-  ``docs/perf_r4.md``).
+  the gradient collectives with backward (in-program WFBP).
 
 Run: ``hvdrun -np 4 python examples/jax/jax_synthetic_benchmark.py --mode eager``
      ``hvdrun -np 4 python examples/jax/jax_synthetic_benchmark.py --mode wfbp``
@@ -109,13 +112,14 @@ def build_step(mode, model, tx, images, labels):
     device = jax.local_devices()[0]
     params = jax.device_put(state.params, device)
     batch_stats = jax.device_put(state.batch_stats, device)
+    apply_updates = jax.jit(optax.apply_updates, donate_argnums=(0,))
 
     def benchmark_step():
         nonlocal params, batch_stats, opt_state
         loss, grads, batch_stats = grad_step(params, batch_stats)
         # eager allreduce of the grad pytree (the Horovod path)
         updates, opt_state = dopt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params = apply_updates(params, updates)
         return loss
 
     return benchmark_step, lambda: params

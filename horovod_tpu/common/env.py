@@ -24,12 +24,10 @@ HOROVOD_HOSTNAME = "HOROVOD_HOSTNAME"
 # -- rendezvous / control plane --
 HOROVOD_RENDEZVOUS_ADDR = "HOROVOD_GLOO_RENDEZVOUS_ADDR"
 HOROVOD_RENDEZVOUS_PORT = "HOROVOD_GLOO_RENDEZVOUS_PORT"
-HOROVOD_CONTROLLER = "HOROVOD_CONTROLLER"  # "tcp" (our gloo-role) | "local"
 # Full-mesh TCP bring-up budget (rendezvous wait + accept + dial), secs.
 # Loaded CI hosts starting N jax runtimes concurrently need more than the
 # 60 s default; the test harness load-scales it.
 HOROVOD_MESH_STARTUP_TIMEOUT = "HOROVOD_MESH_STARTUP_TIMEOUT"
-HOROVOD_CPU_OPERATIONS = "HOROVOD_CPU_OPERATIONS"
 HOROVOD_SECRET_KEY = "HOROVOD_SECRET_KEY"
 HOROVOD_ELASTIC = "HOROVOD_ELASTIC"
 # Negotiation fan-out: "auto" | "star" | "tree" (core/controller.py picks
@@ -312,8 +310,6 @@ HOROVOD_AUTOTUNE = "HOROVOD_AUTOTUNE"
 HOROVOD_AUTOTUNE_LOG = "HOROVOD_AUTOTUNE_LOG"
 HOROVOD_AUTOTUNE_WARMUP_SAMPLES = "HOROVOD_AUTOTUNE_WARMUP_SAMPLES"
 HOROVOD_AUTOTUNE_STEPS_PER_SAMPLE = "HOROVOD_AUTOTUNE_STEPS_PER_SAMPLE"
-HOROVOD_AUTOTUNE_BAYES_OPT_MAX_SAMPLES = "HOROVOD_AUTOTUNE_BAYES_OPT_MAX_SAMPLES"
-HOROVOD_AUTOTUNE_GAUSSIAN_PROCESS_NOISE = "HOROVOD_AUTOTUNE_GAUSSIAN_PROCESS_NOISE"
 # Fold the wire-compression codec ({none, fp16, bf16, int8, onebit})
 # into the autotuner's search space as a categorical dimension ("1"/"0",
 # default off): codec verdicts are gated by the A/B sign test
@@ -324,7 +320,6 @@ HOROVOD_AUTOTUNE_GAUSSIAN_PROCESS_NOISE = "HOROVOD_AUTOTUNE_GAUSSIAN_PROCESS_NOI
 HOROVOD_AUTOTUNE_CODEC = "HOROVOD_AUTOTUNE_CODEC"
 HOROVOD_LOG_LEVEL = "HOROVOD_LOG_LEVEL"
 HOROVOD_LOG_HIDE_TIMESTAMP = "HOROVOD_LOG_HIDE_TIMESTAMP"
-HOROVOD_ADASUM_MPI_CHUNK_SIZE = "HOROVOD_ADASUM_MPI_CHUNK_SIZE"
 # Force the hierarchical (intra-host ring + parallel cross-host rings)
 # allreduce off/on ("0"/"1"; reference common.h:79).  Structural
 # requirements still gate a forced "1" (backend/cpu_ring.py).
@@ -340,7 +335,6 @@ HOROVOD_SPARK_INLINE_MAX_ROWS = "HOROVOD_SPARK_INLINE_MAX_ROWS"
 
 # -- TPU-specific (no reference equivalent: XLA data-plane knobs) --
 HOROVOD_TPU_MESH_AXES = "HOROVOD_TPU_MESH_AXES"  # e.g. "dp:8" or "dp:4,tp:2"
-HOROVOD_XLA_BUCKET_BYTES = "HOROVOD_XLA_BUCKET_BYTES"
 HOROVOD_DATA_PLANE = "HOROVOD_DATA_PLANE"  # "xla" | "tcp" | "auto"
 # "host:port" of the jax.distributed coordination service (rank 0's
 # process); set by the launcher when the XLA data plane is requested.

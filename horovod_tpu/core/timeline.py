@@ -374,7 +374,7 @@ class PhaseStats:
     go" cheaply enough to leave enabled (two monotonic reads + one dict
     update per phase).  :func:`phase` feeds it and puts the same extent
     into the jax profiler's trace.  Surfaced by the chip benchmark's
-    per-layer metrics, ``benchmarks/eager_bench.py --profile``,
+    per-layer metrics (``chip_bench/metrics/``),
     snapshot-able from tests, and registered as a view in the metrics
     registry (``phase_seconds_total``/``phase_ops_total``)."""
 

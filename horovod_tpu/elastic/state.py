@@ -69,6 +69,9 @@ class State:
 
     def __init__(self, **kwargs):
         self._reset_callbacks: List[Callable[[], None]] = []
+        # Logical clock of ``commit()``; survivors compare it before they
+        # skip a post-reset sync (``_sync_for_epoch``).
+        self._commits = 0
 
     def register_reset_callbacks(self, callbacks: List[Callable[[], None]]) -> None:
         self._reset_callbacks.extend(callbacks)
@@ -80,6 +83,7 @@ class State:
 
     def commit(self) -> None:
         self.save()
+        self._commits += 1
         self.check_host_updates()
 
     def check_host_updates(self) -> None:
@@ -326,10 +330,15 @@ def _sync_for_epoch(state: State) -> None:
     resharding").
 
     Legacy path: broadcast everything from rank 0.  Under a
-    reshard-marked epoch: a pure shrink (no joiners) skips the sync
-    entirely — every participant is a survivor restored to the same
-    commit, so the broadcast would move zero information; with joiners,
-    broadcast from ``sync_root`` (the lowest SURVIVING rank — rank 0
+    reshard-marked epoch: a pure shrink (no joiners) skips the sync when
+    every survivor stands on the same commit, so the broadcast would
+    move zero information.  That has to be checked, not assumed: the
+    abort that ended the epoch can reach one rank after its last receive
+    of a step and its peer before it (a frame corrupted on the ring's
+    final allgather hop: the sender has finished and committed the step
+    its receiver rejects), and survivors one commit apart never meet
+    again.  With joiners, or with survivors apart, broadcast from
+    ``sync_root`` (the lowest SURVIVING rank — rank 0
     itself may be the fresh process being state-filled, which on the
     legacy root-0 rule would broadcast its blank init state over the
     survivors' progress).  The marker is read from the store per
@@ -348,14 +357,17 @@ def _sync_for_epoch(state: State) -> None:
         state.sync()
         return
     from ..core import flight_recorder
+    from ..frameworks.jax.functions import allgather_object
 
-    if not info["joiners"]:
+    commits = allgather_object(state._commits, name="elastic.commits")
+    if not info["joiners"] and len(set(commits)) == 1:
         flight_recorder.record("reshard_sync_skipped", epoch=info["epoch"])
         return
     flight_recorder.record("reshard_sync", epoch=info["epoch"],
                            root=info["sync_root"],
                            joiners=len(info["joiners"]))
     state.sync(root_rank=info["sync_root"])
+    state._commits = commits[info["sync_root"]]
 
 
 def _teardown() -> None:

@@ -2,8 +2,8 @@
 
 Currently: the conv(1x1)+BatchNorm-statistics epilogue fusion
 (:mod:`.conv_bn_stats`) targeting the measured ResNet-50 bottleneck —
-BN statistics re-reading every activation from HBM (46.6% of device time,
-``docs/perf_r4.md §5``)."""
+BN statistics re-reading every activation from HBM (they lead the device's
+op list in ``resnet50-wfbp-1chip``: ``PERF.md`` §5, ``ROADMAP.md`` Q1.4)."""
 
 from .conv_bn_stats import (  # noqa: F401
     FusedConv1x1BN,

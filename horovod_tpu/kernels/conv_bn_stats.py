@@ -1,11 +1,11 @@
 """Fused 1x1-conv + BatchNorm-statistics pallas kernel (TPU).
 
-The measured ResNet-50 plateau (``docs/perf_r4.md §5``): XLA emits the
-conv, writes the activation to HBM, then a separate reduce-fusion
-re-reads the WHOLE activation to compute BatchNorm's per-channel
-sum / sum-of-squares — ~18 GB of the step's ~38 GB HBM traffic, 46.6% of
-device time, and the one structural lever the round-4 rejection table
-left standing.  Convs are fusion roots in XLA; the compiler will not sink
+The measured ResNet-50 plateau (``PERF.md`` §5, ``ROADMAP.md`` Q1.4): XLA
+emits the conv, writes the activation to HBM, then a separate
+reduce-fusion re-reads the WHOLE activation to compute BatchNorm's
+per-channel sum / sum-of-squares — the fusions that lead the device's op
+list, and the one structural lever left once the cheap ones
+(``benchmarks/resnet_levers.py``) were measured and rejected.  Convs are fusion roots in XLA; the compiler will not sink
 a cross-batch reduction into the conv epilogue, so this kernel does it by
 hand for the convs where that is tractable: 1x1 convolutions, which are
 plain matmuls over ``[N*H*W, Cin] @ [Cin, Cout]`` and carry roughly half
@@ -288,7 +288,8 @@ class FusedConv1x1BN(nn.Module):
             y = y.astype(jnp.float32)
             mean = s1 / count
             # one-pass E[y^2] - E[y]^2 (the shipped fast-variance
-            # config; measured faster than two-pass, perf_r4 §5)
+            # config; measured faster than two-pass by
+            # benchmarks/resnet_levers.py)
             var = jnp.maximum(s2 / count - mean * mean, 0.0)
             if not self.is_initializing():
                 m = self.momentum

@@ -28,7 +28,7 @@ class BottleneckBlock(nn.Module):
     strides: Tuple[int, int] = (1, 1)
     # When set (a partial of kernels.FusedConv1x1BN), every conv(1x1)+BN
     # pair runs the pallas fused-statistics kernel — the structural lever
-    # for the BN-stat HBM re-read (docs/perf_r4.md §5).  The 3x3 stays on
+    # for the BN-stat HBM re-read (ROADMAP.md Q1.4).  The 3x3 stays on
     # XLA's conv.
     fused_cb: ModuleDef = None
 
@@ -91,7 +91,7 @@ class ResNet(nn.Module):
     num_filters: int = 64
     dtype: Any = jnp.bfloat16
     # BN statistics precision/algorithm levers (benchmarks/resnet_levers.py
-    # measures them; docs/perf_r4.md records the verdicts).  Defaults are
+    # measures them; ROADMAP.md Q1.4 records the verdicts).  Defaults are
     # the numerically safe flax behavior: fp32 reductions, one-pass
     # E[x^2]-E[x]^2 variance.
     bn_f32_stats: bool = True

@@ -196,7 +196,6 @@ class RayExecutor:
             env.update({
                 env_mod.HOROVOD_RENDEZVOUS_ADDR: rdv_addr,
                 env_mod.HOROVOD_RENDEZVOUS_PORT: str(port),
-                env_mod.HOROVOD_CONTROLLER: "tcp",
                 env_mod.HOROVOD_SECRET_KEY: job_secret,
             })
             if self.settings.use_tpu and slot.local_size > 1:
@@ -333,7 +332,6 @@ class ElasticRayExecutor:
             env.update({
                 env_mod.HOROVOD_RENDEZVOUS_ADDR: rdv_addr,
                 env_mod.HOROVOD_RENDEZVOUS_PORT: str(port),
-                env_mod.HOROVOD_CONTROLLER: "tcp",
                 env_mod.HOROVOD_SECRET_KEY: self._job_secret,
                 env_mod.HOROVOD_ELASTIC: "1",
                 env_mod.HOROVOD_EPOCH: str(epoch),

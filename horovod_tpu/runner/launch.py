@@ -108,7 +108,6 @@ def _slot_env(slot: SlotInfo, rdv_addr: str, rdv_port: int,
     env.update({
         env_mod.HOROVOD_RENDEZVOUS_ADDR: rdv_addr,
         env_mod.HOROVOD_RENDEZVOUS_PORT: str(rdv_port),
-        env_mod.HOROVOD_CONTROLLER: "tcp",
     })
     job_host_slots = job_host_slots or [("localhost", slot.local_size)]
     if tpu_chip_binding is None:

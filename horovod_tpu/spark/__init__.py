@@ -154,7 +154,6 @@ def run(fn: Callable, args: tuple = (), kwargs: Optional[dict] = None,
                 env.update({
                     env_mod.HOROVOD_RENDEZVOUS_ADDR: rdv_addr,
                     env_mod.HOROVOD_RENDEZVOUS_PORT: str(port),
-                    env_mod.HOROVOD_CONTROLLER: "tcp",
                 })
                 server.set(_ENV_SCOPE, str(index),
                            json.dumps(env).encode())
@@ -355,7 +354,6 @@ def run_elastic(fn: Callable, args: tuple = (),
         env.update({
             env_mod.HOROVOD_RENDEZVOUS_ADDR: rdv_addr,
             env_mod.HOROVOD_RENDEZVOUS_PORT: str(port),
-            env_mod.HOROVOD_CONTROLLER: "tcp",
             env_mod.HOROVOD_ELASTIC: "1",
             env_mod.HOROVOD_EPOCH: str(epoch),
         })

@@ -44,7 +44,7 @@ Usage::
 
     hvd-control-path merged_timeline.json             # text report
     hvd-control-path server_trace.json tl.json.driver --json cp.json
-    tools/control_path.py /tmp/server.json /tmp/tl.json*   # repo shim
+    python -m horovod_tpu.tools.control_path /tmp/server.json /tmp/tl.json*
 """
 
 from __future__ import annotations

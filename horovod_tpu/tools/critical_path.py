@@ -45,7 +45,7 @@ Usage::
 
     hvd-critical-path merged_timeline.json            # text report
     hvd-critical-path tl.json tl.json.rank1 --json cp.json --top 5
-    tools/critical_path.py /tmp/tl.json*              # repo-root shim
+    python -m horovod_tpu.tools.critical_path /tmp/tl.json*
 """
 
 from __future__ import annotations

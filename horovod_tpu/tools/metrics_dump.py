@@ -11,9 +11,9 @@ Usage::
     python -m horovod_tpu.tools.metrics_dump              # addr from env
     python -m horovod_tpu.tools.metrics_dump --addr 10.0.0.2 --port 41999
     python -m horovod_tpu.tools.metrics_dump --raw        # Prometheus text
-    tools/metrics_dump.py --json                          # raw snapshots
-    tools/metrics_dump.py --watch 2                       # re-scrape every 2s
-    tools/metrics_dump.py --watch 2 --rate                # per-second deltas
+    hvd-metrics-dump --json                               # raw snapshots
+    hvd-metrics-dump --watch 2                            # re-scrape every 2s
+    hvd-metrics-dump --watch 2 --rate                     # per-second deltas
 
 Address defaults come from the launcher-propagated
 ``HOROVOD_GLOO_RENDEZVOUS_ADDR``/``PORT`` env, so running it on any job

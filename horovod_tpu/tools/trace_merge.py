@@ -14,7 +14,7 @@ Usage::
 
     python -m horovod_tpu.tools.trace_merge tl.json tl.json.rank1 \\
         -o merged.json
-    tools/trace_merge.py /tmp/tl.json*          # repo-root shim, globbed
+    hvd-trace-merge /tmp/tl.json*               # console script, globbed
 
 Alignment: a trace's event at local ``ts`` µs happened at server time
 ``wall_base_ns/1e3 + ts - server_offset_ns/1e3`` µs; the merged axis is

@@ -2,8 +2,8 @@
 server restart, and driver crash-recovery (docs/control_plane.md).
 
 The fast, in-process half of the survivability proof; the end-to-end
-SIGKILL-and-restart chaos runs live in tests/test_fault_injection.py's
-chaos lane.
+SIGKILL-and-restart chaos runs live in
+tests/test_fault_injection_elastic.py's chaos lane.
 """
 
 import json

@@ -1,7 +1,7 @@
 """Fused conv1x1+BN-stats kernel: numerics vs the unfused composition.
 
 The pallas kernel (`horovod_tpu/kernels/conv_bn_stats.py`) targets the
-measured ResNet-50 plateau (docs/perf_r4.md §5: BN statistics re-read
+measured ResNet-50 plateau (ROADMAP.md Q1.4: BN statistics re-read
 every activation).  On this CPU rig it runs in interpret mode; the
 contract pinned here — values, statistics, gradients, and module output
 equal to flax's Conv+BatchNorm — is tile-size independent, so the

@@ -199,6 +199,42 @@ def test_object_state_restore_after_failed_mid_sync_broadcast(monkeypatch):
     assert state.batch == 7 and state.params == [1.0, 2.0]
 
 
+@pytest.mark.parametrize("peer_commits, expect_sync", [(3, False), (2, True)])
+def test_pure_shrink_skips_sync_only_when_survivors_share_a_commit(
+        monkeypatch, peer_commits, expect_sync):
+    """A reshard-marked epoch without joiners may skip the state
+    broadcast only if every survivor stands on the same commit.  An
+    abort can leave them one apart (a frame corrupted on the ring's last
+    allgather hop: its sender finished and committed the step its
+    receiver rejected); then the broadcast from ``sync_root`` runs and
+    the commit clock follows the root's."""
+    from horovod_tpu.common import env as env_mod
+    from horovod_tpu.elastic import rendezvous_client, state as state_mod
+    from horovod_tpu.frameworks.jax import functions as jax_fns
+
+    monkeypatch.setenv(env_mod.HOROVOD_ELASTIC, "1")
+    monkeypatch.setattr(
+        rendezvous_client, "current_reshard_info",
+        lambda: {"epoch": 1, "sync_root": 1, "joiners": []})
+    # this process is rank 0 of two; rank 1 is the sync root
+    monkeypatch.setattr(jax_fns, "allgather_object",
+                        lambda obj, name=None: [obj, peer_commits])
+    roots = []
+
+    def fake_broadcast(values, root_rank=0, name=""):
+        roots.append(root_rank)
+        return {"batch": peer_commits}
+
+    monkeypatch.setattr(jax_fns, "broadcast_object", fake_broadcast)
+    state = ObjectState(batch=0)
+    for _ in range(3):
+        state.batch += 1
+        state.commit()
+    state_mod._sync_for_epoch(state)
+    assert roots == ([1] if expect_sync else [])
+    assert state.batch == peer_commits and state._commits == peer_commits
+
+
 def test_object_state_commit_restore_idempotent_across_epochs():
     """Two epoch transitions' worth of commit/restore churn: repeated
     restores of the same commit are idempotent, and a re-commit of an

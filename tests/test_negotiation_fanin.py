@@ -7,7 +7,7 @@ The degrade protocol's crash/reorder interleavings are model-checked in
 tests/test_mck_proto.py (hvd-mck's fanin_degrade scenario); the
 aggregator-death chaos test (abort -> reshard -> bit-identical
 convergence) lives with the other elastic proofs in
-tests/test_fault_injection.py.
+tests/test_fault_injection_elastic.py.
 """
 
 import os
