@@ -318,6 +318,7 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "program_call": ("stat", "phase_stats: calls of the framework's "
                              "jitted programs; count = output arrays"),
     "wfbp_dispatch": ("stat", "phase_stats: one OverlappedTrainStep call"),
+    "state_fuse": ("stat", "phase_stats: updates that joined a tree state"),
     "bytes_on_wire": ("stat", "wire_stats: per-frame payload bytes"),
     "heap_copies": ("stat", "wire_stats: data-plane materializations"),
     "compressed_bytes": ("stat", "wire_stats: narrow wire-dtype bytes"),

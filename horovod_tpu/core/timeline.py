@@ -351,6 +351,7 @@ PHASES = (
     "negotiate", "fuse", "collective", "unfuse", "wait",
     "update", "enqueue", "tree_unflatten", "optimizer_update",
     "queue_wait", "dispatch_wait", "program_call", "wfbp_dispatch",
+    "state_fuse",
 )
 
 
@@ -364,7 +365,9 @@ class PhaseStats:
     ``queue_wait`` (tensor queue → background loop) and ``dispatch_wait``
     (background loop → dispatcher thread).  On the calling thread:
     ``update`` (the whole of ``DistributedOptimizer.update``) with its
-    parts ``enqueue``, ``tree_unflatten`` and ``optimizer_update``;
+    parts ``enqueue``, ``tree_unflatten``, ``optimizer_update`` and
+    ``state_fuse`` (an update that was handed the inner state as a plain
+    tree and joined it; none in a steady job);
     ``wfbp_dispatch`` (one call of an ``OverlappedTrainStep``); and
     ``program_call``, nested in the others, around every call of a jitted
     program the framework owns.

@@ -19,6 +19,7 @@ no wrapper at all.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 from typing import Any, NamedTuple, Optional
@@ -290,28 +291,58 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
         with phase("optimizer_update"):
             return program_call(cached, *args)
 
-    def _update_from_buffers(bufs, pending, inner_state, params):
-        """``tx.update`` on a reduced gradient that is still its fused
-        per-dtype buffers (``wfbp.wait_buffers``): the cut back into leaves
-        happens inside the optimizer's program, where a leaf is a
-        temporary of the device and no output buffer, so the gradient
-        never becomes one array per leaf on the host.  The program closes
-        over the plan's offsets and the treedef, hence the key: a second
-        gradient tree through this instance compiles its own."""
+    def _inner_update(grads, pending, inner_state, params):
+        """``tx.update`` as one program on what lives between steps as
+        fused per-dtype buffers: the reduced gradient
+        (``wfbp.wait_buffers``; with ``pending`` None ``grads`` is the
+        tree, the runtime being down) and the inner state
+        (:class:`wfbp.FusedTree`).  Both are cut back into leaves inside
+        the program, where a leaf is a temporary of the device and no
+        output buffer, and the new state's leaves are joined there: the
+        program returns the updates and one array per dtype of the state.
+
+        A state that arrives as a plain tree (hand-built, or restored from
+        a checkpoint written before the state had this form) is taken as
+        it is and joined on the way out; ``state_fuse`` counts those
+        calls.  A state sharded over a mesh has no local join and stays a
+        tree, and so does a tree state inside a caller's ``jit`` (runtime
+        down), where the caller's program owns its outputs and a loop's
+        carry has to keep its type (``wfbp.single_device``).  The program
+        closes over the plan's offsets and the treedef,
+        hence the key: a second gradient tree through this instance
+        compiles its own."""
         import jax
 
-        unflatten, treedef = pending.plan.unflatten, pending.treedef
+        fused = isinstance(inner_state, wfbp.FusedTree)
+        join = fused or wfbp.single_device(inner_state)
+        plan, treedef, sig = (None, None, None) if pending is None \
+            else (pending.plan, pending.treedef, pending.plan.sig)
 
-        def update(b, s, p):
+        def update(g, s, p):
             # The barrier keeps ``tx.update``'s arithmetic compiled as it
             # is for per-leaf arguments: with the slices fused into it,
             # XLA's CPU backend contracts AdamW's multiply-adds otherwise
             # and the last bit differs from the tree path's.
-            leaves = jax.lax.optimization_barrier(unflatten(b))
-            return tx.update(treedef.unflatten(leaves), s, p)
+            if plan is not None:
+                g = treedef.unflatten(
+                    jax.lax.optimization_barrier(plan.unflatten(g)))
+            # The state's bits do not need theirs.  It is there for the
+            # one optimizer a cell of the benchmark runs: on the chip the
+            # program takes 1.82 ms for ResNet-50 with momentum against
+            # 2.35 ms without it (the tree path 1.16).  AdamW at the same
+            # shapes pays for it: 3.86 ms with, 3.04 without, the tree
+            # path 1.62 (``PERF.md`` section 6, PR 30; section 7 holds
+            # AdamW's 2.2 ms as an open regression of a device-paced job).
+            if isinstance(s, wfbp.FusedTree):
+                s = s.treedef.unflatten(
+                    jax.lax.optimization_barrier(s.leaves()))
+            updates, new = tx.update(g, s, p)
+            return updates, (wfbp.FusedTree.fuse(new) if join else new)
 
-        return _run("update", update, bufs, inner_state, params,
-                    key=(pending.plan.sig, treedef))
+        with phase("state_fuse") if join and not fused \
+                else contextlib.nullcontext():
+            return _run("update", update, grads, inner_state, params,
+                        key=(sig, treedef, join))
 
     def init(params):
         import jax
@@ -322,8 +353,16 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
         # transfer per leaf per step even on off-steps (VERDICT weak #6).
         acc = jax.tree_util.tree_map(jnp.zeros_like, params) \
             if n_accum > 1 else None
-        return DistributedState(inner_state=tx.init(params),
-                                accumulated=acc, counter=0)
+        if not wfbp.single_device(params):
+            # Sharded over a mesh: no local join, and eager ``tx.init``
+            # gives each leaf of the state its parameter's sharding.
+            # Traced (``jax.jit(dopt.init)``): the caller's program.
+            return DistributedState(tx.init(params), acc, 0)
+        if "init" not in _jits:
+            _jits["init"] = _named_jit(
+                "hvd_optimizer_init",
+                lambda p: wfbp.FusedTree.fuse(tx.init(p)))
+        return DistributedState(program_call(_jits["init"], params), acc, 0)
 
     # Overlap mode: in-flight microbatch windows, keyed by the window id
     # carried IN the optimizer state (PendingTree handles are
@@ -415,7 +454,7 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
                     "combine",
                     lambda *bs: tuple(sum(xs) * scale for xs in zip(*bs)),
                     *reduced)
-                updates, inner = _update_from_buffers(
+                updates, inner = _inner_update(
                     bufs, last, state.inner_state, params)
                 return updates, DistributedState(inner, state.accumulated,
                                                  0, -1)
@@ -454,12 +493,11 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
             pending = wfbp.enqueue_tree_fused(
                 grads, op_name, compression, prescale_factor,
                 postscale_factor, name_prefix=_name_root())
-            updates, inner = _update_from_buffers(
-                wfbp.wait_buffers(pending), pending, state.inner_state,
-                params)
+            grads = wfbp.wait_buffers(pending)
         else:
-            updates, inner = _run("update", tx.update, grads,
-                                  state.inner_state, params)
+            pending = None
+        updates, inner = _inner_update(grads, pending, state.inner_state,
+                                       params)
         return updates, DistributedState(inner, new_acc, count)
 
     return optax.GradientTransformation(init, update)

@@ -39,6 +39,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, NamedTuple, Optional
 
+import jax
 import numpy as np
 
 from . import ops
@@ -61,6 +62,7 @@ class FusePlan(NamedTuple):
     sig: tuple              # ((shape, dtype name), ...) per leaf
     groups: list            # [(dtype name, [leaf index, ...]), ...]
     flatten: Callable       # jitted: leaves -> one flat buffer per group
+    join: Callable          # the same, pure and traceable
     unflatten: Callable     # pure and traceable: buffers -> tuple of leaves
     unflatten_jit: Callable  # the same as a program of its own
 
@@ -101,6 +103,25 @@ def _fuse_plan(sig) -> FusePlan:
             if len(idxs) > 1 else leaves_in[idxs[0]].ravel()
             for _, idxs in groups)
 
+    def hvd_tree_join(leaves_in):
+        # The join inside a consumer's program, behind the arithmetic that
+        # makes the leaves: each is written into its place in the buffer.
+        # A ``concatenate`` is 0.17 ms faster on the chip for ResNet-50's
+        # 102 MB (``PERF.md`` section 6, PR 30), but XLA's CPU backend
+        # fuses one of up to eight operands into that arithmetic, which
+        # then rounds otherwise than when each leaf is an output of its
+        # own; it fuses nothing into these.
+        bufs = []
+        for dt, idxs in groups:
+            sizes = [int(np.prod(sig[i][0])) for i in idxs]
+            buf, off = jnp.zeros((sum(sizes),), dt), 0
+            for i, n in zip(idxs, sizes):
+                buf = jax.lax.dynamic_update_slice(
+                    buf, jnp.ravel(leaves_in[i]), (off,))
+                off += n
+            bufs.append(buf)
+        return tuple(bufs)
+
     def hvd_tree_unflatten(bufs):
         # Shapes and offsets are static, so this also traces inside a
         # consumer's program, where a leaf is no output buffer.
@@ -114,11 +135,118 @@ def _fuse_plan(sig) -> FusePlan:
                 off += n
         return tuple(outs)
 
-    cached = FusePlan(sig, groups, jax.jit(hvd_tree_flatten),
+    cached = FusePlan(sig, groups, jax.jit(hvd_tree_flatten), hvd_tree_join,
                       hvd_tree_unflatten, jax.jit(hvd_tree_unflatten))
     with _cache_lock:
         _tree_fuse_cache[sig] = cached
     return cached
+
+
+# A leaf joins its dtype group's buffer while the join costs the device
+# less than an output buffer of its own costs the host.  Both measured on
+# the chip (``PERF.md`` section 6, PR 30).  Where the host sets the pace
+# (``resnet50-eager-4chip``), cutting and joining ResNet-50's 102 MB of
+# momentum adds 0.66 ms to ``jit_hvd_optimizer_update``, 6.5 us a MB, and
+# 160 output buffers fewer save the host 12.8 ms, 80 us each: 80 / 6.5 =
+# 12.4 MB.  One more leaf beside those 161, joined against whole, says
+# that the order is right and the line is not sharp: the device's cost
+# follows the compiler's schedule more than the bytes.  At 33.5 MB whole
+# is faster on the device by 0.39 ms (momentum) and 1.69 ms (AdamW, whose
+# state is two such leaves), many times the buffers' 0.08 and 0.16 ms; at
+# 16.8 MB joined is 0.25 ms faster for momentum and 0.99 ms slower for
+# AdamW; at 8.4 MB 0.20 ms faster and 0.52 ms slower.  Above the limit a
+# leaf stays an array of its own (and a state of such leaves, AdamW's
+# moments of a large embedding, is not held twice while the program runs).
+_JOIN_LIMIT_BYTES = 12_000_000
+
+
+def _split(sig) -> tuple:
+    """``(joined, whole)``: the indices of a signature's leaves that are
+    joined into buffers and of those above :data:`_JOIN_LIMIT_BYTES`,
+    which stay whole."""
+    import jax.numpy as jnp
+
+    whole = {i for i, (shape, dt) in enumerate(sig)
+             if int(np.prod(shape)) * jnp.dtype(dt).itemsize
+             > _JOIN_LIMIT_BYTES}
+    return (tuple(i for i in range(len(sig)) if i not in whole),
+            tuple(sorted(whole)))
+
+
+@jax.tree_util.register_pytree_node_class
+class FusedTree:
+    """A pytree held as the fused per-dtype buffers of its leaves: what
+    ``DistributedOptimizer`` keeps of the inner optimizer's state between
+    steps, so that the update's program returns one array per dtype and
+    not one per leaf.  A pytree node itself: ``buffers`` (one per dtype
+    group of the joined leaves, then the leaves kept whole, see
+    :data:`_JOIN_LIMIT_BYTES`) are its children, the leaf signature and
+    the treedef its static data, so it passes through ``jit``,
+    ``tree_map``, a checkpoint and a broadcast like the tree would.
+    :meth:`unfuse` gives the tree back."""
+
+    __slots__ = ("buffers", "sig", "treedef")
+
+    def __init__(self, buffers, sig, treedef):
+        self.buffers, self.sig, self.treedef = tuple(buffers), sig, treedef
+
+    @classmethod
+    def fuse(cls, tree) -> "FusedTree":
+        """Join ``tree``'s leaves; meant to be traced into the program
+        that makes them."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        sig = _leaf_signature(leaves)
+        joined, whole = _split(sig)
+        plan = _fuse_plan(tuple(sig[i] for i in joined))
+        return cls(plan.join([leaves[i] for i in joined])
+                   + tuple(leaves[i] for i in whole), sig, treedef)
+
+    def _leaves(self, jitted: bool) -> list:
+        joined, whole = _split(self.sig)
+        plan = _fuse_plan(tuple(self.sig[i] for i in joined))
+        n = len(plan.groups)
+        cut = (plan.unflatten_jit if jitted else plan.unflatten)(
+            self.buffers[:n])
+        out = [None] * len(self.sig)
+        for i, leaf in zip(joined + whole, cut + self.buffers[n:]):
+            out[i] = leaf
+        return out
+
+    def leaves(self) -> list:
+        """The tree's leaves, cut from the buffers inside the caller's
+        program (traceable), where a leaf is no output buffer."""
+        return self._leaves(jitted=False)
+
+    def unfuse(self):
+        """The tree itself, one array per leaf, cut by a program of its
+        own (``hvd_tree_unflatten``).  Off the hot path: for whoever reads
+        the optax state (a schedule's ``count``, a moment), and for the
+        tests."""
+        return self.treedef.unflatten(self._leaves(jitted=True))
+
+    def tree_flatten(self):
+        return self.buffers, (self.sig, self.treedef)
+
+    @classmethod
+    def tree_unflatten(cls, static, buffers):
+        return cls(buffers, *static)
+
+    def __repr__(self):
+        return (f"FusedTree({len(self.sig)} leaves in "
+                f"{len(self.buffers)} arrays)")
+
+
+def single_device(tree) -> bool:
+    """Whether every ``jax.Array`` of ``tree`` lies whole on one device of
+    this process: what a local join needs.  A leaf sharded over a mesh has
+    no such copy, and a tracer has no device at all: inside a caller's
+    ``jit`` a leaf is a temporary of that program already, and the tree
+    keeps the form it came in."""
+    return all(not isinstance(l, jax.core.Tracer)
+               and l.is_fully_addressable
+               and len(l.sharding.device_set) == 1
+               for l in jax.tree_util.tree_leaves(tree)
+               if isinstance(l, jax.Array))
 
 
 class PendingTree(NamedTuple):

@@ -16,8 +16,8 @@ import pytest
 from .helpers import reserve_port, run_distributed
 
 # The tree: four float32 leaves, so one fused buffer, one request, one
-# response a step.  SGD with momentum: ``tx.update`` returns a delta and a
-# momentum per leaf.
+# response a step.  SGD with momentum: ``tx.update`` returns a delta per
+# leaf and the momentum as one buffer (the state lives fused, ISSUE 30).
 LEAVES = 4
 
 WORKER = """
@@ -120,13 +120,14 @@ def report():
 
 
 # What one plain step at np=1 adds to each phase's count.  program_call
-# counts output arrays: flatten 1 + allreduce 1 + tx.update 2 * LEAVES; the
+# counts output arrays: flatten 1 + allreduce 1 + tx.update LEAVES + 1; the
 # reduced buffer goes into tx.update's program uncut (ISSUE 26), so no
-# step enters tree_unflatten.
+# step enters tree_unflatten, and the state comes and goes as its buffers
+# (ISSUE 30), so no step after init enters state_fuse.
 STEP_COUNTS = {
     "update": 1, "fuse": 1, "enqueue": 1, "queue_wait": 1, "negotiate": 1,
     "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 0,
-    "optimizer_update": 1, "program_call": 2 + 2 * LEAVES,
+    "state_fuse": 0, "optimizer_update": 1, "program_call": 2 + LEAVES + 1,
 }
 
 
@@ -142,17 +143,18 @@ def test_one_step_enters_no_other_phase(report):
     entered = {k for k, v in report["step"].items() if v["count"]}
     assert entered == {k for k, n in STEP_COUNTS.items() if n}
     assert entered < set(PHASES)
-    # The rest belong to the dispatcher thread, the one-program path and
-    # the entry points that return a gradient tree.
+    # The rest belong to the dispatcher thread, the one-program path, the
+    # entry points that return a gradient tree and an update that is
+    # handed the optimizer's state as a plain tree.
     assert set(PHASES) - entered == {"dispatch_wait", "wfbp_dispatch",
-                                     "tree_unflatten"}
+                                     "tree_unflatten", "state_fuse"}
 
 
 def test_program_call_counts_outputs_worked_out_from_the_tree(report):
     groups = 1                                  # one dtype
     expect = groups + groups + report["update_outputs"]
-    assert report["update_outputs"] == 2 * LEAVES
-    assert report["step"]["program_call"]["count"] == expect == 10
+    assert report["update_outputs"] == LEAVES + groups
+    assert report["step"]["program_call"]["count"] == expect == 7
 
 
 # distributed_value_and_grad hands the tree to the user, so it alone still
@@ -412,7 +414,7 @@ def test_phases_are_the_catalogued_and_documented_names():
     from horovod_tpu.core import metrics
     from horovod_tpu.core.timeline import PHASES
 
-    assert len(set(PHASES)) == len(PHASES) == 13
+    assert len(set(PHASES)) == len(PHASES) == 14
     doc = open(os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "docs", "observability.md")).read()
     section = doc.split("## Reading a step on the profiler's clock")[1] \
