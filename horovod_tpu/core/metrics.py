@@ -222,6 +222,13 @@ CATALOG: Dict[str, Tuple[str, str]] = {
         "gauge", "routed rows a step (k * tokens, summed over layers)"),
     "moe_steps": (
         "gauge", "steps the router's counters have counted"),
+    "moe_rows_held_per_step": (
+        "gauge", "where a layer holds a share of the experts "
+                 "(moe_ffn(held=...)): routed rows a step whose expert "
+                 "lives here, per layer="),
+    "moe_rows_elsewhere_share": (
+        "gauge", "share of a layer's routed rows bound for experts that "
+                 "live elsewhere (counted, not computed), per layer="),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

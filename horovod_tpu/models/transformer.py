@@ -16,21 +16,25 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   axis bound (see :mod:`horovod_tpu.models.training`);
 - optional ``lax.scan``-friendly uniform blocks + remat for HBM headroom;
 - the block's parts are options of one config (norm kind, learned or rotary
-  positions, QK-norm, biases, tied or untied head, dense or sparse-expert
-  FFN): OLMoE is ``olmoe_1b_7b_config()`` over the same ``Transformer``, its
-  expert layer :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``).
+  positions, QK-norm over the projection or per head, grouped KV heads, an
+  explicit head width, biases, tied or untied head, dense or sparse-expert
+  FFN, all experts or a share of them, causal, unmasked or block-diffusion
+  attention): OLMoE is ``olmoe_1b_7b_config()`` and SDAR-30B-A3B
+  ``sdar_30b_a3b_config()`` over the same ``Transformer``, their expert layer
+  :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels import blockdiff_attention as blockdiff
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..parallel.moe import MoEStats, moe_ffn
 
@@ -54,7 +58,10 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     positions: str = "learned"        # learned (a table of max_len) | rope
     rope_theta: float = 10000.0
-    qk_norm: bool = False             # `norm` over the projected q and k
+    # `norm` over the projected q and k: True over the whole projection (all
+    # heads, OLMoE), "head" over each head's width with one scale the heads
+    # share (SDAR, Qwen3).
+    qk_norm: Any = False
     use_bias: bool = True
     tie_embeddings: bool = True       # False: an untied ``lm_head``
     ffn: str = "gelu"                 # gelu (dense, d_ff) | moe
@@ -65,10 +72,26 @@ class TransformerConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_data_axis: Optional[str] = None
+    # The ids of the experts that live here, where a layer's experts are
+    # shared among chips (None: all num_experts); the router keeps its
+    # num_experts outputs.  norm_topk_prob: a token's k weights sum to 1.
+    experts_held: Optional[Tuple[int, ...]] = None
+    norm_topk_prob: bool = False
+    # Grouped-query attention: num_kv_heads KV heads, each serving
+    # num_heads / num_kv_heads query heads (None: as many as heads, one fused
+    # qkv projection).  head_width: a head's width where it is not
+    # d_model / num_heads.
+    num_kv_heads: Optional[int] = None
+    head_width: Optional[int] = None
+    # Block-diffusion training (kernels/blockdiff_attention.py): blocks of
+    # this many tokens; the model then takes [x_t ; x_0], 2L positions, gives
+    # both halves the indices 0..L-1, masks attention by the block rule in
+    # place of `causal`, and returns the noisy half's logits.  0: off.
+    block_diffusion: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_width or self.d_model // self.num_heads
 
 
 def bert_large_config(**overrides) -> TransformerConfig:
@@ -94,6 +117,22 @@ def olmoe_1b_7b_config(**overrides) -> TransformerConfig:
         positions="rope", rope_theta=10000.0, qk_norm=True, use_bias=False,
         tie_embeddings=False, ffn="moe", num_experts=64,
         experts_per_token=8), **overrides})
+
+
+def sdar_30b_a3b_config(**overrides) -> TransformerConfig:
+    """SDAR-30B-A3B-Chat (JetLM/SDAR-30B-A3B-Chat ``config.json``,
+    ``sdar_moe``): 48 layers of 128 experts of width 768, 8 a token with
+    renormalised weights, 32 query heads on 4 KV heads of 128 (not
+    2048 / 32), per-head QK-norm, RoPE at 1e6, RMSNorm, no biases, untied
+    head; trained by block diffusion (``block_diffusion``: the block length
+    is not in ``config.json``; 4 is the released chat models' default)."""
+    return TransformerConfig(**{**dict(
+        vocab_size=151936, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_width=128, d_model=2048, d_ff=768, max_len=32768, causal=False,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1e6,
+        qk_norm="head", use_bias=False, tie_embeddings=False, ffn="moe",
+        num_experts=128, experts_per_token=8, norm_topk_prob=True,
+        block_diffusion=4), **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -122,12 +161,15 @@ def _norm(cfg: TransformerConfig, name: str):
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
-def _rope(x, theta: float):
+def _rope(x, theta: float, positions=None):
     """Rotary positions on ``[b, s, h, d]``, halves rotated as in
-    ``transformers`` (``x*cos + rotate_half(x)*sin``), in fp32."""
+    ``transformers`` (``x*cos + rotate_half(x)*sin``), in fp32.  ``positions``
+    ``[s]``: each position's index (default ``0..s-1``)."""
     s, d = x.shape[1], x.shape[3]
     inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None]
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -139,23 +181,40 @@ class Attention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.cfg
         b, s, _ = x.shape
         h, dh = cfg.num_heads, cfg.head_dim
-        # Column-parallel qkv: heads split over the model axis.
-        qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis), "qkv")(x)
-        q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
-        if cfg.qk_norm:
+        h_kv = cfg.num_kv_heads or h
+        if cfg.num_kv_heads is None:
+            # Column-parallel qkv: heads split over the model axis.
+            qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis), "qkv")(x)
+            q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
+        else:
+            if h % h_kv:
+                raise ValueError(f"{h} heads on {h_kv} KV heads")
+            q = _dense(cfg, h * dh, (None, cfg.model_axis), "q")(x) \
+                .reshape(b, s, h, dh)
+            kv = _dense(cfg, 2 * h_kv * dh, (None, cfg.model_axis), "kv")(x)
+            k, v = jnp.split(kv.reshape(b, s, 2 * h_kv, dh), 2, axis=2)
+        if cfg.qk_norm == "head":
+            # Over each head's width; the heads share the scale.
+            q = _norm(cfg, "q_norm")(q).astype(cfg.dtype)
+            k = _norm(cfg, "k_norm")(k).astype(cfg.dtype)
+        elif cfg.qk_norm:
             # Over the whole projection (all heads), as OLMoE has it.
-            flat = lambda t: t.reshape(b, s, h * dh)  # noqa: E731
+            flat = lambda t: t.reshape(b, s, -1)  # noqa: E731
             q = _norm(cfg, "q_norm")(flat(q)).astype(cfg.dtype)
             k = _norm(cfg, "k_norm")(flat(k)).astype(cfg.dtype)
-            q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h, dh)
+            q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h_kv, dh)
         if cfg.positions == "rope":
             if cfg.attention != "full":
                 raise ValueError("rope positions need attention='full'")
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            q = _rope(q, cfg.rope_theta, positions)
+            k = _rope(k, cfg.rope_theta, positions)
+        if (h_kv != h or cfg.block_diffusion) and cfg.attention != "full":
+            raise ValueError("grouped KV heads and the block-diffusion mask "
+                             "need attention='full'")
 
         if cfg.attention == "ring":
             from ..parallel.ring_attention import ring_attention
@@ -168,7 +227,8 @@ class Attention(nn.Module):
             out = ulysses_attention(q, k, v, axis_name=cfg.seq_axis,
                                     causal=cfg.causal)
         elif cfg.attention == "full":
-            out = _scaled_dot_attention(q, k, v, cfg.causal, dh)
+            out = _scaled_dot_attention(q, k, v, cfg.causal, dh,
+                                        block_diffusion=cfg.block_diffusion)
         else:
             raise ValueError(f"unknown attention mode {cfg.attention!r}")
 
@@ -206,13 +266,44 @@ def _flash_attention(q, k, v, causal: bool, dh: int):
     return o.transpose(0, 2, 1, 3)
 
 
-def _scaled_dot_attention(q, k, v, causal: bool, dh: int):
+def _blockdiff_einsum(q, k, v, dh: int, block: int):
+    """The block-diffusion mask through the einsum, KV heads grouped: below
+    the kernel's smallest shape, and off the TPU."""
+    b, s, h, _ = q.shape
+    h_kv = k.shape[2]
+    q = q.reshape(b, s, h_kv, h // h_kv, dh)
+    scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
+                        preferred_element_type=jnp.float32) * dh ** -0.5
+    mask = blockdiff.block_diffusion_mask(
+        lax.broadcasted_iota(jnp.int32, (s, s), 0),
+        lax.broadcasted_iota(jnp.int32, (s, s), 1), s // 2, block)
+    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bngqk,bknd->bqngd", probs, v).reshape(b, s, h, dh)
+
+
+def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
+                          block_diffusion: int = 0):
     """Single-device attention for the "full" mode, [b, s, h, d] layout: the
     XLA-fused einsum softmax, and on a TPU from ``_FLASH_MIN_SEQ`` positions
-    on the pallas flash-attention kernel, picked from the shape alone.  A
-    kernel that fails to lower fails the step: it is never silently the
-    einsum."""
+    on the pallas flash-attention kernel, picked from the shape alone.  With
+    ``block_diffusion`` (a block length) the mask is the block-diffusion
+    rule in place of ``causal``: on a TPU the kernel of
+    ``kernels/blockdiff_attention.py`` wherever it takes the shape, else the
+    einsum under the same mask.  A kernel that fails to lower fails the step:
+    it is never silently the einsum."""
     s = q.shape[1]
+    if block_diffusion:
+        if jax.default_backend() == "tpu" \
+                and blockdiff.takes(s, dh, block_diffusion):
+            return blockdiff.blockdiff_attention(q, k, v,
+                                                 block=block_diffusion)
+        return _blockdiff_einsum(q, k, v, dh, block_diffusion)
+    if k.shape[2] != q.shape[2]:
+        # Grouped KV heads under `causal` or no mask: each KV head repeated
+        # for the query heads it serves, then the paths below.
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
             and s % _FLASH_BLOCK == 0 and dh % 128 == 0:
         return _flash_attention(q, k, v, causal, dh)
@@ -232,9 +323,9 @@ class Block(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(_norm(cfg, "ln1")(x))
+        x = x + Attention(cfg, name="attn")(_norm(cfg, "ln1")(x), positions)
         y = _norm(cfg, "ln2")(x)
         if cfg.ffn == "gelu":
             y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis), "ffn_in")(y)
@@ -250,15 +341,21 @@ class Block(nn.Module):
         """The sparse-expert FFN; its MoEStats are sown into the ``moe``
         collection (``apply(..., mutable=["moe"])``, then ``moe_stats``)."""
         cfg = self.cfg
-        e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+        d, f = cfg.d_model, cfg.d_ff
+        # The router is as wide as the model has experts; the stacks hold
+        # the experts that live here.
+        routed = cfg.num_experts
+        e = routed if cfg.experts_held is None else len(cfg.experts_held)
         init = nn.initializers.normal(0.02)
         weights = [self.param(name, init, shape, jnp.float32)
-                   for name, shape in (("router", (d, e)),
+                   for name, shape in (("router", (d, routed)),
                                        ("experts_gate", (e, d, f)),
                                        ("experts_up", (e, d, f)),
                                        ("experts_down", (e, f, d)))]
         y, stats = moe_ffn(y, *weights, k=cfg.experts_per_token,
-                           data_axis=cfg.moe_data_axis, dtype=cfg.dtype)
+                           data_axis=cfg.moe_data_axis, dtype=cfg.dtype,
+                           held=cfg.experts_held,
+                           norm_topk_prob=cfg.norm_topk_prob)
         self.sow("moe", "stats", stats)
         return y
 
@@ -273,12 +370,18 @@ def moe_stats(collection) -> MoEStats:
 
 
 class Transformer(nn.Module):
-    """Token ids ``[batch, seq]`` → logits ``[batch, seq, vocab]``."""
+    """Token ids ``[batch, seq]`` → logits ``[batch, seq, vocab]``.
+
+    ``positions`` ``[seq]``: each position's index for the rotary embedding
+    (default ``0..seq-1``).  With ``cfg.block_diffusion`` the tokens are
+    ``[x_t ; x_0]``, both halves count ``0..seq/2-1`` unless told otherwise,
+    and the logits are the noisy half's, ``[batch, seq/2, vocab]``: the clean
+    half's enter no loss."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, train: bool = True):
+    def __call__(self, tokens, train: bool = True, positions=None):
         cfg = self.cfg
         embed = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
@@ -293,11 +396,19 @@ class Transformer(nn.Module):
             x = x + self._learned_positions(s).astype(cfg.dtype)
         elif cfg.positions != "rope":
             raise ValueError(f"unknown positions {cfg.positions!r}")
+        if cfg.block_diffusion:
+            if s % 2 or cfg.positions != "rope":
+                raise ValueError("block diffusion takes [x_t ; x_0], an even "
+                                 "number of positions, and rotary positions")
+            if positions is None:
+                positions = jnp.arange(s) % (s // 2)
         block = Block
         if cfg.remat:
             block = nn.remat(Block)
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"layer_{i}")(x)
+            x = block(cfg, name=f"layer_{i}")(x, positions)
+        if cfg.block_diffusion:
+            x = x[:, :s // 2]
         x = _norm(cfg, "ln_f")(x)
         if cfg.tie_embeddings:
             # Weight-tied readout against the (model-axis-sharded) embedding.

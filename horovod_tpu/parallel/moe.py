@@ -15,16 +15,19 @@ shapes static for XLA.
 every device, top k of many, and no capacity: the routed rows are sorted by
 expert and multiplied through a grouped matmul (``jax.lax.ragged_dot``), so
 every token reaches its k experts whatever the imbalance, and the work is the k
-routed rows a token, not one per expert.
+routed rows a token, not one per expert.  Told which experts it ``held``
+(SDAR-30B-A3B: 16 of 128, a layer's experts shared among 8 chips), it routes
+over all of them and computes the part of the result its own experts give.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -130,16 +133,19 @@ _slots_to_rows.defvjp(lambda y, order, inverse: (y[inverse], order),
                       lambda order, g: (g[order], None, None))
 
 
-def _moe_rows(x, router, gate, up, down, *, k, dtype):
-    """:func:`moe_ffn` on the rows of one rank, routed as one set."""
-    rows, tokens, d = x.shape
-    n, n_experts = rows * tokens, router.shape[-1]
-    xf = x.reshape(n, d)
+def _route(xf, router, k, norm_topk_prob=False):
+    """The router on rows ``xf [n, d]``, in fp32: each row's k weights and
+    experts ``[n, k]``, the rows routed to each expert ``[experts]``, the
+    load-balancing loss and the z-loss, all over every expert of the
+    router."""
+    n, n_experts = xf.shape[0], router.shape[-1]
     with jax.named_scope("hvd.moe.router"):
         logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = lax.top_k(probs, k)                 # [n, k]
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         counts = jnp.sum(jax.nn.one_hot(experts, n_experts, dtype=jnp.int32),
                          axis=(0, 1))                          # [experts]
         # Switch's loss over top-k (transformers' load_balancing_loss_func):
@@ -147,6 +153,16 @@ def _moe_rows(x, router, gate, up, down, *, k, dtype):
         balance = n_experts * jnp.sum(counts.astype(jnp.float32) / n
                                       * jnp.mean(probs, axis=0))
         z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    return weights, experts, counts, balance, z
+
+
+def _moe_rows(x, router, gate, up, down, *, k, dtype, norm_topk_prob=False):
+    """:func:`moe_ffn` on the rows of one rank, routed as one set."""
+    rows, tokens, d = x.shape
+    n = rows * tokens
+    xf = x.reshape(n, d)
+    weights, experts, counts, balance, z = _route(xf, router, k,
+                                                  norm_topk_prob)
     with jax.named_scope("hvd.moe.dispatch"):
         order = jnp.argsort(experts.reshape(n * k))      # stable: by expert
         inverse = jnp.argsort(order)
@@ -164,17 +180,207 @@ def _moe_rows(x, router, gate, up, down, *, k, dtype):
             MoEStats(balance[None], z[None], counts[None]))
 
 
+# -- a share of the experts ---------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _spread(x, token, valid, pos, here, k):
+    """``x[token]``: the rows of the routed slots that sit at one chunk of the
+    sorted places, zero where the place is unused.  The cotangent is a gather
+    through ``pos`` (each slot's place in the chunk, where it is ``here``) and
+    a sum over k, as :func:`_rows_to_slots` has it."""
+    return jnp.where(valid[:, None], x[token], 0)
+
+
+def _spread_fwd(x, token, valid, pos, here, k):
+    return _spread(x, token, valid, pos, here, k), (pos, here)
+
+
+def _spread_bwd(k, res, g):
+    pos, here = res
+    n = pos.shape[0] // k
+    picked = jnp.where(here[:, None], g[pos], 0).reshape(n, k, -1)
+    return (picked.sum(axis=1, dtype=jnp.float32).astype(g.dtype),
+            None, None, None, None)
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine(out, ws, slot, valid, pos, here, k):
+    """``y[t] = sum_j ws[t*k+j] * out[pos[t*k+j]]`` over a token's slots that
+    are ``here``, in fp32.  Backward, both cotangents are taken at the chunk's
+    places (a gather of ``g`` rows by token), so nothing of ``[tokens*k, d]``
+    is kept for it."""
+    n = ws.shape[0] // k
+    picked = jnp.where(here[:, None], out[pos], 0).reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
+                      ws.reshape(n, k))
+
+
+def _combine_fwd(out, ws, slot, valid, pos, here, k):
+    return (_combine(out, ws, slot, valid, pos, here, k),
+            (out, ws, slot, valid, pos, here))
+
+
+def _combine_bwd(k, res, g):
+    out, ws, slot, valid, pos, here = res
+    g_rows = jnp.where(valid[:, None], g[slot // k], 0)        # [cap, d] fp32
+    d_out = (g_rows * ws[slot][:, None]).astype(out.dtype)
+    d_ws = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)  # [cap]
+    return (d_out, jnp.where(here, d_ws[pos], 0).astype(ws.dtype),
+            None, None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _held_chunk(xf, ws, gate, up, down, order, inverse, sizes, lo, *, k, cap,
+                dtype):
+    """What the held experts give for the routed slots at sorted places
+    ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``sizes`` are the held
+    experts' row counts over the whole step; this chunk takes of each what
+    falls inside it."""
+    ends = jnp.cumsum(sizes)
+    group = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+    filled = jnp.sum(group)
+    with jax.named_scope("hvd.moe.dispatch"):
+        slot = lax.dynamic_slice_in_dim(order, lo, cap)
+        valid = jnp.arange(cap) < filled
+        pos = inverse - lo
+        here = (pos >= 0) & (pos < filled)
+        pos = jnp.clip(pos, 0, cap - 1)
+        rows_in = _spread(xf, slot // k, valid, pos, here, k)
+    with jax.named_scope("hvd.moe.experts"):
+        grouped = functools.partial(lax.ragged_dot, group_sizes=group,
+                                    preferred_element_type=dtype)
+        hidden = jax.nn.silu(grouped(rows_in, gate)) * grouped(rows_in, up)
+        out = grouped(hidden, down)                            # [cap, d]
+    with jax.named_scope("hvd.moe.combine"):
+        return _combine(out, ws, slot, valid, pos, here, k)
+
+
+def _overflow_chunks(chunk, chunks: int, cap: int):
+    """The chunks after the first, each run only when the held rows reach it
+    (``lax.cond``), and then recomputed in the backward pass instead of kept:
+    a step whose routing stays inside the first chunk pays nothing for them,
+    and no routing drops a row."""
+
+    def reached(rows_held, j):
+        return rows_held > j * cap
+
+    @jax.custom_vjp
+    def run(xf, ws, gate, up, down, order, inverse, sizes):
+        rows_held = jnp.sum(sizes)
+
+        def body(y, j):
+            return lax.cond(
+                reached(rows_held, j),
+                lambda: y + chunk(xf, ws, gate, up, down, order, inverse,
+                                  sizes, j * cap),
+                lambda: y), None
+
+        # Zeros that vary over a mesh axis wherever the rows do (under
+        # moe_ffn's shard_map the branches' types would differ otherwise).
+        y, _ = lax.scan(body, (xf * 0).astype(jnp.float32),
+                        jnp.arange(1, chunks))
+        return y
+
+    def fwd(*operands):
+        return run(*operands), operands
+
+    def bwd(operands, g):
+        weights, (order, inverse, sizes) = operands[:5], operands[5:]
+        rows_held = jnp.sum(sizes)
+
+        def body(acc, j):
+            def more():
+                _, vjp = jax.vjp(
+                    lambda *w: chunk(*w, order, inverse, sizes, j * cap),
+                    *weights)
+                return tuple(a + d for a, d in zip(acc, vjp(g)))
+
+            return lax.cond(reached(rows_held, j), more, lambda: acc), None
+
+        acc, _ = lax.scan(body, tuple(w * 0 for w in weights),
+                          jnp.arange(1, chunks))
+        return (*acc, None, None, None)
+
+    run.defvjp(fwd, bwd)
+    return run
+
+
+def row_buffer(slots: int, n_held: int, n_experts: int):
+    """(chunks, rows a chunk) for ``slots`` routed rows of which the
+    ``n_held`` of ``n_experts`` held here take ``n_held / n_experts`` on
+    average: one chunk of twice that share, and as many more behind it as the
+    worst routing (every row here) needs."""
+    chunks = max(1, n_experts // (2 * n_held))
+    if slots % chunks:
+        chunks = 1
+    return chunks, slots // chunks
+
+
+def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
+                    norm_topk_prob):
+    """:func:`moe_ffn` on the rows of one rank where only ``held`` of the
+    router's experts live here."""
+    rows, tokens, d = x.shape
+    n, n_experts = rows * tokens, router.shape[-1]
+    held = tuple(held)
+    if len(held) != gate.shape[0] or len(set(held)) != len(held) \
+            or not all(0 <= e < n_experts for e in held):
+        raise ValueError(f"held experts {held} for {gate.shape[0]} stacked "
+                         f"experts and a router of {n_experts}")
+    xf = x.reshape(n, d)
+    weights, experts, counts, balance, z = _route(xf, router, k,
+                                                  norm_topk_prob)
+    with jax.named_scope("hvd.moe.dispatch"):
+        # Each routed slot's expert as its index among the held ones; the
+        # slots bound elsewhere sort behind them all.  By comparison with
+        # every held id: a table lookup is a gather of tokens * k scalars
+        # (1.0 ms a layer on a v5e at 131,072 slots; PERF.md, PR 31).
+        match = experts.reshape(n * k, 1) == np.asarray(held, np.int32)
+        local = jnp.where(jnp.any(match, axis=1), jnp.argmax(match, axis=1),
+                          len(held))
+        order = jnp.argsort(local)
+        inverse = jnp.argsort(order)
+        sizes = counts[np.asarray(held)]
+    chunks, cap = row_buffer(n * k, len(held), n_experts)
+    chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype)
+    operands = (xf.astype(dtype), weights.reshape(n * k), gate.astype(dtype),
+                up.astype(dtype), down.astype(dtype), order, inverse, sizes)
+    y = chunk(*operands, 0)
+    if chunks > 1:
+        y = y + _overflow_chunks(chunk, chunks, cap)(*operands)
+    return (y.astype(dtype).reshape(rows, tokens, d),
+            MoEStats(balance[None], z[None], counts[None]))
+
+
 def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
             down: jax.Array, *, k: int, data_axis: Optional[str] = None,
-            dtype=jnp.bfloat16):
+            dtype=jnp.bfloat16, held: Optional[Sequence[int]] = None,
+            norm_topk_prob: bool = False):
     """Dropless top-k expert layer: ``sum_j p_j * down_j(silu(gate_j x) *
     up_j x)`` over a token's k most probable experts, the probabilities a
-    softmax over all experts and not renormalised.
+    softmax over all experts, renormalised over the k only with
+    ``norm_topk_prob``.
 
     - ``x``: ``[rows, tokens, d]``;
     - ``router``: ``[d, experts]``; logits, softmax and top-k run in fp32;
     - ``gate``, ``up``: ``[experts, d, width]``; ``down``:
-      ``[experts, width, d]``; multiplied in ``dtype``.
+      ``[experts, width, d]``; multiplied in ``dtype``;
+    - ``held``: the ids of the experts that live here, in the order of the
+      stacks, where a layer's experts are shared among chips (default: all,
+      and the layer lowers to what it lowered to without the option).  The
+      router, its softmax, the top k, the renormalisation, the counts and the
+      auxiliary losses are over all experts wherever they live; only the rows
+      routed to a held expert are sorted and multiplied, and the sum returned
+      is those experts' part of the layer: what the absent ones add is added
+      by whoever holds them (on one chip, by no one).  No row bound here is
+      dropped: the rows are taken in chunks of :func:`row_buffer`'s size, the
+      first always, the others when the routing reaches them.
 
     All of the rows given are routed as one set: sorted by expert, multiplied
     by a grouped matmul, brought back.  The auxiliary losses are taken over
@@ -188,7 +394,12 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
 
     Returns ``(y [rows, tokens, d] in dtype, MoEStats)``.
     """
-    body = functools.partial(_moe_rows, k=k, dtype=dtype)
+    if held is None:
+        body = functools.partial(_moe_rows, k=k, dtype=dtype,
+                                 norm_topk_prob=norm_topk_prob)
+    else:
+        body = functools.partial(_moe_rows_share, k=k, dtype=dtype, held=held,
+                                 norm_topk_prob=norm_topk_prob)
     if data_axis is None or \
             data_axis not in jax.sharding.get_abstract_mesh().axis_names:
         return body(x, router, gate, up, down)
@@ -199,25 +410,46 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
     )(x, router, gate, up, down)
 
 
-def moe_counters(n_layers: int, n_experts: int) -> dict:
-    """Zeroed router counters for a step's ``aux``."""
-    return {"tokens_per_expert": jnp.zeros((n_layers, n_experts), jnp.int32),
-            "steps": jnp.zeros((), jnp.int32)}
+def moe_counters(n_layers: int, n_experts: int, share: bool = False) -> dict:
+    """Zeroed router counters for a step's ``aux``; with ``share`` also the
+    rows routed to the experts held here and those bound elsewhere, a
+    layer."""
+    counters = {"tokens_per_expert":
+                jnp.zeros((n_layers, n_experts), jnp.int32),
+                "steps": jnp.zeros((), jnp.int32)}
+    if share:
+        counters["rows_held"] = jnp.zeros((n_layers,), jnp.int32)
+        counters["rows_elsewhere"] = jnp.zeros((n_layers,), jnp.int32)
+    return counters
 
 
-def count_routing(counters: dict, tokens_per_expert: jax.Array) -> dict:
+def count_routing(counters: dict, tokens_per_expert: jax.Array,
+                  held: Optional[Sequence[int]] = None) -> dict:
     """``counters`` after one more step that routed ``tokens_per_expert``
-    ``[layers, experts]``; runs inside the step, on the device."""
-    return {"tokens_per_expert":
-            counters["tokens_per_expert"] + tokens_per_expert,
-            "steps": counters["steps"] + 1}
+    ``[layers, experts]``; runs inside the step, on the device.  ``held``:
+    the ids of the experts that live here, for counters made with
+    ``share``."""
+    out = {"tokens_per_expert":
+           counters["tokens_per_expert"] + tokens_per_expert,
+           "steps": counters["steps"] + 1}
+    if held is not None:
+        here = jnp.sum(tokens_per_expert[:, np.asarray(held)], axis=1)
+        out["rows_held"] = counters["rows_held"] + here
+        out["rows_elsewhere"] = counters["rows_elsewhere"] \
+            + jnp.sum(tokens_per_expert, axis=1) - here
+    return out
 
 
 def publish_routing(counters: dict) -> dict:
     """Read the counters to the host (outside the step: it waits for the
     device) and set the gauges ``moe_max_load_ratio`` (busiest expert over the
-    mean, per layer), ``moe_routed_tokens_per_step`` and ``moe_steps``.
-    Returns ``{"max_load_ratio": [per layer], "steps": n}``."""
+    mean, per layer), ``moe_routed_tokens_per_step`` and ``moe_steps``; for
+    counters of a share of the experts also, per layer,
+    ``moe_rows_held_per_step`` (rows the experts here multiplied) and
+    ``moe_rows_elsewhere_share`` (the share of the routed rows bound for
+    experts that live elsewhere).
+    Returns ``{"max_load_ratio": [per layer], "steps": n}``, with
+    ``"rows_held_per_step"`` and ``"rows_elsewhere_share"`` for a share."""
     import numpy as np
 
     from ..core import metrics
@@ -231,4 +463,18 @@ def publish_routing(counters: dict) -> dict:
     metrics.set_gauge("moe_routed_tokens_per_step",
                       float(counts.sum()) / max(steps, 1))
     metrics.set_gauge("moe_steps", steps)
-    return {"max_load_ratio": ratios, "steps": steps}
+    out = {"max_load_ratio": ratios, "steps": steps}
+    if "rows_held" in counters:
+        here = np.asarray(counters["rows_held"], dtype=np.float64)
+        away = np.asarray(counters["rows_elsewhere"], dtype=np.float64)
+        out["rows_held_per_step"] = [float(h) / max(steps, 1) for h in here]
+        out["rows_elsewhere_share"] = [
+            float(a / (h + a)) if h + a else float("nan")
+            for h, a in zip(here, away)]
+        for layer, (per_step, share) in enumerate(zip(
+                out["rows_held_per_step"], out["rows_elsewhere_share"])):
+            metrics.set_gauge("moe_rows_held_per_step", per_step,
+                              layer=str(layer))
+            metrics.set_gauge("moe_rows_elsewhere_share", share,
+                              layer=str(layer))
+    return out

@@ -105,3 +105,64 @@ def test_expert_layer_compiles_at_published_widths(one_chip,
     routed = 9 * 2 * (8192 * k) * d * f
     flops = compiled.cost_analysis()["flops"]
     assert routed < flops < 1.15 * routed, (flops, routed)
+
+
+def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
+                                                     no_compile_cache):
+    """One sequence as [x_t ; x_0], 16384 positions, 32 query heads on 4 KV
+    heads of 128, blocks of 4: forward and both backward kernels, with the
+    tiles ``kernels/blockdiff_attention.py`` gives them, and no [2L, 2L]
+    table or score square in the program."""
+    from horovod_tpu.kernels import blockdiff_attention as bd
+
+    q = _shape((1, 16384, 32, 128), jnp.bfloat16, one_chip)
+    kv = _shape((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(bd.blockdiff_attention(q, k, v, block=4)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals",
+                       "splash_mha_dq_no_residuals",
+                       "splash_mha_dkv_no_residuals"}, kernels
+    assert all(re.match(bd.OP_LINE_NAMES, k) for k in kernels)
+    assert "16384,16384" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_expert_share_compiles_at_published_widths(one_chip,
+                                                   no_compile_cache):
+    """16384 positions through the 16 held of 128 experts of 2048 x 768, 8 a
+    token: the first chunk's nine grouped products over a quarter of the
+    slots, the chunks behind it under a conditional, and gathers in both
+    directions."""
+    from horovod_tpu.parallel.moe import moe_ffn, row_buffer
+
+    d, f, e, held, k = 2048, 768, 128, 16, 8
+    args = [_shape((1, 16384, d), jnp.bfloat16, one_chip),
+            _shape((d, e), jnp.float32, one_chip),
+            _shape((held, d, f), jnp.float32, one_chip),
+            _shape((held, d, f), jnp.float32, one_chip),
+            _shape((held, f, d), jnp.float32, one_chip)]
+    assert row_buffer(16384 * k, held, e) == (4, 32768)
+
+    def loss(*a):
+        y, stats = moe_ffn(*a, k=k, held=tuple(range(held)),
+                           norm_topk_prob=True)
+        return jnp.sum(y.astype(jnp.float32)) \
+            + jnp.sum(stats.load_balancing_loss)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    # 9 of the first chunk; the chunks behind it add their 3 forward and, in
+    # the backward pass, the same 3 again (recomputed, not kept) and 6 more
+    # (the compiler may share the forward ones).
+    assert len(re.findall(r"%ragged-dot-none[.\d]* =", text)) in (18, 21)
+    assert " conditional(" in text
+    assert not re.findall(r"= \w+\[\d+,2048\]\S* scatter\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
