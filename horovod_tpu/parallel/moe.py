@@ -183,82 +183,92 @@ def _moe_rows(x, router, gate, up, down, *, k, dtype, norm_topk_prob=False):
 # -- a share of the experts ---------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _spread(x, token, valid, pos, here, k):
+def _rows_to_tokens(rows, token, tokens):
+    """``rows [cap, d]`` (fp32) added up by ``token [cap]`` into ``[tokens,
+    d]``: one scatter-add of the chunk's rows, in the order of the places,
+    where a gather the other way round fetches a row for each of the
+    ``tokens * k`` slots.  A row whose token is ``tokens`` (an unused place)
+    is dropped."""
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token].add(
+        rows, mode="drop")
+
+
+@jax.custom_vjp
+def _spread(x, token):
     """``x[token]``: the rows of the routed slots that sit at one chunk of the
-    sorted places, zero where the place is unused.  The cotangent is a gather
-    through ``pos`` (each slot's place in the chunk, where it is ``here``) and
-    a sum over k, as :func:`_rows_to_slots` has it."""
-    return jnp.where(valid[:, None], x[token], 0)
+    sorted places, a zero row where the place is unused (``token`` is then
+    one past the last).  The cotangent adds the chunk's rows up by token in
+    fp32 (:func:`_rows_to_tokens`), where autodiff would add in ``x``'s
+    dtype."""
+    return x.at[token].get(mode="fill", fill_value=0)
 
 
-def _spread_fwd(x, token, valid, pos, here, k):
-    return _spread(x, token, valid, pos, here, k), (pos, here)
+def _spread_fwd(x, token):
+    # x[:, :0] holds nothing and tells the cotangent how many tokens there are.
+    return _spread(x, token), (token, x[:, :0])
 
 
-def _spread_bwd(k, res, g):
-    pos, here = res
-    n = pos.shape[0] // k
-    picked = jnp.where(here[:, None], g[pos], 0).reshape(n, k, -1)
-    return (picked.sum(axis=1, dtype=jnp.float32).astype(g.dtype),
-            None, None, None, None)
+def _spread_bwd(res, g):
+    token, like = res
+    return (_rows_to_tokens(g.astype(jnp.float32), token, like.shape[0])
+            .astype(g.dtype), None)
 
 
 _spread.defvjp(_spread_fwd, _spread_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _combine(out, ws, slot, valid, pos, here, k):
-    """``y[t] = sum_j ws[t*k+j] * out[pos[t*k+j]]`` over a token's slots that
-    are ``here``, in fp32.  Backward, both cotangents are taken at the chunk's
-    places (a gather of ``g`` rows by token), so nothing of ``[tokens*k, d]``
-    is kept for it."""
-    n = ws.shape[0] // k
-    picked = jnp.where(here[:, None], out[pos], 0).reshape(n, k, -1)
-    return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
-                      ws.reshape(n, k))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(out, ws, slot, token, tokens):
+    """``y[t] = sum ws[slot[p]] * out[p]`` over the chunk's places p that
+    hold a slot of token t, in fp32 (:func:`_rows_to_tokens`).  Backward,
+    both cotangents are taken at the chunk's places (a gather of ``g`` rows
+    by token), so nothing of ``[tokens*k, d]`` is fetched or kept in either
+    direction."""
+    return _rows_to_tokens(out.astype(jnp.float32) * ws[slot][:, None],
+                           token, tokens)
 
 
-def _combine_fwd(out, ws, slot, valid, pos, here, k):
-    return (_combine(out, ws, slot, valid, pos, here, k),
-            (out, ws, slot, valid, pos, here))
+def _combine_fwd(out, ws, slot, token, tokens):
+    return _combine(out, ws, slot, token, tokens), (out, ws, slot, token)
 
 
-def _combine_bwd(k, res, g):
-    out, ws, slot, valid, pos, here = res
-    g_rows = jnp.where(valid[:, None], g[slot // k], 0)        # [cap, d] fp32
+def _combine_bwd(tokens, res, g):
+    out, ws, slot, token = res
+    g_rows = g.at[token].get(mode="fill", fill_value=0)        # [cap, d] fp32
     d_out = (g_rows * ws[slot][:, None]).astype(out.dtype)
-    d_ws = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)  # [cap]
-    return (d_out, jnp.where(here, d_ws[pos], 0).astype(ws.dtype),
-            None, None, None, None)
+    # An unused place of ``out`` holds whatever the grouped product left.
+    d_w = jnp.where(token < tokens,
+                    jnp.sum(g_rows * out.astype(jnp.float32), axis=-1), 0)
+    # The slots of a chunk's places are distinct, its unused places' too.
+    return (d_out,
+            jnp.zeros_like(ws).at[slot].set(d_w.astype(ws.dtype),
+                                            unique_indices=True),
+            None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_chunk(xf, ws, gate, up, down, order, inverse, sizes, lo, *, k, cap,
-                dtype):
+def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype):
     """What the held experts give for the routed slots at sorted places
     ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``sizes`` are the held
     experts' row counts over the whole step; this chunk takes of each what
     falls inside it."""
     ends = jnp.cumsum(sizes)
     group = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
-    filled = jnp.sum(group)
+    tokens = xf.shape[0]
     with jax.named_scope("hvd.moe.dispatch"):
         slot = lax.dynamic_slice_in_dim(order, lo, cap)
-        valid = jnp.arange(cap) < filled
-        pos = inverse - lo
-        here = (pos >= 0) & (pos < filled)
-        pos = jnp.clip(pos, 0, cap - 1)
-        rows_in = _spread(xf, slot // k, valid, pos, here, k)
+        # Each place's token, and one past the last where it is unused.
+        token = jnp.where(jnp.arange(cap) < jnp.sum(group), slot // k, tokens)
+        rows_in = _spread(xf, token)
     with jax.named_scope("hvd.moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=group,
                                     preferred_element_type=dtype)
         hidden = jax.nn.silu(grouped(rows_in, gate)) * grouped(rows_in, up)
         out = grouped(hidden, down)                            # [cap, d]
     with jax.named_scope("hvd.moe.combine"):
-        return _combine(out, ws, slot, valid, pos, here, k)
+        return _combine(out, ws, slot, token, tokens)
 
 
 def _overflow_chunks(chunk, chunks: int, cap: int):
@@ -271,14 +281,14 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
         return rows_held > j * cap
 
     @jax.custom_vjp
-    def run(xf, ws, gate, up, down, order, inverse, sizes):
+    def run(xf, ws, gate, up, down, order, sizes):
         rows_held = jnp.sum(sizes)
 
         def body(y, j):
             return lax.cond(
                 reached(rows_held, j),
-                lambda: y + chunk(xf, ws, gate, up, down, order, inverse,
-                                  sizes, j * cap),
+                lambda: y + chunk(xf, ws, gate, up, down, order, sizes,
+                                  j * cap),
                 lambda: y), None
 
         # Zeros that vary over a mesh axis wherever the rows do (under
@@ -291,13 +301,13 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
         return run(*operands), operands
 
     def bwd(operands, g):
-        weights, (order, inverse, sizes) = operands[:5], operands[5:]
+        weights, (order, sizes) = operands[:5], operands[5:]
         rows_held = jnp.sum(sizes)
 
         def body(acc, j):
             def more():
                 _, vjp = jax.vjp(
-                    lambda *w: chunk(*w, order, inverse, sizes, j * cap),
+                    lambda *w: chunk(*w, order, sizes, j * cap),
                     *weights)
                 return tuple(a + d for a, d in zip(acc, vjp(g)))
 
@@ -305,7 +315,7 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
 
         acc, _ = lax.scan(body, tuple(w * 0 for w in weights),
                           jnp.arange(1, chunks))
-        return (*acc, None, None, None)
+        return (*acc, None, None)
 
     run.defvjp(fwd, bwd)
     return run
@@ -345,12 +355,11 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
         local = jnp.where(jnp.any(match, axis=1), jnp.argmax(match, axis=1),
                           len(held))
         order = jnp.argsort(local)
-        inverse = jnp.argsort(order)
         sizes = counts[np.asarray(held)]
     chunks, cap = row_buffer(n * k, len(held), n_experts)
     chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype)
     operands = (xf.astype(dtype), weights.reshape(n * k), gate.astype(dtype),
-                up.astype(dtype), down.astype(dtype), order, inverse, sizes)
+                up.astype(dtype), down.astype(dtype), order, sizes)
     y = chunk(*operands, 0)
     if chunks > 1:
         y = y + _overflow_chunks(chunk, chunks, cap)(*operands)

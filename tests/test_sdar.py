@@ -388,6 +388,113 @@ def test_share_gradients_match_autodiff_of_the_dense_form(skew, normalise):
         assert rel_err(g, wg) < 2e-5
 
 
+def _routed_by_hand(picks, n=32, experts=16, d=32, width=16, seed=5):
+    """Layer inputs whose first ``experts`` features are the router's logits
+    but for a small mix of the others: token t's largest are ``picks(t)``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    logits = np.array(0.3 * jax.random.normal(ks[0], (n, experts)))
+    for t in range(n):
+        logits[t, list(picks(t))] += 8.0 + np.arange(len(picks(t)))
+    x = jnp.concatenate(
+        [jnp.asarray(logits, jnp.float32),
+         jax.random.normal(ks[1], (n, d - experts))], axis=1)[None]
+    router = jnp.concatenate(
+        [jnp.eye(experts), 0.05 * jax.random.normal(ks[2], (d - experts,
+                                                            experts))])
+    gate, up, down = (0.2 * jax.random.normal(key, shape) for key, shape in
+                      zip(ks[3:], [(experts, d, width), (experts, d, width),
+                                   (experts, width, d)]))
+    return x, router, gate, up, down
+
+
+def _share_case(name):
+    """(layer inputs, k, held, what the routing must show): ``places[t, j]``
+    is the sorted place of token t's j-th slot where its expert is held, and
+    -1 elsewhere."""
+    if name == "every_slot_of_a_token_held":
+        # Token 0 routes to the four held experts and to no other.
+        held = (2, 7, 11, 4)
+        inputs = _routed_by_hand(lambda t: held if t == 0
+                                 else (0, 1, 3, 2 + t % 3))
+        return inputs, 4, held, lambda places, cap: (places[0] >= 0).all()
+    if name == "a_token_with_no_slot_held":
+        held = (2, 7, 11, 4)
+        inputs = _routed_by_hand(lambda t: (0, 1, 3, 5) if t == 9
+                                 else (0, 1, 7, 2 + t % 3))
+        return inputs, 4, held, lambda places, cap: (places[9] < 0).all() \
+            and (places >= 0).any(axis=1).sum() == len(places) - 1
+    if name == "a_run_cut_by_a_chunk_boundary":
+        # 94 tokens, top 4 of 16, 2 held: four chunks of 94 places, not a
+        # multiple of k.  With the skew every token's first expert is 0, so
+        # its row for expert 0 lies in the first chunk and its row for
+        # expert 1 in the chunk behind it.
+        inputs = layer_inputs(13, tokens=47, experts=16, skew=6.0)
+        return inputs, 4, (0, 1), lambda places, cap: cap % 4 and (
+            ((places >= 0) & (places < cap)).any(axis=1)
+            & (places >= cap).any(axis=1)).any()
+    if name == "a_chunk_filled_to_its_last_place":
+        # 32 tokens, top 4 of 16, 2 held: four chunks of 32 places; tokens
+        # 0..15 route to both held experts: 32 rows, and no chunk behind.
+        held = (2, 7)
+        inputs = _routed_by_hand(lambda t: (2, 7, 0, 1) if t < 16
+                                 else (0, 1, 3, 5))
+        return inputs, 4, held, \
+            lambda places, cap: (places >= 0).sum() == cap == 32
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "every_slot_of_a_token_held", "a_token_with_no_slot_held",
+    "a_run_cut_by_a_chunk_boundary", "a_chunk_filled_to_its_last_place"])
+def test_share_brings_each_tokens_rows_back_whatever_its_run(name):
+    """The way back to token order sums each token's run of rows among the
+    chunk's places: runs of k, of none, cut by the end of a chunk, and in a
+    chunk with no unused place; the result and all five gradients against
+    autodiff of the dense masked form."""
+    from horovod_tpu.parallel.moe import moe_ffn, row_buffer
+
+    (x, router, gate, up, down), k, held, shows = _share_case(name)
+    n = x.shape[0] * x.shape[1]
+    pick = np.asarray(held)
+    _, cap = row_buffer(n * k, len(held), router.shape[1])
+    logits = np.asarray(x, np.float64).reshape(n, -1) \
+        @ np.asarray(router, np.float64)
+    chosen = np.argsort(-logits, axis=-1, kind="stable")[:, :k].reshape(-1)
+    local = np.array([held.index(e) if e in held else len(held)
+                      for e in chosen])
+    places = np.argsort(np.argsort(local, kind="stable"), kind="stable")
+    places = np.where(local < len(held), places, -1).reshape(n, k)
+    assert shows(places, cap), name
+    w = jax.random.normal(jax.random.PRNGKey(1), x.shape)
+
+    def dense(x, router, gate, up, down):
+        xf = x.reshape(-1, x.shape[-1])
+        top, experts = jax.lax.top_k(jax.nn.softmax(xf @ router, axis=-1), k)
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        y = 0.0
+        for i, e in enumerate(held):
+            we = jnp.sum(jnp.where(experts == e, top, 0.0), axis=-1)
+            y = y + we[:, None] * (
+                (jax.nn.silu(xf @ gate[i]) * (xf @ up[i])) @ down[i])
+        y = y.reshape(x.shape)
+        return jnp.sum(y * w), y
+
+    def program(x, router, gate, up, down):
+        y, _ = moe_ffn(x, router, gate, up, down, k=k, dtype=jnp.float32,
+                       held=held, norm_topk_prob=True)
+        return jnp.sum(y * w), y
+
+    args = (x, router, gate[pick], up[pick], down[pick])
+    with jax.default_matmul_precision("highest"):
+        (_, y), got = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        (_, want_y), want = jax.jit(jax.value_and_grad(
+            dense, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    assert rel_err(y, want_y) < 1e-5
+    for g, wg in zip(got, want):
+        assert rel_err(g, wg) < 1e-5
+
+
 def test_share_counters_become_gauges():
     from horovod_tpu.core import metrics
     from horovod_tpu.parallel.moe import (
