@@ -229,6 +229,13 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "moe_rows_elsewhere_share": (
         "gauge", "share of a layer's routed rows bound for experts that "
                  "live elsewhere (counted, not computed), per layer="),
+    # -- attention under a layer pattern
+    #    (models/transformer.py::publish_attention) --
+    "attn_allowed_pairs_per_step": (
+        "gauge", "(query, key) pairs a step's attention masks allow, from "
+                 "the shapes, summed over the layers of kind=window (causal "
+                 "inside a window) and of kind=global (the model's mask), "
+                 "every query head counted once"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

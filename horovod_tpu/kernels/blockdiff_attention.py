@@ -14,41 +14,26 @@ key ``r`` iff
 
 ``L*b + L(L-b)/2 + L(L+b)/2 = L**2 + L*b`` of the ``4 L**2`` pairs are
 allowed: a quarter of the square.  :func:`block_diffusion_mask` is that rule,
-on numpy or JAX integers.  :func:`blockdiff_attention` is the kernel: JAX's
-pallas splash attention (``jax.experimental.pallas.ops.tpu.splash_attention``)
-over a mask it computes from the rule inside the kernel, so it visits only
-the tiles the rule allows (80 of 256 at ``L`` = 8192 with tiles of 1024),
-keeps no ``[2L, 2L]`` table anywhere, and serves grouped KV heads without
-repeating them.  On the device's op line its three kernels are
-``splash_mha_fwd_residuals``, ``splash_mha_dq_no_residuals`` and
-``splash_mha_dkv_no_residuals`` (:data:`OP_LINE_NAMES`); the calls lie under
+on numpy or JAX integers, and :class:`BlockDiffusion` the rule in the form
+``kernels/masked_attention.py`` takes: its splash kernels compute the mask
+from one code a position, visit only the tiles the rule allows (80 of 256 at
+``L`` = 8192 with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and
+serve grouped KV heads without repeating them.  :func:`blockdiff_attention`
+is that kernel under this rule; the calls lie under
 ``jax.named_scope("hvd.attn.blockdiff")``.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-SCOPE = "hvd.attn.blockdiff"
-# A regular expression for the kernels' names on the device's op line.
-OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
+from . import masked_attention
+from .masked_attention import _TILES, BLOCK, OP_LINE_NAMES  # noqa: F401
 
-# The kernels' tiles (splash attention's ``BlockSizes``: queries x keys of the
-# forward, the dkv and the dq kernel, and the keys the forward and the dkv
-# kernel multiply at a time), and with them the shortest half the kernel
-# takes: a tile never straddles the two halves.  Measured on a v5e at
-# L = 8192, b = 4, 32 query heads on 4 KV heads of 128, forward + backward
-# (PERF.md, PR 31): tiles of 256 94.4 ms, of 512 46.1, of 1024 42.2, these
-# 40.7; keys or queries of 2048 are slower or do not fit the fast memory.
-BLOCK = 1024
-_TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2,
-              block_q_dkv=BLOCK, block_kv_dkv=BLOCK,
-              block_kv_dkv_compute=BLOCK // 2, block_q_dq=BLOCK,
-              block_kv_dq=BLOCK)
+SCOPE = "hvd.attn.blockdiff"
 
 
 def block_diffusion_mask(q_ids, kv_ids, half_len: int, block: int):
@@ -70,9 +55,7 @@ def allowed_pairs(half_len: int, block: int) -> int:
 def takes(seq_len: int, head_dim: int, block: int) -> bool:
     """Whether the kernel takes this shape (``seq_len`` = 2L positions);
     otherwise, and off the TPU, the same mask goes through the einsum."""
-    half = seq_len // 2
-    return seq_len % 2 == 0 and half % BLOCK == 0 and head_dim % 128 == 0 \
-        and block & (block - 1) == 0 and BLOCK % block == 0
+    return masked_attention.takes(BlockDiffusion(block), seq_len, head_dim)
 
 
 def _code(ids, half_len: int, block: int):
@@ -124,36 +107,34 @@ def _make_mask(half_len: int, block: int):
     return _mask_class()(half_len, block)
 
 
-@functools.lru_cache(maxsize=8)
-def _kernel(half_len: int, block: int, heads: int, interpret: bool):
-    """The splash kernel for one shape; building it walks the rule tile by
-    tile on the host, once."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as splash,
-        splash_attention_mask as mask_lib,
-    )
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The rule over ``[x_t ; x_0]`` with blocks of ``block`` tokens, for
+    ``masked_attention``; the sequence it is given is the ``2L`` positions.
+    A tile never straddles the two halves, and the kernel's code needs the
+    block length a power of two that divides a tile."""
 
-    mask = mask_lib.MultiHeadMask([_make_mask(half_len, block)] * heads)
-    # Mask information is made of numpy arrays here, whatever trace is open.
-    with jax.ensure_compile_time_eval():
-        return splash.make_splash_mha(
-            mask, block_sizes=splash.BlockSizes(**_TILES), head_shards=1,
-            q_seq_shards=1, interpret=interpret)
+    block: int
+    scope = SCOPE
+
+    def allowed(self, q_ids, kv_ids, seq_len):
+        return block_diffusion_mask(q_ids, kv_ids, seq_len // 2, self.block)
+
+    def allowed_pairs(self, seq_len: int) -> int:
+        return allowed_pairs(seq_len // 2, self.block)
+
+    def takes(self, seq_len: int) -> bool:
+        return seq_len % 2 == 0 and (seq_len // 2) % BLOCK == 0 \
+            and self.block & (self.block - 1) == 0 \
+            and BLOCK % self.block == 0
+
+    def mask(self, seq_len: int):
+        return _make_mask(seq_len // 2, self.block)
 
 
 def blockdiff_attention(q, k, v, *, block: int, interpret: bool = False):
     """Softmax attention of ``q [b, 2L, h, d]`` on ``k, v [b, 2L, h_kv, d]``
-    under the block-diffusion mask with blocks of ``block`` tokens, scores
-    scaled by ``d ** -0.5``; ``h_kv`` divides ``h`` and KV head ``j`` serves
-    query heads ``j*h/h_kv`` to ``(j+1)*h/h_kv - 1``.  Returns ``[b, 2L, h,
-    d]``.  Differentiable (the library's dq and dkv kernels)."""
-    _, s, h, d = q.shape
-    if not takes(s, d, block):
-        raise ValueError(f"no block-diffusion kernel for {s} positions, "
-                         f"head width {d}, blocks of {block}")
-    kernel = _kernel(s // 2, block, h, interpret)
-    hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
-    with jax.named_scope(SCOPE):
-        out = jax.vmap(kernel)(hsd(q * jnp.asarray(d ** -0.5, q.dtype)),
-                               hsd(k), hsd(v))
-    return out.transpose(0, 2, 1, 3)
+    under the block-diffusion mask with blocks of ``block`` tokens
+    (``masked_attention.attention`` under :class:`BlockDiffusion`)."""
+    return masked_attention.attention(q, k, v, BlockDiffusion(block),
+                                      interpret=interpret)

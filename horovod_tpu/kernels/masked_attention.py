@@ -1,0 +1,161 @@
+"""Softmax attention under a mask that is a rule, not a table.
+
+One wrapper around JAX's pallas splash attention
+(``jax.experimental.pallas.ops.tpu.splash_attention``) for every mask the
+models here state as a rule over (query position, key position): the kernels
+compute the mask from the rule, so they visit only the tiles it allows, no
+``[s, s]`` table exists anywhere, and grouped KV heads are served without
+repeating them.  A rule is a small hashable object with
+
+- ``scope``: the ``jax.named_scope`` its kernel calls lie under;
+- ``allowed(q_ids, kv_ids, seq_len)``: the rule itself, a boolean array, on
+  numpy or JAX integers that broadcast against each other;
+- ``allowed_pairs(seq_len)``: how many pairs it allows in one sequence;
+- ``takes(seq_len)``: whether the kernel's tiles fit the rule at this length;
+- ``mask(seq_len)``: the rule as a mask the library computes in its kernels.
+
+The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
+:class:`Window` (causal, and the key inside the last ``size`` positions:
+``hvd.attn.window``) and ``kernels/blockdiff_attention.py``'s
+``BlockDiffusion``.  At 16,384 positions and tiles of 1024 a causal layer
+visits 136 of 256 tiles and a window of 4096 visits 70.  :func:`attention` is
+the kernel, :func:`einsum` the same mask through a grouped einsum (off the
+TPU, and for shapes the kernel does not take).  On the device's op line the
+three kernels are ``splash_mha_fwd_residuals``, ``splash_mha_dq_no_residuals``
+and ``splash_mha_dkv_no_residuals`` (:data:`OP_LINE_NAMES`) whatever the rule.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# A regular expression for the kernels' names on the device's op line.
+OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
+
+# The kernels' tiles (splash attention's ``BlockSizes``: queries x keys of the
+# forward, the dkv and the dq kernel, and the keys the forward and the dkv
+# kernel multiply at a time).  Measured on a v5e under the block-diffusion
+# mask at 16,384 positions, 32 query heads on 4 KV heads of 128, forward +
+# backward (PERF.md, PR 31): tiles of 256 94.4 ms, of 512 46.1, of 1024 42.2,
+# these 40.7; keys or queries of 2048 are slower or do not fit the fast
+# memory.
+BLOCK = 1024
+_TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2,
+              block_q_dkv=BLOCK, block_kv_dkv=BLOCK,
+              block_kv_dkv_compute=BLOCK // 2, block_q_dq=BLOCK,
+              block_kv_dq=BLOCK)
+
+
+def _mask_lib():
+    """The library is imported only where a kernel is built."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask,
+    )
+
+    return splash_attention_mask
+
+
+@dataclasses.dataclass(frozen=True)
+class Causal:
+    """Query i sees key j iff ``j <= i``."""
+
+    scope = "hvd.attn.causal"
+
+    def allowed(self, q_ids, kv_ids, seq_len=None):
+        return kv_ids <= q_ids
+
+    def allowed_pairs(self, seq_len: int) -> int:
+        return seq_len * (seq_len + 1) // 2
+
+    def takes(self, seq_len: int) -> bool:
+        return seq_len % BLOCK == 0
+
+    def mask(self, seq_len: int):
+        return _mask_lib().CausalMask((seq_len, seq_len))
+
+
+@dataclasses.dataclass(frozen=True)
+class Window:
+    """Query i sees key j iff ``j <= i`` and ``i - j < size``: itself and the
+    ``size - 1`` positions before it."""
+
+    size: int
+    scope = "hvd.attn.window"
+
+    def __post_init__(self):
+        if self.size < 1:
+            raise ValueError(f"a window of {self.size} positions")
+
+    def allowed(self, q_ids, kv_ids, seq_len=None):
+        return (kv_ids <= q_ids) & (q_ids - kv_ids < self.size)
+
+    def allowed_pairs(self, seq_len: int) -> int:
+        beyond = max(seq_len - self.size, 0)
+        return seq_len * (seq_len + 1) // 2 - beyond * (beyond + 1) // 2
+
+    def takes(self, seq_len: int) -> bool:
+        return seq_len % BLOCK == 0
+
+    def mask(self, seq_len: int):
+        return _mask_lib().LocalMask((seq_len, seq_len),
+                                     window_size=(self.size - 1, 0), offset=0)
+
+
+def takes(rule, seq_len: int, head_dim: int) -> bool:
+    """Whether the kernel takes this shape under ``rule``; otherwise, and off
+    the TPU, the same mask goes through :func:`einsum`."""
+    return head_dim % 128 == 0 and rule.takes(seq_len)
+
+
+@functools.lru_cache(maxsize=8)
+def _kernel(rule, seq_len: int, heads: int, interpret: bool):
+    """The splash kernel for one rule and shape; building it walks the rule
+    tile by tile on the host, once."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+    )
+
+    mask = _mask_lib().MultiHeadMask([rule.mask(seq_len)] * heads)
+    # Mask information is made of numpy arrays here, whatever trace is open.
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            mask, block_sizes=splash.BlockSizes(**_TILES), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def attention(q, k, v, rule, *, interpret: bool = False):
+    """Softmax attention of ``q [b, s, h, d]`` on ``k, v [b, s, h_kv, d]``
+    under ``rule``, scores scaled by ``d ** -0.5``; ``h_kv`` divides ``h`` and
+    KV head ``j`` serves query heads ``j*h/h_kv`` to ``(j+1)*h/h_kv - 1``.
+    Returns ``[b, s, h, d]``.  Differentiable (the library's dq and dkv
+    kernels)."""
+    _, s, h, d = q.shape
+    if not takes(rule, s, d):
+        raise ValueError(f"no kernel under {rule} for {s} positions, head "
+                         f"width {d}")
+    kernel = _kernel(rule, s, h, interpret)
+    hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+    with jax.named_scope(rule.scope):
+        out = jax.vmap(kernel)(hsd(q * jnp.asarray(d ** -0.5, q.dtype)),
+                               hsd(k), hsd(v))
+    return out.transpose(0, 2, 1, 3)
+
+
+def einsum(q, k, v, rule):
+    """:func:`attention` through the einsum, KV heads grouped, the mask from
+    iota comparisons: below the kernel's smallest shape, and off the TPU."""
+    b, s, h, dh = q.shape
+    h_kv = k.shape[2]
+    q = q.reshape(b, s, h_kv, h // h_kv, dh)
+    scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
+                        preferred_element_type=jnp.float32) * dh ** -0.5
+    mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
+                        lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
+    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bngqk,bknd->bqngd", probs, v).reshape(b, s, h, dh)

@@ -18,25 +18,41 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
 - the block's parts are options of one config (norm kind, learned or rotary
   positions, QK-norm over the projection or per head, grouped KV heads, an
   explicit head width, biases, tied or untied head, dense or sparse-expert
-  FFN, all experts or a share of them, causal, unmasked or block-diffusion
-  attention): OLMoE is ``olmoe_1b_7b_config()`` and SDAR-30B-A3B
-  ``sdar_30b_a3b_config()`` over the same ``Transformer``, their expert layer
-  :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``).
+  FFN, all experts or a share of them, the gate's activation, a router that
+  reads the FFN's input or the block's, causal, unmasked or block-diffusion
+  attention) and a layer pattern says which layers attend inside a window
+  and which carry rotary positions: OLMoE is ``olmoe_1b_7b_config()``,
+  SDAR-30B-A3B ``sdar_30b_a3b_config()`` and SmallThinker-21BA3B
+  ``smallthinker_21b_a3b_config()`` over the same ``Transformer``, their
+  expert layer :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``),
+  their masks that are rules ``kernels/masked_attention.py``'s.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..kernels import blockdiff_attention as blockdiff
+from ..kernels import masked_attention
+from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..parallel.moe import MoEStats, moe_ffn
+
+
+class LayerKind(NamedTuple):
+    """What one layer of a pattern differs in.  ``window``: attention is
+    causal inside this many positions (a query sees itself and the
+    ``window - 1`` before it); 0: the model's mask.  ``rope``: whether the
+    layer applies the rotary positions (only where ``positions == "rope"``);
+    a layer without them carries no position at all."""
+
+    window: int = 0
+    rope: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +93,11 @@ class TransformerConfig:
     # num_experts outputs.  norm_topk_prob: a token's k weights sum to 1.
     experts_held: Optional[Tuple[int, ...]] = None
     norm_topk_prob: bool = False
+    # What the router reads: "ffn", the normed post-attention state the
+    # experts multiply, or "block", the block's input before attention and
+    # its norm.  expert_activation: the gate's, silu | relu.
+    router_input: str = "ffn"
+    expert_activation: str = "silu"
     # Grouped-query attention: num_kv_heads KV heads, each serving
     # num_heads / num_kv_heads query heads (None: as many as heads, one fused
     # qkv projection).  head_width: a head's width where it is not
@@ -88,10 +109,22 @@ class TransformerConfig:
     # both halves the indices 0..L-1, masks attention by the block rule in
     # place of `causal`, and returns the noisy half's logits.  0: off.
     block_diffusion: int = 0
+    # One period of the layers' kinds, repeated over num_layers (layer i is
+    # layer_pattern[i % len]); None: every layer is LayerKind(), today's
+    # uniform model.
+    layer_pattern: Optional[Tuple[LayerKind, ...]] = None
 
     @property
     def head_dim(self) -> int:
         return self.head_width or self.d_model // self.num_heads
+
+    def layer_kind(self, i: int) -> LayerKind:
+        if self.layer_pattern is None:
+            return LayerKind()
+        if self.num_layers % len(self.layer_pattern):
+            raise ValueError(f"{self.num_layers} layers are no whole periods "
+                             f"of {len(self.layer_pattern)}")
+        return LayerKind(*self.layer_pattern[i % len(self.layer_pattern)])
 
 
 def bert_large_config(**overrides) -> TransformerConfig:
@@ -133,6 +166,25 @@ def sdar_30b_a3b_config(**overrides) -> TransformerConfig:
         qk_norm="head", use_bias=False, tie_embeddings=False, ffn="moe",
         num_experts=128, experts_per_token=8, norm_topk_prob=True,
         block_diffusion=4), **overrides})
+
+
+def smallthinker_21b_a3b_config(**overrides) -> TransformerConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer/SmallThinker-21BA3B-Instruct
+    ``config.json``): 52 layers in periods of four, the first of each global
+    and without positions, the other three inside a window of 4096 with RoPE
+    at 1.5e6; 28 query heads on 4 KV heads of 128 (not 2560 / 28), no
+    QK-norm; every layer 64 relu-gated experts of width 768, 6 a token with
+    renormalised weights, routed by the block's input; RMSNorm, no biases,
+    untied head."""
+    return TransformerConfig(**{**dict(
+        vocab_size=151936, num_layers=52, num_heads=28, num_kv_heads=4,
+        head_width=128, d_model=2560, d_ff=768, max_len=16384, causal=True,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1.5e6,
+        use_bias=False, tie_embeddings=False, ffn="moe", num_experts=64,
+        experts_per_token=6, norm_topk_prob=True, router_input="block",
+        expert_activation="relu",
+        layer_pattern=(LayerKind(0, False),) + (LayerKind(4096, True),) * 3),
+        **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -179,6 +231,7 @@ def _rope(x, theta: float, positions=None):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: LayerKind = LayerKind()
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -207,14 +260,15 @@ class Attention(nn.Module):
             q = _norm(cfg, "q_norm")(flat(q)).astype(cfg.dtype)
             k = _norm(cfg, "k_norm")(flat(k)).astype(cfg.dtype)
             q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h_kv, dh)
-        if cfg.positions == "rope":
+        if cfg.positions == "rope" and self.kind.rope:
             if cfg.attention != "full":
                 raise ValueError("rope positions need attention='full'")
             q = _rope(q, cfg.rope_theta, positions)
             k = _rope(k, cfg.rope_theta, positions)
-        if (h_kv != h or cfg.block_diffusion) and cfg.attention != "full":
-            raise ValueError("grouped KV heads and the block-diffusion mask "
-                             "need attention='full'")
+        if (h_kv != h or cfg.block_diffusion or self.kind.window) \
+                and cfg.attention != "full":
+            raise ValueError("grouped KV heads, a window and the "
+                             "block-diffusion mask need attention='full'")
 
         if cfg.attention == "ring":
             from ..parallel.ring_attention import ring_attention
@@ -228,7 +282,8 @@ class Attention(nn.Module):
                                     causal=cfg.causal)
         elif cfg.attention == "full":
             out = _scaled_dot_attention(q, k, v, cfg.causal, dh,
-                                        block_diffusion=cfg.block_diffusion)
+                                        block_diffusion=cfg.block_diffusion,
+                                        window=self.kind.window)
         else:
             raise ValueError(f"unknown attention mode {cfg.attention!r}")
 
@@ -266,42 +321,39 @@ def _flash_attention(q, k, v, causal: bool, dh: int):
     return o.transpose(0, 2, 1, 3)
 
 
-def _blockdiff_einsum(q, k, v, dh: int, block: int):
-    """The block-diffusion mask through the einsum, KV heads grouped: below
-    the kernel's smallest shape, and off the TPU."""
-    b, s, h, _ = q.shape
-    h_kv = k.shape[2]
-    q = q.reshape(b, s, h_kv, h // h_kv, dh)
-    scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
-                        preferred_element_type=jnp.float32) * dh ** -0.5
-    mask = blockdiff.block_diffusion_mask(
-        lax.broadcasted_iota(jnp.int32, (s, s), 0),
-        lax.broadcasted_iota(jnp.int32, (s, s), 1), s // 2, block)
-    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bngqk,bknd->bqngd", probs, v).reshape(b, s, h, dh)
-
-
 def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
-                          block_diffusion: int = 0):
-    """Single-device attention for the "full" mode, [b, s, h, d] layout: the
-    XLA-fused einsum softmax, and on a TPU from ``_FLASH_MIN_SEQ`` positions
-    on the pallas flash-attention kernel, picked from the shape alone.  With
-    ``block_diffusion`` (a block length) the mask is the block-diffusion
-    rule in place of ``causal``: on a TPU the kernel of
-    ``kernels/blockdiff_attention.py`` wherever it takes the shape, else the
-    einsum under the same mask.  A kernel that fails to lower fails the step:
-    it is never silently the einsum."""
+                          block_diffusion: int = 0, window: int = 0):
+    """Single-device attention for the "full" mode, [b, s, h, d] layout.
+
+    A mask that is a rule of ``kernels/masked_attention.py`` (the
+    block-diffusion rule with ``block_diffusion``, a block length, in place
+    of ``causal``; causal inside ``window`` positions; plain causal where KV
+    heads are grouped) goes on a TPU to that module's kernel wherever it
+    takes the shape, KV heads grouped and not repeated, and else through its
+    einsum under the same mask.  A kernel that fails to lower fails the
+    step: it is never silently the einsum.  Everything else (one KV head a
+    query head under ``causal``, no mask) takes the XLA-fused einsum softmax,
+    and on a TPU from ``_FLASH_MIN_SEQ`` positions on the pallas
+    flash-attention kernel, picked from the shape alone."""
     s = q.shape[1]
+    grouped = k.shape[2] != q.shape[2]
+    rule = None
     if block_diffusion:
+        rule = BlockDiffusion(block_diffusion)
+    elif window:
+        if not causal:
+            raise ValueError("a window is causal: it needs causal=True")
+        rule = masked_attention.Window(window)
+    elif causal and grouped:
+        rule = masked_attention.Causal()
+    if rule is not None:
         if jax.default_backend() == "tpu" \
-                and blockdiff.takes(s, dh, block_diffusion):
-            return blockdiff.blockdiff_attention(q, k, v,
-                                                 block=block_diffusion)
-        return _blockdiff_einsum(q, k, v, dh, block_diffusion)
-    if k.shape[2] != q.shape[2]:
-        # Grouped KV heads under `causal` or no mask: each KV head repeated
-        # for the query heads it serves, then the paths below.
+                and masked_attention.takes(rule, s, dh):
+            return masked_attention.attention(q, k, v, rule)
+        return masked_attention.einsum(q, k, v, rule)
+    if grouped:
+        # Grouped KV heads under no mask: each KV head repeated for the query
+        # heads it serves, then the paths below.
         group = q.shape[2] // k.shape[2]
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
@@ -321,23 +373,29 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    kind: LayerKind = LayerKind()
 
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(_norm(cfg, "ln1")(x), positions)
+        block_input = x
+        x = x + Attention(cfg, self.kind, name="attn")(
+            _norm(cfg, "ln1")(x), positions)
         y = _norm(cfg, "ln2")(x)
         if cfg.ffn == "gelu":
             y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis), "ffn_in")(y)
             y = nn.gelu(y)
             y = _dense(cfg, cfg.d_model, (cfg.model_axis, None), "ffn_out")(y)
         elif cfg.ffn == "moe":
-            y = self._experts(y)
+            if cfg.router_input not in ("ffn", "block"):
+                raise ValueError(f"unknown router_input {cfg.router_input!r}")
+            y = self._experts(y, block_input if cfg.router_input == "block"
+                              else None)
         else:
             raise ValueError(f"unknown ffn {cfg.ffn!r}")
         return x + y
 
-    def _experts(self, y):
+    def _experts(self, y, router_input=None):
         """The sparse-expert FFN; its MoEStats are sown into the ``moe``
         collection (``apply(..., mutable=["moe"])``, then ``moe_stats``)."""
         cfg = self.cfg
@@ -355,7 +413,9 @@ class Block(nn.Module):
         y, stats = moe_ffn(y, *weights, k=cfg.experts_per_token,
                            data_axis=cfg.moe_data_axis, dtype=cfg.dtype,
                            held=cfg.experts_held,
-                           norm_topk_prob=cfg.norm_topk_prob)
+                           norm_topk_prob=cfg.norm_topk_prob,
+                           router_input=router_input,
+                           activation=cfg.expert_activation)
         self.sow("moe", "stats", stats)
         return y
 
@@ -367,6 +427,42 @@ def moe_stats(collection) -> MoEStats:
     layers = sorted(collection, key=lambda name: int(name.split("_")[-1]))
     return jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs), *[collection[n]["stats"][0] for n in layers])
+
+
+def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
+    """``{"window": n, "global": n}``: the (query, key) pairs the masks of
+    one sequence of ``seq_len`` positions allow, summed over the layers that
+    attend inside a window and over those under the model's own mask; from
+    the shapes and the rules alone."""
+    if cfg.block_diffusion:
+        everywhere = BlockDiffusion(cfg.block_diffusion).allowed_pairs(seq_len)
+    elif cfg.causal:
+        everywhere = masked_attention.Causal().allowed_pairs(seq_len)
+    else:
+        everywhere = seq_len * seq_len
+    pairs = {"window": 0, "global": 0}
+    for i in range(cfg.num_layers):
+        window = cfg.layer_kind(i).window
+        if window:
+            pairs["window"] += masked_attention.Window(window) \
+                .allowed_pairs(seq_len)
+        else:
+            pairs["global"] += everywhere
+    return pairs
+
+
+def publish_attention(cfg: TransformerConfig, seq_len: int,
+                      sequences: int = 1) -> dict:
+    """Set the gauge ``attn_allowed_pairs_per_step`` per ``kind=`` for a step
+    of ``sequences`` sequences of ``seq_len`` positions, and return the
+    values; called outside the step, beside ``moe.publish_routing``."""
+    from ..core import metrics
+
+    pairs = {kind: n * sequences
+             for kind, n in attention_pairs(cfg, seq_len).items()}
+    for kind, n in pairs.items():
+        metrics.set_gauge("attn_allowed_pairs_per_step", float(n), kind=kind)
+    return pairs
 
 
 class Transformer(nn.Module):
@@ -406,7 +502,7 @@ class Transformer(nn.Module):
         if cfg.remat:
             block = nn.remat(Block)
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"layer_{i}")(x, positions)
+            x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(x, positions)
         if cfg.block_diffusion:
             x = x[:, :s // 2]
         x = _norm(cfg, "ln_f")(x)

@@ -18,6 +18,9 @@ every token reaches its k experts whatever the imbalance, and the work is the k
 routed rows a token, not one per expert.  Told which experts it ``held``
 (SDAR-30B-A3B: 16 of 128, a layer's experts shared among 8 chips), it routes
 over all of them and computes the part of the result its own experts give.
+The router may read another tensor than the rows it multiplies
+(``router_input``) and the gate's activation is an argument (SmallThinker:
+the block's input, ``relu``).
 """
 
 from __future__ import annotations
@@ -133,12 +136,27 @@ _slots_to_rows.defvjp(lambda y, order, inverse: (y[inverse], order),
                       lambda order, g: (g[order], None, None))
 
 
-def _route(xf, router, k, norm_topk_prob=False):
-    """The router on rows ``xf [n, d]``, in fp32: each row's k weights and
-    experts ``[n, k]``, the rows routed to each expert ``[experts]``, the
-    load-balancing loss and the z-loss, all over every expert of the
+# The gate's activation: ``down(act(gate x) * up x)``.
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _activation(name: str):
+    try:
+        return _ACTIVATIONS[name]
+    except KeyError:
+        raise ValueError(f"unknown activation {name!r}; have "
+                         f"{sorted(_ACTIVATIONS)}") from None
+
+
+def _route(xf, router, k, norm_topk_prob=False, router_input=None):
+    """The router on rows ``xf [n, d]`` (or, where given, on ``router_input
+    [rows, tokens, d_r]``, the same n rows), in fp32: each row's k weights
+    and experts ``[n, k]``, the rows routed to each expert ``[experts]``,
+    the load-balancing loss and the z-loss, all over every expert of the
     router."""
     n, n_experts = xf.shape[0], router.shape[-1]
+    if router_input is not None:
+        xf = router_input.reshape(n, -1)
     with jax.named_scope("hvd.moe.router"):
         logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
@@ -156,13 +174,15 @@ def _route(xf, router, k, norm_topk_prob=False):
     return weights, experts, counts, balance, z
 
 
-def _moe_rows(x, router, gate, up, down, *, k, dtype, norm_topk_prob=False):
-    """:func:`moe_ffn` on the rows of one rank, routed as one set."""
+def _moe_rows(x, router, gate, up, down, *route_by, k, dtype,
+              norm_topk_prob=False, act=jax.nn.silu):
+    """:func:`moe_ffn` on the rows of one rank, routed as one set;
+    ``route_by`` is empty or ``(router_input,)``."""
     rows, tokens, d = x.shape
     n = rows * tokens
     xf = x.reshape(n, d)
     weights, experts, counts, balance, z = _route(xf, router, k,
-                                                  norm_topk_prob)
+                                                  norm_topk_prob, *route_by)
     with jax.named_scope("hvd.moe.dispatch"):
         order = jnp.argsort(experts.reshape(n * k))      # stable: by expert
         inverse = jnp.argsort(order)
@@ -170,7 +190,7 @@ def _moe_rows(x, router, gate, up, down, *, k, dtype, norm_topk_prob=False):
     with jax.named_scope("hvd.moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=counts,
                                     preferred_element_type=dtype)
-        hidden = jax.nn.silu(grouped(slots, gate.astype(dtype))) \
+        hidden = act(grouped(slots, gate.astype(dtype))) \
             * grouped(slots, up.astype(dtype))
         out = grouped(hidden, down.astype(dtype))              # [n*k, d]
     with jax.named_scope("hvd.moe.combine"):
@@ -249,7 +269,8 @@ def _combine_bwd(tokens, res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype):
+def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
+                act=jax.nn.silu):
     """What the held experts give for the routed slots at sorted places
     ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``sizes`` are the held
     experts' row counts over the whole step; this chunk takes of each what
@@ -265,7 +286,7 @@ def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype):
     with jax.named_scope("hvd.moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=group,
                                     preferred_element_type=dtype)
-        hidden = jax.nn.silu(grouped(rows_in, gate)) * grouped(rows_in, up)
+        hidden = act(grouped(rows_in, gate)) * grouped(rows_in, up)
         out = grouped(hidden, down)                            # [cap, d]
     with jax.named_scope("hvd.moe.combine"):
         return _combine(out, ws, slot, token, tokens)
@@ -332,10 +353,11 @@ def row_buffer(slots: int, n_held: int, n_experts: int):
     return chunks, slots // chunks
 
 
-def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
-                    norm_topk_prob):
+def _moe_rows_share(x, router, gate, up, down, *route_by, k, dtype, held,
+                    norm_topk_prob, act=jax.nn.silu):
     """:func:`moe_ffn` on the rows of one rank where only ``held`` of the
-    router's experts live here."""
+    router's experts live here; ``route_by`` is empty or
+    ``(router_input,)``."""
     rows, tokens, d = x.shape
     n, n_experts = rows * tokens, router.shape[-1]
     held = tuple(held)
@@ -345,7 +367,7 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
                          f"experts and a router of {n_experts}")
     xf = x.reshape(n, d)
     weights, experts, counts, balance, z = _route(xf, router, k,
-                                                  norm_topk_prob)
+                                                  norm_topk_prob, *route_by)
     with jax.named_scope("hvd.moe.dispatch"):
         # Each routed slot's expert as its index among the held ones; the
         # slots bound elsewhere sort behind them all.  By comparison with
@@ -357,7 +379,8 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
         order = jnp.argsort(local)
         sizes = counts[np.asarray(held)]
     chunks, cap = row_buffer(n * k, len(held), n_experts)
-    chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype)
+    chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype,
+                              act=act)
     operands = (xf.astype(dtype), weights.reshape(n * k), gate.astype(dtype),
                 up.astype(dtype), down.astype(dtype), order, sizes)
     y = chunk(*operands, 0)
@@ -370,8 +393,10 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
 def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
             down: jax.Array, *, k: int, data_axis: Optional[str] = None,
             dtype=jnp.bfloat16, held: Optional[Sequence[int]] = None,
-            norm_topk_prob: bool = False):
-    """Dropless top-k expert layer: ``sum_j p_j * down_j(silu(gate_j x) *
+            norm_topk_prob: bool = False,
+            router_input: Optional[jax.Array] = None,
+            activation: str = "silu"):
+    """Dropless top-k expert layer: ``sum_j p_j * down_j(act(gate_j x) *
     up_j x)`` over a token's k most probable experts, the probabilities a
     softmax over all experts, renormalised over the k only with
     ``norm_topk_prob``.
@@ -390,6 +415,11 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
       by whoever holds them (on one chip, by no one).  No row bound here is
       dropped: the rows are taken in chunks of :func:`row_buffer`'s size, the
       first always, the others when the routing reaches them.
+    - ``router_input``: ``[rows, tokens, d_r]``, what the router reads where
+      that is not the rows it multiplies (SmallThinker routes by the block's
+      input, before attention; ``router`` is then ``[d_r, experts]``).  Its
+      gradient flows through the k weights and the auxiliary losses.
+    - ``activation``: the gate's, ``"silu"`` or ``"relu"``.
 
     All of the rows given are routed as one set: sorted by expert, multiplied
     by a grouped matmul, brought back.  The auxiliary losses are taken over
@@ -403,20 +433,28 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
 
     Returns ``(y [rows, tokens, d] in dtype, MoEStats)``.
     """
+    common = dict(k=k, dtype=dtype, norm_topk_prob=norm_topk_prob,
+                  act=_activation(activation))
     if held is None:
-        body = functools.partial(_moe_rows, k=k, dtype=dtype,
-                                 norm_topk_prob=norm_topk_prob)
+        body = functools.partial(_moe_rows, **common)
     else:
-        body = functools.partial(_moe_rows_share, k=k, dtype=dtype, held=held,
-                                 norm_topk_prob=norm_topk_prob)
+        body = functools.partial(_moe_rows_share, held=held, **common)
+    # The rows themselves are the default: the program is then the one
+    # without the argument.
+    route_by = () if router_input is None or router_input is x \
+        else (router_input,)
+    if route_by and router_input.shape[:2] != x.shape[:2]:
+        raise ValueError(f"router_input {router_input.shape} for rows "
+                         f"{x.shape}")
     if data_axis is None or \
             data_axis not in jax.sharding.get_abstract_mesh().axis_names:
-        return body(x, router, gate, up, down)
+        return body(x, router, gate, up, down, *route_by)
     sharded = P(data_axis)
     return jax.shard_map(
-        body, in_specs=(sharded, P(), P(), P(), P()),
+        body,
+        in_specs=(sharded, P(), P(), P(), P()) + (sharded,) * len(route_by),
         out_specs=(sharded, MoEStats(sharded, sharded, sharded)),
-    )(x, router, gate, up, down)
+    )(x, router, gate, up, down, *route_by)
 
 
 def moe_counters(n_layers: int, n_experts: int, share: bool = False) -> dict:

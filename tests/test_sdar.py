@@ -230,7 +230,7 @@ def test_kernel_in_interpret_mode_matches_the_einsum_with_grouped_heads():
     """Forward and the three gradients at the kernel's smallest shape (a half
     is one tile), two query heads on one KV head of 128."""
     from horovod_tpu.kernels import blockdiff_attention as bd
-    from horovod_tpu.models.transformer import _blockdiff_einsum
+    from horovod_tpu.kernels import masked_attention
 
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, w = (jax.random.normal(k, (1, 2 * bd.BLOCK, 2, 128)) for k in ks[:2])
@@ -243,8 +243,8 @@ def test_kernel_in_interpret_mode_matches_the_einsum_with_grouped_heads():
     with jax.default_matmul_precision("highest"):
         got, got_grads = through(lambda *qkv: bd.blockdiff_attention(
             *qkv, block=4, interpret=True))(q, k, v)
-        want, want_grads = through(lambda *qkv: _blockdiff_einsum(
-            *qkv, 128, 4))(q, k, v)
+        want, want_grads = through(lambda *qkv: masked_attention.einsum(
+            *qkv, bd.BlockDiffusion(4)))(q, k, v)
     assert abs(float(got) - float(want)) < 1e-4 * abs(float(want))
     for g, wg in zip(got_grads, want_grads):
         assert rel_err(g, wg) < 1e-5
@@ -524,15 +524,26 @@ def test_share_counters_become_gauges():
 # sha256 of the lowered text on the parent commit (84b7007), JAX 0.9.0:
 # tests/test_olmoe.py's tiny OLMoE model in bf16, loss and gradients, on
 # (3, 32) tokens; moe_ffn with every expert held at [2, 16, 64] x 8 experts of
-# width 32, k = 2, gradients of all five operands.
+# width 32, k = 2, gradients of all five operands.  And on PR 34's parent
+# (fbf0cef), before kernels/blockdiff_attention.py was folded into
+# kernels/masked_attention.py: this file's tiny SDAR model in bf16, loss and
+# gradients, on 2 x 16 noised tokens (the einsum under the block mask); the
+# jaxpr of the block-diffusion kernel's call as a TPU gets it, forward and
+# the three gradients, 4 query heads on 2 KV heads of 128 at two tiles (the
+# kernels' names, tiles, grids, layout and scale are in that text).
 PARENT = {"jax": "0.9.0",
           "olmoe_tiny_step":
           "8087d002d28e741e1ac6df77c3274d5d667553b0a46e39fa0118d79057e04382",
           "moe_ffn_all_held":
-          "1201d0c17d334896bcada19038041e50b74a544e9315c5c507cebc314c7f976c"}
+          "1201d0c17d334896bcada19038041e50b74a544e9315c5c507cebc314c7f976c",
+          "sdar_tiny_step":
+          "03bd56b7caf4f535575434484979afbb09f5e4c8cc5ac36998d8100bd9b81149",
+          "blockdiff_kernel_call":
+          "fbe7a75d83f37991f5396e962dd361d15372fbd0f7c56fa0ce835f704a67ce54"}
 
 
-@pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held"])
+@pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held",
+                                   "sdar_tiny_step", "blockdiff_kernel_call"])
 def test_lowers_to_what_the_parent_lowered_to(which):
     from horovod_tpu.parallel.moe import moe_ffn
 
@@ -548,6 +559,27 @@ def test_lowers_to_what_the_parent_lowered_to(which):
         text = jax.jit(jax.value_and_grad(
             test_olmoe.program_loss(model, sizes), has_aux=True)).lower(
                 params, tokens).as_text()
+    elif which == "sdar_tiny_step":
+        model, sizes = tiny_model(jnp.bfloat16)
+        batch = jax.eval_shape(lambda: noised(sizes, 0))
+        params = nn.meta.unbox(jax.eval_shape(
+            model.init, jax.random.PRNGKey(0),
+            jax.ShapeDtypeStruct((1, 32), jnp.int32))["params"])
+        text = jax.jit(jax.value_and_grad(
+            program_loss(model, sizes), has_aux=True)).lower(
+                params, batch).as_text()
+    elif which == "blockdiff_kernel_call":
+        from horovod_tpu.kernels import blockdiff_attention as bd
+
+        q = jax.ShapeDtypeStruct((1, 2 * bd.BLOCK, 4, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 2 * bd.BLOCK, 2, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return jnp.sum(bd.blockdiff_attention(q, k, v, block=4)
+                           .astype(jnp.float32))
+
+        text = str(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, kv, kv).jaxpr)
     else:
         d, f, e, k = 64, 32, 8, 2
         shape = jax.ShapeDtypeStruct

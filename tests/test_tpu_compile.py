@@ -134,6 +134,36 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+@pytest.mark.parametrize("rule_name", ["window", "causal"])
+def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
+                                                          no_compile_cache):
+    """One sequence of 16384 positions, 28 query heads on 4 KV heads of 128,
+    causal and causal inside a window of 4096: forward and both backward
+    kernels with the tiles ``kernels/masked_attention.py`` gives them, KV
+    heads not repeated, and no [s, s] table or score square in the
+    program."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    rule = ma.Window(4096) if rule_name == "window" else ma.Causal()
+    q = _shape((1, 16384, 28, 128), jnp.bfloat16, one_chip)
+    kv = _shape((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, rule).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals",
+                       "splash_mha_dq_no_residuals",
+                       "splash_mha_dkv_no_residuals"}, kernels
+    assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
+    assert "16384,16384" not in text
+    assert "28,16384,128" in text and "bf16[1,16384,28,128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_expert_share_compiles_at_published_widths(one_chip,
                                                    no_compile_cache):
     """16384 positions through the 16 held of 128 experts of 2048 x 768, 8 a
