@@ -203,53 +203,69 @@ def _moe_rows(x, router, gate, up, down, *route_by, k, dtype,
 # -- a share of the experts ---------------------------------------------------
 
 
-def _rows_to_tokens(rows, token, tokens):
-    """``rows [cap, d]`` (fp32) added up by ``token [cap]`` into ``[tokens,
-    d]``: one scatter-add of the chunk's rows, in the order of the places,
-    where a gather the other way round fetches a row for each of the
-    ``tokens * k`` slots.  A row whose token is ``tokens`` (an unused place)
-    is dropped."""
+def _rows_to_tokens(rows, token, group, tokens, ws=None, slot=None):
+    """``rows [cap, d]``, each times its slot's weight ``ws[slot]`` where
+    weights are given, added up by ``token [cap]`` into ``[tokens, d]``, in
+    fp32.  A row whose token is ``tokens`` (an unused place) is dropped.  The
+    places are runs of lengths ``group``, the tokens ascending inside each.
+
+    On the TPU, for the shapes it takes, ``kernels/rows_to_tokens.py``: the
+    rows read once as they are, each token's sum written once.  Elsewhere
+    one scatter-add of the chunk's rows, in the order of the places, where a
+    gather the other way round fetches a row for each of the ``tokens * k``
+    slots."""
+    # Here, not at the top: the package brings flax with it, which a user of
+    # horovod_tpu.parallel alone does not import.
+    from ..kernels import rows_to_tokens as kernel
+
+    if jax.default_backend() == "tpu" \
+            and kernel.takes(*rows.shape, tokens, rows.dtype):
+        return kernel.rows_to_tokens(
+            rows, token, group, tokens, None if ws is None else ws[slot])
+    rows = rows.astype(jnp.float32)
+    if ws is not None:
+        rows = rows * ws[slot][:, None]
     return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token].add(
         rows, mode="drop")
 
 
 @jax.custom_vjp
-def _spread(x, token):
+def _spread(x, token, group):
     """``x[token]``: the rows of the routed slots that sit at one chunk of the
     sorted places, a zero row where the place is unused (``token`` is then
-    one past the last).  The cotangent adds the chunk's rows up by token in
-    fp32 (:func:`_rows_to_tokens`), where autodiff would add in ``x``'s
-    dtype."""
+    one past the last); ``group`` are the lengths of the chunk's runs.  The
+    cotangent adds the chunk's rows up by token in fp32
+    (:func:`_rows_to_tokens`), where autodiff would add in ``x``'s dtype."""
     return x.at[token].get(mode="fill", fill_value=0)
 
 
-def _spread_fwd(x, token):
+def _spread_fwd(x, token, group):
     # x[:, :0] holds nothing and tells the cotangent how many tokens there are.
-    return _spread(x, token), (token, x[:, :0])
+    return _spread(x, token, group), (token, group, x[:, :0])
 
 
 def _spread_bwd(res, g):
-    token, like = res
-    return (_rows_to_tokens(g.astype(jnp.float32), token, like.shape[0])
-            .astype(g.dtype), None)
+    token, group, like = res
+    return (_rows_to_tokens(g, token, group, like.shape[0]).astype(g.dtype),
+            None, None)
 
 
 _spread.defvjp(_spread_fwd, _spread_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(out, ws, slot, token, tokens):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(out, ws, slot, token, group, tokens):
     """``y[t] = sum ws[slot[p]] * out[p]`` over the chunk's places p that
-    hold a slot of token t, in fp32 (:func:`_rows_to_tokens`).  Backward,
-    both cotangents are taken at the chunk's places (a gather of ``g`` rows
-    by token), so nothing of ``[tokens*k, d]`` is fetched or kept in either
-    direction."""
-    return _rows_to_tokens(out.astype(jnp.float32) * ws[slot][:, None],
-                           token, tokens)
+    hold a slot of token t, in fp32 (:func:`_rows_to_tokens`; ``group`` are
+    the lengths of the chunk's runs).  Backward, both cotangents are taken
+    at the chunk's places (a gather of ``g`` rows by token), so nothing of
+    ``[tokens*k, d]`` is fetched or kept in either direction."""
+    return _rows_to_tokens(out, token, group, tokens, ws, slot)
 
 
-def _combine_fwd(out, ws, slot, token, tokens):
-    return _combine(out, ws, slot, token, tokens), (out, ws, slot, token)
+def _combine_fwd(out, ws, slot, token, group, tokens):
+    return (_combine(out, ws, slot, token, group, tokens),
+            (out, ws, slot, token))
 
 
 def _combine_bwd(tokens, res, g):
@@ -263,7 +279,7 @@ def _combine_bwd(tokens, res, g):
     return (d_out,
             jnp.zeros_like(ws).at[slot].set(d_w.astype(ws.dtype),
                                             unique_indices=True),
-            None, None)
+            None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -282,14 +298,14 @@ def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
         slot = lax.dynamic_slice_in_dim(order, lo, cap)
         # Each place's token, and one past the last where it is unused.
         token = jnp.where(jnp.arange(cap) < jnp.sum(group), slot // k, tokens)
-        rows_in = _spread(xf, token)
+        rows_in = _spread(xf, token, group)
     with jax.named_scope("hvd.moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=group,
                                     preferred_element_type=dtype)
         hidden = act(grouped(rows_in, gate)) * grouped(rows_in, up)
         out = grouped(hidden, down)                            # [cap, d]
     with jax.named_scope("hvd.moe.combine"):
-        return _combine(out, ws, slot, token, tokens)
+        return _combine(out, ws, slot, token, group, tokens)
 
 
 def _overflow_chunks(chunk, chunks: int, cap: int):

@@ -206,3 +206,86 @@ def test_expert_share_compiles_at_published_widths(one_chip,
     assert scatters and set(scatters) == {"16384"}, scatters
     # The parent's (1d339cd) count for this program was 1,734,507,520 bytes.
     assert compiled.memory_analysis().temp_size_in_bytes <= 1_734_507_520
+
+
+# (tokens, d, k, held, experts, width, activation, the most temporary bytes):
+# the two cells that run moe_ffn(held=).
+_SHARE_CELLS = {
+    "smallthinker-21b-a3b": (16384, 2560, 6, 8, 64, 768, "relu",
+                             1_400_000_000),
+    # The parent's scatter path took 1,734,507,520; the kernel's tokens and
+    # weights spread over the lanes add 2 x 16.8 MB a call.
+    "sdar-30b-a3b": (16384, 2048, 8, 16, 128, 768, "silu", 1_800_000_000),
+}
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["ones", "weighted"])
+@pytest.mark.parametrize("cell", sorted(_SHARE_CELLS))
+def test_rows_to_tokens_compiles_at_the_cells_shapes(cell, weighted, one_chip,
+                                                     no_compile_cache):
+    """``kernels/rows_to_tokens.py`` for a chunk of each cell (24,576 rows of
+    2560 in 8 runs, 32,768 of 2048 in 16), with the router's weights and
+    without: the chip's compiler takes the copies of 16-row pieces, the
+    transposes of the tokens and weights and the scalars it prefetches."""
+    from horovod_tpu.kernels import rows_to_tokens as rt
+    from horovod_tpu.parallel.moe import row_buffer
+
+    tokens, d, k, held, experts = _SHARE_CELLS[cell][:5]
+    _, cap = row_buffer(tokens * k, held, experts)
+    assert rt.takes(cap, d, tokens)
+    args = [_shape((cap, d), jnp.bfloat16, one_chip),
+            _shape((cap,), jnp.int32, one_chip),
+            _shape((held,), jnp.int32, one_chip)]
+    if weighted:
+        args.append(_shape((cap,), jnp.float32, one_chip))
+    text = jax.jit(lambda r, t, g, w=None: rt.rows_to_tokens(
+        r, t, g, tokens, w)).lower(*args).compile().as_text()
+    assert len(re.findall(rf"%{rt.OP_LINE_NAME}[.\d]* =", text)) == 1
+    assert f"f32[{tokens},{d}]" in text and " scatter(" not in text
+
+
+@pytest.mark.parametrize("cell", sorted(_SHARE_CELLS))
+def test_expert_share_through_the_rows_kernel_compiles(cell, topo,
+                                                       no_compile_cache,
+                                                       monkeypatch):
+    """The share of a layer as the cells run it on the chip (under the one
+    device's mesh, so inside ``moe_ffn``'s shard_map), with the way back to
+    token order through the kernel: four calls (the first chunk's combine and
+    dispatch cotangent, and those of the chunks behind it under their
+    conditional), no scatter of rows left, no more temporary memory."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.kernels import rows_to_tokens as rt
+    from horovod_tpu.parallel.moe import moe_ffn
+
+    tokens, d, k, held, experts, width, act, most = _SHARE_CELLS[cell]
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+
+    def shape(dims, dtype, spec=P()):
+        return _shape(dims, dtype, NamedSharding(mesh, spec))
+
+    args = [shape((1, tokens, d), jnp.bfloat16, P("data")),
+            shape((d, experts), jnp.float32),
+            shape((held, d, width), jnp.float32),
+            shape((held, d, width), jnp.float32),
+            shape((held, width, d), jnp.float32)]
+
+    def loss(*a):
+        y, stats = moe_ffn(*a, k=k, held=tuple(range(held)),
+                           norm_topk_prob=True, activation=act,
+                           data_axis="data")
+        return jnp.sum(y.astype(jnp.float32) ** 2) \
+            + jnp.sum(stats.load_balancing_loss)
+
+    # The program asks which backend it runs on; here that is the CPU.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(rf"%{rt.OP_LINE_NAME}[.\d]* =", text)) == 4
+    assert " conditional(" in text
+    assert not re.findall(rf"= \w+\[\d+,{d}\]\S* scatter\(", text)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* =", text)) in (18, 21)
+    assert compiled.memory_analysis().temp_size_in_bytes <= most
