@@ -46,7 +46,7 @@ from ..common.logging_util import get_logger
 from ..common.topology import ProcessTopology
 from ..core.messages import Response, ResponseType
 from ..core.tensor_queue import Status, TensorTableEntry
-from ..core.timeline import phase, program_call
+from ..core.timeline import phase, program_call, scope
 
 log = get_logger("horovod_tpu.backend.xla")
 
@@ -198,11 +198,13 @@ class XlaContext:
 
         def build():
             def hvd_fuse(*tensors):
-                flat = [t.ravel() for t in tensors]
-                total = sum(int(np.prod(s)) if s else 1 for s in shapes)
-                if bucket > total:
-                    flat.append(jnp.zeros((bucket - total,), np_dtype))
-                return jnp.concatenate(flat) if len(flat) > 1 else flat[0]
+                with scope("fuse"):
+                    flat = [t.ravel() for t in tensors]
+                    total = sum(int(np.prod(s)) if s else 1 for s in shapes)
+                    if bucket > total:
+                        flat.append(jnp.zeros((bucket - total,), np_dtype))
+                    return jnp.concatenate(flat) if len(flat) > 1 \
+                        else flat[0]
             return jax.jit(hvd_fuse)
 
         fused = program_call(self._get(key, build),
@@ -226,10 +228,11 @@ class XlaContext:
             def hvd_unfuse(x):
                 outs = []
                 off = 0
-                for s in shapes:
-                    n = int(np.prod(s)) if s else 1
-                    outs.append(x[off:off + n].reshape(s))
-                    off += n
+                with scope("fuse"):
+                    for s in shapes:
+                        n = int(np.prod(s)) if s else 1
+                        outs.append(x[off:off + n].reshape(s))
+                        off += n
                 return tuple(outs)
             return jax.jit(hvd_unfuse)
 
@@ -276,13 +279,14 @@ class XlaContext:
             widen = dt.itemsize <= 2 and jnp.issubdtype(dt, jnp.floating)
 
             def hvd_allreduce(x):
-                acc = x.astype(jnp.float32) if widen else x
-                if prescale != 1.0:
-                    acc = acc * prescale
-                s = jnp.sum(acc, axis=0)
-                if postscale != 1.0:
-                    s = s * postscale
-                return s.astype(dt)
+                with scope("allreduce"):
+                    acc = x.astype(jnp.float32) if widen else x
+                    if prescale != 1.0:
+                        acc = acc * prescale
+                    s = jnp.sum(acc, axis=0)
+                    if postscale != 1.0:
+                        s = s * postscale
+                    return s.astype(dt)
 
             return jax.jit(hvd_allreduce, in_shardings=(in_sh,),
                            out_shardings=rep)
@@ -307,11 +311,12 @@ class XlaContext:
 
             def hvd_local_allreduce(*ts):
                 outs = []
-                for t in ts:
-                    acc = t.astype(jnp.float32) if widen else t
-                    if scale != 1.0:
-                        acc = acc * scale
-                    outs.append(acc.astype(dt))
+                with scope("allreduce"):
+                    for t in ts:
+                        acc = t.astype(jnp.float32) if widen else t
+                        if scale != 1.0:
+                            acc = acc * scale
+                        outs.append(acc.astype(dt))
                 return tuple(outs)
 
             return jax.jit(hvd_local_allreduce)
@@ -339,19 +344,21 @@ class XlaContext:
             widen = dt.itemsize <= 2 and jnp.issubdtype(dt, jnp.floating)
 
             def hvd_allreduce_unfuse(x):
-                acc = x.astype(jnp.float32) if widen else x
-                if prescale != 1.0:
-                    acc = acc * prescale
-                s = jnp.sum(acc, axis=0)
-                if postscale != 1.0:
-                    s = s * postscale
-                s = s.astype(dt)
+                with scope("allreduce"):
+                    acc = x.astype(jnp.float32) if widen else x
+                    if prescale != 1.0:
+                        acc = acc * prescale
+                    s = jnp.sum(acc, axis=0)
+                    if postscale != 1.0:
+                        s = s * postscale
+                    s = s.astype(dt)
                 outs = []
                 off = 0
-                for shp in shapes:
-                    n = int(np.prod(shp)) if shp else 1
-                    outs.append(s[off:off + n].reshape(shp))
-                    off += n
+                with scope("fuse"):
+                    for shp in shapes:
+                        n = int(np.prod(shp)) if shp else 1
+                        outs.append(s[off:off + n].reshape(shp))
+                        off += n
                 return tuple(outs)
 
             return jax.jit(hvd_allreduce_unfuse, in_shardings=(in_sh,),
@@ -397,33 +404,37 @@ class XlaContext:
                                           jnp.float32))
                 return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
-            def hvd_adasum(x):  # [1, bucket] local block
-                v = x.reshape(-1).astype(jnp.float32)
-                if prescale != 1.0:
-                    v = v * prescale
-                for k in range(rounds):
-                    stride = 1 << k
-                    # pair exchange: r <-> r XOR stride
-                    perm = [(r, r ^ stride) for r in range(size)]
-                    other = jax.lax.ppermute(v, "proc", perm)
-                    v = combine(v, other)
-                if postscale != 1.0:
-                    v = v * postscale
-                out = v.astype(dt)
-                return tuple(
-                    out[bounds[i]:bounds[i + 1]].reshape(shapes[i])
-                    for i in range(len(shapes)))
-
-            if size == 1:
-                def hvd_adasum_local(x):
-                    v = x.reshape(-1).astype(jnp.float32)
-                    scale = prescale * postscale
-                    if scale != 1.0:
-                        v = v * scale
-                    out = v.astype(dt)
+            def cut(out):
+                with scope("fuse"):
                     return tuple(
                         out[bounds[i]:bounds[i + 1]].reshape(shapes[i])
                         for i in range(len(shapes)))
+
+            def hvd_adasum(x):  # [1, bucket] local block
+                with scope("allreduce"):
+                    v = x.reshape(-1).astype(jnp.float32)
+                    if prescale != 1.0:
+                        v = v * prescale
+                    for k in range(rounds):
+                        stride = 1 << k
+                        # pair exchange: r <-> r XOR stride
+                        perm = [(r, r ^ stride) for r in range(size)]
+                        other = jax.lax.ppermute(v, "proc", perm)
+                        v = combine(v, other)
+                    if postscale != 1.0:
+                        v = v * postscale
+                    out = v.astype(dt)
+                return cut(out)
+
+            if size == 1:
+                def hvd_adasum_local(x):
+                    with scope("allreduce"):
+                        v = x.reshape(-1).astype(jnp.float32)
+                        scale = prescale * postscale
+                        if scale != 1.0:
+                            v = v * scale
+                        out = v.astype(dt)
+                    return cut(out)
 
                 return jax.jit(hvd_adasum_local)
 

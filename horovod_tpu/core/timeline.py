@@ -506,6 +506,40 @@ class phase(span_ids):
             phase_stats.add(self.name, self.seconds, self.n)  # hvdlint: disable=HVD007 -- the one forwarding site: HVD007 checks the literal at every phase(...) call
 
 
+#: Every block of a step on the device, for :func:`scope`.  A step builder's
+#: parts come first, then a transformer's blocks, the expert layer's, the
+#: attention kernels by their mask's rule, ResNet's.  ``docs/observability.md``
+#: ("Device time by block") says what each covers and where it is entered.
+SCOPES = (
+    "loss", "optimizer", "fuse", "allreduce",
+    "embed", "norm", "ffn", "head",
+    "attn.proj", "attn.norm", "attn.rope", "attn.layout",
+    "attn.einsum", "attn.flash", "attn.ring", "attn.ulysses",
+    "attn.causal", "attn.window", "attn.blockdiff",
+    "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+    "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
+    "resnet.stage4", "resnet.head", "bn",
+)
+
+
+def scope(name: str):
+    """``with scope(name):`` — the device's counterpart of :func:`phase`:
+    every operation traced inside carries ``hvd.<name>`` in its HLO
+    ``op_name``, which the profiler records as the op's ``tf_op`` on the
+    device's ``XLA Ops`` line, in the same ``.xplane.pb`` and on the same
+    clock as the host's spans.  ``name`` is one of :data:`SCOPES`; scopes
+    nest, and a reader takes the innermost (``chip_bench/scopes.py``).  It
+    is ``jax.named_scope`` and so metadata alone: it exists while a program
+    is traced, adds no operation to it, and costs a compiled step
+    nothing."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; timeline.SCOPES has "
+                         f"{', '.join(SCOPES)}")
+    import jax
+
+    return jax.named_scope("hvd." + name)
+
+
 def program_call(fn, *args):
     """Call a jitted program the framework owns inside a ``program_call``
     phase named for it.  ``count`` grows by the number of arrays the

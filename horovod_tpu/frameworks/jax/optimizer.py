@@ -29,7 +29,7 @@ import numpy as np
 from . import ops, wfbp
 from ...common.exceptions import HorovodInternalError
 from ...common.logging_util import get_logger
-from ...core.timeline import phase, program_call
+from ...core.timeline import phase, program_call, scope
 from .compression import Compression
 
 log = get_logger(__name__)
@@ -123,11 +123,14 @@ class DistributedState(NamedTuple):
 def _named_jit(name: str, fn):
     """``jax.jit(fn)`` as a program called ``name``: a trace's ``XLA
     Modules`` line then shows ``jit_<name>`` for it and not the
-    ``jit__lambda_`` or ``jit_update_fn`` of whatever was passed in."""
+    ``jit__lambda_`` or ``jit_update_fn`` of whatever was passed in.  Its
+    operations lie under the scope ``optimizer`` (a join or a cut inside
+    it under ``fuse``)."""
     import jax
 
     def program(*args):
-        return fn(*args)
+        with scope("optimizer"):
+            return fn(*args)
 
     program.__name__ = program.__qualname__ = name
     return jax.jit(program)

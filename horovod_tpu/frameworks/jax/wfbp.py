@@ -43,7 +43,7 @@ import jax
 import numpy as np
 
 from . import ops
-from ...core.timeline import phase, program_call
+from ...core.timeline import phase, program_call, scope
 from .compression import Compression
 
 # ---------------------------------------------------------------------------
@@ -97,11 +97,14 @@ def _fuse_plan(sig) -> FusePlan:
         groups.setdefault(dt, []).append(i)
     groups = list(groups.items())
 
+    # The three below trace under the scope ``fuse``, as a program of their
+    # own or inside a consumer's.
     def hvd_tree_flatten(leaves_in):
-        return tuple(
-            jnp.concatenate([leaves_in[i].ravel() for i in idxs])
-            if len(idxs) > 1 else leaves_in[idxs[0]].ravel()
-            for _, idxs in groups)
+        with scope("fuse"):
+            return tuple(
+                jnp.concatenate([leaves_in[i].ravel() for i in idxs])
+                if len(idxs) > 1 else leaves_in[idxs[0]].ravel()
+                for _, idxs in groups)
 
     def hvd_tree_join(leaves_in):
         # The join inside a consumer's program, behind the arithmetic that
@@ -112,27 +115,29 @@ def _fuse_plan(sig) -> FusePlan:
         # then rounds otherwise than when each leaf is an output of its
         # own; it fuses nothing into these.
         bufs = []
-        for dt, idxs in groups:
-            sizes = [int(np.prod(sig[i][0])) for i in idxs]
-            buf, off = jnp.zeros((sum(sizes),), dt), 0
-            for i, n in zip(idxs, sizes):
-                buf = jax.lax.dynamic_update_slice(
-                    buf, jnp.ravel(leaves_in[i]), (off,))
-                off += n
-            bufs.append(buf)
+        with scope("fuse"):
+            for dt, idxs in groups:
+                sizes = [int(np.prod(sig[i][0])) for i in idxs]
+                buf, off = jnp.zeros((sum(sizes),), dt), 0
+                for i, n in zip(idxs, sizes):
+                    buf = jax.lax.dynamic_update_slice(
+                        buf, jnp.ravel(leaves_in[i]), (off,))
+                    off += n
+                bufs.append(buf)
         return tuple(bufs)
 
     def hvd_tree_unflatten(bufs):
         # Shapes and offsets are static, so this also traces inside a
         # consumer's program, where a leaf is no output buffer.
         outs = [None] * len(sig)
-        for buf, (_, idxs) in zip(bufs, groups):
-            off = 0
-            for i in idxs:
-                shape = sig[i][0]
-                n = int(np.prod(shape)) if shape else 1
-                outs[i] = buf[off:off + n].reshape(shape)
-                off += n
+        with scope("fuse"):
+            for buf, (_, idxs) in zip(bufs, groups):
+                off = 0
+                for i in idxs:
+                    shape = sig[i][0]
+                    n = int(np.prod(shape)) if shape else 1
+                    outs[i] = buf[off:off + n].reshape(shape)
+                    off += n
         return tuple(outs)
 
     cached = FusePlan(sig, groups, jax.jit(hvd_tree_flatten), hvd_tree_join,
@@ -477,7 +482,18 @@ class OverlappedTrainStep:
 
         rep = self._replicated(ctx)
         bsh = self._batch_sharding(ctx)
-        loss_fn, tx = self._loss_fn, self._tx
+        tx = self._tx
+
+        def loss_fn(*args):
+            # Outermost: what the caller computes outside any block of a
+            # model is named, not lost (``timeline.SCOPES``).
+            with scope("loss"):
+                return self._loss_fn(*args)
+
+        def update(grads, s, p):
+            with scope("optimizer"):
+                updates, new_s = tx.update(grads, s, p)
+                return optax.apply_updates(p, updates), new_s
 
         p_sh = jax.tree_util.tree_map(lambda _: rep, params)
         s_sh = jax.tree_util.tree_map(lambda _: rep, opt_state)
@@ -490,8 +506,7 @@ class OverlappedTrainStep:
             def _step(p, s, a, b):
                 (loss, new_a), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(p, a, b)
-                updates, new_s = tx.update(grads, s, p)
-                new_p = optax.apply_updates(p, updates)
+                new_p, new_s = update(grads, s, p)
                 return new_p, new_s, new_a, loss
 
             donate = (0, 1, 2) if self._donate else ()
@@ -501,8 +516,7 @@ class OverlappedTrainStep:
 
         def _step(p, s, b):
             loss, grads = jax.value_and_grad(loss_fn)(p, b)
-            updates, new_s = tx.update(grads, s, p)
-            new_p = optax.apply_updates(p, updates)
+            new_p, new_s = update(grads, s, p)
             return new_p, new_s, loss
 
         return jax.jit(_step, in_shardings=(p_sh, s_sh, b_sh),

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core.timeline import scope
+
 # A regular expression for the kernels' names on the device's op line.
 OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
 
@@ -140,10 +142,14 @@ def attention(q, k, v, rule, *, interpret: bool = False):
                          f"width {d}")
     kernel = _kernel(rule, s, h, interpret)
     hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
-    with jax.named_scope(rule.scope):
-        out = jax.vmap(kernel)(hsd(q * jnp.asarray(d ** -0.5, q.dtype)),
-                               hsd(k), hsd(v))
-    return out.transpose(0, 2, 1, 3)
+    # The copies into and out of the kernels' [heads, positions, width]
+    # layout apart from the kernels, which alone lie under the rule's scope.
+    with scope("attn.layout"):
+        q, k, v = hsd(q * jnp.asarray(d ** -0.5, q.dtype)), hsd(k), hsd(v)
+    with scope(rule.scope.removeprefix("hvd.")):
+        out = jax.vmap(kernel)(q, k, v)
+    with scope("attn.layout"):
+        return out.transpose(0, 2, 1, 3)
 
 
 def einsum(q, k, v, rule):
@@ -151,11 +157,13 @@ def einsum(q, k, v, rule):
     iota comparisons: below the kernel's smallest shape, and off the TPU."""
     b, s, h, dh = q.shape
     h_kv = k.shape[2]
-    q = q.reshape(b, s, h_kv, h // h_kv, dh)
-    scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
-                        preferred_element_type=jnp.float32) * dh ** -0.5
-    mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
-                        lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
-    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bngqk,bknd->bqngd", probs, v).reshape(b, s, h, dh)
+    with scope("attn.einsum"):
+        q = q.reshape(b, s, h_kv, h // h_kv, dh)
+        scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
+                            preferred_element_type=jnp.float32) * dh ** -0.5
+        mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
+                            lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
+        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bngqk,bknd->bqngd", probs, v) \
+            .reshape(b, s, h, dh)

@@ -18,7 +18,20 @@ from typing import Any, Callable, Sequence, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..core.timeline import scope
+
 ModuleDef = Any
+
+
+class BatchNorm(nn.BatchNorm):
+    """flax's BatchNorm, statistics and normalisation, under the scope
+    ``bn``: inside a stage's scope it is the innermost, so BatchNorm's share
+    of the device's time and the convolutions' separate.  The name keeps the
+    parameters' paths (``BatchNorm_0``)."""
+
+    def __call__(self, x, *args, **kwargs):
+        with scope("bn"):
+            return super().__call__(x, *args, **kwargs)
 
 
 class BottleneckBlock(nn.Module):
@@ -109,7 +122,7 @@ class ResNet(nn.Module):
         bn_momentum, bn_epsilon = 0.9, 1e-5  # shared by BOTH norm paths
         conv = functools.partial(nn.Conv, use_bias=False, dtype=self.dtype,
                                  param_dtype=jnp.float32)
-        norm = functools.partial(nn.BatchNorm, use_running_average=not train,
+        norm = functools.partial(BatchNorm, use_running_average=not train,
                                  momentum=bn_momentum, epsilon=bn_epsilon,
                                  dtype=self.dtype, param_dtype=jnp.float32,
                                  force_float32_reductions=self.bn_f32_stats,
@@ -130,12 +143,14 @@ class ResNet(nn.Module):
                 FusedConv1x1BN, dtype=self.dtype, momentum=bn_momentum,
                 epsilon=bn_epsilon, use_running_average=not train,
                 mesh=self.fused_bn_mesh)
-        x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)],
-                 name="conv_init")(x)
-        x = norm(name="bn_init")(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        with scope("resnet.stem"):
+            x = x.astype(self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+            x = norm(name="bn_init")(x)
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=((1, 1), (1, 1)))
         block_kwargs = {}
         if fused_cb is not None:
             if self.block_cls is not BottleneckBlock:
@@ -148,12 +163,14 @@ class ResNet(nn.Module):
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = self.block_cls(self.num_filters * 2 ** i,
-                                   conv=conv, norm=norm, strides=strides,
-                                   **block_kwargs)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32,
-                     param_dtype=jnp.float32)(x)
+                with scope(f"resnet.stage{i + 1}"):
+                    x = self.block_cls(self.num_filters * 2 ** i,
+                                       conv=conv, norm=norm, strides=strides,
+                                       **block_kwargs)(x)
+        with scope("resnet.head"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32,
+                         param_dtype=jnp.float32)(x)
         return x
 
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core.timeline import scope
 from ..parallel.grad_sync import allreduce_gradients
 from ..parallel.sharding import shard_map_fn
 
@@ -106,18 +107,23 @@ def make_sharded_train_step(model: nn.Module, tx,
     def step(state: TrainState, batch) -> tuple:
         def loss(params):
             variables = {"params": params}
-            if has_batch_stats:
-                variables["batch_stats"] = state.batch_stats
-                logits, updated = model.apply(
-                    variables, batch["x"], mutable=["batch_stats"], **kwargs)
-                return loss_fn(logits, batch["y"]), updated["batch_stats"]
-            logits = model.apply(variables, batch["x"], **kwargs)
-            return loss_fn(logits, batch["y"]), None
+            with scope("loss"):
+                if has_batch_stats:
+                    variables["batch_stats"] = state.batch_stats
+                    logits, updated = model.apply(
+                        variables, batch["x"], mutable=["batch_stats"],
+                        **kwargs)
+                    return loss_fn(logits, batch["y"]), \
+                        updated["batch_stats"]
+                logits = model.apply(variables, batch["x"], **kwargs)
+                return loss_fn(logits, batch["y"]), None
 
         (loss_val, new_stats), grads = jax.value_and_grad(
             loss, has_aux=True)(state.params)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   opt_state=new_opt,
                                   batch_stats=new_stats if has_batch_stats
@@ -141,15 +147,19 @@ def make_seq_parallel_train_step(model: nn.Module, tx, mesh: Mesh,
 
     def local_step(state: TrainState, tokens, targets):
         def loss(params):
-            logits = model.apply({"params": params}, tokens)
-            return cross_entropy_loss(logits, targets)
+            with scope("loss"):
+                logits = model.apply({"params": params}, tokens)
+                return cross_entropy_loss(logits, targets)
 
         loss_val, grads = jax.value_and_grad(loss)(state.params)
         # Params are replicated: average grads and loss across every shard.
-        grads = allreduce_gradients(grads, axis_name=axes, op="average")
-        loss_val = jax.lax.pmean(loss_val, axes)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with scope("allreduce"):
+            grads = allreduce_gradients(grads, axis_name=axes, op="average")
+            loss_val = jax.lax.pmean(loss_val, axes)
+        with scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         return (state.replace(step=state.step + 1, params=new_params,
                               opt_state=new_opt), loss_val)
 

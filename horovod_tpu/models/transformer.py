@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core.timeline import scope
 from ..kernels import masked_attention
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
@@ -239,32 +240,37 @@ class Attention(nn.Module):
         b, s, _ = x.shape
         h, dh = cfg.num_heads, cfg.head_dim
         h_kv = cfg.num_kv_heads or h
-        if cfg.num_kv_heads is None:
-            # Column-parallel qkv: heads split over the model axis.
-            qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis), "qkv")(x)
-            q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
-        else:
-            if h % h_kv:
-                raise ValueError(f"{h} heads on {h_kv} KV heads")
-            q = _dense(cfg, h * dh, (None, cfg.model_axis), "q")(x) \
-                .reshape(b, s, h, dh)
-            kv = _dense(cfg, 2 * h_kv * dh, (None, cfg.model_axis), "kv")(x)
-            k, v = jnp.split(kv.reshape(b, s, 2 * h_kv, dh), 2, axis=2)
-        if cfg.qk_norm == "head":
-            # Over each head's width; the heads share the scale.
-            q = _norm(cfg, "q_norm")(q).astype(cfg.dtype)
-            k = _norm(cfg, "k_norm")(k).astype(cfg.dtype)
-        elif cfg.qk_norm:
-            # Over the whole projection (all heads), as OLMoE has it.
-            flat = lambda t: t.reshape(b, s, -1)  # noqa: E731
-            q = _norm(cfg, "q_norm")(flat(q)).astype(cfg.dtype)
-            k = _norm(cfg, "k_norm")(flat(k)).astype(cfg.dtype)
-            q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h_kv, dh)
+        if h % h_kv:
+            raise ValueError(f"{h} heads on {h_kv} KV heads")
+        with scope("attn.proj"):
+            if cfg.num_kv_heads is None:
+                # Column-parallel qkv: heads split over the model axis.
+                qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis),
+                             "qkv")(x)
+                q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
+            else:
+                q = _dense(cfg, h * dh, (None, cfg.model_axis), "q")(x) \
+                    .reshape(b, s, h, dh)
+                kv = _dense(cfg, 2 * h_kv * dh, (None, cfg.model_axis),
+                            "kv")(x)
+                k, v = jnp.split(kv.reshape(b, s, 2 * h_kv, dh), 2, axis=2)
+        with scope("attn.norm"):
+            if cfg.qk_norm == "head":
+                # Over each head's width; the heads share the scale.
+                q = _norm(cfg, "q_norm")(q).astype(cfg.dtype)
+                k = _norm(cfg, "k_norm")(k).astype(cfg.dtype)
+            elif cfg.qk_norm:
+                # Over the whole projection (all heads), as OLMoE has it.
+                flat = lambda t: t.reshape(b, s, -1)  # noqa: E731
+                q = _norm(cfg, "q_norm")(flat(q)).astype(cfg.dtype)
+                k = _norm(cfg, "k_norm")(flat(k)).astype(cfg.dtype)
+                q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h_kv, dh)
         if cfg.positions == "rope" and self.kind.rope:
             if cfg.attention != "full":
                 raise ValueError("rope positions need attention='full'")
-            q = _rope(q, cfg.rope_theta, positions)
-            k = _rope(k, cfg.rope_theta, positions)
+            with scope("attn.rope"):
+                q = _rope(q, cfg.rope_theta, positions)
+                k = _rope(k, cfg.rope_theta, positions)
         if (h_kv != h or cfg.block_diffusion or self.kind.window) \
                 and cfg.attention != "full":
             raise ValueError("grouped KV heads, a window and the "
@@ -273,13 +279,15 @@ class Attention(nn.Module):
         if cfg.attention == "ring":
             from ..parallel.ring_attention import ring_attention
 
-            out = ring_attention(q, k, v, axis_name=cfg.seq_axis,
-                                 causal=cfg.causal)
+            with scope("attn.ring"):
+                out = ring_attention(q, k, v, axis_name=cfg.seq_axis,
+                                     causal=cfg.causal)
         elif cfg.attention == "ulysses":
             from ..parallel.ulysses import ulysses_attention
 
-            out = ulysses_attention(q, k, v, axis_name=cfg.seq_axis,
-                                    causal=cfg.causal)
+            with scope("attn.ulysses"):
+                out = ulysses_attention(q, k, v, axis_name=cfg.seq_axis,
+                                        causal=cfg.causal)
         elif cfg.attention == "full":
             out = _scaled_dot_attention(q, k, v, cfg.causal, dh,
                                         block_diffusion=cfg.block_diffusion,
@@ -287,9 +295,11 @@ class Attention(nn.Module):
         else:
             raise ValueError(f"unknown attention mode {cfg.attention!r}")
 
-        out = out.reshape(b, s, h * dh)
-        # Row-parallel output projection closes the TP pair.
-        return _dense(cfg, cfg.d_model, (cfg.model_axis, None), "out")(out)
+        with scope("attn.proj"):
+            out = out.reshape(b, s, h * dh)
+            # Row-parallel output projection closes the TP pair.
+            return _dense(cfg, cfg.d_model, (cfg.model_axis, None),
+                          "out")(out)
 
 
 # The pallas flash kernel's blocks, and the shortest sequence it takes.  On a
@@ -316,9 +326,13 @@ def _flash_attention(q, k, v, causal: bool, dh: int):
         block_q_major_dkv=n, block_k_major_dkv=n, block_k_dkv=n,
         block_q_dkv=n, block_k_major_dq=n, block_k_dq=n, block_q_dq=n)
     bhsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
-    o = flash_attention(bhsd(q), bhsd(k), bhsd(v), causal=causal,
-                        sm_scale=dh ** -0.5, block_sizes=blocks)
-    return o.transpose(0, 2, 1, 3)
+    with scope("attn.layout"):
+        q, k, v = bhsd(q), bhsd(k), bhsd(v)
+    with scope("attn.flash"):
+        o = flash_attention(q, k, v, causal=causal, sm_scale=dh ** -0.5,
+                            block_sizes=blocks)
+    with scope("attn.layout"):
+        return o.transpose(0, 2, 1, 3)
 
 
 def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
@@ -355,20 +369,22 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
         # Grouped KV heads under no mask: each KV head repeated for the query
         # heads it serves, then the paths below.
         group = q.shape[2] // k.shape[2]
-        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        with scope("attn.layout"):
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
             and s % _FLASH_BLOCK == 0 and dh % 128 == 0:
         return _flash_attention(q, k, v, causal, dh)
-    scale = dh ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        # From iota comparisons: a [s, s] constant is 16 MB at s = 4096.
-        mask = lax.broadcasted_iota(jnp.int32, (s, s), 0) >= \
-            lax.broadcasted_iota(jnp.int32, (s, s), 1)
-        scores = jnp.where(mask[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    with scope("attn.einsum"):
+        scale = dh ** -0.5
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if causal:
+            # From iota comparisons: a [s, s] constant is 16 MB at s = 4096.
+            mask = lax.broadcasted_iota(jnp.int32, (s, s), 0) >= \
+                lax.broadcasted_iota(jnp.int32, (s, s), 1)
+            scores = jnp.where(mask[None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 class Block(nn.Module):
@@ -379,13 +395,19 @@ class Block(nn.Module):
     def __call__(self, x, positions=None):
         cfg = self.cfg
         block_input = x
-        x = x + Attention(cfg, self.kind, name="attn")(
-            _norm(cfg, "ln1")(x), positions)
-        y = _norm(cfg, "ln2")(x)
+        with scope("norm"):
+            y = _norm(cfg, "ln1")(x)
+        y = Attention(cfg, self.kind, name="attn")(y, positions)
+        with scope("norm"):
+            x = x + y
+            y = _norm(cfg, "ln2")(x)
         if cfg.ffn == "gelu":
-            y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis), "ffn_in")(y)
-            y = nn.gelu(y)
-            y = _dense(cfg, cfg.d_model, (cfg.model_axis, None), "ffn_out")(y)
+            with scope("ffn"):
+                y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis),
+                           "ffn_in")(y)
+                y = nn.gelu(y)
+                y = _dense(cfg, cfg.d_model, (cfg.model_axis, None),
+                           "ffn_out")(y)
         elif cfg.ffn == "moe":
             if cfg.router_input not in ("ffn", "block"):
                 raise ValueError(f"unknown router_input {cfg.router_input!r}")
@@ -393,7 +415,8 @@ class Block(nn.Module):
                               else None)
         else:
             raise ValueError(f"unknown ffn {cfg.ffn!r}")
-        return x + y
+        with scope("norm"):
+            return x + y
 
     def _experts(self, y, router_input=None):
         """The sparse-expert FFN; its MoEStats are sown into the ``moe``
@@ -487,11 +510,12 @@ class Transformer(nn.Module):
         s = tokens.shape[1]
         if s > cfg.max_len:
             raise ValueError(f"{s} positions, max_len is {cfg.max_len}")
-        x = embed(tokens)
-        if cfg.positions == "learned":
-            x = x + self._learned_positions(s).astype(cfg.dtype)
-        elif cfg.positions != "rope":
+        if cfg.positions not in ("learned", "rope"):
             raise ValueError(f"unknown positions {cfg.positions!r}")
+        with scope("embed"):
+            x = embed(tokens)
+            if cfg.positions == "learned":
+                x = x + self._learned_positions(s).astype(cfg.dtype)
         if cfg.block_diffusion:
             if s % 2 or cfg.positions != "rope":
                 raise ValueError("block diffusion takes [x_t ; x_0], an even "
@@ -503,14 +527,17 @@ class Transformer(nn.Module):
             block = nn.remat(Block)
         for i in range(cfg.num_layers):
             x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(x, positions)
-        if cfg.block_diffusion:
-            x = x[:, :s // 2]
-        x = _norm(cfg, "ln_f")(x)
-        if cfg.tie_embeddings:
-            # Weight-tied readout against the (model-axis-sharded) embedding.
-            return embed.attend(x.astype(jnp.float32))
-        return _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
-                      "lm_head")(x)
+        with scope("norm"):
+            if cfg.block_diffusion:
+                x = x[:, :s // 2]
+            x = _norm(cfg, "ln_f")(x)
+        with scope("head"):
+            if cfg.tie_embeddings:
+                # Weight-tied readout against the (model-axis-sharded)
+                # embedding.
+                return embed.attend(x.astype(jnp.float32))
+            return _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
+                          "lm_head")(x)
 
     def _learned_positions(self, s):
         cfg = self.cfg

@@ -34,6 +34,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..core.timeline import scope
 from .collectives import axis_size
 from .mesh import AXIS_EXPERT
 
@@ -117,9 +118,10 @@ def _rows_to_slots_fwd(x, order, inverse, k):
 
 def _rows_to_slots_bwd(k, inverse, g):
     n = g.shape[0] // k
-    return (g[inverse].reshape(n, k, -1).sum(axis=1, dtype=jnp.float32)
-            .astype(g.dtype),
-            None, None)
+    with scope("moe.dispatch"):
+        return (g[inverse].reshape(n, k, -1).sum(axis=1, dtype=jnp.float32)
+                .astype(g.dtype),
+                None, None)
 
 
 _rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
@@ -132,8 +134,13 @@ def _slots_to_rows(y, order, inverse):
     return y[inverse]
 
 
+def _slots_to_rows_bwd(order, g):
+    with scope("moe.combine"):
+        return g[order], None, None
+
+
 _slots_to_rows.defvjp(lambda y, order, inverse: (y[inverse], order),
-                      lambda order, g: (g[order], None, None))
+                      _slots_to_rows_bwd)
 
 
 # The gate's activation: ``down(act(gate x) * up x)``.
@@ -155,9 +162,9 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None):
     the load-balancing loss and the z-loss, all over every expert of the
     router."""
     n, n_experts = xf.shape[0], router.shape[-1]
-    if router_input is not None:
-        xf = router_input.reshape(n, -1)
-    with jax.named_scope("hvd.moe.router"):
+    with scope("moe.router"):
+        if router_input is not None:
+            xf = router_input.reshape(n, -1)
         logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         probs = jax.nn.softmax(logits, axis=-1)
@@ -180,24 +187,26 @@ def _moe_rows(x, router, gate, up, down, *route_by, k, dtype,
     ``route_by`` is empty or ``(router_input,)``."""
     rows, tokens, d = x.shape
     n = rows * tokens
-    xf = x.reshape(n, d)
+    with scope("moe.dispatch"):
+        xf = x.reshape(n, d)
     weights, experts, counts, balance, z = _route(xf, router, k,
                                                   norm_topk_prob, *route_by)
-    with jax.named_scope("hvd.moe.dispatch"):
+    with scope("moe.dispatch"):
         order = jnp.argsort(experts.reshape(n * k))      # stable: by expert
         inverse = jnp.argsort(order)
         slots = _rows_to_slots(xf.astype(dtype), order, inverse, k)
-    with jax.named_scope("hvd.moe.experts"):
+    with scope("moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=counts,
                                     preferred_element_type=dtype)
         hidden = act(grouped(slots, gate.astype(dtype))) \
             * grouped(slots, up.astype(dtype))
         out = grouped(hidden, down.astype(dtype))              # [n*k, d]
-    with jax.named_scope("hvd.moe.combine"):
+    with scope("moe.combine"):
         out = _slots_to_rows(out, order, inverse).reshape(n, k, d)
         y = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), weights)
-    return (y.astype(dtype).reshape(rows, tokens, d),
-            MoEStats(balance[None], z[None], counts[None]))
+        y = y.astype(dtype).reshape(rows, tokens, d)
+    with scope("moe.router"):
+        return y, MoEStats(balance[None], z[None], counts[None])
 
 
 # -- a share of the experts ---------------------------------------------------
@@ -246,8 +255,10 @@ def _spread_fwd(x, token, group):
 
 def _spread_bwd(res, g):
     token, group, like = res
-    return (_rows_to_tokens(g, token, group, like.shape[0]).astype(g.dtype),
-            None, None)
+    with scope("moe.dispatch"):
+        return (_rows_to_tokens(g, token, group, like.shape[0])
+                .astype(g.dtype),
+                None, None)
 
 
 _spread.defvjp(_spread_fwd, _spread_bwd)
@@ -270,16 +281,17 @@ def _combine_fwd(out, ws, slot, token, group, tokens):
 
 def _combine_bwd(tokens, res, g):
     out, ws, slot, token = res
-    g_rows = g.at[token].get(mode="fill", fill_value=0)        # [cap, d] fp32
-    d_out = (g_rows * ws[slot][:, None]).astype(out.dtype)
-    # An unused place of ``out`` holds whatever the grouped product left.
-    d_w = jnp.where(token < tokens,
-                    jnp.sum(g_rows * out.astype(jnp.float32), axis=-1), 0)
-    # The slots of a chunk's places are distinct, its unused places' too.
-    return (d_out,
-            jnp.zeros_like(ws).at[slot].set(d_w.astype(ws.dtype),
-                                            unique_indices=True),
-            None, None, None)
+    with scope("moe.combine"):
+        g_rows = g.at[token].get(mode="fill", fill_value=0)    # [cap, d] fp32
+        d_out = (g_rows * ws[slot][:, None]).astype(out.dtype)
+        # An unused place of ``out`` holds whatever the grouped product left.
+        d_w = jnp.where(token < tokens,
+                        jnp.sum(g_rows * out.astype(jnp.float32), axis=-1), 0)
+        # The slots of a chunk's places are distinct, its unused places' too.
+        return (d_out,
+                jnp.zeros_like(ws).at[slot].set(d_w.astype(ws.dtype),
+                                                unique_indices=True),
+                None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -291,20 +303,21 @@ def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
     ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``sizes`` are the held
     experts' row counts over the whole step; this chunk takes of each what
     falls inside it."""
-    ends = jnp.cumsum(sizes)
-    group = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
     tokens = xf.shape[0]
-    with jax.named_scope("hvd.moe.dispatch"):
+    with scope("moe.dispatch"):
+        ends = jnp.cumsum(sizes)
+        group = jnp.clip(ends, lo, lo + cap) \
+            - jnp.clip(ends - sizes, lo, lo + cap)
         slot = lax.dynamic_slice_in_dim(order, lo, cap)
         # Each place's token, and one past the last where it is unused.
         token = jnp.where(jnp.arange(cap) < jnp.sum(group), slot // k, tokens)
         rows_in = _spread(xf, token, group)
-    with jax.named_scope("hvd.moe.experts"):
+    with scope("moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=group,
                                     preferred_element_type=dtype)
         hidden = act(grouped(rows_in, gate)) * grouped(rows_in, up)
         out = grouped(hidden, down)                            # [cap, d]
-    with jax.named_scope("hvd.moe.combine"):
+    with scope("moe.combine"):
         return _combine(out, ws, slot, token, group, tokens)
 
 
@@ -317,10 +330,10 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
     def reached(rows_held, j):
         return rows_held > j * cap
 
+    # The loops and sums around the chunks lie under ``moe.combine`` in both
+    # directions; what a chunk does keeps its own scopes inside.
     @jax.custom_vjp
     def run(xf, ws, gate, up, down, order, sizes):
-        rows_held = jnp.sum(sizes)
-
         def body(y, j):
             return lax.cond(
                 reached(rows_held, j),
@@ -328,10 +341,13 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
                                   j * cap),
                 lambda: y), None
 
-        # Zeros that vary over a mesh axis wherever the rows do (under
-        # moe_ffn's shard_map the branches' types would differ otherwise).
-        y, _ = lax.scan(body, (xf * 0).astype(jnp.float32),
-                        jnp.arange(1, chunks))
+        with scope("moe.combine"):
+            rows_held = jnp.sum(sizes)
+            # Zeros that vary over a mesh axis wherever the rows do (under
+            # moe_ffn's shard_map the branches' types would differ
+            # otherwise).
+            y, _ = lax.scan(body, (xf * 0).astype(jnp.float32),
+                            jnp.arange(1, chunks))
         return y
 
     def fwd(*operands):
@@ -339,7 +355,6 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
 
     def bwd(operands, g):
         weights, (order, sizes) = operands[:5], operands[5:]
-        rows_held = jnp.sum(sizes)
 
         def body(acc, j):
             def more():
@@ -350,8 +365,10 @@ def _overflow_chunks(chunk, chunks: int, cap: int):
 
             return lax.cond(reached(rows_held, j), more, lambda: acc), None
 
-        acc, _ = lax.scan(body, tuple(w * 0 for w in weights),
-                          jnp.arange(1, chunks))
+        with scope("moe.combine"):
+            rows_held = jnp.sum(sizes)
+            acc, _ = lax.scan(body, tuple(w * 0 for w in weights),
+                              jnp.arange(1, chunks))
         return (*acc, None, None)
 
     run.defvjp(fwd, bwd)
@@ -381,10 +398,11 @@ def _moe_rows_share(x, router, gate, up, down, *route_by, k, dtype, held,
             or not all(0 <= e < n_experts for e in held):
         raise ValueError(f"held experts {held} for {gate.shape[0]} stacked "
                          f"experts and a router of {n_experts}")
-    xf = x.reshape(n, d)
+    with scope("moe.dispatch"):
+        xf = x.reshape(n, d)
     weights, experts, counts, balance, z = _route(xf, router, k,
                                                   norm_topk_prob, *route_by)
-    with jax.named_scope("hvd.moe.dispatch"):
+    with scope("moe.dispatch"):
         # Each routed slot's expert as its index among the held ones; the
         # slots bound elsewhere sort behind them all.  By comparison with
         # every held id: a table lookup is a gather of tokens * k scalars
@@ -394,16 +412,20 @@ def _moe_rows_share(x, router, gate, up, down, *route_by, k, dtype, held,
                           len(held))
         order = jnp.argsort(local)
         sizes = counts[np.asarray(held)]
+        rows_in = (xf.astype(dtype), weights.reshape(n * k))
+    with scope("moe.experts"):
+        stacks = (gate.astype(dtype), up.astype(dtype), down.astype(dtype))
     chunks, cap = row_buffer(n * k, len(held), n_experts)
     chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype,
                               act=act)
-    operands = (xf.astype(dtype), weights.reshape(n * k), gate.astype(dtype),
-                up.astype(dtype), down.astype(dtype), order, sizes)
+    operands = (*rows_in, *stacks, order, sizes)
     y = chunk(*operands, 0)
-    if chunks > 1:
-        y = y + _overflow_chunks(chunk, chunks, cap)(*operands)
-    return (y.astype(dtype).reshape(rows, tokens, d),
-            MoEStats(balance[None], z[None], counts[None]))
+    with scope("moe.combine"):
+        if chunks > 1:
+            y = y + _overflow_chunks(chunk, chunks, cap)(*operands)
+        y = y.astype(dtype).reshape(rows, tokens, d)
+    with scope("moe.router"):
+        return y, MoEStats(balance[None], z[None], counts[None])
 
 
 def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
