@@ -514,7 +514,7 @@ SCOPES = (
     "loss", "optimizer", "fuse", "allreduce",
     "embed", "norm", "ffn", "head",
     "attn.proj", "attn.norm", "attn.rope", "attn.layout",
-    "attn.einsum", "attn.flash", "attn.ring", "attn.ulysses",
+    "attn.einsum", "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
     "attn.causal", "attn.window", "attn.blockdiff",
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",

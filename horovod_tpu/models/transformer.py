@@ -8,8 +8,8 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   ``nn.with_partitioning`` annotations over the ``model`` mesh axis
   (Megatron-style column→row sharding) so ``jit`` + GSPMD inserts the
   collectives — no hand-written TP code;
-- pluggable attention: ``full`` (XLA-fused einsum; the pallas flash kernel
-  from 4096 positions on, on a TPU), ``ring``
+- pluggable attention: ``full`` (XLA-fused einsum; on a TPU our fused kernel
+  at short lengths and the pallas flash kernel from 4096 positions), ``ring``
   (:func:`horovod_tpu.parallel.ring_attention`) or ``ulysses``
   (:func:`horovod_tpu.parallel.ulysses_attention`) for sequence-parallel
   long context — the latter two run inside ``shard_map`` with the ``seq``
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.timeline import scope
-from ..kernels import masked_attention
+from ..kernels import masked_attention, short_attention
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..parallel.moe import MoEStats, moe_ffn
@@ -306,10 +306,10 @@ class Attention(nn.Module):
 # v5e at b=2, h=16, s=4096, d=128, causal, forward + backward (PERF.md, PR
 # 27): the XLA-fused einsum 32.5 ms, the kernel with its default blocks of 128
 # 36.9 ms, with blocks of 512 7.8 ms and of 1024 7.4 ms; it also keeps the
-# [b, h, s, s] scores (2.1 GB in fp32 there) out of HBM.  With its default
-# blocks it had measured slower than the einsum at s=512 (27.6k against
-# 38.5k tokens/s, BERT-large b8) and s=2048 (11.7k against 14.4k, b2); those
-# shapes were not measured with larger blocks and stay on the einsum.
+# [b, h, s, s] scores (2.1 GB in fp32 there) out of HBM.  Shorter sequences
+# that kernels/short_attention.py takes (BERT-large's s=512 among them) keep
+# a head's whole score tile in VMEM instead (PERF.md section 6, PR 37, has
+# both kernels and the einsum side by side); the rest stays on the einsum.
 _FLASH_BLOCK = 1024
 _FLASH_MIN_SEQ = 4096
 
@@ -346,9 +346,9 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
     takes the shape, KV heads grouped and not repeated, and else through its
     einsum under the same mask.  A kernel that fails to lower fails the
     step: it is never silently the einsum.  Everything else (one KV head a
-    query head under ``causal``, no mask) takes the XLA-fused einsum softmax,
-    and on a TPU from ``_FLASH_MIN_SEQ`` positions on the pallas
-    flash-attention kernel, picked from the shape alone."""
+    query head under ``causal``, no mask) goes on a TPU, by its shape alone,
+    to the pallas flash kernel from ``_FLASH_MIN_SEQ`` positions on and to
+    ``kernels/short_attention.py`` at the lengths it takes; else the einsum."""
     s = q.shape[1]
     grouped = k.shape[2] != q.shape[2]
     rule = None
@@ -374,6 +374,10 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
     if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
             and s % _FLASH_BLOCK == 0 and dh % 128 == 0:
         return _flash_attention(q, k, v, causal, dh)
+    if jax.default_backend() == "tpu" \
+            and short_attention.takes(s, dh, q.shape[2], q.dtype):
+        with scope("attn.short"):
+            return short_attention.attention(q, k, v, causal)
     with scope("attn.einsum"):
         scale = dh ** -0.5
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,

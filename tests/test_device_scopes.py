@@ -244,8 +244,8 @@ def test_every_segment_of_every_program_is_in_the_vocabulary(scoped):
     # What a CPU run can reach of it: everything but the kernels' scopes and
     # the sequence-parallel attentions.
     assert set(SCOPES) - found == {
-        "attn.layout", "attn.flash", "attn.ring", "attn.ulysses",
-        "attn.causal", "attn.window", "attn.blockdiff"}
+        "attn.layout", "attn.flash", "attn.short", "attn.ring",
+        "attn.ulysses", "attn.causal", "attn.window", "attn.blockdiff"}
 
 
 def test_the_rules_of_the_attention_kernels_name_scopes_of_the_vocabulary():

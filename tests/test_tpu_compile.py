@@ -76,6 +76,45 @@ def test_flash_attention_compiles_at_olmoes_shape(one_chip, no_compile_cache):
     assert f"block_q_{_FLASH_BLOCK}" in text
 
 
+@pytest.mark.parametrize("shape,causal", [
+    ((8, 512, 16, 64), False),          # bert-large-wfbp-1chip
+    ((2, 2048, 16, 64), True),          # the longest it takes, two heads a
+    ((2, 2048, 16, 128), False),        # lane group and one
+], ids=["berts", "2048x64_causal", "2048x128"])
+def test_short_attention_compiles_at_berts_shape(shape, causal, one_chip,
+                                                 no_compile_cache,
+                                                 monkeypatch):
+    """``_scaled_dot_attention`` as a TPU sees it, forward and backward: the
+    two kernels of ``kernels/short_attention.py``, once each, reading the
+    projections' layout (no ``[b, h, s, d]`` copy), and no score square
+    anywhere in the program."""
+    from horovod_tpu.kernels import short_attention as sa
+    from horovod_tpu.models.transformer import _scaled_dot_attention
+
+    b, s, h, d = shape
+    assert sa.takes(s, d, h)
+    qkv = [_shape(shape, jnp.bfloat16, one_chip)] * 3
+    # The program asks which backend it runs on; here that is the CPU.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(q, k, v):
+        return jnp.sum(_scaled_dot_attention(q, k, v, causal, d)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *qkv).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%(hvd_short_attention\w*?)[.\d]* =", text)
+    assert sorted(kernels) == [sa.BWD_NAME, sa.FWD_NAME], kernels
+    assert all(re.match(sa.OP_LINE_NAMES, k) for k in kernels)
+    assert f",{h},{s},{s}]" not in text             # the scores, any dtype
+    assert f"[{b},{h},{s},{d}]" not in text
+    assert f"f32[{b},{h * d // 128},{128 // d},{s}]" in text    # log-sum-exp
+    # q, k, v, o and their cotangents, not a score tensor.
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 8 * b * s * h * d * 2
+
+
 def test_expert_layer_compiles_at_published_widths(one_chip,
                                                    no_compile_cache):
     """8192 tokens through 64 experts of 2048 x 1024, 8 a token: the grouped
