@@ -229,6 +229,11 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "moe_rows_elsewhere_share": (
         "gauge", "share of a layer's routed rows bound for experts that "
                  "live elsewhere (counted, not computed), per layer="),
+    "moe_expert_bias_abs_max": (
+        "gauge", "where the router chooses by scores plus a bias the step "
+                 "keeps (moe_ffn(bias=...), update_expert_bias): the largest "
+                 "magnitude among a layer's biases, per layer= ; zero until "
+                 "the first step, then a multiple of the update rate"),
     # -- attention under a layer pattern
     #    (models/transformer.py::publish_attention) --
     "attn_allowed_pairs_per_step": (

@@ -516,6 +516,7 @@ SCOPES = (
     "attn.proj", "attn.norm", "attn.rope", "attn.layout",
     "attn.einsum", "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
     "attn.causal", "attn.window", "attn.blockdiff",
+    "conv.proj", "conv.gate",
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",

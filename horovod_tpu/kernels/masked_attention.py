@@ -110,8 +110,10 @@ class Window:
 
 def takes(rule, seq_len: int, head_dim: int) -> bool:
     """Whether the kernel takes this shape under ``rule``; otherwise, and off
-    the TPU, the same mask goes through :func:`einsum`."""
-    return head_dim % 128 == 0 and rule.takes(seq_len)
+    the TPU, the same mask goes through :func:`einsum`.  Heads of 64 go in
+    as they are (LFM2-8B-A1B: 32 query heads on 8 KV heads): the library's
+    kernels take half a lane group, and the chip's compiler pads it."""
+    return (head_dim % 128 == 0 or head_dim == 64) and rule.takes(seq_len)
 
 
 @functools.lru_cache(maxsize=8)

@@ -14,7 +14,11 @@ MXU-sized matmuls):
   BERT-pretraining config) and decoder (GPT preset) with pluggable
   attention: full, ring (sequence-parallel long context), Ulysses; the
   OLMoE preset (RMSNorm, RoPE, QK-norm, untied head, dropless top-k
-  expert FFN) through options of the same config;
+  expert FFN) through options of the same config, and with them the
+  SDAR-30B-A3B (block diffusion, a share of the experts), SmallThinker-21BA3B
+  (a layer pattern of windows and positions) and LFM2-8B-A1B presets (gated
+  short convolutions among attention layers, a dense FFN before the expert
+  layers, sigmoid scores with a selection bias the step keeps);
 - :mod:`.training` — sharded train-step builders wiring models to the
   ``parallel`` layer and optax.
 """
@@ -26,8 +30,11 @@ from .transformer import (  # noqa: F401
     TransformerConfig,
     bert_large_config,
     gpt_small_config,
+    lfm2_8b_a1b_config,
     moe_stats,
     olmoe_1b_7b_config,
+    sdar_30b_a3b_config,
+    smallthinker_21b_a3b_config,
     tiny_config,
 )
 from .training import TrainState, make_sharded_train_step  # noqa: F401

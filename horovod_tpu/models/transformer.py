@@ -1,4 +1,5 @@
-"""Transformer encoder/decoder — BERT-large, GPT and OLMoE presets.
+"""Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker
+and LFM2 presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -19,13 +20,18 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   positions, QK-norm over the projection or per head, grouped KV heads, an
   explicit head width, biases, tied or untied head, dense or sparse-expert
   FFN, all experts or a share of them, the gate's activation, a router that
-  reads the FFN's input or the block's, causal, unmasked or block-diffusion
-  attention) and a layer pattern says which layers attend inside a window
-  and which carry rotary positions: OLMoE is ``olmoe_1b_7b_config()``,
-  SDAR-30B-A3B ``sdar_30b_a3b_config()`` and SmallThinker-21BA3B
-  ``smallthinker_21b_a3b_config()`` over the same ``Transformer``, their
-  expert layer :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``),
-  their masks that are rules ``kernels/masked_attention.py``'s.
+  reads the FFN's input or the block's, softmax or sigmoid scores with a
+  selection bias the step keeps, causal, unmasked or block-diffusion
+  attention) and a layer pattern says, layer by layer, which mixer runs
+  (attention, or the gated short convolution of :class:`ShortConv` over
+  ``kernels/short_conv.py``), which layers attend inside a window, which
+  carry rotary positions and which carry a gated dense FFN in place of the
+  configuration's: OLMoE is ``olmoe_1b_7b_config()``, SDAR-30B-A3B
+  ``sdar_30b_a3b_config()``, SmallThinker-21BA3B
+  ``smallthinker_21b_a3b_config()`` and LFM2-8B-A1B ``lfm2_8b_a1b_config()``
+  over the same ``Transformer``, their expert layer
+  :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``), their masks
+  that are rules ``kernels/masked_attention.py``'s.
 """
 
 from __future__ import annotations
@@ -39,7 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.timeline import scope
-from ..kernels import masked_attention, short_attention
+from ..kernels import masked_attention, short_attention, short_conv
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..parallel.moe import MoEStats, moe_ffn
@@ -50,10 +56,16 @@ class LayerKind(NamedTuple):
     causal inside this many positions (a query sees itself and the
     ``window - 1`` before it); 0: the model's mask.  ``rope``: whether the
     layer applies the rotary positions (only where ``positions == "rope"``);
-    a layer without them carries no position at all."""
+    a layer without them carries no position at all.  ``mixer``: what mixes
+    the tokens, ``"attention"`` or ``"conv"`` (:class:`ShortConv`, which
+    takes neither window nor positions).  ``ffn``: None, the
+    configuration's ``ffn``, or ``"dense"``, the gated dense FFN of width
+    ``d_ff_dense`` (a sparse model's leading dense layers)."""
 
     window: int = 0
     rope: bool = True
+    mixer: str = "attention"
+    ffn: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,9 +123,23 @@ class TransformerConfig:
     # place of `causal`, and returns the noisy half's logits.  0: off.
     block_diffusion: int = 0
     # One period of the layers' kinds, repeated over num_layers (layer i is
-    # layer_pattern[i % len]); None: every layer is LayerKind(), today's
-    # uniform model.
+    # layer_pattern[i % len]), or all num_layers of them where the layers
+    # keep to no period; None: every layer is LayerKind(), today's uniform
+    # model.
     layer_pattern: Optional[Tuple[LayerKind, ...]] = None
+    # The layers of kind ffn="dense": W_2(silu(W_1 x) * W_3 x) of this width.
+    d_ff_dense: int = 0
+    # The layers of kind mixer="conv": taps of the depthwise filter.
+    conv_taps: int = 3
+    # ffn == "moe": the router's scores, softmax | sigmoid (moe_ffn's
+    # ``scoring``); expert_bias: the "moe" collection carries a selection
+    # bias [num_experts] a layer (variable "bias"), added to the scores for
+    # the choice of the experts alone: state the training step keeps
+    # (moe.update_expert_bias), no parameter.  routed_scaling_factor: a
+    # factor on the chosen experts' weights.
+    router_scoring: str = "softmax"
+    expert_bias: bool = False
+    routed_scaling_factor: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -126,6 +152,12 @@ class TransformerConfig:
             raise ValueError(f"{self.num_layers} layers are no whole periods "
                              f"of {len(self.layer_pattern)}")
         return LayerKind(*self.layer_pattern[i % len(self.layer_pattern)])
+
+    def expert_layers(self) -> Tuple[int, ...]:
+        """The indices of the layers whose FFN (their kind's, else the
+        configuration's) is the sparse-expert one."""
+        return tuple(i for i in range(self.num_layers)
+                     if (self.layer_kind(i).ffn or self.ffn) == "moe")
 
 
 def bert_large_config(**overrides) -> TransformerConfig:
@@ -186,6 +218,29 @@ def smallthinker_21b_a3b_config(**overrides) -> TransformerConfig:
         expert_activation="relu",
         layer_pattern=(LayerKind(0, False),) + (LayerKind(4096, True),) * 3),
         **overrides})
+
+
+def lfm2_8b_a1b_config(**overrides) -> TransformerConfig:
+    """LFM2-8B-A1B (LiquidAI/LFM2-8B-A1B ``config.json``, ``lfm2_moe``): 24
+    layers, 18 of them gated short convolutions of 3 taps and 6 (layers 2, 6,
+    10, 14, 18, 21) causal attention of 32 query heads on 8 KV heads of 64
+    with per-head QK-norm and RoPE at 1e6; the first two layers carry a
+    dense SwiGLU of width 7168, the others 32 experts of width 1792, 4 a
+    token, chosen by sigmoid scores plus a bias the step keeps
+    (``expert_bias``), weighted by the scores renormalised; RMSNorm at 1e-5,
+    no biases, a tied readout."""
+    attention = (2, 6, 10, 14, 18, 21)
+    pattern = tuple(
+        LayerKind(0, True, "attention" if i in attention else "conv",
+                  "dense" if i < 2 else None) for i in range(24))
+    return TransformerConfig(**{**dict(
+        vocab_size=65536, num_layers=24, num_heads=32, num_kv_heads=8,
+        head_width=64, d_model=2048, d_ff=1792, d_ff_dense=7168,
+        max_len=128000, causal=True, norm="rmsnorm", norm_eps=1e-5,
+        positions="rope", rope_theta=1e6, qk_norm="head", use_bias=False,
+        tie_embeddings=True, ffn="moe", num_experts=32, experts_per_token=4,
+        norm_topk_prob=True, router_scoring="sigmoid", expert_bias=True,
+        layer_pattern=pattern), **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -391,6 +446,31 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+class ShortConv(nn.Module):
+    """LFM2's token mixer in place of attention: ``[B, C, X] = x W_in``,
+    ``y = (C * conv(B * X)) W_out``, the convolution depthwise, causal and
+    ``cfg.conv_taps`` long, with no activation but the two gates
+    (``kernels/short_conv.py``: its kernels on a TPU, ``jax.numpy``
+    elsewhere).  The projections lie under ``conv.proj``, the gates and taps
+    under ``conv.gate``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        with scope("conv.proj"):
+            bcx = _dense(cfg, 3 * cfg.d_model, (None, cfg.model_axis),
+                         "in_proj")(x)
+        taps = self.param("conv", nn.initializers.normal(0.02),
+                          (cfg.d_model, cfg.conv_taps), jnp.float32)
+        with scope("conv.gate"):
+            y = short_conv.gated_conv(bcx, taps)
+        with scope("conv.proj"):
+            return _dense(cfg, cfg.d_model, (cfg.model_axis, None),
+                          "out_proj")(y)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     kind: LayerKind = LayerKind()
@@ -401,24 +481,39 @@ class Block(nn.Module):
         block_input = x
         with scope("norm"):
             y = _norm(cfg, "ln1")(x)
-        y = Attention(cfg, self.kind, name="attn")(y, positions)
+        if self.kind.mixer == "conv":
+            y = ShortConv(cfg, name="conv")(y)
+        elif self.kind.mixer == "attention":
+            y = Attention(cfg, self.kind, name="attn")(y, positions)
+        else:
+            raise ValueError(f"unknown mixer {self.kind.mixer!r}")
         with scope("norm"):
             x = x + y
             y = _norm(cfg, "ln2")(x)
-        if cfg.ffn == "gelu":
+        ffn = self.kind.ffn or cfg.ffn
+        if ffn == "gelu":
             with scope("ffn"):
                 y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis),
                            "ffn_in")(y)
                 y = nn.gelu(y)
                 y = _dense(cfg, cfg.d_model, (cfg.model_axis, None),
                            "ffn_out")(y)
-        elif cfg.ffn == "moe":
+        elif ffn == "dense":
+            with scope("ffn"):
+                hidden = nn.silu(_dense(cfg, cfg.d_ff_dense,
+                                        (None, cfg.model_axis),
+                                        "ffn_gate")(y)) \
+                    * _dense(cfg, cfg.d_ff_dense, (None, cfg.model_axis),
+                             "ffn_up")(y)
+                y = _dense(cfg, cfg.d_model, (cfg.model_axis, None),
+                           "ffn_down")(hidden)
+        elif ffn == "moe":
             if cfg.router_input not in ("ffn", "block"):
                 raise ValueError(f"unknown router_input {cfg.router_input!r}")
             y = self._experts(y, block_input if cfg.router_input == "block"
                               else None)
         else:
-            raise ValueError(f"unknown ffn {cfg.ffn!r}")
+            raise ValueError(f"unknown ffn {ffn!r}")
         with scope("norm"):
             return x + y
 
@@ -437,12 +532,19 @@ class Block(nn.Module):
                                        ("experts_gate", (e, d, f)),
                                        ("experts_up", (e, d, f)),
                                        ("experts_down", (e, f, d)))]
+        # The selection bias is read here and stepped by the training step
+        # (``expert_bias_collection``, ``moe.update_expert_bias``).
+        bias = self.variable(
+            "moe", "bias", jnp.zeros, (routed,), jnp.float32).value \
+            if cfg.expert_bias else None
         y, stats = moe_ffn(y, *weights, k=cfg.experts_per_token,
                            data_axis=cfg.moe_data_axis, dtype=cfg.dtype,
                            held=cfg.experts_held,
                            norm_topk_prob=cfg.norm_topk_prob,
                            router_input=router_input,
-                           activation=cfg.expert_activation)
+                           activation=cfg.expert_activation,
+                           scoring=cfg.router_scoring, bias=bias,
+                           scale=cfg.routed_scaling_factor)
         self.sow("moe", "stats", stats)
         return y
 
@@ -454,6 +556,14 @@ def moe_stats(collection) -> MoEStats:
     layers = sorted(collection, key=lambda name: int(name.split("_")[-1]))
     return jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs), *[collection[n]["stats"][0] for n in layers])
+
+
+def expert_bias_collection(cfg: TransformerConfig, bias) -> dict:
+    """The ``moe`` collection that hands ``bias [expert layers, experts]``
+    to the layers of a model with ``cfg.expert_bias``:
+    ``model.apply({"params": p, "moe": this}, tokens, mutable=["moe"])``."""
+    return {f"layer_{i}": {"bias": bias[j]}
+            for j, i in enumerate(cfg.expert_layers())}
 
 
 def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
@@ -469,6 +579,8 @@ def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
         everywhere = seq_len * seq_len
     pairs = {"window": 0, "global": 0}
     for i in range(cfg.num_layers):
+        if cfg.layer_kind(i).mixer != "attention":
+            continue
         window = cfg.layer_kind(i).window
         if window:
             pairs["window"] += masked_attention.Window(window) \
@@ -538,7 +650,8 @@ class Transformer(nn.Module):
         with scope("head"):
             if cfg.tie_embeddings:
                 # Weight-tied readout against the (model-axis-sharded)
-                # embedding.
+                # embedding; ``attend`` multiplies in the table's ``dtype``
+                # whatever it is handed.
                 return embed.attend(x.astype(jnp.float32))
             return _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
                           "lm_head")(x)

@@ -20,7 +20,10 @@ routed rows a token, not one per expert.  Told which experts it ``held``
 over all of them and computes the part of the result its own experts give.
 The router may read another tensor than the rows it multiplies
 (``router_input``) and the gate's activation is an argument (SmallThinker:
-the block's input, ``relu``).
+the block's input, ``relu``).  Its scores are a softmax over all experts or a
+sigmoid each (``scoring``), and a ``bias`` an expert may enter the choice of
+the top k without entering their weights (LFM2-8B-A1B; the bias is state the
+step keeps by :func:`update_expert_bias`, not a parameter).
 """
 
 from __future__ import annotations
@@ -155,42 +158,66 @@ def _activation(name: str):
                          f"{sorted(_ACTIVATIONS)}") from None
 
 
-def _route(xf, router, k, norm_topk_prob=False, router_input=None):
+def _route(xf, router, k, norm_topk_prob=False, router_input=None,
+           scoring="softmax", bias=None, scale=1.0):
     """The router on rows ``xf [n, d]`` (or, where given, on ``router_input
     [rows, tokens, d_r]``, the same n rows), in fp32: each row's k weights
     and experts ``[n, k]``, the rows routed to each expert ``[experts]``,
     the load-balancing loss and the z-loss, all over every expert of the
-    router."""
+    router.  ``scoring``: the scores are a softmax over the experts, or a
+    sigmoid of each logit, whose k weights are divided by their sum plus
+    1e-6 under ``norm_topk_prob`` and carry no auxiliary loss (both zero).
+    ``bias [experts]`` is added to the scores for the choice of the k alone;
+    the weights are the scores themselves.  ``scale`` multiplies the
+    weights."""
     n, n_experts = xf.shape[0], router.shape[-1]
     with scope("moe.router"):
         if router_input is not None:
             xf = router_input.reshape(n, -1)
         logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = lax.top_k(probs, k)                 # [n, k]
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown scoring {scoring!r}")
+        if bias is None:
+            weights, experts = lax.top_k(probs, k)             # [n, k]
+        else:
+            _, experts = lax.top_k(
+                probs + lax.stop_gradient(bias.astype(jnp.float32)), k)
+            weights = jnp.take_along_axis(probs, experts, axis=-1)
         if norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            weights = weights / (total + 1e-6 if scoring == "sigmoid"
+                                 else total)
+        if scale != 1.0:
+            weights = weights * scale
         counts = jnp.sum(jax.nn.one_hot(experts, n_experts, dtype=jnp.int32),
                          axis=(0, 1))                          # [experts]
-        # Switch's loss over top-k (transformers' load_balancing_loss_func):
-        # experts * sum_e (routed share of e) * (mean probability of e).
-        balance = n_experts * jnp.sum(counts.astype(jnp.float32) / n
-                                      * jnp.mean(probs, axis=0))
-        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        if scoring == "sigmoid":
+            # The bias is the balancing: no loss of either kind.
+            balance = z = jnp.zeros((), jnp.float32)
+        else:
+            # Switch's loss over top-k (transformers'
+            # load_balancing_loss_func): experts * sum_e (routed share of e)
+            # * (mean probability of e).
+            balance = n_experts * jnp.sum(counts.astype(jnp.float32) / n
+                                          * jnp.mean(probs, axis=0))
+            z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     return weights, experts, counts, balance, z
 
 
-def _moe_rows(x, router, gate, up, down, *route_by, k, dtype,
-              norm_topk_prob=False, act=jax.nn.silu):
+def _moe_rows(x, router, gate, up, down, *, k, dtype, act=jax.nn.silu,
+              **route):
     """:func:`moe_ffn` on the rows of one rank, routed as one set;
-    ``route_by`` is empty or ``(router_input,)``."""
+    ``route`` is what :func:`_route` takes besides the rows."""
     rows, tokens, d = x.shape
     n = rows * tokens
     with scope("moe.dispatch"):
         xf = x.reshape(n, d)
-    weights, experts, counts, balance, z = _route(xf, router, k,
-                                                  norm_topk_prob, *route_by)
+    weights, experts, counts, balance, z = _route(xf, router, k, **route)
     with scope("moe.dispatch"):
         order = jnp.argsort(experts.reshape(n * k))      # stable: by expert
         inverse = jnp.argsort(order)
@@ -386,11 +413,11 @@ def row_buffer(slots: int, n_held: int, n_experts: int):
     return chunks, slots // chunks
 
 
-def _moe_rows_share(x, router, gate, up, down, *route_by, k, dtype, held,
-                    norm_topk_prob, act=jax.nn.silu):
+def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
+                    act=jax.nn.silu, **route):
     """:func:`moe_ffn` on the rows of one rank where only ``held`` of the
-    router's experts live here; ``route_by`` is empty or
-    ``(router_input,)``."""
+    router's experts live here; ``route`` is what :func:`_route` takes
+    besides the rows."""
     rows, tokens, d = x.shape
     n, n_experts = rows * tokens, router.shape[-1]
     held = tuple(held)
@@ -400,8 +427,7 @@ def _moe_rows_share(x, router, gate, up, down, *route_by, k, dtype, held,
                          f"experts and a router of {n_experts}")
     with scope("moe.dispatch"):
         xf = x.reshape(n, d)
-    weights, experts, counts, balance, z = _route(xf, router, k,
-                                                  norm_topk_prob, *route_by)
+    weights, experts, counts, balance, z = _route(xf, router, k, **route)
     with scope("moe.dispatch"):
         # Each routed slot's expert as its index among the held ones; the
         # slots bound elsewhere sort behind them all.  By comparison with
@@ -433,20 +459,21 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
             dtype=jnp.bfloat16, held: Optional[Sequence[int]] = None,
             norm_topk_prob: bool = False,
             router_input: Optional[jax.Array] = None,
-            activation: str = "silu"):
+            activation: str = "silu", scoring: str = "softmax",
+            bias: Optional[jax.Array] = None, scale: float = 1.0):
     """Dropless top-k expert layer: ``sum_j p_j * down_j(act(gate_j x) *
-    up_j x)`` over a token's k most probable experts, the probabilities a
-    softmax over all experts, renormalised over the k only with
+    up_j x)`` over a token's k best-scored experts, the scores a softmax
+    over all experts, renormalised over the k only with
     ``norm_topk_prob``.
 
     - ``x``: ``[rows, tokens, d]``;
-    - ``router``: ``[d, experts]``; logits, softmax and top-k run in fp32;
+    - ``router``: ``[d, experts]``; logits, scores and top-k run in fp32;
     - ``gate``, ``up``: ``[experts, d, width]``; ``down``:
       ``[experts, width, d]``; multiplied in ``dtype``;
     - ``held``: the ids of the experts that live here, in the order of the
       stacks, where a layer's experts are shared among chips (default: all,
       and the layer lowers to what it lowered to without the option).  The
-      router, its softmax, the top k, the renormalisation, the counts and the
+      router, its scores, the top k, the renormalisation, the counts and the
       auxiliary losses are over all experts wherever they live; only the rows
       routed to a held expert are sorted and multiplied, and the sum returned
       is those experts' part of the layer: what the absent ones add is added
@@ -458,6 +485,16 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
       input, before attention; ``router`` is then ``[d_r, experts]``).  Its
       gradient flows through the k weights and the auxiliary losses.
     - ``activation``: the gate's, ``"silu"`` or ``"relu"``.
+    - ``scoring``: ``"softmax"``, or ``"sigmoid"``: each expert's score is
+      the sigmoid of its logit, the k weights are divided by their sum plus
+      1e-6 under ``norm_topk_prob``, and there is no auxiliary loss (the
+      stats' two losses are zeros).
+    - ``bias``: ``[experts]`` fp32, added to the scores where the k experts
+      are chosen and nowhere else: the weights are the scores without it, and
+      no gradient reaches it.  It is the caller's state
+      (:func:`update_expert_bias` after each step, from
+      ``MoEStats.tokens_per_expert``).
+    - ``scale``: a factor on the k weights (``routed_scaling_factor``).
 
     All of the rows given are routed as one set: sorted by expert, multiplied
     by a grouped matmul, brought back.  The auxiliary losses are taken over
@@ -472,48 +509,84 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
     Returns ``(y [rows, tokens, d] in dtype, MoEStats)``.
     """
     common = dict(k=k, dtype=dtype, norm_topk_prob=norm_topk_prob,
-                  act=_activation(activation))
+                  act=_activation(activation), scoring=scoring, scale=scale)
     if held is None:
         body = functools.partial(_moe_rows, **common)
     else:
         body = functools.partial(_moe_rows_share, held=held, **common)
-    # The rows themselves are the default: the program is then the one
-    # without the argument.
-    route_by = () if router_input is None or router_input is x \
-        else (router_input,)
-    if route_by and router_input.shape[:2] != x.shape[:2]:
-        raise ValueError(f"router_input {router_input.shape} for rows "
-                         f"{x.shape}")
+    # The rows themselves are what the router reads by default, and no bias:
+    # the program is then the one without the arguments.
+    sharded = P(data_axis)
+    extras = {}
+    if router_input is not None and router_input is not x:
+        if router_input.shape[:2] != x.shape[:2]:
+            raise ValueError(f"router_input {router_input.shape} for rows "
+                             f"{x.shape}")
+        extras["router_input"] = (router_input, sharded)
+    if bias is not None:
+        extras["bias"] = (bias, P())
+
+    def call(x, router, gate, up, down, *rest):
+        return body(x, router, gate, up, down, **dict(zip(extras, rest)))
+
+    operands = (x, router, gate, up, down) + tuple(
+        value for value, _ in extras.values())
     if data_axis is None or \
             data_axis not in jax.sharding.get_abstract_mesh().axis_names:
-        return body(x, router, gate, up, down, *route_by)
-    sharded = P(data_axis)
+        return call(*operands)
     return jax.shard_map(
-        body,
-        in_specs=(sharded, P(), P(), P(), P()) + (sharded,) * len(route_by),
+        call,
+        in_specs=(sharded, P(), P(), P(), P()) + tuple(
+            spec for _, spec in extras.values()),
         out_specs=(sharded, MoEStats(sharded, sharded, sharded)),
-    )(x, router, gate, up, down, *route_by)
+    )(*operands)
 
 
-def moe_counters(n_layers: int, n_experts: int, share: bool = False) -> dict:
+def update_expert_bias(bias: jax.Array, tokens_per_expert: jax.Array,
+                       rate: float) -> jax.Array:
+    """The selection bias after a step that routed ``tokens_per_expert``
+    (``[layers, experts]``, or ``[layers, sets, experts]`` as
+    ``MoEStats`` stacks them: the sets are then summed, which under
+    ``data_axis`` sums over every rank's rows): ``b + rate * sign(mean - n)``
+    an expert, ``mean`` the layer's rows an expert (DeepSeek-V3's
+    auxiliary-loss-free balancing, arXiv:2412.19437 section 2.1.2): an
+    expert that got more than its share is chosen a little less readily in
+    the next step.  ``bias``: ``[layers, experts]`` fp32; runs inside the
+    step, on the device, outside the gradient."""
+    n = tokens_per_expert
+    if n.ndim == bias.ndim + 1:
+        n = jnp.sum(n, axis=-2)
+    with scope("moe.router"):
+        n = n.astype(jnp.float32)
+        return bias + rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+
+
+def moe_counters(n_layers: int, n_experts: int, share: bool = False,
+                 expert_bias: bool = False) -> dict:
     """Zeroed router counters for a step's ``aux``; with ``share`` also the
     rows routed to the experts held here and those bound elsewhere, a
-    layer."""
+    layer; with ``expert_bias`` also the layers' selection bias
+    (``moe_ffn(bias=)``), state that :func:`count_routing` steps."""
     counters = {"tokens_per_expert":
                 jnp.zeros((n_layers, n_experts), jnp.int32),
                 "steps": jnp.zeros((), jnp.int32)}
     if share:
         counters["rows_held"] = jnp.zeros((n_layers,), jnp.int32)
         counters["rows_elsewhere"] = jnp.zeros((n_layers,), jnp.int32)
+    if expert_bias:
+        counters["expert_bias"] = jnp.zeros((n_layers, n_experts),
+                                            jnp.float32)
     return counters
 
 
 def count_routing(counters: dict, tokens_per_expert: jax.Array,
-                  held: Optional[Sequence[int]] = None) -> dict:
+                  held: Optional[Sequence[int]] = None,
+                  bias_update_rate: float = 0.0) -> dict:
     """``counters`` after one more step that routed ``tokens_per_expert``
     ``[layers, experts]``; runs inside the step, on the device.  ``held``:
     the ids of the experts that live here, for counters made with
-    ``share``."""
+    ``share``.  Counters made with ``expert_bias`` have it stepped by
+    :func:`update_expert_bias` at ``bias_update_rate``."""
     out = {"tokens_per_expert":
            counters["tokens_per_expert"] + tokens_per_expert,
            "steps": counters["steps"] + 1}
@@ -522,6 +595,9 @@ def count_routing(counters: dict, tokens_per_expert: jax.Array,
         out["rows_held"] = counters["rows_held"] + here
         out["rows_elsewhere"] = counters["rows_elsewhere"] \
             + jnp.sum(tokens_per_expert, axis=1) - here
+    if "expert_bias" in counters:
+        out["expert_bias"] = update_expert_bias(
+            counters["expert_bias"], tokens_per_expert, bias_update_rate)
     return out
 
 
@@ -532,9 +608,11 @@ def publish_routing(counters: dict) -> dict:
     counters of a share of the experts also, per layer,
     ``moe_rows_held_per_step`` (rows the experts here multiplied) and
     ``moe_rows_elsewhere_share`` (the share of the routed rows bound for
-    experts that live elsewhere).
+    experts that live elsewhere); for counters with the selection bias,
+    per layer, ``moe_expert_bias_abs_max``.
     Returns ``{"max_load_ratio": [per layer], "steps": n}``, with
-    ``"rows_held_per_step"`` and ``"rows_elsewhere_share"`` for a share."""
+    ``"rows_held_per_step"`` and ``"rows_elsewhere_share"`` for a share and
+    ``"expert_bias_abs_max"`` with a bias."""
     import numpy as np
 
     from ..core import metrics
@@ -562,4 +640,10 @@ def publish_routing(counters: dict) -> dict:
                               layer=str(layer))
             metrics.set_gauge("moe_rows_elsewhere_share", share,
                               layer=str(layer))
+    if "expert_bias" in counters:
+        out["expert_bias_abs_max"] = [
+            float(b) for b in np.abs(np.asarray(
+                counters["expert_bias"], dtype=np.float64)).max(axis=1)]
+        for layer, b in enumerate(out["expert_bias_abs_max"]):
+            metrics.set_gauge("moe_expert_bias_abs_max", b, layer=str(layer))
     return out

@@ -47,6 +47,8 @@ BLOCKS = {
     "moe": _LM + _MOE + ["attn.norm", "attn.rope"],
     "share_mixed": _LM + _MOE + ["attn.rope"],
     "share_blockdiff": _LM + _MOE + ["attn.norm", "attn.rope"],
+    "share_conv": _LM + _MOE + ["attn.norm", "attn.rope", "ffn",
+                                "conv.proj", "conv.gate"],
     "resnet": ["loss", "bn", "resnet.stem", "resnet.stage1", "resnet.stage2",
                "resnet.stage3", "resnet.stage4", "resnet.head"],
 }
@@ -59,8 +61,9 @@ import collections, contextlib, glob, hashlib, json, re
 import flax.linen as nn, jax, jax.numpy as jnp, optax
 from horovod_tpu.models.resnet import BottleneckBlock, ResNet
 from horovod_tpu.models.transformer import (
-    LayerKind, Transformer, moe_stats, olmoe_1b_7b_config,
-    sdar_30b_a3b_config, smallthinker_21b_a3b_config, tiny_config)
+    LayerKind, Transformer, lfm2_8b_a1b_config, moe_stats,
+    olmoe_1b_7b_config, sdar_30b_a3b_config, smallthinker_21b_a3b_config,
+    tiny_config)
 
 if {null}:
     jax.named_scope = lambda name: contextlib.nullcontext()
@@ -118,6 +121,14 @@ MODELS = {{
     "share_blockdiff": lambda: lm(sdar_30b_a3b_config(
         **share, head_width=16, experts_per_token=2,
         experts_held=(1, 3, 4, 6), block_diffusion=4), True),
+    # A convolution layer with the dense FFN, then attention and a
+    # convolution with 2 of 8 experts held, chosen by sigmoid and a bias.
+    "share_conv": lambda: lm(lfm2_8b_a1b_config(
+        **{{**share, "num_layers": 3}}, head_width=16, d_ff_dense=96,
+        experts_per_token=2, experts_held=(1, 6),
+        layer_pattern=(LayerKind(0, True, "conv", "dense"),
+                       LayerKind(0, True, "attention"),
+                       LayerKind(0, True, "conv"))), True),
     "resnet": resnet,
 }}
 

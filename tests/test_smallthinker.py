@@ -282,7 +282,7 @@ def test_tiles_visited_at_the_cells_shape(rule_name, visited):
 
     rule = ma.Window(4096) if rule_name == "window" else ma.Causal()
     n, s = ma.BLOCK, 16384
-    assert ma.takes(rule, s, 128) and not ma.takes(rule, s, 64)
+    assert ma.takes(rule, s, 128) and not ma.takes(rule, s, 96)
     assert not ma.takes(rule, s + 512, 128)
     mask = rule.mask(s)
     tiles = s // n
@@ -605,7 +605,10 @@ def test_flops_and_attention_cost_come_from_the_shapes():
     # Compute-bound on a v5e: 13.3 T operations against 0.54 GB.
     assert operations / 197e12 > 10 * moved / 819e9
     config = module.Config(sizes)
-    assert config.model.cfg.layer_pattern == ((0, False),) + ((4096, True),) * 3
+    assert [(k.window, k.rope, k.mixer, k.ffn)
+            for k in config.model.cfg.layer_pattern] \
+        == [(0, False, "attention", None)] \
+        + [(4096, True, "attention", None)] * 3
     shapes = nn.meta.unbox(jax.eval_shape(
         config.model.init, jax.random.PRNGKey(0),
         jnp.zeros((1, 16), jnp.int32))["params"])

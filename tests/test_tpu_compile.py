@@ -328,3 +328,53 @@ def test_expert_share_through_the_rows_kernel_compiles(cell, topo,
     assert not re.findall(rf"= \w+\[\d+,{d}\]\S* scatter\(", text)
     assert len(re.findall(r"%ragged-dot-none[.\d]* =", text)) in (18, 21)
     assert compiled.memory_analysis().temp_size_in_bytes <= most
+
+
+def test_short_conv_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
+    """Two sequences of 8192 positions at width 2048, 3 taps: the forward
+    and the backward kernel of ``kernels/short_conv.py``, the residual
+    ``bcx`` alone."""
+    from horovod_tpu.kernels import short_conv as sc
+
+    assert sc.takes(8192, 2048, 3)
+    bcx = _shape((2, 8192, 6144), jnp.bfloat16, one_chip)
+    w = _shape((2048, 3), jnp.float32, one_chip)
+
+    def loss(bcx, w):
+        y = sc._gated_conv(bcx, w, False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(bcx, w).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(hvd_short_conv\w*?)[.\d]* =", text))
+    assert kernels == {sc.FWD_NAME, sc.BWD_NAME}, kernels
+    assert all(re.match(sc.OP_LINE_NAMES, k) for k in kernels)
+    # y and its cotangent besides the arguments and d_bcx: nothing else of
+    # their size, and nothing of [b, s, d] in fp32 (134 MB).
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
+    """Two sequences of 8192 positions, 32 query heads on 8 KV heads of 64
+    under the causal rule: the library's three kernels take half a lane
+    group as it is, KV heads not repeated, no score square in the program."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    rule = ma.Causal()
+    assert ma.takes(rule, 8192, 64)
+    q = _shape((2, 8192, 32, 64), jnp.bfloat16, one_chip)
+    kv = _shape((2, 8192, 8, 64), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, rule).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals",
+                       "splash_mha_dq_no_residuals",
+                       "splash_mha_dkv_no_residuals"}, kernels
+    assert "8192,8192" not in text
+    assert "bf16[2,8192,8,64]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
