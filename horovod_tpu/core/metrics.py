@@ -229,6 +229,11 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "moe_rows_elsewhere_share": (
         "gauge", "share of a layer's routed rows bound for experts that "
                  "live elsewhere (counted, not computed), per layer="),
+    "moe_overflow_chunks_per_step": (
+        "gauge", "where a layer holds a share of the experts: chunks of "
+                 "rows behind row_buffer's first that a step ran (and ran "
+                 "again in its backward pass), per layer= ; zero while the "
+                 "routing stays within five quarters of the mean share"),
     "moe_expert_bias_abs_max": (
         "gauge", "where the router chooses by scores plus a bias the step "
                  "keeps (moe_ffn(bias=...), update_expert_bias): the largest "

@@ -348,69 +348,113 @@ def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
         return _combine(out, ws, slot, token, group, tokens)
 
 
-def _overflow_chunks(chunk, chunks: int, cap: int):
-    """The chunks after the first, each run only when the held rows reach it
-    (``lax.cond``), and then recomputed in the backward pass instead of kept:
-    a step whose routing stays inside the first chunk pays nothing for them,
-    and no routing drops a row."""
+def overflow_reached(rows_held, first: int, quantum: int):
+    """The chunks of ``quantum`` places that ``rows_held`` rows need behind a
+    first chunk of ``first``: what the loops of :func:`_chunks` run
+    and :func:`count_routing` counts."""
+    return (jnp.maximum(rows_held - first, 0) + quantum - 1) // quantum
 
-    def reached(rows_held, j):
-        return rows_held > j * cap
 
-    # The loops and sums around the chunks lie under ``moe.combine`` in both
-    # directions; what a chunk does keeps its own scopes inside.
-    @jax.custom_vjp
-    def run(xf, ws, gate, up, down, order, sizes):
-        def body(y, j):
-            return lax.cond(
-                reached(rows_held, j),
-                lambda: y + chunk(xf, ws, gate, up, down, order, sizes,
-                                  j * cap),
-                lambda: y), None
+def _chunks(chunk, first: int, quantum: int):
+    """The held experts' rows in chunks: the first, ``first`` places, on the
+    normal path (its residuals kept), and behind it ``quantum`` places at a
+    time in a loop of as many trips as the held rows reach
+    (:func:`overflow_reached`: none in a step whose routing stays inside the
+    first chunk), recomputed in the backward pass instead of kept.  No
+    routing drops a row.  Both directions are written out, so neither
+    differentiates through a loop and each loop is a ``while``; what the
+    first chunk gives, its sum forward and its five cotangents backward, is
+    what the loops start from, so a loop of no trip costs no pass over
+    either."""
 
+    def place(j):
+        return first + j * quantum
+
+    def reached(sizes):
+        return overflow_reached(jnp.sum(sizes), first, quantum)
+
+    # The loops lie under ``moe.combine`` in both directions; what a chunk
+    # does keeps its own scopes inside.
+    def behind(y, xf, ws, gate, up, down, order, sizes):
         with scope("moe.combine"):
-            rows_held = jnp.sum(sizes)
-            # Zeros that vary over a mesh axis wherever the rows do (under
-            # moe_ffn's shard_map the branches' types would differ
-            # otherwise).
-            y, _ = lax.scan(body, (xf * 0).astype(jnp.float32),
-                            jnp.arange(1, chunks))
-        return y
+            return lax.fori_loop(
+                0, reached(sizes),
+                lambda j, y: y + chunk(xf, ws, gate, up, down, order, sizes,
+                                       place(j), cap=quantum), y)
+
+    @jax.custom_vjp
+    def run(*operands):
+        return behind(chunk(*operands, 0, cap=first), *operands)
 
     def fwd(*operands):
-        return run(*operands), operands
+        weights, (order, sizes) = operands[:5], operands[5:]
+        y, back = jax.vjp(
+            lambda *w: chunk(*w, order, sizes, 0, cap=first), *weights)
+        return behind(y, *operands), (back, operands)
 
-    def bwd(operands, g):
+    def bwd(res, g):
+        back, operands = res
         weights, (order, sizes) = operands[:5], operands[5:]
 
-        def body(acc, j):
-            def more():
-                _, vjp = jax.vjp(
-                    lambda *w: chunk(*w, order, sizes, j * cap),
-                    *weights)
-                return tuple(a + d for a, d in zip(acc, vjp(g)))
+        def more(j, acc):
+            _, vjp = jax.vjp(
+                lambda *w: chunk(*w, order, sizes, place(j), cap=quantum),
+                *weights)
+            return tuple(a + d for a, d in zip(acc, vjp(g)))
 
-            return lax.cond(reached(rows_held, j), more, lambda: acc), None
-
+        acc = back(g)
         with scope("moe.combine"):
-            rows_held = jnp.sum(sizes)
-            acc, _ = lax.scan(body, tuple(w * 0 for w in weights),
-                              jnp.arange(1, chunks))
+            acc = lax.fori_loop(0, reached(sizes), more, acc)
         return (*acc, None, None)
 
     run.defvjp(fwd, bwd)
-    return run
+
+    def varying(xf, *rest):
+        # Under moe_ffn's shard_map the expert weights are one for all
+        # members and their cotangent a sum over them.  Made as varying as
+        # the rows out here, that sum is taken once behind the loop, not in
+        # trips of which each member makes its own number.
+        axes = jax.typeof(xf).vma
+        return run(xf, *(
+            lax.pcast(a, tuple(axes - jax.typeof(a).vma), to="varying")
+            for a in rest))
+
+    return varying
+
+
+def row_quantum(slots: int, n_held: int, n_experts: int) -> int:
+    """Rows an overflow chunk: a quarter of the mean share ``slots * n_held /
+    n_experts`` of the ``slots`` routed rows that the ``n_held`` of
+    ``n_experts`` experts held here take.  0 where the rows are taken as one
+    chunk of them all: the quarter is no whole multiple of the 128 rows
+    ``kernels/rows_to_tokens.py`` multiplies at a time (so the kernel would
+    refuse it and the first chunk), the slots behind the first chunk
+    (:func:`row_buffer`) are no whole number of quarters, or there are
+    none."""
+    # Here, not at the top: see _rows_to_tokens.
+    from ..kernels import rows_to_tokens as kernel
+
+    quantum, rest = divmod(slots * n_held, 4 * n_experts)
+    if rest or not quantum or quantum % kernel.CHUNK or slots % quantum \
+            or 5 * quantum >= slots:
+        return 0
+    return quantum
 
 
 def row_buffer(slots: int, n_held: int, n_experts: int):
-    """(chunks, rows a chunk) for ``slots`` routed rows of which the
-    ``n_held`` of ``n_experts`` held here take ``n_held / n_experts`` on
-    average: one chunk of twice that share, and as many more behind it as the
-    worst routing (every row here) needs."""
-    chunks = max(1, n_experts // (2 * n_held))
-    if slots % chunks:
-        chunks = 1
-    return chunks, slots // chunks
+    """(chunks, rows of the first chunk) for ``slots`` routed rows of which
+    the ``n_held`` of ``n_experts`` held here take ``n_held / n_experts`` on
+    average: a first chunk of five quarters of that share, which every step
+    runs, and behind it as many chunks of a quarter (:func:`row_quantum`) as
+    the worst routing (every row here) needs, of which a step runs those its
+    rows reach.  Five quarters: at the mean itself an even routing overflows
+    every other step, and every place costs its gathered row, its activation
+    and its fp32 cotangent row whether a row sits in it or not (the grouped
+    products and the rows kernel follow the rows: ``docs/moe.md``)."""
+    quantum = row_quantum(slots, n_held, n_experts)
+    if not quantum:
+        return 1, slots
+    return slots // quantum - 4, 5 * quantum
 
 
 def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
@@ -441,14 +485,15 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
         rows_in = (xf.astype(dtype), weights.reshape(n * k))
     with scope("moe.experts"):
         stacks = (gate.astype(dtype), up.astype(dtype), down.astype(dtype))
-    chunks, cap = row_buffer(n * k, len(held), n_experts)
-    chunk = functools.partial(_held_chunk, k=k, cap=cap, dtype=dtype,
-                              act=act)
+    _, first = row_buffer(n * k, len(held), n_experts)
+    quantum = row_quantum(n * k, len(held), n_experts)
+    chunk = functools.partial(_held_chunk, k=k, dtype=dtype, act=act)
     operands = (*rows_in, *stacks, order, sizes)
-    y = chunk(*operands, 0)
+    if quantum:
+        y = _chunks(chunk, first, quantum)(*operands)
+    else:
+        y = chunk(*operands, 0, cap=first)
     with scope("moe.combine"):
-        if chunks > 1:
-            y = y + _overflow_chunks(chunk, chunks, cap)(*operands)
         y = y.astype(dtype).reshape(rows, tokens, d)
     with scope("moe.router"):
         return y, MoEStats(balance[None], z[None], counts[None])
@@ -478,8 +523,9 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
       routed to a held expert are sorted and multiplied, and the sum returned
       is those experts' part of the layer: what the absent ones add is added
       by whoever holds them (on one chip, by no one).  No row bound here is
-      dropped: the rows are taken in chunks of :func:`row_buffer`'s size, the
-      first always, the others when the routing reaches them.
+      dropped: the rows are taken in chunks of :func:`row_buffer`'s sizes, a
+      first of five quarters of the mean share always, then a quarter at a
+      time in a loop of as many trips as the step's routing reaches.
     - ``router_input``: ``[rows, tokens, d_r]``, what the router reads where
       that is not the rows it multiplies (SmallThinker routes by the block's
       input, before attention; ``router`` is then ``[d_r, experts]``).  Its
@@ -562,11 +608,13 @@ def update_expert_bias(bias: jax.Array, tokens_per_expert: jax.Array,
 
 
 def moe_counters(n_layers: int, n_experts: int, share: bool = False,
-                 expert_bias: bool = False) -> dict:
+                 expert_bias: bool = False, overflow: bool = False) -> dict:
     """Zeroed router counters for a step's ``aux``; with ``share`` also the
     rows routed to the experts held here and those bound elsewhere, a
     layer; with ``expert_bias`` also the layers' selection bias
-    (``moe_ffn(bias=)``), state that :func:`count_routing` steps."""
+    (``moe_ffn(bias=)``), state that :func:`count_routing` steps; with
+    ``overflow`` (of a ``share``) also the chunks behind the first that ran,
+    a layer."""
     counters = {"tokens_per_expert":
                 jnp.zeros((n_layers, n_experts), jnp.int32),
                 "steps": jnp.zeros((), jnp.int32)}
@@ -576,17 +624,23 @@ def moe_counters(n_layers: int, n_experts: int, share: bool = False,
     if expert_bias:
         counters["expert_bias"] = jnp.zeros((n_layers, n_experts),
                                             jnp.float32)
+    if overflow:
+        counters["overflow_chunks"] = jnp.zeros((n_layers,), jnp.int32)
     return counters
 
 
 def count_routing(counters: dict, tokens_per_expert: jax.Array,
                   held: Optional[Sequence[int]] = None,
-                  bias_update_rate: float = 0.0) -> dict:
+                  bias_update_rate: float = 0.0,
+                  slots: Optional[int] = None) -> dict:
     """``counters`` after one more step that routed ``tokens_per_expert``
     ``[layers, experts]``; runs inside the step, on the device.  ``held``:
     the ids of the experts that live here, for counters made with
     ``share``.  Counters made with ``expert_bias`` have it stepped by
-    :func:`update_expert_bias` at ``bias_update_rate``."""
+    :func:`update_expert_bias` at ``bias_update_rate``.  Counters made with
+    ``overflow`` need ``slots``, the routed rows a layer and call of
+    :func:`moe_ffn` (its tokens times k), and gain the chunks behind
+    :func:`row_buffer`'s first that those calls ran."""
     out = {"tokens_per_expert":
            counters["tokens_per_expert"] + tokens_per_expert,
            "steps": counters["steps"] + 1}
@@ -598,6 +652,12 @@ def count_routing(counters: dict, tokens_per_expert: jax.Array,
     if "expert_bias" in counters:
         out["expert_bias"] = update_expert_bias(
             counters["expert_bias"], tokens_per_expert, bias_update_rate)
+    if "overflow_chunks" in counters:
+        sizes = (slots, len(held), tokens_per_expert.shape[-1])
+        quantum = row_quantum(*sizes)
+        out["overflow_chunks"] = counters["overflow_chunks"] + (
+            overflow_reached(here, row_buffer(*sizes)[1], quantum)
+            if quantum else 0)
     return out
 
 
@@ -608,10 +668,13 @@ def publish_routing(counters: dict) -> dict:
     counters of a share of the experts also, per layer,
     ``moe_rows_held_per_step`` (rows the experts here multiplied) and
     ``moe_rows_elsewhere_share`` (the share of the routed rows bound for
-    experts that live elsewhere); for counters with the selection bias,
-    per layer, ``moe_expert_bias_abs_max``.
+    experts that live elsewhere); for counters with the overflow, per layer,
+    ``moe_overflow_chunks_per_step`` (chunks behind the first that ran); for
+    counters with the selection bias, per layer,
+    ``moe_expert_bias_abs_max``.
     Returns ``{"max_load_ratio": [per layer], "steps": n}``, with
-    ``"rows_held_per_step"`` and ``"rows_elsewhere_share"`` for a share and
+    ``"rows_held_per_step"`` and ``"rows_elsewhere_share"`` for a share,
+    ``"overflow_chunks_per_step"`` with the overflow and
     ``"expert_bias_abs_max"`` with a bias."""
     import numpy as np
 
@@ -639,6 +702,13 @@ def publish_routing(counters: dict) -> dict:
             metrics.set_gauge("moe_rows_held_per_step", per_step,
                               layer=str(layer))
             metrics.set_gauge("moe_rows_elsewhere_share", share,
+                              layer=str(layer))
+    if "overflow_chunks" in counters:
+        out["overflow_chunks_per_step"] = [
+            float(c) / max(steps, 1)
+            for c in np.asarray(counters["overflow_chunks"])]
+        for layer, per_step in enumerate(out["overflow_chunks_per_step"]):
+            metrics.set_gauge("moe_overflow_chunks_per_step", per_step,
                               layer=str(layer))
     if "expert_bias" in counters:
         out["expert_bias_abs_max"] = [

@@ -472,11 +472,13 @@ def test_four_shares_of_8_add_up_to_the_uncut_layer_of_32(skew):
     same 32-wide routing."""
     from horovod_tpu.parallel.moe import moe_ffn, row_buffer
 
-    x, router, gate, up, down = layer_inputs(11, experts=32, skew=skew)
+    x, router, gate, up, down = layer_inputs(11, tokens=256, experts=32,
+                                             skew=skew)
     bias = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (32,))
     k, n = 4, x.shape[0] * x.shape[1]
-    assert row_buffer(n * k, 8, 32) == (2, n * k // 2)
-    assert row_buffer(16384 * 4, 8, 32) == (2, 32768)       # the cell's
+    # Five quarters of the mean share of 512 rows, and 11 quarters behind.
+    assert row_buffer(n * k, 8, 32) == (12, 640)
+    assert row_buffer(16384 * 4, 8, 32) == (12, 20480)      # the cell's
     route = dict(k=k, dtype=jnp.float32, norm_topk_prob=True,
                  scoring="sigmoid")
     with jax.default_matmul_precision("highest"):

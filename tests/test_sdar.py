@@ -6,6 +6,7 @@ dense expert at a time under a mask, nothing of ``horovod_tpu``) on seeded
 weights at tiny widths.
 """
 
+import functools
 import hashlib
 import json
 import os
@@ -294,16 +295,24 @@ def dense_share(x, router, gate, up, down, k, held, normalise=True):
 def test_eight_shares_add_up_to_the_uncut_layer(skew):
     """16 experts, 2 on each of 8 chips, top 4 renormalised: every share's
     partial result is its own experts' part, the eight add up to the uncut
-    layer, and every share counts the same 16-wide routing.  With the skew
-    nearly every token's first experts are 0 and 1, so share 0 takes twice
-    the rows of its first chunk (four chunks of a quarter of the slots) and
-    the chunk behind it runs."""
-    from horovod_tpu.parallel.moe import moe_ffn, row_buffer
+    layer, and every share counts the same 16-wide routing.  1024 tokens:
+    a first chunk of 640 places, five quarters of the mean share, and 27 of
+    128 behind it.  With the skew nearly every token's first experts are 0
+    and 1, so share 0 takes three times the rows of its first chunk and ten
+    and more of the chunks behind it run."""
+    from horovod_tpu.parallel.moe import (
+        moe_ffn,
+        overflow_reached,
+        row_buffer,
+        row_quantum,
+    )
 
-    x, router, gate, up, down = layer_inputs(7, experts=16, skew=skew)
+    x, router, gate, up, down = layer_inputs(7, tokens=512, experts=16,
+                                             skew=skew)
     k, n = 4, x.shape[0] * x.shape[1]
     chunks, cap = row_buffer(n * k, 2, 16)
-    assert (chunks, cap) == (4, n * k // 4)
+    assert (chunks, cap) == (28, 640)
+    assert row_quantum(n * k, 2, 16) == 128
     whole = dense_share(x, router, gate, up, down, k, range(16))
     total, counts = np.zeros_like(whole), None
     for share in range(8):
@@ -319,7 +328,7 @@ def test_eight_shares_add_up_to_the_uncut_layer(skew):
         if counts is None:
             counts = np.asarray(stats.tokens_per_expert)
             if skew:
-                assert counts[0, :2].sum() > 1.9 * cap   # a second chunk
+                assert overflow_reached(counts[0, :2].sum(), cap, 128) >= 10
         np.testing.assert_array_equal(stats.tokens_per_expert, counts)
     np.testing.assert_allclose(total, whole, atol=1e-4)
     assert counts.sum() == n * k
@@ -424,23 +433,44 @@ def _share_case(name):
         return inputs, 4, held, lambda places, cap: (places[9] < 0).all() \
             and (places >= 0).any(axis=1).sum() == len(places) - 1
     if name == "a_run_cut_by_a_chunk_boundary":
-        # 94 tokens, top 4 of 16, 2 held: four chunks of 94 places, not a
-        # multiple of k.  With the skew every token's first expert is 0, so
-        # its row for expert 0 lies in the first chunk and its row for
-        # expert 1 in the chunk behind it.
-        inputs = layer_inputs(13, tokens=47, experts=16, skew=6.0)
-        return inputs, 4, (0, 1), lambda places, cap: cap % 4 and (
+        # 1024 tokens, top 4 of 16, 2 held: a first chunk of 640 places and
+        # chunks of 128 behind it.  With the skew every token's first expert
+        # is 0, so expert 0's run of a thousand rows is cut by the first
+        # chunk's end and by three more, and a token's row for expert 0 lies
+        # in the first chunk and its row for expert 1 in one behind it.
+        inputs = layer_inputs(13, tokens=512, experts=16, skew=6.0)
+        return inputs, 4, (0, 1), lambda places, cap: cap == 640 and (
             ((places >= 0) & (places < cap)).any(axis=1)
             & (places >= cap).any(axis=1)).any()
     if name == "a_chunk_filled_to_its_last_place":
-        # 32 tokens, top 4 of 16, 2 held: four chunks of 32 places; tokens
-        # 0..15 route to both held experts: 32 rows, and no chunk behind.
+        # 1024 tokens, top 4 of 16, 2 held: a first chunk of 640 places;
+        # tokens 0..319 route to both held experts: 640 rows, and no chunk
+        # behind.
         held = (2, 7)
-        inputs = _routed_by_hand(lambda t: (2, 7, 0, 1) if t < 16
-                                 else (0, 1, 3, 5))
+        inputs = _routed_by_hand(lambda t: (2, 7, 0, 1) if t < 320
+                                 else (0, 1, 3, 5), n=1024)
         return inputs, 4, held, \
-            lambda places, cap: (places >= 0).sum() == cap == 32
+            lambda places, cap: (places >= 0).sum() == cap == 640
     raise ValueError(name)
+
+
+def _dense_share(held, k, w):
+    """``(sum(y * w), y)`` of the held experts' part of the layer, one dense
+    expert at a time under a mask, for autodiff: the weights renormalised
+    over each token's k most probable experts wherever they live."""
+    def dense(x, router, gate, up, down):
+        xf = x.reshape(-1, x.shape[-1])
+        top, experts = jax.lax.top_k(jax.nn.softmax(xf @ router, axis=-1), k)
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        y = 0.0
+        for i, e in enumerate(held):
+            we = jnp.sum(jnp.where(experts == e, top, 0.0), axis=-1)
+            y = y + we[:, None] * (
+                (jax.nn.silu(xf @ gate[i]) * (xf @ up[i])) @ down[i])
+        y = y.reshape(x.shape)
+        return jnp.sum(y * w), y
+
+    return dense
 
 
 @pytest.mark.parametrize("name", [
@@ -467,18 +497,6 @@ def test_share_brings_each_tokens_rows_back_whatever_its_run(name):
     assert shows(places, cap), name
     w = jax.random.normal(jax.random.PRNGKey(1), x.shape)
 
-    def dense(x, router, gate, up, down):
-        xf = x.reshape(-1, x.shape[-1])
-        top, experts = jax.lax.top_k(jax.nn.softmax(xf @ router, axis=-1), k)
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
-        y = 0.0
-        for i, e in enumerate(held):
-            we = jnp.sum(jnp.where(experts == e, top, 0.0), axis=-1)
-            y = y + we[:, None] * (
-                (jax.nn.silu(xf @ gate[i]) * (xf @ up[i])) @ down[i])
-        y = y.reshape(x.shape)
-        return jnp.sum(y * w), y
-
     def program(x, router, gate, up, down):
         y, _ = moe_ffn(x, router, gate, up, down, k=k, dtype=jnp.float32,
                        held=held, norm_topk_prob=True)
@@ -489,10 +507,152 @@ def test_share_brings_each_tokens_rows_back_whatever_its_run(name):
         (_, y), got = jax.jit(jax.value_and_grad(
             program, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
         (_, want_y), want = jax.jit(jax.value_and_grad(
-            dense, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+            _dense_share(held, k, w), argnums=(0, 1, 2, 3, 4),
+            has_aux=True))(*args)
     assert rel_err(y, want_y) < 1e-5
     for g, wg in zip(got, want):
         assert rel_err(g, wg) < 1e-5
+
+
+# Top 4 of 32, 4 held, 1024 tokens: 4096 slots, a first chunk of 640 places
+# (five quarters of the mean share of 512) and 27 chunks of 128 behind it.
+_OVERFLOW_HELD, _OVERFLOW_FIRST, _OVERFLOW_QUANTUM = (2, 7, 11, 4), 640, 128
+
+
+@pytest.mark.parametrize("rows_held", [
+    600, _OVERFLOW_FIRST, _OVERFLOW_FIRST + 1,
+    _OVERFLOW_FIRST + _OVERFLOW_QUANTUM,
+    _OVERFLOW_FIRST + _OVERFLOW_QUANTUM + 1, 4096],
+    ids=["below_the_first_chunk", "the_first_chunk_full", "one_row_over",
+         "a_quarter_over", "a_quarter_and_a_row_over", "every_slot_held"])
+def test_share_runs_the_chunks_its_rows_reach_and_no_more(rows_held):
+    """The loop behind the first chunk: the rows held below its size, at it,
+    one over, a quarter over, one more, and every routed slot (27 chunks);
+    the result and all five gradients in float32 against autodiff of one
+    dense expert at a time, and the counter reads the trips the loop made."""
+    from horovod_tpu.parallel.moe import (
+        count_routing,
+        moe_counters,
+        moe_ffn,
+        publish_routing,
+        row_buffer,
+        row_quantum,
+    )
+
+    held, k, n = _OVERFLOW_HELD, 4, 1024
+    whole, rest = divmod(rows_held, k)
+    away = (0, 1, 3, 5)
+
+    def picks(t):       # every slot held, `rest` of them, none
+        return held if t < whole else \
+            held[:rest] + away[rest:] if t == whole else away
+
+    x, router, gate, up, down = _routed_by_hand(picks, n=n, experts=32, d=48)
+    assert row_buffer(n * k, len(held), 32) == (28, _OVERFLOW_FIRST)
+    assert row_quantum(n * k, len(held), 32) == _OVERFLOW_QUANTUM
+    pick = np.asarray(held)
+    w = jax.random.normal(jax.random.PRNGKey(1), x.shape)
+
+    def program(x, router, gate, up, down):
+        y, stats = moe_ffn(x, router, gate, up, down, k=k, dtype=jnp.float32,
+                           held=held, norm_topk_prob=True)
+        return jnp.sum(y * w), (y, stats.tokens_per_expert)
+
+    args = (x, router, gate[pick], up[pick], down[pick])
+    with jax.default_matmul_precision("highest"):
+        (_, (y, counts)), got = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        (_, want_y), want = jax.jit(jax.value_and_grad(
+            _dense_share(held, k, w), argnums=(0, 1, 2, 3, 4),
+            has_aux=True))(*args)
+    assert int(counts.sum()) == n * k
+    assert int(counts[0, pick].sum()) == rows_held
+    assert rel_err(y, want_y) < 1e-5
+    for g, wg in zip(got, want):
+        assert rel_err(g, wg) < 1e-5
+    counters = jax.jit(lambda c, r: count_routing(
+        c, r, held=held, slots=n * k))(
+            moe_counters(1, 32, share=True, overflow=True), counts)
+    reached = -(-max(rows_held - _OVERFLOW_FIRST, 0) // _OVERFLOW_QUANTUM)
+    assert int(counters["overflow_chunks"][0]) == reached
+    assert publish_routing(counters)["overflow_chunks_per_step"] == [reached]
+
+
+def test_each_member_of_the_data_axis_runs_the_chunks_its_own_rows_reach():
+    """Under a mesh that binds ``data_axis`` the loop's trip count follows
+    each member's own rows: one member's routing is skewed to the held
+    experts (11 chunks behind the first), the other's is not (none), and
+    loss and gradients are those of the two members by themselves."""
+    from jax.sharding import Mesh
+
+    from horovod_tpu.parallel.moe import moe_ffn, overflow_reached
+
+    skewed, router, gate, up, down = layer_inputs(
+        7, rows=1, tokens=1024, experts=16, skew=6.0)
+    plain = layer_inputs(8, rows=1, tokens=1024, experts=16)[0] - 1.0
+    x, held, k = jnp.concatenate([skewed, plain]), (0, 1), 4
+    args = (x, router, gate[:2], up[:2], down[:2])
+
+    def loss(*a, axis=None):
+        y, stats = moe_ffn(*a, k=k, dtype=jnp.float32, held=held,
+                           norm_topk_prob=True, data_axis=axis)
+        return jnp.sum(y ** 2), stats.tokens_per_expert
+
+    through = functools.partial(jax.value_and_grad, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    with jax.default_matmul_precision("highest"):
+        with jax.set_mesh(mesh):
+            (got, counts), got_grads = jax.jit(through(
+                functools.partial(loss, axis="data")))(*args)
+        alone = [jax.jit(through(loss))(x[r:r + 1], *args[1:])
+                 for r in range(2)]
+    trips = [int(overflow_reached(c[:2].sum(), 640, 128)) for c in counts]
+    assert trips[0] >= 10 and trips[1] == 0, trips
+    assert abs(float(got) - sum(float(a[0][0]) for a in alone)) \
+        < 1e-5 * float(got)
+    want_grads = [jnp.concatenate([a[1][0] for a in alone])] + [
+        alone[0][1][i] + alone[1][1][i] for i in range(1, 5)]
+    for g, wg in zip(got_grads, want_grads):
+        assert rel_err(g, wg) < 1e-5
+
+
+@pytest.mark.parametrize("cell, sizes, d, first, quantum, chunks", [
+    ("sdar-30b-a3b", (16384 * 8, 16, 128), 2048, 20480, 4096, 28),
+    ("smallthinker-21b-a3b", (16384 * 6, 8, 64), 2560, 15360, 3072, 28),
+    ("lfm2-8b-a1b", (16384 * 4, 8, 32), 2048, 20480, 4096, 12)])
+def test_row_buffer_at_the_cells_sizes_is_what_the_rows_kernel_takes(
+        cell, sizes, d, first, quantum, chunks):
+    """(slots, held, experts) of the three share cells: five quarters of
+    the mean share and a quarter, both whole multiples of the 128 rows
+    ``kernels/rows_to_tokens.py`` multiplies at a time, so neither falls back
+    to XLA's scatter-add; the worst routing fits the chunks."""
+    from horovod_tpu.kernels import rows_to_tokens as rt
+    from horovod_tpu.parallel import moe
+
+    assert moe.row_buffer(*sizes) == (chunks, first)
+    assert moe.row_quantum(*sizes) == quantum
+    assert first + (chunks - 1) * quantum == sizes[0]
+    assert 4 * first == 5 * sizes[0] * sizes[1] // sizes[2]
+    for cap in (first, quantum):
+        assert cap % 128 == 0 and rt.takes(cap, d, 16384)
+    assert int(moe.overflow_reached(sizes[0], first, quantum)) == chunks - 1
+    assert int(moe.overflow_reached(first, first, quantum)) == 0
+
+
+@pytest.mark.parametrize("sizes", [
+    (376, 2, 16),        # the quarter is no whole number of rows
+    (2048, 2, 16),       # a quarter of 64 rows: no multiple of 128
+    (16384, 3, 32),      # 16384 slots are no whole number of 384
+    (5120, 4, 5),        # five quarters of the mean are all the slots
+    (4096, 16, 16)],     # every expert held
+    ids=["fraction", "under_128", "slots_not_in_quarters", "no_room",
+         "all_held"])
+def test_row_buffer_falls_back_to_one_chunk_of_every_slot(sizes):
+    from horovod_tpu.parallel.moe import row_buffer, row_quantum
+
+    assert row_buffer(*sizes) == (1, sizes[0])
+    assert row_quantum(*sizes) == 0
 
 
 def test_share_counters_become_gauges():
@@ -514,9 +674,14 @@ def test_share_counters_become_gauges():
     text = metrics.registry.to_prometheus() if hasattr(
         metrics.registry, "to_prometheus") else ""
     assert "moe_rows_held_per_step" in text or not text
-    # Counters made without the share keep their two keys.
+    # Counters made without the share keep their two keys, and a share's
+    # its four unless the overflow is asked for.
     plain = count_routing(moe_counters(2, 8), routed)
     assert sorted(plain) == ["steps", "tokens_per_expert"]
+    assert sorted(counters) == ["rows_elsewhere", "rows_held", "steps",
+                                "tokens_per_expert"]
+    assert "overflow_chunks_per_step" not in out
+    assert "moe_overflow_chunks_per_step" in metrics.CATALOG
 
 
 # -- nothing moved for the models the benchmark already had --------------------

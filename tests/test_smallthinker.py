@@ -365,7 +365,9 @@ def dense_layer(x, routed_by, router, gate, up, down, k, held, act):
 
 def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
     """The defaults spelled out lower to what the parent lowered to, whole
-    layer and share alike (the share's digest taken on the parent fbf0cef)."""
+    layer and share alike (the share's digest taken on this tree without the
+    arguments spelled: 64 slots are one chunk since PR 39, two until then,
+    when the digest was the parent fbf0cef's)."""
     from horovod_tpu.parallel.moe import moe_ffn
 
     if jax.__version__ != PARENT["jax"]:
@@ -392,7 +394,7 @@ def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
         return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
     assert text(None, spelled=True) == PARENT["moe_ffn_all_held"]
-    share = "ba0c9eb7825fc574f7d9f9c420cbf6688d9fda47b59af8735084c031461559ff"
+    share = "c246ebd84530964d80920d7766ea96768cae007645310697490a2e7a0c97db1e"
     assert text((1, 6)) == text((1, 6), spelled=True) == share
 
 
@@ -486,8 +488,9 @@ def test_eight_shares_of_8_add_up_to_the_uncut_layer_of_64(skew):
     routed_by = jax.random.normal(jax.random.PRNGKey(5), x.shape) \
         + (1.0 if skew else 0.0)
     k, n = 6, x.shape[0] * x.shape[1]
-    assert row_buffer(n * k, 8, 64) == (4, n * k // 4)
-    assert row_buffer(16384 * 6, 8, 64) == (4, 24576)   # the cell's
+    # A quarter of these 72 rows' mean share is no whole chunk: one chunk.
+    assert row_buffer(n * k, 8, 64) == (1, n * k)
+    assert row_buffer(16384 * 6, 8, 64) == (28, 15360)  # the cell's
     with jax.default_matmul_precision("highest"):
         whole, whole_stats = jax.jit(lambda *a: moe_ffn(
             *a[:5], k=k, dtype=jnp.float32, norm_topk_prob=True,
