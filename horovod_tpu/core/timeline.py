@@ -517,7 +517,9 @@ SCOPES = (
     "attn.einsum", "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
     "attn.causal", "attn.window", "attn.blockdiff",
     "conv.proj", "conv.gate",
+    "ssm.proj", "ssm.conv", "ssm.scan", "ssm.norm",
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+    "moe.latent", "moe.shared",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",
 )

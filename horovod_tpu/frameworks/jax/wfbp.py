@@ -37,6 +37,7 @@ cross-rank agreement, elastic-reset awareness.
 from __future__ import annotations
 
 import threading
+import weakref
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -377,6 +378,9 @@ class OverlappedTrainStep:
         self._mesh = None
         self._step = None
         self._sig_checked = False
+        # (lifted leaf, the caller's array it shares a buffer with), both
+        # weakly: what `init` handed back without a copy (`_own`).
+        self._borrowed = []
 
     # -- mesh plumbing ---------------------------------------------------
 
@@ -427,8 +431,20 @@ class OverlappedTrainStep:
         # placed array aliases the caller's buffer — donation would delete
         # the user's own params out from under them.
         if ctx.topo.size == 1:
-            return jax.tree_util.tree_map(
-                lambda x: jax.device_put(jnp.array(x), rep), tree)
+            def lift(x):
+                if not (self._donate and isinstance(x, jax.Array)):
+                    return jax.device_put(jnp.array(x), rep)
+                # One process: the copy waits for the first call, and is
+                # made only of what the caller still holds then (`_own`).
+                # A caller that has let go of its trees by then (a training
+                # script does) never has both on the chip: 16 B a parameter
+                # of weights and AdamW state twice over is more than a chip
+                # holds from 650 M parameters on.
+                lifted = jax.device_put(x, rep)
+                self._borrowed.append((weakref.ref(lifted), weakref.ref(x)))
+                return lifted
+
+            return jax.tree_util.tree_map(lift, tree)
 
         def lift(x):
             x = jax.device_put(jnp.array(x), ctx.device)
@@ -436,6 +452,23 @@ class OverlappedTrainStep:
                 x.shape, rep, [x])
 
         return jax.tree_util.tree_map(lift, tree)
+
+    def _own(self, *trees):
+        """``trees`` with a copy in place of every leaf that `init` handed
+        back sharing a buffer with an array the caller still holds: the
+        step donates its arguments, and a donated buffer must be no one
+        else's.  Run once, before the first call."""
+        import jax
+        import jax.numpy as jnp
+
+        held = {id(lifted()): source for lifted, source in self._borrowed
+                if lifted() is not None and source() is not None}
+        self._borrowed = []
+        if not held:
+            return trees
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(jnp.array(x), x.sharding)
+            if id(x) in held else x, trees)
 
     def _lift_batch(self, ctx, batch):
         """Local batch shard [B, ...] → global [P*B, ...] sharded on the
@@ -460,7 +493,10 @@ class OverlappedTrainStep:
 
     def init(self, params, opt_state, aux=None):
         """Lift local params/optimizer state (and the aux state when
-        ``has_aux`` — e.g. flax batch_stats) onto the mesh (replicated)."""
+        ``has_aux`` — e.g. flax batch_stats) onto the mesh (replicated).
+        The caller's arrays stay the caller's: the step donates copies.  In
+        one process the copy of a leaf is made at the first call, and only
+        if the caller still holds its array then."""
         ctx = self._context()
         lifted = (self._lift_replicated(ctx, params),
                   self._lift_replicated(ctx, opt_state))
@@ -558,6 +594,8 @@ class OverlappedTrainStep:
                                        aux=aux)
         import jax
 
+        if self._borrowed:
+            params, opt_state, aux = self._own(params, opt_state, aux)
         with jax.set_mesh(ctx.mesh):
             if self._has_aux:
                 return self._step(params, opt_state, aux, gbatch)

@@ -18,7 +18,10 @@ MXU-sized matmuls):
   SDAR-30B-A3B (block diffusion, a share of the experts), SmallThinker-21BA3B
   (a layer pattern of windows and positions) and LFM2-8B-A1B presets (gated
   short convolutions among attention layers, a dense FFN before the expert
-  layers, sigmoid scores with a selection bias the step keeps);
+  layers, sigmoid scores with a selection bias the step keeps;
+  Nemotron-3-Super-120B-A12B: layers that are a Mamba-2 mixer, attention or
+  experts alone, the experts without a gate on a latent width beside a
+  shared expert);
 - :mod:`.training` — sharded train-step builders wiring models to the
   ``parallel`` layer and optax.
 """
@@ -32,6 +35,7 @@ from .transformer import (  # noqa: F401
     gpt_small_config,
     lfm2_8b_a1b_config,
     moe_stats,
+    nemotron_3_super_config,
     olmoe_1b_7b_config,
     sdar_30b_a3b_config,
     smallthinker_21b_a3b_config,

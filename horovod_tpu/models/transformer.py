@@ -1,5 +1,5 @@
-"""Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker
-and LFM2 presets.
+"""Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
+LFM2 and Nemotron-H presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -26,9 +26,13 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   (attention, or the gated short convolution of :class:`ShortConv` over
   ``kernels/short_conv.py``), which layers attend inside a window, which
   carry rotary positions and which carry a gated dense FFN in place of the
-  configuration's: OLMoE is ``olmoe_1b_7b_config()``, SDAR-30B-A3B
-  ``sdar_30b_a3b_config()``, SmallThinker-21BA3B
-  ``smallthinker_21b_a3b_config()`` and LFM2-8B-A1B ``lfm2_8b_a1b_config()``
+  configuration's, or that the layer is a mixer alone or an FFN alone under
+  one norm (Nemotron-H: a Mamba-2 mixer of ``models/mamba2.py`` over
+  ``kernels/ssd_scan.py``, attention, or experts without a gate on a latent
+  width beside a shared expert): OLMoE is ``olmoe_1b_7b_config()``,
+  SDAR-30B-A3B ``sdar_30b_a3b_config()``, SmallThinker-21BA3B
+  ``smallthinker_21b_a3b_config()``, LFM2-8B-A1B ``lfm2_8b_a1b_config()``
+  and Nemotron-3-Super-120B-A12B ``nemotron_3_super_config()``
   over the same ``Transformer``, their expert layer
   :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``), their masks
   that are rules ``kernels/masked_attention.py``'s.
@@ -48,7 +52,7 @@ from ..core.timeline import scope
 from ..kernels import masked_attention, short_attention, short_conv
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
-from ..parallel.moe import MoEStats, moe_ffn
+from ..parallel.moe import MoEStats, _activation, moe_ffn
 
 
 class LayerKind(NamedTuple):
@@ -57,10 +61,14 @@ class LayerKind(NamedTuple):
     ``window - 1`` before it); 0: the model's mask.  ``rope``: whether the
     layer applies the rotary positions (only where ``positions == "rope"``);
     a layer without them carries no position at all.  ``mixer``: what mixes
-    the tokens, ``"attention"`` or ``"conv"`` (:class:`ShortConv`, which
-    takes neither window nor positions).  ``ffn``: None, the
-    configuration's ``ffn``, or ``"dense"``, the gated dense FFN of width
-    ``d_ff_dense`` (a sparse model's leading dense layers)."""
+    the tokens, ``"attention"``, ``"conv"`` (:class:`ShortConv`, which
+    takes neither window nor positions), ``"mamba2"``
+    (``models/mamba2.py``, nor that) or ``"none"``: the layer is its FFN
+    alone, under one norm.  ``ffn``: None, the configuration's ``ffn``;
+    ``"dense"``, the gated dense FFN of width ``d_ff_dense`` (a sparse
+    model's leading dense layers); ``"moe"``; or ``"none"``: the layer is
+    its mixer alone, under one norm (Nemotron-H's layers are one or the
+    other)."""
 
     window: int = 0
     rope: bool = True
@@ -140,6 +148,31 @@ class TransformerConfig:
     router_scoring: str = "softmax"
     expert_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # ffn == "moe", Nemotron-H's LatentMoE.  expert_gate False: the experts
+    # are down(act(up x)) with no gate.  moe_latent: the width the routed
+    # experts multiply where that is not d_model: a projection d_model ->
+    # moe_latent in front of them and one back behind their weighted sum,
+    # the router still reading d_model.  d_ff_shared: the width of a shared
+    # expert of the experts' form that every token passes through on the
+    # full d_model, added to the routed sum (0: none).
+    expert_gate: bool = True
+    moe_latent: int = 0
+    d_ff_shared: int = 0
+    # The layers of kind mixer="mamba2" (models/mamba2.py): heads of
+    # mamba_head_dim channels in mamba_groups groups that share B and C of
+    # width mamba_state, a depthwise convolution of mamba_conv taps, the scan
+    # in chunks of mamba_chunk.  mamba_groups_held: the groups that live
+    # here where a mixer's heads are shared among chips (None: all).
+    # mamba_dt_limits: (time_step_min, time_step_max, time_step_floor) of
+    # dt_bias's initialisation.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_groups: int = 1
+    mamba_state: int = 128
+    mamba_conv: int = 4
+    mamba_chunk: int = 128
+    mamba_groups_held: Optional[Tuple[int, ...]] = None
+    mamba_dt_limits: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
 
     @property
     def head_dim(self) -> int:
@@ -241,6 +274,44 @@ def lfm2_8b_a1b_config(**overrides) -> TransformerConfig:
         tie_embeddings=True, ffn="moe", num_experts=32, experts_per_token=4,
         norm_topk_prob=True, router_scoring="sigmoid", expert_bias=True,
         layer_pattern=pattern), **overrides})
+
+
+# Nemotron-H's letters for a layer: a Mamba-2 mixer, an expert FFN or an
+# attention layer, each alone under one norm; attention carries no positions
+# (arXiv:2504.03624).
+HYBRID_KINDS = {"M": LayerKind(0, False, "mamba2", "none"),
+                "E": LayerKind(0, False, "none", "moe"),
+                "*": LayerKind(0, False, "attention", "none")}
+
+
+def hybrid_pattern(letters: str) -> Tuple[LayerKind, ...]:
+    """The layers' kinds of a ``hybrid_override_pattern``."""
+    return tuple(HYBRID_KINDS[letter] for letter in letters)
+
+
+def nemotron_3_super_config(**overrides) -> TransformerConfig:
+    """NVIDIA-Nemotron-3-Super-120B-A12B (``config.json`` of the BF16
+    release, ``nemotron_h``): 88 layers, each a Mamba-2 mixer (40: 128 heads
+    of 64 in 8 groups, state 128, 4 taps), a LatentMoE (40: 512 experts
+    ``down(relu(up x)^2)`` of width 2688 on a latent of 1024, 22 a token by
+    sigmoid scores plus a bias the step keeps, weights renormalised and
+    times 5, beside a shared expert of width 5376 on the full width) or
+    causal attention without positions (8: 32 query heads on 2 KV heads of
+    128), under one RMSNorm at 1e-5; no biases, an untied head.  The
+    multi-token-prediction module behind the stack is not built."""
+    letters = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+               "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+    return TransformerConfig(**{**dict(
+        vocab_size=131072, num_layers=88, num_heads=32, num_kv_heads=2,
+        head_width=128, d_model=4096, d_ff=2688, max_len=262144, causal=True,
+        norm="rmsnorm", norm_eps=1e-5, positions="rope", use_bias=False,
+        tie_embeddings=False, ffn="moe", num_experts=512,
+        experts_per_token=22, norm_topk_prob=True, router_scoring="sigmoid",
+        expert_bias=True, routed_scaling_factor=5.0, expert_gate=False,
+        expert_activation="relu2", moe_latent=1024, d_ff_shared=5376,
+        mamba_heads=128, mamba_head_dim=64, mamba_groups=8, mamba_state=128,
+        mamba_conv=4, mamba_chunk=128,
+        layer_pattern=hybrid_pattern(letters)), **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -479,18 +550,30 @@ class Block(nn.Module):
     def __call__(self, x, positions=None):
         cfg = self.cfg
         block_input = x
+        mixer, ffn = self.kind.mixer, self.kind.ffn or cfg.ffn
+        if mixer == ffn == "none":
+            raise ValueError("a layer of neither mixer nor FFN")
+        if mixer != "none":
+            with scope("norm"):
+                y = _norm(cfg, "ln1")(x)
+            if mixer == "conv":
+                y = ShortConv(cfg, name="conv")(y)
+            elif mixer == "attention":
+                y = Attention(cfg, self.kind, name="attn")(y, positions)
+            elif mixer == "mamba2":
+                # Here, not at the top: the mixer and its kernel load where
+                # a configuration asks for them.
+                from .mamba2 import Mamba2
+
+                y = Mamba2(cfg, name="mamba")(y)
+            else:
+                raise ValueError(f"unknown mixer {mixer!r}")
+            with scope("norm"):
+                x = x + y
+        if ffn == "none":
+            return x
         with scope("norm"):
-            y = _norm(cfg, "ln1")(x)
-        if self.kind.mixer == "conv":
-            y = ShortConv(cfg, name="conv")(y)
-        elif self.kind.mixer == "attention":
-            y = Attention(cfg, self.kind, name="attn")(y, positions)
-        else:
-            raise ValueError(f"unknown mixer {self.kind.mixer!r}")
-        with scope("norm"):
-            x = x + y
             y = _norm(cfg, "ln2")(x)
-        ffn = self.kind.ffn or cfg.ffn
         if ffn == "gelu":
             with scope("ffn"):
                 y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis),
@@ -522,31 +605,61 @@ class Block(nn.Module):
         collection (``apply(..., mutable=["moe"])``, then ``moe_stats``)."""
         cfg = self.cfg
         d, f = cfg.d_model, cfg.d_ff
+        # What the routed experts multiply: the model's width, or a latent.
+        width = cfg.moe_latent or d
         # The router is as wide as the model has experts; the stacks hold
         # the experts that live here.
         routed = cfg.num_experts
         e = routed if cfg.experts_held is None else len(cfg.experts_held)
         init = nn.initializers.normal(0.02)
-        weights = [self.param(name, init, shape, jnp.float32)
-                   for name, shape in (("router", (d, routed)),
-                                       ("experts_gate", (e, d, f)),
-                                       ("experts_up", (e, d, f)),
-                                       ("experts_down", (e, f, d)))]
+        shapes = {"router": (d, routed), "experts_gate": (e, width, f),
+                  "experts_up": (e, width, f), "experts_down": (e, f, width)}
+        if not cfg.expert_gate:
+            del shapes["experts_gate"]
+        router, *stacks = [self.param(name, init, shape, jnp.float32)
+                           for name, shape in shapes.items()]
+        gate = stacks.pop(0) if cfg.expert_gate else None
+        rows = y
+        if cfg.moe_latent:
+            with scope("moe.latent"):
+                rows = _dense(cfg, width, (None, cfg.model_axis),
+                              "latent_in")(y)
+            if router_input is None:
+                router_input = y
         # The selection bias is read here and stepped by the training step
         # (``expert_bias_collection``, ``moe.update_expert_bias``).
         bias = self.variable(
             "moe", "bias", jnp.zeros, (routed,), jnp.float32).value \
             if cfg.expert_bias else None
-        y, stats = moe_ffn(y, *weights, k=cfg.experts_per_token,
-                           data_axis=cfg.moe_data_axis, dtype=cfg.dtype,
-                           held=cfg.experts_held,
-                           norm_topk_prob=cfg.norm_topk_prob,
-                           router_input=router_input,
-                           activation=cfg.expert_activation,
-                           scoring=cfg.router_scoring, bias=bias,
-                           scale=cfg.routed_scaling_factor)
+        out, stats = moe_ffn(rows, router, gate, *stacks,
+                             k=cfg.experts_per_token,
+                             data_axis=cfg.moe_data_axis, dtype=cfg.dtype,
+                             held=cfg.experts_held,
+                             norm_topk_prob=cfg.norm_topk_prob,
+                             router_input=router_input,
+                             activation=cfg.expert_activation,
+                             scoring=cfg.router_scoring, bias=bias,
+                             scale=cfg.routed_scaling_factor)
         self.sow("moe", "stats", stats)
-        return y
+        if cfg.moe_latent:
+            # Of a share: applied to its own experts' partial sum.
+            with scope("moe.latent"):
+                out = _dense(cfg, d, (cfg.model_axis, None),
+                             "latent_out")(out)
+        if cfg.d_ff_shared:
+            # Every chip that shares the layer computes it alike: the sum
+            # over the shares counts it once.
+            with scope("moe.shared"):
+                act = _activation(cfg.expert_activation)
+                up = _dense(cfg, cfg.d_ff_shared, (None, cfg.model_axis),
+                            "shared_up")(y)
+                hidden = act(_dense(cfg, cfg.d_ff_shared,
+                                    (None, cfg.model_axis),
+                                    "shared_gate")(y)) * up \
+                    if cfg.expert_gate else act(up)
+                out = out + _dense(cfg, d, (cfg.model_axis, None),
+                                   "shared_down")(hidden)
+        return out
 
 
 def moe_stats(collection) -> MoEStats:

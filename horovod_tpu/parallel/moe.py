@@ -23,7 +23,10 @@ The router may read another tensor than the rows it multiplies
 the block's input, ``relu``).  Its scores are a softmax over all experts or a
 sigmoid each (``scoring``), and a ``bias`` an expert may enter the choice of
 the top k without entering their weights (LFM2-8B-A1B; the bias is state the
-step keeps by :func:`update_expert_bias`, not a parameter).
+step keeps by :func:`update_expert_bias`, not a parameter).  An expert may
+have no gate at all (``gate=None``: ``down(act(up x))``, Nemotron-H's
+squared-relu experts, which multiply rows of a latent width while the router
+reads the model's through ``router_input``).
 """
 
 from __future__ import annotations
@@ -146,8 +149,10 @@ _slots_to_rows.defvjp(lambda y, order, inverse: (y[inverse], order),
                       _slots_to_rows_bwd)
 
 
-# The gate's activation: ``down(act(gate x) * up x)``.
-_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# The gate's activation, ``down(act(gate x) * up x)``, or, of an expert
+# without a gate, the hidden layer's: ``down(act(up x))``.
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _activation(name: str):
@@ -209,10 +214,24 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
     return weights, experts, counts, balance, z
 
 
-def _moe_rows(x, router, gate, up, down, *, k, dtype, act=jax.nn.silu,
-              **route):
-    """:func:`moe_ffn` on the rows of one rank, routed as one set;
+def _hidden(grouped, rows, gate_up, act, dtype=None):
+    """``act(gate x) * up x`` for stacks ``(gate, up)``, ``act(up x)`` for
+    ``(up,)``, through the grouped product ``grouped``; the stacks cast to
+    ``dtype`` where one is given."""
+    def cast(w):
+        return w if dtype is None else w.astype(dtype)
+
+    *gate, up = gate_up
+    if gate:
+        return act(grouped(rows, cast(gate[0]))) * grouped(rows, cast(up))
+    return act(grouped(rows, cast(up)))
+
+
+def _moe_rows(x, router, *stacks, k, dtype, act=jax.nn.silu, **route):
+    """:func:`moe_ffn` on the rows of one rank, routed as one set; ``stacks``
+    are ``(gate, up, down)`` or, of experts without a gate, ``(up, down)``;
     ``route`` is what :func:`_route` takes besides the rows."""
+    *gate_up, down = stacks
     rows, tokens, d = x.shape
     n = rows * tokens
     with scope("moe.dispatch"):
@@ -225,8 +244,7 @@ def _moe_rows(x, router, gate, up, down, *, k, dtype, act=jax.nn.silu,
     with scope("moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=counts,
                                     preferred_element_type=dtype)
-        hidden = act(grouped(slots, gate.astype(dtype))) \
-            * grouped(slots, up.astype(dtype))
+        hidden = _hidden(grouped, slots, gate_up, act, dtype)
         out = grouped(hidden, down.astype(dtype))              # [n*k, d]
     with scope("moe.combine"):
         out = _slots_to_rows(out, order, inverse).reshape(n, k, d)
@@ -324,12 +342,13 @@ def _combine_bwd(tokens, res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
-                act=jax.nn.silu):
+def _held_chunk(xf, ws, *rest, k, cap, dtype, act=jax.nn.silu):
     """What the held experts give for the routed slots at sorted places
-    ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``sizes`` are the held
-    experts' row counts over the whole step; this chunk takes of each what
-    falls inside it."""
+    ``lo .. lo+cap``: ``[tokens, d]`` in fp32.  ``rest`` are the experts'
+    stacks (``gate, up, down``, or ``up, down`` without a gate), then
+    ``order, sizes, lo``.  ``sizes`` are the held experts' row counts over
+    the whole step; this chunk takes of each what falls inside it."""
+    *gate_up, down, order, sizes, lo = rest
     tokens = xf.shape[0]
     with scope("moe.dispatch"):
         ends = jnp.cumsum(sizes)
@@ -342,7 +361,7 @@ def _held_chunk(xf, ws, gate, up, down, order, sizes, lo, *, k, cap, dtype,
     with scope("moe.experts"):
         grouped = functools.partial(lax.ragged_dot, group_sizes=group,
                                     preferred_element_type=dtype)
-        hidden = act(grouped(rows_in, gate)) * grouped(rows_in, up)
+        hidden = _hidden(grouped, rows_in, gate_up, act)
         out = grouped(hidden, down)                            # [cap, d]
     with scope("moe.combine"):
         return _combine(out, ws, slot, token, group, tokens)
@@ -363,7 +382,8 @@ def _chunks(chunk, first: int, quantum: int):
     first chunk), recomputed in the backward pass instead of kept.  No
     routing drops a row.  Both directions are written out, so neither
     differentiates through a loop and each loop is a ``while``; what the
-    first chunk gives, its sum forward and its five cotangents backward, is
+    first chunk gives, its sum forward and its cotangents (of the rows, the
+    weights and the stacks) backward, is
     what the loops start from, so a loop of no trip costs no pass over
     either."""
 
@@ -375,26 +395,25 @@ def _chunks(chunk, first: int, quantum: int):
 
     # The loops lie under ``moe.combine`` in both directions; what a chunk
     # does keeps its own scopes inside.
-    def behind(y, xf, ws, gate, up, down, order, sizes):
+    def behind(y, *operands):
         with scope("moe.combine"):
             return lax.fori_loop(
-                0, reached(sizes),
-                lambda j, y: y + chunk(xf, ws, gate, up, down, order, sizes,
-                                       place(j), cap=quantum), y)
+                0, reached(operands[-1]),
+                lambda j, y: y + chunk(*operands, place(j), cap=quantum), y)
 
     @jax.custom_vjp
     def run(*operands):
         return behind(chunk(*operands, 0, cap=first), *operands)
 
     def fwd(*operands):
-        weights, (order, sizes) = operands[:5], operands[5:]
+        weights, (order, sizes) = operands[:-2], operands[-2:]
         y, back = jax.vjp(
             lambda *w: chunk(*w, order, sizes, 0, cap=first), *weights)
         return behind(y, *operands), (back, operands)
 
     def bwd(res, g):
         back, operands = res
-        weights, (order, sizes) = operands[:5], operands[5:]
+        weights, (order, sizes) = operands[:-2], operands[-2:]
 
         def more(j, acc):
             _, vjp = jax.vjp(
@@ -425,7 +444,9 @@ def _chunks(chunk, first: int, quantum: int):
 def row_quantum(slots: int, n_held: int, n_experts: int) -> int:
     """Rows an overflow chunk: a quarter of the mean share ``slots * n_held /
     n_experts`` of the ``slots`` routed rows that the ``n_held`` of
-    ``n_experts`` experts held here take.  0 where the rows are taken as one
+    ``n_experts`` experts held here take (or, where that is no whole
+    multiple of the kernel's 128 rows and at least as many, the first
+    multiple above it that divides the slots).  0 where the rows are taken as one
     chunk of them all: the quarter is no whole multiple of the 128 rows
     ``kernels/rows_to_tokens.py`` multiplies at a time (so the kernel would
     refuse it and the first chunk), the slots behind the first chunk
@@ -435,7 +456,20 @@ def row_quantum(slots: int, n_held: int, n_experts: int) -> int:
     from ..kernels import rows_to_tokens as kernel
 
     quantum, rest = divmod(slots * n_held, 4 * n_experts)
-    if rest or not quantum or quantum % kernel.CHUNK or slots % quantum \
+    if quantum >= kernel.CHUNK and (rest or quantum % kernel.CHUNK):
+        # A quarter that is rows enough for the kernel and no whole number
+        # of its 128: the next multiple of 128 above it that the slots are
+        # whole chunks of, if there is one under twice the quarter (22 of
+        # 512 experts a token over 8192 tokens, 8 held: 704 -> 1024).
+        quarter, quantum = quantum, 0
+        for rows in range(-(-(quarter + 1) // kernel.CHUNK) * kernel.CHUNK,
+                          2 * quarter, kernel.CHUNK):
+            if slots % rows == 0:
+                quantum = rows
+                break
+    elif rest:
+        return 0
+    if not quantum or quantum % kernel.CHUNK or slots % quantum \
             or 5 * quantum >= slots:
         return 0
     return quantum
@@ -444,7 +478,9 @@ def row_quantum(slots: int, n_held: int, n_experts: int) -> int:
 def row_buffer(slots: int, n_held: int, n_experts: int):
     """(chunks, rows of the first chunk) for ``slots`` routed rows of which
     the ``n_held`` of ``n_experts`` held here take ``n_held / n_experts`` on
-    average: a first chunk of five quarters of that share, which every step
+    average: a first chunk of five quarters of that share (five of
+    :func:`row_quantum`'s rows: more where it rounded the quarter up to the
+    kernel's 128), which every step
     runs, and behind it as many chunks of a quarter (:func:`row_quantum`) as
     the worst routing (every row here) needs, of which a step runs those its
     rows reach.  Five quarters: at the mean itself an even routing overflows
@@ -457,18 +493,18 @@ def row_buffer(slots: int, n_held: int, n_experts: int):
     return slots // quantum - 4, 5 * quantum
 
 
-def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
-                    act=jax.nn.silu, **route):
+def _moe_rows_share(x, router, *stacks, k, dtype, held, act=jax.nn.silu,
+                    **route):
     """:func:`moe_ffn` on the rows of one rank where only ``held`` of the
-    router's experts live here; ``route`` is what :func:`_route` takes
-    besides the rows."""
+    router's experts live here; ``stacks`` as :func:`_moe_rows` takes them;
+    ``route`` is what :func:`_route` takes besides the rows."""
     rows, tokens, d = x.shape
     n, n_experts = rows * tokens, router.shape[-1]
     held = tuple(held)
-    if len(held) != gate.shape[0] or len(set(held)) != len(held) \
+    if len(held) != stacks[0].shape[0] or len(set(held)) != len(held) \
             or not all(0 <= e < n_experts for e in held):
-        raise ValueError(f"held experts {held} for {gate.shape[0]} stacked "
-                         f"experts and a router of {n_experts}")
+        raise ValueError(f"held experts {held} for {stacks[0].shape[0]} "
+                         f"stacked experts and a router of {n_experts}")
     with scope("moe.dispatch"):
         xf = x.reshape(n, d)
     weights, experts, counts, balance, z = _route(xf, router, k, **route)
@@ -484,7 +520,7 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
         sizes = counts[np.asarray(held)]
         rows_in = (xf.astype(dtype), weights.reshape(n * k))
     with scope("moe.experts"):
-        stacks = (gate.astype(dtype), up.astype(dtype), down.astype(dtype))
+        stacks = tuple(w.astype(dtype) for w in stacks)
     _, first = row_buffer(n * k, len(held), n_experts)
     quantum = row_quantum(n * k, len(held), n_experts)
     chunk = functools.partial(_held_chunk, k=k, dtype=dtype, act=act)
@@ -499,8 +535,8 @@ def _moe_rows_share(x, router, gate, up, down, *, k, dtype, held,
         return y, MoEStats(balance[None], z[None], counts[None])
 
 
-def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
-            down: jax.Array, *, k: int, data_axis: Optional[str] = None,
+def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
+            up: jax.Array, down: jax.Array, *, k: int, data_axis: Optional[str] = None,
             dtype=jnp.bfloat16, held: Optional[Sequence[int]] = None,
             norm_topk_prob: bool = False,
             router_input: Optional[jax.Array] = None,
@@ -514,7 +550,8 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
     - ``x``: ``[rows, tokens, d]``;
     - ``router``: ``[d, experts]``; logits, scores and top-k run in fp32;
     - ``gate``, ``up``: ``[experts, d, width]``; ``down``:
-      ``[experts, width, d]``; multiplied in ``dtype``;
+      ``[experts, width, d]``; multiplied in ``dtype``.  ``gate`` None: the
+      experts have no gate and are ``down(act(up x))``;
     - ``held``: the ids of the experts that live here, in the order of the
       stacks, where a layer's experts are shared among chips (default: all,
       and the layer lowers to what it lowered to without the option).  The
@@ -530,7 +567,8 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
       that is not the rows it multiplies (SmallThinker routes by the block's
       input, before attention; ``router`` is then ``[d_r, experts]``).  Its
       gradient flows through the k weights and the auxiliary losses.
-    - ``activation``: the gate's, ``"silu"`` or ``"relu"``.
+    - ``activation``: the gate's, ``"silu"`` or ``"relu"``, or, without a
+      gate, the hidden layer's (``"relu2"``: the relu squared).
     - ``scoring``: ``"softmax"``, or ``"sigmoid"``: each expert's score is
       the sigmoid of its logit, the k weights are divided by their sum plus
       1e-6 under ``norm_topk_prob``, and there is no auxiliary loss (the
@@ -572,17 +610,20 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array,
     if bias is not None:
         extras["bias"] = (bias, P())
 
-    def call(x, router, gate, up, down, *rest):
-        return body(x, router, gate, up, down, **dict(zip(extras, rest)))
+    stacks = (up, down) if gate is None else (gate, up, down)
 
-    operands = (x, router, gate, up, down) + tuple(
+    def call(x, router, *rest):
+        return body(x, router, *rest[:len(stacks)],
+                    **dict(zip(extras, rest[len(stacks):])))
+
+    operands = (x, router, *stacks) + tuple(
         value for value, _ in extras.values())
     if data_axis is None or \
             data_axis not in jax.sharding.get_abstract_mesh().axis_names:
         return call(*operands)
     return jax.shard_map(
         call,
-        in_specs=(sharded, P(), P(), P(), P()) + tuple(
+        in_specs=(sharded, P()) + (P(),) * len(stacks) + tuple(
             spec for _, spec in extras.values()),
         out_specs=(sharded, MoEStats(sharded, sharded, sharded)),
     )(*operands)

@@ -49,6 +49,8 @@ BLOCKS = {
     "share_blockdiff": _LM + _MOE + ["attn.norm", "attn.rope"],
     "share_conv": _LM + _MOE + ["attn.norm", "attn.rope", "ffn",
                                 "conv.proj", "conv.gate"],
+    "share_ssm": _LM + _MOE + ["ssm.proj", "ssm.conv", "ssm.scan", "ssm.norm",
+                               "moe.latent", "moe.shared"],
     "resnet": ["loss", "bn", "resnet.stem", "resnet.stage1", "resnet.stage2",
                "resnet.stage3", "resnet.stage4", "resnet.head"],
 }
@@ -61,9 +63,9 @@ import collections, contextlib, glob, hashlib, json, re
 import flax.linen as nn, jax, jax.numpy as jnp, optax
 from horovod_tpu.models.resnet import BottleneckBlock, ResNet
 from horovod_tpu.models.transformer import (
-    LayerKind, Transformer, lfm2_8b_a1b_config, moe_stats,
-    olmoe_1b_7b_config, sdar_30b_a3b_config, smallthinker_21b_a3b_config,
-    tiny_config)
+    LayerKind, Transformer, hybrid_pattern, lfm2_8b_a1b_config, moe_stats,
+    nemotron_3_super_config, olmoe_1b_7b_config, sdar_30b_a3b_config,
+    smallthinker_21b_a3b_config, tiny_config)
 
 if {null}:
     jax.named_scope = lambda name: contextlib.nullcontext()
@@ -129,6 +131,15 @@ MODELS = {{
         layer_pattern=(LayerKind(0, True, "conv", "dense"),
                        LayerKind(0, True, "attention"),
                        LayerKind(0, True, "conv"))), True),
+    # Layers that are a mixer alone or experts alone: a Mamba-2 mixer of 2
+    # of 4 groups of heads, attention without positions, 2 of 8 experts
+    # without a gate on a latent width beside a shared expert.
+    "share_ssm": lambda: lm(nemotron_3_super_config(
+        **{{**share, "num_layers": 3}}, head_width=16, experts_per_token=2,
+        experts_held=(1, 6), moe_latent=16, d_ff_shared=48, mamba_heads=8,
+        mamba_head_dim=8, mamba_groups=4, mamba_groups_held=(0, 2),
+        mamba_state=16, mamba_chunk=16,
+        layer_pattern=hybrid_pattern("M*E")), True),
     "resnet": resnet,
 }}
 

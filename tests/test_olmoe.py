@@ -155,7 +155,9 @@ def test_program_agrees_with_the_plain_reference(case, dtype, seed):
     ("tests/smallthinker_reference.py",
      "chip_bench/configs/smallthinker-21b-a3b_reference.py"),
     ("tests/lfm2_reference.py",
-     "chip_bench/configs/lfm2-8b-a1b_reference.py")])
+     "chip_bench/configs/lfm2-8b-a1b_reference.py"),
+    ("tests/nemotron_reference.py",
+     "chip_bench/configs/nemotron-3-super-120b-a12b_reference.py")])
 def test_reference_copies_share_their_text(tests_copy, benchmarks_copy):
     """The benchmark keeps its own copy of each reference, so that the files
     under ``chip_bench/`` are enough by themselves."""

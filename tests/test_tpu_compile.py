@@ -405,3 +405,88 @@ def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     assert "8192,8192" not in text
     assert "bf16[2,8192,8,64]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_ssd_scan_compiles_at_nemotrons_shape(one_chip, no_compile_cache):
+    """One sequence of 8192 positions, a group of 16 heads of 64 with a state
+    of 128, in chunks of 128: the forward and the backward kernel of
+    ``kernels/ssd_scan.py``; the residuals are the inputs and the state every
+    chunk starts from (33.5 MB in fp32), and nothing the size of a state a
+    token (4.3 GB) is in the program."""
+    from horovod_tpu.kernels import ssd_scan as ss
+
+    assert ss.takes(8192, 16, 64, 1, 128)
+    x = _shape((1, 8192, 1024), jnp.bfloat16, one_chip)
+    bc = _shape((1, 8192, 128), jnp.bfloat16, one_chip)
+    per_head = _shape((1, 1, 8192, 16), jnp.float32, one_chip)
+
+    def loss(x, b, c, dt, cum):
+        y = ss._scan(x, b, c, dt, cum, 64, False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, bc, bc, per_head, per_head).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(hvd_ssd_scan\w*?)[.\d]* =", text))
+    assert kernels == {ss.FWD_NAME, ss.BWD_NAME}, kernels
+    assert all(re.match(ss.OP_LINE_NAMES, k) for k in kernels)
+    assert "f32[1,1,64,8,128,128]" in text          # the chunks' states
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def test_gateless_latent_expert_share_compiles_at_nemotrons_widths(
+        topo, no_compile_cache, monkeypatch):
+    """8192 positions, 22 of 512 experts a token, 8 held, rows of the latent
+    1024 against experts of width 2688 without a gate, the router reading the
+    model's 4096: a first chunk of 5120 places (the quarter of 704 rows
+    rounded up to 1024) through the rows kernel, two grouped products forward
+    where a gated expert has three, a ``while`` in each direction."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.kernels import rows_to_tokens as rt
+    from horovod_tpu.parallel.moe import moe_ffn, row_buffer, row_quantum
+
+    tokens, d, latent, k, held, experts, width = 8192, 4096, 1024, 22, 8, \
+        512, 2688
+    assert row_buffer(tokens * k, held, experts) == (172, 5120)
+    assert row_quantum(tokens * k, held, experts) == 1024
+    assert rt.takes(5120, latent, tokens) and rt.takes(1024, latent, tokens)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+
+    def shape(dims, dtype, spec=P()):
+        return _shape(dims, dtype, NamedSharding(mesh, spec))
+
+    args = [shape((1, tokens, latent), jnp.bfloat16, P("data")),
+            shape((1, tokens, d), jnp.float32, P("data")),
+            shape((d, experts), jnp.float32),
+            shape((held, latent, width), jnp.float32),
+            shape((held, width, latent), jnp.float32),
+            shape((experts,), jnp.float32)]
+
+    def loss(rows, seen, router, up, down, bias):
+        y, _ = moe_ffn(rows, router, None, up, down, k=k,
+                       held=tuple(range(held)), norm_topk_prob=True,
+                       router_input=seen, activation="relu2",
+                       scoring="sigmoid", bias=bias, scale=5.0,
+                       data_axis="data")
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    products = re.findall(
+        r"%ragged-dot-none[.\d]* = \w+\[(\d+),\d+[\],]", text)
+    # 6 of the first chunk (2 forward, 2 over its rows and 2 that give the
+    # stacks' gradients), and the loops' 2 forward, the same 2 recomputed
+    # and 4 more backward.
+    assert len(products) == 14, products
+    assert products.count("5120") == 4 and products.count("1024") == 6
+    assert products.count("8") == 4
+    assert len(re.findall(rf"%{rt.OP_LINE_NAME}[.\d]* =", text)) == 4
+    assert len(re.findall(r" while\(", text)) == 2
+    assert " conditional(" not in text
+    assert not re.findall(r"= \(?\w+\[180224,1024\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29

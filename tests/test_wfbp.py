@@ -159,6 +159,64 @@ print("WFBP_AUX_OK", rank, flush=True)
     assert "WFBP_AUX_OK 0" in out[0]
 
 
+@pytest.mark.parametrize("caller_keeps", [True, False],
+                         ids=["caller_keeps_its_trees", "caller_lets_go"])
+def test_init_copies_only_what_the_caller_still_holds(caller_keeps):
+    """np=1: ``init`` hands back arrays that share the caller's buffers and
+    the copy waits for the first call.  A caller that still holds its trees
+    then gets them copied (the step donates the copies: the caller's arrays
+    stay whole and unchanged); one that let go has nothing copied, so
+    weights and optimizer state are never on the device twice."""
+    out = run_distributed(1, f"""
+import gc
+import jax
+import jax.numpy as jnp
+import optax
+from horovod_tpu.frameworks.jax.wfbp import make_overlapped_train_step
+
+def loss_fn(p, aux, b):
+    return jnp.mean((b["x"] @ p["w"] - b["y"]) ** 2), aux + 1
+
+rng = np.random.RandomState(0)
+params = {{"w": jnp.asarray(rng.randn(4, 2), jnp.float32)}}
+before = np.asarray(params["w"]).copy()
+tx = optax.adam(0.05)
+b = {{"x": jnp.asarray(rng.randn(8, 4), jnp.float32),
+     "y": jnp.asarray(rng.randn(8, 2), jnp.float32)}}
+step = make_overlapped_train_step(loss_fn, tx, has_aux=True)
+state, aux = tx.init(params), jnp.zeros((), jnp.int32)
+p, s, a = step.init(params, state, aux)
+borrowed = len(step._borrowed)
+assert borrowed == 1 + 3 + 1, borrowed        # w; count, mu, nu; aux
+assert p["w"].unsafe_buffer_pointer() == params["w"].unsafe_buffer_pointer()
+pointer = p["w"].unsafe_buffer_pointer()
+handed = []
+own = step._own
+def watched(*trees):
+    out = own(*trees)
+    handed.append(out[0]["w"].unsafe_buffer_pointer())
+    return out
+step._own = watched
+if not {caller_keeps}:
+    del params, state, aux
+    gc.collect()
+for _ in range(3):
+    p, s, a, loss = step(p, s, b, a)
+assert step._borrowed == [] and len(handed) == 1
+if {caller_keeps}:
+    # The step got a copy; the caller's array is whole and as it was.
+    assert handed[0] != pointer
+    assert np.array_equal(np.asarray(params["w"]), before)
+    assert not params["w"].is_deleted()
+else:
+    assert handed[0] == pointer
+assert int(step.fetch(a)) == 3
+assert not np.array_equal(np.asarray(step.fetch(p)["w"]), before)
+print("WFBP_OWN_OK", rank, flush=True)
+""", timeout=240)
+    assert "WFBP_OWN_OK 0" in out[0]
+
+
 def test_overlapped_step_matches_big_batch_two_ranks():
     """Sync-DP equivalence: two ranks on half-batches through the
     overlapped step == one process on the full batch.  The in-program
