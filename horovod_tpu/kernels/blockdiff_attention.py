@@ -15,10 +15,11 @@ key ``r`` iff
 ``L*b + L(L-b)/2 + L(L+b)/2 = L**2 + L*b`` of the ``4 L**2`` pairs are
 allowed: a quarter of the square.  :func:`block_diffusion_mask` is that rule,
 on numpy or JAX integers, and :class:`BlockDiffusion` the rule in the form
-``kernels/masked_attention.py`` takes: its splash kernels compute the mask
-from one code a position, visit only the tiles the rule allows (80 of 256 at
-``L`` = 8192 with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and
-serve grouped KV heads without repeating them.  :func:`blockdiff_attention`
+``kernels/masked_attention.py`` takes: its kernels (the library's splash
+forward, one backward kernel of this repo) compute the mask from one code a
+position, visit only the tiles the rule allows (80 of 256 at ``L`` = 8192
+with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and serve grouped
+KV heads without repeating them.  :func:`blockdiff_attention`
 is that kernel under this rule; the calls lie under
 ``jax.named_scope("hvd.attn.blockdiff")``.
 """
@@ -118,7 +119,24 @@ class BlockDiffusion:
     scope = SCOPE
 
     def allowed(self, q_ids, kv_ids, seq_len):
-        return block_diffusion_mask(q_ids, kv_ids, seq_len // 2, self.block)
+        """:func:`block_diffusion_mask` by one number a position,
+        ``2 * B(p) + H(p)``: the key's equals the query's (the same block of
+        the same half), or it is odd (a clean key) and less (an earlier
+        block; for a clean query that leaves out its own block, which the
+        equality lets in).  It is :func:`_code`'s number by a division, so
+        that any block length does; it is made on the positions as they
+        come, so ids that broadcast against each other (the backward
+        kernel's, a row against a column) cost two comparisons a pair."""
+        half_len = seq_len // 2
+
+        def code(ids):
+            clean = ids >= half_len
+            return (ids - clean * half_len) // self.block * 2 + clean
+
+        q_code, kv_code = code(q_ids), code(kv_ids)
+        # An even code (a noisy key) is never less than a query's.
+        earlier_clean = kv_code + (1 - (kv_code & 1)) * (1 << 30)
+        return (kv_code == q_code) | (earlier_clean < q_code)
 
     def allowed_pairs(self, seq_len: int) -> int:
         return allowed_pairs(seq_len // 2, self.block)

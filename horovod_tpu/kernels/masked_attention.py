@@ -1,18 +1,20 @@
 """Softmax attention under a mask that is a rule, not a table.
 
-One wrapper around JAX's pallas splash attention
-(``jax.experimental.pallas.ops.tpu.splash_attention``) for every mask the
-models here state as a rule over (query position, key position): the kernels
-compute the mask from the rule, so they visit only the tiles it allows, no
-``[s, s]`` table exists anywhere, and grouped KV heads are served without
-repeating them.  A rule is a small hashable object with
+One wrapper for every mask the models here state as a rule over (query
+position, key position): the forward is JAX's pallas splash attention
+(``jax.experimental.pallas.ops.tpu.splash_attention``), the backward one
+kernel of this repo (``kernels/masked_attention_bwd.py``: dq, dk and dv from
+a single pass).  Both compute the mask from the rule, so they visit only the
+tiles it allows, no ``[s, s]`` table exists anywhere, and grouped KV heads are
+served without repeating them.  A rule is a small hashable object with
 
 - ``scope``: the ``jax.named_scope`` its kernel calls lie under;
 - ``allowed(q_ids, kv_ids, seq_len)``: the rule itself, a boolean array, on
-  numpy or JAX integers that broadcast against each other;
+  numpy or JAX integers that broadcast against each other (the backward
+  kernel's table of tiles and its mask in a partial tile come from it);
 - ``allowed_pairs(seq_len)``: how many pairs it allows in one sequence;
-- ``takes(seq_len)``: whether the kernel's tiles fit the rule at this length;
-- ``mask(seq_len)``: the rule as a mask the library computes in its kernels.
+- ``takes(seq_len)``: whether the kernels' tiles fit the rule at this length;
+- ``mask(seq_len)``: the rule as a mask the library computes in its kernel.
 
 The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
 :class:`Window` (causal, and the key inside the last ``size`` positions:
@@ -21,8 +23,8 @@ The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
 visits 136 of 256 tiles and a window of 4096 visits 70.  :func:`attention` is
 the kernel, :func:`einsum` the same mask through a grouped einsum (off the
 TPU, and for shapes the kernel does not take).  On the device's op line the
-three kernels are ``splash_mha_fwd_residuals``, ``splash_mha_dq_no_residuals``
-and ``splash_mha_dkv_no_residuals`` (:data:`OP_LINE_NAMES`) whatever the rule.
+two kernels are ``splash_mha_fwd_residuals`` and ``splash_mha_dkv_dq``
+(:data:`OP_LINE_NAMES`) whatever the rule.
 """
 
 from __future__ import annotations
@@ -35,22 +37,25 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.timeline import scope
+from . import masked_attention_bwd
 
 # A regular expression for the kernels' names on the device's op line.
 OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
 
-# The kernels' tiles (splash attention's ``BlockSizes``: queries x keys of the
-# forward, the dkv and the dq kernel, and the keys the forward and the dkv
-# kernel multiply at a time).  Measured on a v5e under the block-diffusion
-# mask at 16,384 positions, 32 query heads on 4 KV heads of 128, forward +
-# backward (PERF.md, PR 31): tiles of 256 94.4 ms, of 512 46.1, of 1024 42.2,
-# these 40.7; keys or queries of 2048 are slower or do not fit the fast
-# memory.
+# The forward kernel's tiles (splash attention's ``BlockSizes``: queries x
+# keys, and the keys it multiplies at a time).  Measured on a v5e under the
+# block-diffusion mask at 16,384 positions, 32 query heads on 4 KV heads of
+# 128, forward + the library's backward (PERF.md, PR 31): tiles of 256 94.4
+# ms, of 512 46.1, of 1024 42.2, these 40.7; keys or queries of 2048 are
+# slower or do not fit the fast memory.
 BLOCK = 1024
-_TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2,
-              block_q_dkv=BLOCK, block_kv_dkv=BLOCK,
-              block_kv_dkv_compute=BLOCK // 2, block_q_dq=BLOCK,
-              block_kv_dq=BLOCK)
+_TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2)
+# The backward kernel's: queries x keys, and the keys multiplied at a time.
+# At the same shape (PERF.md, PR 44; the backward alone, ms a layer, with the
+# block rule still in three clauses): these 23.96, the keys 256 or 1024 at a
+# time 23.88 and 23.93, queries of 512 25.12, of 2048 30.53 (fewer, larger
+# tiles hold more forbidden pairs); by codes these read 20.35.
+BWD_TILES = (BLOCK, BLOCK, BLOCK // 2)
 
 
 def _mask_lib():
@@ -118,8 +123,9 @@ def takes(rule, seq_len: int, head_dim: int) -> bool:
 
 @functools.lru_cache(maxsize=8)
 def _kernel(rule, seq_len: int, heads: int, interpret: bool):
-    """The splash kernel for one rule and shape; building it walks the rule
-    tile by tile on the host, once."""
+    """The library's forward kernel for one rule and shape, which also
+    returns the rows' log-sum-exp; building it walks the rule tile by tile
+    on the host, once."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
     )
@@ -129,27 +135,51 @@ def _kernel(rule, seq_len: int, heads: int, interpret: bool):
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mha(
             mask, block_sizes=splash.BlockSizes(**_TILES), head_shards=1,
-            q_seq_shards=1, interpret=interpret)
+            q_seq_shards=1, save_residuals=True, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attend(q, k, v, rule, interpret):
+    """``[b, h, s, d]`` in and out, ``q`` scaled."""
+    return _attend_fwd(q, k, v, rule, interpret)[0]
+
+
+def _attend_fwd(q, k, v, rule, interpret):
+    kernel = _kernel(rule, q.shape[2], q.shape[1], interpret)
+    with scope(rule.scope.removeprefix("hvd.")):
+        out, (logsumexp,) = jax.vmap(kernel)(q, k, v)
+    return out, (q, k, v, out, logsumexp)
+
+
+def _attend_bwd(rule, interpret, kept, do):
+    q, k, v, out, logsumexp = kept
+    with scope(rule.scope.removeprefix("hvd.")):
+        di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32),
+                        do.astype(jnp.float32))
+        return tuple(masked_attention_bwd.dq_dk_dv(
+            q, k, v, logsumexp, di, do, rule=rule, tiles=BWD_TILES,
+            interpret=interpret))
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def attention(q, k, v, rule, *, interpret: bool = False):
     """Softmax attention of ``q [b, s, h, d]`` on ``k, v [b, s, h_kv, d]``
     under ``rule``, scores scaled by ``d ** -0.5``; ``h_kv`` divides ``h`` and
     KV head ``j`` serves query heads ``j*h/h_kv`` to ``(j+1)*h/h_kv - 1``.
-    Returns ``[b, s, h, d]``.  Differentiable (the library's dq and dkv
-    kernels)."""
+    Returns ``[b, s, h, d]``.  Differentiable: the forward is the library's
+    kernel, the backward ``kernels/masked_attention_bwd.py``'s one."""
     _, s, h, d = q.shape
     if not takes(rule, s, d):
         raise ValueError(f"no kernel under {rule} for {s} positions, head "
                          f"width {d}")
-    kernel = _kernel(rule, s, h, interpret)
     hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
     # The copies into and out of the kernels' [heads, positions, width]
     # layout apart from the kernels, which alone lie under the rule's scope.
     with scope("attn.layout"):
         q, k, v = hsd(q * jnp.asarray(d ** -0.5, q.dtype)), hsd(k), hsd(v)
-    with scope(rule.scope.removeprefix("hvd.")):
-        out = jax.vmap(kernel)(q, k, v)
+    out = _attend(q, k, v, rule, interpret)
     with scope("attn.layout"):
         return out.transpose(0, 2, 1, 3)
 

@@ -695,16 +695,39 @@ def test_share_counters_become_gauges():
 # gradients, on 2 x 16 noised tokens (the einsum under the block mask); the
 # jaxpr of the block-diffusion kernel's call as a TPU gets it, forward and
 # the three gradients, 4 query heads on 2 KV heads of 128 at two tiles (the
-# kernels' names, tiles, grids, layout and scale are in that text).
+# kernels' names, tiles, grids, layout and scale are in that text).  PR 44
+# gave the wrapper a backward kernel of its own: ``blockdiff_kernel_call`` is
+# that PR's text, and ``blockdiff_forward_call`` the forward kernel's
+# equation in it without the line of profiler metadata that lists the
+# library's block sizes (the backward's are no longer among them): on PR
+# 44's parent (1e203e9) that equation hashes to the same.  ``sdar_tiny_step``
+# is PR 44's too: ``BlockDiffusion.allowed`` became the rule by one code a
+# position, and the einsum's mask with it (the same booleans:
+# tests/test_masked_attention_bwd.py).
 PARENT = {"jax": "0.9.0",
           "olmoe_tiny_step":
           "8087d002d28e741e1ac6df77c3274d5d667553b0a46e39fa0118d79057e04382",
           "moe_ffn_all_held":
           "1201d0c17d334896bcada19038041e50b74a544e9315c5c507cebc314c7f976c",
           "sdar_tiny_step":
-          "03bd56b7caf4f535575434484979afbb09f5e4c8cc5ac36998d8100bd9b81149",
+          "4867b927b92f44d46062e1653bbbf6af58448c5311e23a019e20845272bcf1ac",
           "blockdiff_kernel_call":
-          "fbe7a75d83f37991f5396e962dd361d15372fbd0f7c56fa0ce835f704a67ce54"}
+          "2579f64f8c25cb3b01701d988c9aedd3970d34b4c8d1f1bcff17b110c7ee59b8",
+          "blockdiff_forward_call":
+          "2cf36cf3da9d62dacb1dfe9ecede7018afcee1b43cdb825ddaa1292216f0b4a5"}
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr`` and of the jaxprs its
+    equations hold."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
 
 
 @pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held",
@@ -743,8 +766,15 @@ def test_lowers_to_what_the_parent_lowered_to(which):
             return jnp.sum(bd.blockdiff_attention(q, k, v, block=4)
                            .astype(jnp.float32))
 
-        text = str(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-            q, kv, kv).jaxpr)
+        jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, kv, kv).jaxpr
+        text = str(jaxpr)
+        forward, = (str(eqn) for eqn in _pallas_calls(jaxpr)
+                    if eqn.params["name"].startswith("splash_mha_fwd"))
+        forward = "\n".join(line for line in forward.splitlines()
+                            if "xprof_metadata" not in line)
+        assert hashlib.sha256(forward.encode()).hexdigest() \
+            == PARENT["blockdiff_forward_call"]
     else:
         d, f, e, k = 64, 32, 8, 2
         shape = jax.ShapeDtypeStruct

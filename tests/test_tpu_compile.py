@@ -149,9 +149,10 @@ def test_expert_layer_compiles_at_published_widths(one_chip,
 def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
                                                      no_compile_cache):
     """One sequence as [x_t ; x_0], 16384 positions, 32 query heads on 4 KV
-    heads of 128, blocks of 4: forward and both backward kernels, with the
-    tiles ``kernels/blockdiff_attention.py`` gives them, and no [2L, 2L]
-    table or score square in the program."""
+    heads of 128, blocks of 4: the library's forward kernel and the one
+    backward kernel of ``kernels/masked_attention_bwd.py`` with the tiles
+    ``kernels/masked_attention.py`` gives them, and no [2L, 2L] table or
+    score square in the program."""
     from horovod_tpu.kernels import blockdiff_attention as bd
 
     q = _shape((1, 16384, 32, 128), jnp.bfloat16, one_chip)
@@ -165,9 +166,8 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals",
-                       "splash_mha_dq_no_residuals",
-                       "splash_mha_dkv_no_residuals"}, kernels
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+        kernels
     assert all(re.match(bd.OP_LINE_NAMES, k) for k in kernels)
     assert "16384,16384" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
@@ -177,10 +177,10 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
 def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
                                                           no_compile_cache):
     """One sequence of 16384 positions, 28 query heads on 4 KV heads of 128,
-    causal and causal inside a window of 4096: forward and both backward
-    kernels with the tiles ``kernels/masked_attention.py`` gives them, KV
-    heads not repeated, and no [s, s] table or score square in the
-    program."""
+    causal and causal inside a window of 4096: the library's forward kernel
+    and the one backward kernel (no ``splash_mha_dq*``) with the tiles
+    ``kernels/masked_attention.py`` gives them, KV heads not repeated, and no
+    [s, s] table or score square in the program."""
     from horovod_tpu.kernels import masked_attention as ma
 
     rule = ma.Window(4096) if rule_name == "window" else ma.Causal()
@@ -194,9 +194,8 @@ def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals",
-                       "splash_mha_dq_no_residuals",
-                       "splash_mha_dkv_no_residuals"}, kernels
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+        kernels
     assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
     assert "16384,16384" not in text
     assert "28,16384,128" in text and "bf16[1,16384,28,128]" in text
@@ -383,8 +382,9 @@ def test_short_conv_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
 
 def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     """Two sequences of 8192 positions, 32 query heads on 8 KV heads of 64
-    under the causal rule: the library's three kernels take half a lane
-    group as it is, KV heads not repeated, no score square in the program."""
+    under the causal rule: the library's forward kernel and the one backward
+    kernel take half a lane group as it is, KV heads not repeated, no score
+    square in the program."""
     from horovod_tpu.kernels import masked_attention as ma
 
     rule = ma.Causal()
@@ -399,12 +399,34 @@ def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals",
-                       "splash_mha_dq_no_residuals",
-                       "splash_mha_dkv_no_residuals"}, kernels
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+        kernels
     assert "8192,8192" not in text
     assert "bf16[2,8192,8,64]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_masked_attention_compiles_at_nemotrons_shape(one_chip,
+                                                      no_compile_cache):
+    """One sequence of 8192 positions, 4 query heads on the KV head of 128
+    that serves them, causal: the same two kernels."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    rule = ma.Causal()
+    q = _shape((1, 8192, 4, 128), jnp.bfloat16, one_chip)
+    kv = _shape((1, 8192, 1, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, rule).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+        kernels
+    assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
+    assert "8192,8192" not in text
 
 
 def test_ssd_scan_compiles_at_nemotrons_shape(one_chip, no_compile_cache):
