@@ -239,6 +239,12 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                  "keeps (moe_ffn(bias=...), update_expert_bias): the largest "
                  "magnitude among a layer's biases, per layer= ; zero until "
                  "the first step, then a multiple of the update rate"),
+    "moe_router_product_passes": (
+        "gauge", "bf16 passes of the MXU a layer's router spends on its "
+                 "logits, per layer= : 3 where the rows it reads are a "
+                 "bfloat16 array (the fp32 weights alone are split), 6 for "
+                 "any other dtype (fp32 by fp32 at the highest precision); "
+                 "set where the layer is traced (models/transformer.py)"),
     # -- attention under a layer pattern
     #    (models/transformer.py::publish_attention) --
     "attn_allowed_pairs_per_step": (

@@ -52,7 +52,13 @@ from ..core.timeline import scope
 from ..kernels import masked_attention, short_attention, short_conv
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
-from ..parallel.moe import MoEStats, _activation, moe_ffn
+from ..parallel.moe import (
+    MoEStats,
+    RouterRows,
+    _activation,
+    moe_ffn,
+    router_product_passes,
+)
 
 
 class LayerKind(NamedTuple):
@@ -573,7 +579,8 @@ class Block(nn.Module):
         if ffn == "none":
             return x
         with scope("norm"):
-            y = _norm(cfg, "ln2")(x)
+            ln2 = _norm(cfg, "ln2")
+            y = ln2(x)
         if ffn == "gelu":
             with scope("ffn"):
                 y = _dense(cfg, cfg.d_ff, (None, cfg.model_axis),
@@ -593,8 +600,23 @@ class Block(nn.Module):
         elif ffn == "moe":
             if cfg.router_input not in ("ffn", "block"):
                 raise ValueError(f"unknown router_input {cfg.router_input!r}")
-            y = self._experts(y, block_input if cfg.router_input == "block"
-                              else None)
+            if cfg.router_input == "block":
+                router_input = block_input
+            elif cfg.norm == "rmsnorm" \
+                    and router_product_passes(x.dtype) == 3:
+                # y as the product it is, so that the router multiplies the
+                # bf16 stream itself (moe.RouterRows); the statistics are
+                # the norm's own formula, which XLA computes once (held to
+                # ln2's output in tests/test_lfm2.py).
+                with scope("norm"):
+                    mean2 = jnp.mean(jnp.square(x.astype(jnp.float32)),
+                                     axis=-1)
+                    router_input = RouterRows(
+                        x, lax.rsqrt(mean2 + cfg.norm_eps),
+                        ln2.variables["params"]["scale"])
+            else:
+                router_input = None
+            y = self._experts(y, router_input)
         else:
             raise ValueError(f"unknown ffn {ffn!r}")
         with scope("norm"):
@@ -641,6 +663,9 @@ class Block(nn.Module):
                              scoring=cfg.router_scoring, bias=bias,
                              scale=cfg.routed_scaling_factor)
         self.sow("moe", "stats", stats)
+        self._publish_router_product(
+            rows if router_input is None
+            else getattr(router_input, "rows", router_input))
         if cfg.moe_latent:
             # Of a share: applied to its own experts' partial sum.
             with scope("moe.latent"):
@@ -660,6 +685,20 @@ class Block(nn.Module):
                 out = out + _dense(cfg, d, (cfg.model_axis, None),
                                    "shared_down")(hidden)
         return out
+
+    def _publish_router_product(self, read):
+        """Set the gauge ``moe_router_product_passes`` of this layer, numbered
+        among the expert layers as ``moe.publish_routing`` numbers them, from
+        the dtype of the rows ``read`` that its router reads; runs where the
+        layer is traced (a block built by itself has no number and sets
+        none)."""
+        from ..core import metrics
+
+        layers = [f"layer_{i}" for i in self.cfg.expert_layers()]
+        if self.name in layers:
+            metrics.set_gauge("moe_router_product_passes",
+                              router_product_passes(read.dtype),
+                              layer=str(layers.index(self.name)))
 
 
 def moe_stats(collection) -> MoEStats:

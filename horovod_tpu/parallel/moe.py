@@ -32,7 +32,7 @@ reads the model's through ``router_input``).
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -163,13 +163,112 @@ def _activation(name: str):
                          f"{sorted(_ACTIVATIONS)}") from None
 
 
+class RouterRows(NamedTuple):
+    """What the router reads, as the product it is made of: the logical rows
+    are ``rows * row_scale[..., None] * col_scale`` (an RMSNorm of a bf16
+    stream: the stream, each row's ``rsqrt(mean(x**2) + eps)`` and the norm's
+    scale).  :func:`moe_ffn` takes it as ``router_input``, for rows that are
+    a bfloat16 array: the factors move out of the product (:func:`_logits`)
+    and the rows are multiplied as they are."""
+
+    rows: jax.Array         # [rows, tokens, d] bf16
+    row_scale: jax.Array    # [rows, tokens] fp32
+    col_scale: jax.Array    # [d] fp32
+
+
+def router_product_passes(dtype) -> int:
+    """bf16 passes of the MXU that the router's logits take over rows of
+    ``dtype``: three where the rows are a bfloat16 array (only the weights
+    are split), six for any other (fp32 by fp32 at the highest precision)."""
+    return 3 if dtype == jnp.bfloat16 else 6
+
+
+def _bf16_pieces(w):
+    """``w`` (fp32) as three bfloat16 arrays, joined along the last axis,
+    whose sum in fp32 is ``w`` to its last bit: 8 + 8 + 8 bits of its 24.
+    ``reduce_precision`` and not a cast there and back, which XLA may take
+    for no rounding at all (``xla_allow_excess_precision``)."""
+    pieces = []
+    for _ in range(3):
+        piece = lax.reduce_precision(w, exponent_bits=8, mantissa_bits=7)
+        pieces.append(piece.astype(jnp.bfloat16))
+        w = w - piece
+    return jnp.concatenate(pieces, axis=-1)
+
+
+def _sum_of_slabs(s3):
+    """``[n, 3 e]`` as its three slabs of e columns added up, the smallest
+    first."""
+    e = s3.shape[-1] // 3
+    return (s3[:, 2 * e:] + s3[:, e:2 * e]) + s3[:, :e]
+
+
+def _bf16_dot(a, b, contract):
+    """One bf16 by bf16 product with fp32 sums."""
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _rows_dot(x, w):
+    """``x [n, d]`` in bfloat16 times ``w [d, e]`` in fp32, to fp32: ``x`` is
+    exact in bf16, so three bf16 products against the pieces of ``w``
+    (:func:`_bf16_pieces`; one product ``[n, d] x [d, 3 e]``) hold every
+    term there is, where the highest precision splits both operands and
+    makes six.  Backward ``dw = x^T [u1 | u2 | u3]``, three passes over the
+    cotangent's pieces, and ``dx = u w^T``, two fp32 operands, in the six
+    passes of the highest precision, as it always was."""
+    return _rows_dot_fwd(x, w)[0]
+
+
+def _rows_dot_fwd(x, w):
+    s3 = _bf16_dot(x, _bf16_pieces(w), ((1,), (0,)))
+    return _sum_of_slabs(s3), (x, w)
+
+
+def _rows_dot_bwd(res, u):
+    x, w = res
+    with scope("moe.router"):
+        dw = _sum_of_slabs(_bf16_dot(x, _bf16_pieces(u), ((0,), (0,))))
+        dx = jnp.dot(u, w.T, precision=lax.Precision.HIGHEST)
+        return dx.astype(x.dtype), dw
+
+
+_rows_dot.defvjp(_rows_dot_fwd, _rows_dot_bwd)
+
+
+def _logits(xf, router, row_scale=None, col_scale=None):
+    """The router's logits ``(xf * row_scale[:, None] * col_scale) @ router``
+    in fp32 for rows ``xf [n, d]`` in bfloat16: ``row_scale * (xf @
+    (col_scale[:, None] * router))``, the fp32 factors moved out of the
+    product so that its left operand is the bf16 rows as they are
+    (:func:`_rows_dot`)."""
+    w = router.astype(jnp.float32)
+    if col_scale is not None:
+        w = col_scale.astype(jnp.float32)[:, None] * w
+    # Under moe_ffn's shard_map the router is one for all members and its
+    # cotangent a sum over them: as varying as the rows, that sum is taken
+    # behind the product's own cotangent.
+    axes = jax.typeof(xf).vma
+    s = _rows_dot(xf, lax.pcast(w, tuple(axes - jax.typeof(w).vma),
+                                to="varying"))
+    if row_scale is not None:
+        s = row_scale.astype(jnp.float32)[:, None] * s
+    return s
+
+
 def _route(xf, router, k, norm_topk_prob=False, router_input=None,
-           scoring="softmax", bias=None, scale=1.0):
+           scoring="softmax", bias=None, scale=1.0, row_scale=None,
+           col_scale=None):
     """The router on rows ``xf [n, d]`` (or, where given, on ``router_input
-    [rows, tokens, d_r]``, the same n rows), in fp32: each row's k weights
-    and experts ``[n, k]``, the rows routed to each expert ``[experts]``,
-    the load-balancing loss and the z-loss, all over every expert of the
-    router.  ``scoring``: the scores are a softmax over the experts, or a
+    [rows, tokens, d_r]``, the same n rows, times ``row_scale [rows,
+    tokens]`` and ``col_scale [d_r]`` where those are given), in fp32: each
+    row's k weights and experts ``[n, k]``, the rows routed to each expert
+    ``[experts]``, the load-balancing loss and the z-loss, all over every
+    expert of the router.  The logits of rows that are a bfloat16 array are
+    three bf16 products over the split weights (:func:`_logits`); rows of
+    any other dtype are multiplied in fp32 at the highest precision.
+    ``scoring``: the scores are a softmax over the experts, or a
     sigmoid of each logit, whose k weights are divided by their sum plus
     1e-6 under ``norm_topk_prob`` and carry no auxiliary loss (both zero).
     ``bias [experts]`` is added to the scores for the choice of the k alone;
@@ -179,8 +278,17 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
     with scope("moe.router"):
         if router_input is not None:
             xf = router_input.reshape(n, -1)
-        logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)
+        if router_product_passes(xf.dtype) == 3:
+            if row_scale is not None:
+                row_scale = row_scale.reshape(n)
+            logits = _logits(xf, router, row_scale, col_scale)
+        elif row_scale is not None or col_scale is not None:
+            raise ValueError(f"RouterRows of {xf.dtype} rows: the factors "
+                             f"beside them are a bfloat16 stream's")
+        else:
+            logits = jnp.dot(xf.astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)
         if scoring == "softmax":
             probs = jax.nn.softmax(logits, axis=-1)
         elif scoring == "sigmoid":
@@ -539,7 +647,7 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
             up: jax.Array, down: jax.Array, *, k: int, data_axis: Optional[str] = None,
             dtype=jnp.bfloat16, held: Optional[Sequence[int]] = None,
             norm_topk_prob: bool = False,
-            router_input: Optional[jax.Array] = None,
+            router_input: Union[jax.Array, RouterRows, None] = None,
             activation: str = "silu", scoring: str = "softmax",
             bias: Optional[jax.Array] = None, scale: float = 1.0):
     """Dropless top-k expert layer: ``sum_j p_j * down_j(act(gate_j x) *
@@ -566,7 +674,13 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
     - ``router_input``: ``[rows, tokens, d_r]``, what the router reads where
       that is not the rows it multiplies (SmallThinker routes by the block's
       input, before attention; ``router`` is then ``[d_r, experts]``).  Its
-      gradient flows through the k weights and the auxiliary losses.
+      gradient flows through the k weights and the auxiliary losses.  Or a
+      :class:`RouterRows`: those rows beside an fp32 factor a row and one a
+      column, the router reading their product (an RMSNorm of a bf16 stream
+      handed over as the stream and the norm's two factors).  Rows that are
+      a bfloat16 array, given either way or as ``x`` itself, are multiplied
+      in three bf16 passes over the split weights (:func:`_rows_dot`); any
+      other dtype in fp32 at the highest precision.
     - ``activation``: the gate's, ``"silu"`` or ``"relu"``, or, without a
       gate, the hidden layer's (``"relu2"``: the relu squared).
     - ``scoring``: ``"softmax"``, or ``"sigmoid"``: each expert's score is
@@ -602,7 +716,11 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
     # the program is then the one without the arguments.
     sharded = P(data_axis)
     extras = {}
-    if router_input is not None and router_input is not x:
+    if isinstance(router_input, RouterRows):
+        router_input, row_scale, col_scale = router_input
+        extras["row_scale"] = (row_scale, sharded)
+        extras["col_scale"] = (col_scale, P())
+    if router_input is not None and (router_input is not x or extras):
         if router_input.shape[:2] != x.shape[:2]:
             raise ValueError(f"router_input {router_input.shape} for rows "
                              f"{x.shape}")

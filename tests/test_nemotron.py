@@ -434,6 +434,56 @@ def test_the_shares_of_an_expert_layer_add_up_with_the_shared_expert_once():
     assert rel_err(routed[0] + shared, want) > 0.1
 
 
+# -- the router over 512 outputs, top 22 (PR 45) --------------------------------
+
+
+@pytest.mark.parametrize("factors", [True, False])
+def test_three_pass_logits_over_512_outputs_choose_float64s_top_22(factors):
+    """``tests/test_lfm2.py``'s check of the split product at this model's
+    router: sigmoid scores over 512 experts, the top 22."""
+    from .test_lfm2 import check_three_pass_logits
+
+    check_three_pass_logits("sigmoid", factors)
+
+
+def test_three_pass_gradients_over_512_outputs_are_the_old_lines():
+    from .test_lfm2 import check_gradients_are_the_old_lines
+
+    check_gradients_are_the_old_lines("sigmoid")
+
+
+@pytest.mark.parametrize("dtype,passes", [("bfloat16", 3), ("float32", 6)])
+def test_a_layers_router_product_follows_the_streams_dtype(dtype, passes):
+    """A bf16 stream reaches the router as it is, with the norm's two
+    factors beside it, and its logits are one bf16 product against the three
+    pieces of the weights; a float32 stream is multiplied as the parent
+    multiplied it.  The gauge says which, a layer."""
+    from horovod_tpu.core import metrics
+
+    from .test_lfm2 import _dot_generals
+
+    model, sizes = tiny_model(getattr(jnp, dtype))
+    tokens = tokens_of(sizes, 0)["tokens"]
+    metrics.registry.reset()
+    jaxpr = jax.make_jaxpr(lambda: model.init(jax.random.PRNGKey(0), tokens))()
+    for layer in range(ref.layer_plan(sizes).count("E")):
+        assert metrics.registry.get_gauge(
+            "moe_router_product_passes", layer=str(layer)) == passes
+    n = tokens.size
+    experts = sizes["n_routed_experts_published"]
+    router = [eqn for eqn in _dot_generals(jaxpr.jaxpr)
+              if eqn.outvars[0].aval.shape in ((n, experts), (n, 3 * experts))]
+    assert len(router) == ref.layer_plan(sizes).count("E")
+    for eqn in router:
+        if passes == 3:
+            assert eqn.outvars[0].aval.shape == (n, 3 * experts)
+            assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        else:
+            assert eqn.outvars[0].aval.shape == (n, experts)
+            assert [v.aval.dtype for v in eqn.invars] == [jnp.float32] * 2
+            assert eqn.params["precision"] is not None
+
+
 # -- experts without a gate ----------------------------------------------------
 
 

@@ -703,31 +703,37 @@ def test_share_counters_become_gauges():
 # 44's parent (1e203e9) that equation hashes to the same.  ``sdar_tiny_step``
 # is PR 44's too: ``BlockDiffusion.allowed`` became the rule by one code a
 # position, and the einsum's mask with it (the same booleans:
-# tests/test_masked_attention_bwd.py).
+# tests/test_masked_attention_bwd.py).  PR 45 moved the three that hold a
+# router over bf16 rows, ``olmoe_tiny_step``, ``moe_ffn_all_held`` and
+# ``sdar_tiny_step``: their logits are three bf16 products over the split
+# weights (``parallel/moe.py::_rows_dot``) and the tiny models hand the
+# router their stream and the norm's two factors; the three are that PR's
+# text.  The same programs in float32 lower to PR 45's parent's
+# (``tests/test_lfm2.py::FLOAT32_PARENT``).
 PARENT = {"jax": "0.9.0",
           "olmoe_tiny_step":
-          "8087d002d28e741e1ac6df77c3274d5d667553b0a46e39fa0118d79057e04382",
+          "f40aeee7bbc7a2319322d0e265ef7f6c753bcce30c9b6819e8a48c14b20b520c",
           "moe_ffn_all_held":
-          "1201d0c17d334896bcada19038041e50b74a544e9315c5c507cebc314c7f976c",
+          "0243b7b05474ef8842aca6e04f4b42b53e8246f6ab654a16c4afb7e4886007ae",
           "sdar_tiny_step":
-          "4867b927b92f44d46062e1653bbbf6af58448c5311e23a019e20845272bcf1ac",
+          "b680afb5a8d812b1e0f01cd824bab2910ede866f619d74caf5e41067d5dac2d4",
           "blockdiff_kernel_call":
           "2579f64f8c25cb3b01701d988c9aedd3970d34b4c8d1f1bcff17b110c7ee59b8",
           "blockdiff_forward_call":
           "2cf36cf3da9d62dacb1dfe9ecede7018afcee1b43cdb825ddaa1292216f0b4a5"}
 
 
-def _pallas_calls(jaxpr):
-    """Every ``pallas_call`` equation of ``jaxpr`` and of the jaxprs its
+def equations_of(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr`` and in the jaxprs its
     equations hold."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == primitive:
             yield eqn
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _pallas_calls(inner)
+                    yield from equations_of(inner, primitive)
 
 
 @pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held",
@@ -769,7 +775,7 @@ def test_lowers_to_what_the_parent_lowered_to(which):
         jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
             q, kv, kv).jaxpr
         text = str(jaxpr)
-        forward, = (str(eqn) for eqn in _pallas_calls(jaxpr)
+        forward, = (str(eqn) for eqn in equations_of(jaxpr, "pallas_call")
                     if eqn.params["name"].startswith("splash_mha_fwd"))
         forward = "\n".join(line for line in forward.splitlines()
                             if "xprof_metadata" not in line)

@@ -367,7 +367,9 @@ def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
     """The defaults spelled out lower to what the parent lowered to, whole
     layer and share alike (the share's digest taken on this tree without the
     arguments spelled: 64 slots are one chunk since PR 39, two until then,
-    when the digest was the parent fbf0cef's)."""
+    when the digest was the parent fbf0cef's).  Both digests are PR 45's:
+    the rows are bf16, so the router's logits are three bf16 products over
+    the split weights."""
     from horovod_tpu.parallel.moe import moe_ffn
 
     if jax.__version__ != PARENT["jax"]:
@@ -394,7 +396,7 @@ def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
         return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
     assert text(None, spelled=True) == PARENT["moe_ffn_all_held"]
-    share = "c246ebd84530964d80920d7766ea96768cae007645310697490a2e7a0c97db1e"
+    share = "dcdd670a4e5e94ee0c4e896832a6c0abca916f22e4f6c1b6b6db3092851e2253"
     assert text((1, 6)) == text((1, 6), spelled=True) == share
 
 
