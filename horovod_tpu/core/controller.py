@@ -465,8 +465,9 @@ class Controller:
 
     def _worker_round_aggregator(self, payload: bytes) -> ResponseList:
         """Fan-in aggregator: collect the host's cycle payloads, fold the
-        mask frames into one HostMaskFrame (fold_host — stateless, pure
-        per cycle), forward ONE bundle to the coordinator, and relay the
+        mask frames that agree into one HostMaskFrame (fold_host —
+        stateless, pure per cycle), forward ONE bundle to the
+        coordinator, and relay the
         response payload down verbatim (it is identical for every rank,
         like the tree fan-out's relays).  Heartbeat is touched AFTER the
         relay completes: a wedged coordinator link must not keep
@@ -536,9 +537,8 @@ class Controller:
 
         Under fan-in, a HostMaskFrame expands to one identical
         pending-mask contribution per covered rank — bit-exact with the
-        star's per-rank MaskFrames because the frame's mask is the AND of
-        exactly those ranks' masks and every rank re-announces its full
-        mask every cycle."""
+        star's per-rank MaskFrames because the frame covers exactly the
+        ranks that sent this very mask (``fold_host``)."""
         plan = self.fanin_plan
         if plan is not None and plan.role == "coordinator":
             entries: List[tuple] = []

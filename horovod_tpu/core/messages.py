@@ -393,16 +393,16 @@ class HostMaskFrame:
     """One HOST's aggregated steady-state contribution — the negotiation
     fan-in frame (``core/negotiation_fanin.py``).
 
-    Under tree fan-in the host's aggregator ANDs the MaskFrames of the
-    colocated ranks it covers into one bitvector and forwards THIS frame
-    in their place, so coordinator ingress per busy cycle scales with
-    hosts, not ranks.  Correctness leans on the mask fast path's
-    re-announcement property: every rank re-announces its FULL pending
-    cache-bit mask every cycle, so the aggregation is a stateless
-    per-cycle fold — nothing is accumulated at the aggregator, and an
-    aggregator death can lose at most the in-flight cycle, which the
-    lockstep abort already discards on every path.  ``covered`` names the
-    exact ranks whose masks were folded (ranks that sent a full
+    Under tree fan-in the host's aggregator sends THIS frame in place of
+    the MaskFrames of the colocated ranks whose bitvectors are the same
+    this cycle, so coordinator ingress per busy cycle scales with hosts,
+    not ranks.  Nothing is combined: a worker announces a cached
+    tensor's bit once and the coordinator keeps it pending rank by rank,
+    so a frame may only stand for ranks that said exactly this
+    (``fold_host``), the aggregator accumulates nothing, and its death
+    loses at most the in-flight cycle, which the lockstep abort already
+    discards on every path.  ``covered`` names those ranks (ranks that
+    sent another mask get a frame of their own, ranks that sent a full
     RequestList ride the bundle unfolded); the coordinator expands the
     frame to one identical pending-mask contribution per covered rank.
     ``shutdown`` is the OR of the covered ranks' flags, matching the
@@ -410,7 +410,7 @@ class HostMaskFrame:
     """
 
     covered: List[int] = field(default_factory=list)
-    mask: bytes = b""        # little-endian big-int bitvector (AND-fold)
+    mask: bytes = b""        # little-endian big-int bitvector
     shutdown: bool = False
 
     def to_bytes(self) -> bytes:

@@ -8,9 +8,10 @@ after the control plane (elastic/fanin.py) and membership churn
 (docs/elastic.md "Live resharding") were fixed.  This module supplies
 the data-plane analog of the reference's hierarchical controller: each
 host's ``local_rank 0`` becomes the **negotiation aggregator** — it
-collects its colocated ranks' cycle payloads, ANDs the mask frames into
-ONE :class:`~.messages.HostMaskFrame`, forwards a single bundle up to
-the coordinator, and fans the coordinator's (identical-for-everyone)
+collects its colocated ranks' cycle payloads, folds the mask frames that
+agree into ONE :class:`~.messages.HostMaskFrame`, forwards a single
+bundle up to the coordinator, and fans the coordinator's
+(identical-for-everyone)
 response payload back down.  Coordinator ingress per cycle drops from
 ``np - 1`` frames to ``(hosts - 1) + (local_size - 1)``.
 
@@ -18,14 +19,15 @@ Scope is deliberately the mask fast path only: a rank whose cycle needs
 a full ``RequestList`` (cache miss, join, shutdown-with-requests) rides
 the aggregator's bundle UNFOLDED, and the coordinator ingests it exactly
 as the star would — the PR 1 cache-bit semantics stay bit-exact because
-folding only ever touches frames whose entire meaning is "AND me".
+folding only ever merges frames that say the same thing.
 
-Statelessness is the correctness keystone: workers re-announce their
-FULL pending cache-bit mask every cycle, so the aggregator keeps no
-accumulated readiness — each cycle's fold is a pure function of that
-cycle's frames, and no crash/reorder can lose or double-count a bit
-across cycles (the ``hvd-mck`` fan-in model checks exactly this,
-``tools/mck/fanin_model.py``).
+Statelessness is the correctness keystone: the aggregator keeps no
+accumulated readiness and combines no two ranks' bits — a worker
+announces a cached tensor's bit once and the coordinator alone keeps it
+pending, rank by rank, as under the star — so each cycle's fold is a
+pure function of that cycle's frames, and no crash/reorder can lose or
+double-count a bit across cycles (``tools/mck/fanin_model.py`` checks
+the fold with an aggregator that dies or stalls at any step).
 
 Degrade semantics mirror ``elastic/fanin.py``'s aggregator-liveness
 idiom, adapted to a blocking lockstep mesh where a member CANNOT
@@ -49,6 +51,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import shutil
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -81,33 +84,36 @@ def fold_host(collected: Sequence[Tuple[int, bytes]]) -> List[Tuple[int, bytes]]
     """One host's per-cycle fold: ``[(rank, payload)]`` (the aggregator's
     own payload included) → bundle entries for the coordinator.
 
-    Mask frames collapse into ONE :class:`HostMaskFrame` — mask = AND of
-    the senders' bitvectors, ``covered`` = exactly those senders,
-    shutdown = OR of their flags (matching the coordinator's own OR-fold
-    over per-rank frames).  Everything else passes through unfolded, so
-    full-RequestList cycles keep per-rank fidelity.  Pure and stateless:
-    the output is a function of this cycle's input alone.
+    Mask frames that carry the SAME bitvector collapse into one
+    :class:`HostMaskFrame` — that mask, ``covered`` = exactly those
+    senders, shutdown = OR of their flags (matching the coordinator's own
+    OR-fold over per-rank frames) — so a host whose ranks agree this
+    cycle (all idle, the steady state, or all announcing the same
+    tensors) sends one frame, and one frame more for each mask that
+    differs.  Masks are never ANDed across ranks: a worker announces a
+    cached tensor's bit ONCE, in the cycle it pops the request, and the
+    coordinator keeps it pending per rank; an AND over one cycle's frames
+    drops the bit of every rank whose neighbour announces a cycle later,
+    for good, and the job waits for ever on a tensor all ranks are ready
+    for (the wedge of ``ROADMAP.md`` D0 (ii)).  Everything else passes
+    through unfolded, so full-RequestList cycles keep per-rank fidelity.
+    Pure and stateless: the output is a function of this cycle's input
+    alone.
     """
-    covered: List[int] = []
-    host_mask: Optional[int] = None
-    shutdown = False
+    by_mask: Dict[int, HostMaskFrame] = {}
     entries: List[Tuple[int, bytes]] = []
     for rank, payload in collected:
         if is_mask_frame(payload):
             frame = MaskFrame.from_bytes(payload)
-            covered.append(rank)
-            host_mask = frame.mask_int if host_mask is None \
-                else host_mask & frame.mask_int
-            shutdown = shutdown or frame.shutdown
+            host = by_mask.setdefault(
+                frame.mask_int, HostMaskFrame(mask=frame.mask))
+            host.covered.append(rank)
+            host.shutdown = host.shutdown or frame.shutdown
         else:
             entries.append((rank, payload))
-    if covered:
-        covered.sort()
-        mask_bytes = host_mask.to_bytes((host_mask.bit_length() + 7) // 8,
-                                        "little")
-        entries.append((covered[0],
-                        HostMaskFrame(covered=covered, mask=mask_bytes,
-                                      shutdown=shutdown).to_bytes()))
+    for host in by_mask.values():
+        host.covered.sort()
+        entries.append((host.covered[0], host.to_bytes()))
     entries.sort()
     return entries
 
@@ -283,6 +289,7 @@ class AggregatorHeartbeat:
         self._period = max(period, 1e-3)
         self._aggregator_rank = aggregator_rank
         self._cross_rank = cross_rank
+        self._is_aggregator = is_aggregator
         self._armed_at = time.time()
         self._last_touch = 0.0
         self._last_check = 0.0
@@ -313,6 +320,13 @@ class AggregatorHeartbeat:
 
     def touch(self) -> None:
         self._touch()
+
+    def close(self) -> None:
+        """The aggregator takes its heartbeat with it when its loop ends
+        (its members' loops have ended with it: the recv sets are
+        lockstep), so that no later job with the same key finds it."""
+        if self._is_aggregator:
+            shutil.rmtree(os.path.dirname(self._path), ignore_errors=True)
 
     # -- member side --------------------------------------------------
 

@@ -64,6 +64,9 @@ class HorovodGlobalState:
         self.initialized = threading.Event()
         self.shutdown_requested = threading.Event()
         self.shutdown_complete = threading.Event()
+        # Ends the metrics-and-lease pusher: with the loop, or with a
+        # bring-up that failed after the pusher had begun.
+        self._push_stop = threading.Event()
         self.joined = False
         self.join_event: Optional[threading.Event] = None
         self.cycle_time_ms = env_mod.DEFAULT_CYCLE_TIME_MS
@@ -166,6 +169,12 @@ class HorovodGlobalState:
             # (reference: workers surface through the rendezvous server and
             # horovodrun aborts if they don't within the timeout).
             store.set("worker_started", str(topo.rank), b"1")
+            # The lease is renewed from here on, before the mesh is up: a
+            # survivor of an epoch change waits in the mesh's bring-up for
+            # a joiner that may take longer to start than a lease lasts,
+            # and a live process that the driver declares dead is
+            # respawned beside itself.
+            self._start_metrics_pusher(store)
             # Per-link transport selection (transport/select.py): shm for
             # intra-host links, TCP cross-host, per HOROVOD_TRANSPORT.
             # Under the "tcp" policy this IS a plain TcpMesh.
@@ -228,8 +237,6 @@ class HorovodGlobalState:
                 self.controller.timeline = self.timeline
         metrics.registry.register_view("controller",
                                        self._controller_metrics_view)
-        if store is not None:
-            self._start_metrics_pusher(store)
         self._register_default_ops()
 
     def _sync_controller_topology(self, store, epoch: int,
@@ -443,7 +450,7 @@ class HorovodGlobalState:
         fanin = fanin_mod.maybe_create(store, period)
 
         rank = self.topo.rank
-        done = self.shutdown_complete
+        done = self._push_stop = threading.Event()
         identity = (
             f"{env_mod.get_str(env_mod.HOROVOD_HOSTNAME) or 'localhost'}:"
             f"{env_mod.get_int(env_mod.HOROVOD_LOCAL_RANK, 0)}")
@@ -502,6 +509,8 @@ class HorovodGlobalState:
             while not done.wait(period):
                 _push()
             _push()  # final snapshot so short jobs still land one
+            if fanin is not None:
+                fanin.close()
 
         threading.Thread(target=_push_loop,
                          name=f"hvd-metrics-push-r{rank}",
@@ -567,6 +576,7 @@ class HorovodGlobalState:
             self._build_transport()
         except BaseException as e:  # noqa: BLE001
             self.init_error = e
+            self._push_stop.set()  # the lease pusher, if it had begun
             self.initialized.set()
             return
         self.initialized.set()
@@ -613,6 +623,11 @@ class HorovodGlobalState:
             # reference draining the tensor table on shutdown.
             self._stop_dispatcher()
             self._fail_all_pending("Horovod has been shut down")
+            # Agreed in lockstep, so no member checks the heartbeat again.
+            # (A loop that died leaves it: a member that has not heard
+            # the abort yet would convict an aggregator of its absence.)
+            if self.controller.fanin_heartbeat is not None:
+                self.controller.fanin_heartbeat.close()
         finally:
             if self._finalizer_pool is not None:
                 # In-flight device work must complete (and fire callbacks)
@@ -622,6 +637,7 @@ class HorovodGlobalState:
                 self.mesh.close()
             if self.timeline is not None:
                 self.timeline.close()
+            self._push_stop.set()
             self.shutdown_complete.set()
 
     def _dump_flight_recorder(self, error: BaseException) -> None:

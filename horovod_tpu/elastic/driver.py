@@ -94,6 +94,8 @@ from ..transport.scopes import DRIVER_SCOPE  # noqa: E402  (re-export)
 #   (STEP_EXPIRE, identity)                    drop a dead-leased identity
 #   (STEP_ADVANCE, cause, removalish)          THE epoch advance (at most
 #                                   one per judged tick, cause-tagged)
+#   (STEP_FINISH,)                             the job is over: every rank
+#                                   of the committed world exited 0
 
 STEP_TXN = "txn"
 STEP_CLOCK = "clock"
@@ -103,6 +105,7 @@ STEP_POLL_HOSTS = "poll_hosts"
 STEP_GATE = "gate"
 STEP_EXPIRE = "expire"
 STEP_ADVANCE = "advance"
+STEP_FINISH = "finish"
 
 
 def pending_reset_reasons(raws: Dict[str, object], epoch: int) -> List[str]:
@@ -154,6 +157,22 @@ def decide_cause(expired, demoted, reset_reasons, missing_workers) -> str:
             "demotion" if demoted else
             "reset_request" if reset_reasons else
             "worker_exit" if missing_workers else "host_change")
+
+
+def job_end_steps(slot_identities, succeeded):
+    """The end of the job, judged at the head of every tick and from
+    nothing the store holds.  ``succeeded`` are the identities whose
+    process has exited 0 since it was last spawned.  Once they cover
+    the committed slot table the world is gone, and whatever process is
+    still up is a respawn in flight: the joiner of an identity whose
+    lease ran out while its process lived on and finished with its
+    peers.  It has no world to join and would wait out its mesh timeout,
+    exit transient and be respawned, for ever; so the job ends here and
+    the launcher cancels it.  Returns True when the job ended."""
+    if not slot_identities or set(slot_identities) - set(succeeded):
+        return False
+    yield (STEP_FINISH,)
+    return True
 
 
 def tick_read_steps(epoch: int, await_ack, slot_ids, removed, exited):
@@ -463,6 +482,10 @@ class ElasticDriver:
         self._await_ack: Optional[bool] = None  # added_only flavor, or None
         self._removed_identities: set = set()
         self._exited_identities: set = set()
+        # Identities whose process exited 0 since it was last spawned:
+        # what job_end_steps holds against the committed slot table.
+        self._succeeded_identities: set = set()
+        self.job_ended = False
         # (reporter identity, epoch, rank) demotions already counted: a
         # current-epoch report stays readable in the store until the
         # epoch advances (e.g. across waiting-for-capacity ticks), and
@@ -512,25 +535,37 @@ class ElasticDriver:
 
     # ------------------------------------------------------------------
 
-    def wait_for_available_slots(self, min_np: Optional[int] = None) -> None:
+    def wait_for_available_slots(self, min_np: Optional[int] = None,
+                                 start_np: Optional[int] = None) -> None:
         """Block until discovery provides enough slots
-        (reference ``driver.py:145``)."""
+        (reference ``driver.py:145``): ``start_np`` of them, the size the
+        job was asked to start at (the reference waits for ``num_proc``),
+        or at the timeout at least ``min_np``."""
         need = min_np or self.min_np
+        want = max(need, start_np or 0)
         deadline = time.monotonic() + self.timeout
         while True:
             self.hosts.update_available_hosts()
-            if self.hosts.total_slots() >= need:
+            have = self.hosts.total_slots()
+            if have >= want:
                 return
             if time.monotonic() > deadline:
+                if have >= need:
+                    log.warning("starting with %d of the %d slots asked "
+                                "for", have, want)
+                    return
                 raise TimeoutError(
-                    f"timed out waiting for {need} slots "
-                    f"(have {self.hosts.total_slots()})")
+                    f"timed out waiting for {need} slots (have {have})")
             time.sleep(DISCOVER_HOSTS_FREQUENCY_SECS)
 
-    def start(self, create_worker: Callable[[SlotInfo, int], None]) -> None:
-        """Publish epoch 0 assignments, spawn workers, start discovery."""
+    def start(self, create_worker: Callable[[SlotInfo, int], None],
+              start_np: Optional[int] = None) -> None:
+        """Publish epoch 0 assignments, spawn workers, start discovery.
+        Where hosts register one by one (Spark tasks), ``start_np`` keeps
+        the first of them from being a world by itself: with ``min_np`` 1
+        it would train alone, finish, and leave the others unranked."""
         self._create_worker = create_worker
-        self.wait_for_available_slots()
+        self.wait_for_available_slots(start_np=start_np)
         for attempt in range(5):
             if self._rendezvous_epoch(initial=True):
                 break
@@ -704,8 +739,16 @@ class ElasticDriver:
                             "driver", "DRV_SPAWN", t_spawn,
                             identity=identity, epoch=self.epoch)
                     self._exited_identities.discard(identity)
+                    self._succeeded_identities.discard(identity)
                     ack_ops.append(("set", EPOCH_ACK_SCOPE, identity,
                                     str(self.epoch).encode()))
+                    # Its predecessor's lease goes: left in the store it
+                    # stands unchanged while the new process starts, and
+                    # a start that takes longer than the lease lasts ends
+                    # in the next respawn.  An identity that has posted
+                    # no lease yet is exempt (scan_lease_steps).
+                    ack_ops.append(("delete", LEASE_SCOPE, identity))
+                    self._lease_seen.pop(identity, None)
                 self._known_identities[identity] = s
             if ack_ops:
                 self.rendezvous.batch(ack_ops)
@@ -770,6 +813,16 @@ class ElasticDriver:
         old ``continue``s).  ``t0_ns`` anchors the CHURN_EVENT span when
         this tick advances the epoch, so the span covers the detection
         work (lease scan, reset-request reads) that led to it."""
+        with self._lock:
+            ended = job_end_steps(
+                {f"{s.hostname}:{s.local_rank}" for s in self._slots},
+                set(self._succeeded_identities))
+        if any(step[0] == STEP_FINISH for step in ended):
+            log.info("every rank of epoch %d's world has exited 0; "
+                     "the job ends", self.epoch)
+            self.job_ended = True
+            self.stop()
+            return
         # Every per-tick store op rides one try: a failure means the
         # store is down/partitioned, NOT that workers died — freeze
         # membership judgment (no lease expiry, no epoch advance)
@@ -1141,12 +1194,14 @@ class ElasticDriver:
             self._registry.record_success(slot.rank)
             with self._lock:
                 self._exited_identities.add(identity)
+                self._succeeded_identities.add(identity)
                 self._success = True
                 # A clean exit clears the host's record: sporadic transient
                 # strikes spread over a long job must not accumulate into a
                 # blacklist of a healthy host.
                 self._crash_failures.pop(slot.hostname, None)
                 self._transient_failures.pop(slot.hostname, None)
+            self._wakeup.set()
             return
         self._registry.record_failure(slot.rank)
         transient = exit_code == TRANSIENT_EXIT_CODE

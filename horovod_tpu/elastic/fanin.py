@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import shutil
 import tempfile
 import time
 from typing import Dict, List, Optional
@@ -61,11 +62,15 @@ _HEARTBEAT = "aggregator.hb"
 def _spool_root(store: Store, fanin_dir: str) -> str:
     """Spool directory shared by this job's ranks on this host: keyed by
     the store endpoint (job-unique — two jobs on one box must not merge
-    spools) and the host identity (boot id — two "hosts" simulated on
-    one box share a spool only if they share an identity override)."""
+    spools) and the host identity (boot id and the host's index in the
+    job — two "hosts" simulated on one box each get their own spool, as
+    they get their own negotiation heartbeat; with one spool between
+    them their ``rank-N.ops`` overwrite each other and a live rank's
+    lease runs out)."""
     endpoint = getattr(store, "_base", "in-process")
+    cross_rank = env_mod.get_int(env_mod.HOROVOD_CROSS_RANK, 0)
     token = hashlib.sha1(
-        f"{endpoint}|{host_identity()}".encode()).hexdigest()[:16]
+        f"{endpoint}|{host_identity(cross_rank)}".encode()).hexdigest()[:16]
     return os.path.join(fanin_dir, f"hvd-fanin-{token}")
 
 
@@ -173,6 +178,15 @@ class HostFanin:
                         "directly", e)
             return False
         return self._heartbeat_fresh()
+
+    def close(self) -> None:
+        """The job's own teardown: the aggregator takes the spool with
+        it.  Left behind, it is found by whatever later job's store gets
+        the same endpoint, whose aggregator forwards the dead job's
+        leases and metrics as its own ranks'.  A peer that pushes after
+        this finds no directory to spool in and pushes directly."""
+        if self._is_aggregator:
+            shutil.rmtree(self._dir, ignore_errors=True)
 
 
 def maybe_create(store: Store, period: float) -> Optional[HostFanin]:

@@ -30,7 +30,7 @@ from ..runner.rendezvous import RendezvousServer
 from ..transport.shm import sweep_dead_segments
 from .discovery import FixedHosts, HostDiscoveryScript, HostManager
 from .driver import ElasticDriver
-from .registration import FAILURE, SUCCESS
+from .registration import FAILURE
 
 log = get_logger("horovod_tpu.elastic.launcher")
 
@@ -148,8 +148,17 @@ def launch_elastic_job(args, command: List[str]) -> int:
         env = _slot_env(slot, slot_rdv_addr, port, extra,
                         tpu_chip_binding=False)
         env[env_mod.HOROVOD_EPOCH] = str(epoch)
-        proc = spawn_worker(slot, command, env)
         identity = f"{slot.hostname}:{slot.local_rank}"
+        with lock:
+            previous = procs.get(identity)
+        if previous is not None and previous.poll() is None:
+            # The driver judged this identity dead (its lease ran out)
+            # and its process is not: one process an identity, or the two
+            # contend for one rank of the new world.
+            log.warning("worker %s is respawned while its process %d "
+                        "lives; killing that one", identity, previous.pid)
+            previous.kill()
+        proc = spawn_worker(slot, command, env)
         with lock:
             procs[identity] = proc
         prefix = f"[{slot.rank}]<stdout>: " if args.verbose else ""
@@ -165,14 +174,19 @@ def launch_elastic_job(args, command: List[str]) -> int:
     def _monitor(identity: str, slot: SlotInfo, proc: subprocess.Popen):
         code = proc.wait()
         with lock:
-            if procs.get(identity) is proc:
+            current = procs.get(identity) is proc
+            if current:
                 procs.pop(identity, None)
         log.info("worker %s exited with %d", identity, code)
         if code != 0:
             # A crashed worker never ran ShmMesh.close(); reclaim its
             # /dev/shm ring segments before the next epoch respawns here.
             sweep_dead_segments([proc.pid])
-        driver.record_worker_exit(slot, code)
+        if current:
+            driver.record_worker_exit(slot, code)
+        # else: superseded by a respawn of its identity (create_worker
+        # killed it); its exit says nothing of the process that holds the
+        # identity now.
 
     try:
         driver.start(create_worker)
@@ -180,10 +194,13 @@ def launch_elastic_job(args, command: List[str]) -> int:
             time.sleep(0.5)
             with lock:
                 alive = len(procs)
-            successes = driver._registry.count(SUCCESS)
             failures = driver._registry.count(FAILURE)
-            current = len(driver.current_slots)
-            if successes and successes >= current and alive == 0:
+            if driver.job_ended:
+                # The driver's judgment (job_end_steps).  A process still
+                # up is a respawn in flight with no world left to join:
+                # the teardown below cancels it.
+                if alive:
+                    log.info("cancelling %d respawn(s) in flight", alive)
                 return 0
             if alive == 0 and failures and \
                     driver.hosts.total_slots() < min_np:

@@ -19,9 +19,9 @@ on numpy or JAX integers, and :class:`BlockDiffusion` the rule in the form
 forward, one backward kernel of this repo) compute the mask from one code a
 position, visit only the tiles the rule allows (80 of 256 at ``L`` = 8192
 with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and serve grouped
-KV heads without repeating them.  :func:`blockdiff_attention`
-is that kernel under this rule; the calls lie under
-``jax.named_scope("hvd.attn.blockdiff")``.
+KV heads without repeating them.  ``masked_attention.attention(q, k, v,
+BlockDiffusion(block))`` is that kernel under this rule; the calls lie
+under ``jax.named_scope("hvd.attn.blockdiff")``.
 """
 
 from __future__ import annotations
@@ -148,11 +148,3 @@ class BlockDiffusion:
 
     def mask(self, seq_len: int):
         return _make_mask(seq_len // 2, self.block)
-
-
-def blockdiff_attention(q, k, v, *, block: int, interpret: bool = False):
-    """Softmax attention of ``q [b, 2L, h, d]`` on ``k, v [b, 2L, h_kv, d]``
-    under the block-diffusion mask with blocks of ``block`` tokens
-    (``masked_attention.attention`` under :class:`BlockDiffusion`)."""
-    return masked_attention.attention(q, k, v, BlockDiffusion(block),
-                                      interpret=interpret)

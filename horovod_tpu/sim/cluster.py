@@ -375,6 +375,7 @@ class SimCluster:
                 load_trace(os.path.join(self._tdir, "driver.json"))]))
             attribution = {
                 "coverage": doc["coverage"],
+                "event_count": doc["event_count"],
                 "phase_share": doc["phase_share"],
                 "phase_ms_per_event": {
                     p: round(v / 1e3 / max(len(event_records), 1), 3)
@@ -546,6 +547,7 @@ class SimCluster:
                 load_trace(os.path.join(self._tdir, "driver.json"))]))
             attribution = {
                 "coverage": doc["coverage"],
+                "event_count": doc["event_count"],
                 "phase_share": doc["phase_share"],
                 "event_wall_ms_p50": round(doc["wall_us"]["p50"] / 1e3, 3),
             }
@@ -712,6 +714,7 @@ class SimCluster:
                 load_trace(os.path.join(self._tdir, "driver.json"))]))
             attribution = {
                 "coverage": doc["coverage"],
+                "event_count": doc["event_count"],
                 "phase_share": doc["phase_share"],
                 "event_wall_ms_p50": round(doc["wall_us"]["p50"] / 1e3, 3),
             }

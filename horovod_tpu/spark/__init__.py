@@ -404,7 +404,7 @@ def run_elastic(fn: Callable, args: tuple = (),
                                   name="hvd-spark-elastic-job")
     job_thread.start()
     try:
-        driver.start(create_worker)
+        driver.start(create_worker, start_np=num_proc)
         threading.Thread(target=monitor, daemon=True,
                          name="hvd-spark-elastic-mon").start()
         while True:

@@ -2,12 +2,15 @@
 negotiation fan-in degrade protocol (core/negotiation_fanin.py).
 
 One host's negotiation tree under the same bounded-exhaustive engine as
-the epoch protocol: two members and their aggregator announce full
-cache-bit masks every cycle, the aggregator folds them through the REAL
-production ``fold_host`` kernel into one ``HostMaskFrame`` bundle, and
-the coordinator ingests bundles/direct frames, ANDs them into the
-agreed mask, and fans replies back (bundle replies relay through the
-aggregator).  The explorer crashes the aggregator at every step (free,
+the epoch protocol: two members and their aggregator announce the
+scenario's cache-bit masks every cycle (the model's workers hold what
+they are ready for and say all of it each round; a real worker says a
+bit once and the coordinator keeps it pending), the aggregator folds
+them through the REAL production ``fold_host`` kernel into one bundle
+(a ``HostMaskFrame`` for each distinct mask), and the coordinator
+ingests bundles/direct frames, ANDs them into the agreed mask, and fans
+replies back (bundle replies relay through the aggregator).  The
+explorer crashes the aggregator at every step (free,
 like proto crashes) and advances a model clock that stales the
 aggregator's heartbeat, driving the degrade path at every possible
 point of the cycle.
@@ -30,8 +33,8 @@ Degrade model: members check the heartbeat before acting; staleness
 aggregator can never touch it again) convicts — a coordinated abort
 discards the torn round, vetoes the host, and every survivor re-enters
 DIRECT.  Statelessness is what makes this safe and is exactly what the
-checker leans on: workers re-announce their FULL mask every cycle, so
-the retry round re-delivers everything the aborted round consumed.  A
+checker leans on: the aggregator holds nothing between rounds, so the
+retry round re-delivers everything the aborted round consumed.  A
 send to an already-dead aggregator (``PeerGoneError`` in production →
 abort → reshard → re-tree) collapses to the same veto-direct outcome
 here: the respawned re-treed epoch is bit-equivalent to a fresh model
@@ -142,6 +145,7 @@ class FaninExecution:
 
         # coordinator internals
         self.coord_inbox: List[Tuple[int, bytes]] = []
+        self.coord_frames = 0  # wire frames behind coord_inbox's entries
         self.replies: Dict[str, int] = {}
         self.completions: List[dict] = []
 
@@ -362,6 +366,7 @@ class FaninExecution:
         if w["state"] == "idle":
             if self.mode == "direct":
                 self.coord_inbox.append((w["rank"], self._payload(name)))
+                self.coord_frames += 1
                 w["state"], w["via"] = "posted", "coord"
             elif self._stale():
                 self._abort_and_veto(f"{name} convicted a stale heartbeat")
@@ -390,6 +395,7 @@ class FaninExecution:
         if self.mode == "direct":
             if w["state"] == "idle":
                 self.coord_inbox.append((w["rank"], self._payload("agg")))
+                self.coord_frames += 1
                 w["state"], w["via"] = "posted", "coord"
             else:
                 self.replies.pop("agg")
@@ -407,6 +413,7 @@ class FaninExecution:
                                             {"agg_rank": w["rank"]})
             # the REAL production fold — the kernel under check
             self.coord_inbox.extend(fold_host(list(stream)))
+            self.coord_frames += 1  # one bundle, whatever it holds
             self.agg_collected = {}
             self.agg_forwarded = True
             w["state"] = "posted"
@@ -428,6 +435,7 @@ class FaninExecution:
 
     def _coord_step(self) -> None:
         inbox, self.coord_inbox = self.coord_inbox, []
+        frames, self.coord_frames = self.coord_frames, 0
         agreed: Optional[int] = None
         counts: Dict[int, int] = {}
         bundle_covered: Tuple[int, ...] = ()
@@ -436,7 +444,8 @@ class FaninExecution:
                 frame = HostMaskFrame.from_bytes(payload)
                 for r in frame.covered:
                     counts[r] = counts.get(r, 0) + 1
-                bundle_covered = tuple(frame.covered)
+                bundle_covered = tuple(sorted(
+                    bundle_covered + tuple(frame.covered)))
                 mask = frame.mask_int
             elif is_mask_frame(payload):
                 counts[sender] = counts.get(sender, 0) + 1
@@ -480,7 +489,7 @@ class FaninExecution:
             return
         self.completions.append({
             "round": len(self.completions), "agreed": agreed,
-            "covered": tuple(sorted(counts)), "ingress_frames": len(inbox),
+            "covered": tuple(sorted(counts)), "ingress_frames": frames,
         })
         for sender, payload in inbox:
             if is_host_mask_frame(payload):
@@ -502,6 +511,7 @@ class FaninExecution:
         self.vetoed = True
         self.mode = "direct"
         self.coord_inbox = []
+        self.coord_frames = 0
         self.agg_collected = {}
         self.agg_forwarded = False
         self.relay_pending = None

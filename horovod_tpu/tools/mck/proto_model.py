@@ -62,7 +62,10 @@ Invariants (violation vocabulary below):
   the store's own data (V_RESHARD_EARLY_COMMIT);
 - a reshard-marked slot table never publishes while an older marked
   epoch sits uncommitted — the degradation to the legacy full-teardown
-  path is mandatory, not best-effort (V_RESHARD_FALLBACK_MISSED).
+  path is mandatory, not best-effort (V_RESHARD_FALLBACK_MISSED);
+- at the head of a tick the driver ends the job exactly when every
+  identity of the slot table has exited 0 since it was last spawned,
+  whatever respawn is still in flight (V_JOB_END).
 """
 
 from __future__ import annotations
@@ -77,10 +80,12 @@ from ...elastic.driver import (
     STEP_BLACKLIST,
     STEP_CLOCK,
     STEP_EXPIRE,
+    STEP_FINISH,
     STEP_GATE,
     STEP_GRACE,
     STEP_POLL_HOSTS,
     STEP_TXN,
+    job_end_steps,
     outage_recovery_steps,
     recover_steps,
     reshard_commit_steps,
@@ -126,7 +131,7 @@ __all__ = [
     "V_TORN_GROUP", "V_STALE_ACTED", "V_SMALL_WORLD_DEMOTION",
     "V_LIVE_DROPPED", "V_DEMOTED_HOST_KEPT", "V_RECOVER_MISMATCH",
     "V_RESHARD_EARLY_COMMIT", "V_RESHARD_FALLBACK_MISSED",
-    "V_MODEL_ERROR",
+    "V_JOB_END", "V_MODEL_ERROR",
 ]
 
 #: Violation names — the proto checker's vocabulary, referenced by the
@@ -142,6 +147,7 @@ V_DEMOTED_HOST_KEPT = "demoted-host-kept"
 V_RECOVER_MISMATCH = "recover-epoch-mismatch"
 V_RESHARD_EARLY_COMMIT = "reshard-early-commit"
 V_RESHARD_FALLBACK_MISSED = "reshard-fallback-missed"
+V_JOB_END = "job-end-misjudged"
 V_MODEL_ERROR = "model-error"
 
 RUNNABLE = "runnable"
@@ -300,6 +306,21 @@ def _driver_ticks(ex: "ProtoExecution", d: dict):
     scn = ex.scenario
     while d["tick"] < scn.ticks:
         d["tick"] += 1
+        # The job's end is judged first and from nothing the store
+        # holds, as in ``ElasticDriver._tick``.  The ground truth is the
+        # cluster's own record of who exited 0 since its last spawn.
+        ended = any(step[0] == STEP_FINISH for step in _maybe_wrap(
+            ex, "driver_job_end",
+            job_end_steps(set(ex.slots), set(d["succeeded"])), d))
+        if ended != (set(ex.slots) <= ex.exited_ok):
+            ex._fail(V_JOB_END,
+                     f"tick {d['tick']}: the driver "
+                     f"{'ended' if ended else 'did not end'} the job "
+                     f"with {sorted(ex.exited_ok)} of {sorted(ex.slots)} "
+                     "exited 0 since their last spawn")
+            return
+        if ended:
+            return
         reads = _maybe_wrap(
             ex, "driver_reads",
             tick_read_steps(d["epoch"], None, sorted(ex.slots), (), ()), d)
@@ -399,7 +420,11 @@ def _driver_ticks(ex: "ProtoExecution", d: dict):
             else:
                 if scn.reshard:
                     # Mirror the spawn loop: every ranked identity has a
-                    # live process after a successful publish.
+                    # live process after a successful publish, and a
+                    # spawned identity's earlier exit no longer counts.
+                    spawned = set(ex.slots) - d["known"]
+                    d["succeeded"] -= spawned
+                    ex.exited_ok -= spawned
                     d["known"] = set(ex.slots)
 
 
@@ -519,6 +544,15 @@ def _worker_proc(ex: "ProtoExecution", spec: dict):
             ops = [("set", EPOCH_ACK_SCOPE, spec["identity"],
                     str(observed).encode())]
             tag = "epoch_ack"
+        elif item[0] == "exit0":
+            # The process ends with code 0 and the launcher's monitor
+            # tells the driver (``record_worker_exit``): no wire, one
+            # step of its own.
+            yield ("pause", "exit")
+            ex.exited_ok.add(spec["identity"])
+            ex.drv["succeeded"].add(spec["identity"])
+            ex.drv["success"] = True
+            continue
         else:
             raise AssertionError(f"unknown worker script item {item!r}")
         try:
@@ -624,8 +658,13 @@ class ProtoExecution:
             "epoch": scenario.epoch0, "tick": 0, "outage": False,
             "grace": 0.0, "known": set(self.slots), "lease_seen": {},
             "reshard_pending": None, "last_joiners": set(),
+            "succeeded": set(), "success": False,
         }
+        # Who exited 0 since its last spawn: the cluster's own record.
+        self.exited_ok: Set[str] = set()
 
+        self._exiting = {spec["name"] for spec in scenario.workers
+                         if ("exit0",) in spec["script"]}
         self.procs: Dict[str, _Proc] = {"drv": _Proc(_driver_proc(self))}
         for spec in scenario.workers:
             self.procs[spec["name"]] = _Proc(_worker_proc(self, spec))
@@ -720,6 +759,10 @@ class ProtoExecution:
           steps (lease scan, expiry, re-grace stamps).  Workers and the
           coordinator never look at the clock, so they commute with it.
 
+        - ``exits`` — who exited 0 since its last spawn.  Written by a
+          worker's exit and by the driver (a spawn forgets an exit; the
+          head of a tick reads it), in scenarios whose workers exit.
+
         Over-approximation stays sound; the risk is UNDER-approximation,
         which tests/test_mck_proto.py guards by diffing a sleep-set run
         against a ``--no-sleep-sets`` run on a full scenario.
@@ -730,6 +773,8 @@ class ProtoExecution:
             touch = {("w", f"proc:{name}"), ("w", f"inbox:{name}")}
             if name == "drv":
                 touch.add(("r", "clock"))
+            if self._exiting and name in self._exiting | {"drv"}:
+                touch.add(("w", "exits"))
             return frozenset(touch)
         if kind == "s":
             req = self.inbox[action[1]]
@@ -1098,7 +1143,7 @@ class ProtoExecution:
             elif kind == STEP_POLL_HOSTS:
                 resp = self._poll_hosts()
             elif kind == STEP_GATE:
-                resp = False
+                resp = step[1] == "success" and d["success"]
             elif kind == STEP_EXPIRE:
                 self._apply_expire(step[1], d)
             elif kind == STEP_ADVANCE:

@@ -21,6 +21,7 @@ from typing import Dict
 
 from ...elastic.driver import (
     STEP_BLACKLIST,
+    STEP_FINISH,
     STEP_GRACE,
     STEP_POLL_HOSTS,
     STEP_TXN,
@@ -31,6 +32,7 @@ from .mutations import Mutation
 from .proto_model import (
     V_ACKED_LOST,
     V_DEMOTED_HOST_KEPT,
+    V_JOB_END,
     V_LIVE_DROPPED,
     V_RESHARD_EARLY_COMMIT,
     V_RESHARD_FALLBACK_MISSED,
@@ -184,6 +186,17 @@ def _regrace_dropped(gen, ctx):
         resp = yield step
 
 
+def _job_end_awaits_respawn(gen, ctx):
+    """Swallow the job's end while any identity was respawned: the
+    launcher's rule before the driver judged the end (every rank
+    finished AND no process alive), under which the joiner of a world
+    that has exited is waited for without end."""
+    for step in gen:
+        if step[0] == STEP_FINISH and ctx["epoch"] > 0:
+            continue
+        yield step
+
+
 PROTO_MUTATIONS: Dict[str, Mutation] = {m.name: m for m in (
     Mutation(
         "apply_before_journal", role="store", scenario="txn_crash",
@@ -232,6 +245,13 @@ PROTO_MUTATIONS: Dict[str, Mutation] = {m.name: m for m in (
         description="reshard marker kept while a previous reshard is "
                     "still uncommitted (legacy-fallback branch deleted)",
         wrap=_reshard_fallback_dropped),
+    Mutation(
+        "job_end_awaits_respawn", role="driver_job_end",
+        scenario="finished_beside_respawn",
+        expected=frozenset({V_JOB_END}),
+        description="the job's end withheld while a respawn is in "
+                    "flight (every rank exited 0, the joiner waited for)",
+        wrap=_job_end_awaits_respawn),
     Mutation(
         "fanin_bits_dropped", role="fanin_forward",
         scenario="fanin_degrade",

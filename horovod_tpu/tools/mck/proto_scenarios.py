@@ -256,6 +256,28 @@ PROTO_SCENARIOS: Dict[str, ProtoScenario] = {s.name: s for s in (
             [_slot_seed("h1:0", 1, 0, "h1"), _lease_seed("h1:0", 1, 0)],
         ],
         clock_steps=[11.0], reshard=True),
+    ProtoScenario(
+        "finished_beside_respawn",
+        "two workers go silent, their leases run out after a clock jump "
+        "and the advance respawns their identities, while the silent "
+        "processes live on and exit 0: the driver must end the job "
+        "at the first tick that finds every identity of the slot table "
+        "exited 0 since its last spawn (an exit before the respawn does "
+        "not count, and once a worker has finished no advance respawns "
+        "anything), and at no tick before",
+        preemptions=2, ticks=4, lease_timeout=10.0,
+        slots={"h0:0": (0, "h0"), "h1:0": (1, "h1")},
+        workers=[
+            {"name": "w0", "identity": "h0:0", "rank": 0, "epoch": 0,
+             "script": [("exit0",)]},
+            {"name": "w1", "identity": "h1:0", "rank": 1, "epoch": 0,
+             "script": [("exit0",)]},
+        ],
+        seeds=[
+            [_slot_seed("h0:0", 0, 0, "h0"), _lease_seed("h0:0", 0, 0)],
+            [_slot_seed("h1:0", 1, 0, "h1"), _lease_seed("h1:0", 1, 0)],
+        ],
+        clock_steps=[11.0], reshard=True),
 )}
 
 # The negotiation fan-in degrade scenario rides the same registry so the

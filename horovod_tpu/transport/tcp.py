@@ -26,7 +26,8 @@ mixed-version meshes fail loudly by construction.  When
 ``HOROVOD_WIRE_CRC`` is off the CRC field is absent from every frame.
 Connection establishment is deterministic to avoid crossed sockets: every
 rank listens; rank *i* dials every rank *j < i* and introduces itself
-with an 8-byte hello (magic + rank).
+with a hello (magic + rank + target), which the listener answers and the
+dialer confirms.
 
 Zero-copy data plane: ``send`` accepts any C-contiguous bytes-like object
 (a memoryview over a numpy slice included) and writes ``[header, payload]``
@@ -81,6 +82,9 @@ from .store import Store
 log = get_logger("horovod_tpu.transport.tcp")
 
 _HELLO = struct.pack("<I", 0x48564D54)  # "HVMT"
+# What either side gives the other to answer a hello.  A dialer that has
+# waited this long closes the socket and dials again.
+_HELLO_TIMEOUT_SECS = 5.0
 # How often a blocked recv wakes to check the mesh-wide abort flag and its
 # progress deadline.  Bounds abort-propagation latency for threads blocked
 # on a DIFFERENT peer's socket than the one the abort arrived on.
@@ -271,10 +275,10 @@ class TcpMesh:
             # candidate addresses and dialers try them in order — same
             # outcome on multi-homed hosts, no ssh dance).
             candidates = candidate_advertise_addrs()
-        store.set(scope, str(rank),
-                  ",".join(f"{a}:{port}" for a in candidates).encode())
-
         # Accept connections from higher ranks while dialing lower ranks.
+        # The acceptor runs before the address is published: a dialer
+        # reads the address the moment the store has it, which can be
+        # seconds before a busy store's answer lets this thread go on.
         accept_err: List[BaseException] = []
         self._accept_done = threading.Event()
         n_expected = size - 1 - rank
@@ -282,6 +286,8 @@ class TcpMesh:
             target=self._accept_loop, args=(n_expected, accept_err, timeout),
             name=f"hvd-tcp-accept-r{rank}", daemon=True)
         acceptor.start()
+        store.set(scope, str(rank),
+                  ",".join(f"{a}:{port}" for a in candidates).encode())
 
         lower = [str(j) for j in range(rank)]
         addrs = store.wait(scope, lower, timeout=timeout) if lower else {}
@@ -307,6 +313,17 @@ class TcpMesh:
     #
     # dialer:   HELLO + my_rank + target_rank [+ HMAC]  →
     # acceptor:                    ←  HELLO + its_rank + dialer_rank [+ HMAC]
+    # dialer:   HELLO (the bare magic: "I kept this socket")  →
+    #
+    # The acceptor registers a connection only when the third message has
+    # arrived.  A dialer gives a handshake 5 s and then dials again; an
+    # acceptor that was starved for longer found the abandoned socket
+    # first in its queue, answered it and registered it, closed the
+    # dialer's second attempt as a duplicate, and left both ranks holding a
+    # dead socket: the job died at its first frame with "peer closed
+    # connection".  An abandoned socket now ends in EOF where the third
+    # message is read, and the attempt the dialer kept is the one that
+    # registers.
     #
     # Carrying the intended TARGET lets the acceptor refuse (without
     # registering) a connection that reached the wrong machine — with
@@ -449,11 +466,12 @@ class TcpMesh:
         # Bounded handshake: an endpoint that accepts but never answers
         # must fall through to the next candidate, not hang the mesh
         # (symmetric with the accept side).
-        sock.settimeout(5.0)
+        sock.settimeout(_HELLO_TIMEOUT_SECS)
         sock.sendall(self._hello_blob(self.rank, target))
         got, _ = self._check_hello(_recv_exact(sock, self._hello_len()))
         if got != target:
             raise HorovodInternalError(f"peer answered as rank {got}")
+        sock.sendall(_HELLO)
         sock.settimeout(None)
         return sock
 
@@ -462,7 +480,7 @@ class TcpMesh:
         registered (duplicates and misroutes are answered, then closed)."""
         try:
             _configure(sock)
-            sock.settimeout(5.0)
+            sock.settimeout(_HELLO_TIMEOUT_SECS)
             peer_rank, intended = self._check_hello(
                 _recv_exact(sock, self._hello_len()))
             # Always answer with our identity so a misrouted dialer
@@ -472,6 +490,8 @@ class TcpMesh:
             if intended != self.rank:
                 sock.close()
                 return False
+            if _recv_exact(sock, len(_HELLO)) != _HELLO:
+                raise HorovodInternalError("bad tcp mesh hello")
             sock.settimeout(None)
         except (OSError, HorovodInternalError):
             # Unauthenticated or malformed connection: drop it
@@ -503,14 +523,12 @@ class TcpMesh:
             # bring-up immediately, not after the full startup timeout.
             self._accept_done.set()
             return
-        # Quota filled — keep servicing LATE dial retries until close.
-        # Under load a dialer can abandon a half-done handshake (5 s
-        # hello timeout) that we already counted, then retry; with nobody
-        # accepting, that retry jams in the listen backlog and its rank
-        # blocks in connect until the job dies — the silent-hang flavor
-        # of the bring-up race.  Answering the hello (and closing the
-        # duplicate) turns it into a fast PeerGoneError on whichever
-        # socket lost, which the coordinated-abort plane then cleans up.
+        # Quota filled — keep answering until close.  A connection that
+        # arrives now is a stray (a candidate address that lost the
+        # dialer's connect race, a dial that reached the wrong machine):
+        # with nobody accepting it would sit in the listen backlog.  It
+        # cannot be a retry of a handshake that was counted: only a
+        # socket its dialer kept is counted (the third message above).
         while not self._closed:
             try:
                 self._listener.settimeout(1.0)

@@ -11,7 +11,6 @@ hard-set the env *and* update the live jax config.
 """
 
 import os
-import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Containerized CI reports the HOST's loadavg (≈0 even when this cgroup's
@@ -26,10 +25,50 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
-if "jax" in sys.modules:
-    import jax
+# A session's directory, made anew by the process that starts the session
+# (xdist's controller, or the only one), handed to its workers through
+# HVD_TEST_SESSION_DIR and removed at the end (pytest_unconfigure), so that
+# nothing of a run outlives it and two runs do the same work.  It holds:
+#
+# - A compilation cache for each pytest process (JAX 0.9.0 serves its
+#   persistent cache on the CPU backend).  The model path compiles the same
+#   tiny programs again and again in one process: a suite's cases that
+#   differ in a seed, a model's suite and its cell's, the siblings' steps
+#   that the next model re-runs, un-jitted losses primitive by primitive.
+#   Each process has a directory of its own, set in jax's configuration and
+#   not in the environment: the cache writes an entry in place
+#   (``LRUCache.put``: ``write_bytes``), so a reader beside a writer can be
+#   handed half an entry, and the two ranks of a launched job write the
+#   same programs at the same moment (a two-rank job waited out its 900 s
+#   on such a cache).  A test of caching itself names its own directory.
+# - What a job leaves where its product's defaults point: the lease spools
+#   (``/dev/shm/hvd-fanin-*``) and negotiation heartbeats
+#   (``$TMPDIR/hvd-neg-fanin-*``) of jobs that were killed, and the flight
+#   recorder's post-mortems (``hvd_flight_recorder/`` in the working
+#   directory, which is the checkout).  A job's own teardown removes the
+#   first two; a SIGKILL leaves them, and they are keyed by the store's
+#   endpoint, which a later job's ephemeral port can repeat.  Under the
+#   session's directory they are this session's alone and go with it.
+_session_dir = None
+if "PYTEST_XDIST_WORKER" not in os.environ:
+    import tempfile
 
-    jax.config.update("jax_platforms", "cpu")
+    _session_dir = tempfile.mkdtemp(prefix="hvd_test_session_")
+    os.environ["HVD_TEST_SESSION_DIR"] = _session_dir
+    for _name, _sub in (("HOROVOD_FANIN_DIR", "fanin"),
+                        ("HOROVOD_NEGOTIATION_FANIN_DIR", "neg_fanin"),
+                        ("HOROVOD_FLIGHT_RECORDER_DIR", "post_mortems")):
+        os.environ[_name] = os.path.join(_session_dir, _sub)
+        os.mkdir(os.environ[_name])
+
+import jax  # noqa: E402
+
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_compilation_cache_dir", os.path.join(
+    os.environ["HVD_TEST_SESSION_DIR"], "jax_cache",
+    os.environ.get("PYTEST_XDIST_WORKER", "main")))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 # Lockdep (horovod_tpu/common/lockdep.py): when HOROVOD_LOCK_DEBUG is
 # enabled, instrument THIS pytest process too (worker subprocesses
@@ -69,6 +108,13 @@ def pytest_configure(config):
     # "engagements this run" must mean THIS run even when the operator
     # pins the log path across runs: start from an empty file.
     open(os.environ["HVD_TEST_RETRY_LOG"], "w").close()
+
+
+def pytest_unconfigure(config):
+    if _session_dir is not None:
+        import shutil
+
+        shutil.rmtree(_session_dir, ignore_errors=True)
 
 
 def pytest_collection_modifyitems(config, items):

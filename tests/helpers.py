@@ -9,6 +9,7 @@ against the real runtime, and the test asserts on worker stdout/exit codes.
 
 from __future__ import annotations
 
+import functools
 import os
 import socket
 import subprocess
@@ -17,6 +18,28 @@ import time
 from typing import Dict, List, Optional
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def reference_path(config: str) -> str:
+    return os.path.join(REPO_ROOT, "chip_bench", "configs",
+                        config + "_reference.py")
+
+
+@functools.lru_cache(maxsize=None)
+def load_reference(config: str):
+    """The plain float32 reference of a configuration, e.g.
+    ``load_reference("sdar-30b-a3b")``: the one file
+    ``chip_bench/configs/<config>_reference.py``, which the benchmark
+    decides ``correct`` by and the tier-1 suites hold the program to.  The
+    name has hyphens, so it is loaded by path; once a process."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_bench_" + config.split("-")[0] + "_reference",
+        reference_path(config))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 # ---------------------------------------------------------------------------
 # port reservation (de-flake: the bind(0)-close-reuse idiom races the OS

@@ -192,7 +192,12 @@ def test_kill_rank_mid_allreduce_np4_coordinated_abort():
     recv."""
     outs = run_distributed(
         4, _SURVIVOR_BODY, timeout=120, expect_failure=True, retries=0,
-        extra_env={**_FAST_DEADLINE,
+        # A death is seen at once, as the end of a stream: no deadline is
+        # this test's subject, and _FAST_DEADLINE's 3 s of no progress is
+        # what a rank starved by the other xdist workers' jobs shows too,
+        # so that the victim heard a survivor's abort before its own
+        # fault fired.
+        extra_env={"HOROVOD_TRANSPORT": "tcp",
                    "HOROVOD_FAULT_SPEC":
                        "dispatch.collective:rank=2:nth=2:action=exit,9"})
     for r in (0, 1, 3):
@@ -265,7 +270,14 @@ def test_stall_shutdown_np4_propagates_to_all_ranks():
 import time
 from horovod_tpu.common.exceptions import HorovodInternalError
 if rank == 3:
-    time.sleep(8)    # never submits (must outlive the 3s stall deadline)
+    # Never submits, and outlives the stall deadline whatever the machine's
+    # load: it stays until the abort has reached its own loop (a fixed 8 s
+    # ran out first on a busy machine, and its exit was what the others
+    # reported).
+    from horovod_tpu.core.state import global_state
+    until = time.monotonic() + 100
+    while global_state().background.is_alive() and time.monotonic() < until:
+        time.sleep(0.1)
 else:
     try:
         hvd.allreduce(np.ones(4, np.float32), name="never")
@@ -273,7 +285,10 @@ else:
     except HorovodInternalError as e:
         print("STALL_ABORT", rank, str(e).replace("\\n", " "), flush=True)
 """, timeout=120, expect_failure=True, retries=0,
-        extra_env={**_FAST_DEADLINE,
+        # The stall inspector's clocks are the subject, not the
+        # transport's 3 s of _FAST_DEADLINE, which a starved rank trips
+        # first on a busy machine.
+        extra_env={"HOROVOD_TRANSPORT": "tcp",
                    "HOROVOD_STALL_CHECK_TIME_SECONDS": "1",
                    "HOROVOD_STALL_SHUTDOWN_TIME_SECONDS": "3"})
     for r in (0, 1, 2):
@@ -340,7 +355,7 @@ def test_corrupt_abort_writes_flight_recorder_dump_on_every_rank(tmp_path):
         assert "background loop death" in doc["reason"], doc["reason"]
         assert doc["events"], "flight-recorder ring was empty"
         kinds = {e["kind"] for e in doc["events"]}
-        assert "frame" in kinds, kinds
+        assert "frame" in kinds, (r, doc["events"])
         assert doc["metrics"] and "counters" in doc["metrics"]
     # the detector's dump names the CRC failure; the injector's ring
     # recorded its own fired fault clause

@@ -683,6 +683,54 @@ def test_dead_worker_lease_expiry_advances_epoch_within_one_tick():
         server.stop()
 
 
+@pytest.mark.timeout(120)
+def test_job_ends_when_every_rank_finished_whatever_respawn_is_in_flight():
+    """The second half of ROADMAP D0 (ii): a live worker's lease runs out,
+    its identity is respawned, and then the world finishes, the silent
+    process with it (a remote one the launcher cannot kill, or one that
+    exits before the kill lands).  The joiner has no world to join; the
+    launcher waited on it without end.  The driver ends the job at its
+    next tick (``job_end_steps``), and the respawned identity's stale
+    lease is gone from the store, so the joiner is not judged by it."""
+    from horovod_tpu.elastic.discovery import FixedHosts, HostManager
+    from horovod_tpu.elastic.driver import ElasticDriver
+    from horovod_tpu.runner.hosts import parse_hosts
+    from horovod_tpu.runner.rendezvous import RendezvousServer
+    from horovod_tpu.transport.store import LEASE_SCOPE
+
+    server = RendezvousServer("127.0.0.1")
+    server.start()
+    spawned = {}
+    driver = ElasticDriver(
+        server,
+        HostManager(FixedHosts(parse_hosts("localhost:1,127.0.0.1:1"))),
+        min_np=2, lease_timeout=1.0)
+    try:
+        driver.start(lambda slot, epoch: spawned.setdefault(
+            f"{slot.hostname}:{slot.local_rank}", []).append((slot, epoch)))
+        for identity in ("localhost:0", "127.0.0.1:0"):
+            server.set(LEASE_SCOPE, identity, b"once")   # then silence
+        deadline = time.monotonic() + 30
+        while driver.epoch == 0 and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert driver.epoch == 1
+        assert [e for _s, e in spawned["127.0.0.1:0"]] == [0, 1], spawned
+        assert server.get(LEASE_SCOPE, "127.0.0.1:0") is None
+        assert not driver.job_ended
+        # The silent processes finish after all, beside their joiners.
+        for identity in spawned:
+            driver.record_worker_exit(spawned[identity][0][0], 0)
+        deadline = time.monotonic() + 10
+        while not driver.job_ended and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert driver.job_ended and driver.finished()
+        assert driver.epoch == 1, "a finished job advanced its epoch"
+    finally:
+        driver.stop()
+        driver._discovery_thread.join(timeout=10)
+        server.stop()
+
+
 _SURVIVABILITY_TRAIN = """
 import time
 import numpy as np

@@ -15,9 +15,10 @@ import numpy as np
 import optax
 import pytest
 
-from . import lfm2_reference as ref
-from .helpers import REPO_ROOT
+from .helpers import REPO_ROOT, load_reference
 from .test_lfm2 import LAYER_TYPES, TINY, some_bias
+
+ref = load_reference("lfm2-8b-a1b")
 
 
 
@@ -122,23 +123,33 @@ TINY_CELL = {
     "logits_float32_rtol": 1e-4}
 
 
+@pytest.fixture(scope="module")
+def seeded_cell():
+    """The tiny cell's configuration module and sizes, and the weights, the
+    bias and the batch that seeds 5 and 6 give: made once for the cases that
+    only read them (they do not depend on a limit)."""
+    module, _ = _config_module()
+    sizes = {k: v for k, v in TINY_CELL.items() if k != "module"}
+    config = module.Config(sizes)
+    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
+    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
+    return module, sizes, params, aux, batch
+
+
 @pytest.mark.parametrize("which,limit,passes", [
     ("logits_rtol", 0.2, True), ("logits_rtol", 1e-6, False),
     ("logits_median_rtol", 1e-6, False),
     ("logits_float32_rtol", 1e-9, False)])
 def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
-                                                       capfd):
+                                                       capfd, seeded_cell):
     """Behind ``_chip_bench_grad`` the program's logits are held to the
     float32 reference's, once, before the reference's first step: inside the
     three limits the reference's gradient comes back, outside any of them
     the run ends there.  The reference in a lower precision and with a layer
     wrong is what the limits are set against; in float32 and sound it is
     zero, and the program's model in float32 lies within rounding of it."""
-    module, _ = _config_module()
-    sizes = {k: v for k, v in TINY_CELL.items() if k != "module"}
+    module, sizes, params, aux, batch = seeded_cell
     config = module.Config({**sizes, which: limit})
-    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
-    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
     if not passes:
         with pytest.raises(SystemExit, match=f"over the limit {limit:.2e}"):
             config._chip_bench_grad(params, aux, batch)
@@ -168,19 +179,16 @@ def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
                                wrong=("bias_in_weights",)) == 0
 
 
-def test_the_step_keeps_the_bias_and_no_gradient_reaches_it():
+def test_the_step_keeps_the_bias_and_no_gradient_reaches_it(seeded_cell):
     """``hvd.make_overlapped_train_step(has_aux=True)`` on the program's
     model beside plain steps of the float32 reference: after three steps the
     bias is not zero, equals the reference's exactly (a sign rule over whole
     counts) and the losses agree."""
     import horovod_tpu as hvd
 
-    module, _ = _config_module()
-    sizes = {k: v for k, v in TINY_CELL.items() if k != "module"}
+    module, sizes, params, aux, batch = seeded_cell
     config = module.Config(sizes)
     tx = config.optimizer(1)
-    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
-    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
     grad = jax.jit(jax.value_and_grad(config.reference.make_loss(sizes),
                                       has_aux=True))
     want_params, want_aux, want_state = params, aux, tx.init(params)

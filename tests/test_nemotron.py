@@ -2,14 +2,12 @@
 under one norm, the Mamba-2 mixer over its chunked scan, experts without a
 gate on a latent width beside a shared expert, the shares of heads and of
 experts that add up to the uncut layers, and the whole model against the
-plain reference (``tests/nemotron_reference.py``: float32, the recurrence a
+plain reference (``chip_bench/configs/nemotron-3-super-120b-a12b_reference.py``: float32, the recurrence a
 token at a time, nothing of ``horovod_tpu``) on seeded weights at tiny widths.
 ``tests/test_nemotron_cell.py`` holds the configuration and its cell.
 """
 
 import dataclasses
-import functools
-import hashlib
 import os
 import subprocess
 import sys
@@ -20,9 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from . import nemotron_reference as ref
-from .helpers import REPO_ROOT
+from .helpers import REPO_ROOT, load_reference
 from .test_olmoe import rel_err
+
+ref = load_reference("nemotron-3-super-120b-a12b")
 
 # One of every letter twice over, 4 of 8 mixer heads in 2 of 4 groups, 2 of 4
 # query heads on 1 of 2 KV heads, 4 of 16 experts held, a sliced vocabulary.
@@ -247,93 +246,6 @@ def test_the_preset_is_the_published_model():
     assert count(cut) == 700_862_960
 
 
-# -- the chunked scan ---------------------------------------------------------
-
-
-def scan_inputs(seed, batch, s, heads, p, groups, n, dtype=jnp.float32):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    return (jax.random.normal(ks[0], (batch, s, heads, p)).astype(dtype),
-            jax.nn.softplus(jax.random.normal(ks[1], (batch, s, heads)) - 2),
-            -jnp.exp(jax.random.uniform(ks[2], (heads,), maxval=2.7)),
-            jax.random.normal(ks[3], (batch, s, groups, n)).astype(dtype),
-            jax.random.normal(ks[4], (batch, s, groups, n)).astype(dtype))
-
-
-def recurrence(x, dt, a, b, c):
-    return jax.vmap(lambda x, dt, b, c: ref._recurrence(x, dt, a, b, c))(
-        x, dt, b, c)
-
-
-@pytest.mark.parametrize("s,heads,groups,chunk", [
-    (200, 4, 2, 64), (64, 2, 1, 16), (37, 6, 3, 128), (128, 4, 4, 128)],
-    ids=["no_multiple_two_groups", "one_group", "shorter_than_a_chunk",
-         "a_head_a_group"])
-def test_the_chunked_scan_is_the_recurrence(s, heads, groups, chunk):
-    """``ssd_scan.chunked`` (the path off the TPU) against the reference's
-    token-by-token recurrence, forward and the gradients of all five."""
-    from horovod_tpu.kernels import ssd_scan
-
-    args = scan_inputs(0, 2, s, heads, 8, groups, 16)
-    weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
-
-    def loss(fn):
-        return lambda *a: jnp.sum(weight * jnp.tanh(fn(*a)))
-
-    with jax.default_matmul_precision("highest"):
-        want = recurrence(*args)
-        got = ssd_scan.ssd_scan(*args, chunk=chunk)
-        assert rel_err(got, want) < 1e-5
-        want_grads = jax.grad(loss(recurrence), argnums=range(5))(*args)
-        grads = jax.grad(loss(functools.partial(ssd_scan.ssd_scan,
-                                                chunk=chunk)),
-                         argnums=range(5))(*args)
-    for name, g, w in zip("x dt a b c".split(), grads, want_grads):
-        assert rel_err(g, w) < 2e-4, name
-
-
-@pytest.mark.parametrize("heads,p,groups", [(16, 64, 1), (32, 64, 2),
-                                            (8, 128, 1)],
-                         ids=["the_cells", "two_groups", "heads_of_128"])
-def test_the_kernels_are_the_chunked_form(heads, p, groups):
-    """The two pallas kernels in interpret mode against ``chunked`` on the
-    same bf16 inputs: ``y`` to bf16's rounding, the cotangents of ``x``,
-    ``B`` and ``C`` too, those of ``dt`` and ``a`` (fp32 sums) closer."""
-    from horovod_tpu.kernels import ssd_scan
-
-    args = scan_inputs(1, 2, 256, heads, p, groups, 128, jnp.bfloat16)
-    assert ssd_scan.takes(256, heads, p, groups, 128)
-    weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
-
-    def loss(fn):
-        return lambda *a: jnp.sum(weight * fn(*a).astype(jnp.float32))
-
-    got = ssd_scan.ssd_scan(*args, interpret=True)
-    want = ssd_scan.chunked(*args)
-    assert got.dtype == jnp.bfloat16 and rel_err(got, want) < 1e-2
-    grads = jax.grad(loss(functools.partial(ssd_scan.ssd_scan,
-                                            interpret=True)),
-                     argnums=range(5))(*args)
-    want_grads = jax.grad(loss(ssd_scan.chunked), argnums=range(5))(*args)
-    for name, g, w, tol in zip("x dt a b c".split(), grads, want_grads,
-                               (1e-2, 2e-3, 2e-3, 1e-2, 1e-2)):
-        assert g.dtype == w.dtype and rel_err(g, w) < tol, name
-
-
-@pytest.mark.parametrize("shape,taken", [
-    ((8192, 16, 64, 1, 128), True), ((8192, 128, 64, 8, 128), True),
-    ((8192, 16, 64, 1, 64), False), ((8100, 16, 64, 1, 128), False),
-    ((8192, 4, 64, 1, 128), False), ((8192, 16, 32, 1, 128), False),
-    ((8192, 16, 64, 3, 128), False)],
-    ids=["the_cells", "the_whole_mixer", "state_64", "no_whole_chunks",
-         "four_heads", "heads_of_32", "heads_in_no_groups"])
-def test_takes_refuses_what_the_kernels_cannot_run(shape, taken):
-    from horovod_tpu.kernels import ssd_scan
-
-    assert ssd_scan.takes(*shape) is taken
-    assert not ssd_scan.takes(*shape, dtype=jnp.float32)
-    assert not ssd_scan.takes(*shape, chunk=64)
-
-
 # -- the shares add up --------------------------------------------------------
 
 
@@ -439,15 +351,15 @@ def test_the_shares_of_an_expert_layer_add_up_with_the_shared_expert_once():
 
 @pytest.mark.parametrize("factors", [True, False])
 def test_three_pass_logits_over_512_outputs_choose_float64s_top_22(factors):
-    """``tests/test_lfm2.py``'s check of the split product at this model's
+    """``tests/test_router_product.py``'s check of the split product at this model's
     router: sigmoid scores over 512 experts, the top 22."""
-    from .test_lfm2 import check_three_pass_logits
+    from .test_router_product import check_three_pass_logits
 
     check_three_pass_logits("sigmoid", factors)
 
 
 def test_three_pass_gradients_over_512_outputs_are_the_old_lines():
-    from .test_lfm2 import check_gradients_are_the_old_lines
+    from .test_router_product import check_gradients_are_the_old_lines
 
     check_gradients_are_the_old_lines("sigmoid")
 
@@ -460,7 +372,7 @@ def test_a_layers_router_product_follows_the_streams_dtype(dtype, passes):
     multiplied it.  The gauge says which, a layer."""
     from horovod_tpu.core import metrics
 
-    from .test_lfm2 import _dot_generals
+    from .test_router_product import _dot_generals
 
     model, sizes = tiny_model(getattr(jnp, dtype))
     tokens = tokens_of(sizes, 0)["tokens"]
@@ -562,61 +474,6 @@ def test_a_quarter_that_is_no_multiple_of_128_is_rounded_up(sizes, quantum,
 
 
 # -- what stays as it was ------------------------------------------------------
-
-# sha1 over the sorted (path, shape) pairs of the parameter tree that each
-# transformer configuration of the benchmark builds at a tiny size, taken on
-# the parent of PR 41 (3cce4b3): a layer of every kind they use.
-_TREES = {
-    "bert-large": "937ead76c45f971d816bd63ce22f6878266feefa",
-    "olmoe-1b-7b": "73df0b693c052979780575ddb5b5f3a9f59d46bb",
-    "sdar-30b-a3b": "fa6dff3fc67ccecb9d81983641bd0477aa24047a",
-    "smallthinker-21b-a3b": "5996a7811d123657dca6869ca4c999ef890137de",
-    "lfm2-8b-a1b": "811fb3c5a0e5cb8c1a78b62aaa51b32ca1585094",
-}
-
-
-def small_presets():
-    from horovod_tpu.models import transformer as t
-
-    share = dict(vocab_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
-                 d_model=64, d_ff=32, max_len=64, num_experts=8)
-    return {
-        "bert-large": t.bert_large_config(
-            vocab_size=128, num_layers=2, num_heads=4, d_model=64, d_ff=128,
-            max_len=64),
-        "olmoe-1b-7b": t.olmoe_1b_7b_config(
-            vocab_size=128, num_layers=2, num_heads=4, d_model=64, d_ff=32,
-            max_len=32, num_experts=8, experts_per_token=2),
-        "sdar-30b-a3b": t.sdar_30b_a3b_config(
-            **share, head_width=16, experts_per_token=2,
-            experts_held=(1, 3, 4, 6), block_diffusion=4),
-        "smallthinker-21b-a3b": t.smallthinker_21b_a3b_config(
-            **share, head_width=8, experts_per_token=3, experts_held=(1, 6),
-            layer_pattern=(t.LayerKind(0, False), t.LayerKind(8, True))),
-        "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
-            **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
-            experts_per_token=2, experts_held=(1, 6),
-            layer_pattern=(t.LayerKind(0, True, "conv", "dense"),
-                           t.LayerKind(0, True, "attention"),
-                           t.LayerKind(0, True, "conv"))),
-    }
-
-
-def tree_digest(cfg):
-    from horovod_tpu.models.transformer import Transformer
-
-    shapes = jax.eval_shape(
-        lambda: Transformer(cfg).init(jax.random.PRNGKey(0),
-                                      jnp.zeros((1, 8), jnp.int32)))
-    pairs = sorted((jax.tree_util.keystr(path), tuple(x.shape))
-                   for path, x in jax.tree_util.tree_leaves_with_path(shapes))
-    return hashlib.sha1(repr(pairs).encode()).hexdigest()
-
-
-@pytest.mark.parametrize("name", sorted(_TREES))
-def test_every_kind_of_layer_builds_the_parents_parameter_tree(name):
-    assert tree_digest(small_presets()[name]) == _TREES[name]
-
 
 def test_the_scan_and_the_mixer_load_where_a_configuration_asks():
     """Neither ``import horovod_tpu`` nor ``hvd.init()`` nor the models'

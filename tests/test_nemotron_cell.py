@@ -17,9 +17,10 @@ import numpy as np
 import optax
 import pytest
 
-from . import nemotron_reference as ref
-from .helpers import REPO_ROOT
+from .helpers import REPO_ROOT, load_reference
 from .test_nemotron import TINY, some_bias
+
+ref = load_reference("nemotron-3-super-120b-a12b")
 
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
@@ -147,10 +148,8 @@ def test_batch_weights_and_bias_come_from_the_seed():
                 if x.shape == (5, 512) or x.shape == (512,)]
 
 
-def test_fresh_weights_follow_the_model_codes_rules():
-    module, _ = _config_module()
-    config = module.Config(TINY_SIZES)
-    params, _ = jax.jit(config.init)(jax.random.PRNGKey(5))
+def test_fresh_weights_follow_the_model_codes_rules(seeded_cell):
+    _, params, _, _ = seeded_cell
     mixer = params["layer_0"]["mamba"]
     dt = np.asarray(jax.nn.softplus(mixer["dt_bias"]))
     assert (dt > 0.99e-3).all() and (dt < 0.101).all()
@@ -182,21 +181,31 @@ TINY_SIZES = {
 TINY_CELL = {"module": "nemotron-3-super-120b-a12b", **TINY_SIZES}
 
 
+@pytest.fixture(scope="module")
+def seeded_cell():
+    """The tiny cell's configuration module, and the weights, the bias and
+    the batch that seeds 5 and 6 give: made once for the cases that only
+    read them (they do not depend on a limit)."""
+    module, _ = _config_module()
+    config = module.Config(TINY_SIZES)
+    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
+    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
+    return module, params, aux, batch
+
+
 @pytest.mark.parametrize("which,limit,passes", [
     ("logits_rtol", 0.2, True), ("logits_rtol", 1e-6, False),
     ("logits_median_rtol", 1e-6, False),
     ("logits_float32_rtol", 1e-9, False),
     ("logits_float32_norm_rtol", 1e-9, False)])
 def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
-                                                       capfd):
+                                                       capfd, seeded_cell):
     """Behind ``_chip_bench_grad`` the program's logits are held to the
     float32 reference's, once, before the reference's first step: inside the
     four limits the reference's gradient comes back, outside any of them
     the run ends there."""
-    module, _ = _config_module()
+    module, params, aux, batch = seeded_cell
     config = module.Config({**TINY_SIZES, which: limit})
-    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
-    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
     if not passes:
         with pytest.raises(SystemExit, match=f"over the limit {limit:.2e}"):
             config._chip_bench_grad(params, aux, batch)
@@ -223,7 +232,7 @@ def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
                                wrong=("no_shared_expert",), bias=bias) > 1e-3
 
 
-def test_the_step_keeps_the_bias_and_no_gradient_reaches_it():
+def test_the_step_keeps_the_bias_and_no_gradient_reaches_it(seeded_cell):
     """``hvd.make_overlapped_train_step(has_aux=True)`` on the program's
     model beside plain steps of the float32 reference: after three steps the
     bias is not zero, follows the rule over each step's own counts and is
@@ -231,11 +240,9 @@ def test_the_step_keeps_the_bias_and_no_gradient_reaches_it():
     losses agree."""
     import horovod_tpu as hvd
 
-    module, _ = _config_module()
+    module, params, aux, batch = seeded_cell
     config = module.Config(TINY_SIZES)
     tx = config.optimizer(1)
-    params, aux = jax.jit(config.init)(jax.random.PRNGKey(5))
-    batch = jax.jit(config.make_batch)(jax.random.PRNGKey(6))
     grad = jax.jit(jax.value_and_grad(
         config.reference.make_loss(TINY_SIZES), has_aux=True))
     want_params, want_aux, want_state = params, aux, tx.init(params)

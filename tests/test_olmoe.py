@@ -1,10 +1,9 @@
 """OLMoE: the dropless top-k expert layer, the block's options in
 ``models/transformer.py``, and both against the plain reference
-(``tests/olmoe_reference.py``: float32, one dense expert at a time under a
+(``chip_bench/configs/olmoe-1b-7b_reference.py``: float32, one dense expert at a time under a
 mask, nothing of ``horovod_tpu``) on seeded weights at tiny widths.
 """
 
-import hashlib
 import json
 import os
 
@@ -15,8 +14,15 @@ import numpy as np
 import optax
 import pytest
 
-from . import olmoe_reference as ref
-from .helpers import REPO_ROOT, reserve_port, run_distributed
+from .helpers import (
+    REPO_ROOT,
+    load_reference,
+    reference_path,
+    reserve_port,
+    run_distributed,
+)
+
+ref = load_reference("olmoe-1b-7b")
 
 TINY = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
             intermediate_size=32, num_experts=8, num_experts_per_tok=2,
@@ -147,31 +153,19 @@ def test_program_agrees_with_the_plain_reference(case, dtype, seed):
     assert worst[1] < tol["grads"], (jax.tree_util.keystr(worst[0]), worst[1])
 
 
-@pytest.mark.parametrize("tests_copy,benchmarks_copy", [
-    ("tests/olmoe_reference.py",
-     "chip_bench/configs/olmoe-1b-7b_reference.py"),
-    ("tests/sdar_reference.py",
-     "chip_bench/configs/sdar-30b-a3b_reference.py"),
-    ("tests/smallthinker_reference.py",
-     "chip_bench/configs/smallthinker-21b-a3b_reference.py"),
-    ("tests/lfm2_reference.py",
-     "chip_bench/configs/lfm2-8b-a1b_reference.py"),
-    ("tests/nemotron_reference.py",
-     "chip_bench/configs/nemotron-3-super-120b-a12b_reference.py")])
-def test_reference_copies_share_their_text(tests_copy, benchmarks_copy):
-    """The benchmark keeps its own copy of each reference, so that the files
-    under ``chip_bench/`` are enough by themselves."""
-    marker = "# ---- below this line the two copies are the same text ----\n"
-
-    def body(path):
-        with open(os.path.join(REPO_ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        return text.split(marker)[1]
-
-    assert body(tests_copy) == body(benchmarks_copy)
-    assert "horovod_tpu" not in body(tests_copy)
-    assert 'default_matmul_precision("highest")' in body(tests_copy)
+@pytest.mark.parametrize("config", [
+    "olmoe-1b-7b", "sdar-30b-a3b", "smallthinker-21b-a3b", "lfm2-8b-a1b",
+    "nemotron-3-super-120b-a12b"])
+def test_reference_stands_alone_at_the_highest_precision(config):
+    """One reference a configuration, under ``chip_bench/configs/``, where
+    the benchmark decides ``correct`` and these suites load it
+    (``helpers.load_reference``).  It is independent of the code under
+    test, and it computes under the highest matmul precision."""
+    with open(reference_path(config)) as f:
+        text = f.read()
+    assert "import horovod_tpu" not in text
+    assert "from horovod_tpu" not in text
+    assert 'default_matmul_precision("highest")' in text
 
 
 # -- the layer alone ----------------------------------------------------------
@@ -335,36 +329,6 @@ def test_causal_mask_comes_from_iota_and_matches_tril():
     assert "iota" in text and 'dense<"0x' not in text
 
 
-# The parent's (PR 26) BERT-large, recorded with the JAX that made it: the
-# parameter tree (paths, shapes, dtypes) and the StableHLO text of the
-# forward pass on 2 x 512 tokens.  The block's new options must leave both as
-# they were.
-BERT_LARGE_PARENT = {
-    "jax": "0.9.0",
-    "tree": "7a83d0db599ba93df161711175c48928b272ebe34eed8a54b5c37a65b9f3c743",
-    "forward": "5cc4b71051904c78462d6251b64486ec73f6d157015fca7e29aafccdd5f5ca32",
-}
-
-
-def test_bert_large_lowers_to_what_the_parent_lowered_to():
-    from horovod_tpu.models.transformer import Transformer, bert_large_config
-
-    if jax.__version__ != BERT_LARGE_PARENT["jax"]:
-        pytest.skip(f"recorded with JAX {BERT_LARGE_PARENT['jax']}")
-    model = Transformer(bert_large_config(attention="full"))
-    tokens = jax.ShapeDtypeStruct((2, 512), jnp.int32)
-    params = nn.meta.unbox(jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), tokens)["params"])
-    tree = sorted((jax.tree_util.keystr(k), tuple(v.shape), v.dtype.name)
-                  for k, v in jax.tree_util.tree_leaves_with_path(params))
-    assert len(tree) == 292
-    text = jax.jit(lambda p, t: model.apply({"params": p}, t)).lower(
-        params, tokens).as_text()
-    digest = lambda s: hashlib.sha256(s.encode()).hexdigest()  # noqa: E731
-    assert digest(repr(tree)) == BERT_LARGE_PARENT["tree"]
-    assert digest(text) == BERT_LARGE_PARENT["forward"]
-
-
 # -- through both entry points ------------------------------------------------
 
 _TWO_RANKS = """
@@ -373,7 +337,8 @@ sys.path.insert(0, {tests!r})
 import flax.linen as nn
 import jax, jax.numpy as jnp, optax
 from tests.test_olmoe import tiny_model, program_loss, zero_counters
-from tests import olmoe_reference as ref
+from tests.helpers import load_reference
+ref = load_reference("olmoe-1b-7b")
 from horovod_tpu.parallel.moe import count_routing
 
 model, sizes = tiny_model(moe_data_axis=hvd.PROCESS_AXIS)

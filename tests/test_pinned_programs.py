@@ -1,0 +1,290 @@
+"""One rule, one table: default arguments build the parent's parameter tree
+and lower to the parent's text.
+
+A rewrite of shared code (``models/transformer.py``, ``parallel/moe.py``, the
+kernels' wrappers) leaves the program of every model it did not mean to
+change as it was.  This module holds that to a digest a program: ``PINS``
+names each pinned program, ``digest`` hashes, and each case builds its
+program from the tiny models of the suites (``test_olmoe.tiny_model`` and
+its siblings) at abstract shapes, so nothing here compiles or runs.  A PR
+that means to change a program edits one row and says in the row's comment
+whose text the new digest is; a new model adds its rows.
+"""
+
+import hashlib
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import pytest
+
+from . import test_lfm2, test_nemotron, test_olmoe, test_sdar, \
+    test_smallthinker
+
+RECORDED_WITH = "0.9.0"     # the text of a lowering is the JAX version's own
+
+pytestmark = pytest.mark.skipif(
+    jax.__version__ != RECORDED_WITH,
+    reason=f"recorded with JAX {RECORDED_WITH}")
+
+PINS = {
+    # BERT-large on PR 26's tree: the parameter tree (paths, shapes, dtypes)
+    # and the StableHLO text of the forward pass on 2 x 512 tokens.
+    "bert_large/tree":
+    "7a83d0db599ba93df161711175c48928b272ebe34eed8a54b5c37a65b9f3c743",
+    "bert_large/forward":
+    "5cc4b71051904c78462d6251b64486ec73f6d157015fca7e29aafccdd5f5ca32",
+    # bf16 programs.  Taken on PR 30's tree (84b7007): test_olmoe.py's tiny
+    # OLMoE model, loss and gradients, on (3, 32) tokens; moe_ffn with every
+    # expert held at [2, 16, 64] x 8 experts of width 32, k = 2, gradients
+    # of all five operands.  On PR 34's parent (fbf0cef): test_sdar.py's
+    # tiny SDAR model, loss and gradients, on 2 x 16 noised tokens (the
+    # einsum under the block mask); the jaxpr of the block-diffusion
+    # kernel's call as a TPU gets it, forward and the three gradients, 4
+    # query heads on 2 KV heads of 128 at two tiles (the kernels' names,
+    # tiles, grids, layout and scale are in that text).  PR 44 gave the
+    # wrapper a backward kernel of its own: ``blockdiff_kernel_call`` is
+    # that PR's text, and ``blockdiff_forward_call`` the forward kernel's
+    # equation in it without the line of profiler metadata that lists the
+    # library's block sizes: on PR 44's parent (1e203e9) that equation
+    # hashes to the same.  ``sdar_tiny_step`` is PR 44's too
+    # (``BlockDiffusion.allowed`` by one code a position).  PR 45 moved the
+    # four that hold a router over bf16 rows (three bf16 products over the
+    # split weights, ``parallel/moe.py::_rows_dot``): they are that PR's
+    # text.  ``moe_ffn_share_1_6`` is the share (1, 6) of the same layer:
+    # 64 slots are one chunk since PR 39.
+    "olmoe_tiny_step":
+    "f40aeee7bbc7a2319322d0e265ef7f6c753bcce30c9b6819e8a48c14b20b520c",
+    "moe_ffn_all_held":
+    "0243b7b05474ef8842aca6e04f4b42b53e8246f6ab654a16c4afb7e4886007ae",
+    "moe_ffn_share_1_6":
+    "dcdd670a4e5e94ee0c4e896832a6c0abca916f22e4f6c1b6b6db3092851e2253",
+    "sdar_tiny_step":
+    "b680afb5a8d812b1e0f01cd824bab2910ede866f619d74caf5e41067d5dac2d4",
+    "blockdiff_kernel_call":
+    "2579f64f8c25cb3b01701d988c9aedd3970d34b4c8d1f1bcff17b110c7ee59b8",
+    "blockdiff_forward_call":
+    "2cf36cf3da9d62dacb1dfe9ecede7018afcee1b43cdb825ddaa1292216f0b4a5",
+    # The lowered loss and gradients in float32 on PR 45's parent (9016782),
+    # before ``_route`` learnt the three-pass product: rows that are no
+    # bfloat16 array run the line it had, so a float32 model (every
+    # configuration's float32 twin, the references' programs) lowers to the
+    # parent's text.
+    "float32/moe_ffn_softmax":
+    "3641c0a5bb1c210353d534b3f102845510abca2419fd00cefe0b8471ae8384fe",
+    "float32/moe_ffn_sigmoid_bias":
+    "0477d753849994f0d5752d73fac09ec75a7049c1dc46392cfa903345bdb36c2b",
+    "float32/moe_ffn_router_input":
+    "e833980ee850d3a4c06dfc83fb5868c984cdba9bfe9722926a9213ed43eed144",
+    "float32/olmoe_tiny_step":
+    "f90360f3dee26f7df01f83d8c6d88adee7a6617429d83e3445f2e1e945fa0794",
+    "float32/smallthinker_tiny_step":
+    "b7bba8f89fd5609883591adae556204aeb218f99caa13690031a4305e29463d2",
+    "float32/lfm2_tiny_step":
+    "65255ffd7d08e9b5e389e9a0a6985c59328d50a7edff86573f843e0d6b2e734c",
+    "float32/nemotron_tiny_step":
+    "aea9a3902d531dabfba256441ce4022e3ac776ade995fd3baf55518f2de842f3",
+    # sha1 over the sorted (path, shape) pairs of the parameter tree that
+    # each transformer configuration of the benchmark builds at a tiny size,
+    # taken on the parent of PR 41 (3cce4b3): a layer of every kind they use.
+    "tree/bert-large": "937ead76c45f971d816bd63ce22f6878266feefa",
+    "tree/olmoe-1b-7b": "73df0b693c052979780575ddb5b5f3a9f59d46bb",
+    "tree/sdar-30b-a3b": "fa6dff3fc67ccecb9d81983641bd0477aa24047a",
+    "tree/smallthinker-21b-a3b": "5996a7811d123657dca6869ca4c999ef890137de",
+    "tree/lfm2-8b-a1b": "811fb3c5a0e5cb8c1a78b62aaa51b32ca1585094",
+}
+
+
+def digest(text, algorithm="sha256"):
+    return hashlib.new(algorithm, text.encode()).hexdigest()
+
+
+def names(prefix):
+    return sorted(name[len(prefix):] for name in PINS
+                  if name.startswith(prefix))
+
+
+shape = jax.ShapeDtypeStruct
+
+
+def abstract(model, like):
+    """The model's parameters as shapes: ``like`` is the tokens it takes."""
+    return nn.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), like)["params"])
+
+
+def step_text(loss, *args):
+    """The lowered text of a loss and its gradients, auxiliary output kept."""
+    return jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        *args).as_text()
+
+
+def moe_ffn_text(rows, held=None, gradients=5, **options):
+    """The lowered gradients of ``moe_ffn``'s sum, and of its balancing loss,
+    at [2, 16, 64] rows of dtype ``rows`` x 8 experts of width 32, k = 2, by
+    the operands' first ``gradients``.  ``options`` go to the layer; a value
+    that names an operand (``"bias"``, ``"routed_by"``, ``"x"``) is that
+    operand."""
+    from horovod_tpu.parallel.moe import moe_ffn
+
+    d, f, e, k = 64, 32, 8, 2
+    n = e if held is None else len(held)
+    args = [shape((2, 16, d), rows), shape((d, e), jnp.float32),
+            shape((n, d, f), jnp.float32), shape((n, d, f), jnp.float32),
+            shape((n, f, d), jnp.float32), shape((e,), jnp.float32),
+            shape((2, 16, d), jnp.float32)][:gradients]
+
+    def loss(*a):
+        operands = dict(zip(("x", "router", "gate", "up", "down", "bias",
+                             "routed_by"), a))
+        given = {key: operands.get(value, value) if isinstance(value, str)
+                 else value for key, value in options.items()}
+        if held is not None:
+            given.update(held=held, norm_topk_prob=True)
+        y, stats = moe_ffn(*a[:5], k=k, **given)
+        total = jnp.sum(y.astype(jnp.float32)) \
+            + jnp.sum(stats.load_balancing_loss)
+        if gradients == 7:
+            total = total + jnp.sum(stats.router_z_loss)
+        return total
+
+    return jax.jit(jax.grad(loss, argnums=tuple(range(gradients)))).lower(
+        *args).as_text()
+
+
+def test_bert_large_lowers_to_what_the_parent_lowered_to():
+    from horovod_tpu.models.transformer import Transformer, bert_large_config
+
+    model = Transformer(bert_large_config(attention="full"))
+    tokens = shape((2, 512), jnp.int32)
+    params = abstract(model, tokens)
+    tree = sorted((jax.tree_util.keystr(k), tuple(v.shape), v.dtype.name)
+                  for k, v in jax.tree_util.tree_leaves_with_path(params))
+    assert len(tree) == 292
+    text = jax.jit(lambda p, t: model.apply({"params": p}, t)).lower(
+        params, tokens).as_text()
+    assert digest(repr(tree)) == PINS["bert_large/tree"]
+    assert digest(text) == PINS["bert_large/forward"]
+
+
+@pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held",
+                                   "sdar_tiny_step", "blockdiff_kernel_call"])
+def test_lowers_to_what_the_parent_lowered_to(which):
+    if which == "olmoe_tiny_step":
+        model, sizes = test_olmoe.tiny_model(jnp.bfloat16)
+        tokens = shape((3, 32), jnp.int32)
+        text = step_text(test_olmoe.program_loss(model, sizes),
+                         abstract(model, tokens), tokens)
+    elif which == "sdar_tiny_step":
+        model, sizes = test_sdar.tiny_model(jnp.bfloat16)
+        batch = jax.eval_shape(lambda: test_sdar.noised(sizes, 0))
+        text = step_text(test_sdar.program_loss(model, sizes),
+                         abstract(model, shape((1, 32), jnp.int32)), batch)
+    elif which == "blockdiff_kernel_call":
+        from horovod_tpu.kernels import blockdiff_attention as bd
+        from horovod_tpu.kernels import masked_attention
+
+        q = shape((1, 2 * bd.BLOCK, 4, 128), jnp.bfloat16)
+        kv = shape((1, 2 * bd.BLOCK, 2, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return jnp.sum(masked_attention.attention(
+                q, k, v, bd.BlockDiffusion(4)).astype(jnp.float32))
+
+        jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, kv, kv).jaxpr
+        text = str(jaxpr)
+        forward, = (str(eqn)
+                    for eqn in test_sdar.equations_of(jaxpr, "pallas_call")
+                    if eqn.params["name"].startswith("splash_mha_fwd"))
+        forward = "\n".join(line for line in forward.splitlines()
+                            if "xprof_metadata" not in line)
+        assert digest(forward) == PINS["blockdiff_forward_call"]
+    else:
+        text = moe_ffn_text(jnp.bfloat16)
+    assert digest(text) == PINS[which]
+
+
+def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
+    """SmallThinker's two options at their defaults, spelled out, lower to
+    what the parent lowered to, whole layer and share alike."""
+    spelled = dict(router_input="x", activation="silu")
+    assert digest(moe_ffn_text(jnp.bfloat16, **spelled)) \
+        == PINS["moe_ffn_all_held"]
+    assert digest(moe_ffn_text(jnp.bfloat16, held=(1, 6))) \
+        == digest(moe_ffn_text(jnp.bfloat16, held=(1, 6), **spelled)) \
+        == PINS["moe_ffn_share_1_6"]
+
+
+def float32_text(which):
+    """The lowered loss and gradients of ``which`` in float32."""
+    tokens = shape((2, 32), jnp.int32)
+    if which.startswith("moe_ffn"):
+        return moe_ffn_text(jnp.float32, gradients=7, dtype=jnp.float32, **{
+            "moe_ffn_softmax": {},
+            "moe_ffn_sigmoid_bias": dict(
+                scoring="sigmoid", bias="bias", norm_topk_prob=True,
+                scale=2.5),
+            "moe_ffn_router_input": dict(router_input="routed_by")}[which])
+    if which == "olmoe_tiny_step":
+        model, sizes = test_olmoe.tiny_model(jnp.float32)
+        return step_text(test_olmoe.program_loss(model, sizes),
+                         abstract(model, tokens), tokens)
+    if which == "smallthinker_tiny_step":
+        model, sizes = test_smallthinker.tiny_model(jnp.float32)
+        return step_text(test_smallthinker.program_loss(model, sizes),
+                         abstract(model, tokens), {"tokens": tokens})
+    if which == "lfm2_tiny_step":
+        model, sizes = test_lfm2.tiny_model(jnp.float32)
+        aux = jax.eval_shape(lambda: test_lfm2.counters(sizes))
+        return step_text(test_lfm2.program_loss(model, sizes),
+                         abstract(model, tokens), aux, {"tokens": tokens})
+    model, sizes = test_nemotron.tiny_model(jnp.float32)
+    few = shape((2, sizes["sequence_length"]), jnp.int32)
+    aux = jax.eval_shape(lambda: test_nemotron.zero_aux(sizes))
+    return step_text(test_nemotron.program_loss(model, sizes),
+                     abstract(model, few), aux, {"tokens": few})
+
+
+@pytest.mark.parametrize("which", names("float32/"))
+def test_float32_rows_lower_to_the_parents_text(which):
+    assert digest(float32_text(which)) == PINS["float32/" + which]
+
+
+def small_presets():
+    from horovod_tpu.models import transformer as t
+
+    share = dict(vocab_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
+                 d_model=64, d_ff=32, max_len=64, num_experts=8)
+    return {
+        "bert-large": t.bert_large_config(
+            vocab_size=128, num_layers=2, num_heads=4, d_model=64, d_ff=128,
+            max_len=64),
+        "olmoe-1b-7b": t.olmoe_1b_7b_config(
+            vocab_size=128, num_layers=2, num_heads=4, d_model=64, d_ff=32,
+            max_len=32, num_experts=8, experts_per_token=2),
+        "sdar-30b-a3b": t.sdar_30b_a3b_config(
+            **share, head_width=16, experts_per_token=2,
+            experts_held=(1, 3, 4, 6), block_diffusion=4),
+        "smallthinker-21b-a3b": t.smallthinker_21b_a3b_config(
+            **share, head_width=8, experts_per_token=3, experts_held=(1, 6),
+            layer_pattern=(t.LayerKind(0, False), t.LayerKind(8, True))),
+        "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
+            **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
+            experts_per_token=2, experts_held=(1, 6),
+            layer_pattern=(t.LayerKind(0, True, "conv", "dense"),
+                           t.LayerKind(0, True, "attention"),
+                           t.LayerKind(0, True, "conv"))),
+    }
+
+
+@pytest.mark.parametrize("name", names("tree/"))
+def test_every_kind_of_layer_builds_the_parents_parameter_tree(name):
+    from horovod_tpu.models.transformer import Transformer
+
+    shapes = jax.eval_shape(
+        lambda: Transformer(small_presets()[name]).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    pairs = sorted((jax.tree_util.keystr(path), tuple(x.shape))
+                   for path, x in jax.tree_util.tree_leaves_with_path(shapes))
+    assert digest(repr(pairs), "sha1") == PINS["tree/" + name]

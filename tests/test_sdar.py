@@ -1,13 +1,12 @@
 """SDAR-30B-A3B: block-diffusion attention (the mask rule, the einsum under it
 and the pallas kernel in interpret mode), grouped KV heads, per-head QK-norm,
 the share of a layer's experts, and the whole model against the plain
-reference (``tests/sdar_reference.py``: float32, a dense masked softmax, one
+reference (``chip_bench/configs/sdar-30b-a3b_reference.py``: float32, a dense masked softmax, one
 dense expert at a time under a mask, nothing of ``horovod_tpu``) on seeded
 weights at tiny widths.
 """
 
 import functools
-import hashlib
 import json
 import os
 
@@ -18,9 +17,10 @@ import numpy as np
 import optax
 import pytest
 
-from . import sdar_reference as ref
-from .helpers import REPO_ROOT
+from .helpers import REPO_ROOT, load_reference
 from .test_olmoe import dense_top_k, layer_inputs, rel_err
+
+ref = load_reference("sdar-30b-a3b")
 
 TINY = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=8,
             num_key_value_heads=2, head_dim=16, moe_intermediate_size=32,
@@ -242,8 +242,8 @@ def test_kernel_in_interpret_mode_matches_the_einsum_with_grouped_heads():
             lambda *qkv: jnp.sum(attention(*qkv) * w), argnums=(0, 1, 2))
 
     with jax.default_matmul_precision("highest"):
-        got, got_grads = through(lambda *qkv: bd.blockdiff_attention(
-            *qkv, block=4, interpret=True))(q, k, v)
+        got, got_grads = through(lambda *qkv: masked_attention.attention(
+            *qkv, bd.BlockDiffusion(4), interpret=True))(q, k, v)
         want, want_grads = through(lambda *qkv: masked_attention.einsum(
             *qkv, bd.BlockDiffusion(4)))(q, k, v)
     assert abs(float(got) - float(want)) < 1e-4 * abs(float(want))
@@ -684,45 +684,6 @@ def test_share_counters_become_gauges():
     assert "moe_overflow_chunks_per_step" in metrics.CATALOG
 
 
-# -- nothing moved for the models the benchmark already had --------------------
-
-# sha256 of the lowered text on the parent commit (84b7007), JAX 0.9.0:
-# tests/test_olmoe.py's tiny OLMoE model in bf16, loss and gradients, on
-# (3, 32) tokens; moe_ffn with every expert held at [2, 16, 64] x 8 experts of
-# width 32, k = 2, gradients of all five operands.  And on PR 34's parent
-# (fbf0cef), before kernels/blockdiff_attention.py was folded into
-# kernels/masked_attention.py: this file's tiny SDAR model in bf16, loss and
-# gradients, on 2 x 16 noised tokens (the einsum under the block mask); the
-# jaxpr of the block-diffusion kernel's call as a TPU gets it, forward and
-# the three gradients, 4 query heads on 2 KV heads of 128 at two tiles (the
-# kernels' names, tiles, grids, layout and scale are in that text).  PR 44
-# gave the wrapper a backward kernel of its own: ``blockdiff_kernel_call`` is
-# that PR's text, and ``blockdiff_forward_call`` the forward kernel's
-# equation in it without the line of profiler metadata that lists the
-# library's block sizes (the backward's are no longer among them): on PR
-# 44's parent (1e203e9) that equation hashes to the same.  ``sdar_tiny_step``
-# is PR 44's too: ``BlockDiffusion.allowed`` became the rule by one code a
-# position, and the einsum's mask with it (the same booleans:
-# tests/test_masked_attention_bwd.py).  PR 45 moved the three that hold a
-# router over bf16 rows, ``olmoe_tiny_step``, ``moe_ffn_all_held`` and
-# ``sdar_tiny_step``: their logits are three bf16 products over the split
-# weights (``parallel/moe.py::_rows_dot``) and the tiny models hand the
-# router their stream and the norm's two factors; the three are that PR's
-# text.  The same programs in float32 lower to PR 45's parent's
-# (``tests/test_lfm2.py::FLOAT32_PARENT``).
-PARENT = {"jax": "0.9.0",
-          "olmoe_tiny_step":
-          "f40aeee7bbc7a2319322d0e265ef7f6c753bcce30c9b6819e8a48c14b20b520c",
-          "moe_ffn_all_held":
-          "0243b7b05474ef8842aca6e04f4b42b53e8246f6ab654a16c4afb7e4886007ae",
-          "sdar_tiny_step":
-          "b680afb5a8d812b1e0f01cd824bab2910ede866f619d74caf5e41067d5dac2d4",
-          "blockdiff_kernel_call":
-          "2579f64f8c25cb3b01701d988c9aedd3970d34b4c8d1f1bcff17b110c7ee59b8",
-          "blockdiff_forward_call":
-          "2cf36cf3da9d62dacb1dfe9ecede7018afcee1b43cdb825ddaa1292216f0b4a5"}
-
-
 def equations_of(jaxpr, primitive):
     """Every equation of ``primitive`` in ``jaxpr`` and in the jaxprs its
     equations hold."""
@@ -734,68 +695,6 @@ def equations_of(jaxpr, primitive):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
                     yield from equations_of(inner, primitive)
-
-
-@pytest.mark.parametrize("which", ["olmoe_tiny_step", "moe_ffn_all_held",
-                                   "sdar_tiny_step", "blockdiff_kernel_call"])
-def test_lowers_to_what_the_parent_lowered_to(which):
-    from horovod_tpu.parallel.moe import moe_ffn
-
-    from . import test_olmoe
-
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"recorded with JAX {PARENT['jax']}")
-    if which == "olmoe_tiny_step":
-        model, sizes = test_olmoe.tiny_model(jnp.bfloat16)
-        tokens = jax.ShapeDtypeStruct((3, 32), jnp.int32)
-        params = nn.meta.unbox(jax.eval_shape(
-            model.init, jax.random.PRNGKey(0), tokens)["params"])
-        text = jax.jit(jax.value_and_grad(
-            test_olmoe.program_loss(model, sizes), has_aux=True)).lower(
-                params, tokens).as_text()
-    elif which == "sdar_tiny_step":
-        model, sizes = tiny_model(jnp.bfloat16)
-        batch = jax.eval_shape(lambda: noised(sizes, 0))
-        params = nn.meta.unbox(jax.eval_shape(
-            model.init, jax.random.PRNGKey(0),
-            jax.ShapeDtypeStruct((1, 32), jnp.int32))["params"])
-        text = jax.jit(jax.value_and_grad(
-            program_loss(model, sizes), has_aux=True)).lower(
-                params, batch).as_text()
-    elif which == "blockdiff_kernel_call":
-        from horovod_tpu.kernels import blockdiff_attention as bd
-
-        q = jax.ShapeDtypeStruct((1, 2 * bd.BLOCK, 4, 128), jnp.bfloat16)
-        kv = jax.ShapeDtypeStruct((1, 2 * bd.BLOCK, 2, 128), jnp.bfloat16)
-
-        def loss(q, k, v):
-            return jnp.sum(bd.blockdiff_attention(q, k, v, block=4)
-                           .astype(jnp.float32))
-
-        jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-            q, kv, kv).jaxpr
-        text = str(jaxpr)
-        forward, = (str(eqn) for eqn in equations_of(jaxpr, "pallas_call")
-                    if eqn.params["name"].startswith("splash_mha_fwd"))
-        forward = "\n".join(line for line in forward.splitlines()
-                            if "xprof_metadata" not in line)
-        assert hashlib.sha256(forward.encode()).hexdigest() \
-            == PARENT["blockdiff_forward_call"]
-    else:
-        d, f, e, k = 64, 32, 8, 2
-        shape = jax.ShapeDtypeStruct
-        args = [shape((2, 16, d), jnp.bfloat16), shape((d, e), jnp.float32),
-                shape((e, d, f), jnp.float32), shape((e, d, f), jnp.float32),
-                shape((e, f, d), jnp.float32)]
-
-        def loss(*a):
-            y, stats = moe_ffn(*a, k=k)
-            return jnp.sum(y.astype(jnp.float32)) \
-                + jnp.sum(stats.load_balancing_loss)
-
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            *args).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT[which]
 
 
 # -- the configuration --------------------------------------------------------

@@ -3,13 +3,12 @@ layers without positions), the causal and window rules of
 ``kernels/masked_attention.py`` (the rules, the einsum under them and the
 pallas kernel in interpret mode), an expert layer routed by the block's input
 with relu gates, and the whole model against the plain reference
-(``tests/smallthinker_reference.py``: float32, a dense masked softmax, one
+(``chip_bench/configs/smallthinker-21b-a3b_reference.py``: float32, a dense masked softmax, one
 dense expert at a time under a mask, nothing of ``horovod_tpu``) on seeded
 weights at tiny widths.
 """
 
 import dataclasses
-import hashlib
 import json
 import os
 
@@ -20,10 +19,10 @@ import numpy as np
 import optax
 import pytest
 
-from . import smallthinker_reference as ref
-from .helpers import REPO_ROOT
+from .helpers import REPO_ROOT, load_reference
 from .test_olmoe import layer_inputs, rel_err
-from .test_sdar import PARENT
+
+ref = load_reference("smallthinker-21b-a3b")
 
 # 4 layers of the published pattern with a window shorter than the sequence,
 # 7 query heads a KV head, 2 of 8 experts held, a sliced vocabulary.
@@ -361,43 +360,6 @@ def dense_layer(x, routed_by, router, gate, up, down, k, held, act):
         we = jnp.sum(jnp.where(chosen == e, top, 0.0), axis=-1)
         y = y + we[:, None] * ((act(xf @ gate[i]) * (xf @ up[i])) @ down[i])
     return y.reshape(x.shape)
-
-
-def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
-    """The defaults spelled out lower to what the parent lowered to, whole
-    layer and share alike (the share's digest taken on this tree without the
-    arguments spelled: 64 slots are one chunk since PR 39, two until then,
-    when the digest was the parent fbf0cef's).  Both digests are PR 45's:
-    the rows are bf16, so the router's logits are three bf16 products over
-    the split weights."""
-    from horovod_tpu.parallel.moe import moe_ffn
-
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"recorded with JAX {PARENT['jax']}")
-    d, f, e, k = 64, 32, 8, 2
-    shape = jax.ShapeDtypeStruct
-
-    def text(held, **spelled):
-        n = e if held is None else len(held)
-        args = [shape((2, 16, d), jnp.bfloat16), shape((d, e), jnp.float32),
-                shape((n, d, f), jnp.float32), shape((n, d, f), jnp.float32),
-                shape((n, f, d), jnp.float32)]
-
-        def loss(x, *a):
-            extra = {"router_input": x, "activation": "silu"} if spelled \
-                else {}
-            share = {} if held is None else dict(held=held,
-                                                 norm_topk_prob=True)
-            y, stats = moe_ffn(x, *a, k=k, **share, **extra)
-            return jnp.sum(y.astype(jnp.float32)) \
-                + jnp.sum(stats.load_balancing_loss)
-
-        lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args)
-        return hashlib.sha256(lowered.as_text().encode()).hexdigest()
-
-    assert text(None, spelled=True) == PARENT["moe_ffn_all_held"]
-    share = "dcdd670a4e5e94ee0c4e896832a6c0abca916f22e4f6c1b6b6db3092851e2253"
-    assert text((1, 6)) == text((1, 6), spelled=True) == share
 
 
 @pytest.mark.parametrize("held", [None, (0, 5)])

@@ -1,0 +1,101 @@
+"""The chunked state-space scan (``kernels/ssd_scan.py``, Mamba-2's, which
+Nemotron's mixers run): the chunked form against the token-by-token
+recurrence of the plain reference, the two pallas kernels in interpret mode
+against the chunked form, and what ``takes()`` refuses.
+``tests/test_nemotron.py`` holds the mixer and the model.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from .helpers import load_reference
+from .test_olmoe import rel_err
+
+ref = load_reference("nemotron-3-super-120b-a12b")
+
+
+def scan_inputs(seed, batch, s, heads, p, groups, n, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (batch, s, heads, p)).astype(dtype),
+            jax.nn.softplus(jax.random.normal(ks[1], (batch, s, heads)) - 2),
+            -jnp.exp(jax.random.uniform(ks[2], (heads,), maxval=2.7)),
+            jax.random.normal(ks[3], (batch, s, groups, n)).astype(dtype),
+            jax.random.normal(ks[4], (batch, s, groups, n)).astype(dtype))
+
+
+def recurrence(x, dt, a, b, c):
+    return jax.vmap(lambda x, dt, b, c: ref._recurrence(x, dt, a, b, c))(
+        x, dt, b, c)
+
+
+@pytest.mark.parametrize("s,heads,groups,chunk", [
+    (200, 4, 2, 64), (64, 2, 1, 16), (37, 6, 3, 128), (128, 4, 4, 128)],
+    ids=["no_multiple_two_groups", "one_group", "shorter_than_a_chunk",
+         "a_head_a_group"])
+def test_the_chunked_scan_is_the_recurrence(s, heads, groups, chunk):
+    """``ssd_scan.chunked`` (the path off the TPU) against the reference's
+    token-by-token recurrence, forward and the gradients of all five."""
+    from horovod_tpu.kernels import ssd_scan
+
+    args = scan_inputs(0, 2, s, heads, 8, groups, 16)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(weight * jnp.tanh(fn(*a)))
+
+    with jax.default_matmul_precision("highest"):
+        want = recurrence(*args)
+        got = ssd_scan.ssd_scan(*args, chunk=chunk)
+        assert rel_err(got, want) < 1e-5
+        want_grads = jax.grad(loss(recurrence), argnums=range(5))(*args)
+        grads = jax.grad(loss(functools.partial(ssd_scan.ssd_scan,
+                                                chunk=chunk)),
+                         argnums=range(5))(*args)
+    for name, g, w in zip("x dt a b c".split(), grads, want_grads):
+        assert rel_err(g, w) < 2e-4, name
+
+
+@pytest.mark.parametrize("heads,p,groups", [(16, 64, 1), (32, 64, 2),
+                                            (8, 128, 1)],
+                         ids=["the_cells", "two_groups", "heads_of_128"])
+def test_the_kernels_are_the_chunked_form(heads, p, groups):
+    """The two pallas kernels in interpret mode against ``chunked`` on the
+    same bf16 inputs: ``y`` to bf16's rounding, the cotangents of ``x``,
+    ``B`` and ``C`` too, those of ``dt`` and ``a`` (fp32 sums) closer."""
+    from horovod_tpu.kernels import ssd_scan
+
+    args = scan_inputs(1, 2, 256, heads, p, groups, 128, jnp.bfloat16)
+    assert ssd_scan.takes(256, heads, p, groups, 128)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(weight * fn(*a).astype(jnp.float32))
+
+    got = ssd_scan.ssd_scan(*args, interpret=True)
+    want = ssd_scan.chunked(*args)
+    assert got.dtype == jnp.bfloat16 and rel_err(got, want) < 1e-2
+    grads = jax.grad(loss(functools.partial(ssd_scan.ssd_scan,
+                                            interpret=True)),
+                     argnums=range(5))(*args)
+    want_grads = jax.grad(loss(ssd_scan.chunked), argnums=range(5))(*args)
+    for name, g, w, tol in zip("x dt a b c".split(), grads, want_grads,
+                               (1e-2, 2e-3, 2e-3, 1e-2, 1e-2)):
+        assert g.dtype == w.dtype and rel_err(g, w) < tol, name
+
+
+@pytest.mark.parametrize("shape,taken", [
+    ((8192, 16, 64, 1, 128), True), ((8192, 128, 64, 8, 128), True),
+    ((8192, 16, 64, 1, 64), False), ((8100, 16, 64, 1, 128), False),
+    ((8192, 4, 64, 1, 128), False), ((8192, 16, 32, 1, 128), False),
+    ((8192, 16, 64, 3, 128), False)],
+    ids=["the_cells", "the_whole_mixer", "state_64", "no_whole_chunks",
+         "four_heads", "heads_of_32", "heads_in_no_groups"])
+def test_takes_refuses_what_the_kernels_cannot_run(shape, taken):
+    from horovod_tpu.kernels import ssd_scan
+
+    assert ssd_scan.takes(*shape) is taken
+    assert not ssd_scan.takes(*shape, dtype=jnp.float32)
+    assert not ssd_scan.takes(*shape, chunk=64)

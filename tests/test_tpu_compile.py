@@ -154,13 +154,14 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
     ``kernels/masked_attention.py`` gives them, and no [2L, 2L] table or
     score square in the program."""
     from horovod_tpu.kernels import blockdiff_attention as bd
+    from horovod_tpu.kernels import masked_attention
 
     q = _shape((1, 16384, 32, 128), jnp.bfloat16, one_chip)
     kv = _shape((1, 16384, 4, 128), jnp.bfloat16, one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(bd.blockdiff_attention(q, k, v, block=4)
-                       .astype(jnp.float32))
+        return jnp.sum(masked_attention.attention(
+            q, k, v, bd.BlockDiffusion(4)).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()

@@ -122,6 +122,42 @@ def test_tcp_mesh_pairwise(size):
         assert got == {p: f"from-{p}" for p in range(size) if p != rank}
 
 
+def test_tcp_mesh_forms_when_a_dialer_gave_up_a_hello_and_dialled_again(
+        monkeypatch):
+    """A listener starved for longer than the dialer's hello timeout finds
+    the abandoned socket first in its queue.  It must not take that one
+    for the link: it used to answer it, register it and close the dialer's
+    second attempt as a duplicate, and both ranks held a dead socket ("peer
+    closed connection" at the first frame of a job on a busy machine)."""
+    import time
+
+    from horovod_tpu.transport import tcp
+
+    monkeypatch.setattr(tcp, "_HELLO_TIMEOUT_SECS", 0.3)
+    accept_one = TcpMesh._accept_one
+    starved = [True]
+
+    def late_to_its_first(self, sock):
+        if starved:
+            starved.pop()
+            time.sleep(0.9)
+        return accept_one(self, sock)
+
+    monkeypatch.setattr(TcpMesh, "_accept_one", late_to_its_first)
+    store = MemoryStore()
+
+    def fn(rank):
+        mesh = TcpMesh(rank, 2, store, bind_addr="127.0.0.1",
+                       advertise_addr="127.0.0.1", timeout=20)
+        try:
+            mesh.send(1 - rank, f"from-{rank}".encode())
+            return mesh.recv(1 - rank).decode()
+        finally:
+            mesh.close()
+
+    assert run_ranks(2, fn) == ["from-1", "from-0"]
+
+
 def test_tcp_mesh_large_payload_ring():
     """Ring exchange with payloads larger than socket buffers must not
     deadlock (sendrecv overlaps directions)."""
