@@ -250,7 +250,8 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "attn_allowed_pairs_per_step": (
         "gauge", "(query, key) pairs a step's attention masks allow, from "
                  "the shapes, summed over the layers of kind=window (causal "
-                 "inside a window) and of kind=global (the model's mask), "
+                 "inside a window) and of kind=global (the model's mask; the "
+                 "prediction modules' blocks behind the stack among them), "
                  "every query head counted once"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "

@@ -513,13 +513,13 @@ class phase(span_ids):
 SCOPES = (
     "loss", "optimizer", "fuse", "allreduce",
     "embed", "norm", "ffn", "head",
-    "attn.proj", "attn.norm", "attn.rope", "attn.layout",
+    "attn.proj", "attn.norm", "attn.rope", "attn.layout", "attn.latent",
     "attn.einsum", "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
     "attn.causal", "attn.window", "attn.blockdiff",
     "conv.proj", "conv.gate",
     "ssm.proj", "ssm.conv", "ssm.scan", "ssm.norm",
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
-    "moe.latent", "moe.shared",
+    "moe.latent", "moe.shared", "mtp.proj",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",
 )

@@ -50,6 +50,12 @@ OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
 # slower or do not fit the fast memory.
 BLOCK = 1024
 _TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2)
+# Float32 operands wider than a lane group (the float32 twin of latent
+# attention, 192 wide, which holds a bf16 program's logits to a reference):
+# at tiles of 1024 the library's forward asks the compiler for 16.9 MiB of
+# its 16 of scoped fast memory (my chip run, PR 47); at 512 it fits.
+_TILES_WIDE_FLOAT32 = dict(block_q=BLOCK // 2, block_kv=BLOCK // 2,
+                           block_kv_compute=BLOCK // 2)
 # The backward kernel's: queries x keys, and the keys multiplied at a time.
 # At the same shape (PERF.md, PR 44; the backward alone, ms a layer, with the
 # block rule still in three clauses): these 23.96, the keys 256 or 1024 at a
@@ -113,16 +119,28 @@ class Window:
                                      window_size=(self.size - 1, 0), offset=0)
 
 
-def takes(rule, seq_len: int, head_dim: int) -> bool:
+def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
     """Whether the kernel takes this shape under ``rule``; otherwise, and off
     the TPU, the same mask goes through :func:`einsum`.  Heads of 64 go in
     as they are (LFM2-8B-A1B: 32 query heads on 8 KV heads): the library's
-    kernels take half a lane group, and the chip's compiler pads it."""
+    kernels take half a lane group, and the chip's compiler pads it.
+    ``head_dim_v``: the values' width where it is not the keys'; the one
+    such pair taken is latent attention's 192 over 128 (JoyAI-LLM-Flash), a
+    lane group and a half that the compiler pads likewise."""
+    if head_dim_v not in (None, head_dim):
+        return (head_dim, head_dim_v) == (192, 128) and rule.takes(seq_len)
     return (head_dim % 128 == 0 or head_dim == 64) and rule.takes(seq_len)
 
 
+def _wide_float32(q) -> bool:
+    """Whether operands like ``q [..., d]`` take the forward kernel's smaller
+    tiles (:data:`_TILES_WIDE_FLOAT32`)."""
+    return q.dtype.itemsize > 2 and q.shape[-1] > 128
+
+
 @functools.lru_cache(maxsize=8)
-def _kernel(rule, seq_len: int, heads: int, interpret: bool):
+def _kernel(rule, seq_len: int, heads: int, interpret: bool,
+            wide_float32: bool = False):
     """The library's forward kernel for one rule and shape, which also
     returns the rows' log-sum-exp; building it walks the rule tile by tile
     on the host, once."""
@@ -134,8 +152,10 @@ def _kernel(rule, seq_len: int, heads: int, interpret: bool):
     # Mask information is made of numpy arrays here, whatever trace is open.
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mha(
-            mask, block_sizes=splash.BlockSizes(**_TILES), head_shards=1,
-            q_seq_shards=1, save_residuals=True, interpret=interpret)
+            mask, block_sizes=splash.BlockSizes(
+                **(_TILES_WIDE_FLOAT32 if wide_float32 else _TILES)),
+            head_shards=1, q_seq_shards=1, save_residuals=True,
+            interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -145,7 +165,8 @@ def _attend(q, k, v, rule, interpret):
 
 
 def _attend_fwd(q, k, v, rule, interpret):
-    kernel = _kernel(rule, q.shape[2], q.shape[1], interpret)
+    kernel = _kernel(rule, q.shape[2], q.shape[1], interpret,
+                     _wide_float32(q))
     with scope(rule.scope.removeprefix("hvd.")):
         out, (logsumexp,) = jax.vmap(kernel)(q, k, v)
     return out, (q, k, v, out, logsumexp)
@@ -165,15 +186,16 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def attention(q, k, v, rule, *, interpret: bool = False):
-    """Softmax attention of ``q [b, s, h, d]`` on ``k, v [b, s, h_kv, d]``
-    under ``rule``, scores scaled by ``d ** -0.5``; ``h_kv`` divides ``h`` and
-    KV head ``j`` serves query heads ``j*h/h_kv`` to ``(j+1)*h/h_kv - 1``.
-    Returns ``[b, s, h, d]``.  Differentiable: the forward is the library's
-    kernel, the backward ``kernels/masked_attention_bwd.py``'s one."""
+    """Softmax attention of ``q [b, s, h, d]`` on ``k [b, s, h_kv, d]`` and
+    ``v [b, s, h_kv, dv]`` under ``rule``, scores scaled by ``d ** -0.5``;
+    ``h_kv`` divides ``h`` and KV head ``j`` serves query heads ``j*h/h_kv``
+    to ``(j+1)*h/h_kv - 1``.  Returns ``[b, s, h, dv]``.  Differentiable: the
+    forward is the library's kernel, the backward
+    ``kernels/masked_attention_bwd.py``'s one."""
     _, s, h, d = q.shape
-    if not takes(rule, s, d):
+    if not takes(rule, s, d, v.shape[3]):
         raise ValueError(f"no kernel under {rule} for {s} positions, head "
-                         f"width {d}")
+                         f"width {d} over values of {v.shape[3]}")
     hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
     # The copies into and out of the kernels' [heads, positions, width]
     # layout apart from the kernels, which alone lie under the rule's scope.
@@ -198,4 +220,4 @@ def einsum(q, k, v, rule):
         scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bngqk,bknd->bqngd", probs, v) \
-            .reshape(b, s, h, dh)
+            .reshape(b, s, h, v.shape[3])

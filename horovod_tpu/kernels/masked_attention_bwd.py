@@ -159,17 +159,20 @@ def _bwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("rule", "tiles", "interpret"))
 def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
-    """The three gradients of attention under ``rule``: ``q`` (scaled) and
-    ``do`` are ``[b, h, s, d]``, ``k`` and ``v`` ``[b, h_kv, s, d]``, ``lse``
-    (the rows' log-sum-exp) and ``di`` (``sum(out * do)`` a row) fp32
-    ``[b, h, s]``; ``tiles`` is (queries, keys, keys multiplied at a time).
-    Jitted: traced once a process and lowered once a program, whatever the
-    number of layers."""
+    """The three gradients of attention under ``rule``: ``q`` (scaled)
+    ``[b, h, s, d]`` and ``k`` ``[b, h_kv, s, d]``, ``v`` ``[b, h_kv, s, dv]``
+    and ``do`` ``[b, h, s, dv]`` (``dv`` is ``d`` but for latent attention,
+    whose keys are 192 wide over values of 128: the three products with ``q``
+    and ``k`` contract or produce ``d``, the two with ``v`` and ``do``
+    ``dv``), ``lse`` (the rows' log-sum-exp) and ``di`` (``sum(out * do)`` a
+    row) fp32 ``[b, h, s]``; ``tiles`` is (queries, keys, keys multiplied at
+    a time).  Jitted: traced once a process and lowered once a program,
+    whatever the number of layers."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
-    h_kv = k.shape[1]
+    h_kv, dv = k.shape[1], v.shape[3]
     group = h // h_kv
     block_q, block_kv, block_kv_compute = tiles
     if block_kv % block_kv_compute:
@@ -188,23 +191,29 @@ def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
     def of_key(n, i, g, t, q_tile, kv_tile, flags):
         return n, i, kv_tile[t], 0
 
-    q_block = pl.BlockSpec((None, None, block_q, d), of_query)
+    def blocks(width):
+        """(a query tile, a key tile, a KV head's whole sequence) of tensors
+        ``width`` wide."""
+        return (pl.BlockSpec((None, None, block_q, width), of_query),
+                pl.BlockSpec((None, None, block_kv, width), of_key),
+                pl.BlockSpec((None, None, s, width),
+                             lambda n, i, g, t, *_: (n, i, 0, 0)))
+
+    q_block, k_block, whole_k = blocks(d)
+    do_block, v_block, whole_v = blocks(dv)
     row_block = pl.BlockSpec((None, None, 1, block_q), of_query_row)
-    kv_block = pl.BlockSpec((None, None, block_kv, d), of_key)
-    whole_kv = pl.BlockSpec((None, None, s, d),
-                            lambda n, i, g, t, *_: (n, i, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, rule=rule, seq_len=s,
                           block_kv_compute=block_kv_compute),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, h_kv, group, table[0].shape[0]),
-            in_specs=[q_block, kv_block, kv_block, row_block, row_block,
-                      q_block],
-            out_specs=[q_block, whole_kv, whole_kv],
+            in_specs=[q_block, k_block, v_block, row_block, row_block,
+                      do_block],
+            out_specs=[q_block, whole_k, whole_v],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                             pltpu.VMEM((s, d), jnp.float32),
-                            pltpu.VMEM((s, d), jnp.float32)]),
+                            pltpu.VMEM((s, dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],

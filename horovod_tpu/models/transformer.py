@@ -1,5 +1,5 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2 and Nemotron-H presets.
+LFM2, Nemotron-H and JoyAI-LLM-Flash presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -29,11 +29,15 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   configuration's, or that the layer is a mixer alone or an FFN alone under
   one norm (Nemotron-H: a Mamba-2 mixer of ``models/mamba2.py`` over
   ``kernels/ssd_scan.py``, attention, or experts without a gate on a latent
-  width beside a shared expert): OLMoE is ``olmoe_1b_7b_config()``,
+  width beside a shared expert), and attention is plain or latent
+  (``models/deepseek.py``: DeepSeek-V3's MLA, and its multi-token-prediction
+  module behind the stack, JoyAI-LLM-Flash's): OLMoE is
+  ``olmoe_1b_7b_config()``,
   SDAR-30B-A3B ``sdar_30b_a3b_config()``, SmallThinker-21BA3B
-  ``smallthinker_21b_a3b_config()``, LFM2-8B-A1B ``lfm2_8b_a1b_config()``
-  and Nemotron-3-Super-120B-A12B ``nemotron_3_super_config()``
-  over the same ``Transformer``, their expert layer
+  ``smallthinker_21b_a3b_config()``, LFM2-8B-A1B ``lfm2_8b_a1b_config()``,
+  Nemotron-3-Super-120B-A12B ``nemotron_3_super_config()`` and
+  JoyAI-LLM-Flash ``joyai_llm_flash_config()`` over the same
+  ``Transformer``, their expert layer
   :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``), their masks
   that are rules ``kernels/masked_attention.py``'s.
 """
@@ -179,6 +183,26 @@ class TransformerConfig:
     mamba_chunk: int = 128
     mamba_groups_held: Optional[Tuple[int, ...]] = None
     mamba_dt_limits: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
+    # Latent attention (DeepSeek-V2/V3's MLA, models/deepseek.py), where
+    # kv_lora_rank is set: queries through a latent of q_lora_rank and keys
+    # and values through one of kv_lora_rank, an RMSNorm on each; a head's
+    # query and key are qk_nope_head_dim without positions beside
+    # qk_rope_head_dim rotary, the rotary key one head that all share; values
+    # of v_head_dim; scores scaled by the key's whole width.  rope_interleave:
+    # the rotary part rotates the pairs (2i, 2i+1), not the halves.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # Multi-token-prediction modules behind the stack (DeepSeek-V3 section
+    # 2.2, models/deepseek.py): module k reads the state before ln_f (or
+    # module k-1's) beside the embedding of token i+k, runs one block of the
+    # last layer's kind, named layer_{num_layers + k - 1}, and goes through a
+    # norm of its own and the model's head; the model then returns (logits,
+    # (module 1's logits, ...)).
+    mtp_modules: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -190,12 +214,20 @@ class TransformerConfig:
         if self.num_layers % len(self.layer_pattern):
             raise ValueError(f"{self.num_layers} layers are no whole periods "
                              f"of {len(self.layer_pattern)}")
+        if i >= self.num_layers:
+            # A prediction module's block: the stack's last layer's kind.
+            i = self.num_layers - 1
         return LayerKind(*self.layer_pattern[i % len(self.layer_pattern)])
 
+    @property
+    def num_blocks(self) -> int:
+        """The stack's layers and the prediction modules' blocks behind."""
+        return self.num_layers + self.mtp_modules
+
     def expert_layers(self) -> Tuple[int, ...]:
-        """The indices of the layers whose FFN (their kind's, else the
+        """The indices of the blocks whose FFN (their kind's, else the
         configuration's) is the sparse-expert one."""
-        return tuple(i for i in range(self.num_layers)
+        return tuple(i for i in range(self.num_blocks)
                      if (self.layer_kind(i).ffn or self.ffn) == "moe")
 
 
@@ -318,6 +350,31 @@ def nemotron_3_super_config(**overrides) -> TransformerConfig:
         mamba_heads=128, mamba_head_dim=64, mamba_groups=8, mamba_state=128,
         mamba_conv=4, mamba_chunk=128,
         layer_pattern=hybrid_pattern(letters)), **overrides})
+
+
+def joyai_llm_flash_config(**overrides) -> TransformerConfig:
+    """JoyAI-LLM-Flash (jdopensource/JoyAI-LLM-Flash ``config.json``,
+    ``joyai_llm_flash``; every key is DeepSeek-V3's, arXiv:2412.19437): 40
+    layers of latent attention, 32 heads whose keys are 128 without positions
+    beside a rotary 64 that the heads share (interleaved pairs, theta 3.2e7)
+    over values of 128, through latents of 1536 (queries) and 512 (keys and
+    values); layer 0 a dense SwiGLU of width 7168, the others 256 experts of
+    width 768, 8 a token by sigmoid scores plus a bias the step keeps,
+    weights renormalised and times 2.5, beside a gated shared expert of
+    width 768; one multi-token-prediction module behind the stack; RMSNorm
+    at 1e-6, no biases, an untied head."""
+    pattern = tuple(LayerKind(ffn="dense" if i < 1 else None)
+                    for i in range(40))
+    return TransformerConfig(**{**dict(
+        vocab_size=129280, num_layers=40, num_heads=32, d_model=2048,
+        d_ff=768, d_ff_dense=7168, d_ff_shared=768, max_len=131072,
+        causal=True, norm="rmsnorm", norm_eps=1e-6, positions="rope",
+        rope_theta=3.2e7, rope_interleave=True, use_bias=False,
+        tie_embeddings=False, ffn="moe", num_experts=256,
+        experts_per_token=8, norm_topk_prob=True, router_scoring="sigmoid",
+        expert_bias=True, routed_scaling_factor=2.5, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, mtp_modules=1, layer_pattern=pattern), **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -564,6 +621,10 @@ class Block(nn.Module):
                 y = _norm(cfg, "ln1")(x)
             if mixer == "conv":
                 y = ShortConv(cfg, name="conv")(y)
+            elif mixer == "attention" and cfg.kv_lora_rank:
+                from .deepseek import LatentAttention
+
+                y = LatentAttention(cfg, self.kind, name="attn")(y, positions)
             elif mixer == "attention":
                 y = Attention(cfg, self.kind, name="attn")(y, positions)
             elif mixer == "mamba2":
@@ -720,9 +781,10 @@ def expert_bias_collection(cfg: TransformerConfig, bias) -> dict:
 
 def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
     """``{"window": n, "global": n}``: the (query, key) pairs the masks of
-    one sequence of ``seq_len`` positions allow, summed over the layers that
-    attend inside a window and over those under the model's own mask; from
-    the shapes and the rules alone."""
+    one sequence of ``seq_len`` positions allow, summed over the blocks (the
+    stack's and the prediction modules') that attend inside a window and
+    over those under the model's own mask; from the shapes and the rules
+    alone."""
     if cfg.block_diffusion:
         everywhere = BlockDiffusion(cfg.block_diffusion).allowed_pairs(seq_len)
     elif cfg.causal:
@@ -730,7 +792,7 @@ def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
     else:
         everywhere = seq_len * seq_len
     pairs = {"window": 0, "global": 0}
-    for i in range(cfg.num_layers):
+    for i in range(cfg.num_blocks):
         if cfg.layer_kind(i).mixer != "attention":
             continue
         window = cfg.layer_kind(i).window
@@ -757,7 +819,9 @@ def publish_attention(cfg: TransformerConfig, seq_len: int,
 
 
 class Transformer(nn.Module):
-    """Token ids ``[batch, seq]`` → logits ``[batch, seq, vocab]``.
+    """Token ids ``[batch, seq]`` → logits ``[batch, seq, vocab]``; with
+    ``cfg.mtp_modules`` → (those, a tuple of each prediction module's logits
+    of the same shape).
 
     ``positions`` ``[seq]``: each position's index for the rotary embedding
     (default ``0..seq-1``).  With ``cfg.block_diffusion`` the tokens are
@@ -795,18 +859,42 @@ class Transformer(nn.Module):
             block = nn.remat(Block)
         for i in range(cfg.num_layers):
             x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(x, positions)
+        stack_output = x
         with scope("norm"):
             if cfg.block_diffusion:
                 x = x[:, :s // 2]
             x = _norm(cfg, "ln_f")(x)
+        if cfg.tie_embeddings:
+            # Weight-tied readout against the (model-axis-sharded)
+            # embedding; ``attend`` multiplies in the table's ``dtype``
+            # whatever it is handed.
+            head = lambda y: embed.attend(y.astype(jnp.float32))  # noqa: E731
+        else:
+            head = _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
+                          "lm_head")
         with scope("head"):
-            if cfg.tie_embeddings:
-                # Weight-tied readout against the (model-axis-sharded)
-                # embedding; ``attend`` multiplies in the table's ``dtype``
-                # whatever it is handed.
-                return embed.attend(x.astype(jnp.float32))
-            return _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
-                          "lm_head")(x)
+            logits = head(x)
+        if not cfg.mtp_modules:
+            return logits
+        if cfg.block_diffusion:
+            raise ValueError("no prediction modules under block diffusion")
+        from .deepseek import PredictionModule
+
+        # Module k reads token i + k beside position i's state and predicts
+        # token i + k + 1; it runs over all positions (the sequence rolled,
+        # so that the kernels take its length), and the last k positions,
+        # which read a token from the sequence's start, enter no loss.
+        ahead, state = [], stack_output
+        for k in range(cfg.mtp_modules):
+            module = PredictionModule(cfg, name=f"mtp_{k}")
+            with scope("embed"):
+                following = embed(jnp.roll(tokens, -(k + 1), axis=1))
+            i = cfg.num_layers + k
+            state = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(
+                module.join(state, following), positions)
+            with scope("head"):
+                ahead.append(head(module.readout_norm(state)))
+        return logits, tuple(ahead)
 
     def _learned_positions(self, s):
         cfg = self.cfg

@@ -51,6 +51,8 @@ BLOCKS = {
                                 "conv.proj", "conv.gate"],
     "share_ssm": _LM + _MOE + ["ssm.proj", "ssm.conv", "ssm.scan", "ssm.norm",
                                "moe.latent", "moe.shared"],
+    "share_latent": _LM + _MOE + ["attn.latent", "attn.rope", "attn.layout",
+                                  "ffn", "moe.shared", "mtp.proj"],
     "resnet": ["loss", "bn", "resnet.stem", "resnet.stage1", "resnet.stage2",
                "resnet.stage3", "resnet.stage4", "resnet.head"],
 }
@@ -63,9 +65,10 @@ import collections, contextlib, glob, hashlib, json, re
 import flax.linen as nn, jax, jax.numpy as jnp, optax
 from horovod_tpu.models.resnet import BottleneckBlock, ResNet
 from horovod_tpu.models.transformer import (
-    LayerKind, Transformer, hybrid_pattern, lfm2_8b_a1b_config, moe_stats,
-    nemotron_3_super_config, olmoe_1b_7b_config, sdar_30b_a3b_config,
-    smallthinker_21b_a3b_config, tiny_config)
+    LayerKind, Transformer, hybrid_pattern, joyai_llm_flash_config,
+    lfm2_8b_a1b_config, moe_stats, nemotron_3_super_config,
+    olmoe_1b_7b_config, sdar_30b_a3b_config, smallthinker_21b_a3b_config,
+    tiny_config)
 
 if {null}:
     jax.named_scope = lambda name: contextlib.nullcontext()
@@ -82,6 +85,9 @@ def lm(cfg, moe):
                                         mutable=["moe"])
             extra = 0.01 * jnp.sum(
                 moe_stats(state["moe"]).load_balancing_loss)
+            if cfg.mtp_modules:
+                # Both heads enter the loss.
+                logits = logits[0] + sum(logits[1])
         else:
             logits, extra = model.apply({{"params": params}},
                                         batch["tokens"]), 0.0
@@ -140,6 +146,15 @@ MODELS = {{
         mamba_head_dim=8, mamba_groups=4, mamba_groups_held=(0, 2),
         mamba_state=16, mamba_chunk=16,
         layer_pattern=hybrid_pattern("M*E")), True),
+    # Latent attention (heads of 16 + 8 over 12), a dense layer and one with
+    # 2 of 8 experts held beside a gated shared expert, and a prediction
+    # module with a block of its own behind them.
+    "share_latent": lambda: lm(joyai_llm_flash_config(
+        **{{**share, "num_kv_heads": None}}, d_ff_dense=96, d_ff_shared=32,
+        experts_per_token=2, experts_held=(1, 6), q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=12, layer_pattern=(LayerKind(ffn="dense"), LayerKind())),
+        True),
     "resnet": resnet,
 }}
 
@@ -264,10 +279,11 @@ def test_every_segment_of_every_program_is_in_the_vocabulary(scoped):
              for s in scopes.segments(name)}
     assert found <= set(SCOPES), found - set(SCOPES)
     # What a CPU run can reach of it: everything but the kernels' scopes and
-    # the sequence-parallel attentions.
+    # the sequence-parallel attentions (``attn.layout`` by latent
+    # attention's assembly of its keys, which is no kernel's).
     assert set(SCOPES) - found == {
-        "attn.layout", "attn.flash", "attn.short", "attn.ring",
-        "attn.ulysses", "attn.causal", "attn.window", "attn.blockdiff"}
+        "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
+        "attn.causal", "attn.window", "attn.blockdiff"}
 
 
 def test_the_rules_of_the_attention_kernels_name_scopes_of_the_vocabulary():

@@ -162,6 +162,65 @@ def test_gradients_in_interpret_mode_match_the_grouped_einsum(
         assert rel_err(g, e) < 1e-5
 
 
+@pytest.mark.parametrize("rule_name", ["causal", "window_that_cuts_a_tile"])
+def test_gradients_at_keys_of_192_over_values_of_128(rule_name, tiles_of_128):
+    """Latent attention's widths (JoyAI-LLM-Flash): q and k 192 wide, v and
+    the output 128, one key head a query head: the library's forward kernel
+    and the one backward kernel in interpret mode against ``jax.grad`` of the
+    einsum at the two widths; the scores are scaled by the keys' width."""
+    rule, s, h = RULES[rule_name], 512, 3
+    assert ma.takes(rule, s, 192, 128)
+    assert not ma.takes(rule, s, 128, 192) and not ma.takes(rule, s, 192)
+    assert not ma.takes(rule, s, 256, 128) and ma.takes(rule, s, 128, 128)
+    keys = jax.random.split(jax.random.PRNGKey(192), 4)
+    q, k = (jax.random.normal(key, (2, s, h, 192)) for key in keys[:2])
+    v, w = (jax.random.normal(key, (2, s, h, 128)) for key in keys[2:])
+    with jax.default_matmul_precision("highest"):
+        out = ma.attention(q, k, v, rule, interpret=True)
+        plain = ma.einsum(q, k, v, rule)
+        got = gradients(lambda *qkv: ma.attention(*qkv, rule, interpret=True),
+                        q, k, v, w)
+        want = gradients(lambda *qkv: ma.einsum(*qkv, rule), q, k, v, w)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 192 ** -0.5
+        ids = jnp.arange(s)
+        by_hand = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(
+            rule.allowed(ids[:, None], ids[None, :], s), scores, -jnp.inf)),
+            v)
+    assert out.shape == plain.shape == (2, s, h, 128)
+    assert rel_err(out, by_hand) < 1e-5 and rel_err(plain, by_hand) < 1e-5
+    for g, e, like in zip(got, want, (q, k, v)):
+        assert g.shape == e.shape == like.shape and g.dtype == e.dtype
+        assert rel_err(g, e) < 1e-5
+    with pytest.raises(ValueError, match="head width 128 over values of 192"):
+        ma.attention(v, v, q, rule, interpret=True)
+
+
+def test_equal_widths_give_bit_for_bit_what_one_width_gave(tiles_of_128):
+    """At one width for q, k and v the kernel's blocks, scratch and products
+    are what they were before it learnt two (the pinned call of
+    ``tests/test_pinned_programs.py`` holds the text): the same call twice,
+    and with the widths spelled apart through ``dq_dk_dv``, gives the same
+    bits."""
+    rule, b, s, h, d = ma.Causal(), 1, 256, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, k, v, do = (jax.random.normal(key, (b, h, s, d), jnp.bfloat16)
+                   for key in keys)
+    lse = jnp.zeros((b, h, s)) + 3.0
+    di = jnp.ones((b, h, s))
+    once = bwd.dq_dk_dv(q, k, v, lse, di, do, rule=rule,
+                        tiles=(128, 128, 64), interpret=True)
+    # Keys padded by a lane group of zeros add nothing to any product:
+    # dq and dk gain zero columns, dv is the same bits.
+    wide = lambda t: jnp.pad(t, ((0, 0),) * 3 + ((0, 128),))  # noqa: E731
+    apart = bwd.dq_dk_dv(wide(q), wide(k), v, lse, di, do, rule=rule,
+                         tiles=(128, 128, 64), interpret=True)
+    assert apart[0].shape == apart[1].shape == (b, h, s, 256)
+    for got, want in zip(apart, once):
+        np.testing.assert_array_equal(got[..., :d], want)
+    assert not np.asarray(apart[0][..., d:]).any()
+    assert not np.asarray(apart[1][..., d:]).any()
+
+
 def test_a_last_key_tile_that_a_single_query_tile_sees(tiles_of_128):
     """Under a window of one tile every key tile but the last is seen from
     two query tiles, the last from one: its dk and dv are that one step's,

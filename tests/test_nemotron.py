@@ -475,18 +475,23 @@ def test_a_quarter_that_is_no_multiple_of_128_is_rounded_up(sizes, quantum,
 
 # -- what stays as it was ------------------------------------------------------
 
-def test_the_scan_and_the_mixer_load_where_a_configuration_asks():
+@pytest.mark.parametrize("suite,late", [
+    ("test_nemotron", ("horovod_tpu.kernels.ssd_scan",
+                       "horovod_tpu.models.mamba2")),
+    ("test_joyai", ("horovod_tpu.models.deepseek",))])
+def test_the_scan_and_the_mixer_load_where_a_configuration_asks(suite, late):
     """Neither ``import horovod_tpu`` nor ``hvd.init()`` nor the models'
     package loads the kernel or the mixer's module; a layer of kind
-    ``mamba2`` does."""
+    ``mamba2`` does.  Nor DeepSeek-V3's parts (latent attention, the
+    prediction module): a model with ``kv_lora_rank`` does."""
     code = (
         "import sys, horovod_tpu as hvd\n"
         "hvd.init()\n"
         "import horovod_tpu.models.transformer, horovod_tpu.parallel.moe\n"
-        "late = ('horovod_tpu.kernels.ssd_scan', 'horovod_tpu.models.mamba2')\n"
+        f"late = {late!r}\n"
         "assert not [m for m in late if m in sys.modules], sys.modules.keys()\n"
         "assert 'jax.experimental.pallas' not in sys.modules\n"
-        "from tests.test_nemotron import tiny_model\n"
+        f"from tests.{suite} import tiny_model\n"
         "import jax, jax.numpy as jnp\n"
         "model, _ = tiny_model()\n"
         "jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), "

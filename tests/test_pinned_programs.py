@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from . import test_lfm2, test_nemotron, test_olmoe, test_sdar, \
+from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, test_sdar, \
     test_smallthinker
 
 RECORDED_WITH = "0.9.0"     # the text of a lowering is the JAX version's own
@@ -92,6 +92,23 @@ PINS = {
     "tree/sdar-30b-a3b": "fa6dff3fc67ccecb9d81983641bd0477aa24047a",
     "tree/smallthinker-21b-a3b": "5996a7811d123657dca6869ca4c999ef890137de",
     "tree/lfm2-8b-a1b": "811fb3c5a0e5cb8c1a78b62aaa51b32ca1585094",
+    # PR 47 (JoyAI-LLM-Flash).  ``causal_kernel_call`` is the jaxpr of the
+    # wrapper's call under ``Causal``, 4 query heads on 2 KV heads of 128,
+    # forward and the three gradients, without the line of profiler
+    # metadata, taken on PR 47's parent (7ca964c): the backward kernel learnt
+    # a second width and at one width traces to what it traced to.  The
+    # others are PR 47's own text: the same call at latent attention's
+    # widths (keys of 192 over values of 128, 3 heads), test_joyai.py's tiny
+    # model (latent attention, the dense layer, two sparse ones, the
+    # prediction module), loss and gradients in float32 on 2 x 20 tokens, and
+    # its parameter tree.
+    "causal_kernel_call":
+    "24810e80ba11de23dfe0ce8a38df66b9c7dd61d850461033f693e052d8d2973e",
+    "latent_kernel_call":
+    "80503e498bf54f7a33dc4467687a35edea933a73117ea368ecbbf0fb6aa97ccc",
+    "float32/joyai_tiny_step":
+    "f380ca403f239979494b1e26168e5e627009993a578e21674753a8406373c3fc",
+    "tree/joyai-llm-flash": "e2bc7c473a1f641f1f61e76d8b6ac6dadbacba87",
 }
 
 
@@ -205,6 +222,28 @@ def test_lowers_to_what_the_parent_lowered_to(which):
     assert digest(text) == PINS[which]
 
 
+@pytest.mark.parametrize("which,heads,kv_heads,widths", [
+    ("causal_kernel_call", 4, 2, (128, 128)),
+    ("latent_kernel_call", 3, 3, (192, 128))])
+def test_the_causal_kernels_call_traces_to_the_pinned_text(which, heads,
+                                                           kv_heads, widths):
+    from horovod_tpu.kernels import masked_attention as ma
+
+    d, dv = widths
+    q = shape((1, 2 * ma.BLOCK, heads, d), jnp.bfloat16)
+    k = shape((1, 2 * ma.BLOCK, kv_heads, d), jnp.bfloat16)
+    v = shape((1, 2 * ma.BLOCK, kv_heads, dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, ma.Causal())
+                       .astype(jnp.float32))
+
+    jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, v).jaxpr
+    text = "\n".join(line for line in str(jaxpr).splitlines()
+                     if "xprof_metadata" not in line)
+    assert digest(text) == PINS[which]
+
+
 def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
     """SmallThinker's two options at their defaults, spelled out, lower to
     what the parent lowered to, whole layer and share alike."""
@@ -239,10 +278,11 @@ def float32_text(which):
         aux = jax.eval_shape(lambda: test_lfm2.counters(sizes))
         return step_text(test_lfm2.program_loss(model, sizes),
                          abstract(model, tokens), aux, {"tokens": tokens})
-    model, sizes = test_nemotron.tiny_model(jnp.float32)
+    tiny = test_joyai if which == "joyai_tiny_step" else test_nemotron
+    model, sizes = tiny.tiny_model(jnp.float32)
     few = shape((2, sizes["sequence_length"]), jnp.int32)
-    aux = jax.eval_shape(lambda: test_nemotron.zero_aux(sizes))
-    return step_text(test_nemotron.program_loss(model, sizes),
+    aux = jax.eval_shape(lambda: tiny.zero_aux(sizes))
+    return step_text(tiny.program_loss(model, sizes),
                      abstract(model, few), aux, {"tokens": few})
 
 
@@ -269,6 +309,7 @@ def small_presets():
         "smallthinker-21b-a3b": t.smallthinker_21b_a3b_config(
             **share, head_width=8, experts_per_token=3, experts_held=(1, 6),
             layer_pattern=(t.LayerKind(0, False), t.LayerKind(8, True))),
+        "joyai-llm-flash": test_joyai.tiny_model()[0].cfg,
         "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
             **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
             experts_per_token=2, experts_held=(1, 6),

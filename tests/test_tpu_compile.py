@@ -515,3 +515,143 @@ def test_gateless_latent_expert_share_compiles_at_nemotrons_widths(
     assert " conditional(" not in text
     assert not re.findall(r"= \(?\w+\[180224,1024\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def test_masked_attention_compiles_at_joyais_widths(one_chip,
+                                                    no_compile_cache):
+    """One sequence of 8192 positions, 32 heads, keys of 192 over values of
+    128, causal (latent attention, nothing grouped): the library's forward
+    kernel and the one backward kernel take a lane group and a half as it
+    is, and dq and dk come back 192 wide, dv 128."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    rule = ma.Causal()
+    assert ma.takes(rule, 8192, 192, 128)
+    qk = _shape((1, 8192, 32, 192), jnp.bfloat16, one_chip)
+    v = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, rule).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+        kernels
+    assert "8192,8192" not in text
+    assert [tuple(x.shape) for x in compiled.output_shardings
+            and jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)),
+                               qk, qk, v)] == [
+        (1, 8192, 32, 192), (1, 8192, 32, 192), (1, 8192, 32, 128)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_joyais_step_compiles_and_fits_the_chip(topo, no_compile_cache,
+                                                monkeypatch,
+                                                record_property):
+    """``joyai-llm-flash-wfbp-1chip``'s whole step (loss, gradients, AdamW)
+    at the timed sizes under the one device's mesh, as
+    ``hvd.make_overlapped_train_step`` builds it: it compiles through the
+    kernels' path (the two attention kernels, the rows kernel, no einsum over
+    a score square) and the compiler's own count of its memory stays inside
+    the 15.75 GiB it may use; the count goes into the junit."""
+    import json
+    import os
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.frameworks.jax.wfbp import PROCESS_AXIS
+    from horovod_tpu.kernels import masked_attention as ma
+
+    from .helpers import REPO_ROOT
+    from .test_joyai_cell import _config_module
+
+    module, sizes = _config_module()
+    config = module.Config(sizes)
+    tx = config.optimizer(1)
+    mesh = Mesh(np.array(topo.devices[:1]), (PROCESS_AXIS,))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P(PROCESS_AXIS))
+
+    def step(params, opt_state, aux, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            config.loss, has_aux=True)(params, aux, batch)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, aux, loss
+
+    def on(sharding, tree):
+        return jax.tree_util.tree_map(
+            lambda x: _shape(x.shape, x.dtype, sharding), tree)
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    params, aux = jax.eval_shape(config.init, key)
+    args = (on(rep, params), on(rep, jax.eval_shape(tx.init, params)),
+            on(rep, aux), on(rows, jax.eval_shape(config.make_batch, key)))
+    # The forward kernel's mask tables are made of numpy arrays at trace
+    # time, which a described device cannot hold: built here, outside the
+    # mesh, once (the wrapper caches them).
+    ma._kernel(ma.Causal(), sizes["sequence_length"],
+               sizes["num_attention_heads"], False, False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            *args).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
+    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
+                       "hvd_rows_to_tokens"}, kernels
+    assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 6
+    assert "32,8192,8192" not in text            # the scores, any layout
+    mem = compiled.memory_analysis()
+    gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+           + mem.output_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
+    record_property("joyai_step_gib", round(gib, 3))
+    record_property("joyai_step_argument_gib",
+                    round(mem.argument_size_in_bytes / 2 ** 30, 3))
+    record_property("joyai_step_temp_gib",
+                    round(mem.temp_size_in_bytes / 2 ** 30, 3))
+    assert 14.0 < gib < 15.75, gib
+    # The file states what the compiler counted.
+    with open(os.path.join(REPO_ROOT, "chip_bench/configs",
+                           "joyai-llm-flash.json")) as f:
+        assert f"{gib:.2f} GiB" in json.load(f)["assumed"]["fit"]
+
+
+def test_joyais_float32_twin_compiles(one_chip, no_compile_cache,
+                                      monkeypatch):
+    """The program's model computed in float32 at the timed sizes, both
+    heads' logits: what ``logits_float32_rtol`` reads on the chip.  Its
+    forward kernel takes float32 keys of 192 in tiles of 512: at the bf16
+    program's 1024 the chip's compiler refused the whole program for 16.9
+    MiB of scoped fast memory where the kernel compiled alone passes (my
+    chip run, PR 47)."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    from .test_joyai_cell import _config_module
+
+    module, sizes = _config_module()
+    config = module.Config(sizes)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _shape(x.shape, x.dtype, one_chip), tree)
+
+    args = (on_chip(jax.eval_shape(config.init, key)[0]),
+            on_chip(jax.eval_shape(config.make_batch, key)),
+            on_chip(jax.eval_shape(
+                lambda: config.reference.zero_bias(sizes))))
+    assert ma._wide_float32(_shape((1, 8, 2, 192), jnp.float32, None))
+    for shape, dtype in (((1, 8, 2, 192), jnp.bfloat16),
+                         ((1, 8, 2, 128), jnp.float32)):
+        assert not ma._wide_float32(_shape(shape, dtype, None))
+    assert ma._TILES_WIDE_FLOAT32["block_q"] == 512
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = config._logits("program_float32", ()).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 6
+    assert '\\"block_q\\": 512' in text
+    assert "32,8192,8192" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
