@@ -257,6 +257,43 @@ def _logits(xf, router, row_scale=None, col_scale=None):
     return s
 
 
+def _chosen(probs, experts):
+    """``probs [n, E]`` at ``experts [n, k]`` (distinct within a row), ``[n,
+    k]``: ``take_along_axis(probs, experts, -1)`` to the last bit, read by
+    comparing the row's j-th index with an iota over the E outputs and
+    summing the one term that is left, and differentiated the same way: the
+    cotangent of ``probs`` is the sum over the k of ``g[:, j]`` where
+    ``experts[:, j]`` is the column, at most one term an entry.  No gather
+    forward and no scatter backward, whose scalars cost the TPU 10 ns each;
+    k selects over ``[n, E]`` each way, written out, which XLA fuses with
+    the scores' neighbours better than one select over ``[n, k, E]``
+    (``PERF.md`` §6, PR 48).  What the backward keeps is ``experts`` alone."""
+    k, n_experts = experts.shape[1], probs.shape[1]
+
+    def column(a, j):
+        return lax.slice_in_dim(a, j, j + 1, axis=1)               # [n, 1]
+
+    def where_chosen(experts, j, values):
+        """``values`` in the column that is the row's j-th expert, else 0."""
+        outputs = lax.broadcasted_iota(jnp.int32, (1, n_experts), 1)
+        return jnp.where(column(experts, j) == outputs, values, 0)
+
+    @jax.custom_vjp
+    def read(probs, experts):
+        return jnp.stack([jnp.sum(where_chosen(experts, j, probs), axis=1)
+                          for j in range(k)], axis=1)
+
+    def backward(experts, g):
+        with scope("moe.router"):
+            return functools.reduce(jnp.add, [
+                where_chosen(experts, j, column(g, j))
+                for j in range(k)]), None
+
+    read.defvjp(lambda probs, experts: (read(probs, experts), experts),
+                backward)
+    return read(probs, experts)
+
+
 def _route(xf, router, k, norm_topk_prob=False, router_input=None,
            scoring="softmax", bias=None, scale=1.0, row_scale=None,
            col_scale=None):
@@ -272,8 +309,9 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
     sigmoid of each logit, whose k weights are divided by their sum plus
     1e-6 under ``norm_topk_prob`` and carry no auxiliary loss (both zero).
     ``bias [experts]`` is added to the scores for the choice of the k alone;
-    the weights are the scores themselves.  ``scale`` multiplies the
-    weights."""
+    the weights are the scores themselves, read back at the chosen indices
+    by :func:`_chosen` whatever the scoring (the choice sees no tangent).
+    ``scale`` multiplies the weights."""
     n, n_experts = xf.shape[0], router.shape[-1]
     with scope("moe.router"):
         if router_input is not None:
@@ -295,12 +333,10 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
             probs = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"unknown scoring {scoring!r}")
-        if bias is None:
-            weights, experts = lax.top_k(probs, k)             # [n, k]
-        else:
-            _, experts = lax.top_k(
-                probs + lax.stop_gradient(bias.astype(jnp.float32)), k)
-            weights = jnp.take_along_axis(probs, experts, axis=-1)
+        chosen_by = probs if bias is None \
+            else probs + bias.astype(jnp.float32)
+        _, experts = lax.top_k(lax.stop_gradient(chosen_by), k)    # [n, k]
+        weights = _chosen(probs, experts)
         if norm_topk_prob:
             total = jnp.sum(weights, axis=-1, keepdims=True)
             weights = weights / (total + 1e-6 if scoring == "sigmoid"

@@ -371,12 +371,14 @@ def _sibling(name):
 
 
 @pytest.mark.parametrize("name", ["olmoe", "sdar", "smallthinker"])
-def test_default_arguments_give_the_siblings_losses_bit_for_bit(
-        name, monkeypatch):
+def test_default_arguments_give_the_siblings_losses(name, monkeypatch):
     """With ``scoring``, ``bias`` and ``scale`` at their defaults the router
-    is the parent's: the sibling's loss and its gradient through the new
-    ``_route`` equal, bit for bit, those through the parent's text, and the
-    two trace to the same program."""
+    computes what the parent's did: the sibling's loss through the new
+    ``_route`` equals that through the parent's text, and its gradient lies
+    within float32's rounding of it.  Bit for bit until PR 48 read the
+    chosen scores by a compare (``moe._chosen``), around which XLA fuses the
+    renormalisation in another order: SDAR's and SmallThinker's gradients
+    still agree to the bit, OLMoE's to 1.3e-7 of its largest entry."""
     from horovod_tpu.parallel import moe
 
     loss, params = _sibling(name)
@@ -384,14 +386,12 @@ def test_default_arguments_give_the_siblings_losses_bit_for_bit(
     # the second), where the bare call compiles primitive by primitive.
     run = lambda: jax.jit(jax.value_and_grad(loss))(params)  # noqa: E731
     new, new_grads = run()
-    new_text = str(jax.make_jaxpr(loss)(params))
     monkeypatch.setattr(moe, "_route", _frozen_route)
     old, old_grads = run()
-    assert str(jax.make_jaxpr(loss)(params)) == new_text
     assert float(new) == float(old)
     for a, b in zip(jax.tree_util.tree_leaves(new_grads),
                     jax.tree_util.tree_leaves(old_grads)):
-        np.testing.assert_array_equal(a, b)
+        assert rel_err(a, b) < 1e-6
 
 
 def test_the_bias_enters_the_choice_and_not_the_weights():

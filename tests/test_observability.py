@@ -269,6 +269,14 @@ def test_server_request_metrics_and_scrape_fold_in():
         client.set("obs-smoke", "k", b"v")
         client.get("obs-smoke", "k")
         client.keys("obs-smoke")
+        # The handler counts a request behind its reply: the last one's
+        # count may still be on its way when the client has its answer.
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline and not (
+                reg.get_counter("rendezvous_scope_ops_total", op="keys",
+                                scope="obs-smoke")
+                and reg.get_gauge("rendezvous_requests_in_flight") == 0):
+            time.sleep(0.01)
         assert reg.get_counter("rendezvous_scope_ops_total",
                                op="put", scope="obs-smoke") == puts0 + 1
         assert reg.get_counter("rendezvous_scope_ops_total",

@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, test_sdar, \
-    test_smallthinker
+from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, \
+    test_router_product, test_sdar, test_smallthinker
 
 RECORDED_WITH = "0.9.0"     # the text of a lowering is the JAX version's own
 
@@ -50,40 +50,44 @@ PINS = {
     # hashes to the same.  ``sdar_tiny_step`` is PR 44's too
     # (``BlockDiffusion.allowed`` by one code a position).  PR 45 moved the
     # four that hold a router over bf16 rows (three bf16 products over the
-    # split weights, ``parallel/moe.py::_rows_dot``): they are that PR's
-    # text.  ``moe_ffn_share_1_6`` is the share (1, 6) of the same layer:
-    # 64 slots are one chunk since PR 39.
+    # split weights, ``parallel/moe.py::_rows_dot``), and PR 48 moved them
+    # again (the k chosen scores read, and their cotangent written, by a
+    # compare and not by a gather and a scatter, ``_chosen``): they are PR
+    # 48's text.  ``moe_ffn_share_1_6`` is the share (1, 6) of the same
+    # layer: 64 slots are one chunk since PR 39.
     "olmoe_tiny_step":
-    "f40aeee7bbc7a2319322d0e265ef7f6c753bcce30c9b6819e8a48c14b20b520c",
+    "f207566475ff18a27f783a5c9534a4f9b89eec2f285b233b86f24ff384e1a295",
     "moe_ffn_all_held":
-    "0243b7b05474ef8842aca6e04f4b42b53e8246f6ab654a16c4afb7e4886007ae",
+    "b984e2cd4967e02c21bb3e7a29f1851f46b1b7ecc9af285de185d6c78b212a93",
     "moe_ffn_share_1_6":
-    "dcdd670a4e5e94ee0c4e896832a6c0abca916f22e4f6c1b6b6db3092851e2253",
+    "892af5ed1f0b554332c69f8306487e2cafd1f561d76537a250d650ab29cf91a2",
     "sdar_tiny_step":
-    "b680afb5a8d812b1e0f01cd824bab2910ede866f619d74caf5e41067d5dac2d4",
+    "b75516136f52cd075192c42f2dfd13486342c7747d8072ad24613fe0e5e5394e",
     "blockdiff_kernel_call":
     "2579f64f8c25cb3b01701d988c9aedd3970d34b4c8d1f1bcff17b110c7ee59b8",
     "blockdiff_forward_call":
     "2cf36cf3da9d62dacb1dfe9ecede7018afcee1b43cdb825ddaa1292216f0b4a5",
-    # The lowered loss and gradients in float32 on PR 45's parent (9016782),
-    # before ``_route`` learnt the three-pass product: rows that are no
-    # bfloat16 array run the line it had, so a float32 model (every
-    # configuration's float32 twin, the references' programs) lowers to the
-    # parent's text.
+    # The lowered loss and gradients in float32, PR 48's text: rows that
+    # are no bfloat16 array still run the highest-precision product they ran
+    # before PR 45 (a float32 model is every configuration's float32 twin and
+    # the references' programs), and since PR 48 every router reads its
+    # chosen scores through ``_chosen``, so these rows moved with the bf16
+    # ones; ``test_float32_numbers_are_the_gathers_and_the_scatters`` holds
+    # their numbers to the line that went.
     "float32/moe_ffn_softmax":
-    "3641c0a5bb1c210353d534b3f102845510abca2419fd00cefe0b8471ae8384fe",
+    "faa377faec2b8acfd822dee4814944c0dddc1f5f3de5fd3d51bee652858b72b4",
     "float32/moe_ffn_sigmoid_bias":
-    "0477d753849994f0d5752d73fac09ec75a7049c1dc46392cfa903345bdb36c2b",
+    "90f5bbed449cc92b623d6f4f8f927b0313a6e61ba460fbf464c331471b365854",
     "float32/moe_ffn_router_input":
-    "e833980ee850d3a4c06dfc83fb5868c984cdba9bfe9722926a9213ed43eed144",
+    "e8428a11f7c960cdc06794c96fe7615447647adea5644c2e9a7eb4b56b68f61e",
     "float32/olmoe_tiny_step":
-    "f90360f3dee26f7df01f83d8c6d88adee7a6617429d83e3445f2e1e945fa0794",
+    "0b7d0ff036f278367134135cc929f9ae5530b0bc1b2152b6191f24f99ead337f",
     "float32/smallthinker_tiny_step":
-    "b7bba8f89fd5609883591adae556204aeb218f99caa13690031a4305e29463d2",
+    "be9e351bc5aef0b3c569ec990a9f6dde74a4c8c5b108ae15e9cef29968bb7850",
     "float32/lfm2_tiny_step":
-    "65255ffd7d08e9b5e389e9a0a6985c59328d50a7edff86573f843e0d6b2e734c",
+    "8922ad63109ea0d89831c6b2d8d8007265e6011d07a96d70bbd58b2694301683",
     "float32/nemotron_tiny_step":
-    "aea9a3902d531dabfba256441ce4022e3ac776ade995fd3baf55518f2de842f3",
+    "e4f3e9afe098dfdcb6254c5a3ebf19b9c16a0e67535f83b56fcd44fe8cae96c3",
     # sha1 over the sorted (path, shape) pairs of the parameter tree that
     # each transformer configuration of the benchmark builds at a tiny size,
     # taken on the parent of PR 41 (3cce4b3): a layer of every kind they use.
@@ -100,14 +104,14 @@ PINS = {
     # others are PR 47's own text: the same call at latent attention's
     # widths (keys of 192 over values of 128, 3 heads), test_joyai.py's tiny
     # model (latent attention, the dense layer, two sparse ones, the
-    # prediction module), loss and gradients in float32 on 2 x 20 tokens, and
-    # its parameter tree.
+    # prediction module), loss and gradients in float32 on 2 x 20 tokens
+    # (PR 48's text since: it holds three routers), and its parameter tree.
     "causal_kernel_call":
     "24810e80ba11de23dfe0ce8a38df66b9c7dd61d850461033f693e052d8d2973e",
     "latent_kernel_call":
     "80503e498bf54f7a33dc4467687a35edea933a73117ea368ecbbf0fb6aa97ccc",
     "float32/joyai_tiny_step":
-    "f380ca403f239979494b1e26168e5e627009993a578e21674753a8406373c3fc",
+    "332323402570e391ea8eb4cfff042a6aa505226726ab4db589aa9a0ae6b6c4f0",
     "tree/joyai-llm-flash": "e2bc7c473a1f641f1f61e76d8b6ac6dadbacba87",
 }
 
@@ -130,18 +134,27 @@ def abstract(model, like):
         model.init, jax.random.PRNGKey(0), like)["params"])
 
 
+def lowered(program):
+    """The lowered text of a program: a function and its operands' shapes."""
+    fn, args = program
+    return jax.jit(fn).lower(*args).as_text()
+
+
+def step_program(loss, *args):
+    """A loss and its gradients, auxiliary output kept, and its operands."""
+    return jax.value_and_grad(loss, has_aux=True), args
+
+
 def step_text(loss, *args):
-    """The lowered text of a loss and its gradients, auxiliary output kept."""
-    return jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
-        *args).as_text()
+    return lowered(step_program(loss, *args))
 
 
-def moe_ffn_text(rows, held=None, gradients=5, **options):
-    """The lowered gradients of ``moe_ffn``'s sum, and of its balancing loss,
-    at [2, 16, 64] rows of dtype ``rows`` x 8 experts of width 32, k = 2, by
-    the operands' first ``gradients``.  ``options`` go to the layer; a value
-    that names an operand (``"bias"``, ``"routed_by"``, ``"x"``) is that
-    operand."""
+def moe_ffn_program(rows, held=None, gradients=5, **options):
+    """The gradients of ``moe_ffn``'s sum, and of its balancing loss, at [2,
+    16, 64] rows of dtype ``rows`` x 8 experts of width 32, k = 2, by the
+    operands' first ``gradients``, and those operands.  ``options`` go to
+    the layer; a value that names an operand (``"bias"``, ``"routed_by"``,
+    ``"x"``) is that operand."""
     from horovod_tpu.parallel.moe import moe_ffn
 
     d, f, e, k = 64, 32, 8, 2
@@ -165,8 +178,11 @@ def moe_ffn_text(rows, held=None, gradients=5, **options):
             total = total + jnp.sum(stats.router_z_loss)
         return total
 
-    return jax.jit(jax.grad(loss, argnums=tuple(range(gradients)))).lower(
-        *args).as_text()
+    return jax.grad(loss, argnums=tuple(range(gradients))), args
+
+
+def moe_ffn_text(rows, **options):
+    return lowered(moe_ffn_program(rows, **options))
 
 
 def test_bert_large_lowers_to_what_the_parent_lowered_to():
@@ -255,40 +271,90 @@ def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
         == PINS["moe_ffn_share_1_6"]
 
 
-def float32_text(which):
-    """The lowered loss and gradients of ``which`` in float32."""
+def float32_program(which):
+    """The loss and gradients of ``which`` in float32, and its operands'
+    shapes."""
     tokens = shape((2, 32), jnp.int32)
     if which.startswith("moe_ffn"):
-        return moe_ffn_text(jnp.float32, gradients=7, dtype=jnp.float32, **{
-            "moe_ffn_softmax": {},
-            "moe_ffn_sigmoid_bias": dict(
-                scoring="sigmoid", bias="bias", norm_topk_prob=True,
-                scale=2.5),
-            "moe_ffn_router_input": dict(router_input="routed_by")}[which])
+        return moe_ffn_program(
+            jnp.float32, gradients=7, dtype=jnp.float32, **{
+                "moe_ffn_softmax": {},
+                "moe_ffn_sigmoid_bias": dict(
+                    scoring="sigmoid", bias="bias", norm_topk_prob=True,
+                    scale=2.5),
+                "moe_ffn_router_input": dict(router_input="routed_by")}[which])
     if which == "olmoe_tiny_step":
         model, sizes = test_olmoe.tiny_model(jnp.float32)
-        return step_text(test_olmoe.program_loss(model, sizes),
-                         abstract(model, tokens), tokens)
+        return step_program(test_olmoe.program_loss(model, sizes),
+                            abstract(model, tokens), tokens)
     if which == "smallthinker_tiny_step":
         model, sizes = test_smallthinker.tiny_model(jnp.float32)
-        return step_text(test_smallthinker.program_loss(model, sizes),
-                         abstract(model, tokens), {"tokens": tokens})
+        return step_program(test_smallthinker.program_loss(model, sizes),
+                            abstract(model, tokens), {"tokens": tokens})
     if which == "lfm2_tiny_step":
         model, sizes = test_lfm2.tiny_model(jnp.float32)
         aux = jax.eval_shape(lambda: test_lfm2.counters(sizes))
-        return step_text(test_lfm2.program_loss(model, sizes),
-                         abstract(model, tokens), aux, {"tokens": tokens})
+        return step_program(test_lfm2.program_loss(model, sizes),
+                            abstract(model, tokens), aux, {"tokens": tokens})
     tiny = test_joyai if which == "joyai_tiny_step" else test_nemotron
     model, sizes = tiny.tiny_model(jnp.float32)
     few = shape((2, sizes["sequence_length"]), jnp.int32)
     aux = jax.eval_shape(lambda: tiny.zero_aux(sizes))
-    return step_text(tiny.program_loss(model, sizes),
-                     abstract(model, few), aux, {"tokens": few})
+    return step_program(tiny.program_loss(model, sizes),
+                        abstract(model, few), aux, {"tokens": few})
 
 
 @pytest.mark.parametrize("which", names("float32/"))
-def test_float32_rows_lower_to_the_parents_text(which):
-    assert digest(float32_text(which)) == PINS["float32/" + which]
+def test_float32_rows_lower_to_the_pinned_text(which):
+    assert digest(lowered(float32_program(which))) == PINS["float32/" + which]
+
+
+def filled(shapes, seed=0):
+    """Values for a program's operands: floats at normal(0.3), whole numbers
+    (tokens, counters) below 32, the smallest vocabulary here being 128."""
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return tree.unflatten([
+        0.3 * jax.random.normal(key, leaf.shape, leaf.dtype)
+        if jnp.issubdtype(leaf.dtype, jnp.floating)
+        else jax.random.randint(key, leaf.shape, 0, 32, leaf.dtype)
+        for key, leaf in zip(keys, leaves)])
+
+
+@pytest.mark.parametrize("which", names("float32/"))
+def test_float32_numbers_are_the_gathers_and_the_scatters(which, monkeypatch):
+    """PR 48 moved the text of every program that holds a router and none of
+    its numbers: the loss (or the layer's sum), what rides beside it and
+    every gradient equal those of the same program with the chosen scores
+    read by ``take_along_axis``, whose transpose is the scatter, as the
+    parent read them, to 1e-5 of their norm.  That is float32's rounding
+    under another fusion and nothing else: the same experts are chosen, op
+    by op (``jax.disable_jit``) the two agree to the bit, and jitted on a
+    CPU the losses do and the gradients lie 1e-7 to 7e-7 apart, 1.8e-6 in
+    SmallThinker's routers and 2.9e-6 in Nemotron's last, whose gradient is
+    a thousandth of its neighbours'."""
+    from horovod_tpu.parallel import moe
+
+    fn, shapes = float32_program(which)
+    args = filled(shapes)
+
+    def run():
+        # A function of its own a side: jit keeps its traces by function.
+        program = jax.jit(lambda *a: fn(*a))
+        return program(*args), program.lower(*args).as_text().count("scatter")
+
+    new, new_scatters = run()
+    monkeypatch.setattr(moe, "_chosen", test_router_product.gathered)
+    old, old_scatters = run()
+    assert old_scatters > new_scatters
+    got, want = (jax.tree_util.tree_leaves(out) for out in (new, old))
+    assert len(got) == len(want) > 3
+    for a, b in zip(got, want):
+        if jnp.issubdtype(a.dtype, jnp.floating):
+            assert float(jnp.linalg.norm((a - b).ravel())) \
+                <= 1e-5 * float(jnp.linalg.norm(b.ravel()))
+        else:
+            assert (a == b).all()
 
 
 def small_presets():

@@ -309,3 +309,111 @@ def test_the_bf16_path_holds_no_product_at_the_default_precision(name):
     for eqn in _dot_generals(jax.make_jaxpr(jax.grad(
             old, argnums=(1,), has_aux=True))(*operands).jaxpr):
         assert eqn.params["precision"] is not None
+
+
+# -- the k chosen scores, read and differentiated by a compare (PR 48) --------
+
+# (k, E) of the six sparse cells' routers.
+CHOSEN_SHAPES = {"nemotron": (22, 512), "joyai": (8, 256), "lfm2": (4, 32),
+                 "sdar": (8, 128), "smallthinker": (6, 64), "olmoe": (8, 64)}
+
+
+def gathered(probs, experts):
+    """The line ``_chosen`` took the place of; its transpose is a scatter."""
+    return jnp.take_along_axis(probs, experts, axis=-1)
+
+
+def chosen_case(cell, kind, rows, n=48, d=32):
+    """(rows [n, d] of dtype ``rows``, router, bias or None, k, what
+    ``_route`` takes besides) at a cell's k of E: ``softmax`` without a bias
+    and the top k's sum left alone, ``sigmoid_bias`` renormalised."""
+    k, experts = CHOSEN_SHAPES[cell]
+    ks = jax.random.split(jax.random.PRNGKey(k + experts), 3)
+    x = jax.random.normal(ks[0], (n, d)).astype(rows)
+    router = 0.3 * jax.random.normal(ks[1], (d, experts))
+    if kind == "softmax":
+        return x, router, None, k, {}
+    return x, router, 0.05 * jax.random.normal(ks[2], (experts,)), k, dict(
+        scoring="sigmoid", norm_topk_prob=True, scale=2.5)
+
+
+@pytest.mark.parametrize("rows", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["softmax", "sigmoid_bias"])
+@pytest.mark.parametrize("cell", list(CHOSEN_SHAPES))
+def test_chosen_scores_are_the_gathers_to_the_bit(cell, kind, rows,
+                                                  monkeypatch):
+    """``_chosen`` and its cotangent equal ``take_along_axis`` and its
+    scatter with ``==``, on the scores of float32 rows and of a bf16 stream;
+    so do the weights of ``_route`` and, op by op, the gradients of the rows
+    and the router through it."""
+    from horovod_tpu.parallel import moe
+
+    x, router, bias, k, options = chosen_case(cell, kind, rows)
+    logits = jnp.dot(x.astype(jnp.float32), router,
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits) if kind == "softmax" \
+        else jax.nn.sigmoid(logits)
+    experts = lax.top_k(probs if bias is None else probs + bias, k)[1]
+    g = jax.random.normal(jax.random.PRNGKey(7), experts.shape)
+    got, pull = jax.vjp(lambda p: moe._chosen(p, experts), probs)
+    want, pull_gathered = jax.vjp(lambda p: gathered(p, experts), probs)
+    assert got.dtype == want.dtype and (got == want).all()
+    (d_got,), (d_want,) = pull(g), pull_gathered(g)
+    assert d_got.dtype == d_want.dtype and (d_got == d_want).all()
+    assert (np.asarray(d_got) != 0).sum() == experts.size
+
+    def loss(x, router):
+        weights, chosen, *_ = moe._route(x, router, k, bias=bias, **options)
+        return jnp.sum(weights ** 2 * (1 + chosen % 3)), (weights, chosen)
+
+    with jax.disable_jit():
+        grad = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        new = grad(x, router)
+        monkeypatch.setattr(moe, "_chosen", gathered)
+        old = grad(x, router)
+    for a, b in zip(jax.tree_util.tree_leaves(new),
+                    jax.tree_util.tree_leaves(old)):
+        assert a.dtype == b.dtype and (a == b).all()
+
+
+@pytest.mark.parametrize("rows", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["softmax", "sigmoid_bias"])
+def test_the_routers_gradient_holds_no_gather_and_no_scatter(kind, rows,
+                                                             monkeypatch):
+    """The lowered gradient of ``_route`` at Nemotron's 22 of 512: not one
+    gather or scatter of any kind, where the line before it had both."""
+    from horovod_tpu.parallel import moe
+
+    x, router, bias, k, options = chosen_case("nemotron", kind, rows)
+
+    def text():
+        def loss(x, router):
+            weights, _, _, balance, z = moe._route(x, router, k, bias=bias,
+                                                   **options)
+            return jnp.sum(weights ** 2) + balance + z
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            x, router).as_text()
+
+    new = text()
+    assert "gather" not in new and "scatter" not in new
+    assert "top_k" in new
+    monkeypatch.setattr(moe, "_chosen", gathered)
+    old = text()
+    assert "gather" in old and "scatter" in old
+
+
+@pytest.mark.parametrize("cell", list(CHOSEN_SHAPES))
+def test_the_backward_keeps_the_experts_alone(cell):
+    """What ``_chosen``'s cotangent is computed from: the ``[n, k]`` indices
+    and nothing else: no mask of n k E elements waits from forward to
+    backward (92 MB a layer at Nemotron's sizes)."""
+    from horovod_tpu.parallel import moe
+
+    n = 48
+    k, n_experts = CHOSEN_SHAPES[cell]
+    probs = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(1),
+                                             (n, n_experts)))
+    experts = lax.top_k(probs, k)[1]
+    kept = jax.tree_util.tree_leaves(
+        jax.vjp(lambda p: moe._chosen(p, experts), probs)[1])
+    assert [(a.shape, a.dtype) for a in kept] == [((n, k), jnp.int32)]
