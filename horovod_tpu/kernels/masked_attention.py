@@ -206,6 +206,28 @@ def attention(q, k, v, rule, *, interpret: bool = False):
         return out.transpose(0, 2, 1, 3)
 
 
+def attention_hsd(q, k, v, rule):
+    """:func:`attention` for a caller that builds its operands in the
+    kernels' layout: ``q [b, h, s, d]`` already scaled by ``d ** -0.5``,
+    ``k [b, h_kv, s, d]`` and ``v [b, h_kv, s, dv]``.  Returns
+    ``[b, h, s, dv]``: no copy on either side of the kernels."""
+    _, _, s, d = q.shape
+    if not takes(rule, s, d, v.shape[3]):
+        raise ValueError(f"no kernel under {rule} for {s} positions, head "
+                         f"width {d} over values of {v.shape[3]}")
+    return _attend(q, k, v, rule, False)
+
+
+def _probabilities(scores, rule, dtype):
+    """The softmax of ``scores [..., s, s]`` over the keys ``rule`` allows,
+    the mask from iota comparisons."""
+    s = scores.shape[-1]
+    mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
+                        lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
+    scores = jnp.where(mask[(None,) * (scores.ndim - 2)], scores, -jnp.inf)
+    return jax.nn.softmax(scores, axis=-1).astype(dtype)
+
+
 def einsum(q, k, v, rule):
     """:func:`attention` through the einsum, KV heads grouped, the mask from
     iota comparisons: below the kernel's smallest shape, and off the TPU."""
@@ -215,9 +237,19 @@ def einsum(q, k, v, rule):
         q = q.reshape(b, s, h_kv, h // h_kv, dh)
         scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
                             preferred_element_type=jnp.float32) * dh ** -0.5
-        mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
-                            lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
-        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bngqk,bknd->bqngd", probs, v) \
+        return jnp.einsum("bngqk,bknd->bqngd",
+                          _probabilities(scores, rule, q.dtype), v) \
             .reshape(b, s, h, v.shape[3])
+
+
+def einsum_hsd(q, k, v, rule):
+    """:func:`attention_hsd` through the einsum (``h_kv = h``): off the TPU,
+    and for shapes the kernel does not take.  ``q`` comes scaled, as the
+    kernels take it, where :func:`einsum` scales the fp32 scores: in bf16 the
+    two round at different points and agree to bf16's rounding, in float32
+    to float32's."""
+    with scope("attn.einsum"):
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+        return jnp.einsum("bhqk,bhkd->bhqd",
+                          _probabilities(scores, rule, q.dtype), v)

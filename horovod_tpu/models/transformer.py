@@ -384,9 +384,11 @@ def tiny_config(**overrides) -> TransformerConfig:
         d_ff=64, max_len=64, causal=True), **overrides})
 
 
-def _dense(cfg: TransformerConfig, features: int, kernel_spec, name: str):
-    """Dense with a TP partitioning annotation on the kernel."""
-    return nn.Dense(
+def _dense(cfg: TransformerConfig, features: int, kernel_spec, name: str,
+           cls=nn.Dense):
+    """Dense with a TP partitioning annotation on the kernel (``cls``: a
+    subclass that does something else with the same parameters)."""
+    return cls(
         features, dtype=cfg.dtype, param_dtype=jnp.float32, name=name,
         use_bias=cfg.use_bias,
         kernel_init=nn.with_partitioning(
@@ -403,15 +405,20 @@ def _norm(cfg: TransformerConfig, name: str):
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
+def _rope_angles(s: int, d: int, theta: float, positions=None):
+    """``[s, d / 2]`` in fp32: position times frequency, the pair ``i`` of a
+    head of ``d`` at ``theta ** (-2 i / d)``."""
+    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    return positions.astype(jnp.float32)[:, None] * inv_freq[None]
+
+
 def _rope(x, theta: float, positions=None):
     """Rotary positions on ``[b, s, h, d]``, halves rotated as in
     ``transformers`` (``x*cos + rotate_half(x)*sin``), in fp32.  ``positions``
     ``[s]``: each position's index (default ``0..s-1``)."""
-    s, d = x.shape[1], x.shape[3]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    if positions is None:
-        positions = jnp.arange(s, dtype=jnp.float32)
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None]
+    angles = _rope_angles(x.shape[1], x.shape[3], theta, positions)
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -354,6 +354,122 @@ def test_interleaved_rope_is_complex_multiplication():
     assert rel_err(_rope(q, theta), want_q) > 0.1
 
 
+def parents_block(p, x, cfg):
+    """``LatentAttention`` as it ran until PR 49, written out: the
+    projections flat, the slice, the evens-then-odds copy of the rows,
+    ``_rope``, the two concatenations, then the scale and the transposes of
+    ``masked_attention.attention``.  Returns the block's output and q, k, v
+    as the kernels received them, ``[b, h, s, .]``."""
+    from horovod_tpu.kernels import masked_attention
+    from horovod_tpu.models.deepseek import _pairs_first
+    from horovod_tpu.models.transformer import _rope
+
+    b, s, _ = x.shape
+    h, latent = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+
+    def dense(name, rows):
+        y = rows.astype(cfg.dtype) @ p[name]["kernel"].astype(cfg.dtype)
+        return y + p[name]["bias"].astype(cfg.dtype) if cfg.use_bias else y
+
+    def norm(name, rows):
+        return ref._rms_norm(rows.astype(jnp.float32), p[name]["scale"],
+                             cfg.norm_eps).astype(cfg.dtype)
+
+    down = dense("kv_a", x)
+    q = dense("q_b", norm("q_a_norm", dense("q_a", x))) \
+        .reshape(b, s, h, nope + rope)
+    kv = dense("kv_b", norm("kv_a_norm", down[..., :latent])) \
+        .reshape(b, s, h, nope + dv)
+    q_r = _rope(_pairs_first(q[..., nope:]), cfg.rope_theta)
+    k_r = _rope(_pairs_first(down[..., None, latent:]), cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r, (b, s, h, rope))], axis=-1)
+    v = kv[..., nope:]
+    out = masked_attention.einsum(q, k, v, masked_attention.Causal())
+    hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+    return dense("out", out.reshape(b, s, h * dv)), (
+        hsd(q * jnp.asarray((nope + rope) ** -0.5, q.dtype)), hsd(k), hsd(v))
+
+
+def latent_block(model, params, x, monkeypatch):
+    """The block's output and q, k, v as it hands them to the attention
+    kernels' entry (off the TPU, the einsum in their layout)."""
+    from horovod_tpu.kernels import masked_attention
+    from horovod_tpu.models.deepseek import LatentAttention
+
+    taken = []
+    einsum = masked_attention.einsum_hsd
+
+    def take(q, k, v, rule):
+        taken.append((q, k, v))
+        return einsum(q, k, v, rule)
+
+    monkeypatch.setattr(masked_attention, "einsum_hsd", take)
+    out = LatentAttention(model.cfg).apply({"params": params}, x)
+    return out, taken[0]
+
+
+@pytest.mark.parametrize("what", ["float32-operands", "float32-gradients",
+                                  "float32-biases-gradients",
+                                  "bfloat16-operands"])
+def test_the_operands_built_in_the_kernels_layout_are_the_parents(
+        what, monkeypatch):
+    """PR 49 moved the interleave from the rows to the weights' rotary
+    columns, the products' outputs into ``[b, h, s, .]`` and the rotation,
+    the scale and ``[k_nope ; k_r]`` into one pass: q, k and v as the
+    kernels receive them, the block's output and the gradients of ``q_b``,
+    ``kv_a``, ``kv_b`` and of the block's input are the parent's, in float32
+    to its rounding and in bf16 **to the bit** (the same values rounded at
+    the same points: q's rotary part once behind the rotation and once
+    behind the scale, nothing below bf16), from parameters in the published
+    layout; and with biases on the five projections (``use_bias``, which no
+    configuration sets), permuted and cut with their columns."""
+    from horovod_tpu.models.deepseek import LatentAttention
+
+    dtype = jnp.bfloat16 if what.startswith("bfloat16") else jnp.float32
+    model, _ = tiny_model(dtype)
+    params = seeded(model)["layer_0"]["attn"]
+    if "biases" in what:
+        model = model.clone(cfg=dataclasses.replace(model.cfg, use_bias=True))
+        params = {name: {**leaves, "bias": 0.3 * jax.random.normal(
+            jax.random.PRNGKey(len(name)), leaves["kernel"].shape[1:])}
+            if "kernel" in leaves else leaves
+            for name, leaves in params.items()}
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 20, 32))
+    g = jax.random.normal(jax.random.PRNGKey(3), (2, 20, 32))
+    with jax.default_matmul_precision("highest"):
+        if what.endswith("operands"):
+            got, operands = latent_block(model, params, x, monkeypatch)
+            want, parents = parents_block(params, x, model.cfg)
+            for a, b in zip(operands, parents):
+                assert a.dtype == b.dtype == dtype and a.shape == b.shape
+                if dtype == jnp.bfloat16:
+                    np.testing.assert_array_equal(
+                        np.asarray(a, np.float32), np.asarray(b, np.float32))
+                else:
+                    np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+            # Behind the operands the einsum off the TPU takes q scaled
+            # where the parent's scaled the scores: bf16's rounding apart.
+            assert got.dtype == want.dtype == dtype
+            assert rel_err(got, want) < (1e-6 if dtype == jnp.float32
+                                         else 2e-2)
+            return
+        def through(block):
+            return jax.grad(lambda p, x: jnp.sum(block(p, x) * g),
+                            argnums=(0, 1))(params, x)
+
+        got = through(lambda p, x: LatentAttention(model.cfg).apply(
+            {"params": p}, x))
+        want = through(lambda p, x: parents_block(p, x, model.cfg)[0])
+    for name in ("q_b", "kv_a", "kv_b", "q_a", "out"):
+        for leaf in got[0][name]:
+            assert rel_err(got[0][name][leaf], want[0][name][leaf]) < 1e-6
+    assert rel_err(got[1], want[1]) < 1e-6
+
+
 def test_latent_attention_refuses_what_it_does_not_build():
     from horovod_tpu.models.transformer import Transformer
 

@@ -105,13 +105,17 @@ PINS = {
     # widths (keys of 192 over values of 128, 3 heads), test_joyai.py's tiny
     # model (latent attention, the dense layer, two sparse ones, the
     # prediction module), loss and gradients in float32 on 2 x 20 tokens
-    # (PR 48's text since: it holds three routers), and its parameter tree.
+    # (PR 48's text since: it holds three routers; PR 49's since: latent
+    # attention builds q, k and v in the kernels' layout, the interleave on
+    # the weights' columns: ``test_joyai.py`` holds its numbers to the
+    # parent's block written out), and its parameter tree, which PR 49 left
+    # as it was: the parameters keep the published layout.
     "causal_kernel_call":
     "24810e80ba11de23dfe0ce8a38df66b9c7dd61d850461033f693e052d8d2973e",
     "latent_kernel_call":
     "80503e498bf54f7a33dc4467687a35edea933a73117ea368ecbbf0fb6aa97ccc",
     "float32/joyai_tiny_step":
-    "332323402570e391ea8eb4cfff042a6aa505226726ab4db589aa9a0ae6b6c4f0",
+    "529388bca1069ec7110e721d773f271a50f10bdaaf5f98cd9e69f1f111cfc62f",
     "tree/joyai-llm-flash": "e2bc7c473a1f641f1f61e76d8b6ac6dadbacba87",
 }
 
@@ -307,6 +311,35 @@ def float32_program(which):
 @pytest.mark.parametrize("which", names("float32/"))
 def test_float32_rows_lower_to_the_pinned_text(which):
     assert digest(lowered(float32_program(which))) == PINS["float32/" + which]
+
+
+def test_the_interleave_is_on_the_weights_and_not_on_the_rows():
+    """PR 49: a latent block's evens-then-odds permutation is a stride-2
+    read of ``q_b``'s and ``kv_a``'s rotary columns (``[l, h, d]`` and
+    ``[d, l]``: rank 3 and 2), in the forward pass, from parameters in the
+    published layout; the lowered block and its gradients hold no stride-2
+    slice of an activation (rank 4 a head at a time, rank 3 flat), so the
+    copy of the rows cannot come back unnoticed."""
+    import re
+
+    from horovod_tpu.models.deepseek import LatentAttention
+
+    model, _ = test_joyai.tiny_model(jnp.bfloat16)
+    cfg, x = model.cfg, shape((2, 20, 32), jnp.float32)
+    layer = LatentAttention(cfg)
+    params = abstract(layer, x)
+    text = lowered((jax.grad(
+        lambda p, x: jnp.sum(layer.apply({"params": p}, x)
+                             .astype(jnp.float32)), argnums=(0, 1)),
+        (params, x)))
+    strided = re.findall(
+        r"stablehlo\.slice %\S+ \[[^\]]*\d+:\d+:2[^\]]*\] : \(tensor<([\dx]+)x\w+>\)",
+        text)
+    operands = sorted({tuple(map(int, dims.split("x"))) for dims in strided})
+    rope = cfg.qk_rope_head_dim
+    assert operands == [(cfg.q_lora_rank, cfg.num_heads, rope),
+                        (cfg.d_model, rope)], operands
+    assert len(strided) == 4            # evens and odds of each, forward only
 
 
 def filled(shapes, seed=0):

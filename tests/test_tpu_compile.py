@@ -547,6 +547,34 @@ def test_masked_attention_compiles_at_joyais_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def test_mla_operands_compile_at_joyais_widths(one_chip, no_compile_cache):
+    """One sequence of 8192 positions, 32 heads of 128 + 64: the two kernels
+    that finish latent attention's q and k in the attention kernels' layout
+    (``kernels/mla_operands.py``), forward and backward, the query's two
+    products flat."""
+    from horovod_tpu.kernels import mla_operands as mo
+
+    assert mo.takes(8192, 32, 128, 64)
+    shapes = {"q_nope": (1, 8192, 32 * 128), "q_rope": (1, 8192, 32 * 64),
+              "k_nope": (1, 32, 8192, 128), "k_r": (1, 1, 8192, 64)}
+    wide, table = (1, 32, 8192, 192), (8192, 64)
+
+    def both(q_nope, q_rope, k_nope, k_r, cos, sin, dq, dk):
+        out, back = jax.vjp(
+            lambda *a: mo._operands(*a, cos, sin, 192 ** -0.5, False),
+            q_nope, q_rope, k_nope, k_r)
+        return out, back((dq, dk))
+
+    args = [_shape(shape, jnp.bfloat16, one_chip)
+            for shape in (*shapes.values(), wide, wide)]
+    args[4:4] = [_shape(table, jnp.float32, one_chip)] * 2
+    compiled = jax.jit(both).lower(*args).compile()
+    kernels = set(re.findall(r"%(hvd\w*?)[.\d]* =", compiled.as_text()))
+    assert kernels == {mo.FWD_NAME, mo.BWD_NAME}, kernels
+    assert [tuple(x.shape) for x in jax.tree_util.tree_leaves(
+        jax.eval_shape(both, *args))] == [wide, wide, *shapes.values()]
+
+
 def test_joyais_step_compiles_and_fits_the_chip(topo, no_compile_cache,
                                                 monkeypatch,
                                                 record_property):
@@ -601,8 +629,11 @@ def test_joyais_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
     assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
-                       "hvd_rows_to_tokens"}, kernels
-    assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 6
+                       "hvd_rows_to_tokens", "hvd_mla_operands_fwd",
+                       "hvd_mla_operands_bwd"}, kernels
+    for kernel in ("splash_mha_fwd_residuals", "hvd_mla_operands_fwd",
+                   "hvd_mla_operands_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == 6, kernel
     assert "32,8192,8192" not in text            # the scores, any layout
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -614,15 +645,16 @@ def test_joyais_step_compiles_and_fits_the_chip(topo, no_compile_cache,
                     round(mem.temp_size_in_bytes / 2 ** 30, 3))
     assert 14.0 < gib < 15.75, gib
     # The file states what the compiler counted when the configuration was
-    # sized (PR 47: 15.08 GiB).  A program that changed since may take a
-    # little less (15.03 since PR 48's router keeps no gather's operands)
-    # and never more: the file is the benchmark's, which only a benchmark PR
-    # restates.
+    # sized (PR 47: 15.08 GiB).  A program that changed since may take less
+    # (15.03 since PR 48's router keeps no gather's operands; 14.88 since
+    # PR 49 makes the output projection's copy of the attention's output
+    # again in the backward pass and keeps it no longer) and never more: the
+    # file is the benchmark's, which only a benchmark PR restates.
     with open(os.path.join(REPO_ROOT, "chip_bench/configs",
                            "joyai-llm-flash.json")) as f:
         stated = float(re.search(r"takes ([\d.]+) GiB with 16 experts held",
                                  json.load(f)["assumed"]["fit"]).group(1))
-    assert stated - 0.1 < gib < stated + 0.005, (gib, stated)
+    assert stated - 0.3 < gib < stated + 0.005, (gib, stated)
 
 
 def test_joyais_float32_twin_compiles(one_chip, no_compile_cache,
