@@ -253,6 +253,10 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                  "inside a window) and of kind=global (the model's mask; the "
                  "prediction modules' blocks behind the stack among them), "
                  "every query head counted once"),
+    "gdn_chunks_per_step": (
+        "gauge", "chunks of the gated delta rule a step runs, one a value "
+                 "head, chunk of positions and Gated DeltaNet layer, from "
+                 "the shapes (models/transformer.py::publish_gated_delta)"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

@@ -515,11 +515,12 @@ SCOPES = (
     "embed", "norm", "ffn", "head",
     "attn.proj", "attn.norm", "attn.rope", "attn.layout", "attn.latent",
     "attn.einsum", "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
-    "attn.causal", "attn.window", "attn.blockdiff",
+    "attn.causal", "attn.window", "attn.blockdiff", "attn.gate",
     "conv.proj", "conv.gate",
     "ssm.proj", "ssm.conv", "ssm.scan", "ssm.norm",
+    "gdn.proj", "gdn.conv", "gdn.gates", "gdn.rule", "gdn.norm",
     "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
-    "moe.latent", "moe.shared", "mtp.proj",
+    "moe.latent", "moe.shared", "moe.shared_gate", "mtp.proj",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",
 )

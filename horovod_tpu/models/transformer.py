@@ -1,5 +1,5 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2, Nemotron-H and JoyAI-LLM-Flash presets.
+LFM2, Nemotron-H, JoyAI-LLM-Flash and Qwen3-Next presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -37,7 +37,11 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   ``smallthinker_21b_a3b_config()``, LFM2-8B-A1B ``lfm2_8b_a1b_config()``,
   Nemotron-3-Super-120B-A12B ``nemotron_3_super_config()`` and
   JoyAI-LLM-Flash ``joyai_llm_flash_config()`` over the same
-  ``Transformer``, their expert layer
+  ``Transformer`` (and Qwen3-Next-80B-A3B ``qwen3_next_80b_a3b_config()``:
+  Gated DeltaNet mixers of ``models/gated_delta.py`` over
+  ``kernels/gated_delta.py``, a gate on the attention's output, rotary
+  positions over a share of a head, norms whose scale is ``1 + w``, a gated
+  shared expert), their expert layer
   :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``), their masks
   that are rules ``kernels/masked_attention.py``'s.
 """
@@ -73,7 +77,8 @@ class LayerKind(NamedTuple):
     a layer without them carries no position at all.  ``mixer``: what mixes
     the tokens, ``"attention"``, ``"conv"`` (:class:`ShortConv`, which
     takes neither window nor positions), ``"mamba2"``
-    (``models/mamba2.py``, nor that) or ``"none"``: the layer is its FFN
+    (``models/mamba2.py``, nor that), ``"gated_delta"``
+    (``models/gated_delta.py``, nor that) or ``"none"``: the layer is its FFN
     alone, under one norm.  ``ffn``: None, the configuration's ``ffn``;
     ``"dense"``, the gated dense FFN of width ``d_ff_dense`` (a sparse
     model's leading dense layers); ``"moe"``; or ``"none"``: the layer is
@@ -203,6 +208,27 @@ class TransformerConfig:
     # norm of its own and the model's head; the model then returns (logits,
     # (module 1's logits, ...)).
     mtp_modules: int = 0
+    # Qwen3-Next's parts.  norm_offset: an RMSNorm's scale is ``1 + w``, w
+    # zero at the start (the block's norms, ln_f and the per-head QK-norm).
+    # attention_gate: the query projection is twice as wide, a head's second
+    # half a sigmoid gate on that head's output in front of the output
+    # projection.  partial_rotary_factor: the share of a head's width, from
+    # its start, that the rotary positions turn; the rest carries none.
+    # shared_expert_gate: the shared expert's output times sigmoid(x . w_g).
+    norm_offset: bool = False
+    attention_gate: bool = False
+    partial_rotary_factor: float = 1.0
+    shared_expert_gate: bool = False
+    # The layers of kind mixer="gated_delta" (models/gated_delta.py):
+    # gdn_key_heads key heads of gdn_key_dim, each serving gdn_value_heads /
+    # gdn_key_heads value heads of gdn_value_dim, a depthwise convolution of
+    # gdn_conv taps over q, k and v (the rule's chunk is the kernels' own,
+    # kernels/gated_delta.py::CHUNK).
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
 
     @property
     def head_dim(self) -> int:
@@ -377,6 +403,31 @@ def joyai_llm_flash_config(**overrides) -> TransformerConfig:
         v_head_dim=128, mtp_modules=1, layer_pattern=pattern), **overrides})
 
 
+def qwen3_next_80b_a3b_config(**overrides) -> TransformerConfig:
+    """Qwen3-Next-80B-A3B-Instruct (Qwen/Qwen3-Next-80B-A3B-Instruct
+    ``config.json``, ``qwen3_next``): 48 layers in periods of four, three
+    Gated DeltaNet mixers (16 key heads serving 32 value heads of 128, 4
+    taps) then one causal attention layer of 16 query heads on 2 KV heads of
+    256 with a sigmoid gate on its output, per-head QK-norm and RoPE at 1e7
+    over the first quarter of a head; every layer 512 experts of width 512,
+    10 a token by a softmax renormalised, beside a shared expert of width
+    512 behind a sigmoid gate; RMSNorm with a scale of ``1 + w`` at 1e-6, no
+    biases, an untied head.  The multi-token-prediction weights of the
+    release are not in ``config.json`` and are not built."""
+    pattern = (LayerKind(mixer="gated_delta"),) * 3 + (LayerKind(),)
+    return TransformerConfig(**{**dict(
+        vocab_size=151936, num_layers=48, num_heads=16, num_kv_heads=2,
+        head_width=256, d_model=2048, d_ff=512, d_ff_shared=512,
+        max_len=262144, causal=True, norm="rmsnorm", norm_eps=1e-6,
+        norm_offset=True, positions="rope", rope_theta=1e7,
+        partial_rotary_factor=0.25, qk_norm="head", attention_gate=True,
+        use_bias=False, tie_embeddings=False, ffn="moe", num_experts=512,
+        experts_per_token=10, norm_topk_prob=True, shared_expert_gate=True,
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128,
+        gdn_value_dim=128, gdn_conv=4,
+        layer_pattern=pattern), **overrides})
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """For tests and the multichip dryrun: tiny shapes, same code paths."""
     return TransformerConfig(**{**dict(
@@ -395,11 +446,28 @@ def _dense(cfg: TransformerConfig, features: int, kernel_spec, name: str,
             nn.initializers.normal(0.02), kernel_spec))
 
 
+class _OffsetScale(nn.Module):
+    """``x * (1 + scale)``, ``scale`` zero at the start: what stands behind
+    an RMSNorm without a scale where ``cfg.norm_offset`` (the parameter keeps
+    the name and the place ``nn.RMSNorm``'s has)."""
+
+    epsilon: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        return nn.RMSNorm(epsilon=self.epsilon, dtype=jnp.float32,
+                          use_scale=False, name="unit")(x) * (1.0 + scale)
+
+
 def _norm(cfg: TransformerConfig, name: str):
     """The configuration's norm, computed and handed on in fp32."""
     if cfg.norm == "layernorm":
         return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
                             name=name)
+    if cfg.norm == "rmsnorm" and cfg.norm_offset:
+        return _OffsetScale(cfg.norm_eps, name=name)
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
     raise ValueError(f"unknown norm {cfg.norm!r}")
@@ -414,10 +482,17 @@ def _rope_angles(s: int, d: int, theta: float, positions=None):
     return positions.astype(jnp.float32)[:, None] * inv_freq[None]
 
 
-def _rope(x, theta: float, positions=None):
+def _rope(x, theta: float, positions=None, share: float = 1.0):
     """Rotary positions on ``[b, s, h, d]``, halves rotated as in
     ``transformers`` (``x*cos + rotate_half(x)*sin``), in fp32.  ``positions``
-    ``[s]``: each position's index (default ``0..s-1``)."""
+    ``[s]``: each position's index (default ``0..s-1``).  ``share`` below 1:
+    only the first ``share * d`` of a head are turned, as a head of that
+    width, and the rest goes through as it is."""
+    if share != 1.0:
+        turned = int(x.shape[3] * share)
+        return jnp.concatenate(
+            [_rope(x[..., :turned], theta, positions), x[..., turned:]],
+            axis=-1)
     angles = _rope_angles(x.shape[1], x.shape[3], theta, positions)
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
@@ -440,13 +515,23 @@ class Attention(nn.Module):
             raise ValueError(f"{h} heads on {h_kv} KV heads")
         with scope("attn.proj"):
             if cfg.num_kv_heads is None:
+                if cfg.attention_gate:
+                    raise ValueError("the output gate is written for the "
+                                     "split q and kv projections "
+                                     "(num_kv_heads)")
                 # Column-parallel qkv: heads split over the model axis.
                 qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis),
                              "qkv")(x)
                 q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
+            elif cfg.attention_gate:
+                # A head's columns are its query, then its gate.
+                q, gate = jnp.split(
+                    _dense(cfg, 2 * h * dh, (None, cfg.model_axis),
+                           "q")(x).reshape(b, s, h, 2 * dh), 2, axis=-1)
             else:
                 q = _dense(cfg, h * dh, (None, cfg.model_axis), "q")(x) \
                     .reshape(b, s, h, dh)
+            if cfg.num_kv_heads is not None:
                 kv = _dense(cfg, 2 * h_kv * dh, (None, cfg.model_axis),
                             "kv")(x)
                 k, v = jnp.split(kv.reshape(b, s, 2 * h_kv, dh), 2, axis=2)
@@ -465,8 +550,10 @@ class Attention(nn.Module):
             if cfg.attention != "full":
                 raise ValueError("rope positions need attention='full'")
             with scope("attn.rope"):
-                q = _rope(q, cfg.rope_theta, positions)
-                k = _rope(k, cfg.rope_theta, positions)
+                q = _rope(q, cfg.rope_theta, positions,
+                          cfg.partial_rotary_factor)
+                k = _rope(k, cfg.rope_theta, positions,
+                          cfg.partial_rotary_factor)
         if (h_kv != h or cfg.block_diffusion or self.kind.window) \
                 and cfg.attention != "full":
             raise ValueError("grouped KV heads, a window and the "
@@ -491,6 +578,10 @@ class Attention(nn.Module):
         else:
             raise ValueError(f"unknown attention mode {cfg.attention!r}")
 
+        if cfg.attention_gate:
+            with scope("attn.gate"):
+                out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))) \
+                    .astype(cfg.dtype)
         with scope("attn.proj"):
             out = out.reshape(b, s, h * dh)
             # Row-parallel output projection closes the TP pair.
@@ -640,6 +731,10 @@ class Block(nn.Module):
                 from .mamba2 import Mamba2
 
                 y = Mamba2(cfg, name="mamba")(y)
+            elif mixer == "gated_delta":
+                from .gated_delta import GatedDeltaNet
+
+                y = GatedDeltaNet(cfg, name="gdn")(y)
             else:
                 raise ValueError(f"unknown mixer {mixer!r}")
             with scope("norm"):
@@ -679,9 +774,10 @@ class Block(nn.Module):
                 with scope("norm"):
                     mean2 = jnp.mean(jnp.square(x.astype(jnp.float32)),
                                      axis=-1)
+                    scale = ln2.variables["params"]["scale"]
                     router_input = RouterRows(
                         x, lax.rsqrt(mean2 + cfg.norm_eps),
-                        ln2.variables["params"]["scale"])
+                        1.0 + scale if cfg.norm_offset else scale)
             else:
                 router_input = None
             y = self._experts(y, router_input)
@@ -739,6 +835,9 @@ class Block(nn.Module):
             with scope("moe.latent"):
                 out = _dense(cfg, d, (cfg.model_axis, None),
                              "latent_out")(out)
+        if cfg.shared_expert_gate and not cfg.d_ff_shared:
+            raise ValueError("shared_expert_gate gates the shared expert: "
+                             "d_ff_shared is 0")
         if cfg.d_ff_shared:
             # Every chip that shares the layer computes it alike: the sum
             # over the shares counts it once.
@@ -750,8 +849,17 @@ class Block(nn.Module):
                                     (None, cfg.model_axis),
                                     "shared_gate")(y)) * up \
                     if cfg.expert_gate else act(up)
-                out = out + _dense(cfg, d, (cfg.model_axis, None),
-                                   "shared_down")(hidden)
+                shared = _dense(cfg, d, (cfg.model_axis, None),
+                                "shared_down")(hidden)
+                if not cfg.shared_expert_gate:
+                    out = out + shared
+            if cfg.shared_expert_gate:
+                # One logit a token; every share gates alike.
+                with scope("moe.shared_gate"):
+                    logit = _dense(cfg, 1, (None, None),
+                                   "shared_expert_gate")(y)
+                    out = out + shared * jax.nn.sigmoid(
+                        logit.astype(jnp.float32)).astype(shared.dtype)
         return out
 
     def _publish_router_product(self, read):
@@ -823,6 +931,25 @@ def publish_attention(cfg: TransformerConfig, seq_len: int,
     for kind, n in pairs.items():
         metrics.set_gauge("attn_allowed_pairs_per_step", float(n), kind=kind)
     return pairs
+
+
+def publish_gated_delta(cfg: TransformerConfig, seq_len: int,
+                        sequences: int = 1) -> int:
+    """Set the gauge ``gdn_chunks_per_step`` for a step of ``sequences``
+    sequences of ``seq_len`` positions, and return it: the chunks of the
+    gated delta rule, one a value head, ``kernels.gated_delta.CHUNK``
+    positions (the last of a sequence filled) and layer of kind
+    ``gated_delta``; from the shapes alone, called outside the step, beside
+    :func:`publish_attention`."""
+    from ..core import metrics
+    from ..kernels.gated_delta import CHUNK
+
+    layers = sum(cfg.layer_kind(i).mixer == "gated_delta"
+                 for i in range(cfg.num_blocks))
+    chunks = layers * sequences * cfg.gdn_value_heads \
+        * -(-seq_len // CHUNK)
+    metrics.set_gauge("gdn_chunks_per_step", float(chunks))
+    return chunks
 
 
 class Transformer(nn.Module):

@@ -53,6 +53,10 @@ BLOCKS = {
                                "moe.latent", "moe.shared"],
     "share_latent": _LM + _MOE + ["attn.latent", "attn.rope", "attn.layout",
                                   "ffn", "moe.shared", "mtp.proj"],
+    "share_delta": _LM + _MOE + ["attn.norm", "attn.rope", "attn.gate",
+                                 "gdn.proj", "gdn.conv", "gdn.gates",
+                                 "gdn.rule", "gdn.norm", "moe.shared",
+                                 "moe.shared_gate"],
     "resnet": ["loss", "bn", "resnet.stem", "resnet.stage1", "resnet.stage2",
                "resnet.stage3", "resnet.stage4", "resnet.head"],
 }
@@ -67,8 +71,8 @@ from horovod_tpu.models.resnet import BottleneckBlock, ResNet
 from horovod_tpu.models.transformer import (
     LayerKind, Transformer, hybrid_pattern, joyai_llm_flash_config,
     lfm2_8b_a1b_config, moe_stats, nemotron_3_super_config,
-    olmoe_1b_7b_config, sdar_30b_a3b_config, smallthinker_21b_a3b_config,
-    tiny_config)
+    olmoe_1b_7b_config, qwen3_next_80b_a3b_config, sdar_30b_a3b_config,
+    smallthinker_21b_a3b_config, tiny_config)
 
 if {null}:
     jax.named_scope = lambda name: contextlib.nullcontext()
@@ -155,6 +159,14 @@ MODELS = {{
         kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=12, layer_pattern=(LayerKind(ffn="dense"), LayerKind())),
         True),
+    # A Gated DeltaNet layer (2 key heads serving 4 value heads of 8), then
+    # gated attention with partial rotary positions, 2 of 8 experts held
+    # beside a shared expert behind its gate.
+    "share_delta": lambda: lm(qwen3_next_80b_a3b_config(
+        **share, head_width=16, d_ff_shared=32, experts_per_token=2,
+        experts_held=(1, 6), gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=8, gdn_value_dim=8,
+        layer_pattern=(LayerKind(mixer="gated_delta"), LayerKind())), True),
     "resnet": resnet,
 }}
 

@@ -95,7 +95,7 @@ def test_configuration_keeps_every_published_width():
     cells = [w for w in bench["workloads"] if w["config"] == sizes["name"]]
     assert [(w["name"], w["traffic"], w["chips"]) for w in cells] == [
         (CELL, "wfbp", 1)]
-    assert len(bench["configs"]) == 8 and len(bench["workloads"]) == 10
+    assert len(bench["configs"]) >= 8 and len(bench["workloads"]) >= 10
     listed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
               if CELL in m.get("workloads", [])}
     assert {"mla_attention_ms_step", "mla_attention_roofline_pct",

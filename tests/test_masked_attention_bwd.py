@@ -162,6 +162,27 @@ def test_gradients_in_interpret_mode_match_the_grouped_einsum(
         assert rel_err(g, e) < 1e-5
 
 
+def test_gradients_at_heads_of_256(tiles_of_128):
+    """Qwen3-Next's attention layer: eight query heads on one KV head of 256
+    (two lane groups a head) under the causal rule, four tiles of 128: dq, dk
+    and dv against ``jax.grad`` of the grouped einsum.  The kernel took the
+    width as it was (``takes()`` admits multiples of 128): what 128 and 192
+    over 128 lower to is pinned in ``tests/test_pinned_programs.py``
+    (``causal_kernel_call``, ``latent_kernel_call``) and did not move."""
+    rule, s, width = RULES["causal"], 512, 256
+    assert ma.takes(rule, s, width)
+    keys = jax.random.split(jax.random.PRNGKey(256), 4)
+    q, w = (jax.random.normal(key, (1, s, 8, width)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, s, 1, width)) for key in keys[2:])
+    with jax.default_matmul_precision("highest"):
+        got = gradients(lambda *qkv: ma.attention(*qkv, rule, interpret=True),
+                        q, k, v, w)
+        want = gradients(lambda *qkv: ma.einsum(*qkv, rule), q, k, v, w)
+    for g, e in zip(got, want):
+        assert g.shape == e.shape and g.dtype == e.dtype
+        assert rel_err(g, e) < 1e-5
+
+
 @pytest.mark.parametrize("rule_name", ["causal", "window_that_cuts_a_tile"])
 def test_gradients_at_keys_of_192_over_values_of_128(rule_name, tiles_of_128):
     """Latent attention's widths (JoyAI-LLM-Flash): q and k 192 wide, v and

@@ -478,12 +478,15 @@ def test_a_quarter_that_is_no_multiple_of_128_is_rounded_up(sizes, quantum,
 @pytest.mark.parametrize("suite,late", [
     ("test_nemotron", ("horovod_tpu.kernels.ssd_scan",
                        "horovod_tpu.models.mamba2")),
-    ("test_joyai", ("horovod_tpu.models.deepseek",))])
+    ("test_joyai", ("horovod_tpu.models.deepseek",)),
+    ("test_qwen3_next", ("horovod_tpu.kernels.gated_delta",
+                         "horovod_tpu.models.gated_delta"))])
 def test_the_scan_and_the_mixer_load_where_a_configuration_asks(suite, late):
     """Neither ``import horovod_tpu`` nor ``hvd.init()`` nor the models'
     package loads the kernel or the mixer's module; a layer of kind
     ``mamba2`` does.  Nor DeepSeek-V3's parts (latent attention, the
-    prediction module): a model with ``kv_lora_rank`` does."""
+    prediction module): a model with ``kv_lora_rank`` does.  Nor the Gated
+    DeltaNet and its rule: a layer of kind ``gated_delta`` does."""
     code = (
         "import sys, horovod_tpu as hvd\n"
         "hvd.init()\n"

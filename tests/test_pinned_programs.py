@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, \
+    test_qwen3_next, \
     test_router_product, test_sdar, test_smallthinker
 
 RECORDED_WITH = "0.9.0"     # the text of a lowering is the JAX version's own
@@ -117,6 +118,19 @@ PINS = {
     "float32/joyai_tiny_step":
     "529388bca1069ec7110e721d773f271a50f10bdaaf5f98cd9e69f1f111cfc62f",
     "tree/joyai-llm-flash": "e2bc7c473a1f641f1f61e76d8b6ac6dadbacba87",
+    # PR 50 (Qwen3-Next-80B-A3B), its own text: the wrapper's call under
+    # ``Causal`` at 8 query heads on 1 KV head of 256 (the kernels took the
+    # width as they were: no line of either changed, and the two rows above
+    # keep their digests); the jaxpr of ``kernels/gated_delta.py``'s call,
+    # forward and the five cotangents, 2 key heads serving 4 value heads of
+    # 128 at three chunks; test_qwen3_next.py's tiny model (three Gated
+    # DeltaNet layers through ``chunked``, gated attention with partial
+    # rotary positions, the gated shared expert), loss and gradients in
+    # float32 on 2 x 70 tokens, and its parameter tree.
+    "gqa256_kernel_call": "007e566675d0e83b4395f2276686d739bea65891ff88b5bad6674cfe07c29225",
+    "gated_delta_kernel_call": "465911a65def5b1d98e673f9e1e66a7cfdb016b73abafced361b4664e527da12",
+    "float32/qwen3_next_tiny_step": "2bf8b684115f1b20df7bdb3e03652029c01bceacaa61eb0270cbd136fc2a40e1",
+    "tree/qwen3-next-80b-a3b": "cf7826f4e49abb86956d6246f258969362f43e4b",
 }
 
 
@@ -244,7 +258,8 @@ def test_lowers_to_what_the_parent_lowered_to(which):
 
 @pytest.mark.parametrize("which,heads,kv_heads,widths", [
     ("causal_kernel_call", 4, 2, (128, 128)),
-    ("latent_kernel_call", 3, 3, (192, 128))])
+    ("latent_kernel_call", 3, 3, (192, 128)),
+    ("gqa256_kernel_call", 8, 1, (256, 256))])
 def test_the_causal_kernels_call_traces_to_the_pinned_text(which, heads,
                                                            kv_heads, widths):
     from horovod_tpu.kernels import masked_attention as ma
@@ -262,6 +277,25 @@ def test_the_causal_kernels_call_traces_to_the_pinned_text(which, heads,
     text = "\n".join(line for line in str(jaxpr).splitlines()
                      if "xprof_metadata" not in line)
     assert digest(text) == PINS[which]
+
+
+def test_the_gated_delta_kernels_call_traces_to_the_pinned_text():
+    from horovod_tpu.kernels import gated_delta as gd
+
+    q = shape((1, 3 * gd.CHUNK, 2, 128), jnp.bfloat16)
+    v = shape((1, 3 * gd.CHUNK, 4, 128), jnp.bfloat16)
+    head = shape((1, 3 * gd.CHUNK, 4), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gd.gated_delta(q, k, v, g, beta, interpret=True)
+                       .astype(jnp.float32))
+
+    jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
+        q, q, v, head, head).jaxpr
+    names = [eqn.params["name"]
+             for eqn in test_sdar.equations_of(jaxpr, "pallas_call")]
+    assert names == [gd.FWD_NAME, gd.BWD_NAME]
+    assert digest(str(jaxpr)) == PINS["gated_delta_kernel_call"]
 
 
 def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
@@ -300,7 +334,8 @@ def float32_program(which):
         aux = jax.eval_shape(lambda: test_lfm2.counters(sizes))
         return step_program(test_lfm2.program_loss(model, sizes),
                             abstract(model, tokens), aux, {"tokens": tokens})
-    tiny = test_joyai if which == "joyai_tiny_step" else test_nemotron
+    tiny = {"joyai_tiny_step": test_joyai,
+            "qwen3_next_tiny_step": test_qwen3_next}.get(which, test_nemotron)
     model, sizes = tiny.tiny_model(jnp.float32)
     few = shape((2, sizes["sequence_length"]), jnp.int32)
     aux = jax.eval_shape(lambda: tiny.zero_aux(sizes))
@@ -409,6 +444,7 @@ def small_presets():
             **share, head_width=8, experts_per_token=3, experts_held=(1, 6),
             layer_pattern=(t.LayerKind(0, False), t.LayerKind(8, True))),
         "joyai-llm-flash": test_joyai.tiny_model()[0].cfg,
+        "qwen3-next-80b-a3b": test_qwen3_next.tiny_model()[0].cfg,
         "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
             **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
             experts_per_token=2, experts_held=(1, 6),
