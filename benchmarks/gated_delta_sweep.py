@@ -1,12 +1,28 @@
 """The gated delta rule's kernels and the DeltaNet's convolution at
 qwen3-next-80b-a3b's shapes, on the chip, a form at a time.
 
-    python3 benchmarks/gated_delta_sweep.py [--what rule,conv] [--out file]
+    python3 benchmarks/gated_delta_sweep.py [--what passes,rule,conv] [--out file]
 
+``passes``: what an MXU pass costs by its shape, the hinge of PR 51: inside one
+pallas kernel, a grid step of eight value heads' worth of independent chains
+of dependent products (bf16 operands, fp32 sums, nothing to HBM between
+them; 512 grid steps, a layer's), a chain being 30 ``[64, 64]`` products a
+head; 30 ``[128, 128]`` ones a pair of heads (the pair's block diagonal); 20
+of ``[64, 128] . [128, 64]`` a head (a three-pass fp32 product as ``[a_h | a_l]
+[b_h ; b_h]`` and ``a_h b_l``); and 30 of ``[64, 128] . [128, 128]`` a pair (the
+pair's blocks side by side against a block-diagonal right-hand side); each
+with a step's chains written one after the other and ``abreast``, product i
+of every chain on neighbouring lines of the program (the chip's compiler
+overlaps only those: what ``kernels/gated_delta.py``'s leading axis of pairs
+rests on).
 ``rule``: ``kernels/gated_delta.py``'s forward kernel and forward + backward
+(a pair of value heads a block-diagonal chunk, a grid step's pairs abreast)
 at one sequence of 8192, 16 key heads serving 32 value heads of 128, with the
-chunks' inverses in 6 and in 3 bf16 passes and 4, 8 and 16 value heads a grid
-step, beside how far ``o`` and ``dv`` lie from ``chunked()``'s in float32.
+chunks' inverses in 6 and in 3 bf16 passes (``--inverse-passes``) and 4, 8 and
+16 value heads a grid step (``--heads-a-step``), beside how far ``o`` and
+``dv`` lie from ``chunked()``'s in float32.  The script reads the package
+beside it, so a copy of it in a checkout of another commit times that
+commit's kernels on the same chip.
 ``conv``: ``models/mamba2.py::causal_conv`` (padded, four shifted slices,
 fp32) at ``[1, 8192, 8192]`` in bf16 beside the same taps as rolls under a
 mask, of the fp32 copy and of the bf16 input itself
@@ -18,6 +34,7 @@ one jitted function, one process, one chip; a time, not a result line.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -39,7 +56,80 @@ def timed(fn, args, iters):
     return 1e3 * (time.perf_counter() - start) / iters
 
 
-def rule(iters):
+# form: (rows, columns of a chain's fp32 tile, heads a chain, products a chain)
+PASS_FORMS = {"64x64x64": (64, 64, 1, 30), "128x128x128": (128, 128, 2, 30),
+              "64x128x64": (64, 64, 1, 20), "64x128x128": (64, 128, 2, 30)}
+
+
+def _pass_product(form, y):
+    """The next tile of a chain: both operands made of ``y``, fp32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bf16 = jnp.bfloat16
+    left = right = y.astype(bf16)
+    if form == "64x128x64":
+        low = (y - left.astype(jnp.float32)).astype(bf16)
+        left = jnp.concatenate([left, low], axis=1)
+        right = jnp.concatenate([right, right], axis=0)
+    elif form == "64x128x128":
+        t = lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+        s = lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+        right = jnp.where(t // 64 == s // 64,
+                          jnp.concatenate([right, right], axis=0), 0)
+    return jnp.dot(left, right, preferred_element_type=jnp.float32)
+
+
+def passes(iters, steps=512, heads=8):
+    import jax
+    import jax.numpy as jnp
+    import jax.experimental.pallas as pl
+
+    def kernel(x_ref, o_ref, *, form, products, abreast):
+        def advance(y, w):
+            # Bounded by 1 where y and w are: timing, not a result.
+            return _pass_product(form, y) * (0.5 / 128) + 0.5 * w
+
+        ws = [x_ref[chain] for chain in range(x_ref.shape[0])]
+        if abreast:        # product i of every chain on neighbouring lines
+            ys = ws
+            for _ in range(products):
+                ys = [advance(y, w) for y, w in zip(ys, ws)]
+        else:              # a chain after the other
+            ys = []
+            for w in ws:
+                y = w
+                for _ in range(products):
+                    y = advance(y, w)
+                ys.append(y)
+        for chain, y in enumerate(ys):
+            o_ref[chain] = y
+
+    rows = []
+    for abreast in (False, True):
+        for form, (n, m, heads_a_chain, products) in PASS_FORMS.items():
+            chains = heads // heads_a_chain
+            x = jax.random.uniform(jax.random.PRNGKey(0),
+                                   (steps * chains, n, m), minval=-1.0)
+            block = pl.BlockSpec((chains, n, m), lambda i: (i, 0, 0))
+            call = jax.jit(pl.pallas_call(
+                functools.partial(kernel, form=form, products=products,
+                                  abreast=abreast),
+                grid=(steps,), in_specs=[block], out_specs=block,
+                out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+                name=f"hvd_sweep_passes_{form}{'_abreast' * abreast}"))
+            ms = timed(call, (x,), iters)
+            rows.append({
+                "form": form, "abreast": abreast, "chains_a_step": chains,
+                "products_a_chain": products, "ms": ms,
+                "finite": bool(jnp.isfinite(call(x)).all()),
+                "ns_a_product": 1e6 * ms / (steps * chains * products),
+                "ns_a_head_chain": 1e6 * ms / (steps * heads)})
+            print(rows[-1], file=sys.stderr, flush=True)
+    return rows
+
+
+def rule(iters, inverse_passes=(6, 3), heads_a_step=(8, 4, 16)):
     import jax
     import jax.numpy as jnp
 
@@ -74,8 +164,8 @@ def rule(iters):
                      / jnp.linalg.norm(b.ravel()))
 
     rows = []
-    for passes in (6, 3):
-        for heads in (8, 4, 16):
+    for passes in inverse_passes:
+        for heads in heads_a_step:
             gd._KERNEL_INVERSE_PASSES, gd._HEADS_A_STEP = passes, heads
             jax.clear_caches()
             forward = jax.jit(gd.gated_delta)
@@ -135,16 +225,24 @@ def conv(iters):
 
 def main():
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    p.add_argument("--what", default="rule,conv")
+    p.add_argument("--what", default="passes,rule,conv")
     p.add_argument("--iters", type=int, default=10)
+    p.add_argument("--inverse-passes", default="6,3", help="rule's rows")
+    p.add_argument("--heads-a-step", default="8,4,16", help="rule's rows")
     p.add_argument("--out", default=None)
     args = p.parse_args()
 
     import jax
 
+    def numbers(text):
+        return tuple(int(n) for n in text.split(","))
+
+    forms = {"passes": passes, "conv": conv, "rule": functools.partial(
+        rule, inverse_passes=numbers(args.inverse_passes),
+        heads_a_step=numbers(args.heads_a_step))}
     out = {"device": jax.devices()[0].device_kind}
     for what in args.what.split(","):
-        out[what] = {"rule": rule, "conv": conv}[what](args.iters)
+        out[what] = forms[what](args.iters)
     print(json.dumps(out), flush=True)
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

@@ -1,5 +1,5 @@
 """The gated delta rule of a Gated DeltaNet layer in its chunked form,
-forward and backward in one kernel each.
+forward and backward in one kernel each, a pair of value heads a block.
 
 A Gated DeltaNet mixer (``models/gated_delta.py``, arXiv:2412.06464;
 Qwen3-Next's linear-attention layers) carries, a value head, a state
@@ -41,16 +41,34 @@ form ``-T^T dT T^T``, two.
 **The kernels.**  A grid step is one chunk of up to eight value heads (their
 key heads beside them, read once for the value heads they serve and not
 copied) of one sequence, the chunks in order (backward: in reverse), the
-heads' states ``[heads, K, V]`` in fp32 in VMEM across them.  The forward
+heads' states ``[heads, K, V]`` in fp32 in VMEM across them.  The two value
+heads of a key head are one block-diagonal chunk of ``2C = 128`` positions
+(:func:`_pair_chunk`: the second head's rows under the first's, ``A``, ``T``
+and ``tril(q k^T D)`` ``[128, 128]`` with zeros where the heads differ), so
+``k k^T`` and ``q k^T`` are computed once a key head and the inverse, ``U``,
+``W`` and ``tril(q k^T D) V'`` are one 128-wide product for both; only the
+three products with a head's own state stay a head's: 41 MXU passes a pair
+where two single heads took 76.  The inverse's series ends where a chunk's
+powers do, whatever the width (ten products), and the zeros add exact zeros:
+``o`` is the single heads' to the bit.  A step's pairs go through that
+algebra together, the pairs a leading axis of every operand: a chunk is one
+chain of dependent products, the chip's compiler overlaps only products that
+one line of the program holds, and a chain alone waits out each product's
+latency (128 ns a dependent ``[64, 64]`` product, 175 a ``[128, 128]`` one,
+23 and 49 with eight and four chains abreast: ``benchmarks/
+gated_delta_sweep.py --what passes``, PR 51).  :func:`takes` wants an even
+number of value heads a key head; one, or an odd number, leaves no pair
+inside a key head and goes to :func:`chunked`.  The forward
 kernel writes ``o`` and, for the backward pass, the state every chunk
 *started* from (``[chunks, heads, K, V]`` fp32: 268 MB a layer at 8192
 positions and 32 heads of 128 x 128; a state a token would be 64 times
 that).  The backward kernel starts from those, carries the state's cotangent
 from the last chunk to the first, and gives the cotangents of ``q``, ``k``,
-``v``, ``beta`` and ``gamma``; it is the same chunk's algebra
-(:func:`_chunk`) taken backward by ``jax.vjp`` inside the kernel, the inverse
-by its closed form.  The sums that turn ``gamma``'s cotangent into ``g``'s run
-in XLA, by autodiff of the cumulative sum that made it.
+``v``, ``beta`` and ``gamma``; it is the same pairs' algebra taken backward
+by ``jax.vjp`` inside the kernel (a key head's ``dq`` and ``dk`` come out
+summed over its pair), the inverse by its closed form.  The sums that turn
+``gamma``'s cotangent into ``g``'s run in XLA, by autodiff of the cumulative
+sum that made it.
 
 **Precision**: ``q``, ``k``, ``v`` and ``o`` in bf16; ``g``, ``beta``,
 ``gamma``, ``D``, ``A``, ``T`` and the states in fp32; the products with the
@@ -73,8 +91,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .ssd_scan import _column
-
 # The calls' names on the device's op line, and what matches both.
 FWD_NAME = "hvd_gated_delta_fwd"
 BWD_NAME = "hvd_gated_delta_bwd"
@@ -89,16 +105,20 @@ _HEADS_A_STEP = 8
 # rounded to bf16, so the three passes' 2**-16 cost ``o`` and the cotangents
 # nothing that shows (3.016e-3 and 4.748e-3 of float32's either way) and save
 # a sixth of both kernels: a layer forward + backward 19.67 -> 16.31 ms at
-# Qwen3-Next's shape (`benchmarks/gated_delta_sweep.py`, my chip run, PR 50;
-# 4 or 16 heads a step within 4% of 8).
+# Qwen3-Next's shape a head at a time (`benchmarks/gated_delta_sweep.py`, my
+# chip run, PR 50), and 7.73 in pairs, a step's four abreast (PR 51: two
+# pairs a step 9.71, eight 7.48).
 _KERNEL_INVERSE_PASSES = 3
 _VMEM_LIMIT = 64 * 2 ** 20
 
 
 def heads_a_step(key_heads: int, value_heads: int) -> int:
     """The value heads one grid step takes: the most, up to eight, that are
-    whole key heads' and divide the heads."""
+    whole key heads' and divide the heads; none where a key head serves an
+    odd number of value heads, which leaves no pairs inside it."""
     ratio = value_heads // key_heads
+    if ratio % 2:
+        return 0
     return max((n for n in range(ratio, _HEADS_A_STEP + 1, ratio)
                 if value_heads % n == 0), default=0)
 
@@ -106,8 +126,9 @@ def heads_a_step(key_heads: int, value_heads: int) -> int:
 def takes(seq_len: int, key_heads: int, value_heads: int, key_dim: int,
           value_dim: int, dtype=jnp.bfloat16) -> bool:
     """Whether the kernels take ``q``, ``k`` ``[b, seq_len, key_heads,
-    key_dim]`` and ``v [b, seq_len, value_heads, value_dim]`` of ``dtype``;
-    otherwise, and off the TPU, :func:`chunked`."""
+    key_dim]`` and ``v [b, seq_len, value_heads, value_dim]`` of ``dtype``:
+    an even number of value heads a key head (a chunk is a pair's); otherwise,
+    and off the TPU, :func:`chunked`."""
     if key_heads <= 0 or value_heads % key_heads:
         return False
     return (jnp.dtype(dtype) == jnp.bfloat16
@@ -127,12 +148,12 @@ def _mm(a, b, ta: bool = False, tb: bool = False, precision=None):
         precision=precision, preferred_element_type=jnp.float32)
 
 
-def _lower(c: int, strict: bool):
+def _lower(c: int, strict: bool, block: int = _BLOCK):
     """``[c, c]``: whether ``s <= t`` (``strict``: ``s < t``), and whether
     both lie in one diagonal block."""
     t = lax.broadcasted_iota(jnp.int32, (c, c), 0)
     s = lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    return (t > s if strict else t >= s), t // _BLOCK == s // _BLOCK
+    return (t > s if strict else t >= s), t // block == s // block
 
 
 def _mm3(a, b, ta: bool = False, tb: bool = False):
@@ -169,10 +190,11 @@ def _inverse_products(a, passes: int):
         t = high(t, eye + power)
     if c <= _BLOCK:
         return t
-    # The blocks under them: (I + t a_o)^-1 t, (t a_o)^(c / 16) = 0.
+    # The blocks under them: (I + t a_o)^-1 t, (t a_o)^(c / 16) = 0; an ``a``
+    # wider than a chunk is block diagonal by chunks, and so are the powers.
     b = high(t, a - inside)
     merged, power, width = eye - b, b, 1
-    while 2 * width < -(-c // _BLOCK):
+    while 2 * width < -(-min(c, CHUNK) // _BLOCK):
         power, width = high(power, power), 2 * width
         merged = high(merged, eye + power)
     return high(merged, t)
@@ -180,8 +202,9 @@ def _inverse_products(a, passes: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def unit_lower_inverse(a, passes: int = 6):
-    """``(I + a)^-1`` for ``a [..., C, C]`` strictly lower triangular, fp32,
-    its products in ``passes`` bf16 passes (the module's text says how)."""
+    """``(I + a)^-1`` for ``a [..., C, C]`` strictly lower triangular (wider
+    than :data:`CHUNK`: block diagonal by chunks besides), fp32, its products
+    in ``passes`` bf16 passes (the module's text says how)."""
     return _inverse_products(a, passes)
 
 
@@ -230,6 +253,61 @@ def _chunk(q, k, v, gamma_col, gamma_row, beta, state, dot=None):
     return o, new
 
 
+def _pair_chunk(q, k, v, gamma_col, gamma_row, beta, state, dot):
+    """:func:`_chunk` of the two value heads of one key head as one block-
+    diagonal problem ``[2C, 2C]``, the second head's rows under the first's:
+    ``q``, ``k [C, K]`` the key head's, ``v [2C, V]``, ``gamma`` as ``[2C,
+    1]`` and as ``[1, 2C]``, ``beta [2C, 1]``, the states ``[2, K, V]`` ->
+    ``(o [2C, V], the states the chunk ends with)``.  ``k k^T`` and ``q k^T``
+    are computed once, and the inverse, ``U``, ``W`` and ``tril(q k^T D) V'``
+    are one product each for both heads; only the products with a head's own
+    state stay a head's.  Every operand is rounded where :func:`_chunk`
+    rounds it, and the zeros off the diagonal blocks add exact zeros."""
+    c = q.shape[-2]
+
+    def cast(t):
+        return t.astype(dot)
+
+    def twice(t):                  # the key head's rows, once a value head
+        return jnp.concatenate([t, t], axis=-2)
+
+    def a_head(rows, states, ta=False):
+        """``rows [2C, .]`` by ``states [2, ., .]``, each head's by its own."""
+        return jnp.concatenate(
+            [_mm(rows[..., h * c:(h + 1) * c, :], states[..., h, :, :], ta)
+             for h in range(2)], axis=-2)
+
+    lower, own = _lower(2 * c, False, c)
+    strict, _ = _lower(2 * c, True)
+    decay = jnp.where(lower & own,
+                      jnp.exp(jnp.minimum(gamma_col - gamma_row, 0.0)), 0.0)
+    q, k = cast(q), twice(cast(k))
+    # [2C, 2C] of a [C, C] in every quarter: the decays keep the diagonal two.
+    kk = twice(_mm(k[..., :c, :], k, tb=True))
+    qk = twice(_mm(q, k, tb=True))
+    a = jnp.where(strict, beta * kk * decay, 0.0)
+    t = cast(unit_lower_inverse(a, _KERNEL_INVERSE_PASSES))
+    grown = jnp.exp(gamma_col)
+    kf = k.astype(jnp.float32)
+    u = _mm(t, cast(beta * v))
+    w = _mm(t, cast(beta * kf * grown))
+    state_in = cast(state)
+    writes = cast(u - a_head(cast(w), state_in))
+    o = a_head(cast(twice(q.astype(jnp.float32)) * grown), state_in) \
+        + _mm(cast(qk * decay), writes)
+    new = []
+    for h in range(2):
+        rows = slice(h * c, (h + 1) * c)
+        last = gamma_col[..., (h + 1) * c - 1:(h + 1) * c, :]
+        # Over the state's rows first, then its lanes: the cotangent of one
+        # spread over both is a vector a pair, which Mosaic does not lay out.
+        kept = jnp.exp(last) * jnp.ones((state.shape[-2], 1), jnp.float32)
+        new.append(kept * state[..., h, :, :] + _mm(
+            cast(kf[..., rows, :] * jnp.exp(last - gamma_col[..., rows, :])),
+            writes[..., rows, :], ta=True))
+    return o, jnp.stack(new, axis=-3)
+
+
 def _chunks_of(q, k, v, g, beta, chunk: int):
     """The operands of :func:`_chunk` in fp32, chunks leading: ``q``, ``k``
     given to the value heads they serve, the sequence filled to whole chunks
@@ -276,30 +354,75 @@ def chunked(q, k, v, g, beta, chunk: int = CHUNK):
 # -- the kernels --------------------------------------------------------------
 
 
+def _by_head(ref, heads=None):
+    """A block ``[C, heads * 128]`` as ``[heads, C, 128]`` in fp32 (``heads``:
+    which, in order)."""
+    picked = range(ref.shape[1] // _LANES) if heads is None else heads
+    return jnp.stack([ref[:, h * _LANES:(h + 1) * _LANES]
+                      for h in picked]).astype(jnp.float32)
+
+
+def _to_heads(ref, by_head):
+    """:func:`_by_head` undone, into ``ref``."""
+    for h in range(by_head.shape[0]):
+        ref[:, h * _LANES:(h + 1) * _LANES] = by_head[h].astype(ref.dtype)
+
+
+def _columns_under(x):
+    """``[C, heads]`` -> ``[pairs, 2C, 1]``: a pair's first column with its
+    second under it, as one masked sum over the lanes (``ssd_scan._column``'s
+    way: the sum leaves every lane holding the column, which a ``[1, 1]`` of
+    it spread over a state needs)."""
+    c, heads = x.shape
+    both = jnp.broadcast_to(jnp.concatenate([x, x], axis=0),
+                            (heads // 2, 2 * c, heads))
+    pair, row, lane = (lax.broadcasted_iota(jnp.int32, both.shape, axis)
+                       for axis in range(3))
+    return jnp.sum(jnp.where(lane == 2 * pair + row // c, both, 0.0), axis=2,
+                   keepdims=True)
+
+
+def _columns_beside(x):
+    """:func:`_columns_under` undone: ``[pairs, 2C, 1]`` -> ``[C, heads]``."""
+    heads, c = 2 * x.shape[0], x.shape[1] // 2
+    x = x.reshape(heads, c, 1)
+    lane = lax.broadcasted_iota(jnp.int32, (c, heads), 1)
+    return sum(jnp.where(lane == h, x[h], 0.0) for h in range(heads))
+
+
+def _pairs(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, ratio: int):
+    """:func:`_pair_chunk`'s operands but the states for all of a grid
+    step's pairs, the pairs leading: their chains of products are independent
+    and the compiler overlaps only what one line of the program holds."""
+    c, heads = gc_ref.shape
+    key_heads = [2 * p // ratio for p in range(heads // 2)]
+    return (_by_head(q_ref, key_heads), _by_head(k_ref, key_heads),
+            _by_head(v_ref).reshape(heads // 2, 2 * c, _LANES),
+            _columns_under(gc_ref[...]), gr_ref[...][:, None, :],
+            _columns_under(beta_ref[...]))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, o_ref,
                 before_ref, state_ref, *, ratio: int):
-    """One chunk of one step's heads: ``q``, ``k [C, key heads * 128]``, ``v
-    [C, heads * 128]``, ``gamma`` as ``[C, heads]`` and as ``[heads, C]``,
-    ``beta [C, heads]``; ``o`` out, and the states the chunk started from,
-    ``[heads, 128, 128]``."""
+    """One chunk of one step's heads, in pairs: ``q``, ``k [C, key heads *
+    128]``, ``v [C, heads * 128]``, ``gamma`` as ``[C, heads]`` and as
+    ``[pairs, 2C]``, ``beta [C, heads]``; ``o`` out, and the states the
+    chunk started from, ``[heads, 128, 128]``."""
     import jax.experimental.pallas as pl
 
-    f32 = jnp.float32
+    c, heads = gc_ref.shape
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     before_ref[...] = state_ref[...]
-    for h in range(state_ref.shape[0]):
-        keys = slice(h // ratio * _LANES, (h // ratio + 1) * _LANES)
-        values = slice(h * _LANES, (h + 1) * _LANES)
-        o, state_ref[h] = _chunk(
-            q_ref[:, keys].astype(f32), k_ref[:, keys].astype(f32),
-            v_ref[:, values].astype(f32), _column(gc_ref[...], h),
-            gr_ref[h:h + 1, :], _column(beta_ref[...], h), state_ref[h],
-            dot=q_ref.dtype)
-        o_ref[:, values] = o.astype(o_ref.dtype)
+    o, state = _pair_chunk(
+        *_pairs(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, ratio),
+        state_ref[...].reshape(heads // 2, 2, _LANES, _LANES),
+        dot=q_ref.dtype)
+    state_ref[...] = state.reshape(state_ref.shape)
+    _to_heads(o_ref, o.reshape(heads, c, _LANES))
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, before_ref,
@@ -309,45 +432,33 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, before_ref,
     ``dstate`` holds the cotangent of the state the chunk *ends* with and
     leaves that of the state it started from (``before_ref``).  ``gamma``'s
     cotangent comes in two parts, what falls out as columns ``[C, heads]``
-    and what falls out as rows ``[heads, C]``; the caller adds them."""
+    and what falls out as rows ``[pairs, 2C]``; the caller adds them."""
     import jax.experimental.pallas as pl
 
-    f32 = jnp.float32
     c, heads = gc_ref.shape
+    paired = (heads // 2, 2, _LANES, _LANES)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
-    dgc = jnp.zeros((c, heads), f32)
-    dbeta = jnp.zeros((c, heads), f32)
-    dgr = jnp.zeros((heads, c), f32)
-    head_lane = lax.broadcasted_iota(jnp.int32, (c, heads), 1)
-    head_row = lax.broadcasted_iota(jnp.int32, (heads, c), 0)
-    for h in range(heads):
-        keys = slice(h // ratio * _LANES, (h // ratio + 1) * _LANES)
-        values = slice(h * _LANES, (h + 1) * _LANES)
-        _, back = jax.vjp(
-            functools.partial(_chunk, dot=q_ref.dtype),
-            q_ref[:, keys].astype(f32), k_ref[:, keys].astype(f32),
-            v_ref[:, values].astype(f32), _column(gc_ref[...], h),
-            gr_ref[h:h + 1, :], _column(beta_ref[...], h), before_ref[h])
-        dq, dk, dv, col, row, dbeta_h, dstate_ref[h] = back(
-            (do_ref[:, values].astype(f32), dstate_ref[h]))
-        # A key head's cotangents are the sum over the value heads it serves.
-        first = h % ratio == 0
-        dq_sum = dq if first else dq_sum + dq
-        dk_sum = dk if first else dk_sum + dk
-        if h % ratio == ratio - 1:
-            dq_ref[:, keys] = dq_sum.astype(dq_ref.dtype)
-            dk_ref[:, keys] = dk_sum.astype(dk_ref.dtype)
-        dv_ref[:, values] = dv.astype(dv_ref.dtype)
-        dgc = jnp.where(head_lane == h, col, dgc)
-        dbeta = jnp.where(head_lane == h, dbeta_h, dbeta)
-        dgr = jnp.where(head_row == h, row, dgr)
-    dgc_ref[...] = dgc
-    dgr_ref[...] = dgr
-    dbeta_ref[...] = dbeta
+    _, back = jax.vjp(
+        functools.partial(_pair_chunk, dot=q_ref.dtype),
+        *_pairs(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, ratio),
+        before_ref[...].reshape(paired))
+    dq, dk, dv, col, row, dbeta, dstate = back(
+        (_by_head(do_ref).reshape(heads // 2, 2 * c, _LANES),
+         dstate_ref[...].reshape(paired)))
+    dstate_ref[...] = dstate.reshape(dstate_ref.shape)
+    dgr_ref[...] = row.reshape(dgr_ref.shape)
+    dgc_ref[...] = _columns_beside(col)
+    dbeta_ref[...] = _columns_beside(dbeta)
+    _to_heads(dv_ref, dv.reshape(heads, c, _LANES))
+    # A key head's cotangents are the sum over the value heads it serves:
+    # over a pair already, and here over the key head's pairs.
+    for d_ref, d in ((dq_ref, dq), (dk_ref, dk)):
+        _to_heads(d_ref, jnp.sum(
+            d.reshape(heads // ratio, ratio // 2, c, _LANES), axis=1))
 
 
 def _specs(s: int, heads: int, ratio: int, reverse: bool):
@@ -366,9 +477,8 @@ def _specs(s: int, heads: int, ratio: int, reverse: bool):
                           lambda i, g, j: (i, at(j), g))
     columns = pl.BlockSpec((None, None, CHUNK, heads),
                            lambda i, g, j: (i, g, at(j), 0))
-    # A chunk's rows are a whole trailing pair: 64 positions are half a
-    # lane group, which no block of a longer axis may be.
-    rows = pl.BlockSpec((None, None, None, heads, CHUNK),
+    # A chunk's rows, a pair of heads a row of one lane group.
+    rows = pl.BlockSpec((None, None, None, heads // 2, 2 * CHUNK),
                         lambda i, g, j: (i, g, at(j), 0, 0))
     states = pl.BlockSpec((None, None, None, heads, _LANES, _LANES),
                           lambda i, g, j: (i, g, at(j), 0, 0, 0))
@@ -376,10 +486,20 @@ def _specs(s: int, heads: int, ratio: int, reverse: bool):
 
 
 def _rows(columns):
-    """``[batch, steps, s, heads]`` as ``[batch, steps, chunks, heads, C]``."""
+    """``[batch, steps, s, heads]`` as ``[batch, steps, chunks, pairs, 2C]``,
+    a pair's second head behind its first."""
     batch, steps, s, heads = columns.shape
-    return jnp.swapaxes(
-        columns.reshape(batch, steps, s // CHUNK, CHUNK, heads), 3, 4)
+    return columns.reshape(batch, steps, s // CHUNK, CHUNK, heads // 2, 2) \
+        .transpose(0, 1, 2, 4, 5, 3) \
+        .reshape(batch, steps, s // CHUNK, heads // 2, 2 * CHUNK)
+
+
+def _columns(rows):
+    """:func:`_rows` undone."""
+    batch, steps, nc, pairs, _ = rows.shape
+    return rows.reshape(batch, steps, nc, pairs, 2, CHUNK) \
+        .transpose(0, 1, 2, 5, 3, 4) \
+        .reshape(batch, steps, nc * CHUNK, 2 * pairs)
 
 
 def _params():
@@ -449,7 +569,7 @@ def _backward(q, k, v, gamma, beta, before, do, *, ratio: int,
     def like(t):
         return jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
 
-    by_rows = jax.ShapeDtypeStruct((batch, steps, nc, heads, CHUNK),
+    by_rows = jax.ShapeDtypeStruct((batch, steps, nc, heads // 2, 2 * CHUNK),
                                    jnp.float32, vma=vma)
     calls = batch * steps * nc * heads
     dq, dk, dv, dgc, dgr, dbeta = pl.pallas_call(
@@ -468,8 +588,7 @@ def _backward(q, k, v, gamma, beta, before, do, *, ratio: int,
             + 4 * calls * _LANES * _LANES),
         name=BWD_NAME, interpret=interpret,
     )(q, k, v, gamma, _rows(gamma), beta, before, do)
-    return (dq, dk, dv,
-            dgc + jnp.swapaxes(dgr, 3, 4).reshape(gamma.shape), dbeta)
+    return dq, dk, dv, dgc + _columns(dgr), dbeta
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

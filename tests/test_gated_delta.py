@@ -13,6 +13,7 @@ import pytest
 
 from .helpers import load_reference
 from .test_olmoe import rel_err
+from .test_sdar import equations_of
 
 ref = load_reference("qwen3-next-80b-a3b")
 
@@ -61,28 +62,67 @@ def test_chunked_is_the_recurrence_token_by_token(s):
                 assert not got.any()    # one position: no decay is read
 
 
-@pytest.mark.parametrize("hk,hv", [(1, 2), (4, 16)])
+def one_head_at_a_time(q, k, v, g, beta):
+    """PR 50's kernels' arithmetic without the kernels: ``_chunk`` a value
+    head with the MXU's operands in bf16, the chunks in order (what the grid
+    step's loop over single heads computed before PR 51 paired them)."""
+    from horovod_tpu.kernels import gated_delta as gd
+
+    batch, s = q.shape[:2]
+    hv, dv = v.shape[2:]
+
+    def carry(state, chunk_in):
+        o, state = gd._chunk(*chunk_in, state, dot=jnp.bfloat16)
+        return state, o
+
+    _, o = jax.lax.scan(
+        carry, jnp.zeros((batch, hv, q.shape[3], dv), jnp.float32),
+        gd._chunks_of(q, k, v, g, beta, gd.CHUNK))
+    return o.transpose(1, 0, 3, 2, 4).reshape(v.shape).astype(v.dtype)
+
+
+@pytest.mark.parametrize("hk,hv", [(1, 2), (4, 16), (2, 4), (2, 2), (1, 3)])
 def test_the_kernels_in_interpret_mode_are_chunked(hk, hv):
     """``hvd_gated_delta_fwd`` and ``_bwd`` in interpret mode at heads of
     128, bf16, three chunks: ``o`` and the five cotangents within bf16's
-    rounding of ``chunked``'s on the same operands; (4, 16) takes two grid
-    steps of eight heads, four value heads a key head."""
+    rounding of ``chunked``'s on the same operands.  A grid step takes its
+    value heads a pair of one key head at a time: (1, 2) is one pair, (2, 4)
+    the cell's ratio, a pair a key head, (4, 16) two grid steps of eight
+    heads, two pairs a key head.  One value head a key head (2, 2) or an odd
+    number (1, 3) leaves no pairs inside a key head: ``takes`` refuses them
+    and ``gated_delta`` is ``chunked`` there, whatever ``interpret`` says."""
     from horovod_tpu.kernels import gated_delta as gd
 
     s = 192 if hv < 16 else 128
-    assert gd.takes(s, hk, hv, 128, 128)
-    assert gd.heads_a_step(hk, hv) == min(hv, 8)
+    pairs = (hv // hk) % 2 == 0
+    assert gd.takes(s, hk, hv, 128, 128) == pairs
+    assert gd.heads_a_step(hk, hv) == (min(hv, 8) if pairs else 0)
     operands = rule_operands(hv, 1, s, hk, hv, 128, 128, jnp.bfloat16)
+    kernels = jax.jit(lambda *a: gd.gated_delta(*a, interpret=True))
+    names = [eqn.params["name"] for eqn in equations_of(
+        kernels.trace(*operands).jaxpr, "pallas_call")]
+    assert names == ([gd.FWD_NAME] if pairs else [])
     with jax.default_matmul_precision("highest"):
         want = gd.chunked(*operands).astype(jnp.float32)
-        got = gd.gated_delta(*operands, interpret=True)
+        got = kernels(*operands)
         assert got.dtype == jnp.bfloat16
         assert rel_err(got, want) < 2e-2
-        grads = all_gradients(
-            lambda *a: gd.gated_delta(*a, interpret=True), operands)
+        grads = all_gradients(kernels, operands)
         for got, want in zip(grads, all_gradients(gd.chunked, operands)):
             assert got.dtype == want.dtype and got.shape == want.shape
             assert rel_err(got, want.astype(jnp.float32)) < 2e-2
+
+
+def test_a_pair_of_heads_is_two_heads():
+    """The paired kernel's ``o`` against the same chunks a head at a time
+    (PR 50's loop): the block diagonal's zeros add exact zeros and every
+    operand is rounded where it was, so the two agree to 1e-6 of ``o``'s
+    norm, far inside bf16's rounding of either."""
+    from horovod_tpu.kernels import gated_delta as gd
+
+    operands = rule_operands(7, 1, 192, 2, 4, 128, 128, jnp.bfloat16)
+    got = gd.gated_delta(*operands, interpret=True)
+    assert rel_err(got, one_head_at_a_time(*operands)) < 1e-6
 
 
 def test_what_the_kernels_take():
@@ -94,6 +134,9 @@ def test_what_the_kernels_take():
     assert not gd.takes(8192, 16, 32, 64, 128)
     assert not gd.takes(8192, 16, 32, 128, 128, dtype=jnp.float32)
     assert not gd.takes(8192, 3, 32, 128, 128)
+    assert not gd.takes(8192, 32, 32, 128, 128)             # no pairs
+    assert not gd.takes(8192, 8, 24, 128, 128)              # an odd ratio
+    assert gd.takes(8192, 4, 32, 128, 128) and gd.heads_a_step(4, 32) == 8
     operands = rule_operands(0, 1, 64, 1, 2, 128, 128, jnp.bfloat16)
     with pytest.raises(ValueError, match="beta"):
         gd.gated_delta(*operands[:4], operands[4][..., :1])

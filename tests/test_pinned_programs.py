@@ -128,7 +128,9 @@ PINS = {
     # rotary positions, the gated shared expert), loss and gradients in
     # float32 on 2 x 70 tokens, and its parameter tree.
     "gqa256_kernel_call": "007e566675d0e83b4395f2276686d739bea65891ff88b5bad6674cfe07c29225",
-    "gated_delta_kernel_call": "465911a65def5b1d98e673f9e1e66a7cfdb016b73abafced361b4664e527da12",
+    # PR 51 re-pinned this row alone: the kernels take a key head's two value
+    # heads as one block-diagonal chunk 128 wide, a step's pairs abreast.
+    "gated_delta_kernel_call": "712ade0f10b50ea922e16a9e4c7c0ef8d81fc7e670d8612025bbe8b767066393",
     "float32/qwen3_next_tiny_step": "2bf8b684115f1b20df7bdb3e03652029c01bceacaa61eb0270cbd136fc2a40e1",
     "tree/qwen3-next-80b-a3b": "cf7826f4e49abb86956d6246f258969362f43e4b",
 }

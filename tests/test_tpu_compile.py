@@ -699,12 +699,16 @@ def test_gated_delta_compiles_at_qwen3_nexts_shape(one_chip,
                                                    no_compile_cache):
     """One sequence of 8192 positions, 16 key heads serving 32 value heads
     of 128, in chunks of 64: the forward and the backward kernel of
-    ``kernels/gated_delta.py`` (the backward is the chunk's algebra through
+    ``kernels/gated_delta.py``, a grid step's eight value heads as four
+    pairs, a pair one block-diagonal chunk 128 wide and the four a leading
+    axis of every product (PR 51; the backward is the pairs' algebra through
     ``jax.vjp`` inside the kernel: what the chip's compiler makes of its
-    transposed products shows here and in no interpret-mode test); the
-    residuals are the inputs and the state every chunk starts from (268 MB
-    in fp32), and nothing the size of a state a token (17 GB) is in the
-    program."""
+    transposed and batched products, and of a cotangent that is a vector a
+    pair, shows here and in no interpret-mode test); two kernel names, one
+    call of each; the residuals are the inputs and the state every chunk
+    starts from (268 MB in fp32, ``f32[1,4,128,8,128,128]``: four grid steps
+    of eight heads, as before the pairs), and nothing the size of a state a
+    token (17 GB) is in the program."""
     from horovod_tpu.kernels import gated_delta as gd
 
     assert gd.takes(8192, 16, 32, 128, 128)
@@ -762,7 +766,9 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     """``qwen3-next-80b-a3b-wfbp-1chip``'s whole step (loss, gradients,
     AdamW) at the timed sizes under the one device's mesh, as
     ``hvd.make_overlapped_train_step`` builds it: it compiles through the
-    kernels' path (the rule's two kernels a DeltaNet layer, the two attention
+    kernels' path (the rule's two kernels a DeltaNet layer, three calls of
+    each and no other name of theirs, the pairs' backward through ``jax.vjp``
+    inside the one kernel; the two attention
     kernels at width 256, the rows kernel, no einsum over a score square),
     the compiler computes nothing again to make it fit (with 32 experts held
     it does: the configuration's ``fit``), and its own count of the memory
