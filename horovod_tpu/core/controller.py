@@ -42,6 +42,7 @@ from . import flight_recorder
 from ..common.topology import ProcessTopology
 from ..transport.tcp import TcpMesh
 from . import metrics
+from . import timeline as timeline_mod
 from .messages import (
     DataType,
     HostMaskFrame,
@@ -337,6 +338,11 @@ class Controller:
         # compute_response_list, so it is consistent across ranks without
         # a wire field — the FANIN_RELAY span's cycle tag rides it.
         self.cycle_index = 0
+        # The loop's word, before each round, that this rank has a tensor
+        # to announce or one announced and not yet agreed on: what the
+        # round then sits blocked on other ranks' frames is that tensor's
+        # waiting, and goes to ``negotiate_recv`` (``_blocked_recv``).
+        self.tensors_in_flight = False
 
     # ------------------------------------------------------------------
     # the per-cycle negotiation round
@@ -435,11 +441,27 @@ class Controller:
             # counted so the tree-vs-direct split is observable.
             self.fanin_direct_frame_count += 1
             self.mesh.send(0, payload)
-            return self._apply_reply(self.mesh.recv(0))
+            return self._apply_reply(self._blocked_recv(0))
         if self.fanout_topology == "tree":
             return self._worker_round_tree(payload)
         self.mesh.send(0, payload)
-        return self._apply_reply(self.mesh.recv(0))
+        return self._apply_reply(self._blocked_recv(0))
+
+    def _blocked_recv(self, peer: int) -> bytes:
+        """One receive of the round: on the coordinator a rank's frame
+        (``_recv_ingress``), on a relay its members' or children's, on
+        every other rank the verdict, from the rank that hands it on (rank
+        0, the host's aggregator, the tree's parent), which waits for the
+        coordinator and, through it, for the slowest rank.  While a tensor
+        of this rank's is in flight it is a ``negotiate_recv``: a
+        ``hvd.negotiate_recv`` span with ``peer=`` inside the round's
+        ``hvd.negotiate``, and its length in the total.  A round with
+        nothing in flight (the idle lockstep exchange) takes no reading and
+        builds no span."""
+        if not self.tensors_in_flight:
+            return self.mesh.recv(peer)
+        with timeline_mod.phase("negotiate_recv", peer=peer):
+            return self.mesh.recv(peer)
 
     def _worker_round_member(self, payload: bytes) -> ResponseList:
         """Fan-in member: heartbeat-gate, then route this cycle through
@@ -461,7 +483,7 @@ class Controller:
         self.fanin_tree_frame_count += 1
         agg = self.fanin_plan.aggregator_rank
         self.mesh.send(agg, payload)
-        return self._apply_reply(self.mesh.recv(agg))
+        return self._apply_reply(self._blocked_recv(agg))
 
     def _worker_round_aggregator(self, payload: bytes) -> ResponseList:
         """Fan-in aggregator: collect the host's cycle payloads, fold the
@@ -472,16 +494,15 @@ class Controller:
         like the tree fan-out's relays).  Heartbeat is touched AFTER the
         relay completes: a wedged coordinator link must not keep
         advertising a live aggregator while members' frames pile up."""
-        from . import timeline as timeline_mod
         from .negotiation_fanin import fold_host
 
         t0 = time.monotonic_ns() if timeline_mod.control_active() else None
         collected = [(self.topo.rank, payload)]
         for member in self.fanin_plan.member_ranks:
-            collected.append((member, self.mesh.recv(member)))
+            collected.append((member, self._blocked_recv(member)))
         self.mesh.send(0, _encode_bundle(fold_host(collected)))
         self.fanin_tree_frame_count += 1
-        reply = self.mesh.recv(0)
+        reply = self._blocked_recv(0)
         for member in self.fanin_plan.member_ranks:
             self.mesh.send(member, reply)
         hb = self.fanin_heartbeat
@@ -503,9 +524,9 @@ class Controller:
         rank, size = self.topo.rank, self.topo.size
         entries = [(rank, payload)]
         for child in tree_children(rank, size):
-            entries.extend(_decode_bundle(self.mesh.recv(child)))
+            entries.extend(_decode_bundle(self._blocked_recv(child)))
         self.mesh.send(tree_parent(rank), _encode_bundle(entries))
-        resp_payload = self.mesh.recv(tree_parent(rank))
+        resp_payload = self._blocked_recv(tree_parent(rank))
         for child in tree_children(rank, size):
             self.mesh.send(child, resp_payload)
         return self._apply_reply(resp_payload)
@@ -524,8 +545,10 @@ class Controller:
         """One coordinator gather recv, counted: every fan-out shape
         funnels through here so ``controller_ingress_frames_total`` /
         ``_bytes_total`` compare star vs tree vs fan-in like for like —
-        one increment per frame that actually arrived at rank 0."""
-        data = self.mesh.recv(sender)
+        one increment per frame that actually arrived at rank 0.  It is
+        also the coordinator's blocked receive: how long rank 0 waited
+        for ``sender``."""
+        data = self._blocked_recv(sender)
         self.ingress_frame_count += 1
         self.ingress_byte_count += len(data)
         return data

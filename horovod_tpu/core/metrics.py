@@ -354,6 +354,18 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                              "jitted programs; count = output arrays"),
     "wfbp_dispatch": ("stat", "phase_stats: one OverlappedTrainStep call"),
     "state_fuse": ("stat", "phase_stats: updates that joined a tree state"),
+    "negotiate_wait": ("stat", "phase_stats: a tensor from this rank's "
+                               "announcement to the round that agreed"),
+    "negotiate_recv": ("stat", "phase_stats: rounds blocked on another "
+                               "rank's frame, a tensor in flight"),
+    "negotiate_idle": ("stat", "phase_stats: the rounds negotiate leaves "
+                               "out"),
+    "cpu.loop": ("stat", "phase_stats: the loop thread's CPU seconds "
+                         "(thread_time, not wall)"),
+    "cpu.dispatch": ("stat", "phase_stats: the dispatcher thread's CPU "
+                             "seconds (thread_time, not wall)"),
+    "cpu.update": ("stat", "phase_stats: the calling thread's CPU seconds "
+                           "inside update (thread_time, not wall)"),
     "bytes_on_wire": ("stat", "wire_stats: per-frame payload bytes"),
     "heap_copies": ("stat", "wire_stats: data-plane materializations"),
     "compressed_bytes": ("stat", "wire_stats: narrow wire-dtype bytes"),

@@ -79,6 +79,14 @@ class HorovodGlobalState:
         # rather than a floor under every dispatch's latency).
         self._wake = threading.Event()
         self._last_cycle_had_work = False
+        # What phase_stats gets of the idle rounds (negotiate_idle) and of
+        # the loop thread's CPU (cpu.loop), kept here until a round has
+        # work (_account_loop): an idle round costs two additions, not a
+        # clock's system call and two locked updates while the calling
+        # thread waits for the interpreter.
+        self._idle_rounds = 0
+        self._idle_seconds = 0.0
+        self._loop_cpu_at = 0.0
         # Pipelined negotiate/dispatch (double-buffered background loop):
         # device-plane responses are handed to a dedicated dispatcher
         # thread so cycle i+1's negotiation overlaps cycle i's XLA dispatch
@@ -582,12 +590,14 @@ class HorovodGlobalState:
         self.initialized.set()
 
         try:
+            self._loop_cpu_at = time.thread_time()
             while True:
                 start = time.monotonic()
                 # Clear BEFORE popping: an add landing between pop and a
                 # clear-afterwards would lose its wakeup.
                 self._wake.clear()
                 if not self._run_loop_once():
+                    self._account_loop()
                     break
                 if self._last_cycle_had_work:
                     # Spin: a busy cycle usually has an immediate follow-up
@@ -685,6 +695,11 @@ class HorovodGlobalState:
         JOIN/ERROR/BARRIER bookkeeping) executes inline behind a drain
         barrier so the cross-rank execution order stays identical."""
         requests = self.tensor_queue.pop_messages()
+        # While this rank announces a tensor or has one waiting for the
+        # others, the round's blocked receives are negotiate_recv
+        # (controller._blocked_recv).
+        self.controller.tensors_in_flight = bool(requests) \
+            or self.tensor_queue.size() > 0
         # The profiler's span shows every round, with the number of
         # requests it took, and the step of the first of them.
         with timeline_mod.phase(
@@ -699,6 +714,14 @@ class HorovodGlobalState:
                 self.timeline.set_cycle(self.cycle_count + 1)
             response_list = self.controller.compute_response_list(
                 requests, self.shutdown_requested.is_set())
+            if response_list.responses:
+                # Where negotiate_wait ends and dispatch_wait begins, for
+                # every tensor this round agreed on.
+                agreed_at = time.monotonic()
+                for response in response_list.responses:
+                    response._agreed_at = agreed_at
+                span.annotate(agreed=sum(
+                    len(r.tensor_names) for r in response_list.responses))
             self.cycle_count += 1
             self._last_cycle_had_work = bool(requests) \
                 or bool(response_list.responses)
@@ -711,6 +734,14 @@ class HorovodGlobalState:
             flight_recorder.record("cycle", n=self.cycle_count,
                                    requests=len(requests),
                                    responses=len(response_list.responses))
+            # Before the responses go out: whoever hears of this round's
+            # tensors finds the rounds before it counted.
+            self._account_loop()
+        else:
+            # The rounds negotiate leaves out: its count and negotiate_idle's
+            # are the rounds (_account_loop hands these on).
+            self._idle_rounds += 1
+            self._idle_seconds += span.seconds
         if response_list.tuned_params is not None:
             # Autotuner moved (reference SynchronizeParameters): adopt the
             # broadcast cycle time on every rank.
@@ -730,6 +761,20 @@ class HorovodGlobalState:
         if response_list.shutdown:
             return False
         return True
+
+    def _account_loop(self) -> None:
+        """Into phase_stats, in every round that had work and when the loop
+        ends: the idle rounds since the last call (``negotiate_idle``) and
+        this thread's CPU seconds since then (``cpu.loop``: by
+        ``time.thread_time()``, not wall time; the rounds, what they ran
+        inline and the parks between them)."""
+        if self._idle_rounds:
+            timeline_mod.phase_stats.add(
+                "negotiate_idle", self._idle_seconds, n=self._idle_rounds)
+            self._idle_rounds, self._idle_seconds = 0, 0.0
+        cpu = time.thread_time()
+        timeline_mod.phase_stats.add("cpu.loop", cpu - self._loop_cpu_at)
+        self._loop_cpu_at = cpu
 
     def _device_plane_response(self, response: Response) -> bool:
         """True when this response will execute on the XLA device plane
@@ -770,17 +815,18 @@ class HorovodGlobalState:
             self._dispatch_thread.start()
         with self._dispatch_cv:
             self._dispatch_inflight += 1
-        response._dispatched_at = time.monotonic()
         self._dispatch_queue.put(response)
 
     def _dispatch_loop(self) -> None:
         timeline_mod.name_os_thread()
+        cpu_at = time.thread_time()
         while True:
             response = self._dispatch_queue.get()
             if response is None:
                 return
+            # dispatch_wait starts where negotiate_wait ended.
             timeline_mod.phase_stats.add(
-                "dispatch_wait", time.monotonic() - response._dispatched_at)
+                "dispatch_wait", time.monotonic() - response._agreed_at)
             try:
                 self._perform_operation(response, require_device=True)
             except BaseException as e:  # noqa: BLE001 — the negotiation
@@ -791,6 +837,11 @@ class HorovodGlobalState:
                 log.error("pipelined dispatch failed: %s", e, exc_info=True)
                 self.async_error = f"pipelined dispatch failed: {e}"
             finally:
+                # cpu.dispatch: this thread's CPU seconds, not wall time,
+                # a response (the blocked get costs none).
+                cpu = time.thread_time()
+                timeline_mod.phase_stats.add("cpu.dispatch", cpu - cpu_at)
+                cpu_at = cpu
                 with self._dispatch_cv:
                     self._dispatch_inflight -= 1
                     if self._dispatch_inflight == 0:
@@ -853,7 +904,15 @@ class HorovodGlobalState:
         # Lifecycle spans: close each tensor's LC_SUBMITTED (opened at
         # enqueue) and stamp the cycle-tagged LC_NEGOTIATED instant.
         # Zero-substituted entries (built below) never enqueued, so they
-        # correctly get neither.
+        # correctly get neither.  negotiate_wait closes here too: from the
+        # round that took this rank's request to the round that agreed.
+        agreed_at = getattr(response, "_agreed_at", None)
+        if agreed_at is not None:
+            waits = [agreed_at - e.announced_at for e in entries
+                     if e.announced_at is not None]
+            if waits:
+                timeline_mod.phase_stats.add("negotiate_wait", sum(waits),
+                                             n=len(waits))
         if timeline_mod.ACTIVE is not None and timeline_mod.LIFECYCLE_ENABLED:
             cyc = getattr(response, "_cycle", None)
             for e in entries:

@@ -84,6 +84,10 @@ class TensorTableEntry:
     # ordinal of the optimizer update that submitted this tensor, if one
     # did: the ``step`` of its spans on the runtime's threads
     step: Optional[int] = None
+    # ``time.monotonic()`` of the ``pop_messages`` that handed this rank's
+    # request to a negotiation round: where ``queue_wait`` ends and
+    # ``negotiate_wait`` begins
+    announced_at: Optional[float] = None
 
 
 class TensorQueue:
@@ -139,13 +143,21 @@ class TensorQueue:
     def pop_messages(self) -> List[Request]:
         """Drain pending requests (one cycle's worth) —
         ``PopMessagesFromQueue`` (``tensor_queue.h:44``)."""
+        # queue_wait: from ``add`` to this hand-over, per tensor (a JOIN
+        # request or a re-queued one carries no stamp).  The same reading
+        # opens negotiate_wait on the tensor's table entry, so a re-queued
+        # request keeps its first.
+        waits = []
         with self._lock:
             out, self._pending = self._pending, []
-        # queue_wait: from ``add`` to this hand-over, per tensor (a JOIN
-        # request or a re-queued one carries no stamp).
-        now = time.monotonic()
-        waits = [now - r.__dict__.pop("_enqueued_at") for r in out
-                 if "_enqueued_at" in r.__dict__]
+            now = time.monotonic()
+            for r in out:
+                enqueued_at = r.__dict__.pop("_enqueued_at", None)
+                if enqueued_at is not None:
+                    waits.append(now - enqueued_at)
+                    entry = self._table.get(r.tensor_name)
+                    if entry is not None:
+                        entry.announced_at = now
         if waits:
             timeline_mod.phase_stats.add("queue_wait", sum(waits),
                                          n=len(waits))

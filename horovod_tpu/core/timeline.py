@@ -352,6 +352,8 @@ PHASES = (
     "update", "enqueue", "tree_unflatten", "optimizer_update",
     "queue_wait", "dispatch_wait", "program_call", "wfbp_dispatch",
     "state_fuse",
+    "negotiate_wait", "negotiate_recv", "negotiate_idle",
+    "cpu.loop", "cpu.dispatch", "cpu.update",
 )
 
 
@@ -361,16 +363,27 @@ class PhaseStats:
     busy cycles only), ``fuse`` (staging the fused buffer), ``collective``
     (host cost of dispatching the device collective), ``unfuse`` (results
     back to per-entry outputs), ``wait`` (framework-thread handle
-    synchronization), and the two hand-offs between threads,
-    ``queue_wait`` (tensor queue → background loop) and ``dispatch_wait``
-    (background loop → dispatcher thread).  On the calling thread:
+    synchronization), and a tensor's three waits between threads and
+    ranks, ``queue_wait`` (tensor queue → background loop),
+    ``negotiate_wait`` (announced by this rank → agreed by all) and
+    ``dispatch_wait`` (agreed → the dispatcher thread has it); inside
+    ``negotiate``, ``negotiate_recv`` (a round blocked on another rank's
+    frame while a tensor of this rank's is in flight), and beside it ``negotiate_idle`` (the rounds
+    ``negotiate`` leaves out, so that the two counts are the rounds).  On
+    the calling thread:
     ``update`` (the whole of ``DistributedOptimizer.update``) with its
     parts ``enqueue``, ``tree_unflatten``, ``optimizer_update`` and
     ``state_fuse`` (an update that was handed the inner state as a plain
     tree and joined it; none in a steady job);
     ``wfbp_dispatch`` (one call of an ``OverlappedTrainStep``); and
     ``program_call``, nested in the others, around every call of a jitted
-    program the framework owns.
+    program the framework owns.  Three names are **thread CPU seconds**
+    (``time.thread_time()``, user and system) and not wall time:
+    ``cpu.loop`` (the loop thread: rounds, what they ran inline and the
+    parks between), ``cpu.dispatch`` (the dispatcher thread, a response)
+    and ``cpu.update`` (the calling thread inside ``update``).  A thread
+    that holds the interpreter is on the CPU, so these bound from above
+    how long each thread kept it from the others.
 
     This is the aggregate companion to the traces: a trace answers "what
     happened when", this answers "where does a step's millisecond budget
@@ -387,7 +400,8 @@ class PhaseStats:
 
     def add(self, phase: str, seconds: float, n: int = 1) -> None:
         """``n`` is what ``count`` grows by: 1 event for most phases, the
-        number of tensors that waited for ``queue_wait``, and for
+        number of tensors that waited for ``queue_wait`` and
+        ``negotiate_wait``, the receives for ``negotiate_recv``, and for
         ``program_call`` the number of output arrays the program returned
         (so ``mean_ms`` there is host ms per output buffer)."""
         with self._lock:
@@ -476,7 +490,9 @@ class phase(span_ids):
     of every span inside it on its thread: one ``step=`` at the top names
     every span of that step.  Inside the block, ``record = False`` keeps
     the extent out of the accumulator (the span stays) and ``n`` sets what
-    ``count`` grows by; ``seconds`` holds the elapsed time afterwards."""
+    ``count`` grows by; ``annotate(**ids)`` gives the open span arguments
+    known only once its body has run; ``seconds`` holds the elapsed time
+    afterwards."""
 
     __slots__ = ("name", "record", "n", "seconds", "_span", "_t0")
 
@@ -496,6 +512,10 @@ class phase(span_ids):
             self._span.__enter__()
         self._t0 = time.monotonic()
         return self
+
+    def annotate(self, **ids) -> None:
+        if self._span is not None:
+            self._span.set_metadata(**ids)
 
     def __exit__(self, *exc) -> None:
         self.seconds = time.monotonic() - self._t0

@@ -22,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import threading
+import time
 from typing import Any, NamedTuple, Optional
 
 import numpy as np
@@ -29,7 +30,7 @@ import numpy as np
 from . import ops, wfbp
 from ...common.exceptions import HorovodInternalError
 from ...common.logging_util import get_logger
-from ...core.timeline import phase, program_call, scope
+from ...core.timeline import phase, phase_stats, program_call, scope
 from .compression import Compression
 
 log = get_logger(__name__)
@@ -46,6 +47,22 @@ _instance_ids = itertools.count()
 # span of that update carries, on the runtime's threads too.
 _update_ordinals = itertools.count()
 
+
+@contextlib.contextmanager
+def _update_span():
+    """One ``update()``: its ``hvd.update`` span under the call's ordinal,
+    and under ``cpu.update`` the CPU seconds (``time.thread_time()``, not
+    wall time) the calling thread spent inside it.  ``update`` less ``wait``
+    less ``cpu.update`` is what the caller stood there neither waiting on a
+    handle nor running."""
+    with phase("update", step=next(_update_ordinals)):
+        cpu_at = time.thread_time()
+        try:
+            yield
+        finally:
+            phase_stats.add("cpu.update", time.thread_time() - cpu_at)
+
+
 _DRAIN_TIMEOUT_S = 120.0
 _drain_lock = threading.Lock()
 _drain_queue: list = []      # (handle, deadline) pairs
@@ -53,8 +70,6 @@ _drain_thread: Optional[threading.Thread] = None
 
 
 def _drain_handles_async(handles, timeout_s: float = _DRAIN_TIMEOUT_S):
-    import time
-
     deadline = time.monotonic() + timeout_s
     global _drain_thread
     with _drain_lock:
@@ -67,8 +82,6 @@ def _drain_handles_async(handles, timeout_s: float = _DRAIN_TIMEOUT_S):
 
 
 def _drain_loop():
-    import time
-
     global _drain_thread
     while True:
         with _drain_lock:
@@ -381,7 +394,7 @@ def DistributedOptimizer(tx, op: Optional[str] = None,
     _window_seq = [0]
 
     def update(grads, state: DistributedState, params=None):
-        with phase("update", step=next(_update_ordinals)):
+        with _update_span():
             return _update(grads, state, params)
 
     def _update(grads, state: DistributedState, params):
@@ -542,7 +555,7 @@ def DistributedAdasumOptimizer(tx, compression=Compression.none,
     def update(grads, state, params=None):
         if "u" not in _jits:
             _jits["u"] = _named_jit("hvd_optimizer_update", tx.update)
-        with phase("update", step=next(_update_ordinals)):
+        with _update_span():
             with phase("optimizer_update"):
                 updates, inner = program_call(_jits["u"], grads, state,
                                               params)

@@ -20,7 +20,57 @@ from .helpers import reserve_port, run_distributed
 # leaf and the momentum as one buffer (the state lives fused, ISSUE 30).
 LEAVES = 4
 
-WORKER = """
+# One tensor at a time from ``add`` to its callback, the test's own clock
+# beside the program's stamps: ``chain(n)`` returns a row a tensor, the five
+# readings in order and what the six phases between them added.
+CHAIN = """
+def chain(n):
+    import time
+    import jax.numpy as jnp
+    from horovod_tpu.core.state import global_state
+    from horovod_tpu.core.timeline import phase_stats
+    st = global_state()
+    q, seen = st.tensor_queue, {}
+    add0, perform0 = q.add, st._perform_operation
+    def add(entry, request):
+        inner = entry.callback
+        def callback(status, e):
+            seen["callback"] = time.monotonic()
+            inner(status, e)
+        entry.callback = callback
+        seen["entry"] = entry
+        seen["add"] = time.monotonic()
+        add0(entry, request)
+    def perform(response, **kw):
+        seen["agreed"] = response._agreed_at
+        seen["dispatched"] = time.monotonic()
+        perform0(response, **kw)
+    q.add, st._perform_operation = add, perform
+    parts = ("queue_wait", "negotiate_wait", "dispatch_wait", "fuse",
+             "collective", "unfuse")
+    rows = []
+    try:
+        for i in range(n):
+            before = phase_stats.snapshot()
+            hvd.synchronize(hvd.allreduce_async(
+                jnp.full((8,), float(i)), name="chain.%d" % i))
+            after = phase_stats.snapshot()
+            rows.append({
+                "stamps": [seen["add"], seen["entry"].announced_at,
+                           seen["agreed"], seen["dispatched"],
+                           seen["callback"]],
+                "parts_ms": sum(after[k]["total_ms"]
+                                - before.get(k, {"total_ms": 0.0})["total_ms"]
+                                for k in parts if k in after),
+                "counts": {k: after[k]["count"]
+                           - before.get(k, {"count": 0})["count"]
+                           for k in parts if k in after}})
+    finally:
+        q.add, st._perform_operation = add0, perform0
+    return rows
+"""
+
+WORKER = CHAIN + """
 import glob, json, tempfile
 import jax, jax.numpy as jnp, optax
 from horovod_tpu.backend import xla
@@ -73,6 +123,15 @@ for _ in range(2):
     _, acc_state = acc.update(grads, acc_state, params)
 (_, acc_state), report["off_step"] = delta(
     lambda: acc.update(grads, acc_state, params))
+
+# A sleep inside update, on the calling thread (the fault site in
+# TensorQueue.add): wall time and no CPU.
+from horovod_tpu.common import faults
+faults.configure("enqueue.collective:action=delay_ms,50")
+(updates, state), report["slept"] = delta(
+    lambda: dopt.update(grads, state, params))
+faults.reset()
+report["chain"] = chain(5)
 
 # The one-program path.
 wstep = hvd.make_overlapped_train_step(loss_fn, inner)
@@ -128,7 +187,13 @@ STEP_COUNTS = {
     "update": 1, "fuse": 1, "enqueue": 1, "queue_wait": 1, "negotiate": 1,
     "collective": 1, "unfuse": 1, "wait": 1, "tree_unflatten": 0,
     "state_fuse": 0, "optimizer_update": 1, "program_call": 2 + LEAVES + 1,
+    "negotiate_wait": 1, "cpu.update": 1, "negotiate_recv": 0,
+    "cpu.dispatch": 0, "dispatch_wait": 0,
 }
+# The loop thread's own account (the idle rounds since its last busy one, its
+# CPU clock): handed on after the busy round, which the caller's snapshot may
+# or may not have seen, and how many rounds a step spans is the scheduler's.
+A_ROUND = {"negotiate_idle", "cpu.loop"}
 
 
 @pytest.mark.parametrize("name", sorted(STEP_COUNTS))
@@ -140,14 +205,19 @@ def test_one_step_adds_exactly(report, name):
 def test_one_step_enters_no_other_phase(report):
     from horovod_tpu.core.timeline import PHASES
 
-    entered = {k for k, v in report["step"].items() if v["count"]}
+    entered = {k for k, v in report["step"].items() if v["count"]} - A_ROUND
     assert entered == {k for k, n in STEP_COUNTS.items() if n}
     assert entered < set(PHASES)
     # The rest belong to the dispatcher thread, the one-program path, the
     # entry points that return a gradient tree and an update that is
-    # handed the optimizer's state as a plain tree.
-    assert set(PHASES) - entered == {"dispatch_wait", "wfbp_dispatch",
-                                     "tree_unflatten", "state_fuse"}
+    # handed the optimizer's state as a plain tree.  Of the six names of
+    # ISSUE 52 a step at one rank may not enter three: negotiate_recv (no
+    # other rank, so no frame to block on), cpu.dispatch and with it
+    # dispatch_wait (one rank dispatches inline, on the loop thread, whose
+    # CPU is cpu.loop's).
+    assert set(PHASES) - entered - A_ROUND == {
+        "dispatch_wait", "wfbp_dispatch", "tree_unflatten", "state_fuse",
+        "negotiate_recv", "cpu.dispatch"}
 
 
 def test_program_call_counts_outputs_worked_out_from_the_tree(report):
@@ -196,15 +266,15 @@ def test_xla_stats_gains_no_key(report):
 
 def test_off_step_of_local_aggregation_is_one_optimizer_program(report):
     entered = {k: v["count"] for k, v in report["off_step"].items()
-               if v["count"]}
+               if v["count"] and k not in A_ROUND}
     # accumulate returns the accumulator and the zero updates.
-    assert entered == {"update": 1, "optimizer_update": 1,
+    assert entered == {"update": 1, "cpu.update": 1, "optimizer_update": 1,
                        "program_call": 2 * LEAVES}
 
 
 def test_overlapped_step_is_one_wfbp_dispatch(report):
     entered = {k: v["count"] for k, v in report["wfbp"].items()
-               if v["count"]}
+               if v["count"] and k not in A_ROUND}
     assert entered == {"wfbp_dispatch": 1}
 
 
@@ -253,6 +323,59 @@ def test_dispatcher_thread_records_dispatch_wait(report):
     assert "dispatch_wait" not in report["step"]
 
 
+def test_dispatcher_thread_records_its_cpu(report):
+    # cpu.dispatch: one reading a response on the dispatcher thread, CPU
+    # seconds, so no more than the stretch's wall time.
+    assert report["pipelined"]["cpu.dispatch"]["count"] == 4
+    assert 0 <= report["pipelined"]["cpu.dispatch"]["ms"]
+    assert "cpu.dispatch" not in report["step"]
+
+
+def test_a_sleep_inside_update_moves_update_and_not_its_cpu(report):
+    slept, step = report["slept"], report["step"]
+    assert slept["update"]["count"] == slept["cpu.update"]["count"] == 1
+    assert slept["update"]["ms"] >= 50
+    assert slept["enqueue"]["ms"] >= 50           # where the sleep stood
+    assert slept["cpu.update"]["ms"] <= slept["update"]["ms"] - 40
+    # CPU seconds never pass the wall time of the same stretch.
+    assert 0 <= step["cpu.update"]["ms"] <= step["update"]["ms"] + 0.005
+
+
+def _assert_chain_has_no_hole(rows, dispatcher):
+    for row in rows:
+        stamps = row["stamps"]
+        assert all(t is not None for t in stamps), row
+        # add <= announced <= agreed <= dispatched <= callback
+        assert stamps == sorted(stamps), row
+        assert row["counts"]["queue_wait"] == 1
+        assert row["counts"]["negotiate_wait"] == 1
+        assert row["counts"].get("dispatch_wait", 0) == dispatcher
+        assert row["counts"]["collective"] == row["counts"]["unfuse"] == 1
+    gaps = [1e3 * (row["stamps"][-1] - row["stamps"][0]) - row["parts_ms"]
+            for row in rows]
+    # The six phases are disjoint stretches between add and the callback
+    # (totals are rounded to a microsecond each) ...
+    assert min(gaps) > -0.01, gaps
+    # ... and leave no hole: within 2 ms of the test's own clock.  The
+    # median, since one thread switch under load costs more than that.
+    assert sorted(gaps)[len(gaps) // 2] < 2.0, gaps
+
+
+def test_one_tensor_one_rank_has_no_hole_from_add_to_callback(report):
+    _assert_chain_has_no_hole(report["chain"], dispatcher=0)
+
+
+@pytest.mark.parametrize("mode", ["trace_inline", "trace_pipelined"])
+def test_agreeing_round_says_how_many_tensors_it_agreed_on(report, mode):
+    rounds = [ids for _, ids in _by_name(report[mode], "hvd.negotiate")]
+    busy = [ids for ids in rounds if ids["requests"]]
+    assert [ids["agreed"] for ids in busy] == [1, 1, 1]
+    assert all("agreed" not in ids for ids in rounds if not ids["requests"])
+    # One rank has no frame to block on.
+    assert _by_name(report[mode], "hvd.negotiate_recv") == []
+
+
+
 @pytest.mark.timeout(300)
 def test_two_ranks_stamp_queue_wait_and_dispatch_wait():
     out = run_distributed(2, """
@@ -286,6 +409,186 @@ print("SPANS_NP2_OK", rank, flush=True)
         "HOROVOD_JAX_COORDINATOR": f"127.0.0.1:{reserve_port()}"})
     for r, o in enumerate(out):
         assert f"SPANS_NP2_OK {r}" in o
+
+
+# Two ranks, one job (ISSUE 52): five plain steps, five with rank 1 asleep
+# 50 ms in TensorQueue.add, five with rank 0 asleep there, five single
+# tensors with the test's clock beside the program's stamps, two steps
+# under the profiler, and the totals after shutdown.
+TWO_RANKS = CHAIN + """
+import glob, json, tempfile, time
+import jax, jax.numpy as jnp, optax
+from horovod_tpu.common import faults
+from horovod_tpu.core.state import global_state
+from horovod_tpu.core.timeline import phase_stats
+
+params = {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}
+grads = {"w": jnp.full((4, 8), float(rank + 1)), "b": jnp.ones((8,))}
+dopt = hvd.DistributedOptimizer(optax.sgd(0.1))
+state = dopt.init(params)
+
+def step():
+    global state
+    updates, state = dopt.update(grads, state, params)
+    jax.block_until_ready(updates)
+
+def stretch(n):
+    rows = []
+    for _ in range(n):
+        before, t0 = phase_stats.snapshot(), time.monotonic()
+        step()
+        wall, after = time.monotonic() - t0, phase_stats.snapshot()
+        zero = {"count": 0, "total_ms": 0.0}
+        row = {k: [v["count"] - before.get(k, zero)["count"],
+                   v["total_ms"] - before.get(k, zero)["total_ms"]]
+               for k, v in after.items()}
+        row["wall_ms"] = 1e3 * wall
+        row["snapshot"] = {k: v["total_ms"] for k, v in after.items()}
+        rows.append(row)
+    hvd.barrier()
+    return rows
+
+for _ in range(3):
+    step()
+hvd.barrier()
+report = {"plain": stretch(5)}
+for late in (1, 0):
+    faults.configure("enqueue.collective:rank=%d:action=delay_ms,50" % late)
+    report["late%d" % late] = stretch(5)
+faults.reset()
+report["chain"] = chain(5)
+hvd.barrier()
+
+d = tempfile.mkdtemp()
+options = jax.profiler.ProfileOptions()
+options.python_tracer_level = 0
+jax.profiler.start_trace(d, profiler_options=options)
+step(); step()
+jax.profiler.stop_trace()
+path = glob.glob(d + "/plugins/profile/*/*.xplane.pb")[0]
+report["trace"] = [
+    [line.name, e.name, dict(e.stats), e.start_ns, e.start_ns + e.duration_ns]
+    for plane in jax.profiler.ProfileData.from_file(path).planes
+    for line in plane.lines for e in line.events
+    if e.name in ("hvd.negotiate", "hvd.negotiate_recv")]
+
+hvd.shutdown()                      # the loop has made its last round
+after = phase_stats.snapshot()
+report["rounds"] = after["negotiate"]["count"] \
+    + after["negotiate_idle"]["count"]
+report["cycle_count"] = global_state().cycle_count
+report["busy_rounds"] = after["negotiate"]["count"]
+report["cpu_loop_readings"] = after["cpu.loop"]["count"]
+print("REPORT " + json.dumps(report), flush=True)
+"""
+
+
+@pytest.fixture(scope="module")
+def two_ranks():
+    out = run_distributed(2, TWO_RANKS, timeout=300, extra_env={
+        "HOROVOD_DATA_PLANE": "xla",
+        "HOROVOD_JAX_COORDINATOR": f"127.0.0.1:{reserve_port()}"})
+    return [json.loads([x for x in o.splitlines()
+                        if x.startswith("REPORT ")][-1][len("REPORT "):])
+            for o in out]
+
+
+def _median_ms(rows, name, per_tensor=False):
+    values = sorted(ms / max(n, 1) if per_tensor else ms
+                    for n, ms in (row[name] for row in rows))
+    return values[len(values) // 2]
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("late", [1, 0])
+def test_the_rank_that_waits_reads_the_wait_and_the_late_rank_none(
+        two_ranks, late):
+    early = 1 - late
+    waited, slept = two_ranks[early]["late%d" % late], \
+        two_ranks[late]["late%d" % late]
+    plain = two_ranks[early]["plain"]
+    # The early rank's tensor waits for the late rank's announcement: the
+    # 50 ms, a tensor (100 leaves room for a loaded host, not for a hole).
+    grew = _median_ms(waited, "negotiate_wait", per_tensor=True) \
+        - _median_ms(plain, "negotiate_wait", per_tensor=True)
+    assert 40 <= grew <= 100, grew
+    # ... and its rounds sit blocked on the late rank's frames (rank 0) or
+    # on the coordinator's reply (rank 1) for most of it.
+    assert _median_ms(waited, "negotiate_recv") >= 20
+    assert _median_ms(waited, "negotiate_recv") \
+        <= _median_ms(waited, "negotiate_wait") + 5
+    # The late rank's own tensor is agreed on in the round that takes it,
+    # and its receives are counted only while it has one in flight: under
+    # 10 ms on a quiet host, one round of a loaded one at most (25), and
+    # never near what the early rank reads.
+    for name in ("negotiate_wait", "negotiate_recv"):
+        late_ms = _median_ms(slept, name, per_tensor=name == "negotiate_wait")
+        assert late_ms < 25, (name, late_ms)
+        assert late_ms < 0.5 * _median_ms(waited, name), (name, late_ms)
+    # The sleep is wall time of update and none of its CPU.
+    assert _median_ms(slept, "update") >= 50
+    assert _median_ms(slept, "cpu.update") \
+        <= _median_ms(slept, "update") - 40
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("rank", [0, 1])
+def test_a_blocked_receive_lies_inside_its_round(two_ranks, rank):
+    for name in ("plain", "late0", "late1"):
+        for row in two_ranks[rank][name]:
+            snap = row["snapshot"]
+            # negotiate_recv counts every round this rank had a tensor in
+            # flight in, negotiate the rounds with a request or a response
+            # and negotiate_idle the others: a receive is inside one of
+            # the two (the issue's ``negotiate >= negotiate_recv`` cannot
+            # hold: the rounds a tensor waits through are idle ones).
+            assert snap["negotiate_recv"] <= snap["negotiate"] \
+                + snap["negotiate_idle"] + 0.005, (name, snap)
+            # CPU seconds against the wall time of the same stretch, a
+            # thread each (a count a round or a response may land a
+            # reading from before the stretch: 1 ms).
+            for cpu in ("cpu.loop", "cpu.dispatch", "cpu.update"):
+                assert 0 <= row[cpu][1] <= row["wall_ms"] + 1, (name, cpu)
+            assert row["cpu.update"][1] <= row["update"][1] + 0.005
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("rank", [0, 1])
+def test_rounds_are_negotiate_and_negotiate_idle(two_ranks, rank):
+    r = two_ranks[rank]
+    assert r["rounds"] == r["cycle_count"] > 100
+    # The loop hands its idle rounds and its CPU clock on after a round that
+    # had work and when it ends: a reading a busy round, and the last.
+    assert r["busy_rounds"] <= r["cpu_loop_readings"] <= r["busy_rounds"] + 1
+    # A step of the late stretches spans the 50 ms of rounds.
+    assert sum(row["negotiate_idle"][0] for row in r["late1"]) > 50
+
+
+@pytest.mark.timeout(400)
+def test_one_tensor_two_ranks_has_no_hole_from_add_to_callback(two_ranks):
+    for r in two_ranks:
+        _assert_chain_has_no_hole(r["chain"], dispatcher=1)
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("rank", [0, 1])
+def test_trace_holds_the_blocked_receive_inside_its_round(two_ranks, rank):
+    events = two_ranks[rank]["trace"]
+    rounds = {ids["cycle"]: (line, start, end)
+              for line, name, ids, start, end in events
+              if name == "hvd.negotiate"}
+    recvs = [(line, ids, start, end) for line, name, ids, start, end in events
+             if name == "hvd.negotiate_recv"]
+    assert recvs
+    for line, ids, start, end in recvs:
+        assert ids["peer"] == 1 - rank            # two ranks: the other one
+        round_line, round_start, round_end = rounds[ids["cycle"]]
+        assert line == round_line == "horovod-backgro"
+        assert round_start <= start <= end <= round_end
+    agreed = [ids["agreed"] for _, name, ids, _, _ in events
+              if name == "hvd.negotiate" and "agreed" in ids]
+    assert agreed == [1, 1]
+
 
 
 # ---------------------------------------------------------------------------
@@ -408,13 +711,60 @@ def test_tensor_queue_stamps_step_and_queue_wait(stats):
     assert stats.snapshot()["queue_wait"]["count"] == 2
 
 
+def test_requeued_request_keeps_its_first_announcement(stats):
+    from horovod_tpu.core.messages import Request
+    from horovod_tpu.core.tensor_queue import TensorQueue, TensorTableEntry
+
+    q = TensorQueue()
+    entry = TensorTableEntry("t0")
+    assert entry.announced_at is None
+    q.add(entry, Request(tensor_name="t0"))
+    popped = q.pop_messages()
+    first = entry.announced_at
+    assert first is not None
+    q.push_messages(popped)
+    assert q.pop_messages() == popped
+    assert entry.announced_at == first
+    # A request whose entry has left the table stamps nothing.
+    q.remove("t0")
+    q.push_messages(popped)
+    q.pop_messages()
+    assert entry.announced_at == first
+
+
+def test_annotate_reaches_an_open_span_and_needs_none(stats, monkeypatch):
+    from horovod_tpu.core import timeline
+
+    with timeline.phase("negotiate") as span:
+        span.annotate(agreed=3)                 # no profiler: nothing to do
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **ids):
+            seen.append((name, ids))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+        def set_metadata(self, **ids):
+            seen.append(ids)
+
+    monkeypatch.setattr(timeline, "_annotation", Annotation)
+    with timeline.phase("negotiate", cycle=4) as span:
+        span.annotate(agreed=3)
+    assert seen == [("hvd.negotiate", {"cycle": 4}), {"agreed": 3}]
+
+
 def test_phases_are_the_catalogued_and_documented_names():
     import os
 
     from horovod_tpu.core import metrics
     from horovod_tpu.core.timeline import PHASES
 
-    assert len(set(PHASES)) == len(PHASES) == 14
+    assert len(set(PHASES)) == len(PHASES) == 20
     doc = open(os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "docs", "observability.md")).read()
     section = doc.split("## Reading a step on the profiler's clock")[1] \
