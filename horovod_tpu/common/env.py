@@ -295,7 +295,11 @@ HOROVOD_TIMELINE_CONTROL_PLANE = "HOROVOD_TIMELINE_CONTROL_PLANE"
 
 # -- core runtime tunables (reference common.h:64-91) --
 HOROVOD_FUSION_THRESHOLD = "HOROVOD_FUSION_THRESHOLD"  # bytes, default 64MB
-HOROVOD_CYCLE_TIME = "HOROVOD_CYCLE_TIME"  # float ms, default 1.0 here (5.0 in ref)
+# float ms, default 5.0 as in the reference: the longest the background
+# loop parks after an idle round (it parks IDLE_PARK_FLOOR_MS after the first
+# and doubles from there; an enqueue ends any park at once).  Under the
+# floor it is both floor and cap.
+HOROVOD_CYCLE_TIME = "HOROVOD_CYCLE_TIME"
 HOROVOD_CACHE_CAPACITY = "HOROVOD_CACHE_CAPACITY"
 HOROVOD_STALL_CHECK_DISABLE = "HOROVOD_STALL_CHECK_DISABLE"
 HOROVOD_STALL_CHECK_TIME_SECONDS = "HOROVOD_STALL_CHECK_TIME_SECONDS"
@@ -341,9 +345,17 @@ HOROVOD_DATA_PLANE = "HOROVOD_DATA_PLANE"  # "xla" | "tcp" | "auto"
 HOROVOD_JAX_COORDINATOR = "HOROVOD_JAX_COORDINATOR"
 
 DEFAULT_FUSION_THRESHOLD = 64 * 1024 * 1024
-# Reference default cycle is 5 ms (operations.cc:458); our control plane is
-# Python so we default lower to keep small-tensor latency reasonable.
-DEFAULT_CYCLE_TIME_MS = 1.0
+# The longest an idle background loop parks between rounds (core/state.py
+# ``_background_loop``): the reference's cycle, 5 ms (operations.cc:458).
+# Every round is a lockstep exchange between Python processes, and at a
+# round a millisecond sixty of them a ResNet-50 step took the interpreter
+# from the coordinator's calling thread (PERF.md §6, PRs 52 and 54).
+# Small-tensor latency keeps its pace through the floor: the first idle
+# round after a round with work parks IDLE_PARK_FLOOR_MS, every further one
+# twice the last up to this cap, and a round with a request or a response
+# starts over.  HOROVOD_CYCLE_TIME moves the cap alone.
+DEFAULT_CYCLE_TIME_MS = 5.0
+IDLE_PARK_FLOOR_MS = 1.0
 DEFAULT_CACHE_CAPACITY = 1024
 DEFAULT_STALL_CHECK_TIME_SECONDS = 60
 DEFAULT_STALL_SHUTDOWN_TIME_SECONDS = 0  # disabled

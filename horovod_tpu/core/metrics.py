@@ -95,6 +95,11 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                    "(nonzero on the coordinator only)"),
     "tensor_queue_depth": (
         "gauge", "tensors in flight (submitted, not yet completed)"),
+    "controller_idle_park_ms": (
+        "gauge", "the park the background loop last took before a round "
+                 "with work, in ms: 0 while rounds with work follow each "
+                 "other, up to HOROVOD_CYCLE_TIME after an idle stretch "
+                 "(set on rounds with work only)"),
     # -- collectives --
     "collective_latency_seconds": (
         "histogram", "host-side dispatch latency per negotiated response, "

@@ -79,6 +79,9 @@ class HorovodGlobalState:
         # rather than a floor under every dispatch's latency).
         self._wake = threading.Event()
         self._last_cycle_had_work = False
+        # The park the loop last took, in ms, 0 after a round with work
+        # (_next_idle_park_ms).  The loop thread's alone.
+        self._idle_park_ms = 0.0
         # What phase_stats gets of the idle rounds (negotiate_idle) and of
         # the loop thread's CPU (cpu.loop), kept here until a round has
         # work (_account_loop): an idle round costs two additions, not a
@@ -606,15 +609,17 @@ class HorovodGlobalState:
                     # recv provides the backstop: an eager rank parks in
                     # the kernel waiting for its peers, it does not burn
                     # CPU.
+                    self._idle_park_ms = 0.0
                     continue
-                # Idle: park on the wake event with the (autotuned) cycle
-                # time as the backstop, so an enqueue starts the next
-                # negotiation immediately instead of after the residue of
-                # a fixed sleep.
-                cycle = self.cycle_time_ms / 1000.0
-                elapsed = time.monotonic() - start
-                if elapsed < cycle:
-                    self._wake.wait(cycle - elapsed)
+                # Idle: park on the wake event, so an enqueue starts the
+                # next negotiation immediately instead of after the residue
+                # of a fixed sleep.  The park backs off while nothing
+                # arrives (env.DEFAULT_CYCLE_TIME_MS has why).
+                self._idle_park_ms = self._next_idle_park_ms()
+                left = self._idle_park_ms / 1000.0 \
+                    - (time.monotonic() - start)
+                if left > 0:
+                    self._wake.wait(left)
         except BaseException as e:  # noqa: BLE001
             log.error("background loop died: %s", e, exc_info=True)
             # Sticky failure (NCCL async-watchdog role): the NEXT enqueue on
@@ -649,6 +654,14 @@ class HorovodGlobalState:
                 self.timeline.close()
             self._push_stop.set()
             self.shutdown_complete.set()
+
+    def _next_idle_park_ms(self) -> float:
+        """How long the loop parks after an idle round: the floor after
+        the first idle round that follows work, twice the last park after
+        every further one, never above ``cycle_time_ms`` (the
+        environment's or the autotuner's), which under the floor is both."""
+        return min(self.cycle_time_ms, max(env_mod.IDLE_PARK_FLOOR_MS,
+                                           2.0 * self._idle_park_ms))
 
     def _dump_flight_recorder(self, error: BaseException) -> None:
         """Loop-death post-mortem: dump the flight-recorder ring + metrics
@@ -731,6 +744,9 @@ class HorovodGlobalState:
             span.record = self._last_cycle_had_work
         if self._last_cycle_had_work:
             metrics.observe("controller_cycle_seconds", span.seconds)
+            # How far the back-off had grown when work arrived (busy rounds
+            # only: an idle round stays two additions).
+            metrics.set_gauge("controller_idle_park_ms", self._idle_park_ms)
             flight_recorder.record("cycle", n=self.cycle_count,
                                    requests=len(requests),
                                    responses=len(response_list.responses))
@@ -1270,6 +1286,8 @@ class HorovodGlobalState:
         if not self.initialized.is_set() or self.shutdown_complete.is_set():
             return
         self.shutdown_requested.set()
+        # Leaving does not wait out a grown park.
+        self._wake.set()
         self.shutdown_complete.wait(timeout=60)
         try:
             atexit.unregister(self.shutdown)
@@ -1311,6 +1329,9 @@ def abort_for_reshard(epoch: Optional[int] = None) -> None:
     try:
         st.mesh.send_abort(
             f"elastic reshard to epoch {epoch}: re-rendezvous in place")
+        # A parked loop meets the flag in its next round: now, not after
+        # what is left of a grown park.
+        st._wake.set()
     except Exception as e:  # noqa: BLE001 — best-effort fast path; the
         # progress deadline still unblocks the slow way
         log.debug("reshard abort broadcast failed: %s", e)

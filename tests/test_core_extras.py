@@ -113,8 +113,8 @@ class TestParameterManager:
 
 
     def test_idle_cycles_do_not_advance_samples(self, tmp_path):
-        """The background loop ticks every cycle_time_ms even when idle;
-        zero-byte cycles must not close samples (else the tuner scores
+        """The background loop ticks when idle too (a park of 1 ms doubling
+        to cycle_time_ms between rounds); zero-byte cycles must not close samples (else the tuner scores
         noise — reference parameter_manager.cc:148-159 steps by actual
         reductions)."""
         pm = ParameterManager(enabled=True, warmup_samples=0,

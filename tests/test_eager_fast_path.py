@@ -231,20 +231,26 @@ def test_controller_topology_mismatch_is_loud():
 
 def test_wake_event_cuts_idle_latency():
     """An enqueue while the background loop is parked must start the next
-    cycle immediately: with a deliberately huge cycle time, a round trip
-    still completes far inside one cycle period."""
+    cycle immediately: with a deliberately huge cycle time, and a loop that
+    has backed off to parks of hundreds of ms (ISSUE 54: the cycle time is
+    the longest an idle loop parks, reached by doubling from 1 ms), a round
+    trip still completes far inside one park."""
     out = run_distributed(2, """
 import time
+from horovod_tpu.core.state import global_state
 x = np.ones(16, np.float32)
 # warm (negotiate + cache)
 hvd.allreduce(x, op=hvd.Sum, name="wake.t")
+# 1 + 2 + ... + 256 ms of idle rounds, and the next park is the cap's.
+time.sleep(1.2)
+assert global_state()._idle_park_ms >= 256, global_state()._idle_park_ms
 t0 = time.perf_counter()
 for i in range(3):
     hvd.allreduce(x, op=hvd.Sum, name="wake.t")
 dt = (time.perf_counter() - t0) / 3
-# cycle time is 500 ms: without the wake event each op waits out the
-# remainder of a sleep; with it the three ops must finish well inside
-# ONE cycle period each (generous 450 ms bound for loaded boxes).
+# the parks have grown to 500 ms: without the wake event the first op
+# waits out the remainder of one; with it the three ops must finish well
+# inside ONE such park each (generous 450 ms bound for loaded boxes).
 assert dt < 0.45, f"enqueue->complete took {dt:.3f}s with 500ms cycles"
 print("WAKE_OK", rank, flush=True)
 """, extra_env={"HOROVOD_CYCLE_TIME": "500"}, timeout=240)
