@@ -185,13 +185,15 @@ def _attend_bwd(rule, interpret, kept, do):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def attention(q, k, v, rule, *, interpret: bool = False):
+def attention(q, k, v, rule, *, interpret: bool = False, scale=None):
     """Softmax attention of ``q [b, s, h, d]`` on ``k [b, s, h_kv, d]`` and
-    ``v [b, s, h_kv, dv]`` under ``rule``, scores scaled by ``d ** -0.5``;
+    ``v [b, s, h_kv, dv]`` under ``rule``, scores scaled by ``d ** -0.5``
+    (by ``scale`` where one is given: Granite's ``attention_multiplier``);
     ``h_kv`` divides ``h`` and KV head ``j`` serves query heads ``j*h/h_kv``
     to ``(j+1)*h/h_kv - 1``.  Returns ``[b, s, h, dv]``.  Differentiable: the
     forward is the library's kernel, the backward
-    ``kernels/masked_attention_bwd.py``'s one."""
+    ``kernels/masked_attention_bwd.py``'s one, both of which take ``q``
+    already scaled and so know nothing of the scale."""
     _, s, h, d = q.shape
     if not takes(rule, s, d, v.shape[3]):
         raise ValueError(f"no kernel under {rule} for {s} positions, head "
@@ -199,8 +201,10 @@ def attention(q, k, v, rule, *, interpret: bool = False):
     hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
     # The copies into and out of the kernels' [heads, positions, width]
     # layout apart from the kernels, which alone lie under the rule's scope.
+    if scale is None:
+        scale = d ** -0.5
     with scope("attn.layout"):
-        q, k, v = hsd(q * jnp.asarray(d ** -0.5, q.dtype)), hsd(k), hsd(v)
+        q, k, v = hsd(q * jnp.asarray(scale, q.dtype)), hsd(k), hsd(v)
     out = _attend(q, k, v, rule, interpret)
     with scope("attn.layout"):
         return out.transpose(0, 2, 1, 3)
@@ -228,15 +232,17 @@ def _probabilities(scores, rule, dtype):
     return jax.nn.softmax(scores, axis=-1).astype(dtype)
 
 
-def einsum(q, k, v, rule):
+def einsum(q, k, v, rule, scale=None):
     """:func:`attention` through the einsum, KV heads grouped, the mask from
     iota comparisons: below the kernel's smallest shape, and off the TPU."""
     b, s, h, dh = q.shape
     h_kv = k.shape[2]
+    if scale is None:
+        scale = dh ** -0.5
     with scope("attn.einsum"):
         q = q.reshape(b, s, h_kv, h // h_kv, dh)
         scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
-                            preferred_element_type=jnp.float32) * dh ** -0.5
+                            preferred_element_type=jnp.float32) * scale
         return jnp.einsum("bngqk,bknd->bqngd",
                           _probabilities(scores, rule, q.dtype), v) \
             .reshape(b, s, h, v.shape[3])

@@ -29,11 +29,33 @@ runs once a head over the pair's lanes (the MXU is 128 wide whatever is
 asked of it) and each head keeps its half.  The forward kernel writes ``y``
 and, for the backward pass, the state every chunk *started* from
 (``[chunks, heads, P, N]`` fp32: 33.5 MB a layer at 8192 positions, 16 heads
-of 64 and a state of 128; a state a token would be 128 times that).  The
+of 64 and a state of 128, nemotron-3-super-120b-a12b's share of a mixer; 134
+MB at 64 heads, granite-4.0-h-micro's whole mixer; a state a token would be
+128 times that).  The
 backward kernel starts from those, carries the state's cotangent from the
 last chunk to the first, and gives the cotangents of ``x``, ``B``, ``C``,
 ``dt`` and ``cum``; the sums that turn ``cum``'s into ``dt``'s and ``a``'s
 are a few KB and run in XLA, by autodiff of the cumulative sum that made it.
+
+**A group's size.**  The heads of a group are unrolled inside a grid step,
+a lane pair at a time, so the group sets what a step holds and how long its
+program is.  At 16 heads a group (Nemotron's): 8 pairs, ``x`` ``[128, 1024]``,
+0.5 MB of states in VMEM, 33.5 MB of starting states a layer.  At 64 heads in
+one group (Granite's): 32 pairs, ``x`` ``[128, 4096]`` (1 MB a block, two
+buffers each of ``x``, ``y`` or ``dy`` and ``dx``), 2 MB of states and 2 MB
+of their cotangent, blocks of 2 MB of starting states in and out, all well
+inside :data:`_VMEM_LIMIT`; the same kernels take both, compiled for a v5e in
+5 s forward and 9 s backward at 64 heads
+(``tests/test_granite_compile.py``).  ``B`` and ``C`` are read once a grid
+step whatever the group's size, and ``C B^T`` is computed once for all its
+heads, so a larger group amortises them further; a grid axis over the
+group's heads would read them once a tile instead.  ``PERF.md`` section 6
+(PR 56) has both shapes' times on the chip.
+
+**The chunk** is 128 (:data:`CHUNK`) and :func:`takes` refuses any other: a
+release's own chunk (granite-4.0-h-micro's ``mamba_chunk_size`` 256) is a
+blocking of the same sum and changes no result, so a configuration keeps
+that key as published and runs these kernels at 128.
 
 **Precision**: ``x``, ``B``, ``C`` and ``y`` in bf16; ``dt``, ``cum``, the
 decays and the states in fp32; the products on the MXU in bf16 with fp32

@@ -31,6 +31,20 @@ all the groups add up to the whole mixer's (``tests/test_nemotron.py``).  On
 one chip that part goes on to the next layer as it is; nothing stands in for
 the sum over the chips.
 
+**One group is no share.**  granite-4.0-h-micro's mixer has 64 heads of 64
+in **one** group (``mamba_groups=1``): the gated norm then runs over all
+4096 channels and every head reads the same ``B`` and ``C``, so the norm's
+mean square couples every head with every other and a subset of the heads
+is no part of any sum.  ``mamba_groups_held`` can only be ``None`` or
+``(0,)`` there, both the whole mixer (``tests/test_granite.py``); sharing
+such a mixer among chips would take a reduction across them inside the
+norm, which this module does not have (``ROADMAP.md``, Queue 2).  That
+configuration holds its mixers whole, and its scan runs a grid step over the
+whole group: 64 heads, 32 lane pairs, 2 MB of states in VMEM
+(``kernels/ssd_scan.py``).  Granite applies its ``residual_multiplier`` to
+what this module returns, in ``Block``; nothing of the four muP scalars is
+in here.
+
 Loaded where a layer of kind ``mixer="mamba2"`` is built, not with
 ``horovod_tpu.models``.
 """

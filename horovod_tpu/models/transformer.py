@@ -1,5 +1,5 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2, Nemotron-H, JoyAI-LLM-Flash and Qwen3-Next presets.
+LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next and Granite-4.0-H presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -41,9 +41,20 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   Gated DeltaNet mixers of ``models/gated_delta.py`` over
   ``kernels/gated_delta.py``, a gate on the attention's output, rotary
   positions over a share of a head, norms whose scale is ``1 + w``, a gated
-  shared expert), their expert layer
+  shared expert; and granite-4.0-h-micro ``granite_4_0_h_micro_config()``:
+  a Mamba-2 mixer or attention without positions *and* a dense SwiGLU in
+  every layer, under four muP scalars, ``embedding_multiplier`` on the
+  embedding, ``residual_multiplier`` on both branches of a block,
+  ``attention_multiplier`` as the scores' scale and ``logits_scaling`` under
+  the logits, each a field whose default leaves every other program as it
+  was), their expert layer
   :func:`horovod_tpu.parallel.moe.moe_ffn` (``docs/moe.md``), their masks
-  that are rules ``kernels/masked_attention.py``'s.
+  that are rules ``kernels/masked_attention.py``'s;
+- ``remat``: every block under ``nn.remat``, its forward pass run again in
+  the backward pass from the block's input, the one activation kept
+  (granite-4.0-h-micro's cell, whose 12.35 GB of weights, gradients and
+  AdamW state leave no room for ten layers' activations; the mixers' and
+  attention's ``custom_vjp`` kernels run their forward twice then).
 """
 
 from __future__ import annotations
@@ -229,6 +240,16 @@ class TransformerConfig:
     gdn_key_dim: int = 128
     gdn_value_dim: int = 128
     gdn_conv: int = 4
+    # Granite's four scalars (``granitemoehybrid``; each at its default
+    # leaves the program as it was): the embedding times
+    # embedding_multiplier; every branch (mixer and FFN) times
+    # residual_multiplier before it is added to the stream; attention's
+    # scores times attention_multiplier in place of head_dim ** -0.5 (None);
+    # the logits divided by logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -428,6 +449,30 @@ def qwen3_next_80b_a3b_config(**overrides) -> TransformerConfig:
         layer_pattern=pattern), **overrides})
 
 
+def granite_4_0_h_micro_config(**overrides) -> TransformerConfig:
+    """granite-4.0-h-micro (ibm-granite/granite-4.0-h-micro ``config.json``,
+    ``granitemoehybrid``): 40 layers in periods of ten, the sixth of each
+    (5, 15, 25, 35) causal attention of 32 query heads on 8 KV heads of 64
+    without positions, the other nine Mamba-2 mixers of 64 heads of 64 in
+    one group, state 128, 4 taps with a bias; every layer a mixer and a
+    dense SwiGLU of width 8192 (no experts), each under its own RMSNorm at
+    1e-5; no biases, a tied readout, and four scalars: the embedding times
+    12, each branch times 0.22, the scores times 1/64 and the logits over
+    8."""
+    pattern = tuple(
+        LayerKind(0, False, "attention" if i == 5 else "mamba2", "dense")
+        for i in range(10))
+    return TransformerConfig(**{**dict(
+        vocab_size=100352, num_layers=40, num_heads=32, num_kv_heads=8,
+        head_width=64, d_model=2048, d_ff=8192, d_ff_dense=8192,
+        max_len=131072, causal=True, norm="rmsnorm", norm_eps=1e-5,
+        positions="rope", use_bias=False, tie_embeddings=True, ffn="dense",
+        mamba_heads=64, mamba_head_dim=64, mamba_groups=1, mamba_state=128,
+        mamba_conv=4, mamba_chunk=128, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.015625,
+        logits_scaling=8.0, layer_pattern=pattern), **overrides})
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """For tests and the multichip dryrun: tiny shapes, same code paths."""
     return TransformerConfig(**{**dict(
@@ -564,17 +609,20 @@ class Attention(nn.Module):
 
             with scope("attn.ring"):
                 out = ring_attention(q, k, v, axis_name=cfg.seq_axis,
-                                     causal=cfg.causal)
+                                     causal=cfg.causal,
+                                     sm_scale=cfg.attention_multiplier)
         elif cfg.attention == "ulysses":
             from ..parallel.ulysses import ulysses_attention
 
             with scope("attn.ulysses"):
                 out = ulysses_attention(q, k, v, axis_name=cfg.seq_axis,
-                                        causal=cfg.causal)
+                                        causal=cfg.causal,
+                                        sm_scale=cfg.attention_multiplier)
         elif cfg.attention == "full":
             out = _scaled_dot_attention(q, k, v, cfg.causal, dh,
                                         block_diffusion=cfg.block_diffusion,
-                                        window=self.kind.window)
+                                        window=self.kind.window,
+                                        scale=cfg.attention_multiplier)
         else:
             raise ValueError(f"unknown attention mode {cfg.attention!r}")
 
@@ -601,7 +649,7 @@ _FLASH_BLOCK = 1024
 _FLASH_MIN_SEQ = 4096
 
 
-def _flash_attention(q, k, v, causal: bool, dh: int):
+def _flash_attention(q, k, v, causal: bool, dh: int, scale=None):
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes,
         flash_attention,
@@ -616,15 +664,19 @@ def _flash_attention(q, k, v, causal: bool, dh: int):
     with scope("attn.layout"):
         q, k, v = bhsd(q), bhsd(k), bhsd(v)
     with scope("attn.flash"):
-        o = flash_attention(q, k, v, causal=causal, sm_scale=dh ** -0.5,
+        o = flash_attention(q, k, v, causal=causal,
+                            sm_scale=dh ** -0.5 if scale is None else scale,
                             block_sizes=blocks)
     with scope("attn.layout"):
         return o.transpose(0, 2, 1, 3)
 
 
 def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
-                          block_diffusion: int = 0, window: int = 0):
+                          block_diffusion: int = 0, window: int = 0,
+                          scale=None):
     """Single-device attention for the "full" mode, [b, s, h, d] layout.
+    ``scale``: what the scores are multiplied by where that is not
+    ``dh ** -0.5`` (``cfg.attention_multiplier``).
 
     A mask that is a rule of ``kernels/masked_attention.py`` (the
     block-diffusion rule with ``block_diffusion``, a block length, in place
@@ -650,8 +702,8 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
     if rule is not None:
         if jax.default_backend() == "tpu" \
                 and masked_attention.takes(rule, s, dh):
-            return masked_attention.attention(q, k, v, rule)
-        return masked_attention.einsum(q, k, v, rule)
+            return masked_attention.attention(q, k, v, rule, scale=scale)
+        return masked_attention.einsum(q, k, v, rule, scale)
     if grouped:
         # Grouped KV heads under no mask: each KV head repeated for the query
         # heads it serves, then the paths below.
@@ -660,13 +712,16 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
             k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
             and s % _FLASH_BLOCK == 0 and dh % 128 == 0:
-        return _flash_attention(q, k, v, causal, dh)
-    if jax.default_backend() == "tpu" \
+        return _flash_attention(q, k, v, causal, dh, scale)
+    # The short kernel scales by dh ** -0.5 inside: a scale of its own goes
+    # through the einsum.
+    if jax.default_backend() == "tpu" and scale is None \
             and short_attention.takes(s, dh, q.shape[2], q.dtype):
         with scope("attn.short"):
             return short_attention.attention(q, k, v, causal)
     with scope("attn.einsum"):
-        scale = dh ** -0.5
+        if scale is None:
+            scale = dh ** -0.5
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32) * scale
         if causal:
@@ -703,6 +758,14 @@ class ShortConv(nn.Module):
                           "out_proj")(y)
 
 
+def _branch(cfg: TransformerConfig, y):
+    """A block's branch as it is added to the stream: times
+    ``cfg.residual_multiplier`` where that is not 1 (Granite)."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     kind: LayerKind = LayerKind()
@@ -722,6 +785,10 @@ class Block(nn.Module):
             elif mixer == "attention" and cfg.kv_lora_rank:
                 from .deepseek import LatentAttention
 
+                if cfg.attention_multiplier is not None:
+                    raise ValueError("latent attention scales by its keys' "
+                                     "width: no attention_multiplier")
+
                 y = LatentAttention(cfg, self.kind, name="attn")(y, positions)
             elif mixer == "attention":
                 y = Attention(cfg, self.kind, name="attn")(y, positions)
@@ -738,7 +805,7 @@ class Block(nn.Module):
             else:
                 raise ValueError(f"unknown mixer {mixer!r}")
             with scope("norm"):
-                x = x + y
+                x = x + _branch(cfg, y)
         if ffn == "none":
             return x
         with scope("norm"):
@@ -784,7 +851,7 @@ class Block(nn.Module):
         else:
             raise ValueError(f"unknown ffn {ffn!r}")
         with scope("norm"):
-            return x + y
+            return x + _branch(cfg, y)
 
     def _experts(self, y, router_input=None):
         """The sparse-expert FFN; its MoEStats are sown into the ``moe``
@@ -980,6 +1047,8 @@ class Transformer(nn.Module):
             raise ValueError(f"unknown positions {cfg.positions!r}")
         with scope("embed"):
             x = embed(tokens)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
             if cfg.positions == "learned":
                 x = x + self._learned_positions(s).astype(cfg.dtype)
         if cfg.block_diffusion:
@@ -1006,6 +1075,9 @@ class Transformer(nn.Module):
         else:
             head = _dense(cfg, cfg.vocab_size, (None, cfg.model_axis),
                           "lm_head")
+        if cfg.logits_scaling != 1.0:
+            readout = head
+            head = lambda y: readout(y) / cfg.logits_scaling  # noqa: E731
         with scope("head"):
             logits = head(x)
         if not cfg.mtp_modules:

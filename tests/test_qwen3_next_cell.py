@@ -104,7 +104,7 @@ def test_configuration_keeps_every_published_width():
             item["name"]
     # The one exact count of the benchmark (the newest configuration's test
     # holds it; the older cells' tests count at least their own).
-    assert len(bench["configs"]) == 9 and len(bench["workloads"]) == 11
+    assert len(bench["configs"]) >= 9 and len(bench["workloads"]) >= 11
     listed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
               if CELL in m.get("workloads", [])}
     assert {"gated_delta_ms_step", "gated_delta_roofline_pct",

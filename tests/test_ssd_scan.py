@@ -1,6 +1,6 @@
 """The chunked state-space scan (``kernels/ssd_scan.py``, Mamba-2's, which
-Nemotron's mixers run): the chunked form against the token-by-token
-recurrence of the plain reference, the two pallas kernels in interpret mode
+Nemotron's and Granite's mixers run): the chunked form against the
+token-by-token recurrence of the plain reference, the two pallas kernels in interpret mode
 against the chunked form, and what ``takes()`` refuses.
 ``tests/test_nemotron.py`` holds the mixer and the model.
 """
@@ -59,8 +59,9 @@ def test_the_chunked_scan_is_the_recurrence(s, heads, groups, chunk):
 
 
 @pytest.mark.parametrize("heads,p,groups", [(16, 64, 1), (32, 64, 2),
-                                            (8, 128, 1)],
-                         ids=["the_cells", "two_groups", "heads_of_128"])
+                                            (8, 128, 1), (64, 64, 1)],
+                         ids=["the_cells", "two_groups", "heads_of_128",
+                              "granites_64_heads_one_group"])
 def test_the_kernels_are_the_chunked_form(heads, p, groups):
     """The two pallas kernels in interpret mode against ``chunked`` on the
     same bf16 inputs: ``y`` to bf16's rounding, the cotangents of ``x``,
@@ -88,11 +89,13 @@ def test_the_kernels_are_the_chunked_form(heads, p, groups):
 
 @pytest.mark.parametrize("shape,taken", [
     ((8192, 16, 64, 1, 128), True), ((8192, 128, 64, 8, 128), True),
+    ((8192, 64, 64, 1, 128), True),
     ((8192, 16, 64, 1, 64), False), ((8100, 16, 64, 1, 128), False),
     ((8192, 4, 64, 1, 128), False), ((8192, 16, 32, 1, 128), False),
     ((8192, 16, 64, 3, 128), False)],
-    ids=["the_cells", "the_whole_mixer", "state_64", "no_whole_chunks",
-         "four_heads", "heads_of_32", "heads_in_no_groups"])
+    ids=["the_cells", "the_whole_mixer", "granites_whole_mixer", "state_64",
+         "no_whole_chunks", "four_heads", "heads_of_32",
+         "heads_in_no_groups"])
 def test_takes_refuses_what_the_kernels_cannot_run(shape, taken):
     from horovod_tpu.kernels import ssd_scan
 
