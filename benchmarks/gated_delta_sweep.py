@@ -23,10 +23,22 @@ chunks' inverses in 6 and in 3 bf16 passes (``--inverse-passes``) and 4, 8 and
 ``dv`` lie from ``chunked()``'s in float32.  The script reads the package
 beside it, so a copy of it in a checkout of another commit times that
 commit's kernels on the same chip.
-``conv``: ``models/mamba2.py::causal_conv`` (padded, four shifted slices,
-fp32) at ``[1, 8192, 8192]`` in bf16 beside the same taps as rolls under a
-mask, of the fp32 copy and of the bf16 input itself
-(``models/gated_delta.py::causal_conv``), forward and forward + backward.
+``conv``: the mixers' depthwise causal convolution at the three cells'
+shapes (granite-4.0-h-micro's ``xBC``, 4352 channels from column 4096 of a row
+of 8512, with a bias; nemotron-3-super-120b-a12b's share, 1280 from 1024 of
+2320, with a bias; qwen3-next-80b-a3b's ``[q ; k ; v]``, 8192 from 0 of 12288,
+without), one sequence of 8192 in bf16, four taps, forward and forward +
+backward, a form at a time: ``padded_slices`` (what ``models/mamba2.py`` ran
+until PR 57: a padded fp32 copy and four shifted slices; kept here alone, as
+the thing measured against), ``rolled`` (rolls of the fp32 copy under a
+mask), ``rolled_bf16`` (``kernels/causal_conv.py::reference``: rolls of the
+input itself) and ``kernel`` (``kernels/causal_conv.py``'s two kernels, at
+``--tiles`` positions a grid step), each on the channels ``alone`` (an array
+of their own) and cut out of the ``row`` (what the mixers do; there
+``kernel_of_slice`` hands the kernels XLA's slice and ``kernel`` the row and
+the window's first column), beside the floor by bytes (4 B a channel and
+position forward, 10 B forward + backward, at 819 GB/s) and how far ``y`` and
+``dx`` lie from ``padded_slices``'s.
 Times are wall-clock around ``block_until_ready`` over ``--iters`` calls of
 one jitted function, one process, one chip; a time, not a result line.
 """
@@ -180,46 +192,93 @@ def rule(iters, inverse_passes=(6, 3), heads_a_step=(8, 4, 16)):
     return rows
 
 
-def conv(iters):
+# cell: (the projection's row, the window's first column and width, a bias)
+CONV_SHAPES = {"granite-4.0-h-micro": (8512, 4096, 4352, True),
+               "nemotron-3-super-120b-a12b": (2320, 1024, 1280, True),
+               "qwen3-next-80b-a3b": (12288, 0, 8192, False)}
+
+
+def conv(iters, tiles=(512,), s=8192, taps=4):
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    from horovod_tpu.models import gated_delta, mamba2
+    from horovod_tpu.kernels import causal_conv as cc
 
-    s, c, taps = 8192, 8192, 4
-    x = jax.random.normal(jax.random.PRNGKey(0), (1, s, c)) \
-        .astype(jnp.bfloat16)
-    w = 0.02 * jax.random.normal(jax.random.PRNGKey(1), (c, taps))
+    def padded_slices(x, w, bias):
+        padded = jnp.pad(x.astype(jnp.float32),
+                         ((0, 0), (taps - 1, 0), (0, 0)))
+        out = sum(w[:, j] * padded[:, j:j + s] for j in range(taps))
+        return jax.nn.silu(out if bias is None else out + bias) \
+            .astype(x.dtype)
 
-    def causal_conv(x, w):
-        # Mamba-2's form adds a bias; the DeltaNet's convolution has none.
-        return mamba2.causal_conv(x, w, jnp.zeros((c,), jnp.float32))
-
-    def rolled(x, w):
+    def rolled(x, w, bias):
         f = x.astype(jnp.float32)
         at = lax.broadcasted_iota(jnp.int32, (1, s, 1), 1)
         out = w[:, taps - 1] * f
         for back in range(1, taps):
             out = out + w[:, taps - 1 - back] * jnp.where(
                 at >= back, jnp.roll(f, back, axis=1), 0.0)
-        return jax.nn.silu(out).astype(x.dtype)
+        return jax.nn.silu(out if bias is None else out + bias) \
+            .astype(x.dtype)
 
-    forms = {"padded_slices": causal_conv, "rolled": rolled,
-             "rolled_bf16": gated_delta.causal_conv}
-    want = causal_conv(x, w).astype(jnp.float32)
+    def of_slice(form):
+        return lambda row, start, w, bias: form(
+            row[..., start:start + w.shape[0]], w, bias)
+
+    def kernel(row, start, w, bias):
+        return cc.causal_conv(row[..., start:start + w.shape[0]], w, bias,
+                              within=(row, start))
+
+    forms = {"padded_slices": of_slice(padded_slices),
+             "rolled": of_slice(rolled),
+             "rolled_bf16": of_slice(cc.reference),
+             "kernel_of_slice": of_slice(cc.causal_conv), "kernel": kernel}
+
+    def far(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm((a - b).ravel())
+                     / jnp.linalg.norm(b.ravel()))
+
     rows = []
-    for name, form in forms.items():
-        forward = jax.jit(form)
-        backward = jax.jit(jax.grad(
-            lambda x, w, form=form: jnp.sum(
-                form(x, w).astype(jnp.float32) ** 2), argnums=(0, 1)))
-        rows.append({
-            "form": name, "forward_ms": timed(forward, (x, w), iters),
-            "forward_backward_ms": timed(backward, (x, w), iters),
-            "from_padded_slices": float(jnp.abs(
-                forward(x, w).astype(jnp.float32) - want).max())})
-        print(rows[-1], file=sys.stderr, flush=True)
+    for cell, (width, first, c, biased) in CONV_SHAPES.items():
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        w = 0.5 * jax.random.normal(keys[1], (c, taps))
+        bias = jax.random.normal(keys[2], (c,)) if biased else None
+        for operand, (width, first) in {"alone": (c, 0),
+                                        "row": (width, first)}.items():
+            row = jax.random.normal(keys[0], (1, s, width)) \
+                .astype(jnp.bfloat16)
+            want = None
+            for name, form in forms.items():
+                if operand == "alone" and name == "kernel_of_slice":
+                    continue
+                for tile in tiles if name.startswith("kernel") else (None,):
+                    if tile:
+                        cc._TILE = tile
+                        jax.clear_caches()
+                    forward = jax.jit(
+                        lambda row, w, bias, form=form, first=first: form(
+                            row, first, w, bias))
+                    backward = jax.jit(jax.grad(
+                        lambda row, w, bias, form=form, first=first: jnp.sum(
+                            form(row, first, w, bias)
+                            .astype(jnp.float32) ** 2),
+                        argnums=(0, 1, 2) if biased else (0, 1)))
+                    y = forward(row, w, bias)
+                    dx = backward(row, w, bias)[0]
+                    want = want or (y, dx)
+                    rows.append({
+                        "cell": cell, "operand": operand, "form": name,
+                        "tile": tile,
+                        "forward_ms": timed(forward, (row, w, bias), iters),
+                        "forward_backward_ms": timed(
+                            backward, (row, w, bias), iters),
+                        "floor_forward_ms": 4e3 * s * c / 819e9,
+                        "floor_forward_backward_ms": 10e3 * s * c / 819e9,
+                        "y_from_padded_slices": far(y, want[0]),
+                        "dx_from_padded_slices": far(dx, want[1])})
+                    print(rows[-1], file=sys.stderr, flush=True)
     return rows
 
 
@@ -229,6 +288,9 @@ def main():
     p.add_argument("--iters", type=int, default=10)
     p.add_argument("--inverse-passes", default="6,3", help="rule's rows")
     p.add_argument("--heads-a-step", default="8,4,16", help="rule's rows")
+    p.add_argument("--tiles", default="512", help="conv's kernel rows")
+    p.add_argument("--seq", type=int, default=8192,
+                   help="conv's positions (small: a rehearsal on the CPU)")
     p.add_argument("--out", default=None)
     args = p.parse_args()
 
@@ -237,7 +299,10 @@ def main():
     def numbers(text):
         return tuple(int(n) for n in text.split(","))
 
-    forms = {"passes": passes, "conv": conv, "rule": functools.partial(
+    forms = {"passes": passes,
+             "conv": functools.partial(conv, tiles=numbers(args.tiles),
+                                       s=args.seq),
+             "rule": functools.partial(
         rule, inverse_passes=numbers(args.inverse_passes),
         heads_a_step=numbers(args.heads_a_step))}
     out = {"device": jax.devices()[0].device_kind}

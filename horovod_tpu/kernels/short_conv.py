@@ -63,10 +63,10 @@ _MAX_TILE = 256   # positions a grid step
 _VMEM_LIMIT = 64 * 2 ** 20
 
 
-def _tile(seq_len: int) -> int:
-    """Positions a grid step: the largest power of two up to
-    :data:`_MAX_TILE` that divides the sequence."""
-    tile = _MAX_TILE
+def _tile(seq_len: int, most: int = _MAX_TILE) -> int:
+    """Positions a grid step: the largest power of two up to ``most``
+    (:data:`_MAX_TILE`) that divides the sequence."""
+    tile = most
     while tile > _HALO and seq_len % tile:
         tile //= 2
     return tile
@@ -190,28 +190,41 @@ def _bwd_kernel(w_ref, bcx_ref, before_ref, after_ref, dy_ref, dy_after_ref,
             dbcx_ref[:, lo + c0:lo + c0 + cols] = value.astype(dbcx_ref.dtype)
 
 
-def _specs(s: int, d: int):
+def _specs(s: int, d: int, columns: bool = False, most: int = _MAX_TILE):
     """(tile, a ``[b, s, width]`` operand's block by width, the ``_HALO``
-    rows before a tile, those behind it, the taps' block)."""
+    rows before a tile, those behind it, the taps' block ``[8, d]``).
+
+    With ``columns`` (``kernels/causal_conv.py``) the grid is (sequence,
+    block of columns, tile) and not (sequence, tile): a block of ``width``
+    columns is one of several side by side, counted from its array's block
+    ``first``, which is how a kernel reads a window of a wider row where it
+    lies, and ``d`` is the taps' share of one such block; tiles of at most
+    ``most`` positions."""
     import jax.experimental.pallas as pl
 
-    tile = _tile(s)
+    tile = _tile(s, most)
     per, blocks = tile // _HALO, s // _HALO
 
-    def rows(width):
-        return pl.BlockSpec((None, tile, width), lambda i, t: (i, t, 0))
+    def spec(rows, width, row_of, first):
+        if columns:
+            return pl.BlockSpec((None, rows, width),
+                                lambda i, j, t: (i, row_of(t), first + j))
+        return pl.BlockSpec((None, rows, width),
+                            lambda i, t: (i, row_of(t), 0))
 
-    def before(width):
-        return pl.BlockSpec(
-            (None, _HALO, width),
-            lambda i, t: (i, jnp.maximum(t * per - 1, 0), 0))
+    def rows(width, first=0):
+        return spec(tile, width, lambda t: t, first)
 
-    def after(width):
-        return pl.BlockSpec(
-            (None, _HALO, width),
-            lambda i, t: (i, jnp.minimum((t + 1) * per, blocks - 1), 0))
+    def before(width, first=0):
+        return spec(_HALO, width, lambda t: jnp.maximum(t * per - 1, 0),
+                    first)
 
-    taps = pl.BlockSpec((_TAP_ROWS, d), lambda i, t: (0, 0))
+    def after(width, first=0):
+        return spec(_HALO, width,
+                    lambda t: jnp.minimum((t + 1) * per, blocks - 1), first)
+
+    taps = pl.BlockSpec((_TAP_ROWS, d), (lambda i, j, t: (0, j)) if columns
+                        else (lambda i, t: (0, 0)))
     return tile, rows, before, after, taps
 
 

@@ -17,11 +17,10 @@ linear-attention layers, three of every four.
 
 Key head ``j`` serves the value heads ``j * r .. (j + 1) * r - 1``, ``r`` the
 value heads a key head.  The rule runs in its chunked form
-(``kernels/gated_delta.py``: its kernels on a TPU, ``jax.numpy`` elsewhere);
-the convolution is XLA's, as the Mamba-2 mixer's is and for its reasons
-(``models/mamba2.py``), but brings the earlier positions by rolls of the
-bf16 input under a mask where that one pads and slices in fp32
-(:func:`causal_conv`).
+(``kernels/gated_delta.py``: its kernels on a TPU, ``jax.numpy`` elsewhere),
+and so does the convolution (``kernels/causal_conv.py``, the Mamba-2 mixer's
+too, here without a bias: its kernels read ``[q ; k ; v]`` where it lies in
+``W_qkvz``'s output).
 
 **The columns' order.**  ``W_qkvz``'s columns are all the key heads' ``q``,
 then all their ``k``, then the value heads' ``v``, then their ``z``, each
@@ -46,6 +45,7 @@ import numpy as np
 
 from ..core.timeline import scope
 from ..kernels import gated_delta
+from ..kernels.causal_conv import causal_conv
 from .transformer import TransformerConfig, _dense
 
 
@@ -74,26 +74,6 @@ def release_columns(cfg: TransformerConfig):
     return (np.concatenate([p.reshape(-1) for p in parts]),
             np.concatenate([(pair + np.arange(per)).reshape(-1),
                             (pair + per + np.arange(per)).reshape(-1)]))
-
-
-def causal_conv(x, w):
-    """``silu(conv(x))`` for ``x [b, s, c]`` and ``w [c, L]`` (tap ``L - 1``
-    on the position itself): depthwise, causal, zero before the sequence, no
-    bias; the sums in fp32, the result in ``x``'s dtype.  Each earlier
-    position comes by a roll of ``x`` as it is under a mask of the rows that
-    rolled round: at ``[1, 8192, 8192]`` in bf16 on a v5e 1.07 ms forward
-    and 4.82 forward + backward where ``mamba2.causal_conv``'s padded fp32
-    copy and its four slices take 2.58 and 7.87
-    (``benchmarks/gated_delta_sweep.py``, my chip run, PR 50)."""
-    s, taps = x.shape[1], w.shape[1]
-    at = jax.lax.broadcasted_iota(jnp.int32, (1, s, 1), 1)
-    w = w.astype(jnp.float32)
-    out = w[:, taps - 1] * x.astype(jnp.float32)
-    for back in range(1, taps):
-        earlier = jnp.where(at >= back, jnp.roll(x, back, axis=1),
-                            jnp.zeros_like(x))
-        out = out + w[:, taps - 1 - back] * earlier.astype(jnp.float32)
-    return nn.silu(out).astype(x.dtype)
 
 
 def _l2_normed(x, scale: float = 1.0):
@@ -128,7 +108,7 @@ class GatedDeltaNet(nn.Module):
         taps = self.param("conv", nn.initializers.normal(0.02),
                           (2 * key_dim + value_dim, cfg.gdn_conv), f32)
         with scope("gdn.conv"):
-            qkv = causal_conv(qkv, taps)
+            qkv = causal_conv(qkv, taps, within=(qkvz, 0))
         q, k, v = jnp.split(qkv, [key_dim, 2 * key_dim], axis=-1)
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
         a_log = self.param("A_log", _a_log_init, (hv,), f32)

@@ -14,11 +14,9 @@ layers.
     out   = y W_out
 
 The recurrence runs in its chunked form (``kernels/ssd_scan.py``: its kernels
-on a TPU, ``jax.numpy`` elsewhere).  The convolution is XLA's: four shifted
-sums, the bias and the silu fuse into one pass that reads ``xBC`` once and
-writes it once, which is all a kernel could do for it
-(``kernels/short_conv.py``'s tap loop serves LFM2's two gates around three
-taps and has no bias or activation to give).
+on a TPU, ``jax.numpy`` elsewhere), and so does the convolution
+(``kernels/causal_conv.py``, the Gated DeltaNet's too: its kernels read
+``xBC`` where it lies in ``W_in``'s output and write it once a pass).
 
 **A share of the heads.**  The norm is taken group by group, a head reads the
 ``B`` and ``C`` of its own group and the convolution is depthwise, so the
@@ -59,6 +57,7 @@ import jax.numpy as jnp
 
 from ..core.timeline import scope
 from ..kernels import ssd_scan
+from ..kernels.causal_conv import causal_conv
 from .transformer import TransformerConfig, _dense
 
 
@@ -103,17 +102,6 @@ def _conv_init(taps: int):
     return init
 
 
-def causal_conv(x, w, bias):
-    """``silu(conv(x) + bias)`` for ``x [b, s, c]``, ``w [c, L]`` (tap ``L -
-    1`` on the position itself) and ``bias [c]``: depthwise, causal, zero
-    before the sequence; in fp32, the result in ``x``'s dtype."""
-    s, taps = x.shape[1], w.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    out = sum(w[:, j] * padded[:, j:j + s] for j in range(taps))
-    return nn.silu(out + bias.astype(jnp.float32)).astype(x.dtype)
-
-
 class Mamba2(nn.Module):
     cfg: TransformerConfig
 
@@ -132,7 +120,7 @@ class Mamba2(nn.Module):
         conv_bias = self.param("conv_bias", _conv_init(cfg.mamba_conv),
                                (conv_dim,), f32)
         with scope("ssm.conv"):
-            xbc = causal_conv(xbc, taps, conv_bias)
+            xbc = causal_conv(xbc, taps, conv_bias, within=(zxbcdt, inner))
         x, bm, cm = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (heads,), f32)
         a_log = self.param("A_log", _a_log_init, (heads,), f32)

@@ -112,7 +112,8 @@ def test_configuration_keeps_every_published_width():
     assert listed == {"mfu_pct", "step_ms_p95.observed",
                       "wfbp_dispatch_ms_step", "ssd_scan_ms_step",
                       "ssd_scan_roofline_pct", "gqa64_attention_ms_step",
-                      "recompute_ms_step", "ssd_scan_fwd_calls_step"}
+                      "recompute_ms_step", "ssd_scan_fwd_calls_step",
+                      "causal_conv_ms_step"}
     for name in listed - {"mfu_pct"}:
         assert os.path.exists(os.path.join(
             REPO_ROOT, "chip_bench/metrics", name + ".json")), name

@@ -60,9 +60,11 @@ def test_granites_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     """``granite-4.0-h-micro-wfbp-1chip``'s whole step (loss, gradients,
     AdamW) at the timed sizes under the one device's mesh, as
     ``hvd.make_overlapped_train_step`` builds it, every block under
-    ``nn.remat``: it compiles through the kernels' path (the scan's forward
-    kernel twice a mixer, once in the forward pass and once in the second
-    forward, its backward kernel once; the attention layer's forward kernel
+    ``nn.remat``: it compiles through the kernels' path (the scan's and the
+    convolution's forward kernel twice a mixer, once in the forward pass and
+    once in the second forward, their backward kernels once, the
+    convolution's reading ``xBC`` in ``in_proj``'s ``[8192, 8512]`` where it
+    lies; the attention layer's forward kernel
     twice and its backward once; no einsum over a score square), the compiler
     computes nothing again by itself, and its own count of the memory stays
     inside what the configuration's ``fit`` states; the count goes into the
@@ -109,8 +111,11 @@ def test_granites_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
     assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
-                       "hvd_ssd_scan_fwd", "hvd_ssd_scan_bwd"}, kernels
+                       "hvd_ssd_scan_fwd", "hvd_ssd_scan_bwd",
+                       "hvd_causal_conv_fwd", "hvd_causal_conv_bwd"}, kernels
     for kernel, calls in (("hvd_ssd_scan_fwd", 18), ("hvd_ssd_scan_bwd", 9),
+                          ("hvd_causal_conv_fwd", 18),
+                          ("hvd_causal_conv_bwd", 9),
                           ("splash_mha_fwd_residuals", 2),
                           ("splash_mha_dkv_dq", 1)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel

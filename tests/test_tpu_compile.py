@@ -383,6 +383,41 @@ def test_short_conv_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
 
 
+@pytest.mark.parametrize("width,start,c,biased", [
+    (8512, 4096, 4352, True),       # granite-4.0-h-micro: [z, xBC, dt]
+    (2320, 1024, 1280, True),       # nemotron-3-super-120b-a12b's share
+    (12288, 0, 8192, False),        # qwen3-next-80b-a3b: [q ; k ; v ; z]
+])
+def test_causal_conv_compiles_at_the_mixers_shapes(one_chip, no_compile_cache,
+                                                   width, start, c, biased):
+    """One sequence of 8192 positions, 4 taps over a window of the input
+    projection's row: the forward and the backward kernel of
+    ``kernels/causal_conv.py`` read the window where it lies (no copy of the
+    slice in the program), the residual the row alone."""
+    from horovod_tpu.kernels import causal_conv as cc
+
+    assert cc.takes(8192, c, 4)
+    row = _shape((1, 8192, width), jnp.bfloat16, one_chip)
+    w = _shape((c, 4), jnp.float32, one_chip)
+    bias = _shape((c,), jnp.float32, one_chip) if biased else None
+
+    def loss(row, w, bias):
+        y = cc._causal_conv(row, w, bias, start, False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2) if biased else (0, 1))
+                       ).lower(row, w, bias).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(hvd_causal_conv\w*?)[.\d]* =", text))
+    assert kernels == {cc.FWD_NAME, cc.BWD_NAME}, kernels
+    assert all(re.match(cc.OP_LINE_NAMES, k) for k in kernels)
+    # The kernels' operand is the row itself, not a slice of it.
+    for name in kernels:
+        call = next(line for line in text.splitlines()
+                    if f"%{name}" in line and "custom-call(" in line)
+        assert f"bf16[1,8192,{width}]{{2,1,0}}" in call
+
+
 def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     """Two sequences of 8192 positions, 32 query heads on 8 KV heads of 64
     under the causal rule: the library's forward kernel and the one backward
@@ -768,7 +803,9 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     ``hvd.make_overlapped_train_step`` builds it: it compiles through the
     kernels' path (the rule's two kernels a DeltaNet layer, three calls of
     each and no other name of theirs, the pairs' backward through ``jax.vjp``
-    inside the one kernel; the two attention
+    inside the one kernel; the convolution's two kernels as often, reading
+    ``[q ; k ; v]`` in ``in_proj_qkvz``'s ``[8192, 12288]`` where it lies;
+    the two attention
     kernels at width 256, the rows kernel, no einsum over a score square),
     the compiler computes nothing again to make it fit (with 32 experts held
     it does: the configuration's ``fit``), and its own count of the memory
@@ -819,9 +856,12 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
     assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
                        "hvd_rows_to_tokens", "hvd_gated_delta_fwd",
-                       "hvd_gated_delta_bwd"}, kernels
+                       "hvd_gated_delta_bwd", "hvd_causal_conv_fwd",
+                       "hvd_causal_conv_bwd"}, kernels
     for kernel, calls in (("hvd_gated_delta_fwd", 3),
                           ("hvd_gated_delta_bwd", 3),
+                          ("hvd_causal_conv_fwd", 3),
+                          ("hvd_causal_conv_bwd", 3),
                           ("splash_mha_fwd_residuals", 1),
                           ("splash_mha_dkv_dq", 1)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
@@ -839,12 +879,13 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     # The file states what the compiler counted when the configuration was
     # sized (PR 50: 13.88 GiB).  A program that changed since may take less
     # and never more: the file is the benchmark's, which only a benchmark PR
-    # restates.
+    # restates.  PR 57: 12.54, the convolution's residual being the
+    # projection's output where it lies and no fp32 copy of [q ; k ; v].
     with open(os.path.join(REPO_ROOT, "chip_bench/configs",
                            "qwen3-next-80b-a3b.json")) as f:
         stated = float(re.search(r"takes ([\d.]+) GiB with 16 experts held",
                                  json.load(f)["assumed"]["fit"]).group(1))
-    assert stated - 0.5 < gib < stated + 0.005, (gib, stated)
+    assert stated - 1.5 < gib < stated + 0.005, (gib, stated)
 
 
 def test_qwen3_nexts_float32_twin_compiles(one_chip, no_compile_cache,
@@ -875,7 +916,7 @@ def test_qwen3_nexts_float32_twin_compiles(one_chip, no_compile_cache,
     compiled = config._logits("program_float32", ()).lower(*args).compile()
     text = compiled.as_text()
     assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 1
-    assert "hvd_gated_delta" not in text
+    assert "hvd_gated_delta" not in text and "hvd_causal_conv" not in text
     assert '\\"block_q\\": 512' in text
     assert "16,8192,8192" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
