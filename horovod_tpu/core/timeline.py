@@ -543,6 +543,7 @@ SCOPES = (
     "moe.latent", "moe.shared", "moe.shared_gate", "mtp.proj",
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",
+    "hc.coeff", "hc.sinkhorn", "hc.pre", "hc.post",
 )
 
 

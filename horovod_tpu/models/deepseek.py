@@ -39,6 +39,14 @@ rotation of the rotary columns, the scale, and ``k_r`` behind every head's
 projection takes a copy ``[b, s, h * dv]`` of their output, which is made
 again in the backward pass and not kept (:func:`_merged`).
 
+**YaRN** (``cfg.yarn_factor`` above 1; Xing4.0-29B-A4B, PR 58), as
+DeepSeek-V3's rotary embedding has it: the rotary columns' frequencies are
+``transformer.yarn_inv_freq``'s blend, the tables of cosines and sines carry
+the ratio of the two mscales and the scores' scale the square of
+``mscale_all_dim``'s, which rides where the scale rode, in
+``kernels/mla_operands.py``'s pass over q: the attention kernels and their
+backward know nothing of it, and at the default nothing here moves.
+
 Scopes: ``attn.latent`` (the two down-projections and their norms),
 ``attn.proj`` (the up-projections and ``out``), ``attn.rope`` (the weights'
 permutation, the tables of cosines and sines and ``k_r``'s rotation),
@@ -63,6 +71,8 @@ from .transformer import (
     _dense,
     _norm,
     _rope_angles,
+    yarn_inv_freq,
+    yarn_mscale,
 )
 
 
@@ -153,7 +163,7 @@ def _merged(out, weights):
         return _product(out, weights)
 
 
-def _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables):
+def _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables, scale=1.0):
     """``(q, k, v)`` as the attention kernels take them, ``[b, h, s, .]``
     with ``q`` rotated and scaled and ``k = [k_nope ; k_r]``, from the two
     latents, the one rotary key ``[b, 1, s, rope]``, rotated, and the
@@ -161,7 +171,8 @@ def _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables):
     comes as two flat products (a head's 128 and its 64 apart: whole lane
     groups for the MXU, forward and backward), ``k_nope`` and ``v`` are
     written in the kernels' layout by theirs, and ``kernels/mla_operands.py``
-    finishes q and k in one pass."""
+    finishes q and k in one pass.  ``scale``: a factor on the scores' scale
+    (YaRN's squared mscale)."""
     rope = k_r.shape[-1]
     nope = w_uq[0].shape[-1] - rope
     with scope("attn.proj"):
@@ -171,7 +182,7 @@ def _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables):
         v = _up(c_kv, w_ukv, slice(nope, None))
     with scope("attn.layout"):
         q, k = mla_operands.operands(q_nope, q_rope, k_nope, k_r, *tables,
-                                     (nope + rope) ** -0.5)
+                                     (nope + rope) ** -0.5 * scale)
     return q, k, v
 
 
@@ -217,11 +228,24 @@ class LatentAttention(nn.Module):
             w_ukv = _by_head(_dense(
                 cfg, h * (nope + dv), (None, cfg.model_axis), "kv_b",
                 cls=_Weights)(latent), h)
+        # YaRN (yarn_factor above 1): the rotary columns' frequencies
+        # blended, the tables times the ratio of the two mscales and the
+        # scores' scale times the second one's square; nothing else moves.
+        inv_freq, table_scale, score_scale = None, 1.0, 1.0
+        if cfg.yarn_factor > 1.0:
+            inv_freq = yarn_inv_freq(cfg, rope)
+            all_dim = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+            table_scale = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+                / all_dim
+            score_scale = all_dim * all_dim
         with scope("attn.rope"):
             tables = mla_operands.tables(
-                _rope_angles(s, rope, cfg.rope_theta, positions))
+                _rope_angles(s, rope, cfg.rope_theta, positions, inv_freq))
+            if table_scale != 1.0:
+                tables = tuple(t * table_scale for t in tables)
             k_r = mla_operands.turn(down[:, None, :, latent:], *tables)
-        q, k, v = _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables)
+        q, k, v = _kernel_operands(c_q, c_kv, k_r, w_uq, w_ukv, tables,
+                                   score_scale)
         rule = masked_attention.Causal()
         if jax.default_backend() == "tpu" \
                 and masked_attention.takes(rule, s, nope + rope, dv):

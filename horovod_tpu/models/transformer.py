@@ -1,5 +1,6 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next and Granite-4.0-H presets.
+LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H and Xing4.0
+presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -54,12 +55,19 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   the backward pass from the block's input, the one activation kept
   (granite-4.0-h-micro's cell, whose 12.35 GB of weights, gradients and
   AdamW state leave no room for ten layers' activations; the mixers' and
-  attention's ``custom_vjp`` kernels run their forward twice then).
+  attention's ``custom_vjp`` kernels run their forward twice then; and
+  xing4.0-29b-a4b's, whose layer input is four streams wide);
+- ``hc_mult``: the residual path itself is an option: the pre-norm add on
+  one stream, or manifold-constrained hyper-connections over ``hc_mult``
+  streams (``models/hyper_connections.py``, loaded where a configuration
+  asks for it; Xing4.0-29B-A4B ``xing4_0_29b_a4b_config()``, whose latent
+  attention also runs under YaRN, ``yarn_*``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -250,6 +258,30 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # Manifold-constrained hyper-connections (models/hyper_connections.py,
+    # Xing4.0's ``hc_*`` keys), where hc_mult is set: the residual stream is
+    # hc_mult streams of d_model, every sublayer reads one mix of them and
+    # writes back to all, and the matrix that carries the streams past it is
+    # made doubly stochastic by hc_sinkhorn_iters Sinkhorn normalisations of
+    # the exponential of its logits clipped to -+hc_res_clamp, hc_eps in
+    # their denominators.  0: the pre-norm add, one stream.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # YaRN (arXiv:2309.00071) on latent attention's rotary columns, as
+    # DeepSeek-V3's rotary embedding has it, where yarn_factor is above 1:
+    # frequencies that turn less than yarn_beta_slow times in
+    # yarn_original_max_len positions are divided by the factor, those that
+    # turn more than yarn_beta_fast times are kept, the others blended;
+    # cosines and sines times the ratio of the two mscales (0.1 m ln factor +
+    # 1) and the scores' scale times the second one's square.
+    yarn_factor: float = 1.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -473,6 +505,37 @@ def granite_4_0_h_micro_config(**overrides) -> TransformerConfig:
         logits_scaling=8.0, layer_pattern=pattern), **overrides})
 
 
+def xing4_0_29b_a4b_config(**overrides) -> TransformerConfig:
+    """Xing4.0-29B-A4B (XingChen-AGI/Xing4.0-29B-A4B ``config.json``,
+    ``xing4_0``; DeepSeek-V3's keys and the ``hc_*`` keys of
+    manifold-constrained hyper-connections): 40 layers around **four residual
+    streams** of 3584 (``models/hyper_connections.py``: every sublayer reads
+    one mix of them and writes back to all, the matrix that carries them
+    past it made doubly stochastic by 20 Sinkhorn iterations); latent
+    attention of 32 heads (keys 128 + a rotary 64 over values of 128,
+    latents of 768 and 512) under YaRN (factor 64 from 4096 positions: the
+    scores' scale times 2.005); layers 0 and 1 a dense SwiGLU of width 9216,
+    the others 64 experts of width 1024, 4 a token by sigmoid scores plus a
+    bias the step keeps, weights renormalised and times 2, beside a shared
+    expert of width 1024; RMSNorm at 1e-6, no biases, an untied head.  The
+    multi-token-prediction module is not built under several streams."""
+    pattern = tuple(LayerKind(ffn="dense" if i < 2 else None)
+                    for i in range(40))
+    return TransformerConfig(**{**dict(
+        vocab_size=131072, num_layers=40, num_heads=32, d_model=3584,
+        d_ff=1024, d_ff_dense=9216, d_ff_shared=1024, max_len=262144,
+        causal=True, norm="rmsnorm", norm_eps=1e-6, positions="rope",
+        rope_theta=10000.0, rope_interleave=True, use_bias=False,
+        tie_embeddings=False, ffn="moe", num_experts=64,
+        experts_per_token=4, norm_topk_prob=True, router_scoring="sigmoid",
+        expert_bias=True, routed_scaling_factor=2.0, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_res_clamp=30.0, yarn_factor=64.0, yarn_original_max_len=4096,
+        yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_mscale=1.0,
+        yarn_mscale_all_dim=1.0, layer_pattern=pattern), **overrides})
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """For tests and the multichip dryrun: tiny shapes, same code paths."""
     return TransformerConfig(**{**dict(
@@ -518,13 +581,50 @@ def _norm(cfg: TransformerConfig, name: str):
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
-def _rope_angles(s: int, d: int, theta: float, positions=None):
+def _rope_angles(s: int, d: int, theta: float, positions=None,
+                 inv_freq=None):
     """``[s, d / 2]`` in fp32: position times frequency, the pair ``i`` of a
-    head of ``d`` at ``theta ** (-2 i / d)``."""
-    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    head of ``d`` at ``theta ** (-2 i / d)`` (or at ``inv_freq[i]``, where
+    the frequencies are scaled: :func:`yarn_inv_freq`)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.float32)
     return positions.astype(jnp.float32)[:, None] * inv_freq[None]
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's ``0.1 mscale ln(factor) + 1`` (1 at a factor of 1 or less)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(cfg: "TransformerConfig", d: int):
+    """``(low, high)``: the pairs of a head of ``d`` between which YaRN blends
+    (DeepSeek-V3's ``yarn_find_correction_range``): pair ``c(r) = d
+    ln(original / (2 pi r)) / (2 ln theta)`` turns ``r`` times in the
+    original positions; floor of ``c(beta_fast)``, ceiling of
+    ``c(beta_slow)``, inside ``0..d - 1``."""
+    def pair(turns):
+        return d * math.log(cfg.yarn_original_max_len
+                            / (turns * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    return (max(math.floor(pair(cfg.yarn_beta_fast)), 0),
+            min(math.ceil(pair(cfg.yarn_beta_slow)), d - 1))
+
+
+def yarn_inv_freq(cfg: "TransformerConfig", d: int):
+    """``[d / 2]`` in fp32: pair ``i``'s frequency ``(1 - g_i) f_i / factor +
+    g_i f_i``, ``f_i = theta ** (-2 i / d)``, ``g_i = 1 - clip((i - low) /
+    (high - low), 0, 1)``."""
+    low, high = yarn_correction_range(cfg, d)
+    if low == high:
+        high += 0.001
+    plain = 1.0 / cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                     / d)
+    kept = 1.0 - jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                          / (high - low), 0.0, 1.0)
+    return plain / cfg.yarn_factor * (1.0 - kept) + plain * kept
 
 
 def _rope(x, theta: float, positions=None, share: float = 1.0):
@@ -777,7 +877,12 @@ class Block(nn.Module):
         mixer, ffn = self.kind.mixer, self.kind.ffn or cfg.ffn
         if mixer == ffn == "none":
             raise ValueError("a layer of neither mixer nor FFN")
+        if cfg.hc_mult and cfg.router_input == "block" and ffn == "moe":
+            raise ValueError("a router that reads the block's input reads "
+                             "one stream: not written under hc_mult")
         if mixer != "none":
+            if cfg.hc_mult:
+                x, back = self._hyper_connection("hc_mixer", 0)(x)
             with scope("norm"):
                 y = _norm(cfg, "ln1")(x)
             if mixer == "conv":
@@ -804,10 +909,15 @@ class Block(nn.Module):
                 y = GatedDeltaNet(cfg, name="gdn")(y)
             else:
                 raise ValueError(f"unknown mixer {mixer!r}")
-            with scope("norm"):
-                x = x + _branch(cfg, y)
+            if cfg.hc_mult:
+                x = back(_branch(cfg, y))
+            else:
+                with scope("norm"):
+                    x = x + _branch(cfg, y)
         if ffn == "none":
             return x
+        if cfg.hc_mult:
+            x, back = self._hyper_connection("hc_ffn", 1)(x)
         with scope("norm"):
             ln2 = _norm(cfg, "ln2")
             y = ln2(x)
@@ -850,8 +960,22 @@ class Block(nn.Module):
             y = self._experts(y, router_input)
         else:
             raise ValueError(f"unknown ffn {ffn!r}")
+        if cfg.hc_mult:
+            return back(_branch(cfg, y))
         with scope("norm"):
             return x + _branch(cfg, y)
+
+    def _hyper_connection(self, name: str, sublayer: int):
+        """The hyper-connection around this block's mixer (``sublayer`` 0) or
+        FFN (1): ``streams -> (the sublayer's input, back)``.  A fresh one
+        reads the stream of its sublayer's number among the model's (a block
+        built by itself is layer 0).  Loaded here: the module loads where a
+        configuration with ``hc_mult`` builds a block."""
+        from .hyper_connections import HyperConnection
+
+        layer = self.name.rsplit("_", 1)[-1] if self.name else "0"
+        read = 2 * int(layer) + sublayer if layer.isdigit() else sublayer
+        return HyperConnection(self.cfg, read, name=name)
 
     def _experts(self, y, router_input=None):
         """The sparse-expert FFN; its MoEStats are sown into the ``moe``
@@ -1060,8 +1184,18 @@ class Transformer(nn.Module):
         block = Block
         if cfg.remat:
             block = nn.remat(Block)
+        if cfg.hc_mult:
+            if cfg.mtp_modules:
+                raise ValueError("how a prediction module joins several "
+                                 "streams is not written: no mtp_modules "
+                                 "under hc_mult")
+            from . import hyper_connections
+
+            x = hyper_connections.fan_out(x, cfg.hc_mult)
         for i in range(cfg.num_layers):
             x = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")(x, positions)
+        if cfg.hc_mult:
+            x = hyper_connections.fold(x)
         stack_output = x
         with scope("norm"):
             if cfg.block_diffusion:

@@ -183,9 +183,9 @@ def router_product_passes(dtype) -> int:
     return 3 if dtype == jnp.bfloat16 else 6
 
 
-def _bf16_pieces(w):
-    """``w`` (fp32) as three bfloat16 arrays, joined along the last axis,
-    whose sum in fp32 is ``w`` to its last bit: 8 + 8 + 8 bits of its 24.
+def _bf16_pieces(w, axis: int = -1):
+    """``w`` (fp32) as three bfloat16 arrays, joined along ``axis``, whose
+    sum in fp32 is ``w`` to its last bit: 8 + 8 + 8 bits of its 24.
     ``reduce_precision`` and not a cast there and back, which XLA may take
     for no rounding at all (``xla_allow_excess_precision``)."""
     pieces = []
@@ -193,7 +193,7 @@ def _bf16_pieces(w):
         piece = lax.reduce_precision(w, exponent_bits=8, mantissa_bits=7)
         pieces.append(piece.astype(jnp.bfloat16))
         w = w - piece
-    return jnp.concatenate(pieces, axis=-1)
+    return jnp.concatenate(pieces, axis=axis)
 
 
 def _sum_of_slabs(s3):

@@ -57,6 +57,9 @@ BLOCKS = {
                                  "gdn.proj", "gdn.conv", "gdn.gates",
                                  "gdn.rule", "gdn.norm", "moe.shared",
                                  "moe.shared_gate"],
+    "share_streams": _LM + _MOE + ["attn.latent", "attn.rope", "attn.layout",
+                                   "ffn", "moe.shared", "hc.coeff",
+                                   "hc.sinkhorn", "hc.pre", "hc.post"],
     "resnet": ["loss", "bn", "resnet.stem", "resnet.stage1", "resnet.stage2",
                "resnet.stage3", "resnet.stage4", "resnet.head"],
 }
@@ -72,7 +75,7 @@ from horovod_tpu.models.transformer import (
     LayerKind, Transformer, hybrid_pattern, joyai_llm_flash_config,
     lfm2_8b_a1b_config, moe_stats, nemotron_3_super_config,
     olmoe_1b_7b_config, qwen3_next_80b_a3b_config, sdar_30b_a3b_config,
-    smallthinker_21b_a3b_config, tiny_config)
+    smallthinker_21b_a3b_config, tiny_config, xing4_0_29b_a4b_config)
 
 if {null}:
     jax.named_scope = lambda name: contextlib.nullcontext()
@@ -167,6 +170,15 @@ MODELS = {{
         experts_held=(1, 6), gdn_key_heads=2, gdn_value_heads=4,
         gdn_key_dim=8, gdn_value_dim=8,
         layer_pattern=(LayerKind(mixer="gated_delta"), LayerKind())), True),
+    # Four residual streams under hyper-connections (three Sinkhorn
+    # iterations) around latent attention under YaRN: a dense layer and one
+    # with 2 of 8 experts held beside a shared expert.
+    "share_streams": lambda: lm(xing4_0_29b_a4b_config(
+        **{{**share, "num_kv_heads": None}}, d_ff_dense=96, d_ff_shared=32,
+        experts_per_token=2, experts_held=(1, 6), q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=12, hc_sinkhorn_iters=3, yarn_original_max_len=16,
+        layer_pattern=(LayerKind(ffn="dense"), LayerKind())), True),
     "resnet": resnet,
 }}
 

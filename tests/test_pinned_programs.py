@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, \
-    test_qwen3_next, \
+    test_qwen3_next, test_xing, \
     test_router_product, test_sdar, test_smallthinker
 
 RECORDED_WITH = "0.9.0"     # the text of a lowering is the JAX version's own
@@ -137,6 +137,12 @@ PINS = {
     "gated_delta_kernel_call": "712ade0f10b50ea922e16a9e4c7c0ef8d81fc7e670d8612025bbe8b767066393",
     "float32/qwen3_next_tiny_step": "2bf8b684115f1b20df7bdb3e03652029c01bceacaa61eb0270cbd136fc2a40e1",
     "tree/qwen3-next-80b-a3b": "cf7826f4e49abb86956d6246f258969362f43e4b",
+    # PR 58 (Xing4.0-29B-A4B), its own: the parameter tree of test_xing.py's
+    # tiny model (``hc_mixer`` and ``hc_ffn`` with ``phi``, ``bias`` and
+    # ``alpha`` in every layer beside latent attention's seven, a dense layer
+    # and two sparse ones).  Its step's text is not pinned: no other model
+    # runs its module, and ``tests/test_xing.py`` holds its numbers.
+    "tree/xing4.0-29b-a4b": "d1c50a3dfb8332d801c369ee20105e8081e79c9c",
 }
 
 
@@ -451,6 +457,7 @@ def small_presets():
             layer_pattern=(t.LayerKind(0, False), t.LayerKind(8, True))),
         "joyai-llm-flash": test_joyai.tiny_model()[0].cfg,
         "qwen3-next-80b-a3b": test_qwen3_next.tiny_model()[0].cfg,
+        "xing4.0-29b-a4b": test_xing.tiny_config().model.cfg,
         "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
             **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
             experts_per_token=2, experts_held=(1, 6),
