@@ -17,6 +17,16 @@ on the attached chip:
   iterate kept for the backward pass by autodiff, the product with ``phi`` in
   fp32 at the highest precision.
 
+- **kernels**: the shipped layout with its backward pass through
+  ``kernels/hyper_connection.py``'s two kernels (``connect``, what a TPU runs
+  since PR 59; ``tokens_minor`` is ``reference``, plain autodiff of the same
+  text): ``whole``, and ``post_bwd`` and ``pre_bwd`` alone over ``--blocks``
+  tokens a grid step, each cotangent held to the float32 form's by itself
+  (``dy``, ``dX~``, the sixteen ``dH_res``, the four ``dH_post``; ``dX``,
+  ``dphi``, ``dbias``, ``dalpha``, the four ``dH_pre``) beside a fault
+  planted in each that the limit has to refuse (``H_res`` not transposed in
+  ``dX~``; the norm's term dropped from ``dX``).
+
 A line gives a part (``whole``, and of the shipped layout ``coefficients``,
 ``sinkhorn``, ``mix_down``, ``mix_back``), ms forward alone (``ms_fwd``) and
 forward + backward (``ms_layer``: the gradient's program, which runs the
@@ -25,8 +35,9 @@ each program moves and of its temporaries, and for ``whole`` the share of the
 HBM peak that the bytes the algorithm needs (``3 X + 2 u`` forward, ``8 X + 5
 u`` forward + backward: ``chip_bench/configs/xing4.0-29b-a4b.py::
 hyper_connection_cost``) reach in that time.  The shipped layout's output and
-gradients are held to the other's computed in float32 (the run fails beyond
-:data:`ERROR_LIMIT` of their norm).  Needs a TPU; ``--tokens 256 --width 128``
+gradients, and the kernels', are held to the other's computed in float32 (the
+run fails beyond :data:`ERROR_LIMIT` of their norm, or where a planted fault
+stays within it).  Needs a TPU; ``--tokens 256 --width 128``
 on the CPU is a rehearsal.  One JSON object a line; ``--out`` also writes
 them to a file.
 
@@ -40,6 +51,7 @@ import json
 import os
 import sys
 import time
+import types
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -64,12 +76,15 @@ def main():
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--tokens", type=int, default=8192)
     p.add_argument("--width", type=int, default=3584)
+    p.add_argument("--blocks", default="128,256,512",
+                   help="tokens a grid step, for each kernel alone")
     p.add_argument("--out", default=None)
     args = p.parse_args()
 
     import jax
     import jax.numpy as jnp
 
+    from horovod_tpu.kernels import hyper_connection as kernels
     from horovod_tpu.models import hyper_connections as hc
 
     rehearsal = jax.default_backend() != "tpu"
@@ -90,6 +105,15 @@ def main():
         res = hc.sinkhorn(logits, ITERS, EPS)
         u = hc.mix_down(pre, streams, streams.dtype)
         return hc.mix_back(res, post, streams, u * 0.5)
+
+    cfg = types.SimpleNamespace(
+        hc_mult=n, norm_eps=EPS, hc_res_clamp=CLAMP, hc_sinkhorn_iters=ITERS,
+        hc_eps=EPS, dtype=jnp.bfloat16)
+
+    def through_kernels(streams, phi, bias, alpha):
+        u, back, _ = hc.connect(cfg, streams, phi, bias, alpha,
+                                interpret=rehearsal)
+        return back(u * 0.5)
 
     def major(streams, phi, bias, alpha):
         x = streams[0]                                      # [s, n, c]
@@ -139,7 +163,8 @@ def main():
     exact = both(major)
     exact_operands = (streams.astype(jnp.float32),) + operands[1:]
     want = (exact[0](*exact_operands),) + exact[1](*exact_operands)
-    for name, fn in (("tokens_minor", minor), ("tokens_major", major)):
+    for name, fn in (("tokens_minor", minor), ("kernels", through_kernels),
+                     ("tokens_major", major)):
         forward, backward = both(fn)
         line = {"layout": name, "part": "whole", "tokens": s, "width": c,
                 "ms_fwd": timed(forward, *operands),
@@ -149,7 +174,7 @@ def main():
         for key, needed in least.items():
             line[key.replace("ms_", "hbm_peak_pct_")] = \
                 100 * needed / HBM / (line[key] / 1e3)
-        if name == "tokens_minor":
+        if name != "tokens_major":
             got = (forward(*operands),) + backward(*operands)
             line["errors"] = dict(zip(
                 ("out", "dx", "dphi", "dbias", "dalpha"),
@@ -157,6 +182,110 @@ def main():
             failed |= max(line["errors"].values()) > ERROR_LIMIT
         lines.append(line)
         print(json.dumps(line), flush=True)
+
+    # The two kernels alone, each cotangent against float32, a fault each.
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    k = n * (n + 2)
+    y = jax.random.normal(keys[4], (1, s, c), jnp.bfloat16)
+    res, post = (jax.random.uniform(key, shape) for key, shape in zip(
+        jax.random.split(keys[4]), ((n, n, 1, s), (n, 1, s))))
+    dz = jax.random.normal(keys[1], (k, 1, s))
+    # The kernels' operands with the tokens minor, turned here: a copy that
+    # the step does not make (it holds the streams so) and no row times.
+    minor_of = jax.jit(hc._tokens_minor)
+    streams_m, dout_m, y_m = minor_of(streams), minor_of(dout), minor_of(y)
+
+    # Cut here and not in a timed call: an eager slice is a dispatch of its
+    # own, a millisecond of the host's beside a kernel of 1.3.
+    res_t, post_t = res[:, :, 0], post[:, 0]
+
+    def post_bwd(res_t, block=None):
+        named = {} if block is None else {"block": block}
+        return kernels.post_bwd(dout_m, streams_m, y_m, res_t, post_t,
+                                interpret=rehearsal, **named)
+
+    def pre_text(*operands):
+        return hc._pre_side_fwd(*operands, n, EPS, False)[0]
+
+    def pre_rows(dz, without_norm=False):
+        """What ``hc._pre_side_bwd`` hands the kernel."""
+        _, (_, _, _, pre, product, factor) = hc._pre_side_fwd(
+            streams, phi, bias, alpha, n, EPS, False)
+        rows, pieces = hc._pre_bwd_rows(phi, alpha, pre.reshape(n, s),
+                                        product, factor, dz.reshape(k, s), n)
+        if without_norm:
+            rows = rows[:3] + (jnp.zeros((n, s)), jnp.zeros((1, s)))
+        return rows, pieces
+
+    def pre_bwd(du_m, dxt_m, rows, block=None):
+        named = {} if block is None else {"block": block}
+        return kernels.pre_bwd(du_m, streams_m, dxt_m, *rows,
+                               interpret=rehearsal, **named)
+
+    dres, dpost, dxt, dy = jax.jit(lambda *a: jax.vjp(hc.mix_back, *a[:4])[1](
+        a[4]))(res, post, f32(streams), f32(y), f32(dout))
+    got = post_bwd(res_t)
+    errors = {"dy": share(got[0], minor_of(dy)),
+              "dxt": share(got[1], minor_of(dxt))}
+    errors.update({f"dres_{i}{j}": share(got[2][i, j], dres[i, j, 0])
+                   for i in range(n) for j in range(n)})
+    errors.update({f"dpost_{i}": share(got[3][i], dpost[i, 0])
+                   for i in range(n)})
+    fault = share(post_bwd(res_t.swapaxes(0, 1))[1], minor_of(dxt))
+    du = dout[:, :, 0]
+    cotangents = (du, dz, dout[:, :, ::-1])
+    want = jax.jit(lambda *a: jax.vjp(pre_text, *a[:4])[1](a[4:]))(
+        f32(streams), phi, bias, alpha, *(f32(t) for t in cotangents))
+    ours = jax.jit(lambda *a: jax.vjp(
+        lambda *b: hc._pre_side(*b, n, EPS, rehearsal), *a[:4])[1](a[4:]))(
+        *operands, *cotangents)
+    pre_errors = dict(zip(("dx", "dphi", "dbias", "dalpha"),
+                          (share(g, w) for g, w in zip(ours, want))))
+    dpre = jax.jit(lambda h, x, t: jax.vjp(
+        lambda h: hc.mix_down(h, x, jnp.float32), h)[1](t)[0])(
+        post, f32(streams), f32(du))
+    rows, du_m, back_m = pre_rows(dz), minor_of(du), minor_of(cotangents[2])
+    pre_errors.update({f"dpre_{j}": share(
+        pre_bwd(du_m, back_m, rows)[1][j], dpre[j, 0]) for j in range(n)})
+    # The norm's term alone: no dX~, no du, a cotangent along z itself.
+    z = pre_text(streams, phi, jnp.zeros_like(bias), alpha)[1]
+    along = z / jnp.std(z)
+    nothing = jnp.zeros_like(du_m), jnp.zeros_like(streams_m)
+    alone = minor_of(jax.jit(lambda *a: jax.vjp(pre_text, *a[:4])[1](a[4:]))(
+        f32(streams), phi, bias, alpha, jnp.zeros(du.shape), along,
+        jnp.zeros(streams.shape))[0])
+    pre_fault = {
+        "dx_alone": share(pre_bwd(*nothing, pre_rows(along))[0], alone),
+        "dx_without_norm": share(
+            pre_bwd(*nothing, pre_rows(along, without_norm=True))[0],
+            alone)}
+    moved = {"post_bwd": 3 * x_bytes + 2 * u_bytes,
+             "pre_bwd": 3 * x_bytes + u_bytes}
+    blocks = [int(b) for b in args.blocks.split(",") if s % int(b) == 0]
+
+    def or_why(fn, block):
+        """ms, or why not (a block of all channels that the fast memory
+        does not hold)."""
+        try:
+            return fn(block)
+        except Exception as e:  # noqa: BLE001 — the compiler's refusal
+            return f"{type(e).__name__}: {str(e)[:120]}"
+
+    for part, fn, checked in (
+            ("post_bwd", lambda b: timed(post_bwd, res_t, b),
+             {"errors": errors, "h_res_not_transposed": fault}),
+            ("pre_bwd", lambda b: timed(pre_bwd, du_m, back_m, rows, b),
+             {"errors": pre_errors, **pre_fault})):
+        line = {"layout": "kernels", "part": part, "tokens": s, "width": c,
+                "ms_by_block": {b: or_why(fn, b) for b in blocks}, **checked}
+        shipped = fn(None)
+        line.update(ms=shipped,
+                    hbm_peak_pct=100 * moved[part] / HBM / (shipped / 1e3))
+        failed |= max(line["errors"].values()) > ERROR_LIMIT
+        lines.append(line)
+        print(json.dumps(line), flush=True)
+    failed |= fault <= ERROR_LIMIT or pre_fault["dx_alone"] > ERROR_LIMIT \
+        or pre_fault["dx_without_norm"] <= ERROR_LIMIT
 
     # The shipped layout a part at a time.
     pre, post, logits = jax.jit(lambda *a: hc.coefficients(

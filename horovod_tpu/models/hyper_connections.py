@@ -42,6 +42,15 @@ bf16 pass of the MXU against the three bf16 pieces of the fp32 weights side
 by side (``parallel/moe.py::_bf16_pieces``: the streams are exact in bf16, so
 every term is there), and its backward one pass each way.
 
+Backward (:func:`connect`): over bf16 streams on a TPU the stream-sized work
+of the backward pass is two kernels (``kernels/hyper_connection.py``), each
+the backward of a ``custom_vjp`` whose forward is the text above:
+:func:`_mix_back` (``dy``, ``dX~ = H_res^T dX'`` and the twenty sums ``dH_res``,
+``dH_post`` in one read of ``dX'``, ``X`` and ``y``) and :func:`_pre_side`
+(the norm, the product, ``H_pre`` and the mix down: ``dX`` written once).
+Everything else (float32, the CPU, other shapes) is :func:`reference`, the
+same forward under plain autodiff.
+
 Scopes: ``hc.coeff`` (the flattened norm, the product with ``phi``, the
 sigmoids), ``hc.sinkhorn`` (the exponential and the iterations, forward and
 again backward), ``hc.pre`` (the mix down), ``hc.post`` (the mix back; the
@@ -63,6 +72,7 @@ import numpy as np
 from jax import lax
 
 from ..core.timeline import scope
+from ..kernels import hyper_connection as kernels
 from ..parallel.moe import _bf16_dot, _bf16_pieces
 
 # What a fresh hyper-connection's biases are the inverses of (`bias_init`).
@@ -97,6 +107,12 @@ def _phi_product_fwd(x, phi):
     return out, (x, phi)
 
 
+def _dphi(x, u3):
+    """``x^T [u1 ; u2 ; u3]^T``: bf16 rows ``x [t, m]`` against the three
+    pieces ``[3 k, t]`` of a cotangent, one pass, to ``[m, k]``."""
+    return _slabs(_bf16_dot(x, u3, ((0,), (1,))), 1)
+
+
 def _phi_product_bwd(res, u):
     """``u [k, t]``.  Over bf16 rows: ``dphi = x^T [u1 ; u2 ; u3]^T``, one
     pass over the cotangent's three pieces; ``dx = u^T phi^T`` with both
@@ -114,7 +130,7 @@ def _phi_product_bwd(res, u):
             return dx.astype(x.dtype), dphi
         k = u.shape[0]
         u3 = _bf16_pieces(u, 0)                                  # [3 k, t]
-        dphi = _slabs(_bf16_dot(x, u3, ((0,), (1,))), 1)
+        dphi = _dphi(x, u3)
         p3 = _bf16_pieces(phi, 1)                                # [m, 3 k]
         u_hi, u_lo = u3[:k], u3[k:2 * k]
         p_hi, p_lo = p3[:, :k], p3[:, k:2 * k]
@@ -127,21 +143,34 @@ def _phi_product_bwd(res, u):
 _phi_product.defvjp(_phi_product_fwd, _phi_product_bwd)
 
 
-def coefficients(streams, phi, bias, alpha, n: int, eps: float, clamp: float):
-    """``(H_pre [n, b, s], H_post [n, b, s], H~_res clipped [n, n, b, s])``
-    of ``streams [b, s, n, C]``, in fp32 with the tokens minor.  The norm has
-    no scale of its own, so its factor, one a token, moves behind the
-    product: ``x~ phi = (vec(X) phi) / rms``."""
-    b, s = streams.shape[:2]
-    flat = streams.reshape(b * s, -1)
+def _pre_activations(streams, phi, bias, alpha, n: int, eps: float):
+    """``(z [n (n + 2), b s]``, the product ``x~ phi`` before ``alpha`` and
+    ``bias``, the norm's factor ``[b s])`` of ``streams [b, s, n, C]``, in
+    fp32 with the tokens minor.  The norm has no scale of its own, so its
+    factor, one a token, moves behind the product: ``x~ phi = (vec(X) phi) /
+    rms``."""
+    flat = streams.reshape(streams.shape[0] * streams.shape[1], -1)
     mean2 = jnp.mean(jnp.square(flat.astype(jnp.float32)), axis=-1)
-    z = _phi_product(flat, phi) * lax.rsqrt(mean2 + eps)[None]   # [k, t]
+    factor = lax.rsqrt(mean2 + eps)
+    product = _phi_product(flat, phi) * factor[None]             # [k, t]
     a = jnp.repeat(alpha, np.asarray([n, n, n * n]))
-    z = (a[:, None] * z + bias[:, None]).reshape(-1, b, s)
+    return a[:, None] * product + bias[:, None], product, factor
+
+
+def _gates(z, n: int, clamp: float):
+    """``(H_pre [n, b, s], H_post [n, b, s], H~_res clipped [n, n, b, s])``
+    of the pre-activations ``z [n (n + 2), b, s]``."""
     pre = jax.nn.sigmoid(z[:n])
     post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
-    res = jnp.clip(z[2 * n:], -clamp, clamp).reshape(n, n, b, s)
+    res = jnp.clip(z[2 * n:], -clamp, clamp).reshape((n, n) + z.shape[1:])
     return pre, post, res
+
+
+def coefficients(streams, phi, bias, alpha, n: int, eps: float, clamp: float):
+    """``(H_pre [n, b, s], H_post [n, b, s], H~_res clipped [n, n, b, s])``
+    of ``streams [b, s, n, C]``, in fp32 with the tokens minor."""
+    z = _pre_activations(streams, phi, bias, alpha, n, eps)[0]
+    return _gates(z.reshape((-1,) + streams.shape[:2]), n, clamp)
 
 
 def _iterations(a, iters: int, eps: float):
@@ -205,6 +234,165 @@ def mix_back(res, post, streams, y):
     return jnp.stack(out, axis=2).astype(streams.dtype)
 
 
+def _tokens(x, rows: int):
+    """``[..., b, s]`` or ``[b, s, ...]`` with the two token axes as one:
+    ``rows`` says how many axes stand before them."""
+    return x.reshape(x.shape[:rows] + (-1,) + x.shape[rows + 2:])
+
+
+def _tokens_minor(x):
+    """``[b, s, ..., C] -> [..., C, b s]``, as the kernels take the streams
+    (and ``y``, ``du``) and as the compiled step holds them: a bitcast
+    there."""
+    return jnp.moveaxis(_tokens(x, 0), 0, -1)
+
+
+def _tokens_major(x, shape):
+    """:func:`_tokens_minor` back: ``[..., C, b s] -> [b, s, ..., C]``."""
+    return jnp.moveaxis(x, -1, 0).reshape(shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _pre_side(streams, phi, bias, alpha, n: int, eps: float, interpret: bool):
+    """``(u [b, s, C], z [n (n + 2), b, s], streams)``: the flattened norm,
+    the product with ``phi``, ``alpha`` and ``bias``, ``H_pre``'s sigmoid and
+    the mix down, whose backward pass is one kernel
+    (``kernels/hyper_connection.py::pre_bwd``).  The streams are an output
+    too: who reads them there and not from the argument (``_mix_back``) sends
+    its cotangent into this function's backward pass, the only writer of
+    ``dX``, where autodiff would add two cotangents up in a pass of its
+    own."""
+    return _pre_side_fwd(streams, phi, bias, alpha, n, eps, interpret)[0]
+
+
+def _pre_side_fwd(streams, phi, bias, alpha, n, eps, interpret):
+    with scope("hc.coeff"):
+        z, product, factor = _pre_activations(streams, phi, bias, alpha, n,
+                                              eps)
+        z = z.reshape((-1,) + streams.shape[:2])
+        pre = jax.nn.sigmoid(z[:n])
+    with scope("hc.pre"):
+        u = mix_down(pre, streams, streams.dtype)
+    return (u, z, streams), (streams, phi, alpha, pre, product, factor)
+
+
+def _pre_bwd_rows(phi, alpha, pre, product, factor, dz, n: int):
+    """What a token has of ``_pre_side``'s backward pass before ``dH_pre`` is
+    known, as ``kernels.pre_bwd`` takes it: the rows ``(q [k, t], H_pre, s,
+    v [n, t], c0 [1, t])`` and ``phi``'s pieces hi, hi, lo ``[m, 3 k]``.
+    With ``w = vec(X) phi``, ``r`` the norm's factor and ``z = a w r +
+    bias``: ``q = a r dz`` (``dw`` but for its ``pre`` rows' ``s dH_pre``,
+    ``s`` the sigmoid's slope times ``a r``), and the norm's term of ``dX``
+    is ``(sum dw v) vec(X)`` with ``v = -(r / n C) w r``: ``c0 = sum q v``."""
+    k, width = phi.shape[1], phi.shape[0]
+    a = jnp.repeat(alpha, np.asarray([n, n, n * n]))[:, None] * factor[None]
+    q = a * dz
+    v = (-1.0 / width) * factor[None] * product
+    p = _bf16_pieces(phi, 1)
+    return ((q, pre, a[:n] * pre * (1.0 - pre), v[:n],
+             jnp.sum(q * v, axis=0, keepdims=True)),
+            jnp.concatenate([p[:, :k], p[:, :k], p[:, k:2 * k]], axis=1))
+
+
+def _pre_side_bwd(n, eps, interpret, kept, cotangents):
+    """``dX = dX~ + H_pre du + phi dw + (sum dw v) vec(X)`` in the kernel
+    (:func:`_pre_bwd_rows`), which returns the ``dH_pre`` that closes ``dz``
+    for ``dbias``, ``dalpha`` and ``dphi``."""
+    streams, phi, alpha, pre, product, factor = kept
+    du, dz, dxt = cotangents
+    pre, dz = _tokens(pre, 1), _tokens(dz, 1)
+    with scope("hc.pre"):
+        dx, dpre = kernels.pre_bwd(
+            _tokens_minor(du), _tokens_minor(streams), _tokens_minor(dxt),
+            *_pre_bwd_rows(phi, alpha, pre, product, factor, dz, n),
+            interpret=interpret)
+    with scope("hc.coeff"):
+        dz = dz.at[:n].add(pre * (1.0 - pre) * dpre)
+        a = jnp.repeat(alpha, np.asarray([n, n, n * n]))[:, None]
+        dalpha = jnp.sum(dz * product, axis=1)
+        dalpha = jnp.stack([jnp.sum(dalpha[:n]), jnp.sum(dalpha[n:2 * n]),
+                            jnp.sum(dalpha[2 * n:])])
+        # The product's own rule for phi, over the closed cotangent.
+        flat = _tokens(streams, 0).reshape(-1, phi.shape[0])
+        dphi = _dphi(flat, _bf16_pieces(a * factor[None] * dz, 0))
+    return (_tokens_major(dx, streams.shape), dphi, jnp.sum(dz, axis=1),
+            dalpha)
+
+
+_pre_side.defvjp(_pre_side_fwd, _pre_side_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _mix_back(res, post, streams, y, interpret: bool):
+    """:func:`mix_back`, whose backward pass is one kernel
+    (``kernels/hyper_connection.py::post_bwd``)."""
+    return mix_back(res, post, streams, y)
+
+
+def _mix_back_fwd(res, post, streams, y, interpret):
+    return mix_back(res, post, streams, y), (res, post, streams, y)
+
+
+def _mix_back_bwd(interpret, kept, g):
+    res, post, streams, y = kept
+    with scope("hc.post"):
+        dy, dxt, dres, dpost = kernels.post_bwd(
+            _tokens_minor(g), _tokens_minor(streams), _tokens_minor(y),
+            _tokens(res, 2), _tokens(post, 1), interpret=interpret)
+    return (dres.reshape(res.shape), dpost.reshape(post.shape),
+            _tokens_major(dxt, streams.shape), _tokens_major(dy, y.shape))
+
+
+_mix_back.defvjp(_mix_back_fwd, _mix_back_bwd)
+
+
+def reference(cfg, streams, phi, bias, alpha):
+    """``streams [b, s, n, C] -> (u [b, s, C], back, H_res)`` in
+    ``jax.numpy`` under plain autodiff: the one form of a hyper-connection,
+    and what :func:`connect` is off the TPU, in float32 and for the shapes
+    ``kernels.takes`` refuses."""
+    n = cfg.hc_mult
+    with scope("hc.coeff"):
+        pre, post, logits = coefficients(
+            streams, phi, bias, alpha, n, cfg.norm_eps, cfg.hc_res_clamp)
+    with scope("hc.sinkhorn"):
+        res = sinkhorn(logits, cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    with scope("hc.pre"):
+        u = mix_down(pre, streams, cfg.dtype)
+
+    def back(y):
+        with scope("hc.post"):
+            return mix_back(res, post, streams, y)
+
+    return u, back, res
+
+
+def connect(cfg, streams, phi, bias, alpha, *, interpret: bool = False):
+    """:func:`reference` with the same forward operations and, on a TPU (or
+    with ``interpret``) for the shapes ``kernels.takes`` takes, the backward
+    pass of the stream-sized work in ``kernels/hyper_connection.py``'s two
+    kernels: :func:`_pre_side` and :func:`_mix_back` carry them, the
+    sigmoids, the clip and :func:`sinkhorn` between the two as they
+    were."""
+    b, s, n, c = streams.shape
+    if not ((interpret or jax.default_backend() == "tpu")
+            and streams.dtype == jnp.dtype(cfg.dtype)
+            and kernels.takes(n, c, b * s, streams.dtype)):
+        return reference(cfg, streams, phi, bias, alpha)
+    u, z, streams = _pre_side(streams, phi, bias, alpha, n, cfg.norm_eps,
+                              interpret)
+    with scope("hc.coeff"):
+        _, post, logits = _gates(z, n, cfg.hc_res_clamp)
+    with scope("hc.sinkhorn"):
+        res = sinkhorn(logits, cfg.hc_sinkhorn_iters, cfg.hc_eps)
+
+    def back(y):
+        with scope("hc.post"):
+            return _mix_back(res, post, streams, y, interpret)
+
+    return u, back, res
+
+
 def fan_out(x, n: int):
     """The embedding on every stream: ``[b, s, C] -> [b, s, n, C]``."""
     with scope("hc.post"):
@@ -259,19 +447,9 @@ class HyperConnection(nn.Module):
         bias = self.param("bias", bias_init(n, self.read), (k,), jnp.float32)
         alpha = self.param("alpha", nn.initializers.constant(INIT_ALPHA),
                            (3,), jnp.float32)
-        with scope("hc.coeff"):
-            pre, post, logits = coefficients(
-                streams, phi, bias, alpha, n, cfg.norm_eps, cfg.hc_res_clamp)
+        u, back, res = connect(cfg, streams, phi, bias, alpha)
         with scope("hc.sinkhorn"):
-            res = sinkhorn(logits, cfg.hc_sinkhorn_iters, cfg.hc_eps)
             self.sow("hc", "deviation", deviation(res))
-        with scope("hc.pre"):
-            u = mix_down(pre, streams, cfg.dtype)
-
-        def back(y):
-            with scope("hc.post"):
-                return mix_back(res, post, streams, y)
-
         return u, back
 
 

@@ -97,7 +97,12 @@ def test_benchmark_json_names_the_cell_and_its_files():
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
         assert metrics[name]["workloads"][-1] == CELL, name
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(NEW_METRICS)
+    # Appended together (PR 58); PR 59's two kernel metrics stand behind.
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 3] == list(NEW_METRICS)
+    assert names[first + 3:] == ["hyper_connection_kernel_calls_step",
+                                 "hyper_connection_kernels_ms_step"]
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"

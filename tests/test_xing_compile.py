@@ -87,18 +87,32 @@ def test_xings_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
     assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
                        "hvd_mla_operands_fwd", "hvd_mla_operands_bwd",
-                       "hvd_rows_to_tokens"}, kernels
+                       "hvd_rows_to_tokens",
+                       "hvd_hyper_connection_post_bwd",
+                       "hvd_hyper_connection_pre_bwd"}, kernels
     for kernel, calls in (("splash_mha_fwd_residuals", 10),
                           ("splash_mha_dkv_dq", 5),
                           ("hvd_mla_operands_fwd", 10),
-                          ("hvd_mla_operands_bwd", 5)):
+                          ("hvd_mla_operands_bwd", 5),
+                          ("hvd_hyper_connection_post_bwd", 10),
+                          ("hvd_hyper_connection_pre_bwd", 10)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
     assert "32,8192,8192" not in text            # the scores, any layout
     assert ".remat" not in text                  # nothing the compiler's own
     # The coefficients keep the tokens minor: no [tokens, 4, 4] (or [tokens,
     # 16], [tokens, 24]) array in fp32, which a TPU pads to (8, 128) tiles.
-    assert not re.findall(r"f32\[(?:1,)?8192,(?:4,4|16|24)\]", text)
+    # (With its layout: ``{0,1`` is an array held tokens-minor, as the
+    # router's four slices of ``f32[8192,64]{0,1}`` are.)
+    assert not re.findall(r"f32\[(?:1,)?8192,(?:4,4|16|24)\]\{(?!0,1[:}])",
+                          text)
     assert re.findall(r"f32\[4,4,1,8192\]", text)
+    # The backward pass of the product with phi is inside the kernel: no
+    # cotangent of the flattened streams in fp32.  And the streams reach the
+    # kernels as the step holds them, the tokens minor: no copy of theirs.
+    assert not re.findall(r"f32\[(?:1,)?8192,14336\]", text)
+    assert re.findall(r"bf16\[1,8192,4,3584\]\{1,3,2,0", text)
+    assert not re.findall(
+        r"= bf16\[(?:1,8192,4,3584|4,3584,8192)\]\S* copy\(", text)
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
            + mem.output_size_in_bytes - mem.alias_size_in_bytes) / 2 ** 30
