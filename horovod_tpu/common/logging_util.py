@@ -5,7 +5,6 @@
 from __future__ import annotations
 
 import logging
-import os
 import sys
 
 from . import env
@@ -40,8 +39,3 @@ def get_logger(name: str = "horovod_tpu") -> logging.Logger:
         root.propagate = False
         _configured = True
     return logger
-
-
-def rank_prefix() -> str:
-    r = os.environ.get(env.HOROVOD_RANK)
-    return f"[{r}]" if r is not None else ""

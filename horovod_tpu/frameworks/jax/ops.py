@@ -207,13 +207,6 @@ def barrier(name: Optional[str] = None) -> None:
         raise HorovodInternalError(status_box[0].error_message)
 
 
-def size_or_one() -> int:
-    """World size, or 1 when the runtime is not initialized (lets wrappers
-    degrade to single-process no-comm mode)."""
-    state = global_state()
-    return state.topo.size if state.topo is not None else 1
-
-
 def initialized() -> bool:
     """True when ``hvd.init()`` has completed and the runtime is live."""
     return global_state().topo is not None

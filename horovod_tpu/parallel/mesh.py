@@ -12,7 +12,6 @@ runtime already does for multi-slice meshes (SURVEY §5.8).
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -110,12 +109,3 @@ def local_mesh_axes(mesh) -> List[str]:
     """Axes of size > 1 (useful for building full psum axis tuples)."""
     return [name for name, size in zip(mesh.axis_names, mesh.devices.shape)
             if size > 1]
-
-
-def validate_power_of_two(n: int, what: str = "ranks") -> None:
-    """Adasum VHDD requires power-of-two participant counts
-    (reference `adasum.h:194-450`)."""
-    if n & (n - 1):
-        raise ValueError(
-            f"{what} must be a power of two for Adasum VHDD, got {n} "
-            f"(nearest: {2 ** int(math.log2(n))})")

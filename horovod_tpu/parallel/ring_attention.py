@@ -19,7 +19,6 @@ them is the profile-guided next step (`/opt/skills/guides/pallas_guide.md`).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -101,20 +100,3 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows → zeros, not NaN
     out = (o / l).astype(q.dtype)
     return jnp.einsum("bhqd->bqhd", out)
-
-
-def ring_attention_sharded(q, k, v, mesh, axis_name: str = AXIS_SEQ,
-                           causal: bool = False,
-                           sm_scale: Optional[float] = None):
-    """Convenience wrapper: shard_map ``ring_attention`` over ``mesh`` with
-    batch on 'data' and sequence on ``axis_name``."""
-    from jax.sharding import PartitionSpec as P
-
-    from .sharding import shard_map_fn
-
-    spec = P("data", axis_name, None, None)
-    fn = shard_map_fn(
-        functools.partial(ring_attention, axis_name=axis_name,
-                          causal=causal, sm_scale=sm_scale),
-        mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    return fn(q, k, v)

@@ -3,9 +3,11 @@ idle round that follows work, twice the last park after every further one,
 up to ``HOROVOD_CYCLE_TIME`` (5 ms as in the reference, or what the
 autotuner hands out); a round with work starts it over, an enqueue ends any
 park at once.  The real ``_background_loop`` and ``_run_loop_once`` over a
-scripted controller with no transport, and two real ranks for what only an
-exchange shows.  Counts and orders only: a bound on a clock here says that
-a wake-up came, never how fast anything is."""
+scripted controller with no transport, whose clock is the parks the loop
+chose (how many rounds a stretch holds is said there, exactly), and two real
+ranks for what only an exchange shows: that every idle round of a real job
+takes its park by that rule.  Counts and orders only: a bound on a clock
+here says that a wake-up came, never how fast anything is."""
 
 import json
 import threading
@@ -47,13 +49,16 @@ class _Script:
     """Stands where the controller does.  A round a character: ``w`` has a
     request, ``i`` has none; after the last the loop is told to leave
     (``rounds=None``: idle rounds until somebody asks it to).  ``tuned``
-    maps a round's index to the cycle time the autotuner hands out in it."""
+    maps a round's index to the cycle time the autotuner hands out in it.
+    ``began_ms`` is the script's clock: when each round began if a round
+    took no time and every park ran out, whatever the host was doing."""
 
     fanin_heartbeat = None
 
     def __init__(self, state, rounds, tuned=None):
         self.state, self.rounds, self.tuned = state, rounds, tuned or {}
         self.seen = 0
+        self.began_ms = []
         self.round_began = threading.Event()
         self._request_if_busy(0)
 
@@ -66,6 +71,8 @@ class _Script:
 
     def compute_response_list(self, requests, shutdown):
         i, self.seen = self.seen, self.seen + 1
+        self.began_ms.append(self.began_ms[-1] + self.state._idle_park_ms
+                             if self.began_ms else 0.0)
         self.round_began.set()
         assert bool(requests) == (self._kind(i) == "w"), (i, requests)
         self._request_if_busy(i + 1)
@@ -148,7 +155,7 @@ def _an_add_ends_a_park_at_the_cap_at_once(loop):
     assert state.wake.parked.wait(SOON_S)
     assert _park_ms(state) == [LONG_MS]
     state.script.round_began.clear()
-    state.script.rounds = "i" * state.script.seen + "w"
+    state.script.rounds = "i" * state.script.seen + "wi"
     t0 = time.monotonic()
     # What TensorQueue.add does once the entry is in the table: the round
     # that takes the request starts long before the cap's time is out.
@@ -156,7 +163,23 @@ def _an_add_ends_a_park_at_the_cap_at_once(loop):
     assert state.script.round_began.wait(SOON_S)
     assert time.monotonic() - t0 < SOON_S
     state.background.join(SOON_S)
-    assert state.shutdown_complete.is_set() and state._idle_park_ms == 0.0
+    # ... and the idle round after it parks the floor, not the cap again.
+    assert state.shutdown_complete.is_set() and state._idle_park_ms == FLOOR
+
+
+def _rounds_in_200_ms(loop, cycle_ms, rounds):
+    state = loop("i" * rounds, cycle_ms)
+    state.background.join(SOON_S)
+    assert state.script.seen == rounds + 1
+    return sum(ms < 200.0 for ms in state.script.began_ms)
+
+
+def _an_idle_stretch_makes_a_fifth_of_the_flat_parks_rounds(loop):
+    # Rounds at 0, 1, 3 and 7 ms and every 5 ms from there, against the
+    # old flat park's one a millisecond (the cap at the floor): a fifth is
+    # the limit the doubling nears from above as the stretch grows.
+    assert _rounds_in_200_ms(loop, CAP, 45) == 42
+    assert _rounds_in_200_ms(loop, FLOOR, 205) == 200
 
 
 def _shutdown_does_not_wait_out_a_park_at_the_cap(loop):
@@ -196,6 +219,8 @@ CASES = {
         _gauge_is_the_park_before_a_round_with_work,
     "an_add_ends_a_park_at_the_cap_at_once":
         _an_add_ends_a_park_at_the_cap_at_once,
+    "an_idle_stretch_makes_a_fifth_of_the_flat_parks_rounds":
+        _an_idle_stretch_makes_a_fifth_of_the_flat_parks_rounds,
     "shutdown_does_not_wait_out_a_park_at_the_cap":
         _shutdown_does_not_wait_out_a_park_at_the_cap,
 }
@@ -230,45 +255,47 @@ def test_the_environments_cycle_time_is_the_cap(monkeypatch, knob, cap):
 
 # Two ranks, one job: 200 ms in which nobody enqueues anything, at the
 # default and at the old flat 1 ms (the cap at the floor); then scalar
-# allreduces one after another with every park written down.
+# allreduces one after another.  Every idle round's park is written down
+# beside the round's number, whether or not the loop had time left to wait
+# it out.
 TWO_RANKS = """
-import json, threading, time
+import json, time
 from horovod_tpu.core.state import global_state
 
 st = global_state()
+chosen = []                     # [round, the park it chose], idle rounds
+next_park = st._next_idle_park_ms
 
-class Wake(threading.Event):
-    parks = None
-    def wait(self, timeout=None):
-        if self.parks is not None:
-            self.parks.append(st._idle_park_ms)
-        return super().wait(timeout)
+def written_down():
+    park = next_park()
+    chosen.append([st.cycle_count, park])
+    return park
 
-wake = Wake()
-st._wake = wake
-st.tensor_queue.set_wake_event(wake)
+st._next_idle_park_ms = written_down
 x = np.ones((), np.float32)
 report = {"default_cap": st.cycle_time_ms}
 
-def idle_rounds(cap_ms):
+def idle_stretch(cap_ms):
     hvd.barrier()
     st.cycle_time_ms = cap_ms
     hvd.allreduce(x, op=hvd.Sum, name="park.sync")
-    before = st.cycle_count
+    t0, first = time.monotonic(), st.cycle_count
     time.sleep(0.2)
-    rounds = st.cycle_count - before
+    last, ms = st.cycle_count, 1e3 * (time.monotonic() - t0)
     hvd.barrier()
-    return rounds
+    return first, last, ms
 
-report["rounds_backoff"] = idle_rounds(report["default_cap"])
-report["rounds_flat_1ms"] = idle_rounds(1.0)
+report["backoff"] = idle_stretch(report["default_cap"])
+report["flat_1ms"] = idle_stretch(1.0)
 st.cycle_time_ms = report["default_cap"]
 hvd.barrier()
 time.sleep(0.05)                    # both loops parked at the cap
-wake.parks = []
+first = st.cycle_count
 for i in range(100):
     hvd.allreduce(x, op=hvd.Sum, name="park.scalar")
-report["parks"], wake.parks = wake.parks, None
+report["scalars"] = first, st.cycle_count
+hvd.barrier()                       # the last park is written down
+report["chosen"] = chosen
 print("REPORT " + json.dumps(report), flush=True)
 """
 
@@ -281,30 +308,49 @@ def two_ranks():
             for o in out]
 
 
+def _idle_rounds(report, stretch, cap):
+    """The parks a rank chose in the rounds of a stretch, each one of the
+    ladder's (1, 2, 4 ms and the cap) and held to the rule by the round
+    before it: twice the last park after an idle round, the floor after a
+    round with work, never above the cap."""
+    first, last = report[stretch][:2]
+    idle = [(n, park) for n, park in report["chosen"] if first < n <= last]
+    ladder = {min(cap, FLOOR * 2 ** k) for k in range(4)}
+    assert {park for _, park in idle} <= ladder, (stretch, idle)
+    for (before, last_park), (n, park) in zip(idle, idle[1:]):
+        start = 2.0 * last_park if n == before + 1 else FLOOR
+        assert park == min(cap, max(FLOOR, start)), (stretch, idle)
+    return [park for _, park in idle]
+
+
 @pytest.mark.timeout(400)
 @pytest.mark.parametrize("rank", [0, 1])
 def test_an_idle_stretch_makes_a_fraction_of_the_flat_parks_rounds(
         two_ranks, rank):
     r = two_ranks[rank]
     assert r["default_cap"] == CAP
-    # 200 ms at the cap are 40 rounds and the three of the way up; a loaded
-    # host makes fewer, never more.  The flat 1 ms made a round a
-    # millisecond: five times as many on a quiet host (a fifth is the
-    # limit the doubling nears from above, 43 of 200), and still over
-    # twice as many on one whose short waits run long.
-    assert 10 <= r["rounds_backoff"] <= 55, r
-    assert r["rounds_flat_1ms"] >= 2.5 * r["rounds_backoff"], r
+    # Nobody enqueues: every round of the two stretches is an idle one, and
+    # its park the ladder's or the flat 1 ms.
+    for stretch, cap in (("backoff", CAP), ("flat_1ms", FLOOR)):
+        first, last, ms = r[stretch]
+        assert len(_idle_rounds(r, stretch, cap)) == last - first, r[stretch]
+        assert ms >= 200, r[stretch]
+    # 200 ms at the cap are 40 rounds and the three of the way up, and a
+    # sleep that ran long had that much longer; a loaded host makes fewer,
+    # never more.  How many the flat park makes of the same stretch is on
+    # the script's clock (CASES): two stretches of a host's wall time do
+    # not compare.
+    first, last, ms = r["backoff"]
+    assert last - first <= 55 * ms / 200, r["backoff"]
 
 
 @pytest.mark.timeout(400)
 @pytest.mark.parametrize("rank", [0, 1])
 def test_back_to_back_scalar_allreduces_keep_the_floor(two_ranks, rank):
-    parks = two_ranks[rank]["parks"]
     # Work in every round or the next: the first idle round after it parks
-    # the floor and the enqueue ends that park.  A second idle round in a
-    # row is a caller this host kept off the CPU for a millisecond: rare,
-    # and never the way up to the cap.
+    # the floor (the enqueue that ends the park is on the script's clock,
+    # CASES).  Further idle rounds in a row are a caller this host kept off
+    # the CPU: each doubles, and only the fourth in a row is at the cap.
+    parks = _idle_rounds(two_ranks[rank], "scalars", CAP)
     assert parks, "a synchronous caller leaves its loop a round to park in"
-    above = [p for p in parks if p > FLOOR]
-    assert len(above) <= len(parks) / 5, parks
     assert sum(p >= CAP for p in parks) <= 2, parks

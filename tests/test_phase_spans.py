@@ -10,8 +10,11 @@ one fact of that report.  Counts only: nothing here is a timing claim.
 import json
 import sys
 import threading
+from statistics import median_high
 
 import pytest
+
+from horovod_tpu.common.env import DEFAULT_CYCLE_TIME_MS as CAP
 
 from .helpers import reserve_port, run_distributed
 
@@ -494,9 +497,15 @@ def two_ranks():
 
 
 def _median_ms(rows, name, per_tensor=False):
-    values = sorted(ms / max(n, 1) if per_tensor else ms
-                    for n, ms in (row[name] for row in rows))
-    return values[len(values) // 2]
+    return median_high([ms / max(n, 1) if per_tensor else ms
+                    for n, ms in (row[name] for row in rows)])
+
+
+def _busy_rounds_a_tensor(rows):
+    """The median step's rounds that took a request or brought a response
+    (``negotiate``'s count), by the tensors that were agreed on in it."""
+    return median_high([row["negotiate"][0] / row["negotiate_wait"][0]
+                    for row in rows])
 
 
 @pytest.mark.timeout(400)
@@ -506,24 +515,29 @@ def test_the_rank_that_waits_reads_the_wait_and_the_late_rank_none(
     early = 1 - late
     waited, slept = two_ranks[early]["late%d" % late], \
         two_ranks[late]["late%d" % late]
-    plain = two_ranks[early]["plain"]
-    # The early rank's tensor waits for the late rank's announcement: the
-    # 50 ms, a tensor (100 leaves room for a loaded host, not for a hole).
-    grew = _median_ms(waited, "negotiate_wait", per_tensor=True) \
-        - _median_ms(plain, "negotiate_wait", per_tensor=True)
-    assert 40 <= grew <= 100, grew
+    # The early rank hands its tensor over 50 ms before the late rank does
+    # (the sleep guarantees them, less what the two callers start apart):
+    # it waits for a round to take it and then for the late rank's
+    # announcement, and however much longer a loaded host takes.
+    held = median_high([row["queue_wait"][1] + row["negotiate_wait"][1]
+                    for row in waited])
+    assert held >= 40, held
     # ... and its rounds sit blocked on the late rank's frames (rank 0) or
-    # on the coordinator's reply (rank 1) for most of it.
-    assert _median_ms(waited, "negotiate_recv") >= 20
-    assert _median_ms(waited, "negotiate_recv") \
-        <= _median_ms(waited, "negotiate_wait") + 5
-    # The late rank's own tensor is agreed on in the round that takes it,
-    # and its receives are counted only while it has one in flight: under
-    # 10 ms on a quiet host, one round of a loaded one at most (25), and
-    # never near what the early rank reads.
+    # on the coordinator's reply (rank 1) for most of it: one receive a
+    # round, so never more than the steps themselves took.
+    assert 20 <= _median_ms(waited, "negotiate_recv") \
+        <= median_high([row["wall_ms"] for row in waited])
+    # How long is the host's; how many rounds is the protocol's.  A step
+    # has one tensor, which the early rank announces in one round with work
+    # and hears agreed in another, and the late rank in the round that
+    # takes it.
+    assert _busy_rounds_a_tensor(waited) == 2
+    assert _busy_rounds_a_tensor(slept) == 1
+    # ... so the late rank's wait, and its receives, which are counted
+    # only while it has a tensor in flight, are never near what the early
+    # rank reads.
     for name in ("negotiate_wait", "negotiate_recv"):
         late_ms = _median_ms(slept, name, per_tensor=name == "negotiate_wait")
-        assert late_ms < 25, (name, late_ms)
         assert late_ms < 0.5 * _median_ms(waited, name), (name, late_ms)
     # The sleep is wall time of update and none of its CPU.
     assert _median_ms(slept, "update") >= 50
@@ -556,19 +570,23 @@ def test_a_blocked_receive_lies_inside_its_round(two_ranks, rank):
 @pytest.mark.parametrize("rank", [0, 1])
 def test_rounds_are_negotiate_and_negotiate_idle(two_ranks, rank):
     r = two_ranks[rank]
-    assert r["rounds"] == r["cycle_count"] > 100
+    # Every one of the job's 25 steps takes a round with work or two, and
+    # the late stretches' sleeps are idle rounds.
+    assert r["rounds"] == r["cycle_count"] > r["busy_rounds"] >= 25
     # The loop hands its idle rounds and its CPU clock on after a round that
     # had work and when it ends: a reading a busy round, and the last.
     assert r["busy_rounds"] <= r["cpu_loop_readings"] <= r["busy_rounds"] + 1
-    # A step of the late stretches spans the 50 ms of rounds: 1, 2 and 4 ms
-    # and then the cap's 5 (ISSUE 54; a round a millisecond before it), so
-    # eleven or twelve a step, where a plain step has two; a loaded host
-    # makes fewer.
-    idle = {name: sum(row["negotiate_idle"][0] for row in r[name])
-            for name in ("plain", "late0", "late1")}
-    assert idle["late1"] >= 25 and idle["late0"] >= 25, idle
-    assert min(idle["late0"], idle["late1"]) > 2 * idle["plain"], idle
-    assert max(idle["late0"], idle["late1"]) <= 100, idle
+    # A step of the late stretches spans the 50 ms of idle rounds: after
+    # each of its rounds with work 1, 2 and 4 ms and then the cap's 5
+    # (ISSUE 54; a round a millisecond before it).  So eleven or twelve a
+    # step on a quiet host, and on any host no more than one for every 5 ms
+    # of the steps' own wall time beside the three of the way up after each
+    # round with work (two a step at most, and as many again to spare): a
+    # loaded host makes fewer, never more.
+    for name in ("plain", "late0", "late1"):
+        idle = sum(row["negotiate_idle"][0] for row in r[name])
+        wall_ms = sum(row["wall_ms"] for row in r[name])
+        assert idle <= 12 * len(r[name]) + wall_ms / CAP, (name, r[name])
 
 
 @pytest.mark.timeout(400)
@@ -586,9 +604,15 @@ def test_trace_holds_the_blocked_receive_inside_its_round(two_ranks, rank):
               if name == "hvd.negotiate"}
     recvs = [(line, ids, start, end) for line, name, ids, start, end in events
              if name == "hvd.negotiate_recv"]
-    assert recvs
+    # The trace's end may cut the last round open: its receive is there and
+    # its own span is not.  The last round's, and no other.
+    cycles = [ids["cycle"] for _, ids, _, _ in recvs]
+    assert {c for c in cycles if c not in rounds} <= {max(cycles)}, cycles
+    assert any(c in rounds for c in cycles)
     for line, ids, start, end in recvs:
         assert ids["peer"] == 1 - rank            # two ranks: the other one
+        if ids["cycle"] not in rounds:
+            continue
         round_line, round_start, round_end = rounds[ids["cycle"]]
         assert line == round_line == "horovod-backgro"
         assert round_start <= start <= end <= round_end
