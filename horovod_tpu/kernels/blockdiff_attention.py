@@ -15,24 +15,21 @@ key ``r`` iff
 ``L*b + L(L-b)/2 + L(L+b)/2 = L**2 + L*b`` of the ``4 L**2`` pairs are
 allowed: a quarter of the square.  :func:`block_diffusion_mask` is that rule,
 on numpy or JAX integers, and :class:`BlockDiffusion` the rule in the form
-``kernels/masked_attention.py`` takes: its kernels (the library's splash
-forward, one backward kernel of this repo) compute the mask from one code a
-position, visit only the tiles the rule allows (80 of 256 at ``L`` = 8192
-with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and serve grouped
-KV heads without repeating them.  ``masked_attention.attention(q, k, v,
-BlockDiffusion(block))`` is that kernel under this rule; the calls lie
-under ``jax.named_scope("hvd.attn.blockdiff")``.
+``kernels/masked_attention.py`` takes: its two kernels compute the mask
+from one code a position, visit only the tiles the rule allows (80 of 256 at
+``L`` = 8192 with tiles of 1024), keep no ``[2L, 2L]`` table anywhere, and
+serve grouped KV heads without repeating them.
+``masked_attention.attention(q, k, v, BlockDiffusion(block))`` is those
+kernels under this rule; the calls lie under
+``jax.named_scope("hvd.attn.blockdiff")``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-
-import numpy as np
 
 from . import masked_attention
-from .masked_attention import _TILES, BLOCK, OP_LINE_NAMES  # noqa: F401
+from .masked_attention import BLOCK, OP_LINE_NAMES  # noqa: F401
 
 SCOPE = "hvd.attn.blockdiff"
 
@@ -59,61 +56,12 @@ def takes(seq_len: int, head_dim: int, block: int) -> bool:
     return masked_attention.takes(BlockDiffusion(block), seq_len, head_dim)
 
 
-def _code(ids, half_len: int, block: int):
-    """``2 * B(p) + H(p)``: one number a position that decides the rule.  For
-    a key code ``c`` and a query code ``r``: allowed iff ``c == r`` (same
-    block of the same half) or ``c`` is odd (a clean key) and ``c < r`` (an
-    earlier block; for a clean query ``c < r`` also excludes its own block,
-    which ``c == r`` lets in)."""
-    clean = ids >= half_len
-    shift = block.bit_length() - 1
-    return (((ids - clean * half_len) >> shift) << 1) | clean
-
-
-@functools.lru_cache(maxsize=None)
-def _mask_class():
-    """The mask's class, made once: the library is imported only where the
-    kernel is used."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask as mask_lib,
-    )
-
-    class BlockDiffusionMask(mask_lib._ComputableMask):
-        """The rule as a mask splash attention computes inside its kernels.
-        The kernel hands ``mask_function`` the rows' entries of
-        ``q_sequence``, here already the queries' codes, and the keys' plain
-        positions."""
-
-        def __init__(self, half_len: int, block: int):
-            def mask_function(q_codes, kv_ids):
-                c = _code(kv_ids, half_len, block)
-                return (c == q_codes) | (((c & 1) == 1) & (c < q_codes))
-
-            super().__init__(shape=(2 * half_len, 2 * half_len),
-                             mask_function=mask_function)
-            self.q_sequence = _code(np.arange(2 * half_len, dtype=np.int32),
-                                    half_len, block).astype(np.int32)
-            self.rule = (half_len, block)
-
-        def __eq__(self, other):
-            return isinstance(other, type(self)) and self.rule == other.rule
-
-        def __hash__(self):
-            return hash((type(self).__name__, self.rule))
-
-    return BlockDiffusionMask
-
-
-def _make_mask(half_len: int, block: int):
-    return _mask_class()(half_len, block)
-
-
 @dataclasses.dataclass(frozen=True)
 class BlockDiffusion:
     """The rule over ``[x_t ; x_0]`` with blocks of ``block`` tokens, for
     ``masked_attention``; the sequence it is given is the ``2L`` positions.
-    A tile never straddles the two halves, and the kernel's code needs the
-    block length a power of two that divides a tile."""
+    A tile never straddles the two halves, and the block length is a power
+    of two that divides a tile."""
 
     block: int
     scope = SCOPE
@@ -123,10 +71,10 @@ class BlockDiffusion:
         ``2 * B(p) + H(p)``: the key's equals the query's (the same block of
         the same half), or it is odd (a clean key) and less (an earlier
         block; for a clean query that leaves out its own block, which the
-        equality lets in).  It is :func:`_code`'s number by a division, so
-        that any block length does; it is made on the positions as they
-        come, so ids that broadcast against each other (the backward
-        kernel's, a row against a column) cost two comparisons a pair."""
+        equality lets in).  The number is made by a division, so that any
+        block length does, and on the positions as they come, so ids that
+        broadcast against each other (the kernels', a row against a column)
+        cost two comparisons a pair."""
         half_len = seq_len // 2
 
         def code(ids):
@@ -145,6 +93,3 @@ class BlockDiffusion:
         return seq_len % 2 == 0 and (seq_len // 2) % BLOCK == 0 \
             and self.block & (self.block - 1) == 0 \
             and BLOCK % self.block == 0
-
-    def mask(self, seq_len: int):
-        return _make_mask(seq_len // 2, self.block)

@@ -1,30 +1,46 @@
 """Softmax attention under a mask that is a rule, not a table.
 
 One wrapper for every mask the models here state as a rule over (query
-position, key position): the forward is JAX's pallas splash attention
-(``jax.experimental.pallas.ops.tpu.splash_attention``), the backward one
-kernel of this repo (``kernels/masked_attention_bwd.py``: dq, dk and dv from
-a single pass).  Both compute the mask from the rule, so they visit only the
-tiles it allows, no ``[s, s]`` table exists anywhere, and grouped KV heads are
+position, key position), and two kernels of this repo: the forward
+(:func:`out_lse`, here) and the backward
+(``kernels/masked_attention_bwd.py``: dq, dk and dv from a single pass).  Both
+walk ``masked_attention_bwd.tile_table``'s list of the tiles the rule allows
+by scalar prefetch and compute the mask from the rule in the tiles that also
+hold a forbidden pair, so no empty tile is visited or fetched, a full tile is
+not masked, no ``[s, s]`` table exists anywhere, and grouped KV heads are
 served without repeating them.  A rule is a small hashable object with
 
 - ``scope``: the ``jax.named_scope`` its kernel calls lie under;
 - ``allowed(q_ids, kv_ids, seq_len)``: the rule itself, a boolean array, on
-  numpy or JAX integers that broadcast against each other (the backward
-  kernel's table of tiles and its mask in a partial tile come from it);
+  numpy or JAX integers that broadcast against each other (the table of tiles
+  and the kernels' mask in a partial tile come from it);
 - ``allowed_pairs(seq_len)``: how many pairs it allows in one sequence;
-- ``takes(seq_len)``: whether the kernels' tiles fit the rule at this length;
-- ``mask(seq_len)``: the rule as a mask the library computes in its kernel.
+- ``takes(seq_len)``: whether the kernels' tiles fit the rule at this length.
 
 The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
 :class:`Window` (causal, and the key inside the last ``size`` positions:
 ``hvd.attn.window``) and ``kernels/blockdiff_attention.py``'s
 ``BlockDiffusion``.  At 16,384 positions and tiles of 1024 a causal layer
 visits 136 of 256 tiles and a window of 4096 visits 70.  :func:`attention` is
-the kernel, :func:`einsum` the same mask through a grouped einsum (off the
-TPU, and for shapes the kernel does not take).  On the device's op line the
-two kernels are ``splash_mha_fwd_residuals`` and ``splash_mha_dkv_dq``
+the kernels, :func:`einsum` the same mask through a grouped einsum (off the
+TPU, and for shapes the kernels do not take).  On the device's op line the
+two kernels are :data:`FWD_NAME` and ``splash_mha_dkv_dq``
 (:data:`OP_LINE_NAMES`) whatever the rule.
+
+The forward lays a tile out queries on the rows and keys on the lanes
+(``s = q k^T``), so that both of its products stream a tile's 1024 queries
+through the MXU past stationary keys and values.  With keys on the rows, as
+the backward has them, the softmax's statistics are one row and their
+reductions element-wise, but the second product then either streams the 128
+rows of ``v^T`` past ``p`` as the stationary operand or needs ``p``
+transposed, and both are slower than the library's kernel at every width but
+64 (PERF.md §6, PR 61).  The running maximum and sum of a row are kept
+copied over one lane group (``[block_q, 128]``), the form the lane
+reductions leave them in and the accumulator ``[block_q, dv]`` takes them in
+without a broadcast.  ``p`` goes to the second product in the operands' dtype
+with an fp32 sum (bf16 operands: rounded, as :func:`einsum` and the
+backward's ``dv`` round it, and as the MXU rounds a float32 operand at the
+default precision; float32 operands: as it is).
 """
 
 from __future__ import annotations
@@ -42,35 +58,43 @@ from . import masked_attention_bwd
 # A regular expression for the kernels' names on the device's op line.
 OP_LINE_NAMES = r"^splash_mha_(fwd|dq|dkv)"
 
-# The forward kernel's tiles (splash attention's ``BlockSizes``: queries x
-# keys, and the keys it multiplies at a time).  Measured on a v5e under the
-# block-diffusion mask at 16,384 positions, 32 query heads on 4 KV heads of
-# 128, forward + the library's backward (PERF.md, PR 31): tiles of 256 94.4
-# ms, of 512 46.1, of 1024 42.2, these 40.7; keys or queries of 2048 are
-# slower or do not fit the fast memory.
+# The kernels' names on the op line: the forward's starts as the library's
+# did (``splash_mha_fwd_residuals``), so that what reads ``OP_LINE_NAMES``
+# reads the same work, and is not the library's.
+FWD_NAME = "splash_mha_fwd_out_lse"
+
+# The forward kernel's tiles: queries x keys, and the keys multiplied at a
+# time.  Measured on a v5e, the kernel alone, ms a layer (PERF.md §6, PR 61;
+# the library's splash forward at its 1024 x 1024 x 512 beside them): one
+# sequence of 8192, 32 heads, keys of 192 over values of 128, causal
+# (JoyAI-LLM-Flash, Xing4.0) 6.31 with these, 6.52 with the keys 512 at a
+# time, 6.69 with 1024, 7.42 with 128, 6.65 with queries of 512, 7.11 with
+# 2048, 6.61 with keys of 2048 (the library 7.35); 16,384 positions, 28
+# heads on 4 of 128, causal 13.40 (13.58 at 512; the library 14.62), under a
+# window of 4096 7.12 (7.44); 2 x 8192, 32 heads on 8 of 64 8.93 (10.40);
+# 8192, 16 heads on 2 of 256 3.56 (3.96).  The loop over the keys is
+# unrolled: rolled (``lax.fori_loop``) it reads 7.55, 16.66, 8.78, 10.89,
+# 3.93.
 BLOCK = 1024
-_TILES = dict(block_q=BLOCK, block_kv=BLOCK, block_kv_compute=BLOCK // 2)
-# Float32 operands wider than a lane group (the float32 twin of latent
-# attention, 192 wide, which holds a bf16 program's logits to a reference):
-# at tiles of 1024 the library's forward asks the compiler for 16.9 MiB of
-# its 16 of scoped fast memory (my chip run, PR 47); at 512 it fits.
-_TILES_WIDE_FLOAT32 = dict(block_q=BLOCK // 2, block_kv=BLOCK // 2,
-                           block_kv_compute=BLOCK // 2)
+FWD_TILES = (BLOCK, BLOCK, BLOCK // 4)
+# Float32 operands wider than a lane group (the float32 twins of latent
+# attention, 192 wide, and of Qwen3-Next's heads of 256, which hold a bf16
+# program's logits to a reference): tiles of 512, as they had under the
+# library's kernel since PR 47.
+FWD_TILES_WIDE_FLOAT32 = (BLOCK // 2, BLOCK // 2, BLOCK // 2)
+# The fast memory the forward may take: its operands' and outputs' tiles
+# twice, the statistics and the accumulator, and the [queries, keys at a
+# time] fp32 temporaries of a step.  A v5e core has 128 MiB.
+_FWD_VMEM_LIMIT = 64 * 2 ** 20
+# A vreg's lanes: the width the forward keeps a row's maximum and sum at.
+_LANES = 128
 # The backward kernel's: queries x keys, and the keys multiplied at a time.
-# At the same shape (PERF.md, PR 44; the backward alone, ms a layer, with the
+# Under the block-diffusion mask at 16,384 positions, 32 query heads on 4 KV
+# heads of 128 (PERF.md, PR 44; the backward alone, ms a layer, with the
 # block rule still in three clauses): these 23.96, the keys 256 or 1024 at a
 # time 23.88 and 23.93, queries of 512 25.12, of 2048 30.53 (fewer, larger
 # tiles hold more forbidden pairs); by codes these read 20.35.
 BWD_TILES = (BLOCK, BLOCK, BLOCK // 2)
-
-
-def _mask_lib():
-    """The library is imported only where a kernel is built."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask,
-    )
-
-    return splash_attention_mask
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,9 +111,6 @@ class Causal:
 
     def takes(self, seq_len: int) -> bool:
         return seq_len % BLOCK == 0
-
-    def mask(self, seq_len: int):
-        return _mask_lib().CausalMask((seq_len, seq_len))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,16 +135,12 @@ class Window:
     def takes(self, seq_len: int) -> bool:
         return seq_len % BLOCK == 0
 
-    def mask(self, seq_len: int):
-        return _mask_lib().LocalMask((seq_len, seq_len),
-                                     window_size=(self.size - 1, 0), offset=0)
-
 
 def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
     """Whether the kernel takes this shape under ``rule``; otherwise, and off
     the TPU, the same mask goes through :func:`einsum`.  Heads of 64 go in
-    as they are (LFM2-8B-A1B: 32 query heads on 8 KV heads): the library's
-    kernels take half a lane group, and the chip's compiler pads it.
+    as they are (LFM2-8B-A1B: 32 query heads on 8 KV heads): the kernels
+    take half a lane group, and the chip's compiler pads it.
     ``head_dim_v``: the values' width where it is not the keys'; the one
     such pair taken is latent attention's 192 over 128 (JoyAI-LLM-Flash), a
     lane group and a half that the compiler pads likewise."""
@@ -132,30 +149,134 @@ def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
     return (head_dim % 128 == 0 or head_dim == 64) and rule.takes(seq_len)
 
 
-def _wide_float32(q) -> bool:
-    """Whether operands like ``q [..., d]`` take the forward kernel's smaller
-    tiles (:data:`_TILES_WIDE_FLOAT32`)."""
-    return q.dtype.itemsize > 2 and q.shape[-1] > 128
+def _fwd_tiles(q):
+    """The forward kernel's tiles for operands like ``q [..., d]``."""
+    wide_float32 = q.dtype.itemsize > 2 and q.shape[-1] > 128
+    return FWD_TILES_WIDE_FLOAT32 if wide_float32 else FWD_TILES
 
 
-@functools.lru_cache(maxsize=8)
-def _kernel(rule, seq_len: int, heads: int, interpret: bool,
-            wide_float32: bool = False):
-    """The library's forward kernel for one rule and shape, which also
-    returns the rows' log-sum-exp; building it walks the rule tile by tile
-    on the host, once."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as splash,
-    )
+def _fwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
+                out_ref, lse_ref, m_ref, l_ref, acc_ref, *, rule,
+                seq_len: int, block_kv_compute: int):
+    import jax.experimental.pallas as pl
 
-    mask = _mask_lib().MultiHeadMask([rule.mask(seq_len)] * heads)
-    # Mask information is made of numpy arrays here, whatever trace is open.
+    PARTIAL, FIRST, LAST = (masked_attention_bwd.PARTIAL,
+                            masked_attention_bwd.FIRST,
+                            masked_attention_bwd.LAST)
+    mask_value = masked_attention_bwd._MASK_VALUE
+    block_q, block_kv, dv = q_ref.shape[0], k_ref.shape[0], v_ref.shape[1]
+    step = pl.program_id(3)
+    flags = flags_ref[step]
+    q_start = q_tile_ref[step] * block_q
+    kv_start = kv_tile_ref[step] * block_kv
+
+    def over_lanes(stat, width):
+        """A row statistic ``[block_q, _LANES]``, one value a row copied over
+        a lane group, against ``width`` columns."""
+        return jnp.tile(stat, (1, -(-width // _LANES)))[:, :width]
+
+    @pl.when(flags & FIRST != 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, mask_value)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(masked: bool):
+        q = q_ref[...]
+        for c in range(block_kv // block_kv_compute):
+            rows = pl.ds(c * block_kv_compute, block_kv_compute)
+            k, v = k_ref[rows, :], v_ref[rows, :]
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            if masked:
+                # A column of queries against a row of keys: what a rule
+                # computes a position it computes on these.
+                q_ids = q_start + lax.broadcasted_iota(jnp.int32,
+                                                       (block_q, 1), 0)
+                kv_ids = kv_start + c * block_kv_compute \
+                    + lax.broadcasted_iota(jnp.int32,
+                                           (1, block_kv_compute), 1)
+                s = jnp.where(rule.allowed(q_ids, kv_ids, seq_len), s,
+                              mask_value)
+            m_prev, l_prev = m_ref[...], l_ref[...]
+            # A row that sees no key of its tile's first chunks keeps the
+            # mask's value as its maximum there and sums ones: alpha is
+            # zero at its first allowed key and takes them out again.
+            m_next = jnp.maximum(m_prev, s.max(axis=1)[:, None])
+            p = jnp.exp(s - over_lanes(m_next, block_kv_compute))
+            alpha = jnp.exp(m_prev - m_next)
+            m_ref[...] = m_next
+            l_ref[...] = alpha * l_prev + lax.broadcast_in_dim(
+                p.sum(axis=1), l_prev.shape, (0,))
+            acc_ref[...] = over_lanes(alpha, dv) * acc_ref[...] + lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    pl.when(flags & PARTIAL != 0)(lambda: tile(True))
+    pl.when(flags & PARTIAL == 0)(lambda: tile(False))
+
+    @pl.when(flags & LAST != 0)
+    def _():
+        l = l_ref[...]
+        out_ref[...] = (acc_ref[...] * over_lanes(1.0 / l, dv)) \
+            .astype(out_ref.dtype)
+        # One row of the statistics' transposition: the log-sum-exp leaves
+        # as the backward reads it, positions on the lanes.
+        lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+@functools.partial(jax.jit, static_argnames=("rule", "tiles", "interpret"))
+def out_lse(q, k, v, *, rule, tiles, interpret: bool = False):
+    """Attention under ``rule`` and its rows' log-sum-exp: ``q`` (scaled)
+    ``[b, h, s, d]``, ``k`` ``[b, h_kv, s, d]`` and ``v`` ``[b, h_kv, s, dv]``
+    give ``out [b, h, s, dv]`` in ``q``'s dtype and ``lse [b, h, s]`` fp32;
+    ``tiles`` is (queries, keys, keys multiplied at a time).  Jitted: traced
+    once a process and lowered once a program, whatever the number of
+    layers."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    h_kv, dv = k.shape[1], v.shape[3]
+    group = h // h_kv
+    block_q, block_kv, block_kv_compute = tiles
+    if block_kv % block_kv_compute:
+        raise ValueError(f"{block_kv_compute} keys at a time do not divide a "
+                         f"tile of {block_kv}")
     with jax.ensure_compile_time_eval():
-        return splash.make_splash_mha(
-            mask, block_sizes=splash.BlockSizes(
-                **(_TILES_WIDE_FLOAT32 if wide_float32 else _TILES)),
-            head_shards=1, q_seq_shards=1, save_residuals=True,
-            interpret=interpret)
+        table = tuple(jnp.asarray(a) for a in masked_attention_bwd.tile_table(
+            rule, s, block_q, block_kv))
+
+    def of_query(n, i, g, t, q_tile, kv_tile, flags):
+        return n, i * group + g, q_tile[t], 0
+
+    def of_query_row(n, i, g, t, q_tile, kv_tile, flags):
+        return n, i * group + g, 0, q_tile[t]
+
+    def of_key(n, i, g, t, q_tile, kv_tile, flags):
+        return n, i, kv_tile[t], 0
+
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, rule=rule, seq_len=s,
+                          block_kv_compute=block_kv_compute),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, h_kv, group, table[0].shape[0]),
+            in_specs=[pl.BlockSpec((None, None, block_q, d), of_query),
+                      pl.BlockSpec((None, None, block_kv, d), of_key),
+                      pl.BlockSpec((None, None, block_kv, dv), of_key)],
+            out_specs=[pl.BlockSpec((None, None, block_q, dv), of_query),
+                       pl.BlockSpec((None, None, 1, block_q), of_query_row)],
+            scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=_FWD_VMEM_LIMIT),
+        name=FWD_NAME, interpret=interpret,
+    )(*table, q, k, v)
+    return out, lse[:, :, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -165,10 +286,9 @@ def _attend(q, k, v, rule, interpret):
 
 
 def _attend_fwd(q, k, v, rule, interpret):
-    kernel = _kernel(rule, q.shape[2], q.shape[1], interpret,
-                     _wide_float32(q))
     with scope(rule.scope.removeprefix("hvd.")):
-        out, (logsumexp,) = jax.vmap(kernel)(q, k, v)
+        out, logsumexp = out_lse(q, k, v, rule=rule, tiles=_fwd_tiles(q),
+                                 interpret=interpret)
     return out, (q, k, v, out, logsumexp)
 
 
@@ -191,8 +311,8 @@ def attention(q, k, v, rule, *, interpret: bool = False, scale=None):
     (by ``scale`` where one is given: Granite's ``attention_multiplier``);
     ``h_kv`` divides ``h`` and KV head ``j`` serves query heads ``j*h/h_kv``
     to ``(j+1)*h/h_kv - 1``.  Returns ``[b, s, h, dv]``.  Differentiable: the
-    forward is the library's kernel, the backward
-    ``kernels/masked_attention_bwd.py``'s one, both of which take ``q``
+    forward is :func:`out_lse`, the backward
+    ``kernels/masked_attention_bwd.py``'s kernel, both of which take ``q``
     already scaled and so know nothing of the scale."""
     _, s, h, d = q.shape
     if not takes(rule, s, d, v.shape[3]):

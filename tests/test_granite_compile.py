@@ -99,24 +99,19 @@ def test_granites_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     params, aux = jax.eval_shape(config.init, key)
     args = (on(rep, params), on(rep, jax.eval_shape(tx.init, params)),
             on(rep, aux), on(rows, jax.eval_shape(config.make_batch, key)))
-    # The forward kernel's mask tables are made of numpy arrays at trace
-    # time, which a described device cannot hold: built here, outside the
-    # mesh, once (the wrapper caches them).
-    ma._kernel(ma.Causal(), sizes["sequence_length"],
-               sizes["num_attention_heads"], False, False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             *args).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq",
                        "hvd_ssd_scan_fwd", "hvd_ssd_scan_bwd",
                        "hvd_causal_conv_fwd", "hvd_causal_conv_bwd"}, kernels
     for kernel, calls in (("hvd_ssd_scan_fwd", 18), ("hvd_ssd_scan_bwd", 9),
                           ("hvd_causal_conv_fwd", 18),
                           ("hvd_causal_conv_bwd", 9),
-                          ("splash_mha_fwd_residuals", 2),
+                          ("splash_mha_fwd_out_lse", 2),
                           ("splash_mha_dkv_dq", 1)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
     assert "32,8192,8192" not in text            # the scores, any layout

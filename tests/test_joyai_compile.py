@@ -27,7 +27,7 @@ from .test_tpu_compile import (  # noqa: F401
 def test_masked_attention_compiles_at_joyais_widths(one_chip,
                                                     no_compile_cache):
     """One sequence of 8192 positions, 32 heads, keys of 192 over values of
-    128, causal (latent attention, nothing grouped): the library's forward
+    128, causal (latent attention, nothing grouped): the forward
     kernel and the one backward kernel take a lane group and a half as it
     is, and dq and dk come back 192 wide, dv 128."""
     from horovod_tpu.kernels import masked_attention as ma
@@ -44,7 +44,7 @@ def test_masked_attention_compiles_at_joyais_widths(one_chip,
         qk, qk, v).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert "8192,8192" not in text
     assert [tuple(x.shape) for x in compiled.output_shardings
@@ -124,21 +124,16 @@ def test_joyais_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     params, aux = jax.eval_shape(config.init, key)
     args = (on(rep, params), on(rep, jax.eval_shape(tx.init, params)),
             on(rep, aux), on(rows, jax.eval_shape(config.make_batch, key)))
-    # The forward kernel's mask tables are made of numpy arrays at trace
-    # time, which a described device cannot hold: built here, outside the
-    # mesh, once (the wrapper caches them).
-    ma._kernel(ma.Causal(), sizes["sequence_length"],
-               sizes["num_attention_heads"], False, False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             *args).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq",
                        "hvd_rows_to_tokens", "hvd_mla_operands_fwd",
                        "hvd_mla_operands_bwd"}, kernels
-    for kernel in ("splash_mha_fwd_residuals", "hvd_mla_operands_fwd",
+    for kernel in ("splash_mha_fwd_out_lse", "hvd_mla_operands_fwd",
                    "hvd_mla_operands_bwd"):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == 6, kernel
     assert "32,8192,8192" not in text            # the scores, any layout
@@ -188,15 +183,14 @@ def test_joyais_float32_twin_compiles(one_chip, no_compile_cache,
             on_chip(jax.eval_shape(config.make_batch, key)),
             on_chip(jax.eval_shape(
                 lambda: config.reference.zero_bias(sizes))))
-    assert ma._wide_float32(_shape((1, 8, 2, 192), jnp.float32, None))
+    assert ma._fwd_tiles(_shape((1, 8, 2, 192), jnp.float32, None)) \
+        == ma.FWD_TILES_WIDE_FLOAT32 == (512, 512, 512)
     for shape, dtype in (((1, 8, 2, 192), jnp.bfloat16),
                          ((1, 8, 2, 128), jnp.float32)):
-        assert not ma._wide_float32(_shape(shape, dtype, None))
-    assert ma._TILES_WIDE_FLOAT32["block_q"] == 512
+        assert ma._fwd_tiles(_shape(shape, dtype, None)) == ma.FWD_TILES
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = config._logits("program_float32", ()).lower(*args).compile()
     text = compiled.as_text()
-    assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 6
-    assert '\\"block_q\\": 512' in text
+    assert len(re.findall(r"%splash_mha_fwd_out_lse[.\d]* =", text)) == 6
     assert "32,8192,8192" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

@@ -30,17 +30,12 @@ def rel_err(a, b):
 
 @pytest.fixture()
 def tiles_of_128(monkeypatch):
-    """The wrapper's tiles cut to 128, forward and backward (whose keys go
-    64 at a time; the library's forward multiplies no fewer than 128): the
-    modules read them while a call is traced."""
+    """The wrapper's tiles cut to 128, forward and backward, the keys 64 at
+    a time: the modules read them while a call is traced."""
     for module in (ma, bd):
         monkeypatch.setattr(module, "BLOCK", 128)
-    monkeypatch.setattr(ma, "_TILES", dict(block_q=128, block_kv=128,
-                                           block_kv_compute=128))
+    monkeypatch.setattr(ma, "FWD_TILES", (128, 128, 64))
     monkeypatch.setattr(ma, "BWD_TILES", (128, 128, 64))
-    ma._kernel.cache_clear()
-    yield
-    ma._kernel.cache_clear()
 
 
 def brute_force(rule, seq_len, block_q, block_kv):
@@ -144,8 +139,8 @@ def test_gradients_in_interpret_mode_match_the_grouped_einsum(
         rule_name, group, width, tiles_of_128):
     """dq, dk and dv of ``attention(..., interpret=True)`` at four tiles of
     128, two sequences, ``group`` query heads a KV head (two KV heads where
-    the group is 1 or 4): the library's forward kernel and the one backward
-    kernel against ``jax.grad`` of the einsum."""
+    the group is 1 or 4): the forward kernel and the one backward kernel
+    against ``jax.grad`` of the einsum."""
     rule = RULES[rule_name]
     s, h_kv = 512, 1 if group == 7 else 2
     assert ma.takes(rule, s, width)
@@ -186,9 +181,9 @@ def test_gradients_at_heads_of_256(tiles_of_128):
 @pytest.mark.parametrize("rule_name", ["causal", "window_that_cuts_a_tile"])
 def test_gradients_at_keys_of_192_over_values_of_128(rule_name, tiles_of_128):
     """Latent attention's widths (JoyAI-LLM-Flash): q and k 192 wide, v and
-    the output 128, one key head a query head: the library's forward kernel
-    and the one backward kernel in interpret mode against ``jax.grad`` of the
-    einsum at the two widths; the scores are scaled by the keys' width."""
+    the output 128, one key head a query head: the forward kernel and the
+    one backward kernel in interpret mode against ``jax.grad`` of the einsum
+    at the two widths; the scores are scaled by the keys' width."""
     rule, s, h = RULES[rule_name], 512, 3
     assert ma.takes(rule, s, 192, 128)
     assert not ma.takes(rule, s, 128, 192) and not ma.takes(rule, s, 192)
@@ -295,16 +290,3 @@ def test_the_kernel_at_other_tiles_and_in_bf16(tiles):
             for g, e in zip(got, want):
                 assert g.dtype == dtype and g.shape == e.shape
                 assert rel_err(g.astype(jnp.float32), e) < limit
-
-
-def test_the_wrapper_builds_no_backward_kernel_of_the_library():
-    """The library's kernel object holds the forward's mask tables alone:
-    its dq and dkv kernels' tables are never walked."""
-    kernel = ma._kernel(ma.Causal(), 2 * ma.BLOCK, 2, True)
-    assert kernel.dq_mask_info is None and kernel.dkv_mask_info is None
-    assert kernel.kwargs["save_residuals"]
-    assert not kernel.kwargs["block_sizes"].has_backward_blocks
-    assert bwd.NAME.startswith("splash_mha_dkv")
-    import re
-    assert re.match(ma.OP_LINE_NAMES, bwd.NAME)
-    assert not re.match(r"^splash_mha_dq", bwd.NAME)

@@ -63,7 +63,7 @@ def test_gated_delta_compiles_at_qwen3_nexts_shape(one_chip,
 def test_masked_attention_compiles_at_qwen3_nexts_width(one_chip,
                                                         no_compile_cache):
     """One sequence of 8192 positions, 16 query heads on 2 KV heads of 256,
-    causal: the library's forward kernel and the one backward kernel take
+    causal: the forward kernel and the one backward kernel take
     two lane groups a head as they are (the backward keeps a KV head's dk
     and dv, 2 x 8 MiB in fp32 at this width, in fast memory), KV heads not
     repeated, no score square in the program."""
@@ -81,7 +81,7 @@ def test_masked_attention_compiles_at_qwen3_nexts_width(one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
     assert "8192,8192" not in text
@@ -137,18 +137,13 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     params, aux = jax.eval_shape(config.init, key)
     args = (on(rep, params), on(rep, jax.eval_shape(tx.init, params)),
             on(rep, aux), on(rows, jax.eval_shape(config.make_batch, key)))
-    # The forward kernel's mask tables are made of numpy arrays at trace
-    # time, which a described device cannot hold: built here, outside the
-    # mesh, once (the wrapper caches them).
-    ma._kernel(ma.Causal(), sizes["sequence_length"],
-               sizes["num_attention_heads"], False, False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             *args).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq",
                        "hvd_rows_to_tokens", "hvd_gated_delta_fwd",
                        "hvd_gated_delta_bwd", "hvd_causal_conv_fwd",
                        "hvd_causal_conv_bwd"}, kernels
@@ -156,7 +151,7 @@ def test_qwen3_nexts_step_compiles_and_fits_the_chip(topo, no_compile_cache,
                           ("hvd_gated_delta_bwd", 3),
                           ("hvd_causal_conv_fwd", 3),
                           ("hvd_causal_conv_bwd", 3),
-                          ("splash_mha_fwd_residuals", 1),
+                          ("splash_mha_fwd_out_lse", 1),
                           ("splash_mha_dkv_dq", 1)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
     assert "16,8192,8192" not in text            # the scores, any layout
@@ -187,8 +182,8 @@ def test_qwen3_nexts_float32_twin_compiles(one_chip, no_compile_cache,
     """The program's model computed in float32 at the timed sizes: what
     ``logits_float32_rtol`` reads on the chip.  The rule goes through
     ``chunked()`` (the kernels take bf16 alone) and the attention layer
-    through the splash forward kernel with float32 heads of 256 in tiles of
-    512 (``_TILES_WIDE_FLOAT32``, PR 47's finding at 192)."""
+    through the forward kernel with float32 heads of 256 in tiles of 512
+    (``FWD_TILES_WIDE_FLOAT32``, PR 47's finding at 192)."""
     from horovod_tpu.kernels import masked_attention as ma
 
     from .test_qwen3_next_cell import _config_module
@@ -203,14 +198,12 @@ def test_qwen3_nexts_float32_twin_compiles(one_chip, no_compile_cache,
 
     args = (on_chip(jax.eval_shape(config.init, key)[0]),
             on_chip(jax.eval_shape(config.make_batch, key)))
-    assert ma._wide_float32(_shape((1, 8, 2, 256), jnp.float32, None))
-    ma._kernel(ma.Causal(), sizes["sequence_length"],
-               sizes["num_attention_heads"], False, True)
+    assert ma._fwd_tiles(_shape((1, 8, 2, 256), jnp.float32, None)) \
+        == ma.FWD_TILES_WIDE_FLOAT32 == (512, 512, 512)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = config._logits("program_float32", ()).lower(*args).compile()
     text = compiled.as_text()
-    assert len(re.findall(r"%splash_mha_fwd_residuals[.\d]* =", text)) == 1
+    assert len(re.findall(r"%splash_mha_fwd_out_lse[.\d]* =", text)) == 1
     assert "hvd_gated_delta" not in text and "hvd_causal_conv" not in text
-    assert '\\"block_q\\": 512' in text
     assert "16,8192,8192" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

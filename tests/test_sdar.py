@@ -190,24 +190,25 @@ def test_mask_rule_against_a_brute_force_table(half_len, block):
     on_device = bd.block_diffusion_mask(
         jnp.arange(n)[:, None], jnp.arange(n)[None, :], half_len, block)
     np.testing.assert_array_equal(np.asarray(on_device), rule)
-    if block & (block - 1) == 0:
-        np.testing.assert_array_equal(bd._make_mask(half_len, block)[:, :],
-                                      rule)
+    np.testing.assert_array_equal(
+        bd.BlockDiffusion(block).allowed(ids[:, None], ids[None, :], n), rule)
 
 
 def test_kernel_takes_the_cells_shape_and_visits_a_third_of_the_tiles():
     from horovod_tpu.kernels import blockdiff_attention as bd
 
+    from horovod_tpu.kernels import masked_attention as ma
+    from horovod_tpu.kernels import masked_attention_bwd
+
     n = bd.BLOCK
-    assert n == max(bd._TILES.values())
+    assert n == max(ma.FWD_TILES) == max(ma.BWD_TILES)
     assert bd.takes(16384, 128, 4) and bd.takes(2 * n, 128, 1)
     assert not bd.takes(32, 16, 4) and not bd.takes(2 * n, 128, 3)
     assert not bd.takes(3 * n, 128, 4)          # a tile across the halves
     half_len = 8192
-    mask = bd._make_mask(half_len, 4)
     tiles = 2 * half_len // n
-    visited = sum(bool(mask[i * n:(i + 1) * n, j * n:(j + 1) * n].any())
-                  for i in range(tiles) for j in range(tiles))
+    visited = masked_attention_bwd.tile_table(
+        bd.BlockDiffusion(4), 2 * half_len, n, n)[0].size
     half = tiles // 2
     # noisy-noisy diagonal, noisy-clean and clean-clean lower triangles: at
     # tiles of 1024, 80 of 256 for 64 tiles' worth of allowed pairs.
@@ -215,16 +216,22 @@ def test_kernel_takes_the_cells_shape_and_visits_a_third_of_the_tiles():
     assert bd.allowed_pairs(half_len, 4) / n ** 2 < visited < 0.32 * tiles ** 2
 
 
-def test_masks_of_one_shape_and_other_blocks_are_not_equal():
-    """splash attention merges masks that compare equal: equality and hash
-    both go by (half length, block), not by the shape alone."""
+def test_rules_of_one_shape_and_other_blocks_are_not_equal():
+    """The kernels and their table of tiles are cached by the rule: equality
+    and hash both go by the block, and the table by the length besides."""
     from horovod_tpu.kernels import blockdiff_attention as bd
+    from horovod_tpu.kernels import masked_attention_bwd
 
-    a, same, other = (bd._make_mask(32, 4), bd._make_mask(32, 4),
-                      bd._make_mask(32, 8))
+    a, same, other = (bd.BlockDiffusion(4), bd.BlockDiffusion(4),
+                      bd.BlockDiffusion(8))
     assert a == same and hash(a) == hash(same)
-    assert a.shape == other.shape and a != other and hash(a) != hash(other)
-    assert a != bd._make_mask(64, 4)
+    assert a != other and hash(a) != hash(other)
+    table = masked_attention_bwd.tile_table(a, 64, 16, 16)
+    assert masked_attention_bwd.tile_table(same, 64, 16, 16)[0] is table[0]
+    wider = masked_attention_bwd.tile_table(other, 64, 16, 16)
+    assert wider[0] is not table[0]
+    assert masked_attention_bwd.tile_table(a, 128, 16, 16)[0].size \
+        > table[0].size
 
 
 def test_kernel_in_interpret_mode_matches_the_einsum_with_grouped_heads():

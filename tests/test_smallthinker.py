@@ -240,9 +240,9 @@ def test_pairs_allowed_become_gauges_by_kind():
 @pytest.mark.parametrize("seq_len,window", [(16, 1), (16, 5), (16, 16),
                                             (16, 40), (48, 7), (24, None)])
 def test_window_rule_against_a_brute_force_table(seq_len, window):
-    """The rule of kernels/masked_attention.py, the reference's own and the
-    mask the kernel computes (the library's), against a table filled pair by
-    pair; a window of one position sees itself alone and one of the whole
+    """The rule of kernels/masked_attention.py, on numpy and on JAX integers
+    (what the kernels compute in a partial tile), and the reference's own,
+    against a table filled pair by pair; a window of one position sees itself alone and one of the whole
     sequence or more is the causal rule.  No window: the causal rule."""
     from horovod_tpu.kernels import masked_attention as ma
 
@@ -258,7 +258,6 @@ def test_window_rule_against_a_brute_force_table(seq_len, window):
     np.testing.assert_array_equal(
         np.asarray(ref.may_see(ids[:, None], ids[None, :], window or 0)),
         table)
-    np.testing.assert_array_equal(rule.mask(seq_len)[:, :], table)
     on_device = rule.allowed(jnp.arange(seq_len)[:, None],
                              jnp.arange(seq_len)[None, :], seq_len)
     np.testing.assert_array_equal(np.asarray(on_device), table)
@@ -283,10 +282,9 @@ def test_tiles_visited_at_the_cells_shape(rule_name, visited):
     n, s = ma.BLOCK, 16384
     assert ma.takes(rule, s, 128) and not ma.takes(rule, s, 96)
     assert not ma.takes(rule, s + 512, 128)
-    mask = rule.mask(s)
-    tiles = s // n
-    assert sum(bool(mask[i * n:(i + 1) * n, j * n:(j + 1) * n].any())
-               for i in range(tiles) for j in range(tiles)) == visited
+    from horovod_tpu.kernels import masked_attention_bwd
+
+    assert masked_attention_bwd.tile_table(rule, s, n, n)[0].size == visited
     assert rule.allowed_pairs(s) / n ** 2 < visited
     assert ma.Window(4096) == ma.Window(4096) != ma.Window(2048)
     assert hash(ma.Causal()) == hash(ma.Causal())

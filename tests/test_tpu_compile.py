@@ -128,7 +128,7 @@ def test_short_attention_compiles_at_berts_shape(shape, causal, one_chip,
 def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
                                                      no_compile_cache):
     """One sequence as [x_t ; x_0], 16384 positions, 32 query heads on 4 KV
-    heads of 128, blocks of 4: the library's forward kernel and the one
+    heads of 128, blocks of 4: the forward kernel and the one
     backward kernel of ``kernels/masked_attention_bwd.py`` with the tiles
     ``kernels/masked_attention.py`` gives them, and no [2L, 2L] table or
     score square in the program."""
@@ -146,7 +146,7 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert all(re.match(bd.OP_LINE_NAMES, k) for k in kernels)
     assert "16384,16384" not in text
@@ -157,7 +157,7 @@ def test_blockdiff_attention_compiles_at_sdars_shape(one_chip,
 def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
                                                           no_compile_cache):
     """One sequence of 16384 positions, 28 query heads on 4 KV heads of 128,
-    causal and causal inside a window of 4096: the library's forward kernel
+    causal and causal inside a window of 4096: the forward kernel
     and the one backward kernel (no ``splash_mha_dq*``) with the tiles
     ``kernels/masked_attention.py`` gives them, KV heads not repeated, and no
     [s, s] table or score square in the program."""
@@ -174,7 +174,7 @@ def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
     assert "16384,16384" not in text
@@ -243,7 +243,7 @@ def test_causal_conv_compiles_at_the_mixers_shapes(one_chip, no_compile_cache,
 
 def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     """Two sequences of 8192 positions, 32 query heads on 8 KV heads of 64
-    under the causal rule: the library's forward kernel and the one backward
+    under the causal rule: the forward kernel and the one backward
     kernel take half a lane group as it is, KV heads not repeated, no score
     square in the program."""
     from horovod_tpu.kernels import masked_attention as ma
@@ -260,7 +260,7 @@ def test_masked_attention_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert "8192,8192" not in text
     assert "bf16[2,8192,8,64]" in text
@@ -284,7 +284,7 @@ def test_masked_attention_compiles_at_nemotrons_shape(one_chip,
         q, kv, kv).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%(splash\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq"}, \
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq"}, \
         kernels
     assert all(re.match(ma.OP_LINE_NAMES, k) for k in kernels)
     assert "8192,8192" not in text

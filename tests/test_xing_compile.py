@@ -74,23 +74,18 @@ def test_xings_step_compiles_and_fits_the_chip(topo, no_compile_cache,
         == 759_346_190
     args = (on(rep, params), on(rep, jax.eval_shape(tx.init, params)),
             on(rep, aux), on(rows, jax.eval_shape(config.make_batch, key)))
-    # The forward kernel's mask tables are made of numpy arrays at trace
-    # time, which a described device cannot hold: built here, outside the
-    # mesh, once (the wrapper caches them).
-    ma._kernel(ma.Causal(), sizes["sequence_length"],
-               sizes["num_attention_heads"], False, False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             *args).compile()
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
-    assert kernels == {"splash_mha_fwd_residuals", "splash_mha_dkv_dq",
+    assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq",
                        "hvd_mla_operands_fwd", "hvd_mla_operands_bwd",
                        "hvd_rows_to_tokens",
                        "hvd_hyper_connection_post_bwd",
                        "hvd_hyper_connection_pre_bwd"}, kernels
-    for kernel, calls in (("splash_mha_fwd_residuals", 10),
+    for kernel, calls in (("splash_mha_fwd_out_lse", 10),
                           ("splash_mha_dkv_dq", 5),
                           ("hvd_mla_operands_fwd", 10),
                           ("hvd_mla_operands_bwd", 5),
