@@ -258,6 +258,12 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                  "inside a window) and of kind=global (the model's mask; the "
                  "prediction modules' blocks behind the stack among them), "
                  "every query head counted once"),
+    "attn_head_pairs_per_step": (
+        "gauge", "attn_allowed_pairs_per_step with each layer's pairs times "
+                 "that layer's query heads (its kind's where a layer kind "
+                 "states them, LayerKind.heads, else the model's): what the "
+                 "attention kernels' work goes by, per kind=window and "
+                 "kind=global"),
     "gdn_chunks_per_step": (
         "gauge", "chunks of the gated delta rule a step runs, one a value "
                  "head, chunk of positions and Gated DeltaNet layer, from "

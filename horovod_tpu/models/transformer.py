@@ -1,6 +1,6 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H and Xing4.0
-presets.
+LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H, Xing4.0 and
+Laguna presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -61,7 +61,13 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   one stream, or manifold-constrained hyper-connections over ``hc_mult``
   streams (``models/hyper_connections.py``, loaded where a configuration
   asks for it; Xing4.0-29B-A4B ``xing4_0_29b_a4b_config()``, whose latent
-  attention also runs under YaRN, ``yarn_*``).
+  attention also runs under YaRN, ``yarn_*``);
+- a layer's kind also says how many query heads its attention has and by
+  which rotary table they turn (``LayerKind.heads``, ``LayerKind.rotary``, a
+  :class:`Rotary`: theta, the share of a head turned, YaRN's numbers), where
+  those are not the model's: Laguna-S-2.1 ``laguna_s_2_1_config()``, 48
+  global heads under YaRN on half a head to 72 under plain RoPE inside a
+  window of 512, a sigmoid gate a head (``attention_gate="head"``).
 """
 
 from __future__ import annotations
@@ -88,6 +94,30 @@ from ..parallel.moe import (
 )
 
 
+class Rotary(NamedTuple):
+    """A rotary table of a layer kind's own (:attr:`LayerKind.rotary`), in
+    the keys of ``transformers``' ``rope_parameters``: pair ``i`` of the
+    first ``share`` of a head turns by ``rope_theta ** (-2 i / width)`` a
+    position, ``width`` that share's; with ``yarn_factor`` above 1 by YaRN's
+    frequencies over that width (:func:`yarn_inv_freq`, whose range
+    :func:`yarn_correction_range` truncates to whole pairs), cosines and
+    sines times ``attention_factor`` (None: ``0.1 ln yarn_factor + 1``), so
+    that a score's turned part carries the factor's square and the part
+    without positions none; nothing goes into the softmax's scale
+    (``transformers``' ``_compute_yarn_parameters``; latent attention's YaRN
+    is DeepSeek-V3's, ``TransformerConfig.yarn_*``, which scales the scores).
+    The field names are the configuration's, so the YaRN helpers read
+    either."""
+
+    rope_theta: float = 10000.0
+    share: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
 class LayerKind(NamedTuple):
     """What one layer of a pattern differs in.  ``window``: attention is
     causal inside this many positions (a query sees itself and the
@@ -102,12 +132,19 @@ class LayerKind(NamedTuple):
     ``"dense"``, the gated dense FFN of width ``d_ff_dense`` (a sparse
     model's leading dense layers); ``"moe"``; or ``"none"``: the layer is
     its mixer alone, under one norm (Nemotron-H's layers are one or the
-    other)."""
+    other).  ``heads``: the layer's query heads where they are not the
+    configuration's ``num_heads`` (0), on the same KV heads of the same
+    width: the q and ``out`` projections and a gate a head follow it.
+    ``rotary``: the layer's own rotary table (:class:`Rotary`) where it is
+    not the configuration's ``rope_theta`` over ``partial_rotary_factor``
+    of a head (None); plain attention's alone."""
 
     window: int = 0
     rope: bool = True
     mixer: str = "attention"
     ffn: Optional[str] = None
+    heads: int = 0
+    rotary: Optional[Rotary] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,13 +266,15 @@ class TransformerConfig:
     mtp_modules: int = 0
     # Qwen3-Next's parts.  norm_offset: an RMSNorm's scale is ``1 + w``, w
     # zero at the start (the block's norms, ln_f and the per-head QK-norm).
-    # attention_gate: the query projection is twice as wide, a head's second
-    # half a sigmoid gate on that head's output in front of the output
-    # projection.  partial_rotary_factor: the share of a head's width, from
-    # its start, that the rotary positions turn; the rest carries none.
+    # attention_gate: True, the query projection is twice as wide, a head's
+    # second half a sigmoid gate on that head's output in front of the output
+    # projection; "head" (Laguna), a projection ``gate`` of one column a
+    # head, its sigmoid on the head's whole width.  partial_rotary_factor:
+    # the share of a head's width, from its start, that the rotary positions
+    # turn; the rest carries none.
     # shared_expert_gate: the shared expert's output times sigmoid(x . w_g).
     norm_offset: bool = False
-    attention_gate: bool = False
+    attention_gate: Any = False
     partial_rotary_factor: float = 1.0
     shared_expert_gate: bool = False
     # The layers of kind mixer="gated_delta" (models/gated_delta.py):
@@ -297,6 +336,10 @@ class TransformerConfig:
             # A prediction module's block: the stack's last layer's kind.
             i = self.num_layers - 1
         return LayerKind(*self.layer_pattern[i % len(self.layer_pattern)])
+
+    def kind_heads(self, i: int) -> int:
+        """The query heads of block ``i``'s attention."""
+        return self.layer_kind(i).heads or self.num_heads
 
     @property
     def num_blocks(self) -> int:
@@ -536,6 +579,35 @@ def xing4_0_29b_a4b_config(**overrides) -> TransformerConfig:
         yarn_mscale_all_dim=1.0, layer_pattern=pattern), **overrides})
 
 
+def laguna_s_2_1_config(**overrides) -> TransformerConfig:
+    """Laguna-S-2.1 (poolside/Laguna-S-2.1 ``config.json``, ``laguna``): 48
+    layers in periods of four whose attention differs by kind in three
+    things at once: the first of each global, **48 query heads** under YaRN
+    (theta 5e5, factor 128 from 8192 positions, cosines and sines times
+    1.4852) over the first half of a head, the other three **72 query
+    heads** under plain RoPE at 1e4 inside a window of 512; all on 8 KV
+    heads of 128, a sigmoid gate a head on the attention's output; layer 0 a
+    dense SwiGLU of width 12,288, the others 256 experts of width 1024, 10 a
+    token by a softmax renormalised and times 2.5, beside an ungated shared
+    expert of width 1024; RMSNorm at 1e-6, no biases, an untied head."""
+    full = LayerKind(heads=48, rotary=Rotary(
+        rope_theta=5e5, share=0.5, yarn_factor=128.0,
+        yarn_original_max_len=8192, yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+        attention_factor=1.4852030263919618))
+    sliding = LayerKind(window=512, heads=72, rotary=Rotary(rope_theta=1e4))
+    pattern = tuple(
+        (sliding if i % 4 else full)._replace(ffn="dense" if i < 1 else None)
+        for i in range(48))
+    return TransformerConfig(**{**dict(
+        vocab_size=100352, num_layers=48, num_heads=48, num_kv_heads=8,
+        head_width=128, d_model=3072, d_ff=1024, d_ff_dense=12288,
+        d_ff_shared=1024, max_len=1048576, causal=True, norm="rmsnorm",
+        norm_eps=1e-6, positions="rope", use_bias=False,
+        tie_embeddings=False, ffn="moe", num_experts=256,
+        experts_per_token=10, norm_topk_prob=True, routed_scaling_factor=2.5,
+        attention_gate="head", layer_pattern=pattern), **overrides})
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """For tests and the multichip dryrun: tiny shapes, same code paths."""
     return TransformerConfig(**{**dict(
@@ -627,20 +699,33 @@ def yarn_inv_freq(cfg: "TransformerConfig", d: int):
     return plain / cfg.yarn_factor * (1.0 - kept) + plain * kept
 
 
-def _rope(x, theta: float, positions=None, share: float = 1.0):
+def _rope(x, theta: float, positions=None, share: float = 1.0,
+          rotary: Optional[Rotary] = None):
     """Rotary positions on ``[b, s, h, d]``, halves rotated as in
     ``transformers`` (``x*cos + rotate_half(x)*sin``), in fp32.  ``positions``
     ``[s]``: each position's index (default ``0..s-1``).  ``share`` below 1:
     only the first ``share * d`` of a head are turned, as a head of that
-    width, and the rest goes through as it is."""
+    width, and the rest goes through as it is.  ``rotary``: a layer kind's
+    own table in place of ``theta`` and ``share``."""
+    if rotary is not None:
+        theta, share = rotary.rope_theta, rotary.share
     if share != 1.0:
         turned = int(x.shape[3] * share)
+        if rotary is not None:
+            rotary = rotary._replace(share=1.0)
         return jnp.concatenate(
-            [_rope(x[..., :turned], theta, positions), x[..., turned:]],
-            axis=-1)
-    angles = _rope_angles(x.shape[1], x.shape[3], theta, positions)
+            [_rope(x[..., :turned], theta, positions, rotary=rotary),
+             x[..., turned:]], axis=-1)
+    scaled = rotary is not None and rotary.yarn_factor > 1.0
+    angles = _rope_angles(x.shape[1], x.shape[3], theta, positions,
+                          yarn_inv_freq(rotary, x.shape[3]) if scaled
+                          else None)
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if scaled:
+        factor = rotary.attention_factor \
+            or yarn_mscale(rotary.yarn_factor, 1.0)
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
@@ -654,7 +739,7 @@ class Attention(nn.Module):
     def __call__(self, x, positions=None):
         cfg = self.cfg
         b, s, _ = x.shape
-        h, dh = cfg.num_heads, cfg.head_dim
+        h, dh = self.kind.heads or cfg.num_heads, cfg.head_dim
         h_kv = cfg.num_kv_heads or h
         if h % h_kv:
             raise ValueError(f"{h} heads on {h_kv} KV heads")
@@ -668,7 +753,7 @@ class Attention(nn.Module):
                 qkv = _dense(cfg, 3 * h * dh, (None, cfg.model_axis),
                              "qkv")(x)
                 q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, dh), 3, axis=2)
-            elif cfg.attention_gate:
+            elif cfg.attention_gate is True:
                 # A head's columns are its query, then its gate.
                 q, gate = jnp.split(
                     _dense(cfg, 2 * h * dh, (None, cfg.model_axis),
@@ -680,6 +765,14 @@ class Attention(nn.Module):
                 kv = _dense(cfg, 2 * h_kv * dh, (None, cfg.model_axis),
                             "kv")(x)
                 k, v = jnp.split(kv.reshape(b, s, 2 * h_kv, dh), 2, axis=2)
+        if cfg.attention_gate == "head":
+            # One column a head, read from what the queries read.
+            with scope("attn.gate"):
+                gate = _dense(cfg, h, (None, cfg.model_axis),
+                              "gate")(x)[..., None]
+        elif cfg.attention_gate not in (False, True):
+            raise ValueError(f"unknown attention_gate "
+                             f"{cfg.attention_gate!r}")
         with scope("attn.norm"):
             if cfg.qk_norm == "head":
                 # Over each head's width; the heads share the scale.
@@ -696,9 +789,9 @@ class Attention(nn.Module):
                 raise ValueError("rope positions need attention='full'")
             with scope("attn.rope"):
                 q = _rope(q, cfg.rope_theta, positions,
-                          cfg.partial_rotary_factor)
+                          cfg.partial_rotary_factor, self.kind.rotary)
                 k = _rope(k, cfg.rope_theta, positions,
-                          cfg.partial_rotary_factor)
+                          cfg.partial_rotary_factor, self.kind.rotary)
         if (h_kv != h or cfg.block_diffusion or self.kind.window) \
                 and cfg.attention != "full":
             raise ValueError("grouped KV heads, a window and the "
@@ -1085,12 +1178,14 @@ def expert_bias_collection(cfg: TransformerConfig, bias) -> dict:
             for j, i in enumerate(cfg.expert_layers())}
 
 
-def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
+def attention_pairs(cfg: TransformerConfig, seq_len: int,
+                    by_head: bool = False) -> dict:
     """``{"window": n, "global": n}``: the (query, key) pairs the masks of
     one sequence of ``seq_len`` positions allow, summed over the blocks (the
     stack's and the prediction modules') that attend inside a window and
     over those under the model's own mask; from the shapes and the rules
-    alone."""
+    alone.  ``by_head``: each block's pairs times its query heads (its
+    kind's, else the model's)."""
     if cfg.block_diffusion:
         everywhere = BlockDiffusion(cfg.block_diffusion).allowed_pairs(seq_len)
     elif cfg.causal:
@@ -1102,25 +1197,32 @@ def attention_pairs(cfg: TransformerConfig, seq_len: int) -> dict:
         if cfg.layer_kind(i).mixer != "attention":
             continue
         window = cfg.layer_kind(i).window
+        heads = cfg.kind_heads(i) if by_head else 1
         if window:
-            pairs["window"] += masked_attention.Window(window) \
+            pairs["window"] += heads * masked_attention.Window(window) \
                 .allowed_pairs(seq_len)
         else:
-            pairs["global"] += everywhere
+            pairs["global"] += heads * everywhere
     return pairs
 
 
 def publish_attention(cfg: TransformerConfig, seq_len: int,
                       sequences: int = 1) -> dict:
-    """Set the gauge ``attn_allowed_pairs_per_step`` per ``kind=`` for a step
-    of ``sequences`` sequences of ``seq_len`` positions, and return the
-    values; called outside the step, beside ``moe.publish_routing``."""
+    """Set the gauges ``attn_allowed_pairs_per_step`` and
+    ``attn_head_pairs_per_step`` (the pairs times each block's query heads:
+    what the kernels' work goes by where the head count differs by kind) per
+    ``kind=`` for a step of ``sequences`` sequences of ``seq_len``
+    positions, and return the first's values; called outside the step,
+    beside ``moe.publish_routing``."""
     from ..core import metrics
 
-    pairs = {kind: n * sequences
-             for kind, n in attention_pairs(cfg, seq_len).items()}
-    for kind, n in pairs.items():
-        metrics.set_gauge("attn_allowed_pairs_per_step", float(n), kind=kind)
+    pairs, head_pairs = ({kind: n * sequences for kind, n in attention_pairs(
+        cfg, seq_len, by_head).items()} for by_head in (False, True))
+    for kind in pairs:
+        metrics.set_gauge("attn_allowed_pairs_per_step", float(pairs[kind]),
+                          kind=kind)
+        metrics.set_gauge("attn_head_pairs_per_step",
+                          float(head_pairs[kind]), kind=kind)
     return pairs
 
 

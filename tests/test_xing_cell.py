@@ -85,24 +85,27 @@ def test_benchmark_json_names_the_cell_and_its_files():
         bench = json.load(f)
     config = [c for c in bench["configs"] if c["name"] == "xing4.0-29b-a4b"]
     assert len(config) == 1 and config[0]["reduced"] == REDUCED
-    assert bench["configs"][-1] is config[0]         # appended, not inserted
+    # Appended, not inserted (PR 58); PR 63's configuration stands behind.
+    assert bench["configs"][10] is config[0]
     assert os.path.exists(os.path.join(REPO_ROOT, config[0]["file"]))
     for suffix in (".py", "_reference.py"):
         assert os.path.exists(os.path.join(
             REPO_ROOT, config[0]["file"].replace(".json", suffix)))
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][12]
     assert cell == {"name": CELL, "config": "xing4.0-29b-a4b",
                     "traffic": "wfbp", "chips": 1, "why": cell["why"]}
     assert len(cell["why"]) <= 200 and len(config[0]["why"]) <= 200
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
-        assert metrics[name]["workloads"][-1] == CELL, name
-    # Appended together (PR 58); PR 59's two kernel metrics stand behind.
+        assert CELL in metrics[name]["workloads"][-2:], name
+    # Appended together (PR 58); PR 59's two kernel metrics and PR 63's three
+    # stand behind.
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index(NEW_METRICS[0])
     assert names[first:first + 3] == list(NEW_METRICS)
-    assert names[first + 3:] == ["hyper_connection_kernel_calls_step",
-                                 "hyper_connection_kernels_ms_step"]
+    assert names[first + 3:first + 5] == [
+        "hyper_connection_kernel_calls_step",
+        "hyper_connection_kernels_ms_step"]
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"
