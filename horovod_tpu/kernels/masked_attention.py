@@ -21,7 +21,9 @@ The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
 :class:`Window` (causal, and the key inside the last ``size`` positions:
 ``hvd.attn.window``) and ``kernels/blockdiff_attention.py``'s
 ``BlockDiffusion``.  At 16,384 positions and tiles of 1024 a causal layer
-visits 136 of 256 tiles and a window of 4096 visits 70.  :func:`attention` is
+visits 136 of 256 tiles and a window of 4096 visits 70; a window narrower
+than a tile gets tiles of 512 (:func:`_tiles`: at 8192 positions a window of
+512 visits 31 of them, half of each allowed).  :func:`attention` is
 the kernels, :func:`einsum` the same mask through a grouped einsum (off the
 TPU, and for shapes the kernels do not take).  On the device's op line the
 two kernels are :data:`FWD_NAME` and ``splash_mha_dkv_dq``
@@ -95,6 +97,10 @@ _LANES = 128
 # time 23.88 and 23.93, queries of 512 25.12, of 2048 30.53 (fewer, larger
 # tiles hold more forbidden pairs); by codes these read 20.35.
 BWD_TILES = (BLOCK, BLOCK, BLOCK // 2)
+# Both kernels' under a window narrower than BLOCK, where a tile of BLOCK is
+# three quarters forbidden pairs or more: half its sides, and all of a tile's
+# keys multiplied at once (:func:`_tiles` has the sweep; PERF.md §6, PR 64).
+NARROW_WINDOW_TILES = (BLOCK // 2, BLOCK // 2, BLOCK // 2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,12 +153,6 @@ def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
     if head_dim_v not in (None, head_dim):
         return (head_dim, head_dim_v) == (192, 128) and rule.takes(seq_len)
     return (head_dim % 128 == 0 or head_dim == 64) and rule.takes(seq_len)
-
-
-def _fwd_tiles(q):
-    """The forward kernel's tiles for operands like ``q [..., d]``."""
-    wide_float32 = q.dtype.itemsize > 2 and q.shape[-1] > 128
-    return FWD_TILES_WIDE_FLOAT32 if wide_float32 else FWD_TILES
 
 
 def _fwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
@@ -287,7 +287,7 @@ def _attend(q, k, v, rule, interpret):
 
 def _attend_fwd(q, k, v, rule, interpret):
     with scope(rule.scope.removeprefix("hvd.")):
-        out, logsumexp = out_lse(q, k, v, rule=rule, tiles=_fwd_tiles(q),
+        out, logsumexp = out_lse(q, k, v, rule=rule, tiles=_tiles(rule, q)[0],
                                  interpret=interpret)
     return out, (q, k, v, out, logsumexp)
 
@@ -298,7 +298,7 @@ def _attend_bwd(rule, interpret, kept, do):
         di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32),
                         do.astype(jnp.float32))
         return tuple(masked_attention_bwd.dq_dk_dv(
-            q, k, v, logsumexp, di, do, rule=rule, tiles=BWD_TILES,
+            q, k, v, logsumexp, di, do, rule=rule, tiles=_tiles(rule, q)[1],
             interpret=interpret))
 
 
@@ -379,3 +379,30 @@ def einsum_hsd(q, k, v, rule):
                             preferred_element_type=jnp.float32)
         return jnp.einsum("bhqk,bhkd->bhqd",
                           _probabilities(scores, rule, q.dtype), v)
+
+
+# Down here, below the kernels' call sites, whose lines the compile cache's
+# keys of every program with these kernels hold (ROADMAP.md Q1.9 (f)).
+# Measured on a v5e, a kernel alone, ms a call (PERF.md §6, PR 64;
+# ``benchmarks/results/laguna_attention_sweep_pr64.jsonl``): 8192 positions,
+# 72 heads on 8 of 128 inside a window of 512 (Laguna-S-2.1), forward 4.17
+# with FWD_TILES (15 tiles, 26% of their pairs allowed), 2.73 with
+# NARROW_WINDOW_TILES (31 tiles, 50%), 3.44 with their keys 256 at a time,
+# 3.54 at 1024 x 512 x 512 (23, 34%), 3.62 at 256 x 512 x 512, 4.07 at 512 x
+# 256 x 256 (62, 50%), 4.76 at 256 x 256 x 256 (93 of which 31 are full,
+# 67%), 12.27 at 128 x 128 x 128 (310, 80%); backward 8.13 with BWD_TILES,
+# 4.99 with NARROW_WINDOW_TILES, 5.16, 6.61, 6.74, 6.31, 7.77 and 15.29 at
+# those: a grid step costs what it costs however small its tile, so tiles
+# finer than the band lose more than their area saves.  Under windows of 256
+# and 128 the same: forward 2.72 with these against 3.39 at 256 x 256 x 256
+# and 5.37 at 128 x 128 x 128, backward 4.98 against 5.34 and 6.41.
+def _tiles(rule, q):
+    """(the forward kernel's tiles, the backward's) under ``rule`` for
+    operands like ``q [..., d]``: :data:`NARROW_WINDOW_TILES` for both where
+    the rule is a :class:`Window` narrower than a tile (float32 operands
+    wider than a lane group ask for nothing finer), else :data:`FWD_TILES`
+    (those operands: :data:`FWD_TILES_WIDE_FLOAT32`) and :data:`BWD_TILES`."""
+    if isinstance(rule, Window) and rule.size < BLOCK:
+        return NARROW_WINDOW_TILES, NARROW_WINDOW_TILES
+    wide_float32 = q.dtype.itemsize > 2 and q.shape[-1] > 128
+    return FWD_TILES_WIDE_FLOAT32 if wide_float32 else FWD_TILES, BWD_TILES

@@ -183,11 +183,13 @@ def test_joyais_float32_twin_compiles(one_chip, no_compile_cache,
             on_chip(jax.eval_shape(config.make_batch, key)),
             on_chip(jax.eval_shape(
                 lambda: config.reference.zero_bias(sizes))))
-    assert ma._fwd_tiles(_shape((1, 8, 2, 192), jnp.float32, None)) \
+    causal = ma.Causal()
+    assert ma._tiles(causal, _shape((1, 8, 2, 192), jnp.float32, None))[0] \
         == ma.FWD_TILES_WIDE_FLOAT32 == (512, 512, 512)
     for shape, dtype in (((1, 8, 2, 192), jnp.bfloat16),
                          ((1, 8, 2, 128), jnp.float32)):
-        assert ma._fwd_tiles(_shape(shape, dtype, None)) == ma.FWD_TILES
+        assert ma._tiles(causal, _shape(shape, dtype, None))[0] \
+            == ma.FWD_TILES
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = config._logits("program_float32", ()).lower(*args).compile()
     text = compiled.as_text()

@@ -2,7 +2,8 @@
 described, not attached (``tests/test_tpu_compile.py`` says how and why):
 every block recomputed, inside the memory the file states, with no
 recomputation of the compiler's own, through the two attention kernels at
-groups of 6 and 9 query heads a KV head.  Nothing runs, so nothing here is a
+groups of 6 and 9 query heads a KV head, the sliding layers' calls over the
+tiles a window of 512 gets (PR 64).  Nothing runs, so nothing here is a
 result or a time.
 
 In a file of its own, so that the minute the step takes lies on another test
@@ -10,6 +11,7 @@ worker than ``tests/test_tpu_compile.py``'s and ``tests/test_laguna.py``'s;
 the topology is described inside a fixture, never while a module is imported.
 """
 
+import collections
 import json
 import os
 import re
@@ -84,6 +86,26 @@ def test_lagunas_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     for kernel, calls in (("splash_mha_fwd_out_lse", 10),
                           ("splash_mha_dkv_dq", 5)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
+    # The sliding layers' calls walk the table of the tiles a window of 512
+    # gets, 31 of 512 x 512 (the table's length is the kernels' last grid
+    # dimension and the length of the three scalar-prefetch operands), the
+    # global layers' the 36 causal tiles of 1024 x 1024 they always walked.
+    from horovod_tpu.kernels import masked_attention as ma
+    from horovod_tpu.kernels import masked_attention_bwd as bwd
+
+    like = jax.ShapeDtypeStruct((1, 72, 8192, 128), jnp.bfloat16)
+    walked = {heads: {str(bwd.tile_table(rule, 8192, *tiles[:2])[0].size)
+                      for tiles in ma._tiles(rule, like)}   # both kernels'
+              for heads, rule in (("72", ma.Window(512)), ("48", ma.Causal()))}
+    assert walked == {"72": {"31"}, "48": {"36"}}
+    calls = collections.Counter(re.findall(
+        r"%(splash_mha\w+?)[.\d]* = [^\n]*?operand_layout_constraints="
+        r"\{s32\[(\d+)\]\{0\}, s32\[\2\]\{0\}, s32\[\2\]\{0\}, "
+        r"bf16\[1,(\d+),8192,128\]", text))
+    assert calls == {("splash_mha_fwd_out_lse", "31", "72"): 6,
+                     ("splash_mha_dkv_dq", "31", "72"): 3,
+                     ("splash_mha_fwd_out_lse", "36", "48"): 4,
+                     ("splash_mha_dkv_dq", "36", "48"): 2}, calls
     # Both head counts reach the kernels, KV heads never repeated.
     assert re.findall(r"bf16\[1,72,8192,128\]", text)
     assert re.findall(r"bf16\[1,48,8192,128\]", text)

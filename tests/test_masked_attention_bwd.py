@@ -97,19 +97,34 @@ def test_the_block_rule_by_codes_is_the_three_clause_rule(half_len, block):
     assert got.sum() == rule.allowed_pairs(n)
 
 
-@pytest.mark.parametrize("rule,seq_len,visited,partial", [
-    (bd.BlockDiffusion(4), 16384, 80, 24), (ma.Causal(), 16384, 136, 16),
-    (ma.Window(4096), 16384, 70, 28), (ma.Causal(), 8192, 36, 8)])
-def test_tiles_visited_at_the_cells_shapes(rule, seq_len, visited, partial):
-    """SDAR's, SmallThinker's two, and LFM2's and Nemotron's: the counts the
-    library's kernels visit too, of which only the partial ones compute a
-    mask; the table is made once a rule and shape."""
-    q_tile, kv_tile, flags = bwd.tile_table(rule, seq_len, ma.BLOCK, ma.BLOCK)
+@pytest.mark.parametrize("rule,seq_len,blocks,visited,partial", [
+    (bd.BlockDiffusion(4), 16384, None, 80, 24),
+    (ma.Causal(), 16384, None, 136, 16),
+    (ma.Window(4096), 16384, None, 70, 28), (ma.Causal(), 8192, None, 36, 8),
+    (ma.Window(512), 8192, None, 31, 31),
+    (ma.Window(512), 8192, (1024, 1024), 15, 15),
+    (ma.Window(512), 8192, (256, 256), 93, 62)])
+def test_tiles_visited_at_the_cells_shapes(rule, seq_len, blocks, visited,
+                                           partial):
+    """SDAR's, SmallThinker's two, LFM2's and Nemotron's, and Laguna's
+    sliding layers', at the tiles the wrapper gives both kernels (``blocks``
+    None) or at the ones named: the counts the library's kernels visit too,
+    of which only the partial ones compute a mask; the table is made once a
+    rule and shape.  A window of 512 in tiles of 1024 visits 15 tiles for
+    3.875 tiles' worth of pairs (25.8% allowed), in the wrapper's tiles of
+    512 31 quarter-tiles (50.0%), in tiles of 256 93 sixteenths, 31 of them
+    full (66.7%)."""
+    if blocks is None:
+        fwd, back = ma._tiles(rule, jax.ShapeDtypeStruct((1, 8, seq_len, 128),
+                                                         jnp.bfloat16))
+        assert fwd[:2] == back[:2]              # one table of tiles
+        blocks = back[:2]
+    q_tile, kv_tile, flags = bwd.tile_table(rule, seq_len, *blocks)
     assert q_tile.size == visited
     assert int((flags & bwd.PARTIAL != 0).sum()) == partial
-    assert np.unique(q_tile).size == seq_len // ma.BLOCK
-    assert rule.allowed_pairs(seq_len) / ma.BLOCK ** 2 < visited
-    assert bwd.tile_table(rule, seq_len, ma.BLOCK, ma.BLOCK)[0] is q_tile
+    assert np.unique(q_tile).size == seq_len // blocks[0]
+    assert rule.allowed_pairs(seq_len) / (blocks[0] * blocks[1]) < visited
+    assert bwd.tile_table(rule, seq_len, *blocks)[0] is q_tile
 
 
 def test_a_rule_that_leaves_a_query_tile_no_key_and_tiles_that_do_not_divide():

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 import horovod_tpu
+from horovod_tpu.kernels import blockdiff_attention as bd
 from horovod_tpu.kernels import masked_attention as ma
 from horovod_tpu.kernels import masked_attention_bwd as bwd
 from .test_masked_attention_bwd import (  # noqa: F401 — tiles_of_128 is a fixture
@@ -149,20 +150,50 @@ def test_grad_through_the_wrapper_matches_the_einsums(rule_name,
         assert rel_err(g, e) < 1e-5
 
 
-def test_the_tiles_follow_the_operands_dtype_and_width():
-    """Float32 operands wider than a lane group take tiles of 512 (the
-    float32 twins of latent attention, 192 wide, and of Qwen3-Next's heads of
-    256); everything else the backward's tiles with the keys 256 at a
-    time."""
-    like = lambda d, dtype: jax.ShapeDtypeStruct((1, 2, 8, d), dtype)  # noqa: E731
-    assert ma.FWD_TILES == (1024, 1024, 256)
-    assert ma.FWD_TILES[:2] == ma.BWD_TILES[:2]     # one table of tiles
+TODAY = ((1024, 1024, 256), (1024, 1024, 512))
+NARROW = ((512, 512, 512), (512, 512, 512))
+
+
+@pytest.mark.parametrize("rule,d,dtype,fwd_and_bwd", [
+    (ma.Causal(), 128, jnp.bfloat16, TODAY),
+    (ma.Causal(), 64, jnp.float32, TODAY),
+    (bd.BlockDiffusion(4), 128, jnp.bfloat16, TODAY),
+    (ma.Window(4096), 128, jnp.bfloat16, TODAY),
+    (ma.Window(1024), 128, jnp.bfloat16, TODAY),
+    (ma.Causal(), 192, jnp.float32, ((512, 512, 512), TODAY[1])),
+    (ma.Causal(), 256, jnp.float32, ((512, 512, 512), TODAY[1])),
+    (ma.Window(1024), 256, jnp.float32, ((512, 512, 512), TODAY[1])),
+    (ma.Causal(), 256, jnp.bfloat16, TODAY),
+    (ma.Window(512), 128, jnp.bfloat16, NARROW),
+    (ma.Window(512), 128, jnp.float32, NARROW),
+    (ma.Window(512), 256, jnp.float32, NARROW),
+    (ma.Window(1023), 128, jnp.bfloat16, NARROW),
+    (ma.Window(300), 64, jnp.bfloat16, NARROW),
+    (ma.Window(1), 128, jnp.bfloat16, NARROW)])
+def test_the_tiles_follow_the_rule_and_the_operands(rule, d, dtype,
+                                                    fwd_and_bwd):
+    """The one function that chooses both kernels' tiles.  Every rule but a
+    window narrower than a tile gets :data:`TODAY`'s (float32 operands wider
+    than a lane group go forward in tiles of 512: the float32 twins of
+    latent attention, 192 wide, and of Qwen3-Next's heads of 256), a window
+    of exactly a tile too; a narrower window gets tiles of 512 in both
+    kernels with a tile's keys multiplied at once, whatever its width (the
+    sweep of PR 64 read finer tiles slower even where they fit the band
+    better), and wide float32 under such a window the same, which is the
+    finer of its two in every dimension."""
+    assert (ma.FWD_TILES, ma.BWD_TILES) == TODAY
     assert ma.FWD_TILES_WIDE_FLOAT32 == (512, 512, 512)
-    for d in (192, 256):
-        assert ma._fwd_tiles(like(d, jnp.float32)) == (512, 512, 512)
-        assert ma._fwd_tiles(like(d, jnp.bfloat16)) == ma.FWD_TILES
-    for d in (64, 128):
-        assert ma._fwd_tiles(like(d, jnp.float32)) == ma.FWD_TILES
+    assert (ma.NARROW_WINDOW_TILES,) * 2 == NARROW
+    assert all(n <= wide for n, wide in zip(ma.NARROW_WINDOW_TILES,
+                                            ma.FWD_TILES_WIDE_FLOAT32))
+    got = ma._tiles(rule, jax.ShapeDtypeStruct((1, 2, 8, d), dtype))
+    assert got == fwd_and_bwd
+    for tiles in got:
+        assert tiles[1] % tiles[2] == 0 and ma.BLOCK % tiles[0] == 0 \
+            and ma.BLOCK % tiles[1] == 0    # whatever takes() takes, they cut
+
+
+def test_tiles_that_do_not_divide_are_refused():
     q = jnp.zeros((1, 1, 128, 128))
     with pytest.raises(ValueError, match="at a time"):
         ma.out_lse(q, q, q, rule=ma.Causal(), tiles=(64, 64, 48),
@@ -170,6 +201,44 @@ def test_the_tiles_follow_the_operands_dtype_and_width():
     with pytest.raises(ValueError, match="do not divide"):
         ma.out_lse(q, q, q, rule=ma.Causal(), tiles=(48, 64, 64),
                    interpret=True)
+
+
+@pytest.mark.parametrize("planted", [None, "window_less_one"])
+def test_a_window_narrower_than_a_tile_through_the_wrapper(planted):
+    """Laguna's sliding layer cut to 2048 positions: nine query heads on one
+    KV head inside a window of 512, the path ``_attend`` takes at the tiles
+    ``_tiles`` gives it (seven tiles of 512 x 512, every one partial, where
+    tiles of 1024 hold three): ``out``, the log-sum-exp, dq, dk and dv
+    against the grouped einsum's in float32; and the einsum under a window
+    of 511, which the same limit has to refuse in each of the five."""
+    rule, s, limit = ma.Window(512), 2048, 1e-5
+    q, k, v = operands(9, 1, s, 9, 1, 128)
+    w = jax.random.normal(jax.random.PRNGKey(10), q.shape)
+    tiles = ma._tiles(rule, q)
+    assert tiles == NARROW
+    assert bwd.tile_table(rule, s, 512, 512)[0].size == 7
+    wrong = ma.Window(rule.size - 1) if planted else rule
+    with jax.default_matmul_precision("highest"):
+        want_out, want_lse = plain(q, k, v, rule)
+        want = gradients(lambda *qkv: ma.einsum(*qkv, rule), q, k, v, w)
+        if planted:
+            out, lse = plain(q, k, v, wrong)
+            got = gradients(lambda *qkv: ma.einsum(*qkv, wrong), q, k, v, w)
+        else:
+            out, (*_, lse) = ma._attend_fwd(hsd(q * 128 ** -0.5), hsd(k),
+                                            hsd(v), rule, True)
+            grad = jax.grad(lambda *qkv: jnp.sum(ma.attention(
+                *qkv, rule, interpret=True) * w), argnums=(0, 1, 2))
+            text = str(jax.make_jaxpr(grad)(q, k, v))
+            # Both kernels' last grid dimension is the table's length.
+            assert text.count("grid=(1, 1, 9, 7)") == 2
+            got = grad(q, k, v)
+    errors = [rel_err(out, want_out), rel_err(lse, want_lse)] \
+        + [rel_err(g, e) for g, e in zip(got, want)]
+    if planted:
+        assert min(errors) > limit, errors
+    else:
+        assert max(errors) < limit, errors
 
 
 def test_the_wrapper_builds_no_kernel_of_the_library():

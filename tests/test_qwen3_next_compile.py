@@ -198,7 +198,8 @@ def test_qwen3_nexts_float32_twin_compiles(one_chip, no_compile_cache,
 
     args = (on_chip(jax.eval_shape(config.init, key)[0]),
             on_chip(jax.eval_shape(config.make_batch, key)))
-    assert ma._fwd_tiles(_shape((1, 8, 2, 256), jnp.float32, None)) \
+    assert ma._tiles(ma.Causal(), _shape((1, 8, 2, 256), jnp.float32,
+                                         None))[0] \
         == ma.FWD_TILES_WIDE_FLOAT32 == (512, 512, 512)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = config._logits("program_float32", ()).lower(*args).compile()

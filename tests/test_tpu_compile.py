@@ -182,6 +182,35 @@ def test_masked_attention_compiles_at_smallthinkers_shape(rule_name, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def test_masked_attention_compiles_at_lagunas_sliding_shape(one_chip,
+                                                            no_compile_cache):
+    """One sequence of 8192 positions, 72 query heads on 8 KV heads of 128
+    inside a window of 512: both kernels over the 31 tiles of 512 x 512 that
+    ``masked_attention._tiles`` gives a window narrower than a tile (the
+    table's length is the length of the scalar-prefetch operands), where
+    tiles of 1024 made 15; KV heads not repeated, no score square."""
+    from horovod_tpu.kernels import masked_attention as ma
+
+    rule = ma.Window(512)
+    q = _shape((1, 8192, 72, 128), jnp.bfloat16, one_chip)
+    kv = _shape((1, 8192, 8, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ma.attention(q, k, v, rule).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    tables = dict(re.findall(
+        r"%(splash\w*?)[.\d]* = [^\n]*?operand_layout_constraints="
+        r"\{s32\[(\d+)\]", text))
+    assert tables == {"splash_mha_fwd_out_lse": "31",
+                      "splash_mha_dkv_dq": "31"}, tables
+    assert "8192,8192" not in text
+    assert "72,8192,128" in text and "bf16[1,8192,72,128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_short_conv_compiles_at_lfm2s_shape(one_chip, no_compile_cache):
     """Two sequences of 8192 positions at width 2048, 3 taps: the forward
     and the backward kernel of ``kernels/short_conv.py``, the residual
