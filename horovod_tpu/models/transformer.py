@@ -67,7 +67,13 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   :class:`Rotary`: theta, the share of a head turned, YaRN's numbers), where
   those are not the model's: Laguna-S-2.1 ``laguna_s_2_1_config()``, 48
   global heads under YaRN on half a head to 72 under plain RoPE inside a
-  window of 512, a sigmoid gate a head (``attention_gate="head"``).
+  window of 512, a sigmoid gate a head (``attention_gate="head"``);
+- on a TPU the rotary positions of bf16 heads of 128 under a rule of
+  ``kernels/masked_attention.py`` are one kernel a direction over q and k
+  (``kernels/rope_operands.py``: turned, q scaled, written where the
+  attention kernels read them; chosen by ``rope_operands.takes`` from the
+  backend, the dtype, the head's width and the rule, never by a model);
+  everything else is :func:`_rope` on the rows.
 """
 
 from __future__ import annotations
@@ -82,7 +88,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.timeline import scope
-from ..kernels import masked_attention, short_attention, short_conv
+from ..kernels import (
+    masked_attention,
+    rope_operands,
+    short_attention,
+    short_conv,
+)
 from ..kernels.blockdiff_attention import BlockDiffusion
 from ..parallel.mesh import AXIS_MODEL, AXIS_SEQ
 from ..parallel.moe import (
@@ -731,6 +742,28 @@ def _rope(x, theta: float, positions=None, share: float = 1.0,
                            axis=-1).astype(x.dtype)
 
 
+def _turned_width(d: int, share: float, rotary: Optional[Rotary]) -> int:
+    """How many of a head's ``d`` columns :func:`_rope` turns."""
+    return int(d * (share if rotary is None else rotary.share))
+
+
+def _rope_tables(s: int, d: int, theta: float, positions=None,
+                 share: float = 1.0, rotary: Optional[Rotary] = None):
+    """:func:`_rope`'s cosines and sines over ``s`` positions of heads of
+    ``d`` as ``kernels/rope_operands.py`` takes them (``[s, d]`` each, 1 and
+    0 over the columns a share below 1 leaves alone), and how far apart the
+    two columns of a pair lie."""
+    turned = _turned_width(d, share, rotary)
+    if rotary is not None:
+        theta = rotary.rope_theta
+    scaled = rotary is not None and rotary.yarn_factor > 1.0
+    angles = _rope_angles(s, turned, theta, positions,
+                          yarn_inv_freq(rotary, turned) if scaled else None)
+    factor = 1.0 if not scaled else \
+        rotary.attention_factor or yarn_mscale(rotary.yarn_factor, 1.0)
+    return *rope_operands.tables(angles, d, factor), turned // 2
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     kind: LayerKind = LayerKind()
@@ -784,20 +817,50 @@ class Attention(nn.Module):
                 q = _norm(cfg, "q_norm")(flat(q)).astype(cfg.dtype)
                 k = _norm(cfg, "k_norm")(flat(k)).astype(cfg.dtype)
                 q, k = q.reshape(b, s, h, dh), k.reshape(b, s, h_kv, dh)
+        in_kernels_layout = False
         if cfg.positions == "rope" and self.kind.rope:
             if cfg.attention != "full":
                 raise ValueError("rope positions need attention='full'")
-            with scope("attn.rope"):
-                q = _rope(q, cfg.rope_theta, positions,
-                          cfg.partial_rotary_factor, self.kind.rotary)
-                k = _rope(k, cfg.rope_theta, positions,
-                          cfg.partial_rotary_factor, self.kind.rotary)
+            rotary = (cfg.rope_theta, positions, cfg.partial_rotary_factor,
+                      self.kind.rotary)
+            rule = _rule(cfg.causal, cfg.block_diffusion, self.kind.window,
+                         h_kv != h)
+            turned = _turned_width(dh, cfg.partial_rotary_factor,
+                                   self.kind.rotary)
+            in_kernels_layout = rope_operands.takes(rule, s, dh, turned,
+                                                    cfg.dtype)
+            if not in_kernels_layout:
+                with scope("attn.rope"):
+                    q, k = _rope(q, *rotary), _rope(k, *rotary)
         if (h_kv != h or cfg.block_diffusion or self.kind.window) \
                 and cfg.attention != "full":
             raise ValueError("grouped KV heads, a window and the "
                              "block-diffusion mask need attention='full'")
 
-        if cfg.attention == "ring":
+        if in_kernels_layout:
+            # One pass turns q and k, scales q and writes both where the
+            # attention kernels read them; v's copy and the output's stay.
+            scale = dh ** -0.5 if cfg.attention_multiplier is None \
+                else cfg.attention_multiplier
+            if cfg.qk_norm:
+                # The kernel reads q and k row-major.  A norm's fp32 rows
+                # leave the products with the positions minor, and without
+                # this XLA turns them (two fp32 copies of q a layer) in front
+                # of the norm's last product and keeps them for the backward
+                # (SDAR-30B-A3B: 1.5 GiB and 10 ms a step); with it the one
+                # bf16 result is turned.
+                q, k = lax.optimization_barrier((q, k))
+            with scope("attn.rope"):
+                *tables, half = _rope_tables(s, dh, *rotary)
+                q, k = rope_operands.operands(
+                    q.reshape(b, s, h * dh), k.reshape(b, s, h_kv * dh),
+                    *tables, scale, half=half)
+            with scope("attn.layout"):
+                v = v.transpose(0, 2, 1, 3)
+            out = masked_attention.attention_hsd(q, k, v, rule)
+            with scope("attn.layout"):
+                out = out.transpose(0, 2, 1, 3)
+        elif cfg.attention == "ring":
             from ..parallel.ring_attention import ring_attention
 
             with scope("attn.ring"):
@@ -864,6 +927,23 @@ def _flash_attention(q, k, v, causal: bool, dh: int, scale=None):
         return o.transpose(0, 2, 1, 3)
 
 
+def _rule(causal: bool, block_diffusion: int, window: int, grouped: bool):
+    """The mask as a rule of ``kernels/masked_attention.py``: the
+    block-diffusion rule with ``block_diffusion``, a block length, in place
+    of ``causal``; causal inside ``window`` positions; plain causal where KV
+    heads are ``grouped``; else None (one KV head a query head under
+    ``causal``, no mask)."""
+    if block_diffusion:
+        return BlockDiffusion(block_diffusion)
+    if window:
+        if not causal:
+            raise ValueError("a window is causal: it needs causal=True")
+        return masked_attention.Window(window)
+    if causal and grouped:
+        return masked_attention.Causal()
+    return None
+
+
 def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
                           block_diffusion: int = 0, window: int = 0,
                           scale=None):
@@ -883,15 +963,7 @@ def _scaled_dot_attention(q, k, v, causal: bool, dh: int,
     ``kernels/short_attention.py`` at the lengths it takes; else the einsum."""
     s = q.shape[1]
     grouped = k.shape[2] != q.shape[2]
-    rule = None
-    if block_diffusion:
-        rule = BlockDiffusion(block_diffusion)
-    elif window:
-        if not causal:
-            raise ValueError("a window is causal: it needs causal=True")
-        rule = masked_attention.Window(window)
-    elif causal and grouped:
-        rule = masked_attention.Causal()
+    rule = _rule(causal, block_diffusion, window, grouped)
     if rule is not None:
         if jax.default_backend() == "tpu" \
                 and masked_attention.takes(rule, s, dh):

@@ -97,7 +97,10 @@ def test_benchmark_json_names_the_cell_and_its_files():
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
         assert metrics[name]["workloads"][-1] == CELL, name
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW_METRICS)
+    # Appended together (PR 63); PR 65's count of the rotary kernels' calls
+    # stands behind.
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-4:] == list(NEW_METRICS) + ["rope_operands_calls_step"]
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"

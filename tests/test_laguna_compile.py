@@ -82,9 +82,14 @@ def test_lagunas_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     text = compiled.as_text()
     kernels = set(re.findall(r"%((?:splash|hvd)\w*?)[.\d]* =", text))
     assert kernels == {"splash_mha_fwd_out_lse", "splash_mha_dkv_dq",
+                       "hvd_rope_operands_fwd", "hvd_rope_operands_bwd",
                        "hvd_rows_to_tokens"}, kernels
+    # q and k are turned, scaled and laid out by one kernel a direction in
+    # front of each attention kernel (``kernels/rope_operands.py``, PR 65).
     for kernel, calls in (("splash_mha_fwd_out_lse", 10),
-                          ("splash_mha_dkv_dq", 5)):
+                          ("splash_mha_dkv_dq", 5),
+                          ("hvd_rope_operands_fwd", 10),
+                          ("hvd_rope_operands_bwd", 5)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
     # The sliding layers' calls walk the table of the tiles a window of 512
     # gets, 31 of 512 x 512 (the table's length is the kernels' last grid
