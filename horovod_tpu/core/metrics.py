@@ -268,6 +268,10 @@ CATALOG: Dict[str, Tuple[str, str]] = {
         "gauge", "chunks of the gated delta rule a step runs, one a value "
                  "head, chunk of positions and Gated DeltaNet layer, from "
                  "the shapes (models/transformer.py::publish_gated_delta)"),
+    "kda_chunks_per_step": (
+        "gauge", "chunks of Kimi Delta Attention's rule a step runs, one a "
+                 "head, chunk of positions and KDA layer, from the shapes "
+                 "(models/transformer.py::publish_kda)"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

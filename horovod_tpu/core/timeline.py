@@ -544,6 +544,7 @@ SCOPES = (
     "resnet.stem", "resnet.stage1", "resnet.stage2", "resnet.stage3",
     "resnet.stage4", "resnet.head", "bn",
     "hc.coeff", "hc.sinkhorn", "hc.pre", "hc.post",
+    "kda.proj", "kda.conv", "kda.gate", "kda.rule", "kda.norm", "kda.out",
 )
 
 

@@ -197,14 +197,14 @@ class LatentAttention(nn.Module):
         h, latent = cfg.num_heads, cfg.kv_lora_rank
         nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-        if not (cfg.causal and cfg.attention == "full" and cfg.q_lora_rank
+        if not (cfg.causal and cfg.attention == "full"
                 and cfg.positions == "rope" and self.kind.rope) \
                 or self.kind.window or cfg.block_diffusion or cfg.qk_norm \
                 or cfg.num_kv_heads not in (None, h):
             raise ValueError(
-                "latent attention is built causal, attention='full', with a "
-                "query latent and rotary positions, and without a window, "
-                "the block-diffusion mask, QK-norm or grouped KV heads")
+                "latent attention is built causal, attention='full', with "
+                "rotary positions, and without a window, the "
+                "block-diffusion mask, QK-norm or grouped KV heads")
         def pairs_first(w):
             """The interleave, on a projection's rotary columns (``kv_a``'s
             last ``rope``, ``q_b``'s a head at a time) and not on the rows;
@@ -215,16 +215,16 @@ class LatentAttention(nn.Module):
                 return _rotary_columns_pairs_first(w, rope)
 
         with scope("attn.latent"):
-            c_q = _dense(cfg, cfg.q_lora_rank, (None, None), "q_a")(x)
-            c_q = _norm(cfg, "q_a_norm")(c_q).astype(cfg.dtype)
+            c_q, q_name = _query_rows(cfg, x)
+            c_q = c_q.astype(cfg.dtype)
             down = _product(x.astype(cfg.dtype), _columns(pairs_first, _dense(
                 cfg, latent + rope, (None, None), "kv_a", cls=_Weights)(d)))
             c_kv = _norm(cfg, "kv_a_norm")(down[..., :latent]) \
                 .astype(cfg.dtype)
         with scope("attn.proj"):
             w_uq = _columns(pairs_first, _by_head(_dense(
-                cfg, h * (nope + rope), (None, cfg.model_axis), "q_b",
-                cls=_Weights)(cfg.q_lora_rank), h))
+                cfg, h * (nope + rope), (None, cfg.model_axis), q_name,
+                cls=_Weights)(c_q.shape[-1]), h))
             w_ukv = _by_head(_dense(
                 cfg, h * (nope + dv), (None, cfg.model_axis), "kv_b",
                 cls=_Weights)(latent), h)
@@ -252,6 +252,8 @@ class LatentAttention(nn.Module):
             out = masked_attention.attention_hsd(q, k, v, rule)
         else:
             out = masked_attention.einsum_hsd(q, k, v, rule)
+        if cfg.attention_gate:
+            out = _gated(cfg, x, out)
         with scope("attn.proj"):
             w_o = _dense(cfg, cfg.d_model, (cfg.model_axis, None), "out",
                          cls=_Weights)(h * dv)
@@ -282,3 +284,27 @@ class PredictionModule(nn.Module):
     def readout_norm(self, x):
         with scope("norm"):
             return self.norm(x)
+
+
+def _query_rows(cfg: TransformerConfig, x):
+    """(what the query's up-projection reads, that projection's name): the
+    query latent under its norm and ``"q_b"``; or, with ``q_lora_rank`` 0,
+    the stream itself and ``"q"`` (Ling-3.0-flash: no query latent, no
+    query norm), the rest of the layer as it is."""
+    if not cfg.q_lora_rank:
+        return x, "q"
+    c_q = _dense(cfg, cfg.q_lora_rank, (None, None), "q_a")(x)
+    return _norm(cfg, "q_a_norm")(c_q), "q_b"
+
+
+def _gated(cfg: TransformerConfig, x, out):
+    """The attention's output ``[b, h, s, dv]`` times a sigmoid gate a head
+    (``attention_gate="head"``: a projection ``gate`` of one column a head,
+    read from what the queries read, as :class:`Attention` has it)."""
+    if cfg.attention_gate != "head":
+        raise ValueError("latent attention's gate is one column a head: "
+                         f"attention_gate={cfg.attention_gate!r}")
+    with scope("attn.gate"):
+        gate = _dense(cfg, out.shape[1], (None, cfg.model_axis), "gate")(x)
+        gate = jax.nn.sigmoid(gate.astype(jnp.float32)).transpose(0, 2, 1)
+        return (out * gate[..., None]).astype(out.dtype)

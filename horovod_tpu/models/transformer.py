@@ -1,6 +1,6 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
-LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H, Xing4.0 and
-Laguna presets.
+LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H, Xing4.0,
+Laguna and Ling presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -68,6 +68,11 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   those are not the model's: Laguna-S-2.1 ``laguna_s_2_1_config()``, 48
   global heads under YaRN on half a head to 72 under plain RoPE inside a
   window of 512, a sigmoid gate a head (``attention_gate="head"``);
+- Ling-3.0-flash ``ling_3_0_flash_config()``: Kimi Delta Attention mixers
+  (``models/kda.py`` over ``kernels/kda.py``, a delta rule whose decay is a
+  vector a key channel) five to one layer of latent attention without a
+  query latent (``q_lora_rank`` 0) under a gate a head, and experts chosen
+  inside the ``moe_groups_kept`` best of ``moe_groups`` groups;
 - on a TPU the rotary positions of bf16 heads of 128 under a rule of
   ``kernels/masked_attention.py`` are one kernel a direction over q and k
   (``kernels/rope_operands.py``: turned, q scaled, written where the
@@ -138,7 +143,8 @@ class LayerKind(NamedTuple):
     the tokens, ``"attention"``, ``"conv"`` (:class:`ShortConv`, which
     takes neither window nor positions), ``"mamba2"``
     (``models/mamba2.py``, nor that), ``"gated_delta"``
-    (``models/gated_delta.py``, nor that) or ``"none"``: the layer is its FFN
+    (``models/gated_delta.py``, nor that), ``"kda"`` (``models/kda.py``, nor
+    that) or ``"none"``: the layer is its FFN
     alone, under one norm.  ``ffn``: None, the configuration's ``ffn``;
     ``"dense"``, the gated dense FFN of width ``d_ff_dense`` (a sparse
     model's leading dense layers); ``"moe"``; or ``"none"``: the layer is
@@ -219,7 +225,8 @@ class TransformerConfig:
     layer_pattern: Optional[Tuple[LayerKind, ...]] = None
     # The layers of kind ffn="dense": W_2(silu(W_1 x) * W_3 x) of this width.
     d_ff_dense: int = 0
-    # The layers of kind mixer="conv": taps of the depthwise filter.
+    # The layers of kind mixer="conv", and "kda"'s convolution over q, k and
+    # v: taps of the depthwise filter.
     conv_taps: int = 3
     # ffn == "moe": the router's scores, softmax | sigmoid (moe_ffn's
     # ``scoring``); expert_bias: the "moe" collection carries a selection
@@ -298,6 +305,14 @@ class TransformerConfig:
     gdn_key_dim: int = 128
     gdn_value_dim: int = 128
     gdn_conv: int = 4
+    # The layers of kind mixer="kda" (models/kda.py): num_heads heads of
+    # kda_head_dim, each with keys of its own, a depthwise convolution of
+    # conv_taps taps over q, k and v (the decay's bound is the kernels' own).
+    kda_head_dim: int = 128
+    # ffn == "moe": the k experts are chosen inside the moe_groups_kept best
+    # of moe_groups groups of experts (moe._route; 1 and 1: among all).
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
     # Granite's four scalars (``granitemoehybrid``; each at its default
     # leaves the program as it was): the embedding times
     # embedding_multiplier; every branch (mixer and FFN) times
@@ -617,6 +632,38 @@ def laguna_s_2_1_config(**overrides) -> TransformerConfig:
         tie_embeddings=False, ffn="moe", num_experts=256,
         experts_per_token=10, norm_topk_prob=True, routed_scaling_factor=2.5,
         attention_gate="head", layer_pattern=pattern), **overrides})
+
+
+def ling_3_0_flash_config(**overrides) -> TransformerConfig:
+    """Ling-3.0-flash, the language model of Ling-3.0-flash-VL
+    (inclusionAI/Ling-3.0-flash-VL ``config.json``, ``bailing_hybrid``): 42
+    layers in periods of six (``layer_group_size``), five Kimi Delta Attention
+    mixers (32 heads of 128, 4 taps, a decay a key channel behind a gate
+    bounded at -5) then one layer of latent attention, 32 heads whose keys
+    are 128 without positions beside a rotary 64 that the heads share (theta
+    6e6) over values of 128, the keys and values through a latent of 512 and
+    the queries straight from the stream (``q_lora_rank`` null), a sigmoid
+    gate a head on its output; layers 0 and 1 a dense SwiGLU of width 6144,
+    the others 512 experts of width 768, 8 a token by sigmoid scores plus a
+    bias the step keeps, chosen inside the 4 best of 8 groups, weights
+    renormalised and times 2.5, beside a shared expert of width 768; RMSNorm
+    at 1e-6, no biases, an untied head.  Neither the vision tower nor the
+    multi-token-prediction module is built."""
+    pattern = tuple(
+        LayerKind(mixer="attention" if (i + 1) % 6 == 0 else "kda",
+                  ffn="dense" if i < 2 else None) for i in range(42))
+    return TransformerConfig(**{**dict(
+        vocab_size=157184, num_layers=42, num_heads=32, d_model=2560,
+        d_ff=768, d_ff_dense=6144, d_ff_shared=768, max_len=131072,
+        causal=True, norm="rmsnorm", norm_eps=1e-6, positions="rope",
+        rope_theta=6e6, rope_interleave=True, use_bias=False,
+        tie_embeddings=False, ffn="moe", num_experts=512,
+        experts_per_token=8, norm_topk_prob=True, router_scoring="sigmoid",
+        expert_bias=True, routed_scaling_factor=2.5, moe_groups=8,
+        moe_groups_kept=4, q_lora_rank=0, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        attention_gate="head", kda_head_dim=128, conv_taps=4,
+        layer_pattern=pattern), **overrides})
 
 
 def tiny_config(**overrides) -> TransformerConfig:
@@ -1072,6 +1119,10 @@ class Block(nn.Module):
                 from .gated_delta import GatedDeltaNet
 
                 y = GatedDeltaNet(cfg, name="gdn")(y)
+            elif mixer == "kda":
+                from .kda import KimiDeltaAttention
+
+                y = KimiDeltaAttention(cfg, name="kda")(y)
             else:
                 raise ValueError(f"unknown mixer {mixer!r}")
             if cfg.hc_mult:
@@ -1181,7 +1232,9 @@ class Block(nn.Module):
                              router_input=router_input,
                              activation=cfg.expert_activation,
                              scoring=cfg.router_scoring, bias=bias,
-                             scale=cfg.routed_scaling_factor)
+                             scale=cfg.routed_scaling_factor,
+                             n_group=cfg.moe_groups,
+                             topk_group=cfg.moe_groups_kept)
         self.sow("moe", "stats", stats)
         self._publish_router_product(
             rows if router_input is None
@@ -1314,6 +1367,23 @@ def publish_gated_delta(cfg: TransformerConfig, seq_len: int,
     chunks = layers * sequences * cfg.gdn_value_heads \
         * -(-seq_len // CHUNK)
     metrics.set_gauge("gdn_chunks_per_step", float(chunks))
+    return chunks
+
+
+def publish_kda(cfg: TransformerConfig, seq_len: int,
+                sequences: int = 1) -> int:
+    """Set the gauge ``kda_chunks_per_step`` for a step of ``sequences``
+    sequences of ``seq_len`` positions, and return it: the chunks of Kimi
+    Delta Attention's rule, one a head, ``kernels.kda.CHUNK`` positions (the
+    last of a sequence filled) and layer of kind ``kda``; from the shapes
+    alone, called outside the step, beside :func:`publish_gated_delta`."""
+    from ..core import metrics
+    from ..kernels.kda import CHUNK
+
+    layers = sum(cfg.layer_kind(i).mixer == "kda"
+                 for i in range(cfg.num_blocks))
+    chunks = layers * sequences * cfg.num_heads * -(-seq_len // CHUNK)
+    metrics.set_gauge("kda_chunks_per_step", float(chunks))
     return chunks
 
 

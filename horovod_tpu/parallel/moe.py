@@ -294,9 +294,34 @@ def _chosen(probs, experts):
     return read(probs, experts)
 
 
+def _within_groups(scores, n_group: int, topk_group: int):
+    """``scores [n, experts]`` (what the k experts are chosen by) with every
+    expert outside the row's ``topk_group`` best groups set to 0: the experts
+    lie in ``n_group`` groups of neighbours, a group's score is the sum of
+    its two largest entries, and the k are then taken among the groups kept
+    (DeepSeek-V3's node-limited routing, ``transformers``'
+    ``DeepseekV3TopkRouter``: ``masked_fill(~mask, 0.0)``, to the letter).
+    The mask by comparison, no scatter.  1 and 1: ``scores`` itself."""
+    if n_group == topk_group == 1:
+        return scores
+    n, n_experts = scores.shape
+    if n_experts % n_group or not 1 <= topk_group <= n_group \
+            or n_experts // n_group < 2:
+        raise ValueError(f"{topk_group} of {n_group} groups over {n_experts} "
+                         f"experts")
+    scores = lax.stop_gradient(scores)
+    by_group = scores.reshape(n, n_group, n_experts // n_group)
+    # lax.top_k's own order among equal groups: the first topk_group of them.
+    _, kept = lax.top_k(jnp.sum(lax.top_k(by_group, 2)[0], axis=-1),
+                        topk_group)                          # [n, topk_group]
+    mask = jnp.any(kept[:, :, None] == lax.broadcasted_iota(
+        jnp.int32, (1, 1, n_group), 2), axis=1)              # [n, n_group]
+    return jnp.where(mask[:, :, None], by_group, 0.0).reshape(n, n_experts)
+
+
 def _route(xf, router, k, norm_topk_prob=False, router_input=None,
            scoring="softmax", bias=None, scale=1.0, row_scale=None,
-           col_scale=None):
+           col_scale=None, n_group=1, topk_group=1):
     """The router on rows ``xf [n, d]`` (or, where given, on ``router_input
     [rows, tokens, d_r]``, the same n rows, times ``row_scale [rows,
     tokens]`` and ``col_scale [d_r]`` where those are given), in fp32: each
@@ -308,7 +333,9 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
     ``scoring``: the scores are a softmax over the experts, or a
     sigmoid of each logit, whose k weights are divided by their sum plus
     1e-6 under ``norm_topk_prob`` and carry no auxiliary loss (both zero).
-    ``bias [experts]`` is added to the scores for the choice of the k alone;
+    ``bias [experts]`` is added to the scores for the choice of the k alone,
+    which is made inside the ``topk_group`` best of ``n_group`` groups of
+    experts (:func:`_within_groups`; 1 and 1: among all);
     the weights are the scores themselves, read back at the chosen indices
     by :func:`_chosen` whatever the scoring (the choice sees no tangent).
     ``scale`` multiplies the weights."""
@@ -335,6 +362,7 @@ def _route(xf, router, k, norm_topk_prob=False, router_input=None,
             raise ValueError(f"unknown scoring {scoring!r}")
         chosen_by = probs if bias is None \
             else probs + bias.astype(jnp.float32)
+        chosen_by = _within_groups(chosen_by, n_group, topk_group)
         _, experts = lax.top_k(lax.stop_gradient(chosen_by), k)    # [n, k]
         weights = _chosen(probs, experts)
         if norm_topk_prob:
@@ -685,7 +713,8 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
             norm_topk_prob: bool = False,
             router_input: Union[jax.Array, RouterRows, None] = None,
             activation: str = "silu", scoring: str = "softmax",
-            bias: Optional[jax.Array] = None, scale: float = 1.0):
+            bias: Optional[jax.Array] = None, scale: float = 1.0,
+            n_group: int = 1, topk_group: int = 1):
     """Dropless top-k expert layer: ``sum_j p_j * down_j(act(gate_j x) *
     up_j x)`` over a token's k best-scored experts, the scores a softmax
     over all experts, renormalised over the k only with
@@ -729,6 +758,9 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
       (:func:`update_expert_bias` after each step, from
       ``MoEStats.tokens_per_expert``).
     - ``scale``: a factor on the k weights (``routed_scaling_factor``).
+    - ``n_group``, ``topk_group``: the k are chosen inside the ``topk_group``
+      best of ``n_group`` groups of experts (:func:`_within_groups`); 1, 1:
+      among all, and the layer lowers to what it lowered to without them.
 
     All of the rows given are routed as one set: sorted by expert, multiplied
     by a grouped matmul, brought back.  The auxiliary losses are taken over
@@ -743,7 +775,8 @@ def moe_ffn(x: jax.Array, router: jax.Array, gate: Optional[jax.Array],
     Returns ``(y [rows, tokens, d] in dtype, MoEStats)``.
     """
     common = dict(k=k, dtype=dtype, norm_topk_prob=norm_topk_prob,
-                  act=_activation(activation), scoring=scoring, scale=scale)
+                  act=_activation(activation), scoring=scoring, scale=scale,
+                  n_group=n_group, topk_group=topk_group)
     if held is None:
         body = functools.partial(_moe_rows, **common)
     else:

@@ -95,6 +95,11 @@ if _lock_debug_enabled():
 
 
 def pytest_configure(config):
+    # The files go out in the order ``pytest_collection_modifyitems`` leaves
+    # them in, not by their number of cases (see ``_LONGEST_FIRST``); the
+    # option exists from xdist 3.x on and only its controller reads it.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     # Audit trail for the infra-retry gate (helpers._log_retry): a de-flake
     # claim needs "zero engagements" to be checkable per run.
     import tempfile
@@ -117,12 +122,53 @@ def pytest_unconfigure(config):
         shutil.rmtree(_session_dir, ignore_errors=True)
 
 
+# The files whose cases summed to a hundred seconds and more in a six-worker
+# run of the driver's command on PR 66's tree (the builder's junit), longest
+# first: the whole-step compiles for a described chip and the models' own
+# suites.  ``--dist loadfile`` hands files to workers in the order of its
+# queue, so these go out first and everything else, in the collection's
+# order behind them, fills the end.  **The queue is not the collection's
+# order unless xdist is told so**: since 3.x it sorts the files by their
+# number of cases (``loadscopereorder``, on by default), which sent the
+# compiles (one or two cases, two to four minutes each) out last, a worker
+# alone with one while five stood idle (165 s of 1328: ROADMAP.md D0);
+# ``pytest_configure`` above switches that off.  The models' suites stand
+# here beside the compiles because the collection's order is the alphabet's,
+# which ends on ``test_xing*.py``, three of the longest.  A file that is not
+# here counts as short; a stale entry costs a little of the balance and
+# nothing else.
+_LONGEST_FIRST = (
+    "test_joyai_compile", "test_qwen3_next_compile", "test_ling_compile",
+    "test_xing", "test_ling_cell", "test_ssd_scan", "test_joyai",
+    "test_qwen3_next", "test_nemotron", "test_sdar", "test_moe_compile",
+    "test_lfm2", "test_ling", "test_olmoe", "test_granite_compile",
+    "test_smallthinker", "test_xing_cell", "test_router_product",
+    "test_qwen3_next_cell", "test_mck_proto", "test_rows_to_tokens",
+    "test_granite", "test_xing_compile", "test_ssd_scan_kernels",
+    "test_elastic", "test_gated_delta", "test_laguna", "test_pinned_programs",
+    "test_laguna_cell",
+)
+
+
 def pytest_collection_modifyitems(config, items):
-    """Run chaos-marked tests LAST (stable sort: everything else keeps its
-    order).  The chaos lane is wall-clock-heavy multiprocess jobs; signal
-    from the fast functional tiers must never queue behind it, and
-    ``ci/chaos.sh`` runs the lane standalone anyway."""
-    items.sort(key=lambda it: it.get_closest_marker("chaos") is not None)
+    """Run chaos-marked tests LAST, and before them the files of
+    :data:`_LONGEST_FIRST` in its order (stable sort: a file's cases keep
+    their order and stay together, and the files that are not named keep
+    theirs behind the others).  The chaos lane is wall-clock-heavy
+    multiprocess jobs; signal from the fast functional tiers must never
+    queue behind it, and ``ci/chaos.sh`` runs the lane standalone anyway."""
+    first_seen = {}
+    for index, it in enumerate(items):
+        first_seen.setdefault(it.nodeid.split("::")[0], index)
+
+    def order(it):
+        path = it.nodeid.split("::")[0]
+        stem = os.path.splitext(os.path.basename(path))[0]
+        return (it.get_closest_marker("chaos") is not None,
+                _LONGEST_FIRST.index(stem) if stem in _LONGEST_FIRST
+                else len(_LONGEST_FIRST), first_seen[path])
+
+    items.sort(key=order)
 
 
 class TestWatchdogTimeout(Exception):

@@ -320,7 +320,9 @@ def test_the_metric_reads_both_kernels_by_their_names():
                     if m["name"] == metric["name"]]
     assert entry["layer"] == "kernel" and entry["source"] == "device_trace"
     assert entry["moves"] == "samples_per_s_chip"
+    # PR 66 appended its cell, the fourth whose mixers run the kernels.
     assert sorted(entry["workloads"]) == [
         "granite-4.0-h-micro-wfbp-1chip",
+        "ling-3.0-flash-vl-wfbp-1chip",
         "nemotron-3-super-120b-a12b-wfbp-1chip",
         "qwen3-next-80b-a3b-wfbp-1chip"]

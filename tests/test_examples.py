@@ -119,18 +119,3 @@ def test_elastic_tensorflow2_resnet50(tmp_path):
                   extra_cli=["--min-np", "1",
                              "--host-discovery-script", str(discover)])
     assert "ELASTIC RESNET DONE" in out
-
-
-@pytest.mark.parametrize("mode", ["wfbp", "eager"])
-def test_jax_synthetic_mode(mode):
-    """The native example's two runtime flavors, two ranks on the XLA data
-    plane: ``wfbp`` is the overlapped step (in-program gradient allreduce),
-    ``eager`` goes through ``DistributedOptimizer`` and applies the
-    updates under jit."""
-    out = _hvdrun(
-        2, ["examples/jax/jax_synthetic_benchmark.py", "--mode", mode,
-            "--batch-size", "4", "--image-size", "32",
-            "--num-warmup-batches", "1", "--num-iters", "1",
-            "--num-batches-per-iter", "2"],
-        extra_cli=("--data-plane", "xla"), timeout=420)
-    assert "Total img/sec" in out

@@ -572,6 +572,21 @@ def test_pending_tree_holds_no_gradient_array():
                                    "compression")
 
 
+def _no_runtime():
+    """The cases below read the update where no runtime is up.  A file that
+    ran before this one in the same process may have left one up (the cells'
+    suites call ``hvd.init()`` and leave it), and which files those are is
+    the order's to say: shut it down and make the state new, so that the
+    case runs whatever ran before and a later ``init()`` starts afresh."""
+    from horovod_tpu.core import state
+    from horovod_tpu.frameworks.jax import ops
+
+    if ops.initialized():
+        state.global_state().shutdown()
+        state.reset_global_state()
+    assert not ops.initialized()
+
+
 def _same_bits(got, want):
     import jax
 
@@ -591,12 +606,10 @@ def test_runtime_down_update_is_the_plain_inner_update(tx_name, tree_in):
     import optax
 
     from horovod_tpu.core.timeline import phase_stats
-    from horovod_tpu.frameworks.jax import ops
     from horovod_tpu.frameworks.jax.optimizer import DistributedOptimizer
     from horovod_tpu.frameworks.jax.wfbp import FusedTree
 
-    if ops.initialized():
-        pytest.skip("a runtime is up in this process")
+    _no_runtime()
     tx = {"sgd_momentum": optax.sgd(0.1, momentum=0.9),
           "adamw": optax.adamw(1e-3, weight_decay=0.01)}[tx_name]
     params = {"w": jnp.ones((2, 3)), "b": jnp.zeros((3,))}
@@ -635,12 +648,10 @@ def test_inside_a_callers_jit_the_state_keeps_its_form(tx_name, form, loop):
     import optax
 
     from horovod_tpu.core.timeline import phase_stats
-    from horovod_tpu.frameworks.jax import ops
     from horovod_tpu.frameworks.jax.optimizer import DistributedOptimizer
     from horovod_tpu.frameworks.jax.wfbp import FusedTree, single_device
 
-    if ops.initialized():
-        pytest.skip("a runtime is up in this process")
+    _no_runtime()
     tx = {"sgd_momentum": optax.sgd(0.1, momentum=0.9),
           "adamw": optax.adamw(1e-3, weight_decay=0.01)}[tx_name]
     params = {"w": jnp.ones((2, 3)), "b": jnp.zeros((3,))}
@@ -733,11 +744,10 @@ def test_a_sharded_state_stays_a_tree():
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.frameworks.jax import ops, wfbp
+    from horovod_tpu.frameworks.jax import wfbp
     from horovod_tpu.frameworks.jax.optimizer import DistributedOptimizer
 
-    if ops.initialized():
-        pytest.skip("a runtime is up in this process")
+    _no_runtime()
     if len(jax.devices()) < 2:
         pytest.skip("needs two devices")
     mesh = Mesh(np.array(jax.devices()[:2]), ("x",))

@@ -119,12 +119,12 @@ def test_configuration_keeps_every_published_width():
             REPO_ROOT, "chip_bench/metrics", name + ".json")), name
     own = [m for m in bench["per_layer"]
            if m["name"] in ("recompute_ms_step", "ssd_scan_fwd_calls_step")]
-    # PRs 58 and 63 appended their cells, the second and the third whose
-    # blocks are recomputed, to the first's list.
+    # PRs 58, 63 and 66 appended their cells, the second to the fourth
+    # whose blocks are recomputed, to the first's list.
     assert [(m["layer"], m["moves"], m["workloads"]) for m in own] \
         == [("step builders", "samples_per_s_chip",
              [CELL, "xing4.0-29b-a4b-wfbp-1chip",
-              "laguna-s-2.1-wfbp-1chip"]),
+              "laguna-s-2.1-wfbp-1chip", "ling-3.0-flash-vl-wfbp-1chip"]),
             ("step builders", "samples_per_s_chip", [CELL])]
 
 

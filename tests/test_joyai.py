@@ -474,11 +474,16 @@ def test_latent_attention_refuses_what_it_does_not_build():
     from horovod_tpu.models.transformer import Transformer
 
     model, _ = tiny_model()
-    for change in (dict(causal=False), dict(q_lora_rank=0),
-                   dict(num_kv_heads=2), dict(qk_norm="head")):
+    for change in (dict(causal=False), dict(num_kv_heads=2),
+                   dict(qk_norm="head")):
         with pytest.raises(ValueError, match="latent attention is built"):
             Transformer(dataclasses.replace(model.cfg, **change)).init(
                 jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    # Since PR 66 a query without a latent is built (``q_lora_rank`` 0:
+    # tests/test_ling.py); a gate that is no column a head is not.
+    with pytest.raises(ValueError, match="one column a head"):
+        Transformer(dataclasses.replace(model.cfg, attention_gate=True)).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
 # -- the shares add up --------------------------------------------------------

@@ -327,11 +327,13 @@ def test_a_pattern_as_a_whole_list_and_as_a_period_build_one_smallthinker():
 
 
 def _frozen_route(xf, router, k, norm_topk_prob=False, router_input=None,
-                  scoring="softmax", bias=None, scale=1.0):
+                  scoring="softmax", bias=None, scale=1.0, n_group=1,
+                  topk_group=1):
     """``parallel/moe.py::_route`` as the parent of PR 38 (3bb7be9) had it,
     word for word but for the scope; what it did not take has to arrive at
     its default."""
-    assert (scoring, bias, scale) == ("softmax", None, 1.0)
+    assert (scoring, bias, scale, n_group, topk_group) \
+        == ("softmax", None, 1.0, 1, 1)
     n, n_experts = xf.shape[0], router.shape[-1]
     with jax.named_scope("hvd.moe.router"):
         if router_input is not None:

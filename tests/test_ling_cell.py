@@ -1,9 +1,9 @@
-"""laguna-s-2.1's configuration and cell
-(``chip_bench/configs/laguna-s-2.1``): the published widths and the cut, the
-counts from shapes, data and weights from the seed, the configuration's own
-limits on the logits, the recomputed blocks through
+"""ling-3.0-flash-vl's configuration and cell
+(``chip_bench/configs/ling-3.0-flash-vl``): the published widths and the cut,
+the counts from shapes, data and weights from the seed, the configuration's
+own limits on the logits, the recomputed blocks through
 ``hvd.make_overlapped_train_step`` and the cell through the harness at a tiny
-size.  ``tests/test_laguna.py`` holds the model and its layers; the two are
+size.  ``tests/test_ling.py`` holds the model and its layers; the two are
 apart so that the test workers can share them.
 """
 
@@ -17,19 +17,19 @@ import numpy as np
 import pytest
 
 from .helpers import REPO_ROOT
-from .test_laguna import TINY
+from .test_ling import tiny_sizes
 
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
 
-CELL = "laguna-s-2.1-wfbp-1chip"
-REDUCED = ["num_hidden_layers", "num_experts", "vocab_size"]
-NEW_METRICS = ("window_attention_ms_step", "window_attention_roofline_pct",
-               "attn_rope_ms_step")
-SHARED_METRICS = ("mfu_pct", "step_ms_p95.observed", "wfbp_dispatch_ms_step",
+CELL = "ling-3.0-flash-vl-wfbp-1chip"
+REDUCED = ["num_hidden_layers", "first_k_dense_replace", "num_experts",
+           "vocab_size"]
+NEW_METRICS = ("kda_ms_step", "kda_roofline_pct")
+SHARED_METRICS = ("step_ms_p95.observed", "wfbp_dispatch_ms_step",
                   "moe_experts_ms_step", "moe_rows_to_tokens_ms_step",
-                  "recompute_ms_step", "mixed_attention_ms_step",
-                  "mixed_attention_roofline_pct")
+                  "mla_attention_ms_step", "mla_attention_roofline_pct",
+                  "causal_conv_ms_step", "recompute_ms_step", "mfu_pct")
 
 
 def _config_module():
@@ -45,7 +45,7 @@ def _catalog_row():
         pytest.skip("no catalog of architectures here")
     with open(catalog) as f:
         rows = [json.loads(line) for line in f if line.strip()]
-    return [r for r in rows if r["name"] == "Laguna-S-2.1"][0]
+    return [r for r in rows if r["name"] == "Ling-3.0-flash-VL"][0]
 
 
 def test_configuration_keeps_every_published_width():
@@ -57,52 +57,57 @@ def test_configuration_keeps_every_published_width():
     differs = [k for k, v in published.items()
                if sizes.get(k, "absent") != v]
     assert sorted(differs) == sorted(REDUCED)
-    assert [sizes[k] for k in REDUCED] == [5, 8, 12544]
+    assert [sizes[k] for k in REDUCED] == [7, 1, 8, 19648]
     for key in REDUCED:
         assert sizes[key + "_published"] == published[key]
-    assert sizes["layers_held"] == [0, 1, 2, 3, 4]
+    assert sizes["layers_held"] == [1, 2, 3, 4, 5, 6, 7]
     assert sizes["experts_held"] == list(range(8))
-    # No width among the cuts; the layers' lists whole, the rotary groups
-    # letter for letter.
+    assert sizes["vocab_size"] * 8 == published["vocab_size"]
+    # No width among the cuts; the clamps' lists whole.
     for key in ("hidden_size", "intermediate_size", "moe_intermediate_size",
-                "shared_expert_intermediate_size", "head_dim",
+                "moe_shared_expert_intermediate_size", "head_dim",
                 "num_attention_heads", "num_key_value_heads",
-                "num_experts_per_tok", "sliding_window", "rope_parameters",
-                "layer_types", "mlp_layer_types", "gating_types",
-                "num_attention_heads_per_layer", "moe_routed_scaling_factor"):
+                "num_experts_per_tok", "kv_lora_rank", "q_lora_rank",
+                "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                "rotary_dim", "short_conv_kernel_size", "kda_lower_bound",
+                "n_group", "topk_group", "layer_group_size",
+                "routed_scaling_factor", "expert_swiglu_limit_list",
+                "share_expert_swiglu_limit_list"):
         assert sizes[key] == published[key], key
-    assert len(sizes["layer_types"]) == 48
+    assert len(sizes["expert_swiglu_limit_list"]) == 42
     assert sizes["recompute_blocks"] is True
-    for key in ("attention", "gate", "yarn", "experts", "init", "precision",
-                "sequence", "reference_limits"):
+    assert "64 chips" in sizes["deployment"] and "8" in sizes["deployment"]
+    for key in ("recomputed", "fit", "reduced_how"):
+        assert len(sizes[key]) > 200, key
+    for key in ("block", "kda", "kda_gates", "latent_attention", "rotary",
+                "experts", "unbuilt_keys", "init", "optimizer", "precision",
+                "sequence", "left_out", "reference_limits"):
         assert len(sizes["assumed"][key]) > 100, key
 
 
 def test_benchmark_json_names_the_cell_and_its_files():
     with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    config = [c for c in bench["configs"] if c["name"] == "laguna-s-2.1"]
+    config = [c for c in bench["configs"] if c["name"] == "ling-3.0-flash-vl"]
     assert len(config) == 1 and config[0]["reduced"] == REDUCED
+    assert bench["configs"][-1] is config[0]         # appended, not inserted
     assert os.path.exists(os.path.join(REPO_ROOT, config[0]["file"]))
     for suffix in (".py", "_reference.py"):
         assert os.path.exists(os.path.join(
             REPO_ROOT, config[0]["file"].replace(".json", suffix)))
-    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
-    assert cell == {"name": CELL, "config": "laguna-s-2.1",
+    cell = bench["workloads"][-1]
+    assert cell == {"name": CELL, "config": "ling-3.0-flash-vl",
                     "traffic": "wfbp", "chips": 1, "why": cell["why"]}
-    assert len(cell["why"]) <= 200 and len(config[0]["why"]) <= 200
-    # The exact count is the newest configuration's (tests/test_ling_cell.py).
-    assert len(bench["workloads"]) >= 14
+    # The newest configuration's test counts exactly.
+    assert len(bench["configs"]) == 13 and len(bench["workloads"]) == 15
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    for entry in bench["configs"] + bench["workloads"]:
+        assert 1 <= len(entry["why"]) <= 200 and entry["why"].isprintable()
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
-        assert CELL in metrics[name]["workloads"], name
-    # Appended together (PR 63); PR 65's count of the rotary kernels' calls
-    # stands behind, and later cells' metrics behind that.
+        assert metrics[name]["workloads"][-1] == CELL, name
     names = [m["name"] for m in bench["per_layer"]]
-    at = names.index(NEW_METRICS[0])
-    assert names[at:at + 4] == list(NEW_METRICS) \
-        + ["rope_operands_calls_step"]
+    assert names[-2:] == list(NEW_METRICS)
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"
@@ -110,95 +115,108 @@ def test_benchmark_json_names_the_cell_and_its_files():
         with open(os.path.join(REPO_ROOT, "chip_bench/metrics",
                                name + ".json")) as f:
             assert json.load(f)["name"] == name
+    # 2 + 14 runs a cell of run_seconds + 60, 180 s more a cell, 1200 spare.
+    runs = 2 + 14 * len(bench["workloads"])
+    assert runs * (bench["run_seconds"] + 60) \
+        + 180 * len(bench["workloads"]) + 1200 < 43200
 
 
 def test_flops_and_costs_from_shapes():
-    """A token's multiply-adds by hand (ISSUE 63's reckoning: 482.3 M of
-    products and 128.1 M of allowed pairs, 30.0 TFLOP a sample) and the
-    attention kernels' cost with the recomputed forward, the two kinds
-    together and the sliding layers alone."""
+    """A sample's multiply-adds by hand, and the two kernels' costs: the
+    rule's and the attention's as the step runs them, with the recomputed
+    forward's call (the rule memory-bound: 14.12 GB a step)."""
     module, sizes = _config_module()
-    s, d = 8192, 3072
+    s, d, h, inner = 8192, 2560, 32, 4096
     causal = s * (s + 1) // 2
-    window = causal - (s - 512) * (s - 511) // 2
-    assert window == 4_063_488
-    head_pairs = 2 * 48 * causal + 3 * 72 * window
+    rule = (s // 64) * h * (2 * 64 * 64 * 128 + 64 * 64 * 256
+                            + 2 * 64 * 128 * 128 + 64 * 64 * 128
+                            + 64 * 128 * 128)
     by_hand = {
-        "q_out": s * 2 * d * 128 * (2 * 48 + 3 * 72),
-        "kv": 5 * s * d * 2048,
-        "gate": s * d * (2 * 48 + 3 * 72),
-        "attention_scores": head_pairs * 128,
-        "attention_values": head_pairs * 128,
-        "dense_ffn": s * 3 * d * 12288,
-        "router": 4 * s * d * 256,
-        "shared_expert": 4 * s * 3 * d * 1024,
-        "experts": 4 * s * (10 * 8 / 256) * 3 * d * 1024,
-        "head": s * d * 12544,
+        "kda_proj": 6 * s * d * (6 * inner + h),
+        "kda_conv": 6 * s * 3 * inner * 4,
+        "kda_rule": 6 * rule,
+        "mla_q": s * d * h * 192,
+        "mla_down": s * d * 576,
+        "mla_up": s * 512 * h * 256,
+        "mla_gate_out": s * d * h * 129,
+        "attention_scores": causal * h * 192,
+        "attention_values": causal * h * 128,
+        "dense_ffn": s * 3 * d * 6144,
+        "router": 6 * s * d * 512,
+        "shared_expert": 6 * s * 3 * d * 768,
+        "experts": 6 * s * (8 * 8 / 512) * 3 * d * 768,
+        "head": s * d * 19648,
     }
     assert module.matmul_macs(sizes) == by_hand
-    per_token = sum(by_hand.values()) / s / 1e6
-    assert per_token == pytest.approx(482.3 + 128.1, abs=0.05)
-    assert module.flops_per_sample(sizes) == pytest.approx(30.0e12, rel=1e-3)
+    assert module.flops_per_sample(sizes) == 6.0 * sum(by_hand.values())
+    assert module.flops_per_sample(sizes) == pytest.approx(29.9e12, rel=2e-2)
     assert module.Config(sizes).flops_per_sample() \
         == module.flops_per_sample(sizes)
-    assert module.head_pairs(sizes) == head_pairs
-    assert module.head_pairs(sizes, "sliding_attention") == 3 * 72 * window
-    # The forward kernel twice and the backward once: 2 + 2 + 4 products.
-    operations, moved = module.mixed_attention_cost(sizes)
-    assert operations == 2 * 4 * head_pairs * 256
-    assert moved == 4 * 2 * s * 128 * (2 * (2 * 48 + 8 * 2)
-                                       + 3 * (2 * 72 + 8 * 2))
-    operations, moved = module.window_attention_cost(sizes)
-    assert operations == 2 * 4 * 3 * 72 * window * 256
-    assert moved == 4 * 2 * s * 128 * 3 * (2 * 72 + 16)
-    assert module.attention_cost({**sizes, "recompute_blocks": False})[0] \
-        == 2 * 3 * head_pairs * 256
-    # A sliding layer's kernels visit 15 tiles of 1024 x 1024 for 3.9 tiles'
-    # worth of allowed pairs.
-    from horovod_tpu.kernels import masked_attention as ma
-    from horovod_tpu.kernels.masked_attention_bwd import tile_table
-
-    assert len(tile_table(ma.Window(512), s, 1024, 1024)[0]) == 15
-    assert window / 1024 ** 2 == pytest.approx(3.875, abs=1e-3)
+    operations, moved = module.kda_cost(sizes)
+    assert operations == 6 * 8 * rule
+    wide, decays, states = 2 * s * inner, 4 * s * inner, 4 * 128 * h * 128 ** 2
+    forward = 4 * wide + decays + 4 * s * h + states
+    backward = 7 * wide + 2 * decays + 2 * 4 * s * h + states
+    assert moved == 6 * (2 * forward + backward)
+    assert moved == pytest.approx(14.12e9, rel=2e-3)
+    assert operations / 197e12 < moved / 819e9          # the bytes bind
+    once = module.kda_cost({**sizes, "recompute_blocks": False})
+    assert once == (6 * 6 * rule, 6 * (forward + backward))
+    operations, moved = module.mla_attention_cost(sizes)
+    assert operations == 2 * 4 * causal * h * 320
+    assert moved == 3 * 2 * s * h * 640
+    assert module.mla_attention_cost(
+        {**sizes, "recompute_blocks": False})[0] == 2 * 3 * causal * h * 320
 
 
 def test_the_model_is_the_presets_at_the_cut():
     from horovod_tpu.frameworks.jax.wfbp import PROCESS_AXIS
-    from horovod_tpu.models.transformer import laguna_s_2_1_config
+    from horovod_tpu.models.transformer import ling_3_0_flash_config
 
     module, sizes = _config_module()
     config = module.Config(sizes)
-    cfg, whole = config.model.cfg, laguna_s_2_1_config()
+    cfg, whole = config.model.cfg, ling_3_0_flash_config()
     differs = {f for f in cfg.__dataclass_fields__
                if getattr(cfg, f) != getattr(whole, f)}
     assert differs == {"num_layers", "vocab_size", "experts_held",
                        "layer_pattern", "remat", "moe_data_axis"}
-    assert cfg.layer_pattern == whole.layer_pattern[:5]
+    assert cfg.layer_pattern == whole.layer_pattern[1:8]
     assert cfg.remat and cfg.moe_data_axis == PROCESS_AXIS
-    assert cfg.expert_layers() == (1, 2, 3, 4)
+    assert cfg.expert_layers() == (1, 2, 3, 4, 5, 6)
+    assert module.layer_plan(sizes) == [("K", True)] + [("K", False)] * 3 \
+        + [("*", False)] + [("K", False)] * 2
     shapes, aux = jax.eval_shape(config.init, jax.random.PRNGKey(3))
     assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
-        == 811_017_216
-    assert shapes["layer_0"]["attn"]["q"]["kernel"].shape == (3072, 6144)
-    assert shapes["layer_1"]["attn"]["q"]["kernel"].shape == (3072, 9216)
-    assert shapes["layer_1"]["attn"]["gate"]["kernel"].shape == (3072, 72)
-    assert shapes["layer_4"]["attn"]["out"]["kernel"].shape == (6144, 3072)
-    assert shapes["layer_4"]["experts_up"].shape == (8, 3072, 1024)
-    assert shapes["layer_4"]["router"].shape == (3072, 256)
-    assert shapes["layer_0"]["ffn_gate"]["kernel"].shape == (3072, 12288)
-    assert shapes["lm_head"]["kernel"].shape == (3072, 12544)
-    assert sorted(aux) == ["rows_elsewhere", "rows_held", "steps",
-                           "tokens_per_expert"]
-    assert aux["tokens_per_expert"].shape == (4, 256)
+        == sizes["parameters"] == 884_456_384
+    kda = shapes["layer_0"]["kda"]
+    assert kda["in_proj"]["kernel"].shape == (2560, 5 * 4096)
+    assert kda["beta_proj"]["kernel"].shape == (2560, 32)
+    assert kda["conv"].shape == (3 * 4096, 4)
+    assert kda["A_log"].shape == (32,) and kda["dt_bias"].shape == (4096,)
+    assert kda["norm"].shape == (128,)
+    assert sum(x.size for x in jax.tree_util.tree_leaves(kda)) == 63_049_888
+    attn = shapes["layer_4"]["attn"]
+    assert attn["q"]["kernel"].shape == (2560, 32 * 192)
+    assert attn["kv_a"]["kernel"].shape == (2560, 576)
+    assert attn["kv_b"]["kernel"].shape == (512, 32 * 256)
+    assert attn["gate"]["kernel"].shape == (2560, 32)
+    assert sum(x.size for x in jax.tree_util.tree_leaves(attn)) == 31_965_696
+    assert shapes["layer_0"]["ffn_gate"]["kernel"].shape == (2560, 6144)
+    assert shapes["layer_1"]["experts_up"].shape == (8, 2560, 768)
+    assert shapes["layer_1"]["router"].shape == (2560, 512)
+    assert shapes["lm_head"]["kernel"].shape == (2560, 19648)
+    assert sorted(aux) == ["expert_bias", "rows_elsewhere", "rows_held",
+                           "steps", "tokens_per_expert"]
+    assert aux["tokens_per_expert"].shape == (6, 512)
 
 
 TINY_SIZES = {
-    **TINY, "recompute_blocks": True, "adamw_learning_rate": 4e-4,
-    "warmup_steps": 4, "warmup_start_share": 0.01, "adamw_b1": 0.9,
-    "adamw_b2": 0.95, "adamw_eps": 1e-8, "adamw_weight_decay": 0.1,
-    "clip_global_norm": 1.0, "logits_rtol": 0.2, "logits_median_rtol": 0.2,
-    "logits_float32_rtol": 1e-4}
-TINY_CELL = {"module": "laguna-s-2.1", **TINY_SIZES}
+    **tiny_sizes(), "sequence_length": 64, "recompute_blocks": True,
+    "adamw_learning_rate": 4e-4, "warmup_steps": 4,
+    "warmup_start_share": 0.01, "logits_rtol": 0.2,
+    "logits_median_rtol": 0.2, "logits_float32_rtol": 1e-4,
+    "logits_float32_norm_rtol": 1e-3}
+TINY_CELL = {"module": "ling-3.0-flash-vl", **TINY_SIZES}
 
 
 @pytest.fixture(scope="module")
@@ -219,26 +237,28 @@ def test_batch_and_weights_come_from_the_seed(seeded_cell):
     other = jax.jit(config.make_batch)(jax.random.PRNGKey(7))
     assert np.array_equal(batch["tokens"], again["tokens"])
     assert not np.array_equal(batch["tokens"], other["tokens"])
-    assert batch["tokens"].shape == (2, 20)
+    assert batch["tokens"].shape == (2, 64)
     assert 0 <= int(batch["tokens"].min()) \
         and int(batch["tokens"].max()) < TINY_SIZES["vocab_size"]
     assert float(jnp.std(params["embed"]["embedding"])) \
         == pytest.approx(1.0, rel=0.1)
-    assert float(jnp.std(params["layer_1"]["attn"]["gate"]["kernel"])) \
-        == pytest.approx(0.02, rel=0.15)
-    assert int(aux["steps"]) == 0
+    assert float(jnp.std(params["layer_0"]["kda"]["in_proj"]["kernel"])) \
+        == pytest.approx(0.02, rel=0.1)
+    assert float(jnp.max(params["layer_0"]["kda"]["dt_bias"])) < -2.2
+    assert int(aux["steps"]) == 0 and not np.any(aux["expert_bias"])
 
 
 @pytest.mark.parametrize("which,limit,passes", [
     ("logits_rtol", 0.2, True), ("logits_rtol", 1e-6, False),
     ("logits_median_rtol", 1e-6, False),
-    ("logits_float32_rtol", 1e-9, False)])
+    ("logits_float32_rtol", 1e-9, False),
+    ("logits_float32_norm_rtol", 1e-9, False)])
 def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
                                                        capfd, seeded_cell):
     """Behind ``_chip_bench_grad`` the program's logits are held to the
     float32 reference's, once, before the reference's first step: inside the
-    three limits the reference's gradient comes back, outside any of them
-    the run ends there."""
+    four limits the reference's gradient comes back, outside any of them the
+    run ends there."""
     module, params, aux, batch = seeded_cell
     config = module.Config({**TINY_SIZES, which: limit})
     if not passes:
@@ -248,6 +268,7 @@ def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
     (loss, new_aux), grads = config._chip_bench_grad(params, aux, batch)
     said = capfd.readouterr().err
     assert said.count("(limit 2.00e-01)") == 2 and "(limit 1.00e-04)" in said
+    assert "(limit 1.00e-03)" in said
     config._chip_bench_grad(params, aux, batch)      # checked once
     assert capfd.readouterr().err == ""
     assert sorted(new_aux) == sorted(aux) and int(new_aux["steps"]) == 1
@@ -259,8 +280,7 @@ def test_the_configurations_own_limit_holds_the_logits(which, limit, passes,
     assert errors(params, batch, jnp.float32) == (0, 0)
     exact = errors(params, batch, "program_float32")[0]
     assert 0 < exact < 1e-5
-    for fault in ("no_gate", "window_1024", "no_routed_scale",
-                  "plain_in_full"):
+    for fault in ("no_head_gate", "no_l2norm", "no_group_mask"):
         assert errors(params, batch, jnp.float32, (fault,))[0] > 20 * exact, \
             fault
 
@@ -269,7 +289,7 @@ def test_the_step_recomputes_and_follows_the_reference(seeded_cell):
     """``hvd.make_overlapped_train_step(has_aux=True)`` on the program's
     model with every block recomputed beside plain steps of the float32
     reference: three losses agree to the harness's limit, and the step's
-    ``aux`` carries the reference's counts."""
+    ``aux`` carries the reference's counts and a stepped bias."""
     import optax
 
     import horovod_tpu as hvd
@@ -297,36 +317,38 @@ def test_the_step_recomputes_and_follows_the_reference(seeded_cell):
     assert got == pytest.approx(want, rel=3e-4)
     assert want[2] < want[0]                     # the updates were applied
     assert int(a["steps"]) == 3
-    # Three sparse layers, 2 x 20 tokens, 2 a token, three steps.
+    # Two expert layers, 2 x 64 tokens, 4 a token, three steps.
     counts = np.asarray(a["tokens_per_expert"], np.int64)
-    np.testing.assert_array_equal(counts.sum(axis=1), 3 * 2 * 20 * 2)
+    np.testing.assert_array_equal(counts.sum(axis=1), 3 * 2 * 64 * 4)
     # The bf16 stream moves a choice between two near scores here and there.
     assert np.abs(counts - np.asarray(want_aux["tokens_per_expert"])).sum() \
         <= 0.05 * counts.sum()
+    bias = np.asarray(a["expert_bias"])
+    assert bias.shape == (2, 64) and 0 < np.abs(bias).max() <= 3e-3 + 1e-9
 
 
 def test_the_cell_runs_through_the_harness_at_a_tiny_size(tmp_path):
     """``worker.py`` under ``hvdrun -np 1`` on the CPU: the wfbp step of the
-    program's model (both kinds of attention, the gate, a dense and three
-    expert layers, the blocks recomputed) against the plain reference's
-    three losses, and the per-layer metrics of the device's op line left out
-    where there is none to read."""
+    program's model (both mixers, the gate, a dense and two expert layers
+    under the group limit, the blocks recomputed) against the plain
+    reference's three losses, and the per-layer metrics of the device's op
+    line left out where there is none to read."""
     from chip_bench.tests import rehearse
 
-    names = NEW_METRICS + ("mixed_attention_roofline_pct",
-                           "recompute_ms_step", "wfbp_dispatch_ms_step")
-    files = {"configs/tiny-laguna.json": TINY_CELL}
+    names = NEW_METRICS + ("mla_attention_roofline_pct", "recompute_ms_step",
+                           "causal_conv_ms_step", "wfbp_dispatch_ms_step")
+    files = {"configs/tiny-ling.json": TINY_CELL}
     for n in names:
         with open(os.path.join(REPO_ROOT, "chip_bench/metrics", n + ".json")) \
                 as f:
             files[f"metrics/tiny.{n}.json"] = json.load(f)
     root = rehearse.make_root(
-        tmp_path, [("tiny-laguna-wfbp", "tiny-laguna", "wfbp", 1)],
+        tmp_path, [("tiny-ling-wfbp", "tiny-ling", "wfbp", 1)],
         files=files,
         per_layer=[{"name": "tiny." + n, "unit": "x", "better": "lower",
                     "source": "device_trace", "layer": "kernel",
                     "moves": "samples_per_s_chip"} for n in names])
-    r0 = rehearse.run_worker(root, "tiny-laguna-wfbp", 1, trace=1)[0]
+    r0 = rehearse.run_worker(root, "tiny-ling-wfbp", 1, trace=1)[0]
     assert all(r0["checks"].values()), r0["checks"]
     assert r0["losses"][:3] == pytest.approx(r0["reference_losses"], rel=3e-4)
     assert r0["failed_steps"] == 0 and r0["deltas"]["compiles"] == 0
@@ -335,38 +357,24 @@ def test_the_cell_runs_through_the_harness_at_a_tiny_size(tmp_path):
     assert r0["per_layer"]["tiny.wfbp_dispatch_ms_step"] > 0
 
 
-def test_the_new_metrics_read_their_scopes_and_nothing_on_a_parent(
+def test_the_new_reductions_read_their_kernels_and_nothing_on_a_parent(
         monkeypatch):
-    """``window_attention_ms_step`` adds up the attention kernels under
-    ``hvd.attn.window`` alone (not the global layers' calls of the same
-    kernels, not the small operations beside them), forward, backward and
-    recomputed; ``attn_rope_ms_step`` everything under ``hvd.attn.rope``,
-    adopted operations too; without a trace, or on a program that wrote no
-    such scope, they read nothing and never raise."""
+    """``kda_roofline_pct`` takes its time from the rule's kernels by their
+    names; ``recompute_ms_step`` (this module's copy) adds up what lies under
+    ``rematted_computation``, adopted operations too; without a trace, or on
+    a program that ran no such kernel, they read nothing and never raise."""
     from chip_bench import readers, scopes
 
     module, sizes = _config_module()
     module.Config(sizes)
-    window = readers.REDUCTIONS["trace_window_attention_ms_per_step"]
-    share = readers.REDUCTIONS["trace_window_attention_roofline_pct"]
-    rope = readers.REDUCTIONS["trace_attn_rope_ms_per_step"]
+    share = readers.REDUCTIONS["trace_kda_roofline_pct"]
+    attention = readers.REDUCTIONS["trace_mla_attention_roofline_pct"]
     again = readers.REDUCTIONS["trace_recompute_ms_per_step"]
     Op = scopes.Op
-    fwd = "jit(step)/jvp(hvd.loss)/layer_1/attn/hvd.attn.{}/x"
     remat = ("jit(step)/transpose(jvp(hvd.loss))/checkpoint/"
-             "rematted_computation/layer_1/attn/hvd.attn.{}/x")
-    bwd = "jit(step)/transpose(jvp(hvd.loss))/layer_1/attn/hvd.attn.{}/x"
-    ops = (Op("splash_mha_fwd_out_lse", 0.0, 1.0, fwd.format("window"), "",
-              0, 0),
-           Op("splash_mha_fwd_out_lse.1", 1.0, 3.0, fwd.format("causal"),
-              "", 0, 0),
-           Op("splash_mha_fwd_out_lse.2", 3.0, 4.0, remat.format("window"),
-              "", 0, 0),
-           Op("splash_mha_dkv_dq", 4.0, 6.0, bwd.format("window"), "", 0, 0),
-           Op("fusion.9", 6.0, 6.5, bwd.format("window"), "", 0, 0),
-           Op("fusion.1", 6.5, 7.0, fwd.format("rope"), "", 0, 0),
-           Op("copy.2", 7.0, 7.25, "", "", 0, 0, remat.format("rope")),
-           Op("fusion.3", 7.25, 8.0, bwd.format("rope"), "", 0, 0),
+             "rematted_computation/layer_1/kda/hvd.kda.{}/x")
+    ops = (Op("hvd_kda_fwd.2", 3.0, 4.0, remat.format("rule"), "", 0, 0),
+           Op("copy.2", 7.0, 7.25, "", "", 0, 0, remat.format("norm")),
            Op("fusion.6", 8.0, 9.0,
               "jit(step)/jvp(hvd.loss)/layer_0/hvd.ffn/dot_general", "", 0, 0))
     monkeypatch.setattr(scopes, "device_ops", lambda path: ops)
@@ -374,19 +382,17 @@ def test_the_new_metrics_read_their_scopes_and_nothing_on_a_parent(
     class Window:
         ops, steps, lo, hi = [1], 2, 0.0, 10.0
 
+        def op_s(self, pattern):
+            return 0.0
+
     ctx = {"window": Window(), "xplane": "a.xplane.pb"}
-    assert window({}, ctx) == pytest.approx(1e3 * (1.0 + 1.0 + 2.0) / 2)
-    assert rope({}, ctx) == pytest.approx(1e3 * (0.5 + 0.25 + 0.75) / 2)
     assert again({}, ctx) == pytest.approx(1e3 * (1.0 + 0.25) / 2)
-    if jax.local_devices()[0].platform != "tpu":
-        with pytest.raises(ValueError, match="peak"):
-            share({}, ctx)
-    # A parent's program: no window kernel, nothing under attn.rope.
+    # A parent's program ran no such kernel: nothing to take a share of.
+    assert share({"pattern": "^hvd_kda_"}, ctx) is None
+    assert attention({"pattern": "^splash_mha_(fwd|dq|dkv)"}, ctx) is None
     monkeypatch.setattr(scopes, "device_ops", lambda path: ops[-1:])
-    ctx["xplane"] = "parent.xplane.pb"       # the op line is read once a file
-    assert window({}, ctx) is None and rope({}, ctx) is None
-    assert share({}, ctx) is None and again({}, ctx) is None
+    assert again({}, ctx) is None
     # No trace, no file.
-    assert window({}, {"window": None}) is None
+    assert share({"pattern": "^hvd_kda_"}, {"window": None}) is None
     monkeypatch.setattr(sys, "argv", ["worker.py"])
-    assert rope({}, {"window": Window()}) is None
+    assert again({}, {"window": Window()}) is None

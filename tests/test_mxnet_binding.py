@@ -34,6 +34,11 @@ def _runtime():
     hvd.init()
     yield hvd
     hvd.shutdown()
+    # The next file's init is to start a loop of its own, not to find this
+    # one's drained state still set.
+    from horovod_tpu.core import state
+
+    state.reset_global_state()
     if prior is not None:
         sys.modules["mxnet"] = prior
     else:

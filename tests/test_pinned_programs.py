@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from . import test_joyai, test_lfm2, test_nemotron, test_olmoe, \
+from . import test_joyai, test_lfm2, test_ling, test_nemotron, test_olmoe, \
     test_qwen3_next, test_xing, \
     test_router_product, test_sdar, test_smallthinker
 
@@ -148,6 +148,15 @@ PINS = {
     # and two sparse ones).  Its step's text is not pinned: no other model
     # runs its module, and ``tests/test_xing.py`` holds its numbers.
     "tree/xing4.0-29b-a4b": "d1c50a3dfb8332d801c369ee20105e8081e79c9c",
+    # PR 66 (Ling-3.0-flash-VL), its own: the jaxpr of ``kernels/kda.py``'s
+    # call, forward and the five cotangents, 4 heads of 128 at three chunks
+    # (the vector decay's sub-blocks, the running sum and the transposed
+    # states are in that text), and the parameter tree of test_ling.py's
+    # tiny model (``kda`` with its seven leaves in two layers, latent
+    # attention's six without ``q_a`` and with ``gate`` in one, a dense
+    # layer and two sparse ones).
+    "kda_kernel_call": "29fc1d30455686957d9a1974d43294ab93205badde5e56cb8481de6f39abfd41",
+    "tree/ling-3.0-flash-vl": "eb5954986052f62bc84572f081895d3d16a82dce",
 }
 
 
@@ -311,6 +320,25 @@ def test_the_gated_delta_kernels_call_traces_to_the_pinned_text():
     assert digest(str(jaxpr)) == PINS["gated_delta_kernel_call"]
 
 
+def test_the_kda_kernels_call_traces_to_the_pinned_text():
+    from horovod_tpu.kernels import kda
+
+    wide = shape((1, 3 * kda.CHUNK, 4, 128), jnp.bfloat16)
+    decays = shape((1, 3 * kda.CHUNK, 4, 128), jnp.float32)
+    head = shape((1, 3 * kda.CHUNK, 4), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda(q, k, v, g, beta, interpret=True)
+                       .astype(jnp.float32))
+
+    jaxpr = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
+        wide, wide, wide, decays, head).jaxpr
+    names = [eqn.params["name"]
+             for eqn in test_sdar.equations_of(jaxpr, "pallas_call")]
+    assert names == [kda.FWD_NAME, kda.BWD_NAME]
+    assert digest(str(jaxpr)) == PINS["kda_kernel_call"]
+
+
 def test_router_input_the_rows_themselves_and_silu_are_the_parents_program():
     """SmallThinker's two options at their defaults, spelled out, lower to
     what the parent lowered to, whole layer and share alike."""
@@ -459,6 +487,7 @@ def small_presets():
         "joyai-llm-flash": test_joyai.tiny_model()[0].cfg,
         "qwen3-next-80b-a3b": test_qwen3_next.tiny_model()[0].cfg,
         "xing4.0-29b-a4b": test_xing.tiny_config().model.cfg,
+        "ling-3.0-flash-vl": test_ling.tiny_config().model.cfg,
         "lfm2-8b-a1b": t.lfm2_8b_a1b_config(
             **{**share, "num_layers": 3}, head_width=16, d_ff_dense=96,
             experts_per_token=2, experts_held=(1, 6),

@@ -253,7 +253,8 @@ def test_the_counter_reads_both_kernels_by_their_names():
     for op in ("hvd_mla_operands_fwd", "splash_mha_fwd_out_lse", "fusion.12"):
         assert not re.search(reader["pattern"], op)
     with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
-        entry = json.load(f)["per_layer"][-1]
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == name]
     assert entry == {"name": name, "unit": "count/step", "better": "higher",
                      "source": "device_trace", "layer": "kernel",
                      "moves": "samples_per_s_chip",

@@ -16,6 +16,17 @@ import pytest
 from tests.helpers import run_distributed
 
 
+def _shut_down(hvd):
+    """Shut the runtime this process started down and make its state new:
+    ``init()`` on a state whose loop has drained is a no-op
+    (``core/state.py::initialize``), and the next file in this process may
+    need a runtime of its own."""
+    from horovod_tpu.core import state
+
+    hvd.shutdown()
+    state.reset_global_state()
+
+
 def test_tf_allreduce_gradient_two_ranks():
     """d/dx of sum(allreduce(x, Sum)) == size (each rank's x contributes to
     every rank's output once; custom gradient = allreduce of upstream)."""
@@ -190,7 +201,7 @@ def test_torch_sync_bn_single_process_matches_plain_bn():
     out_b.sum().backward()
     assert torch.allclose(x.grad, x2.grad, atol=1e-5)
     assert torch.allclose(sbn.running_var, bn.running_var, atol=1e-5)
-    hvdt.shutdown()
+    _shut_down(hvdt)
 
 
 def test_tf_sync_bn_multiple_instances():
@@ -213,4 +224,4 @@ def test_tf_sync_bn_multiple_instances():
     assert out.shape == (6, 4)
     names = [l.name for l in model.layers if "batch" in l.name.lower()]
     assert len(set(names)) == 2, names
-    hvdtf.shutdown()
+    _shut_down(hvdtf)

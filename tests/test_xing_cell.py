@@ -97,7 +97,7 @@ def test_benchmark_json_names_the_cell_and_its_files():
     assert len(cell["why"]) <= 200 and len(config[0]["why"]) <= 200
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
-        assert CELL in metrics[name]["workloads"][-2:], name
+        assert CELL in metrics[name]["workloads"], name
     # Appended together (PR 58); PR 59's two kernel metrics and PR 63's three
     # stand behind.
     names = [m["name"] for m in bench["per_layer"]]
