@@ -398,6 +398,16 @@ def _rule_bwd(interpret, kept, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+def _by_step(beta):
+    """``beta [batch, s, heads]`` as the kernels take it: fp32, ``[batch,
+    steps, s, heads a step]``."""
+    batch, s, heads = beta.shape
+    a_step = heads_a_step(heads)
+    return beta.astype(jnp.float32) \
+        .reshape(batch, s, heads // a_step, a_step) \
+        .transpose(0, 2, 1, 3)
+
+
 def kda(q, k, v, g, beta, *, interpret: bool = False):
     """``o_t = S_t^T q_t`` with ``S`` Kimi Delta Attention's state a head,
     zero before each sequence: ``q``, ``k [batch, s, heads, K]`` (``k`` of
@@ -418,12 +428,29 @@ def kda(q, k, v, g, beta, *, interpret: bool = False):
     if not ((interpret or jax.default_backend() == "tpu") and same
             and takes(s, heads, dk, dv, v.dtype)):
         return chunked(q, k, v, g, beta)
-    a_step = heads_a_step(heads)
-    beta = beta.astype(jnp.float32) \
-        .reshape(batch, s, heads // a_step, a_step) \
-        .transpose(0, 2, 1, 3)                        # [b, steps, s, heads]
+    beta = _by_step(beta)
     o = _rule(q.reshape(batch, s, heads * dk), k.reshape(batch, s, heads * dk),
               v.reshape(batch, s, heads * dv),
               g.astype(jnp.float32).reshape(batch, s, heads * dk), beta,
               interpret)
     return o.reshape(v.shape)
+
+
+def kda_flat(q, k, v, g, beta, *, interpret: bool = False):
+    """:func:`kda` on the layout its kernels take and give: ``q``, ``k``,
+    ``v``, ``g [batch, s, heads * 128]``, a head a lane group, and ``beta
+    [batch, s, heads]``; ``o`` as ``v``.  No head-major array is made where
+    the kernels run (``kernels/head_rows.py`` writes ``q``, ``k`` and ``g``
+    so and reads ``o`` so); elsewhere :func:`kda` on the heads as an axis."""
+    batch, s, heads = beta.shape
+    if not (q.shape == k.shape == v.shape == g.shape
+            == (batch, s, heads * _LANES)):
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}, "
+                         f"g {g.shape}, beta {beta.shape}")
+    if not ((interpret or jax.default_backend() == "tpu")
+            and q.dtype == k.dtype == v.dtype
+            and takes(s, heads, _LANES, _LANES, v.dtype)):
+        by_head = (batch, s, heads, _LANES)
+        return kda(*(t.reshape(by_head) for t in (q, k, v, g)), beta) \
+            .reshape(v.shape)
+    return _rule(q, k, v, g.astype(jnp.float32), _by_step(beta), interpret)

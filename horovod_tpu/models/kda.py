@@ -25,7 +25,11 @@ lets ``kernels/kda.py`` split a chunk's decays between two operands),
 The rule runs in its chunked form (``kernels/kda.py``: its kernels on a TPU,
 ``jax.numpy`` elsewhere), and so does the convolution
 (``kernels/causal_conv.py``, whose kernels read ``[q ; k ; v]`` where it lies
-in ``W_in``'s output).
+in ``W_in``'s output).  On a TPU in bf16 what stands each side of the rule,
+the decay gate with the two L2 norms and the gated norm a head, runs as
+``kernels/head_rows.py``'s row kernels on the flat ``[b, s, heads * 128]``
+layout, which the rule's kernels take and give: no array with the heads as an
+axis stands between the convolution and ``out_proj``.
 
 **The columns' order.**  ``W_in``'s columns are all the heads' ``q``, then
 their ``k``, ``v``, ``f`` and ``z``, each head by head; the release keeps
@@ -46,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.timeline import scope
-from ..kernels import kda
+from ..kernels import head_rows, kda
 from ..kernels.causal_conv import causal_conv
 from .gated_delta import _l2_normed
 from .transformer import TransformerConfig, _dense
@@ -84,26 +88,42 @@ class KimiDeltaAttention(nn.Module):
                           (3 * inner, cfg.conv_taps), f32)
         with scope("kda.conv"):
             qkv = causal_conv(qkv, taps, within=(proj, 0))
-        q, k, v = (t.reshape(b, s, h, dk) for t in jnp.split(qkv, 3, axis=-1))
         a_log = self.param("A_log", _a_log_init, (h,), f32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32)
-        with scope("kda.gate"):
-            beta = jax.nn.sigmoid(beta.astype(f32))
-            g = kda.LOWER_BOUND * jax.nn.sigmoid(
-                jnp.exp(a_log)[:, None]
-                * (f.astype(f32) + dt_bias).reshape(b, s, h, dk))
-            q = _l2_normed(q, dk ** -0.5)
-            k = _l2_normed(k)
-        with scope("kda.rule"):
-            o = kda.kda(q, k, v, g, beta)
         scale = self.param("norm", nn.initializers.ones, (dv,), f32)
-        with scope("kda.norm"):
-            o = o.astype(f32)
-            o = o * jax.lax.rsqrt(
-                jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
-            y = (o * scale * jax.nn.sigmoid(z.astype(f32))
-                 .reshape(b, s, h, dv)).astype(cfg.dtype) \
-                .reshape(b, s, inner)
+        if head_rows.takes(s, dk, qkv.dtype):
+            # A head is a lane group of the flat row from the convolution to
+            # ``out_proj``: the kernels read q, k, f and z where they lie.
+            with scope("kda.gate"):
+                beta = jax.nn.sigmoid(beta.astype(f32))
+                q, k, g = head_rows.gate(
+                    qkv, proj, jnp.repeat(jnp.exp(a_log), dk)[None],
+                    dt_bias[None], f_at=3 * inner, scale=dk ** -0.5,
+                    lower=kda.LOWER_BOUND)
+            with scope("kda.rule"):
+                o = kda.kda_flat(q, k, qkv[..., 2 * inner:], g, beta)
+            with scope("kda.norm"):
+                y = head_rows.norm(o, proj, jnp.tile(scale, h)[None],
+                                   z_at=4 * inner, eps=cfg.norm_eps)
+        else:
+            q, k, v = (t.reshape(b, s, h, dk)
+                       for t in jnp.split(qkv, 3, axis=-1))
+            with scope("kda.gate"):
+                beta = jax.nn.sigmoid(beta.astype(f32))
+                g = kda.LOWER_BOUND * jax.nn.sigmoid(
+                    jnp.exp(a_log)[:, None]
+                    * (f.astype(f32) + dt_bias).reshape(b, s, h, dk))
+                q = _l2_normed(q, dk ** -0.5)
+                k = _l2_normed(k)
+            with scope("kda.rule"):
+                o = kda.kda(q, k, v, g, beta)
+            with scope("kda.norm"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+                y = (o * scale * jax.nn.sigmoid(z.astype(f32))
+                     .reshape(b, s, h, dv)).astype(cfg.dtype) \
+                    .reshape(b, s, inner)
         with scope("kda.out"):
             return _dense(cfg, cfg.d_model, (cfg.model_axis, None),
                           "out_proj")(y)

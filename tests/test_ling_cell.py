@@ -107,7 +107,9 @@ def test_benchmark_json_names_the_cell_and_its_files():
     for name in SHARED_METRICS:
         assert metrics[name]["workloads"][-1] == CELL, name
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-2:] == list(NEW_METRICS)
+    # PR 67 appended the row kernels' two (tests/test_head_rows.py).
+    assert names[-4:] == list(NEW_METRICS) + ["head_rows_ms_step",
+                                              "head_rows_calls_step"]
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"

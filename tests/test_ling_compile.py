@@ -117,8 +117,15 @@ def test_lings_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     assert kernels == {"hvd_kda_fwd", "hvd_kda_bwd", "hvd_causal_conv_fwd",
                        "hvd_causal_conv_bwd", "splash_mha_fwd_out_lse",
                        "splash_mha_dkv_dq", "hvd_mla_operands_fwd",
-                       "hvd_mla_operands_bwd", "hvd_rows_to_tokens"}, kernels
+                       "hvd_mla_operands_bwd", "hvd_rows_to_tokens",
+                       "hvd_head_rows_gate_fwd", "hvd_head_rows_gate_bwd",
+                       "hvd_head_rows_norm_fwd",
+                       "hvd_head_rows_norm_bwd"}, kernels
     for kernel, calls in (("hvd_kda_fwd", 12), ("hvd_kda_bwd", 6),
+                          ("hvd_head_rows_gate_fwd", 12),
+                          ("hvd_head_rows_gate_bwd", 6),
+                          ("hvd_head_rows_norm_fwd", 12),
+                          ("hvd_head_rows_norm_bwd", 6),
                           ("hvd_causal_conv_fwd", 12),
                           ("hvd_causal_conv_bwd", 6),
                           ("splash_mha_fwd_out_lse", 2),
@@ -127,6 +134,14 @@ def test_lings_step_compiles_and_fits_the_chip(topo, no_compile_cache,
                           ("hvd_mla_operands_bwd", 1)):
         assert len(re.findall(rf"%{kernel}[.\d]* =", text)) == calls, kernel
     assert "32,8192,8192" not in text            # the scores, any layout
+    # Between the convolution and ``out_proj`` a head is a lane group of the
+    # flat row (``kernels/head_rows.py``): no instruction of the six mixers
+    # holds the heads as an axis, in any dtype.  (The latent-attention
+    # layer's own per-head arrays lie under ``attn.`` scopes.)
+    head_major = [line for line in text.splitlines()
+                  if "8192,32,128" in line
+                  and re.search(r'op_name="[^"]*hvd\.kda\.', line)]
+    assert not head_major, head_major[:3]
     assert ".remat" not in text                  # nothing the compiler's own
     mem = compiled.memory_analysis()
     gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -142,9 +157,11 @@ def test_lings_step_compiles_and_fits_the_chip(topo, no_compile_cache,
     assert 11.0 < gib < 15.75, gib
     # The file states what the compiler counted when the configuration was
     # sized.  A program that changed since may take less and never more: the
-    # file is the benchmark's, which only a benchmark PR restates.
+    # file is the benchmark's, which only a benchmark PR restates.  PR 67
+    # took the fp32 rows and the head-major copies around the rule away:
+    # 13.80 GiB, so the lower side alone is a GiB wide.
     with open(os.path.join(REPO_ROOT, "chip_bench/configs",
                            "ling-3.0-flash-vl.json")) as f:
         stated = float(re.search(r"takes ([\d.]+) GiB at one sequence of 8192",
                                  json.load(f)["fit"]).group(1))
-    assert stated - 0.5 < gib < stated + 0.005, (gib, stated)
+    assert stated - 1.0 < gib < stated + 0.005, (gib, stated)

@@ -157,6 +157,12 @@ PINS = {
     # layer and two sparse ones).
     "kda_kernel_call": "29fc1d30455686957d9a1974d43294ab93205badde5e56cb8481de6f39abfd41",
     "tree/ling-3.0-flash-vl": "eb5954986052f62bc84572f081895d3d16a82dce",
+    # PR 67 moved the KDA mixer's rows each side of the rule into
+    # ``kernels/head_rows.py`` where it takes a layer (a TPU, bf16, heads of
+    # 128); test_ling.py's tiny model in float32, loss and gradients, is the
+    # text of PR 67's parent (6d9a6ff): what the kernels refuse runs the
+    # lines it ran.
+    "float32/ling_tiny_step": "ae7c21f91a6c2fcade8fdc77200dbcb2cb10300f1d9881ba3f0969275f793d68",
 }
 
 
@@ -375,6 +381,11 @@ def float32_program(which):
         aux = jax.eval_shape(lambda: test_lfm2.counters(sizes))
         return step_program(test_lfm2.program_loss(model, sizes),
                             abstract(model, tokens), aux, {"tokens": tokens})
+    if which == "ling_tiny_step":
+        config = test_ling.tiny_config(jnp.float32)
+        key = shape((2,), jnp.uint32)
+        return step_program(config.loss, *jax.eval_shape(config.init, key),
+                            jax.eval_shape(config.make_batch, key))
     tiny = {"joyai_tiny_step": test_joyai,
             "qwen3_next_tiny_step": test_qwen3_next}.get(which, test_nemotron)
     model, sizes = tiny.tiny_model(jnp.float32)
