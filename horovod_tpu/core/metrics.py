@@ -272,6 +272,15 @@ CATALOG: Dict[str, Tuple[str, str]] = {
         "gauge", "chunks of Kimi Delta Attention's rule a step runs, one a "
                  "head, chunk of positions and KDA layer, from the shapes "
                  "(models/transformer.py::publish_kda)"),
+    "indexer_pairs_scored_per_step": (
+        "gauge", "(query, key) pairs a step's indexers score, the causal "
+                 "pairs of every sequence and layer with an indexer, from "
+                 "the shapes (models/transformer.py::publish_indexer)"),
+    "attention_pairs_chosen_per_step": (
+        "gauge", "(query, key) pairs the chosen sets of a step hold, topk a "
+                 "query and every causal key below topk positions, summed "
+                 "over the layers with an indexer, every query head counted "
+                 "once, from the shapes (publish_indexer)"),
     "driver_tick_seconds": (
         "histogram", "elastic driver discovery-tick duration (lease scan "
                      "+ host discovery + any epoch transition it caused)"),

@@ -545,6 +545,8 @@ SCOPES = (
     "resnet.stage4", "resnet.head", "bn",
     "hc.coeff", "hc.sinkhorn", "hc.pre", "hc.post",
     "kda.proj", "kda.conv", "kda.gate", "kda.rule", "kda.norm", "kda.out",
+    "attn.sparse", "indexer.proj", "indexer.scores", "indexer.choose",
+    "indexer.target", "indexer.loss",
 )
 
 

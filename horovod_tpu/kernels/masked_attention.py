@@ -19,15 +19,18 @@ served without repeating them.  A rule is a small hashable object with
 
 The rules: :class:`Causal` (key <= query; ``hvd.attn.causal``),
 :class:`Window` (causal, and the key inside the last ``size`` positions:
-``hvd.attn.window``) and ``kernels/blockdiff_attention.py``'s
-``BlockDiffusion``.  At 16,384 positions and tiles of 1024 a causal layer
-visits 136 of 256 tiles and a window of 4096 visits 70; a window narrower
-than a tile gets tiles of 512 (:func:`_tiles`: at 8192 positions a window of
-512 visits 31 of them, half of each allowed).  :func:`attention` is
-the kernels, :func:`einsum` the same mask through a grouped einsum (off the
-TPU, and for shapes the kernels do not take).  On the device's op line the
-two kernels are :data:`FWD_NAME` and ``splash_mha_dkv_dq``
-(:data:`OP_LINE_NAMES`) whatever the rule.
+``hvd.attn.window``), ``kernels/blockdiff_attention.py``'s
+``BlockDiffusion``, and one whose mask is data, :class:`Sparse` (causal, and
+the key in the query's chosen set, which a learned indexer made in the same
+step: ``hvd.attn.sparse``; the sets are one more operand of both kernels, a
+bit a pair, and every causal tile is visited and masked).  At 16,384 positions
+and tiles of 1024 a causal layer visits 136 of 256 tiles and a window of 4096
+visits 70; a window narrower than a tile gets tiles of 512 (:func:`_tiles`:
+at 8192 positions a window of 512 visits 31 of them, half of each allowed).
+:func:`attention` is the kernels, :func:`einsum` the same mask through a
+grouped einsum (off the TPU, and for shapes the kernels do not take).  On the
+device's op line the two kernels are :data:`FWD_NAME` and
+``splash_mha_dkv_dq`` (:data:`OP_LINE_NAMES`) whatever the rule.
 
 The forward lays a tile out queries on the rows and keys on the lanes
 (``s = q k^T``), so that both of its products stream a tile's 1024 queries
@@ -142,6 +145,35 @@ class Window:
         return seq_len % BLOCK == 0
 
 
+@dataclasses.dataclass(frozen=True)
+class Sparse:
+    """Query i sees key j iff ``j <= i`` and j is one of the ``topk`` keys
+    chosen for i (all of them where ``i < topk``).  The sets are data, made
+    on the device in the same step (``models/indexer.py``), and reach the
+    kernels and the einsum as ``masked_attention_bwd.pack_chosen``'s words;
+    a set holds no key behind its query, so the words are the whole mask.
+    ``allowed`` is what the table of tiles is made from: every causal tile
+    may hold a chosen pair, and each is masked (``data``)."""
+
+    topk: int
+    scope = "hvd.attn.sparse"
+    data = True
+
+    def __post_init__(self):
+        if self.topk < 1:
+            raise ValueError(f"{self.topk} keys chosen a query")
+
+    def allowed(self, q_ids, kv_ids, seq_len=None):
+        return kv_ids <= q_ids
+
+    def allowed_pairs(self, seq_len: int) -> int:
+        full = min(seq_len, self.topk)
+        return full * (full + 1) // 2 + (seq_len - full) * self.topk
+
+    def takes(self, seq_len: int) -> bool:
+        return seq_len % BLOCK == 0
+
+
 def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
     """Whether the kernel takes this shape under ``rule``; otherwise, and off
     the TPU, the same mask goes through :func:`einsum`.  Heads of 64 go in
@@ -156,9 +188,13 @@ def takes(rule, seq_len: int, head_dim: int, head_dim_v=None) -> bool:
 
 
 def _fwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
-                out_ref, lse_ref, m_ref, l_ref, acc_ref, *, rule,
-                seq_len: int, block_kv_compute: int):
+                *rest, rule, seq_len: int, block_kv_compute: int):
     import jax.experimental.pallas as pl
+
+    data = masked_attention_bwd.is_data(rule)
+    # A rule that is data brings the chosen sets' words as the last operand.
+    words_ref, rest = (rest[0], rest[1:]) if data else (None, rest)
+    out_ref, lse_ref, m_ref, l_ref, acc_ref = rest
 
     PARTIAL, FIRST, LAST = (masked_attention_bwd.PARTIAL,
                             masked_attention_bwd.FIRST,
@@ -188,7 +224,11 @@ def _fwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
             k, v = k_ref[rows, :], v_ref[rows, :]
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-            if masked:
+            if masked and data:
+                s = jnp.where(masked_attention_bwd.chosen(
+                    words_ref[...], kv_start + c * block_kv_compute,
+                    block_kv_compute, 1), s, mask_value)
+            elif masked:
                 # A column of queries against a row of keys: what a rule
                 # computes a position it computes on these.
                 q_ids = q_start + lax.broadcasted_iota(jnp.int32,
@@ -225,13 +265,14 @@ def _fwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "tiles", "interpret"))
-def out_lse(q, k, v, *, rule, tiles, interpret: bool = False):
+def out_lse(q, k, v, words=None, *, rule, tiles, interpret: bool = False):
     """Attention under ``rule`` and its rows' log-sum-exp: ``q`` (scaled)
     ``[b, h, s, d]``, ``k`` ``[b, h_kv, s, d]`` and ``v`` ``[b, h_kv, s, dv]``
     give ``out [b, h, s, dv]`` in ``q``'s dtype and ``lse [b, h, s]`` fp32;
-    ``tiles`` is (queries, keys, keys multiplied at a time).  Jitted: traced
-    once a process and lowered once a program, whatever the number of
-    layers."""
+    ``tiles`` is (queries, keys, keys multiplied at a time); ``words``: the
+    chosen sets of a rule that is data (``pack_chosen``'s, ``[b, s, groups *
+    128]``).  Jitted: traced once a process and lowered once a program,
+    whatever the number of layers."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -245,6 +286,7 @@ def out_lse(q, k, v, *, rule, tiles, interpret: bool = False):
     with jax.ensure_compile_time_eval():
         table = tuple(jnp.asarray(a) for a in masked_attention_bwd.tile_table(
             rule, s, block_q, block_kv))
+    data = () if words is None else (words,)
 
     def of_query(n, i, g, t, q_tile, kv_tile, flags):
         return n, i * group + g, q_tile[t], 0
@@ -263,7 +305,9 @@ def out_lse(q, k, v, *, rule, tiles, interpret: bool = False):
             grid=(b, h_kv, group, table[0].shape[0]),
             in_specs=[pl.BlockSpec((None, None, block_q, d), of_query),
                       pl.BlockSpec((None, None, block_kv, d), of_key),
-                      pl.BlockSpec((None, None, block_kv, dv), of_key)],
+                      pl.BlockSpec((None, None, block_kv, dv), of_key)]
+            + [masked_attention_bwd.words_block(block_q, block_kv)
+               for _ in data],
             out_specs=[pl.BlockSpec((None, None, block_q, dv), of_query),
                        pl.BlockSpec((None, None, 1, block_q), of_query_row)],
             scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -275,7 +319,7 @@ def out_lse(q, k, v, *, rule, tiles, interpret: bool = False):
             dimension_semantics=("parallel",) * 3 + ("arbitrary",),
             vmem_limit_bytes=_FWD_VMEM_LIMIT),
         name=FWD_NAME, interpret=interpret,
-    )(*table, q, k, v)
+    )(*table, q, k, v, *data)
     return out, lse[:, :, 0, :]
 
 
@@ -285,21 +329,22 @@ def _attend(q, k, v, rule, interpret):
     return _attend_fwd(q, k, v, rule, interpret)[0]
 
 
-def _attend_fwd(q, k, v, rule, interpret):
+def _attend_fwd(q, k, v, rule, interpret, words=None):
     with scope(rule.scope.removeprefix("hvd.")):
-        out, logsumexp = out_lse(q, k, v, rule=rule, tiles=_tiles(rule, q)[0],
+        out, logsumexp = out_lse(q, k, v, words, rule=rule,
+                                 tiles=_tiles(rule, q)[0],
                                  interpret=interpret)
-    return out, (q, k, v, out, logsumexp)
+    return out, (words, q, k, v, out, logsumexp)
 
 
 def _attend_bwd(rule, interpret, kept, do):
-    q, k, v, out, logsumexp = kept
+    words, q, k, v, out, logsumexp = kept
     with scope(rule.scope.removeprefix("hvd.")):
         di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32),
                         do.astype(jnp.float32))
         return tuple(masked_attention_bwd.dq_dk_dv(
-            q, k, v, logsumexp, di, do, rule=rule, tiles=_tiles(rule, q)[1],
-            interpret=interpret))
+            q, k, v, logsumexp, di, do, words, rule=rule,
+            tiles=_tiles(rule, q)[1], interpret=interpret))
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
@@ -342,19 +387,27 @@ def attention_hsd(q, k, v, rule):
     return _attend(q, k, v, rule, False)
 
 
-def _probabilities(scores, rule, dtype):
-    """The softmax of ``scores [..., s, s]`` over the keys ``rule`` allows,
-    the mask from iota comparisons."""
+def _probabilities(scores, rule, dtype, words=None):
+    """The softmax of ``scores [b, ..., s, s]`` over the keys ``rule``
+    allows, the mask from iota comparisons, or from ``words [b, s, ...]``
+    under a rule that is data."""
     s = scores.shape[-1]
-    mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
-                        lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
-    scores = jnp.where(mask[(None,) * (scores.ndim - 2)], scores, -jnp.inf)
+    if words is not None:
+        mask = masked_attention_bwd.unpack_chosen(words, s)
+        mask = mask.reshape(mask.shape[:1] + (1,) * (scores.ndim - 3)
+                            + (s, s))
+    else:
+        mask = rule.allowed(lax.broadcasted_iota(jnp.int32, (s, s), 0),
+                            lax.broadcasted_iota(jnp.int32, (s, s), 1), s)
+        mask = mask[(None,) * (scores.ndim - 2)]
+    scores = jnp.where(mask, scores, -jnp.inf)
     return jax.nn.softmax(scores, axis=-1).astype(dtype)
 
 
-def einsum(q, k, v, rule, scale=None):
+def einsum(q, k, v, rule, scale=None, words=None):
     """:func:`attention` through the einsum, KV heads grouped, the mask from
-    iota comparisons: below the kernel's smallest shape, and off the TPU."""
+    iota comparisons (from ``words`` under a rule that is data): below the
+    kernel's smallest shape, and off the TPU."""
     b, s, h, dh = q.shape
     h_kv = k.shape[2]
     if scale is None:
@@ -364,7 +417,7 @@ def einsum(q, k, v, rule, scale=None):
         scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
                             preferred_element_type=jnp.float32) * scale
         return jnp.einsum("bngqk,bknd->bqngd",
-                          _probabilities(scores, rule, q.dtype), v) \
+                          _probabilities(scores, rule, q.dtype, words), v) \
             .reshape(b, s, h, v.shape[3])
 
 
@@ -406,3 +459,38 @@ def _tiles(rule, q):
         return NARROW_WINDOW_TILES, NARROW_WINDOW_TILES
     wide_float32 = q.dtype.itemsize > 2 and q.shape[-1] > 128
     return FWD_TILES_WIDE_FLOAT32 if wide_float32 else FWD_TILES, BWD_TILES
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attend_chosen(q, k, v, words, rule, interpret):
+    """:func:`_attend` under a rule that is data: ``words`` the chosen sets,
+    and the rows' log-sum-exp handed out beside the output."""
+    return _attend_chosen_fwd(q, k, v, words, rule, interpret)[0]
+
+
+def _attend_chosen_fwd(q, k, v, words, rule, interpret):
+    out, kept = _attend_fwd(q, k, v, rule, interpret, words)
+    return (out, kept[-1]), kept
+
+
+def _attend_chosen_bwd(rule, interpret, kept, cotangents):
+    # The log-sum-exp leaves as a constant (:func:`attention_lse_hsd`).
+    return *_attend_bwd(rule, interpret, kept, cotangents[0]), None
+
+
+_attend_chosen.defvjp(_attend_chosen_fwd, _attend_chosen_bwd)
+
+
+def attention_lse_hsd(q, k, v, rule, words, interpret: bool = False):
+    """:func:`attention_hsd` under a rule that is data (:class:`Sparse`):
+    ``words`` the chosen sets, ``pack_chosen``'s ``[b, s, groups * 128]``.
+    Returns ``(out [b, h, s, dv], lse [b, h, s])``, the second the rows'
+    log-sum-exp over their chosen keys in fp32 and cut from the graph: what
+    reads it (the indexer's target, ``models/indexer.py``) is a constant of
+    the step."""
+    _, _, s, d = q.shape
+    if not takes(rule, s, d, v.shape[3]):
+        raise ValueError(f"no kernel under {rule} for {s} positions, head "
+                         f"width {d} over values of {v.shape[3]}")
+    out, lse = _attend_chosen(q, k, v, words, rule, interpret)
+    return out, lax.stop_gradient(lse)

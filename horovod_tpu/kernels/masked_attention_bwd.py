@@ -19,7 +19,10 @@ memory while the grid walks every tile of every query head of the group**,
 and are rounded and written once a KV head: no partial sums exist, the group
 is folded without an XLA sum, and KV heads are never repeated.  Full tiles
 skip the mask; partial tiles compute it from ``rule.allowed`` on iotas plus
-the tile's offsets.
+the tile's offsets.  Under a rule whose mask is data (``rule.data``:
+``masked_attention.Sparse``) every tile is partial and its mask is one more
+operand, the chosen sets as bits (:func:`pack_chosen`), which :func:`chosen`
+reads a lane group of keys at a time.
 
 On the device's op line the kernel is :data:`NAME`, which
 ``masked_attention.OP_LINE_NAMES`` matches.
@@ -42,6 +45,13 @@ _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 # ``flags`` of :func:`tile_table`.
 PARTIAL, FIRST, LAST = 1, 2, 4
+
+# A chosen set as the kernels read it: one int32 word holds 32 keys that lie
+# a lane group apart, so that a word's bit ``j`` over 128 lanes is 128
+# neighbouring keys and a tile's mask is shifts and compares with no lane
+# moved.  A group is the keys one row of 128 words holds.
+_LANES = 128
+CHOSEN_GROUP = 32 * _LANES
 
 # The fast memory the kernel may take: the resident dk and dv (2 x 8 MiB at
 # 16,384 positions), their bf16 output blocks twice (2 x 2 x 4 MiB), the
@@ -79,7 +89,8 @@ def tile_table(rule, seq_len: int, block_q: int, block_kv: int):
         if not held.size:
             raise ValueError(f"{rule} allows query tile {i} no key at "
                              f"{seq_len} positions")
-        flag = np.where(every[held], 0, PARTIAL)
+        # A mask that is data may forbid a pair of any tile.
+        flag = np.where(every[held] & (not is_data(rule)), 0, PARTIAL)
         flag[0] |= FIRST
         flag[-1] |= LAST
         q_tiles.append(np.full(held.size, i))
@@ -90,10 +101,15 @@ def tile_table(rule, seq_len: int, block_q: int, block_kv: int):
 
 
 def _bwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
-                lse_ref, di_ref, do_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-                dk_acc, dv_acc, *, rule, seq_len: int, block_kv_compute: int):
+                lse_ref, di_ref, do_ref, *rest, rule, seq_len: int,
+                block_kv_compute: int):
     import jax.experimental.pallas as pl
 
+    data = is_data(rule)
+    # A rule that is data brings the chosen sets' words as the last operand
+    # and a scratch for their transposition as the last scratch.
+    words_ref, rest = (rest[0], rest[1:]) if data else (None, rest)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *words_t = rest
     block_q, block_kv = q_ref.shape[0], k_ref.shape[0]
     member, step = pl.program_id(2), pl.program_id(3)
     flags = flags_ref[step]
@@ -109,6 +125,13 @@ def _bwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
+    if data:
+        # Keys on the rows here: the words [queries, lanes] turned once a
+        # group of keys, not once a tile.
+        @pl.when((flags & FIRST != 0) | (kv_start % CHOSEN_GROUP == 0))
+        def _():
+            words_t[0][...] = words_ref[...].T
+
     def tile(masked: bool):
         q, do = q_ref[...], do_ref[...]
         lse, di = lse_ref[...], di_ref[...]                     # [1, bq]
@@ -118,7 +141,11 @@ def _bwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
             k, v = k_ref[rows, :], v_ref[rows, :]
             s = lax.dot_general(k, q, nt,
                                 preferred_element_type=jnp.float32)
-            if masked:
+            if masked and data:
+                s = jnp.where(chosen(words_t[0][...],
+                                     kv_start + c * block_kv_compute,
+                                     block_kv_compute, 0), s, _MASK_VALUE)
+            elif masked:
                 # A column of keys against a row of queries: what a rule
                 # computes a position it computes on these.
                 kv_ids = kv_start + c * block_kv_compute \
@@ -158,7 +185,8 @@ def _bwd_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "tiles", "interpret"))
-def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
+def dq_dk_dv(q, k, v, lse, di, do, words=None, *, rule, tiles,
+             interpret: bool = False):
     """The three gradients of attention under ``rule``: ``q`` (scaled)
     ``[b, h, s, d]`` and ``k`` ``[b, h_kv, s, d]``, ``v`` ``[b, h_kv, s, dv]``
     and ``do`` ``[b, h, s, dv]`` (``dv`` is ``d`` but for latent attention,
@@ -166,8 +194,9 @@ def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
     and ``k`` contract or produce ``d``, the two with ``v`` and ``do``
     ``dv``), ``lse`` (the rows' log-sum-exp) and ``di`` (``sum(out * do)`` a
     row) fp32 ``[b, h, s]``; ``tiles`` is (queries, keys, keys multiplied at
-    a time).  Jitted: traced once a process and lowered once a program,
-    whatever the number of layers."""
+    a time); ``words``: the chosen sets of a rule that is data
+    (:func:`pack_chosen`).  Jitted: traced once a process and lowered once a
+    program, whatever the number of layers."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -181,6 +210,7 @@ def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
     with jax.ensure_compile_time_eval():
         table = tuple(jnp.asarray(a)
                       for a in tile_table(rule, s, block_q, block_kv))
+    data = () if words is None else (words,)
 
     def of_query(n, i, g, t, q_tile, kv_tile, flags):
         return n, i * group + g, q_tile[t], 0
@@ -209,11 +239,13 @@ def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
             num_scalar_prefetch=3,
             grid=(b, h_kv, group, table[0].shape[0]),
             in_specs=[q_block, k_block, v_block, row_block, row_block,
-                      do_block],
+                      do_block] + [words_block(block_q, block_kv)
+                                   for _ in data],
             out_specs=[q_block, whole_k, whole_v],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                             pltpu.VMEM((s, d), jnp.float32),
-                            pltpu.VMEM((s, dv), jnp.float32)]),
+                            pltpu.VMEM((s, dv), jnp.float32)]
+            + [pltpu.VMEM((_LANES, block_q), jnp.int32) for _ in data]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -221,4 +253,63 @@ def dq_dk_dv(q, k, v, lse, di, do, *, rule, tiles, interpret: bool = False):
             dimension_semantics=("arbitrary",) * 4,
             vmem_limit_bytes=_VMEM_LIMIT),
         name=NAME, interpret=interpret,
-    )(*table, q, k, v, lse[:, :, None, :], di[:, :, None, :], do)
+    )(*table, q, k, v, lse[:, :, None, :], di[:, :, None, :], do, *data)
+
+
+def is_data(rule) -> bool:
+    """Whether ``rule``'s mask is an operand (``masked_attention.Sparse``)
+    and not a function of two positions alone."""
+    return getattr(rule, "data", False)
+
+
+def pack_chosen(mask):
+    """A chosen set ``mask [..., queries, keys]`` (boolean) as the kernels
+    read it, int32 ``[..., queries, groups * 128]``: the keys filled up to
+    whole groups of :data:`CHOSEN_GROUP`, key ``g * 4096 + j * 128 + lane``
+    of a query the bit ``j`` of its word ``g * 128 + lane``."""
+    *lead, s = mask.shape
+    groups = -(-s // CHOSEN_GROUP)
+    mask = jnp.pad(mask, [(0, 0)] * len(lead)
+                   + [(0, groups * CHOSEN_GROUP - s)])
+    bits = mask.reshape(*lead, groups, 32, _LANES).astype(jnp.uint32) \
+        << jnp.arange(32, dtype=jnp.uint32)[:, None]
+    return lax.bitcast_convert_type(
+        jnp.sum(bits, axis=-2, dtype=jnp.uint32), jnp.int32) \
+        .reshape(*lead, groups * _LANES)
+
+
+def unpack_chosen(words, seq_len: int):
+    """:func:`pack_chosen` undone: ``[..., queries, seq_len]`` boolean."""
+    *lead, n = words.shape
+    bits = (words.reshape(*lead, n // _LANES, 1, _LANES)
+            >> jnp.arange(32, dtype=jnp.int32)[:, None]) & 1
+    return bits.reshape(*lead, n * 32)[..., :seq_len] != 0
+
+
+def chosen(words, first_key, keys: int, axis: int):
+    """Inside a kernel: the mask of ``keys`` neighbouring keys from
+    ``first_key`` on (both whole lane groups, inside one group of
+    :data:`CHOSEN_GROUP`) out of the words of a tile's queries, ``[queries,
+    128]`` with the keys to go along ``axis`` 1 or turned ``[128, queries]``
+    with the keys along 0: one shift and compare a lane group."""
+    first_bit = first_key % CHOSEN_GROUP // _LANES
+    return jnp.concatenate(
+        [(words >> (first_bit + j)) & 1 for j in range(keys // _LANES)],
+        axis=axis) != 0
+
+
+def words_block(block_q: int, block_kv: int):
+    """The block of :func:`pack_chosen`'s words ``[b, s, groups * 128]`` that
+    holds a tile's mask under the kernels' grid ``(sequence, KV head, query
+    head of its group, tile)``: the tile's queries, their words of the
+    tile's group of keys.  The index is the same for the tiles of one group
+    and for every head, so a block is fetched once a group."""
+    import jax.experimental.pallas as pl
+
+    if block_kv % _LANES or CHOSEN_GROUP % block_kv:
+        raise ValueError(f"a tile of {block_kv} keys does not divide a group "
+                         f"of {CHOSEN_GROUP} by lane groups")
+    return pl.BlockSpec(
+        (None, block_q, _LANES),
+        lambda n, i, g, t, q_tile, kv_tile, flags:
+        (n, q_tile[t], kv_tile[t] * block_kv // CHOSEN_GROUP))

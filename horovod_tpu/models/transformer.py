@@ -1,6 +1,6 @@
 """Transformer encoder/decoder — BERT-large, GPT, OLMoE, SDAR, SmallThinker,
 LFM2, Nemotron-H, JoyAI-LLM-Flash, Qwen3-Next, Granite-4.0-H, Xing4.0,
-Laguna and Ling presets.
+Laguna, Ling and Keye-VL-2.0 presets.
 
 Targets the reference's BERT-large Adasum pretraining config (BASELINE.md
 benchmark 4) and serves as the long-context flagship.  TPU-first choices:
@@ -78,7 +78,13 @@ benchmark 4) and serves as the long-context flagship.  TPU-first choices:
   (``kernels/rope_operands.py``: turned, q scaled, written where the
   attention kernels read them; chosen by ``rope_operands.takes`` from the
   backend, the dtype, the head's width and the rule, never by a model);
-  everything else is :func:`_rope` on the rows.
+  everything else is :func:`_rope` on the rows;
+- Keye-VL-2.0-30B-A3B's language model ``keye_vl_2_0_30b_a3b_config()``:
+  grouped-query attention over the ``indexer_topk`` keys a query that a
+  learned indexer chooses (``models/indexer.py``, DeepSeek Sparse Attention's
+  lightning indexer, loaded where a layer that has one is built; the mask is
+  data, ``kernels/masked_attention.py::Sparse``), the indexer's own loss
+  sown into the ``indexer`` collection (``indexer.indexer_loss``).
 """
 
 from __future__ import annotations
@@ -347,6 +353,16 @@ class TransformerConfig:
     yarn_beta_slow: float = 1.0
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 0.0
+    # A learned indexer in front of every attention layer (models/indexer.py,
+    # DeepSeek Sparse Attention's; Keye-VL-2.0's ``sa_config``), where
+    # indexer_topk is set: indexer_heads heads of indexer_head_dim on one
+    # shared key score every causal pair, and a query attends to the
+    # indexer_topk keys of its largest scores (all of them below that many
+    # positions).  The indexer's loss leaves through the ``indexer``
+    # collection.  0: no indexer, every causal key.
+    indexer_heads: int = 0
+    indexer_head_dim: int = 64
+    indexer_topk: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -666,6 +682,24 @@ def ling_3_0_flash_config(**overrides) -> TransformerConfig:
         layer_pattern=pattern), **overrides})
 
 
+def keye_vl_2_0_30b_a3b_config(**overrides) -> TransformerConfig:
+    """Keye-VL-2.0-30B-A3B's language model (Kwai-Keye/Keye-VL-2.0-30B-A3B
+    ``config.json``, ``KeyeVL2``): 48 layers of 128 experts of width 768, 8 a
+    token with renormalised weights, 32 query heads on 4 KV heads of 128,
+    per-head QK-norm, RoPE at 1e7 (M-RoPE's three position streams coincide
+    on text), RMSNorm, no biases, untied head, and ``sa_config``: an indexer
+    of 16 heads of 64 on one shared key chooses the 2048 keys a query
+    attends to."""
+    return TransformerConfig(**{**dict(
+        vocab_size=151936, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_width=128, d_model=2048, d_ff=768, max_len=262144, causal=True,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1e7,
+        qk_norm="head", use_bias=False, tie_embeddings=False, ffn="moe",
+        num_experts=128, experts_per_token=8, norm_topk_prob=True,
+        indexer_heads=16, indexer_head_dim=64, indexer_topk=2048),
+        **overrides})
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """For tests and the multichip dryrun: tiny shapes, same code paths."""
     return TransformerConfig(**{**dict(
@@ -871,7 +905,7 @@ class Attention(nn.Module):
             rotary = (cfg.rope_theta, positions, cfg.partial_rotary_factor,
                       self.kind.rotary)
             rule = _rule(cfg.causal, cfg.block_diffusion, self.kind.window,
-                         h_kv != h)
+                         h_kv != h, cfg.indexer_topk)
             turned = _turned_width(dh, cfg.partial_rotary_factor,
                                    self.kind.rotary)
             in_kernels_layout = rope_operands.takes(rule, s, dh, turned,
@@ -879,9 +913,12 @@ class Attention(nn.Module):
             if not in_kernels_layout:
                 with scope("attn.rope"):
                     q, k = _rope(q, *rotary), _rope(k, *rotary)
-        if (h_kv != h or cfg.block_diffusion or self.kind.window) \
-                and cfg.attention != "full":
-            raise ValueError("grouped KV heads, a window and the "
+        elif cfg.indexer_topk:
+            raise ValueError("an indexer turns its heads by the layer's "
+                             "rotary positions: it needs positions='rope'")
+        if (h_kv != h or cfg.block_diffusion or self.kind.window
+                or cfg.indexer_topk) and cfg.attention != "full":
+            raise ValueError("grouped KV heads, a window, an indexer and the "
                              "block-diffusion mask need attention='full'")
 
         if in_kernels_layout:
@@ -904,9 +941,21 @@ class Attention(nn.Module):
                     *tables, scale, half=half)
             with scope("attn.layout"):
                 v = v.transpose(0, 2, 1, 3)
-            out = masked_attention.attention_hsd(q, k, v, rule)
+            if cfg.indexer_topk:
+                out = self._over_chosen_keys(x, q, k, v, rule, positions)
+            else:
+                out = masked_attention.attention_hsd(q, k, v, rule)
             with scope("attn.layout"):
                 out = out.transpose(0, 2, 1, 3)
+        elif cfg.indexer_topk:
+            hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+            scale = dh ** -0.5 if cfg.attention_multiplier is None \
+                else cfg.attention_multiplier
+            with scope("attn.layout"):
+                q, k, v = hsd(q * jnp.asarray(scale, q.dtype)), hsd(k), hsd(v)
+            out = self._over_chosen_keys(x, q, k, v, rule, positions)
+            with scope("attn.layout"):
+                out = hsd(out)
         elif cfg.attention == "ring":
             from ..parallel.ring_attention import ring_attention
 
@@ -938,6 +987,34 @@ class Attention(nn.Module):
             # Row-parallel output projection closes the TP pair.
             return _dense(cfg, cfg.d_model, (cfg.model_axis, None),
                           "out")(out)
+
+    def _over_chosen_keys(self, x, q, k, v, rule, positions):
+        """Attention over the keys the layer's indexer chooses from the
+        normed input ``x``; ``q [b, h, s, d]`` (turned and scaled), ``k`` and
+        ``v [b, h_kv, s, d]`` in the kernels' layout, as is the result.  The
+        indexer's loss is sown into the ``indexer`` collection, the sets
+        (``pack_chosen``'s words) into ``chosen``."""
+        from . import indexer
+
+        cfg = self.cfg
+        s, dh = q.shape[2], q.shape[3]
+        q_i, k_i, w = indexer.Indexer(cfg, name="indexer")(
+            x, lambda t: _rope(t, cfg.rope_theta, positions))
+        words, lse_i = indexer.choose(q_i, k_i, w, cfg.indexer_topk)
+        if jax.default_backend() == "tpu" \
+                and masked_attention.takes(rule, s, dh):
+            out, lse = masked_attention.attention_lse_hsd(q, k, v, rule,
+                                                          words)
+        else:
+            hsd = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+            lse = None
+            out = hsd(masked_attention.einsum(hsd(q), hsd(k), hsd(v), rule,
+                                              1.0, words))
+        self.sow("indexer", "loss",
+                 indexer.loss(q_i, k_i, w, words, lse_i, q, k, lse))
+        # The sets themselves, for whoever asks (``mutable=["chosen"]``).
+        self.sow("chosen", "words", words)
+        return out
 
 
 # The pallas flash kernel's blocks, and the shortest sequence it takes.  On a
@@ -974,12 +1051,19 @@ def _flash_attention(q, k, v, causal: bool, dh: int, scale=None):
         return o.transpose(0, 2, 1, 3)
 
 
-def _rule(causal: bool, block_diffusion: int, window: int, grouped: bool):
+def _rule(causal: bool, block_diffusion: int, window: int, grouped: bool,
+          topk: int = 0):
     """The mask as a rule of ``kernels/masked_attention.py``: the
     block-diffusion rule with ``block_diffusion``, a block length, in place
-    of ``causal``; causal inside ``window`` positions; plain causal where KV
-    heads are ``grouped``; else None (one KV head a query head under
+    of ``causal``; causal inside ``window`` positions; causal inside the
+    ``topk`` keys an indexer chooses (a rule that is data); plain causal
+    where KV heads are ``grouped``; else None (one KV head a query head under
     ``causal``, no mask)."""
+    if topk:
+        if block_diffusion or window or not causal:
+            raise ValueError("an indexer chooses among a causal layer's "
+                             "keys: no window, no block diffusion")
+        return masked_attention.Sparse(topk)
     if block_diffusion:
         return BlockDiffusion(block_diffusion)
     if window:
@@ -1313,6 +1397,9 @@ def attention_pairs(cfg: TransformerConfig, seq_len: int,
     kind's, else the model's)."""
     if cfg.block_diffusion:
         everywhere = BlockDiffusion(cfg.block_diffusion).allowed_pairs(seq_len)
+    elif cfg.indexer_topk:
+        everywhere = masked_attention.Sparse(cfg.indexer_topk) \
+            .allowed_pairs(seq_len)
     elif cfg.causal:
         everywhere = masked_attention.Causal().allowed_pairs(seq_len)
     else:
@@ -1385,6 +1472,28 @@ def publish_kda(cfg: TransformerConfig, seq_len: int,
     chunks = layers * sequences * cfg.num_heads * -(-seq_len // CHUNK)
     metrics.set_gauge("kda_chunks_per_step", float(chunks))
     return chunks
+
+
+def publish_indexer(cfg: TransformerConfig, seq_len: int,
+                    sequences: int = 1) -> dict:
+    """Set the gauges ``indexer_pairs_scored_per_step`` (the causal pairs of
+    every sequence and layer with an indexer) and
+    ``attention_pairs_chosen_per_step`` (the pairs their chosen sets hold,
+    every query head counted once) for a step of ``sequences`` sequences of
+    ``seq_len`` positions, and return both; from the shapes alone, called
+    outside the step, beside :func:`publish_attention`."""
+    from ..core import metrics
+
+    layers = sequences * sum(cfg.layer_kind(i).mixer == "attention"
+                             for i in range(cfg.num_blocks)) \
+        if cfg.indexer_topk else 0
+    scored = layers * masked_attention.Causal().allowed_pairs(seq_len)
+    chosen = layers and layers * masked_attention.Sparse(
+        cfg.indexer_topk).allowed_pairs(seq_len)
+    metrics.set_gauge("indexer_pairs_scored_per_step", float(scored))
+    metrics.set_gauge("attention_pairs_chosen_per_step", float(chosen))
+    return {"indexer_pairs_scored_per_step": scored,
+            "attention_pairs_chosen_per_step": chosen}
 
 
 class Transformer(nn.Module):

@@ -340,10 +340,14 @@ def test_every_segment_of_every_program_is_in_the_vocabulary(scoped):
     assert found <= set(SCOPES), found - set(SCOPES)
     # What a CPU run can reach of it: everything but the kernels' scopes and
     # the sequence-parallel attentions (``attn.layout`` by latent
-    # attention's assembly of its keys, which is no kernel's).
+    # attention's assembly of its keys, which is no kernel's); the indexer's
+    # five are held by ``tests/test_keye.py`` on its own tiny model, which
+    # these tiny models do not pay for twice.
     assert set(SCOPES) - found == {
         "attn.flash", "attn.short", "attn.ring", "attn.ulysses",
-        "attn.causal", "attn.window", "attn.blockdiff"}
+        "attn.causal", "attn.window", "attn.blockdiff", "attn.sparse",
+        "indexer.proj", "indexer.scores", "indexer.choose", "indexer.target",
+        "indexer.loss"}
 
 
 def test_the_rules_of_the_attention_kernels_name_scopes_of_the_vocabulary():
@@ -352,9 +356,10 @@ def test_the_rules_of_the_attention_kernels_name_scopes_of_the_vocabulary():
     from horovod_tpu.kernels.blockdiff_attention import BlockDiffusion
 
     rules = (masked_attention.Causal(), masked_attention.Window(8),
-             BlockDiffusion(4))
+             BlockDiffusion(4), masked_attention.Sparse(8))
     assert [r.scope for r in rules] == [
-        "hvd.attn.causal", "hvd.attn.window", "hvd.attn.blockdiff"]
+        "hvd.attn.causal", "hvd.attn.window", "hvd.attn.blockdiff",
+        "hvd.attn.sparse"]
     assert all(r.scope.removeprefix("hvd.") in SCOPES for r in rules)
 
 

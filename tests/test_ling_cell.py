@@ -90,26 +90,28 @@ def test_benchmark_json_names_the_cell_and_its_files():
         bench = json.load(f)
     config = [c for c in bench["configs"] if c["name"] == "ling-3.0-flash-vl"]
     assert len(config) == 1 and config[0]["reduced"] == REDUCED
-    assert bench["configs"][-1] is config[0]         # appended, not inserted
     assert os.path.exists(os.path.join(REPO_ROOT, config[0]["file"]))
     for suffix in (".py", "_reference.py"):
         assert os.path.exists(os.path.join(
             REPO_ROOT, config[0]["file"].replace(".json", suffix)))
-    cell = bench["workloads"][-1]
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
     assert cell == {"name": CELL, "config": "ling-3.0-flash-vl",
                     "traffic": "wfbp", "chips": 1, "why": cell["why"]}
-    # The newest configuration's test counts exactly.
-    assert len(bench["configs"]) == 13 and len(bench["workloads"]) == 15
+    # The exact counts are the newest configuration's
+    # (tests/test_keye_cell.py).
+    assert len(bench["configs"]) >= 13 and len(bench["workloads"]) >= 15
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     for entry in bench["configs"] + bench["workloads"]:
         assert 1 <= len(entry["why"]) <= 200 and entry["why"].isprintable()
     metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     for name in SHARED_METRICS:
-        assert metrics[name]["workloads"][-1] == CELL, name
+        assert CELL in metrics[name]["workloads"], name
     names = [m["name"] for m in bench["per_layer"]]
-    # PR 67 appended the row kernels' two (tests/test_head_rows.py).
-    assert names[-4:] == list(NEW_METRICS) + ["head_rows_ms_step",
-                                              "head_rows_calls_step"]
+    # PR 67 appended the row kernels' two (tests/test_head_rows.py), and
+    # later cells' metrics stand behind those.
+    at = names.index(NEW_METRICS[0])
+    assert names[at:at + 4] == list(NEW_METRICS) + ["head_rows_ms_step",
+                                                    "head_rows_calls_step"]
     for name in NEW_METRICS:
         assert metrics[name]["workloads"] == [CELL]
         assert metrics[name]["layer"] == "kernel"

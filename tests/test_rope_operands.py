@@ -260,4 +260,5 @@ def test_the_counter_reads_both_kernels_by_their_names():
                      "moves": "samples_per_s_chip",
                      "workloads": ["laguna-s-2.1-wfbp-1chip",
                                    "smallthinker-21b-a3b-wfbp-1chip",
-                                   "sdar-30b-a3b-wfbp-1chip"]}
+                                   "sdar-30b-a3b-wfbp-1chip",
+                                   "keye-vl-2.0-30b-a3b-wfbp-1chip"]}
