@@ -9,7 +9,10 @@ indexer of 16 heads of 64 on one shared key, 2048 keys chosen a query:
   dense table a block of 512 queries at a time (``models/indexer.py``'s
   ``jax.numpy`` form, a sort on a TPU), ms a layer each, and the share of
   pairs on which the two sets differ (bf16 operands both: the sums' order is
-  the only difference, so last-bit neighbours of the threshold);
+  the only difference, so last-bit neighbours of the threshold); then over
+  ``--tie-seeds`` seeds what the kernel says of its blocks: how many broke
+  ties, how many rows tied, and the counting passes run of
+  ``dsa.passes_at_most`` a block (PR 70);
 - ``attention``: ``masked_attention``'s forward and forward + backward under
   ``Sparse`` (every causal tile masked from the words) beside the same shapes
   under ``Causal`` (16 of 136 tiles masked, from iotas): what the mask's
@@ -19,7 +22,9 @@ indexer of 16 heads of 64 on one shared key, 2048 keys chosen a query:
   to read above :data:`GRADIENT_RTOL` where the kernels stay below it;
 - ``loss``: ``kernels/dsa.py::kl_sum`` (the target, the divergence and its
   gradient in one pass) ms a layer, and its value and gradients against the
-  ``jax.numpy`` form's (``--check-loss``: a dense pass, minutes at 16,384).
+  ``jax.numpy`` form's on the same operands and on the same values in
+  float32 at the highest precision (``--check-loss``: a dense pass, minutes
+  at 16,384; ``--seq 4096 --topk 512`` takes seconds).
 
 Needs a TPU; ``--seq 1024 --interpret`` on the CPU is a rehearsal of the same
 code (the kernels in interpret mode, no time).  One JSON object a line;
@@ -122,6 +127,7 @@ def main(argv=None):
     p.add_argument("--seq", type=int, default=16384)
     p.add_argument("--topk", type=int, default=2048)
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--tie-seeds", type=int, default=6)
     p.add_argument("--interpret", action="store_true")
     p.add_argument("--check-loss", action="store_true")
     p.add_argument("--out")
@@ -149,7 +155,7 @@ def main(argv=None):
     kernel = jax.jit(lambda q_i, k_i, w: dsa.choose(
         q_i, k_i, w, topk=topk, interpret=interpret, **small))
     by_sort = jax.jit(lambda q_i, k_i, w: indexer._choose(q_i, k_i, w, topk))
-    words, lse_i = kernel(x["q_i"], x["k_i"], x["w"])
+    words, lse_i, _ = kernel(x["q_i"], x["k_i"], x["w"])
     ok = True
 
     if "choose" in args.phases:
@@ -168,6 +174,20 @@ def main(argv=None):
             line["ms_top_k"] = timed(by_sort, x["q_i"], x["k_i"], x["w"],
                                      iters=2)
         emit(line)
+        for seed in range(args.seed, args.seed + args.tie_seeds):
+            y = operands(seed, s, jnp.bfloat16)
+            trio = (y["q_i"], y["k_i"], y["w"])
+            got, _, blocks = kernel(*trio)
+            ties, passes = blocks[..., 0], blocks[..., 1]
+            emit({"phase": "choose_ties", "seed": seed,
+                  "blocks": int(ties.size),
+                  "blocks_that_broke_ties": int((ties > 0).sum()),
+                  "rows_that_tied": int(ties.sum()),
+                  "passes_run": int(passes.sum()),
+                  "passes_at_most": int(ties.size) * dsa.passes_at_most(s),
+                  "pairs_that_differ_from_top_k": int(
+                      (unpack_chosen(got, s)
+                       != unpack_chosen(by_sort(*trio), s)).sum())})
 
     rule = ma.Sparse(topk)
     if "attention" in args.phases:
@@ -240,14 +260,21 @@ def main(argv=None):
         if not interpret:
             line["ms_kernel"] = timed(kernel_loss, *trio)
         if args.check_loss or interpret:
-            want_value, want = value_and_gradients(
+            blockwise = value_and_gradients(
                 lambda q_i, k_i, w, words, lse_i, q, k, lse: indexer._kl_sum(
-                    q_i, k_i, w, words, q, k))(*trio)
+                    q_i, k_i, w, words, q, k))
+            want_value, want = blockwise(*trio)
+            with jax.default_matmul_precision("highest"):
+                _, exact_grads = blockwise(*(
+                    t.astype(jnp.float32) if t.dtype == jnp.bfloat16 else t
+                    for t in trio))
             line["kl_mean_jnp"] = float(want_value) / s
-            line["errors"] = dict(zip(
-                ("dq_i", "dk_i", "dw"),
-                (rel(g, jnp.asarray(t, jnp.float32))
-                 for g, t in zip(grads, want))))
+            names = ("dq_i", "dk_i", "dw")
+            line["errors"] = dict(zip(names, (
+                rel(g, jnp.asarray(t, jnp.float32))
+                for g, t in zip(grads, want))))
+            line["errors_float32"] = dict(zip(names, map(
+                rel, grads, exact_grads)))
             line["ok"] = max(line["errors"].values()) < GRADIENT_RTOL \
                 and abs(line["kl_mean"] - line["kl_mean_jnp"]) \
                 < 1e-2 * abs(line["kl_mean_jnp"])

@@ -17,13 +17,18 @@ and the attention of its layer runs over the ``topk`` keys with the largest
 of them, :data:`KEYS` at a time (bf16 operands, fp32 sums), and keeps the
 scores of the block ``[ROWS, s]`` in scratch as integers that order as the
 floats do.  The ``k``-th largest of a row (``k = min(topk, t + 1)``) is then
-found **exactly** by counting: 32 passes over the block, one a bit from the
-sign down, each asking of every row how many of its scores reach the
-threshold built so far with this bit set; of the scores equal to the
-threshold the lower positions are taken, found by the same counting over the
-bits of a position (``lax.top_k``'s order).  The chosen set leaves as
+found **exactly** by counting: passes over the block, one a bit from the sign
+down, each asking of every row how many of its scores reach the threshold
+built so far with this bit set.  A pass's own count says when a row is
+settled (exactly ``k`` of its scores reach its threshold: they are its set),
+and the passes end when every row of the block is, after 32 at the most.
+Only a block left with a row that more than ``k`` reach (scores equal at the
+threshold) runs the passes that break ties: of those scores the lower
+positions are taken, found by the same counting over the bits of a position
+(``lax.top_k``'s order).  The chosen set leaves as
 ``masked_attention_bwd.pack_chosen``'s words, a bit a pair, beside the
-log-sum-exp of ``I[t, .]`` over the set (what :func:`kl_sum` normalises by).
+log-sum-exp of ``I[t, .]`` over the set (what :func:`kl_sum` normalises by)
+and, a block, the rows that tied and the passes it ran.
 
 :func:`kl_sum` (``hvd_dsa_loss``): the sum over the queries of ``KL(p[t, .]
 || softmax over the set of I[t, .])``, ``p`` the layer's own attention
@@ -32,7 +37,14 @@ and log-sum-exp; one pass over the causal tiles (``tile_table`` under
 ``Causal``) that rebuilds ``p`` and ``I`` a tile, adds up the divergence and,
 in the same pass, its gradient to ``q_j``, ``k`` and ``w`` (``dI = softmax -
 p`` over the set, through the ReLU and the weights), keys on the rows as the
-attention's backward kernel lays a tile out.  ``p`` is a constant of the step
+attention's backward kernel lays a tile out.  The products bind the kernel
+(the 64-wide ones fill half the MXU), so a head's scores are multiplied once
+a tile and kept in scratch behind the ReLU for the gradient; there a head
+costs a compare, a select and a cast a pair: its weight leaves the tile
+(``dk += g_j (w_j q_j)``, the weighted queries made once a tile of queries;
+``dq_j = w_j sum_s g_j k``, scaled at the tile's last step) and the queries'
+sum is kept with the queries on the lanes (``k^T g_j``), so ``g_j`` is never
+transposed.  ``p`` is a constant of the step
 (the target is cut from the graph), so the backward pass of the step only
 scales what the forward pass wrote.
 
@@ -69,9 +81,9 @@ _LANES = 128
 # configuration's ``q_chunk_size`` and ``kv_chunk_size`` are blocks of the
 # computation and change no result: these are the kernel's).
 ROWS = 256
-KEYS = 512
+KEYS = 1024
 # :func:`kl_sum`'s tiles, queries x keys.
-LOSS_TILES = (512, 512)
+LOSS_TILES = (256, 512)
 _VMEM_LIMIT = 100 * 2 ** 20
 _INT_MIN = int(np.iinfo(np.int32).min)
 
@@ -84,6 +96,17 @@ def takes(seq_len: int, head_dim: int) -> bool:
             and head_dim in (_LANES // 2, _LANES))
 
 
+def passes_at_most(seq_len: int) -> int:
+    """The counting passes of a block of :func:`choose` that ends none early
+    and breaks ties: one for the sign and 31 for the bits of a score, one for
+    the scores above the threshold and one a bit of a position."""
+    return 32 + 1 + _position_bits(seq_len)
+
+
+def _position_bits(seq_len: int) -> int:
+    return max(1, (seq_len - 1).bit_length())
+
+
 def _ordered(x):
     """fp32 as int32 that compare as the floats do (and back: the map is its
     own inverse)."""
@@ -91,8 +114,8 @@ def _ordered(x):
     return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
 
 
-def _choose_kernel(q_ref, k_ref, w_ref, words_ref, lse_ref, keys_ref, *,
-                   topk: int, seq_len: int, rows: int, keys: int):
+def _choose_kernel(q_ref, k_ref, w_ref, words_ref, lse_ref, blocks_ref,
+                   keys_ref, *, topk: int, seq_len: int, rows: int, keys: int):
     import jax.experimental.pallas as pl
 
     heads = q_ref.shape[0]
@@ -142,30 +165,56 @@ def _choose_kernel(q_ref, k_ref, w_ref, words_ref, lse_ref, keys_ref, *,
                             jnp.zeros((rows, _LANES), jnp.int32))
         return jnp.sum(acc, axis=1, keepdims=True)
 
+    def inexact(reached):
+        """How many rows more than `wanted` scores reach."""
+        return jnp.sum((reached != wanted).astype(jnp.int32))
+
     # The wanted-th largest score of a row, bit by bit from the sign down:
-    # the largest threshold that at least `wanted` scores reach.
-    reach = count(lambda x, ids: x >= 0) >= wanted
+    # the largest threshold that at least `wanted` scores reach, and beside
+    # it how many reach it (the accepted pass's own count; at the start
+    # every score of the block does).  A row that exactly `wanted` reach is
+    # settled, its set is `x >= threshold` whatever the bits below: the
+    # passes end once every row of the block is.
+    first = count(lambda x, ids: x >= 0)
+    reach = first >= wanted
     threshold = jnp.where(reach, 0, _INT_MIN).astype(jnp.int32)
+    reached = jnp.where(reach, first, chunks * keys)
 
-    def score_bit(i, threshold):
+    def score_bit(carry):
+        i, threshold, reached, _ = carry
         with_bit = threshold | jnp.left_shift(jnp.int32(1), 30 - i)
-        reach = count(lambda x, ids: x >= with_bit) >= wanted
-        return jnp.where(reach, with_bit, threshold)
+        n = count(lambda x, ids: x >= with_bit)
+        reach = n >= wanted
+        reached = jnp.where(reach, n, reached)
+        return (i + 1, jnp.where(reach, with_bit, threshold), reached,
+                inexact(reached))
 
-    threshold = lax.fori_loop(0, 31, score_bit, threshold)
-    # Of the scores equal to it, the lower positions: the last position
-    # taken, by the bits of a position.
-    above = count(lambda x, ids: x > threshold)
-    ties_wanted = wanted - above
-    bits = max(1, (seq_len - 1).bit_length())
+    bit_passes, threshold, reached, tied = lax.while_loop(
+        lambda carry: (carry[0] < 31) & (carry[3] > 0), score_bit,
+        (jnp.int32(0), threshold, reached, inexact(reached)))
+    bits = _position_bits(seq_len)
 
-    def position_bit(i, last):
-        with_bit = last | jnp.left_shift(jnp.int32(1), bits - 1 - i)
-        below = count(lambda x, ids: (x == threshold) & (ids < with_bit))
-        return jnp.where(below < ties_wanted, with_bit, last)
+    def break_ties():
+        # Of the scores equal to the threshold, the lower positions: the
+        # last position taken, by the bits of a position.
+        above = count(lambda x, ids: x > threshold)
+        ties_wanted = wanted - above
 
-    last = lax.fori_loop(0, bits, position_bit,
-                         jnp.zeros((rows, 1), jnp.int32))
+        def position_bit(i, last):
+            with_bit = last | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+            below = count(lambda x, ids: (x == threshold) & (ids < with_bit))
+            return jnp.where(below < ties_wanted, with_bit, last)
+
+        return lax.fori_loop(0, bits, position_bit,
+                             jnp.zeros((rows, 1), jnp.int32))
+
+    # Only a block with a row that is not settled breaks ties.
+    last = lax.cond(tied > 0, break_ties,
+                    lambda: jnp.full((rows, 1), seq_len - 1, jnp.int32))
+    passes = 1 + bit_passes + jnp.where(tied > 0, 1 + bits, 0)
+    blocks_ref[...] = jnp.where(
+        lax.broadcasted_iota(jnp.int32, blocks_ref.shape, 1) == 0, tied,
+        passes)
 
     # The set as words, a lane group of keys a bit, and its log-sum-exp.
     total = jnp.zeros((rows, _LANES), jnp.float32)
@@ -196,9 +245,12 @@ def choose(q_i, k_i, w, *, topk: int, interpret: bool = False,
     """The chosen sets of every query: ``q_i [b, H, s, d]`` and ``k_i [b, s,
     d]`` (both turned by their positions) and ``w [b, s, H]`` give ``(words
     [b, s, groups * 128]`` int32, ``pack_chosen``'s, ``lse [b, s]`` fp32, the
-    log-sum-exp of a query's scores over its set``)``.  Query ``t``'s set is
-    the ``min(topk, t + 1)`` keys ``s <= t`` of the largest ``I[t, s]``, of
-    equal scores the lower position."""
+    log-sum-exp of a query's scores over its set, ``blocks [b, s // rows,
+    2]`` int32, of a block of ``rows`` queries how many rows had more scores
+    at their threshold than they take (none: the block broke no ties) and
+    how many counting passes it ran``)``.  Query ``t``'s set is the
+    ``min(topk, t + 1)`` keys ``s <= t`` of the largest ``I[t, s]``, of equal
+    scores the lower position."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -208,7 +260,7 @@ def choose(q_i, k_i, w, *, topk: int, interpret: bool = False,
         raise ValueError(f"blocks of {rows} queries and {keys} keys do not "
                          f"divide {s} positions by lane groups")
     width = -(-s // CHOSEN_GROUP) * _LANES
-    words, lse = pl.pallas_call(
+    words, lse, blocks = pl.pallas_call(
         functools.partial(_choose_kernel, topk=topk, seq_len=s, rows=rows,
                           keys=keys),
         grid=(b, s // rows),
@@ -218,22 +270,26 @@ def choose(q_i, k_i, w, *, topk: int, interpret: bool = False,
                   pl.BlockSpec((None, rows, heads), lambda n, i: (n, i, 0))],
         out_specs=[pl.BlockSpec((None, rows, width), lambda n, i: (n, i, 0)),
                    pl.BlockSpec((None, rows, _LANES),
-                                lambda n, i: (n, i, 0))],
+                                lambda n, i: (n, i, 0)),
+                   pl.BlockSpec((None, None, 8, _LANES),
+                                lambda n, i: (n, i, 0, 0))],
         scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
         out_shape=[jax.ShapeDtypeStruct((b, s, width), jnp.int32),
-                   jax.ShapeDtypeStruct((b, s, _LANES), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, s, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s // rows, 8, _LANES),
+                                        jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
         name=CHOOSE_NAME, interpret=interpret,
     )(q_i, k_i, w)
-    return words, lse[:, :, 0]
+    return words, lse[:, :, 0], blocks[:, :, 0, :2]
 
 
 def _loss_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, lse_ref,
                  qi_ref, ki_ref, wt_ref, lse_i_ref, words_ref, kl_ref,
-                 dqi_ref, dki_ref, dwt_ref, words_t, kl_acc, dqi_acc, dki_acc,
-                 dwt_acc):
+                 dqi_ref, dki_ref, dwt_ref, words_t, kl_acc, qw, kept, g_acc,
+                 dki_acc, dwt_acc):
     import jax.experimental.pallas as pl
 
     heads, block_q = q_ref.shape[0], q_ref.shape[1]
@@ -243,6 +299,7 @@ def _loss_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, lse_ref,
     flags = flags_ref[step]
     kv_start = kv_tile_ref[step] * block_kv
     nt = (((1,), (1,)), ((), ()))
+    wt = wt_ref[...].astype(jnp.float32)                  # [H, queries]
 
     @pl.when(step == 0)
     def _():
@@ -251,8 +308,13 @@ def _loss_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, lse_ref,
     @pl.when(flags & FIRST != 0)
     def _():
         kl_acc[...] = jnp.zeros_like(kl_acc)
-        dqi_acc[...] = jnp.zeros_like(dqi_acc)
+        g_acc[...] = jnp.zeros_like(g_acc)
         dwt_acc[...] = jnp.zeros_like(dwt_acc)
+        # A head's weight times its queries, once a tile of queries: what
+        # the keys' gradient multiplies.
+        for j in range(i_heads):
+            turned = qi_ref[j].astype(jnp.float32).T      # [d, queries]
+            qw[j] = (turned * wt[j:j + 1]).T.astype(qw.dtype)
 
     @pl.when((flags & FIRST != 0) | (kv_start % CHOSEN_GROUP == 0))
     def _():
@@ -266,36 +328,40 @@ def _loss_kernel(q_tile_ref, kv_tile_ref, flags_ref, q_ref, k_ref, lse_ref,
                             preferred_element_type=jnp.float32)
         p = p + jnp.exp(s - lse_ref[h])
     p = jnp.where(mask, p * (1.0 / heads), 0.0)
-    wt = wt_ref[...].astype(jnp.float32)                  # [H, queries]
     ki = ki_ref[...]
-
-    def head_scores(j):
-        return lax.dot_general(ki, qi_ref[j], nt,
-                               preferred_element_type=jnp.float32)
-
+    # The heads' scores behind the ReLU stay in scratch for the gradient.
     scores = jnp.zeros((block_kv, block_q), jnp.float32)
     for j in range(i_heads):
-        scores = scores + wt[j:j + 1] * jnp.maximum(head_scores(j), 0.0)
+        a = jnp.maximum(lax.dot_general(
+            ki, qi_ref[j], nt, preferred_element_type=jnp.float32), 0.0)
+        kept[j] = a
+        scores = scores + wt[j:j + 1] * a
     log_q = scores - lse_i_ref[...]
     kl_acc[...] += jnp.sum(
         jnp.where(p > 0.0, p * (jnp.log(jnp.where(p > 0.0, p, 1.0)) - log_q),
                   0.0), axis=0, keepdims=True)
     d_scores = jnp.where(mask, jnp.exp(log_q) - p, 0.0)
     at = pl.ds(pl.multiple_of(kv_start, block_kv), block_kv)
+    ki_t = ki.T                                           # [d, keys]
     for j in range(i_heads):
-        a = head_scores(j)
-        dwt_acc[j:j + 1, :] += jnp.sum(d_scores * jnp.maximum(a, 0.0),
-                                       axis=0, keepdims=True)
-        g = jnp.where(a > 0.0, d_scores * wt[j:j + 1], 0.0).astype(ki.dtype)
-        dki_acc[at, :] += lax.dot(g, qi_ref[j],
+        # Through the ReLU alone: the head's weight stands in `qw` for the
+        # keys and waits for the queries' sum at the tile's last step.
+        a = kept[j]
+        g = jnp.where(a > 0.0, d_scores, 0.0)
+        dwt_acc[j:j + 1, :] += jnp.sum(g * a, axis=0, keepdims=True)
+        g = g.astype(ki.dtype)
+        dki_acc[at, :] += lax.dot(g, qw[j],
                                   preferred_element_type=jnp.float32)
-        dqi_acc[j] += lax.dot(g.T, ki, preferred_element_type=jnp.float32)
+        # Keys contracted with the queries on the lanes as they lie: no
+        # transposition of `g`.
+        g_acc[j] += lax.dot(ki_t, g, preferred_element_type=jnp.float32)
 
     @pl.when(flags & LAST != 0)
     def _():
         kl_ref[...] = kl_acc[...]
-        dqi_ref[...] = dqi_acc[...].astype(dqi_ref.dtype)
         dwt_ref[...] = dwt_acc[...]
+        for j in range(i_heads):
+            dqi_ref[j] = (g_acc[j] * wt[j:j + 1]).T.astype(dqi_ref.dtype)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _():
@@ -356,7 +422,9 @@ def _kl_and_gradients(q_i, k_i, w, words, lse_i, q, k, lse, *, tiles,
             scratch_shapes=[
                 pltpu.VMEM((_LANES, block_q), jnp.int32),
                 pltpu.VMEM((1, block_q), jnp.float32),
-                pltpu.VMEM((i_heads, block_q, d_i), jnp.float32),
+                pltpu.VMEM((i_heads, block_q, d_i), q_i.dtype),
+                pltpu.VMEM((i_heads, block_kv, block_q), jnp.float32),
+                pltpu.VMEM((i_heads, d_i, block_q), jnp.float32),
                 pltpu.VMEM((s, d_i), jnp.float32),
                 pltpu.VMEM((i_heads, block_q), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, 1, s), jnp.float32),

@@ -27,7 +27,10 @@ the scores and the choice (``hvd.indexer.scores``, ``hvd.indexer.choose``),
 take the shape (one kernel each: the op line tells no scores from the choice
 and no target from the loss, the scopes name what a kernel holds), else the
 same rule in ``jax.numpy`` a block of queries at a time, the choice by
-``lax.top_k``.  :func:`scores` is that form's table of a block.
+``lax.top_k``.  The choosing kernel counts its way to a row's threshold and
+breaks ties only in a block that has one; what it says of its blocks (rows
+that tied, passes run) is ``benchmarks/sparse_attention_sweep.py``'s to
+read and is dropped here.  :func:`scores` is that form's table of a block.
 """
 
 from __future__ import annotations
@@ -130,7 +133,9 @@ def choose(q_i, k_i, w, topk: int):
     # by block (chip_bench/scopes.py) reads it under the choice.
     with scope("indexer.scores"), scope("indexer.choose"):
         if dsa.takes(s, d):
-            return dsa.choose(q_i, k_i, w, topk=topk)
+            # The blocks that broke ties are the sweep's to read, not the
+            # step's: no leaf of the `indexer` collection.
+            return dsa.choose(q_i, k_i, w, topk=topk)[:2]
         return _choose(q_i, k_i, w, topk), None
 
 

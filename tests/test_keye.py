@@ -334,26 +334,32 @@ def _indexer_operands(seed, b, s, heads=4, width=64):
             0.1 * jax.random.normal(keys[2], (b, s, heads)))
 
 
-@pytest.mark.parametrize("topk", [64, 200])
-def test_the_chosen_sets_are_top_ks_with_planted_ties(topk):
+@pytest.mark.parametrize("topk,planted", [(64, True), (200, True),
+                                          (64, False)])
+def test_the_chosen_sets_are_top_ks_with_planted_ties(topk, planted):
     """``hvd_dsa_choose`` in interpret mode against ``lax.top_k`` on the
     reference's dense table, pair for pair: runs of keys that are one key
     (their scores tie, and the lower positions are taken), and queries whose
     weights are all zero (every score ties at zero); the ``jax.numpy`` form
     the same; a query's set holds ``min(topk, t + 1)`` keys; the
-    log-sum-exp over the set."""
+    log-sum-exp over the set.  A block with a planted tie says so and breaks
+    it; without planted ties no block does (the passes over a position's
+    bits are skipped) and the sets are ``lax.top_k``'s all the same."""
     from horovod_tpu.kernels import dsa
     from horovod_tpu.kernels.masked_attention_bwd import unpack_chosen
     from horovod_tpu.models import indexer
 
-    b, s = 2, 512
-    q_i, k_i, w = _indexer_operands(3, b, s)
-    k_i = k_i.at[:, 10:20].set(k_i[:, 10:11])
-    k_i = k_i.at[:, 100:180].set(k_i[:, 100:101])
-    w = w.at[:, 300:310].set(0.0)
+    # Four heads leave a pair in sixteen at exactly zero behind the ReLU,
+    # ties of their own; sixteen leave none.
+    b, s, heads = (1, 512, 4) if planted else (1, 256, 16)
+    q_i, k_i, w = _indexer_operands(3, b, s, heads)
+    if planted:
+        k_i = k_i.at[:, 10:20].set(k_i[:, 10:11])
+        k_i = k_i.at[:, 100:180].set(k_i[:, 100:101])
+        w = w.at[:, 300:310].set(0.0)
     with jax.default_matmul_precision("highest"):
-        words, lse = dsa.choose(q_i, k_i, w, topk=topk, interpret=True,
-                                rows=128, keys=128)
+        words, lse, blocks = dsa.choose(q_i, k_i, w, topk=topk,
+                                        interpret=True, rows=128, keys=128)
         by_top_k = indexer._choose(q_i, k_i, w, topk)
         want = jnp.stack([jnp.concatenate([ref.chosen_block(
             q_i[n].transpose(1, 0, 2), k_i[n], w[n], start, 128, topk)
@@ -365,6 +371,29 @@ def test_the_chosen_sets_are_top_ks_with_planted_ties(topk):
     np.testing.assert_array_equal(
         got.sum(axis=-1), np.broadcast_to(
             np.minimum(np.arange(s) + 1, topk), (b, s)))
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(jnp.where(want, table, -jnp.inf), axis=-1),
+        rtol=1e-5, atol=1e-5)
+    # A block's count of rows with more scores at their threshold than they
+    # take: the dense table's own.
+    dense, sets = np.asarray(table), np.asarray(want)
+    kth = np.take_along_axis(
+        -np.sort(-dense, axis=-1),
+        np.minimum(np.arange(s), topk - 1)[None, :, None], axis=-1)
+    tied = (dense >= kth).sum(axis=-1) != sets.sum(axis=-1)
+    assert blocks.shape == (b, s // 128, 2) and blocks.dtype == jnp.int32
+    ties, passes = np.asarray(blocks[..., 0]), np.asarray(blocks[..., 1])
+    np.testing.assert_array_equal(
+        ties, tied.reshape(b, s // 128, 128).sum(axis=-1))
+    # 32 passes at the most over the scores' bits; a block with a tie runs
+    # one more and one a bit of a position, any other none of them.
+    assert np.all((1 <= passes[ties == 0]) & (passes[ties == 0] <= 32))
+    assert np.all(passes[ties > 0] == dsa.passes_at_most(s))
+    if not planted:
+        assert not np.any(ties)
+        return
+    # The rows of no weight tie at zero: their block breaks ties.
+    assert np.all(ties[:, 300 // 128] >= 10)
     # The planted ties cut a run: some of its keys in, the later ones out.
     run = np.asarray(got[:, :, 100:180])
     cut = (run.any(axis=-1) & ~run.all(axis=-1))[:, 180:]
@@ -374,9 +403,6 @@ def test_the_chosen_sets_are_top_ks_with_planted_ties(topk):
                                    np.arange(80)[None, :]]
                   == (np.arange(80)[None, :]
                       < first_out[:, 180:][cut][:, None]))
-    np.testing.assert_allclose(
-        lse, jax.nn.logsumexp(jnp.where(want, table, -jnp.inf), axis=-1),
-        rtol=1e-5, atol=1e-5)
 
 
 def test_pack_and_unpack_and_the_rule():
@@ -462,26 +488,38 @@ def test_attention_kernels_under_a_chosen_set_match_the_masked_einsum(
         assert rel_err(g, w) < limit, name
 
 
-def test_the_loss_kernel_matches_the_blockwise_form():
+@pytest.mark.parametrize("dtype,value_limit,limits", [
+    (jnp.float32, 1e-5, (1e-4, 1e-4, 1e-4)),
+    (jnp.bfloat16, 1e-5, (4e-3, 4e-3, 1e-5))])
+def test_the_loss_kernel_matches_the_blockwise_form(dtype, value_limit,
+                                                    limits):
     """``hvd_dsa_loss`` in interpret mode, tiles of 128 a side: the sum of
     the divergences and its gradient to the indexer's three operands against
     the ``jax.numpy`` form's (a softmax of its own for the target, autodiff
-    for the gradient); the attention's operands get none."""
+    for the gradient); the attention's operands get none.  In bf16 operands
+    the kernel rounds the divergence's gradient and a head's weighted query
+    to bf16 in front of its two products where the blockwise form keeps
+    fp32: PR 68's kernel read 2.8e-3, 2.7e-3 and 8e-7 at this shape (dq_i,
+    dk_i, dw), half of the first two the bf16 outputs' own rounding."""
     from horovod_tpu.kernels import dsa
     from horovod_tpu.kernels.masked_attention_bwd import unpack_chosen
     from horovod_tpu.models import indexer
 
-    b, s, heads, h_kv, d, topk = 2, 512, 4, 2, 128, 64
-    q_i, k_i, w = _indexer_operands(5, b, s)
+    b, s, heads, h_kv, d, topk = 1, 512, 4, 2, 128, 64
+    if dtype == jnp.bfloat16:
+        s, heads, h_kv, topk = 256, 2, 1, 32
+    q_i, k_i, w = _indexer_operands(5, b, s, heads=heads)
     keys = jax.random.split(jax.random.PRNGKey(6), 2)
     q = jax.random.normal(keys[0], (b, heads, s, d)) * d ** -0.5
     k = jax.random.normal(keys[1], (b, h_kv, s, d))
+    q_i, k_i, q, k = (t.astype(dtype) for t in (q_i, k_i, q, k))
     with jax.default_matmul_precision("highest"):
         words, lse_i = dsa.choose(q_i, k_i, w, topk=topk, interpret=True,
-                                  rows=128, keys=128)
+                                  rows=128, keys=128)[:2]
         mask = unpack_chosen(words, s)
         scores = jnp.einsum("bngtd,bnsd->bngts",
-                            q.reshape(b, h_kv, heads // h_kv, s, d), k)
+                            q.reshape(b, h_kv, heads // h_kv, s, d), k,
+                            preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(
             jnp.where(mask[:, None, None], scores, -jnp.inf),
             axis=-1).reshape(b, heads, s)
@@ -497,10 +535,12 @@ def test_the_loss_kernel_matches_the_blockwise_form():
             q_i, k_i, w, q, k)
         want_value, want = jax.value_and_grad(
             blockwise, argnums=(0, 1, 2, 3, 4))(q_i, k_i, w, q, k)
-    assert float(got_value) == pytest.approx(float(want_value), rel=1e-5)
+    assert float(got_value) == pytest.approx(float(want_value),
+                                             rel=value_limit)
     assert float(want_value) > 1.0
-    for name, g, t in zip(("dq_i", "dk_i", "dw"), got[:3], want[:3]):
-        assert rel_err(g, t) < 1e-4, name
+    for name, g, t, limit in zip(("dq_i", "dk_i", "dw"), got[:3], want[:3],
+                                 limits):
+        assert rel_err(g, t) < limit, name
     for g in got[3:] + want[3:]:
         assert not np.any(g)
 

@@ -72,17 +72,31 @@ class TestRegistry:
         assert h["counts"][-1] == 1  # the +Inf overflow observation
 
     def test_disabled_is_a_noop(self):
+        """Nothing is recorded *while disabled*: the three series read the
+        same after the calls as before them.  (Not "absent from a snapshot
+        taken afterwards": the registry is the process's, and a runtime
+        that an earlier test of this worker left behind writes to it, in a
+        view or from a thread, as soon as recording is on again.)"""
+        def series():
+            hists = metrics.registry.snapshot()["histograms"]
+            return (metrics.registry.get_counter("faults_injected_total"),
+                    metrics.registry.get_gauge("tensor_queue_depth"),
+                    hists.get("controller_cycle_seconds"))
+
         metrics.configure(False)
         try:
+            before = series()
             metrics.inc("faults_injected_total")
             metrics.observe("controller_cycle_seconds", 1.0)
             metrics.set_gauge("tensor_queue_depth", 9)
+            after = series()
         finally:
             metrics.configure(True)
-        snap = metrics.registry.snapshot()
-        assert "faults_injected_total" not in snap["counters"]
-        assert "tensor_queue_depth" not in snap["gauges"]
-        assert "controller_cycle_seconds" not in snap["histograms"]
+        assert after == before
+        # The same calls do record once it is on again.
+        metrics.inc("faults_injected_total")
+        assert metrics.registry.get_counter("faults_injected_total") \
+            == before[0] + 1
 
     def test_flat_roundtrip(self):
         flat = metrics.flat("x_total", op="GET", rank="3")
